@@ -15,14 +15,8 @@ Run (CPU: XLA_FLAGS=--xla_force_host_platform_device_count=8):
 from __future__ import annotations
 
 import argparse
-import os
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    # some images preload jax with a pinned platform; the env var wins here
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -98,10 +92,7 @@ def main():
     log = StepLogger(every=10, jsonl=args.jsonl)
     with trace(args.profile_dir):
         for step, batch in enumerate(stream):
-            batch = store.shard_batch(
-                {k: jnp.asarray(v) for k, v in batch.items()}
-            )
-            loss, _ = run(batch)
+            loss, _ = run(store.shard_batch(batch))
             if step == 0:
                 loss.block_until_ready()
                 metrics.mark_compiled()

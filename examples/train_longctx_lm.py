@@ -16,13 +16,8 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8):
 from __future__ import annotations
 
 import argparse
-import os
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -45,12 +45,6 @@ import argparse
 import os
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    # some images preload jax with a pinned platform; the env var wins here
-    # (the async nodes of one job may deliberately run on different backends)
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 
 import ps_tpu as ps

@@ -105,9 +105,7 @@ def main():
                                 seed=args.seed, steps=args.steps)
     with trace(args.profile_dir):
         for step, batch in enumerate(stream):
-            loss, _ = run(dense.shard_batch(
-                {k: jnp.asarray(v) for k, v in batch.items()}
-            ))
+            loss, _ = run(dense.shard_batch(batch))
             if step == 0:
                 loss.block_until_ready()
                 metrics.mark_compiled()

@@ -25,6 +25,7 @@ step (before the automatic reduction).
 from __future__ import annotations
 
 import contextlib
+import os
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -556,42 +557,18 @@ _LEAKED_SERVICES: list = []
 
 
 def _coordination_seam():
-    """Resolve the module object holding jax's distributed-runtime-client
-    factory across the jax versions supported here: jax >= 0.5 exposes it
-    as ``jax._src.distributed._jax``; jax 0.4.x as the ``xla_extension``
-    import inside the same module. Returns ``(owner, factory)``; raises
-    AttributeError when the seam moved again (the tests turn that into a
-    loud failure)."""
+    """The module object holding jax's distributed-runtime-client factory
+    (the private ``jax._src.distributed._jax``) and the factory itself.
+    Returns ``(owner, factory)``; raises AttributeError when jax moves the
+    seam (the tests turn that into a loud failure)."""
     from jax._src import distributed as _dist
 
-    for attr in ("_jax", "xla_extension"):
-        owner = getattr(_dist, attr, None)
-        if owner is not None and hasattr(owner,
-                                         "get_distributed_runtime_client"):
-            return owner, owner.get_distributed_runtime_client
-    raise AttributeError(
-        "jax._src.distributed exposes no get_distributed_runtime_client "
-        "(checked _jax and xla_extension)"
-    )
+    owner = _dist._jax
+    return owner, owner.get_distributed_runtime_client
 
 
 #: the recoverable-task client options and their values
 _RECOVERABLE_OPTS = {"recoverable": True, "shutdown_on_destruction": False}
-
-
-def _client_factory_kwargs(factory):
-    """Which recoverable-semantics kwargs this factory accepts, probed
-    from its nanobind docstring signature (``inspect.signature`` cannot
-    introspect nanobind functions). jax 0.4.x accepts
-    ``shutdown_on_destruction`` but predates ``recoverable``. Returns
-    ``None`` when the docstring does not carry the signature text at all
-    (stripped docs, a renamed wrapper): the caller must then fall back to
-    optimistically trying every kwarg — a probe false-negative must not
-    silently strip semantics the factory actually supports."""
-    doc = factory.__doc__ or ""
-    if "(" not in doc:
-        return None  # unparseable: capability unknown
-    return [k for k in _RECOVERABLE_OPTS if k in doc]
 
 
 @contextlib.contextmanager
@@ -604,13 +581,9 @@ def _coordination_client_options():
     survivors our failure detector is trying to hand a typed error), and the
     distributed shutdown barrier no longer blocks on dead peers. Dropping
     the client handle is barrier-free, which is what ``shutdown(abort=True)``
-    relies on. Wraps a private jax seam (:func:`_coordination_seam` — it
-    moved once already, in the 0.4→0.5 transition), passing only the
-    kwargs the resolved factory advertises: on jax 0.4.x that is
-    ``shutdown_on_destruction`` alone (``recoverable`` tasks arrived with
-    0.5 — a warning notes the partial semantics). If the seam moves or a
-    supposedly-supported kwarg is refused, initialization falls back to
-    jax's defaults with a warning — and
+    relies on. Wraps a private jax seam (:func:`_coordination_seam`). If the
+    seam moves or a kwarg is refused, initialization falls back to jax's
+    defaults with a warning — and
     ``tests/test_failure.py::test_coordination_seam_accepts_recoverable_kwargs``
     / ``::test_coordination_client_options_inject_without_degrading``
     construct a client through this exact path so the degradation is a loud
@@ -628,25 +601,9 @@ def _coordination_client_options():
         yield
         return
 
-    supported = _client_factory_kwargs(orig)
-    if supported is not None and "recoverable" not in supported:
-        import warnings
-
-        warnings.warn(
-            "this jax's coordination client predates 'recoverable' tasks "
-            "(jax<0.5): peer death may still LOG(FATAL) survivors; "
-            "shutdown_on_destruction=False is applied so aborts stay "
-            "barrier-free"
-        )
-    # unknown capability (unparseable docstring): try everything and let
-    # the TypeError fallback below sort it out — the pre-probe behavior
-    inject = supported if supported is not None else list(_RECOVERABLE_OPTS)
-
     def patched(*args, **kwargs):
-        for k in inject:
-            kwargs[k] = _RECOVERABLE_OPTS[k]
         try:
-            return orig(*args, **kwargs)
+            return orig(*args, **{**kwargs, **_RECOVERABLE_OPTS})
         except TypeError:
             import warnings
 
@@ -655,8 +612,6 @@ def _coordination_client_options():
                 "shutdown_on_destruction; clean aborts will degrade to "
                 "jax defaults (LOG(FATAL) on peer death)"
             )
-            for k in _RECOVERABLE_OPTS:
-                kwargs.pop(k, None)
             return orig(*args, **kwargs)
 
     owner.get_distributed_runtime_client = patched
@@ -666,12 +621,28 @@ def _coordination_client_options():
         owner.get_distributed_runtime_client = orig
 
 
+def _place_compile_cache() -> None:
+    """Give XLA's persistent compile cache a home before anything compiles
+    (README "Running on the chip"). Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set JAX reads it itself and nothing is touched; otherwise the cache is
+    ``<checkout>/.jax_cache``, found from this package's own location — a
+    fixed path, so a second process in the same checkout hits what the
+    first one compiled."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(checkout, ".jax_cache"))
+
+
 class TpuBackend:
     """Backend for ``ps_tpu.init(backend='tpu')``. Despite the name it runs
     anywhere JAX has devices — on CPU it uses virtual devices (tests), on a
     TPU slice it uses the real chips over ICI."""
 
     def __init__(self, config: Config):
+        _place_compile_cache()
         self.config = config
         self._owns_distributed = False
         self.failure_detector = None
@@ -729,18 +700,6 @@ class TpuBackend:
         failure detector is disabled)."""
         if self.failure_detector is not None:
             self.failure_detector.check()
-
-    def fused_apply_tier(self) -> str:
-        """The concrete sparse fused-apply tier this backend's devices
-        get (README "Sparse apply"): ``Config.fused_apply`` with 'auto'
-        resolved against the MESH's device platform — the one place the
-        by-backend detection lives, so every SparseEmbedding on this
-        backend (in-process tables and the remote sparse server's range
-        slices alike) lands on the same tier."""
-        from ps_tpu.ops.sparse_apply import resolve_tier
-
-        platform = next(iter(self.mesh.devices.flat)).platform
-        return resolve_tier(self.config.fused_apply, platform=platform)
 
     def create_server(self, optimizer, mode: Optional[str] = None,
                       aggregate: str = "mean", placement: str = "replicated",

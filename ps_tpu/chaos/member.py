@@ -131,7 +131,9 @@ def run_primary(args) -> int:
 
 
 def main(argv=None) -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # a host-plane member never owns the chip, whatever the parent's
+    # JAX_PLATFORMS names
+    os.environ["JAX_PLATFORMS"] = "cpu"
     ap = argparse.ArgumentParser(prog="ps_tpu.chaos.member")
     ap.add_argument("role", choices=["shard", "primary"])
     ap.add_argument("--out", required=True, help="handshake directory")

@@ -100,9 +100,7 @@ Environment variables honored by :meth:`Config.from_env`:
   registered aggregator (default 200)
 - ``PS_FUSED_APPLY``        — sparse embedding fused apply tier (README
   "Sparse apply"): 'off' = legacy masked full-table apply, 'jax' =
-  batch-sized gather→apply→scatter in pure JAX, 'pallas' = the fused
-  one-HBM-pass TPU kernel, 'auto' (default) = pallas on TPU, jax
-  elsewhere
+  batch-sized gather→apply→scatter in pure JAX, 'auto' (default) = jax
 - ``PS_EMBED_DEVICE_ROWS``  — tiered embedding device budget (README
   "Tiered embedding storage"): tables with more rows than this keep a
   device-HBM hot set of this many slots and spill the rest to a
@@ -409,10 +407,8 @@ class Config:
         masked full-table apply (O(num_rows) HBM traffic per push);
         'jax' gathers only the touched rows + their per-row optimizer
         state, applies the dense-rows rule, and scatters back —
-        batch-sized, pure JAX; 'pallas' fuses that gather→apply→scatter
-        into one TPU kernel pass over HBM; 'auto' (default) resolves by
-        backend platform — pallas on TPU, jax anywhere else. Numerics
-        are pinned to the 'off' path by the parity drill
+        batch-sized, pure JAX; 'auto' (default) is 'jax'. Numerics are
+        pinned to the 'off' path by the parity drill
         (tests/test_sparse_apply.py).
       embed_device_rows: tiered embedding device budget (README "Tiered
         embedding storage"; ps_tpu/kv/tiered.py): a table with more
@@ -619,8 +615,8 @@ class Config:
     nl_slow_frame_ms: float = 250.0
     # sparse fused apply (ps_tpu/ops/sparse_apply.py, README "Sparse
     # apply"): which tier SparseEmbedding's scatter-apply routes through
-    # — 'off' (legacy masked full-table), 'jax' (batch-sized fallback),
-    # 'pallas' (fused one-HBM-pass kernel), 'auto' (by backend platform)
+    # — 'off' (legacy masked full-table), 'jax' (batch-sized), 'auto'
+    # (= 'jax')
     fused_apply: str = "auto"
     # tiered embedding storage (ps_tpu/kv/tiered.py, README "Tiered
     # embedding storage"): device-HBM hot-slot budget (0 = unlimited =
@@ -800,10 +796,10 @@ class Config:
                 f"unknown push_native_admit mode "
                 f"{self.push_native_admit!r}; use 'off', 'on' or 'auto'"
             )
-        if self.fused_apply not in ("auto", "off", "jax", "pallas"):
+        if self.fused_apply not in ("auto", "off", "jax"):
             raise ValueError(
                 f"unknown fused_apply tier {self.fused_apply!r}; use "
-                "'off', 'jax', 'pallas' or 'auto'"
+                "'off', 'jax' or 'auto'"
             )
         if self.embed_device_rows < 0:
             raise ValueError("embed_device_rows must be >= 0 (0 = "

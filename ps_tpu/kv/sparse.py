@@ -45,10 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ps_tpu.api import current_context
 from ps_tpu.ops.sparse_apply import fused_sparse_apply, resolve_tier
@@ -71,10 +68,9 @@ class SparseEmbedding:
       dtype: table dtype (f32 default; bf16 halves pull bytes).
       fused_apply: which apply tier the scatter-apply routes through
         (README "Sparse apply"): 'off' = the legacy masked full-table
-        apply, 'jax'/'pallas' = the batch-sized fused
-        gather→apply→scatter (ps_tpu/ops/sparse_apply.py), 'auto' =
-        by backend platform. None (default) inherits the backend's
-        resolution of ``Config.fused_apply`` (PS_FUSED_APPLY).
+        apply, 'jax' = the batch-sized fused gather→apply→scatter
+        (ps_tpu/ops/sparse_apply.py), 'auto' = 'jax'. None (default)
+        inherits the backend's ``Config.fused_apply`` (PS_FUSED_APPLY).
     """
 
     def __init__(self, num_rows: int, dim: int, optimizer="adagrad",
@@ -101,14 +97,10 @@ class SparseEmbedding:
         self.capacity_factor = capacity_factor
         self._opt = make_rowwise(optimizer, **opt_kwargs)
         # fused apply tier (README "Sparse apply"): explicit arg wins;
-        # otherwise the backend's resolution of Config.fused_apply (the
-        # one place the by-platform 'auto' detection lives)
+        # otherwise the backend's Config.fused_apply
         if fused_apply is None:
-            tier_fn = getattr(ctx.backend, "fused_apply_tier", None)
-            fused_apply = tier_fn() if tier_fn is not None else None
-        self.fused_tier = resolve_tier(
-            fused_apply,
-            platform=next(iter(self.mesh.devices.flat)).platform)
+            fused_apply = ctx.config.fused_apply
+        self.fused_tier = resolve_tier(fused_apply)
         self._table: Optional[jax.Array] = None
         self._state: Any = None
         self._jit_apply = None   # cached jit wrappers: a fresh jax.jit per
@@ -233,7 +225,7 @@ class SparseEmbedding:
         Apply tier (README "Sparse apply"): with ``fused_tier`` 'off'
         the owner shard builds a TABLE-SIZED ``gsum``/``cnt`` and the
         optimizer updates the whole shard under a mask (three-plus full
-        HBM passes per push); 'jax'/'pallas' route through
+        HBM passes per push); 'jax' routes through
         :func:`~ps_tpu.ops.sparse_apply.fused_sparse_apply` — dedupe at
         batch size, gather only the touched rows + state, apply the
         dense-rows rule, scatter back — so apply cost is O(batch ids),
@@ -269,19 +261,15 @@ class SparseEmbedding:
                 ids_m = jnp.where(ok, local, -1)
                 g = jnp.where(ok[:, None], all_grads, 0).astype(jnp.float32)
                 new_table, new_state = fused_sparse_apply(
-                    table_shard, state_shard, ids_m, g, opt, tier
+                    table_shard, state_shard, ids_m, g, opt
                 )
             return new_table, new_state, dropped
 
         state_specs = self._state_specs()
-        # check_rep stays on for the non-pallas tiers; shard_map has no
-        # replication rule for pallas_call, and the fused kernel's output
-        # specs are exactly the input shardings anyway
         fn = shard_map(
             shard_apply, mesh=self.mesh,
             in_specs=(P(axis, None), state_specs, P(axis), P(axis, None)),
             out_specs=(P(axis, None), state_specs, P()),
-            check_rep=(tier != "pallas"),
         )
         return fn(table, state, ids, row_grads)
 

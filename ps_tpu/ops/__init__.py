@@ -1,10 +1,10 @@
-"""TPU kernels (Pallas) for the hot ops the XLA default leaves on the
-table. Currently: flash attention (ops/flash_attention.py) — the
-fused-softmax attention that never materializes the [S, S] probability
-matrix in HBM, the lever for long-sequence MFU — and the fused sparse
-embedding update (ops/sparse_apply.py) — gather→optimizer-apply→scatter
-of only the touched rows in one HBM pass, the lever that makes sparse
-apply cost batch-sized instead of table-sized (README "Sparse apply")."""
+"""Hot ops the default lowering leaves on the table: flash attention
+(ops/flash_attention.py, a Pallas TPU kernel) — the fused-softmax
+attention that never materializes the [S, S] probability matrix in HBM —
+and the fused sparse embedding update (ops/sparse_apply.py, plain JAX) —
+gather→optimizer-apply→scatter of only the touched rows, which makes
+sparse apply cost batch-sized instead of table-sized (README "Sparse
+apply")."""
 
 from ps_tpu.ops.flash_attention import flash_attention  # noqa: F401
 from ps_tpu.ops.sparse_apply import fused_sparse_apply  # noqa: F401

@@ -12,27 +12,24 @@ BATCH size, gather only the touched rows and their per-row optimizer
 state, apply the dense-rows rule (``RowwiseOptimizer.apply_rows``), and
 scatter rows+state back.
 
-Three tiers, selected by ``PS_FUSED_APPLY`` (``Config.fused_apply``,
-``off|jax|pallas|auto``; README "Sparse apply"):
+Two tiers, selected by ``PS_FUSED_APPLY`` (``Config.fused_apply``,
+``off|jax|auto``; README "Sparse apply"):
 
-- ``pallas`` — the fast tier: ONE kernel walks the deduped id list with
-  the table and state in HBM (``pl.ANY``), DMA-gathers each touched
-  row + its state slices into VMEM, runs ``apply_rows`` on-chip, and
-  DMA-scatters the results back. Filler slots (id -1: push padding,
-  merged duplicates) are skipped by ``pl.when`` — never a write, so no
-  read-modify-write hazard against a real row's update. Total HBM
-  traffic per push ≈ 2 · B · (row + state) bytes, table size absent
-  from the expression. Off-TPU the kernel runs in interpret mode, so
-  CPU CI drills the same kernel logic (the flash-attention precedent).
-- ``jax`` — the batch-sized pure-JAX fallback: take/gather the touched
-  rows + state, ``apply_rows``, ``.at[].set(mode='drop')`` scatter
-  (filler ids redirect out of range and drop). Same O(batch) traffic
-  shape, XLA-scheduled; the tier CPU CI runs by default.
+- ``jax`` — the batch-sized path: take/gather the touched rows + state,
+  ``apply_rows``, ``.at[].set(mode='drop')`` scatter (filler ids redirect
+  out of range and drop). O(batch) traffic, XLA-scheduled.
 - ``off`` — the legacy masked full-table path, byte-for-byte today's
   behavior (the caller keeps its own code path; this module is not
   involved).
 
-Numerical contract (tests/test_sparse_apply.py): both fused tiers match
+A third, hand-written tier (a Pallas kernel DMA-ing one ``[1, D]`` row at
+a time out of the HBM-resident table) was removed in PR 21: libtpu 0.0.34's
+Mosaic lays an HBM operand out in 128-lane tiles and refuses a row slice
+narrower than that ("Slice shape along dimension 1 must be aligned to
+tiling (128), but is 16" — and 1 for the wide table), and padding D to
+128 lanes would cost 8x (D=16) to 128x (D=1) the table's memory.
+
+Numerical contract (tests/test_sparse_apply.py): the fused tier matches
 the masked full-table apply bitwise for SGD/Adagrad where the duplicate
 reduction order is fixed (stable-sorted segments sum duplicates in
 arrival order — the same order the full path's scatter-add applies
@@ -46,34 +43,24 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-TIERS = ("off", "jax", "pallas")
-
-#: rows per pallas grid step: each program walks this many deduped ids
-#: sequentially (per-row DMA chains). Small keeps VMEM scratch tiny; the
-#: win over 'off' is O(batch) vs O(table) traffic, not DMA batching.
-_BLOCK_ROWS = 8
+TIERS = ("off", "jax")
 
 
-def resolve_tier(requested: Optional[str], platform: Optional[str] = None
-                 ) -> str:
+def resolve_tier(requested: Optional[str]) -> str:
     """Normalize a ``PS_FUSED_APPLY`` value to a concrete tier.
 
-    ``auto`` (or None) detects by backend platform: ``pallas`` on TPU,
-    ``jax`` anywhere else (the kernel's interpret mode is a correctness
-    tier, not a fast one — CPU's fast tier IS the jax path). Unknown
-    values fail loudly: a typo'd knob must not silently select 'off'.
+    ``auto`` (or None) is ``jax`` on every platform: it is the only fused
+    tier, and the one chip_smoke.py compiles and checks against 'off' on
+    the TPU. Unknown values fail loudly: a typo'd knob must not silently
+    select 'off'.
     """
     if requested is None or requested == "auto":
-        if platform is None:
-            platform = jax.devices()[0].platform
-        return "pallas" if platform == "tpu" else "jax"
+        return "jax"
     if requested not in TIERS:
         raise ValueError(
             f"unknown fused-apply tier {requested!r}; use "
-            f"'off', 'jax', 'pallas' or 'auto'")
+            f"'off', 'jax' or 'auto'")
     return requested
 
 
@@ -134,36 +121,19 @@ def segment_sum_np(ids, grads):
 
 
 def fused_sparse_apply(table: jax.Array, state: Any, ids: jax.Array,
-                       grads: jax.Array, opt, tier: str,
-                       interpret: Optional[bool] = None
-                       ) -> Tuple[jax.Array, Any]:
-    """THE entry point every sparse apply routes through (``kv/sparse``'s
-    shard_apply, and through it the remote sparse server and the mesh
-    backend). ``ids`` [N] are SHARD-LOCAL row indices with -1 filler
-    (out-of-range/padding already masked by the caller), ``grads``
+                       grads: jax.Array, opt) -> Tuple[jax.Array, Any]:
+    """THE entry point every fused sparse apply routes through
+    (``kv/sparse``'s shard_apply, and through it the remote sparse server
+    and the mesh backend). ``ids`` [N] are SHARD-LOCAL row indices with -1
+    filler (out-of-range/padding already masked by the caller), ``grads``
     [N, D] with filler rows zeroed. Returns the updated (table, state);
-    only touched rows' bytes move."""
-    if tier == "off":
-        raise ValueError("tier 'off' is the caller's own full-table path "
-                         "— fused_sparse_apply never runs it")
-    if tier not in TIERS:
-        raise ValueError(f"unknown fused-apply tier {tier!r}")
+    only touched rows' bytes move: batch-sized gather → apply_rows →
+    scatter. Filler slots gather row 0 (harmless: cnt 0 and gsum 0 make
+    apply_rows the identity for them) and scatter out of range
+    (``mode='drop'``)."""
     if ids.shape[0] == 0:  # empty push: nothing gathered, nothing written
         return table, state
     uids, gsum, cnt = batch_segment_sum(ids, grads)
-    if tier == "pallas":
-        return _apply_pallas(opt, table, state, uids, gsum, cnt,
-                             interpret=interpret)
-    return _apply_jax(opt, table, state, uids, gsum, cnt)
-
-
-# -- jax tier ----------------------------------------------------------------
-
-
-def _apply_jax(opt, table, state, uids, gsum, cnt):
-    """Batch-sized gather → apply_rows → scatter in plain JAX. Filler
-    slots gather row 0 (harmless: cnt 0 and gsum 0 make apply_rows the
-    identity for them) and scatter out of range (``mode='drop'``)."""
     num_rows = table.shape[0]
     slot = jnp.where(uids >= 0, uids, 0)
     rows = jnp.take(table, slot, axis=0)
@@ -178,135 +148,6 @@ def _apply_jax(opt, table, state, uids, gsum, cnt):
                                              mode="drop"),
         state, new_state_rows)
     return new_table, new_state
-
-
-# -- pallas tier -------------------------------------------------------------
-
-
-def _leaf_2d(leaf):
-    """Per-row state leaves as 2D [R, S] views for row-sliced DMA."""
-    return leaf if leaf.ndim == 2 else leaf[:, None]
-
-
-def _make_kernel(treedef, leaf_2d_flags, apply_rows):
-    """Build the fused kernel for one (optimizer, state structure). Ref
-    layout per PrefetchScalarGridSpec: scalar-prefetch (uids, cnt), then
-    inputs (gsum block, table, *state), outputs (table, *state — aliased
-    to the inputs), scratch (row, *state rows, one DMA semaphore).
-    ``leaf_2d_flags[k]`` records whether state leaf k was natively 2D
-    (per-dim state like adam's moments) or a per-row scalar reshaped to
-    [R, 1] for row-sliced DMA."""
-    nleaves = len(leaf_2d_flags)
-
-    def kernel(uids_ref, cnt_ref, gsum_ref, *refs):
-        # inputs and outputs alias the same buffers: all reads and
-        # writes go through the out refs, so the data flow is explicit
-        tbl_out = refs[1 + nleaves]
-        st_outs = refs[2 + nleaves:2 + 2 * nleaves]
-        row_scr = refs[2 + 2 * nleaves]
-        st_scrs = refs[3 + 2 * nleaves:3 + 3 * nleaves]
-        i = pl.program_id(0)
-        for j in range(_BLOCK_ROWS):  # npad is a _BLOCK_ROWS multiple:
-            idx = i * _BLOCK_ROWS + j  # every idx is in range
-            rid = uids_ref[idx]
-
-            @pl.when(rid >= 0)  # filler: no DMA, no write — a real
-            def _row(j=j, rid=rid):  # row's update can never be clobbered
-                def run(sem_ref):
-                    # gather: row + its state slices, HBM -> VMEM
-                    cp = pltpu.make_async_copy(
-                        tbl_out.at[pl.ds(rid, 1)], row_scr, sem_ref)
-                    cp.start()
-                    cp.wait()
-                    for st_out, st_scr in zip(st_outs, st_scrs):
-                        cp = pltpu.make_async_copy(
-                            st_out.at[pl.ds(rid, 1)], st_scr, sem_ref)
-                        cp.start()
-                        cp.wait()
-                    # apply: the SAME dense-rows rule as every tier,
-                    # on a [1, D] slab entirely in VMEM
-                    leaves = [s[:] if was_2d else s[:, 0]
-                              for s, was_2d in zip(st_scrs, leaf_2d_flags)]
-                    st = jax.tree_util.tree_unflatten(treedef, leaves)
-                    g = gsum_ref[pl.ds(j, 1)]
-                    c = cnt_ref[idx][None]
-                    new_row, new_st = apply_rows(row_scr[:], st, g, c)
-                    row_scr[:] = new_row.astype(row_scr.dtype)
-                    new_leaves = jax.tree_util.tree_leaves(new_st)
-                    for s, nl, was_2d in zip(st_scrs, new_leaves,
-                                             leaf_2d_flags):
-                        s[:] = (nl if was_2d else nl[:, None]).astype(
-                            s.dtype)
-                    # scatter back: VMEM -> the same HBM rows
-                    cp = pltpu.make_async_copy(
-                        row_scr, tbl_out.at[pl.ds(rid, 1)], sem_ref)
-                    cp.start()
-                    cp.wait()
-                    for st_out, st_scr in zip(st_outs, st_scrs):
-                        cp = pltpu.make_async_copy(
-                            st_scr, st_out.at[pl.ds(rid, 1)], sem_ref)
-                        cp.start()
-                        cp.wait()
-
-                pl.run_scoped(run, sem_ref=pltpu.SemaphoreType.DMA)
-
-    return kernel
-
-
-def _apply_pallas(opt, table, state, uids, gsum, cnt, interpret=None):
-    """One-HBM-pass fused apply: the deduped id list drives per-row DMA
-    gather/apply/scatter against the table and state resident in HBM
-    (``pl.ANY``). Inputs are aliased to the outputs, so untouched rows
-    are never read OR written."""
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    n = uids.shape[0]
-    dim = table.shape[1]
-    pad = (-n) % _BLOCK_ROWS
-    if pad:
-        uids = jnp.concatenate([uids, jnp.full((pad,), -1, uids.dtype)])
-        cnt = jnp.concatenate([cnt, jnp.zeros((pad,), cnt.dtype)])
-        gsum = jnp.concatenate(
-            [gsum, jnp.zeros((pad, dim), gsum.dtype)])
-    npad = n + pad
-    leaves, treedef = jax.tree_util.tree_flatten(state)
-    leaves2d = [_leaf_2d(lf) for lf in leaves]
-    kernel = _make_kernel(treedef, [lf.ndim == 2 for lf in leaves],
-                          opt.apply_rows)
-    nleaves = len(leaves)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # uids, cnt -> SMEM, indexable pre-DMA
-        grid=(npad // _BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((_BLOCK_ROWS, dim),
-                         lambda i, uids, cnt: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # table stays in HBM
-        ] + [pl.BlockSpec(memory_space=pltpu.ANY)] * nleaves,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * (1 + nleaves),
-        scratch_shapes=(
-            [pltpu.VMEM((1, dim), table.dtype)]
-            + [pltpu.VMEM((1, lf.shape[1]), lf.dtype) for lf in leaves2d]
-        ),
-    )
-    out_shape = ([jax.ShapeDtypeStruct(table.shape, table.dtype)]
-                 + [jax.ShapeDtypeStruct(lf.shape, lf.dtype)
-                    for lf in leaves2d])
-    # operand k of (uids, cnt, gsum, table, *state) aliases output k-3:
-    # the kernel updates the table and state IN PLACE, one row at a time
-    aliases = {3 + k: k for k in range(1 + nleaves)}
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(uids, cnt, gsum, table, *leaves2d)
-    new_table = outs[0]
-    new_leaves = [
-        out if lf.ndim == 2 else out[:, 0]
-        for out, lf in zip(outs[1:], leaves)
-    ]
-    return new_table, jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 
 # -- HBM traffic model -------------------------------------------------------

@@ -20,14 +20,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def axis_size(axis: str) -> int:
-    """``jax.lax.axis_size`` where available; older jax spells it
-    ``psum(1, axis)`` (constant-folded to a static int inside shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
 def make_mesh(mesh_shape: Optional[Dict[str, int]] = None,
               devices: Optional[Sequence] = None) -> Mesh:
     """Build a Mesh from a ``{axis_name: size}`` dict.

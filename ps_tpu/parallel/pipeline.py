@@ -25,13 +25,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map
-
-from ps_tpu.parallel.mesh import axis_size
-
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PIPE_AXIS = "pipe"
@@ -62,7 +56,7 @@ def _gpipe_block(stage_params, x, *, stage_fn, axis: str, microbatches: int):
     stage sees them; only stage 0 reads them — keeps the spec simple).
     Returns [M, mb, ...] final-stage outputs, replicated over the axis.
     """
-    size = axis_size(axis)
+    size = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = [(j, (j + 1) % size) for j in range(size)]
     mb_shape = x.shape[1:]

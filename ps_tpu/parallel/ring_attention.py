@@ -28,13 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map
-
-from ps_tpu.parallel.mesh import axis_size
-
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SEQ_AXIS = "seq"
@@ -57,7 +51,7 @@ def _block_scores(q, k, scale, causal, q_start, k_start):
 def _ring_attention_block(q, k, v, *, axis: str, causal: bool, scale: float):
     """Per-shard ring attention (call inside shard_map; q/k/v local blocks
     [B, T_local, H, D])."""
-    size = axis_size(axis)
+    size = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     t_local = q.shape[1]
     b, h = q.shape[0], q.shape[2]
@@ -113,7 +107,7 @@ def _ulysses_attention_block(q, k, v, *, axis: str, causal: bool,
                              scale: float):
     """Per-shard Ulysses attention: a2a swaps seq-sharded -> head-sharded,
     full attention on the local head slice, a2a back."""
-    size = axis_size(axis)
+    size = jax.lax.axis_size(axis)
 
     def seq_to_heads(x):  # [B, T/s, H, D] -> [B, T, H/s, D]
         return jax.lax.all_to_all(x, axis, split_axis=2, concat_axis=1,

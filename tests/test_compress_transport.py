@@ -314,8 +314,15 @@ def test_mnist_parity_cast16_and_int8_tolerance_bounded():
 def test_mnist_topk_converges_within_epsilon_of_dense():
     """topk with error feedback: trajectories may wiggle, but the model
     converges — the final loss lands within epsilon of dense on the same
-    seed, and the run's residual norm is reported."""
-    steps = 14
+    seed, and the run's residual norm is reported.
+
+    Compared on the plateau (40 steps, loss ~0.2), not mid-descent: at step
+    14 the loss still falls ~0.1 a step, so one step of phase shift between
+    the two runs is worth the whole band — measured on jax 0.9.0 / flax
+    0.12.3 over seeds 0-3 the step-14 gap spans -0.25..+0.13 (seed 0, this
+    test's: -0.25, topk AHEAD of dense) while the step-40 gap stays within
+    0.06 for every seed."""
+    steps = 40
     dense, _ = _mnist_losses(None, steps=steps)
     got, ratio = _mnist_losses(
         {"codec": "topk", "topk": 0.25, "min_bytes": 1024}, steps=steps)

@@ -195,22 +195,6 @@ def test_asan_van_clean():
 # -- the jax coordination seam the clean-abort path rides ---------------------
 
 
-def _seam_lacks_recoverable():
-    from ps_tpu.backends.tpu import _client_factory_kwargs, _coordination_seam
-
-    _, factory = _coordination_seam()  # AttributeError = seam moved AGAIN
-    supported = _client_factory_kwargs(factory)
-    # None = capability unknown (unparseable docstring): RUN the test so a
-    # genuinely-unsupported kwarg fails loudly instead of skipping
-    return supported is not None and "recoverable" not in supported
-
-
-@pytest.mark.skipif(
-    _seam_lacks_recoverable(),
-    reason="jax-0.4.x drift: get_distributed_runtime_client predates the "
-           "'recoverable' kwarg (recoverable coordination tasks arrived "
-           "with jax 0.5) — only shutdown_on_destruction is applicable",
-)
 def test_coordination_seam_accepts_recoverable_kwargs():
     """Pin the private jax API `_coordination_client_options` patches
     (ps_tpu/backends/tpu.py): the resolved coordination seam must accept
@@ -220,7 +204,7 @@ def test_coordination_seam_accepts_recoverable_kwargs():
     (VERDICT r3 item 9 / r4 item 4)."""
     from ps_tpu.backends.tpu import _coordination_seam
 
-    _, factory = _coordination_seam()  # AttributeError = moved
+    _, factory = _coordination_seam()  # AttributeError = seam moved
     # constructing (without connect()) exercises kwarg acceptance; a
     # TypeError here is exactly the degradation the runtime warning masks
     client = factory("127.0.0.1:1", 0, init_timeout=1,
@@ -229,13 +213,10 @@ def test_coordination_seam_accepts_recoverable_kwargs():
 
 
 def test_coordination_client_options_inject_without_degrading():
-    """The context manager swaps the factory in (at the version-resolved
-    seam) and restores it, and the patched factory builds a client WITHOUT
-    tripping its TypeError fallback (which would warn and strip the
-    recoverable semantics). On jax 0.4.x the known partial-semantics
-    notice ('predates recoverable tasks') is expected; the TypeError
-    fallback warning never is — a supposedly-supported kwarg being refused
-    means the docstring probe drifted."""
+    """The context manager swaps the factory in (at the seam) and restores
+    it, and the patched factory builds a client WITHOUT tripping its
+    TypeError fallback (which would warn and strip the recoverable
+    semantics)."""
     import warnings
 
     from ps_tpu.backends.tpu import (

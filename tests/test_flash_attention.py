@@ -124,6 +124,33 @@ def test_block_divisibility_validated():
         flash_attention(q, k, v)
 
 
+def test_under_a_mesh_the_kernel_runs_sharded_and_agrees():
+    """GSPMD cannot partition a Mosaic kernel, so under ps.init's mesh the
+    call goes through shard_map: batch over 'data' and heads over 'model'
+    where they divide, replicated where they do not — same values and
+    gradients as the plain call either way."""
+    import ps_tpu as ps
+
+    q, k, v = _qkv(8, s=128)  # B=2, H=4
+    mask = np.ones((B, 128), np.int32)
+    mask[1, 70:] = 0
+    mask = jnp.asarray(mask)
+
+    def value_and_grads():
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, mask=mask) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    want = value_and_grads()
+    for mesh_shape in ({"data": 2, "model": 4}, {"data": 8}):
+        ps.init(backend="tpu", mesh_shape=mesh_shape)  # 8 cannot divide B
+        got = jax.jit(value_and_grads)()
+        ps.shutdown()
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
+                                                    atol=1e-5), got, want)
+
+
 def test_bert_flash_matches_full():
     """Model-level contract: BertMLM(attn='flash') ≡ attn='full' logits,
     including a real padding mask."""

@@ -14,25 +14,11 @@ import socket
 import subprocess
 import sys
 
-import jax
 import numpy as np
 import pytest
 
 _WORKER = os.path.join(os.path.dirname(__file__), "mp_worker.py")
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# jax-0.4.x drift: cross-process computations are unimplemented on the CPU
-# backend (device_put's multihost assert_equal raises XlaRuntimeError
-# "Multiprocess computations aren't implemented on the CPU backend"), and
-# these tests have no TPU to span processes with. CPU cross-process
-# collectives arrived after the 0.4 line.
-_JAX_VERSION = tuple(int(x) for x in jax.__version__.split(".")[:2])
-pytestmark = pytest.mark.skipif(
-    _JAX_VERSION < (0, 5),
-    reason="jax-0.4.x drift: multiprocess computations unimplemented on "
-           "the CPU backend (XlaRuntimeError from multihost assert_equal "
-           "in device_put)",
-)
 
 
 def _free_port() -> int:

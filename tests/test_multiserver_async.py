@@ -37,10 +37,18 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NSHARDS, NWORKERS, CYCLES = 2, 3, 6
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("localhost", 0))
-        return s.getsockname()[1]
+def _free_ports(n: int) -> list:
+    """n DISTINCT free ports: every socket stays bound until all are picked
+    (closing each before binding the next lets the kernel hand the same
+    port out twice — seen once in PR 21's tier-1 runs)."""
+    socks = [socket.socket() for _ in range(n)]
+    try:
+        for s in socks:
+            s.bind(("localhost", 0))
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
 
 
 def _spawn(role, ports, out_dir, a, b, extra=()):
@@ -58,7 +66,7 @@ def _spawn(role, ports, out_dir, a, b, extra=()):
 @pytest.fixture(scope="module")
 def mp_run(tmp_path_factory):
     out = tmp_path_factory.mktemp("multiserver_async")
-    ports = [_free_port() for _ in range(NSHARDS)]
+    ports = _free_ports(NSHARDS)
     servers = [_spawn("server", ports[s], out, NWORKERS, CYCLES,
                       extra=(s, NSHARDS))
                for s in range(NSHARDS)]
@@ -156,7 +164,7 @@ def test_kill_one_server_raises_typed_error(tmp_path):
     not a bare socket error."""
     from tests.mp_async_worker import _model_params, make_grads
 
-    ports = [_free_port() for _ in range(NSHARDS)]
+    ports = _free_ports(NSHARDS)
     # cycles huge: servers wait for pushes that never all arrive; the test
     # kills them instead
     servers = [_spawn("server", ports[s], tmp_path, NWORKERS, 10_000,

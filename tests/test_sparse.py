@@ -192,15 +192,6 @@ def test_widedeep_composite_training_decreases_loss():
     assert dense.collective_bytes > 0
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax-0.4.x drift: the 8-way sharded composite step diverges "
-           "from the 1-way run beyond fp32 reduction noise on the CPU "
-           "backend (loss 0.919 vs 0.886 after 3 steps) — a numeric "
-           "regression of the 0.4.37 CPU lowering, not of this code; "
-           "test_widedeep_composite_training_decreases_loss still covers "
-           "the composite step's training behavior",
-)
 def test_widedeep_composite_shard_parity():
     """Full composite step on an 8-way mesh == on a 1-device mesh."""
     results = {}

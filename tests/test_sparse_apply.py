@@ -1,8 +1,7 @@
 """Fused sparse gather→apply→scatter — the parity drill and edge cases.
 
 The contract (ISSUE 15 / README "Sparse apply"): the fused batch-sized
-tiers ('jax' fallback and the 'pallas' kernel, interpret mode on CPU)
-must match the legacy masked full-table apply ('off') — bitwise for
+tier ('jax') must match the legacy masked full-table apply ('off') — bitwise for
 SGD/Adagrad (the stable-sorted segment sum fixes the duplicate reduction
 order to the full path's scatter-add order), and within 1e-6 relative
 for Adam — across dup-heavy / empty / all-rows id distributions, through
@@ -25,7 +24,6 @@ from ps_tpu.config import Config
 from ps_tpu.kv.sparse import SparseEmbedding, _dedupe_rows
 from ps_tpu.ops.sparse_apply import (
     batch_segment_sum,
-    fused_sparse_apply,
     hbm_bytes_model,
     resolve_tier,
 )
@@ -68,15 +66,14 @@ def _distributions():
     return out
 
 
-@pytest.mark.parametrize("tier", ["jax", "pallas"])
 @pytest.mark.parametrize("optimizer", ["sgd", "adagrad", "adam"])
-def test_fused_tier_parity_sweep(tier, optimizer):
+def test_fused_tier_parity_sweep(optimizer):
     """The acceptance drill: fused vs full-table over the real push path,
     every id distribution in one multi-push sequence (state carries
     across pushes, so drift would compound and show)."""
     pushes = [(ids, grads) for _, ids, grads in _distributions()]
     base_t, base_s = _push_through("off", optimizer, pushes)
-    got_t, got_s = _push_through(tier, optimizer, pushes)
+    got_t, got_s = _push_through("jax", optimizer, pushes)
     if optimizer in ("sgd", "adagrad"):
         # fixed reduction order (stable-sorted segments) -> bitwise
         np.testing.assert_array_equal(got_t, base_t)
@@ -90,9 +87,8 @@ def test_fused_tier_parity_sweep(tier, optimizer):
             got_s, base_s)
 
 
-@pytest.mark.parametrize("tier", ["jax", "pallas"])
-def test_fused_parity_sharded_a2a(tier):
-    """8-way mesh + the a2a exchange compose with the fused tiers: the
+def test_fused_parity_sharded_a2a():
+    """8-way mesh + the a2a exchange compose with the fused tier: the
     owner-shard apply sees routed (possibly capacity-clipped) id lists
     and must still match the 'off' tier bitwise."""
     rng = np.random.default_rng(3)
@@ -101,21 +97,9 @@ def test_fused_parity_sharded_a2a(tier):
     kw = dict(exchange="a2a", capacity_factor=8.0)
     base_t, _ = _push_through("off", "adagrad", [(ids, grads)],
                               mesh_shape={"data": 8}, **kw)
-    got_t, _ = _push_through(tier, "adagrad", [(ids, grads)],
+    got_t, _ = _push_through("jax", "adagrad", [(ids, grads)],
                              mesh_shape={"data": 8}, **kw)
     np.testing.assert_array_equal(got_t, base_t)
-
-
-def test_fused_entry_point_rejects_off_and_unknown():
-    opt = make_rowwise("sgd")
-    t = jnp.zeros((4, D))
-    s = opt.init(t)
-    ids = jnp.zeros((2,), jnp.int32)
-    g = jnp.zeros((2, D))
-    with pytest.raises(ValueError, match="'off'"):
-        fused_sparse_apply(t, s, ids, g, opt, "off")
-    with pytest.raises(ValueError, match="unknown fused-apply tier"):
-        fused_sparse_apply(t, s, ids, g, opt, "vulkan")
 
 
 def test_batch_segment_sum_orders_and_counts():
@@ -160,7 +144,7 @@ def test_dedupe_rows_all_duplicates():
 
 
 def test_empty_push_is_a_noop_every_tier():
-    for tier in ("off", "jax", "pallas"):
+    for tier in ("off", "jax"):
         t, _ = _push_through(tier, "adagrad",
                              [(np.zeros((0,), np.int32),
                                np.zeros((0, D), np.float32))])
@@ -189,7 +173,7 @@ def test_a2a_out_of_range_ids_drop(tier):
     ps.shutdown()
 
 
-@pytest.mark.parametrize("tier", ["off", "jax", "pallas"])
+@pytest.mark.parametrize("tier", ["off", "jax"])
 def test_single_row_table(tier):
     """num_rows=1 pads to the mesh size; every push lands on row 0 of
     shard 0 and the pad rows stay untouched."""
@@ -209,8 +193,8 @@ def test_single_row_table(tier):
 
 
 def test_fused_apply_knob_roundtrip(monkeypatch):
-    monkeypatch.setenv("PS_FUSED_APPLY", "pallas")
-    assert Config.from_env().fused_apply == "pallas"
+    monkeypatch.setenv("PS_FUSED_APPLY", "jax")
+    assert Config.from_env().fused_apply == "jax"
     monkeypatch.setenv("PS_FUSED_APPLY", "")
     assert Config.from_env().fused_apply == "auto"
     monkeypatch.setenv("PS_FUSED_APPLY", "cuda")
@@ -220,18 +204,17 @@ def test_fused_apply_knob_roundtrip(monkeypatch):
         Config(fused_apply="no-such-tier")
 
 
-def test_resolve_tier_auto_by_platform():
-    assert resolve_tier(None, platform="tpu") == "pallas"
-    assert resolve_tier("auto", platform="cpu") == "jax"
-    assert resolve_tier("off", platform="tpu") == "off"
-    assert resolve_tier("jax", platform="tpu") == "jax"
+def test_resolve_tier_auto_is_jax():
+    assert resolve_tier(None) == "jax"
+    assert resolve_tier("auto") == "jax"
+    assert resolve_tier("off") == "off"
     with pytest.raises(ValueError, match="unknown fused-apply tier"):
-        resolve_tier("fast", platform="cpu")
+        resolve_tier("pallas")  # the removed kernel tier is a typo now
 
 
 def test_backend_resolution_reaches_embedding(monkeypatch):
-    """PS_FUSED_APPLY flows Config -> TpuBackend.fused_apply_tier ->
-    SparseEmbedding.fused_tier (on CPU, auto resolves to jax)."""
+    """PS_FUSED_APPLY flows Config -> SparseEmbedding.fused_tier (auto
+    resolves to jax)."""
     monkeypatch.setenv("PS_FUSED_APPLY", "off")
     ps.init(backend="tpu")
     emb = SparseEmbedding(V, D, optimizer="sgd")
@@ -240,7 +223,7 @@ def test_backend_resolution_reaches_embedding(monkeypatch):
     monkeypatch.delenv("PS_FUSED_APPLY")
     ps.init(backend="tpu")
     emb = SparseEmbedding(V, D, optimizer="sgd")
-    assert emb.fused_tier == "jax"  # auto on the CPU backend
+    assert emb.fused_tier == "jax"  # auto
     ps.shutdown()
 
 

@@ -446,12 +446,12 @@ EOF
 
 # 8. sparse fused apply (<45 s): the fused gather->apply->scatter vs the
 # masked full-table baseline (README "Sparse apply"), identical push
-# streams on the CPU fallback tier — asserts numerical parity held
-# (bitwise expected for adagrad's fixed reduction order), the >=2x
-# rows-applied/s acceptance bar at a table >=100x the batch id-set, and
-# that the HBM model + tier landed in the BENCH json. The pallas-tier
-# parity drill runs in tier-1 (tests/test_sparse_apply.py, interpret
-# mode); this leg is the measured-throughput half.
+# streams on the CPU — asserts numerical parity held (bitwise expected
+# for adagrad's fixed reduction order), the >=2x rows-applied/s
+# acceptance bar at a table >=100x the batch id-set, and that the HBM
+# model + tier landed in the BENCH json. The parity drill itself runs
+# in tier-1 (tests/test_sparse_apply.py); this leg is the
+# measured-throughput half.
 out=$(timeout -k 10 120 env JAX_PLATFORMS=cpu python bench.py --model sparse_apply --quick 2>/dev/null | tail -1)
 python - "$out" <<'EOF'
 import json
