@@ -536,8 +536,10 @@ class BucketedTransportMixin:
         its coalesced pull) — parents to the open span instead of
         rooting a new trace: the worker→aggregator→shard chain stays ONE
         trace, and the aggregator's client ops never mint phantom
-        \"steps\". Training threads have no open span, so ordinary
-        worker ops root exactly as before."""
+        \"steps\". Training threads have no open sampled span (the
+        program's own ``step.run`` / ``input.*`` spans nest on a stack
+        apart, which ``current()`` does not read), so ordinary worker ops
+        root exactly as before."""
         parent = obs.tracer().current()
         sp = obs.tracer().span(name, cat="worker", parent=parent)
         if sp:

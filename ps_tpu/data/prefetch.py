@@ -11,7 +11,11 @@ buffer being consumed, one arriving.
 from __future__ import annotations
 
 import collections
+import itertools
 from typing import Callable, Iterable, Iterator, Optional
+
+from ps_tpu import obs
+from ps_tpu.obs import phases
 
 
 def device_prefetch(batches: Iterable, place: Optional[Callable] = None,
@@ -31,9 +35,13 @@ def device_prefetch(batches: Iterable, place: Optional[Callable] = None,
         place = jax.device_put
     if depth < 1:
         raise ValueError("prefetch depth must be >= 1")
+    span = obs.tracer().program_span
     buf = collections.deque()
-    for item in batches:
-        buf.append(place(item))
+    for seq, item in enumerate(batches):
+        nbytes = sum(getattr(x, "nbytes", 0)
+                     for x in jax.tree_util.tree_leaves(item))
+        with span(phases.INPUT_PLACE, seq=seq, nbytes=nbytes):
+            buf.append(place(item))
         if len(buf) >= depth:
             yield buf.popleft()
     while buf:
@@ -53,17 +61,25 @@ def threaded_source(batches: Iterable, capacity: int = 2) -> Iterator:
     q: "queue.Queue" = queue.Queue(maxsize=capacity)
     _END = object()
 
+    span = obs.tracer().program_span
+
     def produce():
+        it = iter(batches)
         try:
-            for item in batches:
+            for seq in itertools.count():
+                with span(phases.INPUT_PRODUCE, seq=seq):
+                    item = next(it, _END)
+                if item is _END:
+                    break
                 q.put(item)
         finally:
             q.put(_END)
 
     t = threading.Thread(target=produce, daemon=True)
     t.start()
-    while True:
-        item = q.get()
+    for seq in itertools.count():
+        with span(phases.INPUT_SOURCE_WAIT, seq=seq):
+            item = q.get()
         if item is _END:
             break
         yield item

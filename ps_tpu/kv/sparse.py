@@ -48,6 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ps_tpu.api import current_context
+from ps_tpu.obs import phases
 from ps_tpu.ops.sparse_apply import fused_sparse_apply, resolve_tier
 from ps_tpu.optim.rowwise import make_rowwise
 from ps_tpu.parallel.mesh import DATA_AXIS
@@ -207,7 +208,8 @@ class SparseEmbedding:
 
         Out-of-range ids are clipped by jnp.take's default mode; valid ids
         are the caller's contract (synthetic data guarantees it)."""
-        return jnp.take(table, ids, axis=0)
+        with jax.named_scope(phases.LOOKUP):
+            return jnp.take(table, ids, axis=0)
 
     def apply(self, table: jax.Array, state: Any, ids: jax.Array,
               row_grads: jax.Array) -> Tuple[jax.Array, Any, jax.Array]:
@@ -235,15 +237,18 @@ class SparseEmbedding:
         opt, tier = self._opt, self.fused_tier
 
         def shard_apply(table_shard, state_shard, ids_loc, grads_loc):
-            if self.exchange == "gather" or k == 1:
-                all_ids = jax.lax.all_gather(ids_loc, axis, tiled=True)
-                all_grads = jax.lax.all_gather(grads_loc, axis, tiled=True)
-                dropped = jnp.int32(0)  # gather is lossless
-            else:
-                all_ids, all_grads, dropped = _a2a_route(
-                    ids_loc, grads_loc, k, axis, rps, self.capacity_factor
-                )
-            dropped = jax.lax.psum(dropped, axis)  # global count, replicated
+            with jax.named_scope(phases.ROW_EXCHANGE):
+                if self.exchange == "gather" or k == 1:
+                    all_ids = jax.lax.all_gather(ids_loc, axis, tiled=True)
+                    all_grads = jax.lax.all_gather(grads_loc, axis,
+                                                   tiled=True)
+                    dropped = jnp.int32(0)  # gather is lossless
+                else:
+                    all_ids, all_grads, dropped = _a2a_route(
+                        ids_loc, grads_loc, k, axis, rps,
+                        self.capacity_factor
+                    )
+                dropped = jax.lax.psum(dropped, axis)  # global, replicated
             lo = jax.lax.axis_index(axis) * rps
             local = all_ids - lo
             ok = (local >= 0) & (local < rps)
@@ -271,7 +276,8 @@ class SparseEmbedding:
             in_specs=(P(axis, None), state_specs, P(axis), P(axis, None)),
             out_specs=(P(axis, None), state_specs, P()),
         )
-        return fn(table, state, ids, row_grads)
+        with jax.named_scope(phases.ROW_APPLY):
+            return fn(table, state, ids, row_grads)
 
     # -- eager PS API (the reference's worker-side protocol surface) ---------
 

@@ -21,8 +21,10 @@ import jax
 import numpy as np
 import optax
 
+from ps_tpu import obs
 from ps_tpu.api import current_context
 from ps_tpu.kv import keys as keymod
+from ps_tpu.obs import phases
 from ps_tpu.optim import make_optimizer
 
 
@@ -293,33 +295,43 @@ class KVStore:
                 keymod.unflatten(treedef, params_kv, key_order), batch, *extra
             )
 
+        # Not named ``fused`` as before the scopes: jax leaves metadata out
+        # of the compile cache's key, so under the old name an executable
+        # cached without the phase marks would be served for this one.
         @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def fused(params_kv, state, batch, *extra):
-            if has_aux:
-                (loss, aux), grads = jax.value_and_grad(kv_loss, has_aux=True)(
-                    params_kv, batch, *extra
-                )
-            else:
-                loss, grads = jax.value_and_grad(kv_loss)(params_kv, batch, *extra)
-                aux = None
-            if grad_scale != 1.0:  # aggregate='sum' semantics
-                grads = jax.tree_util.tree_map(lambda g: g * grad_scale, grads)
-            updates, state = opt.update(grads, state, params_kv)
-            params_kv = optax.apply_updates(params_kv, updates)
+        def fused_step(params_kv, state, batch, *extra):
+            with jax.named_scope(phases.GRAD):
+                if has_aux:
+                    (loss, aux), grads = jax.value_and_grad(
+                        kv_loss, has_aux=True)(params_kv, batch, *extra)
+                else:
+                    loss, grads = jax.value_and_grad(kv_loss)(
+                        params_kv, batch, *extra)
+                    aux = None
+            with jax.named_scope(phases.APPLY):
+                if grad_scale != 1.0:  # aggregate='sum' semantics
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g * grad_scale, grads)
+                updates, state = opt.update(grads, state, params_kv)
+                params_kv = optax.apply_updates(params_kv, updates)
             return params_kv, state, loss, aux
 
         check_health = self._check_health
+        span = obs.tracer().program_span
 
         def run(batch, *extra):
-            check_health()  # dead peer -> typed error, not a hung psum
-            params_kv, state = engine.get_tree_and_state()
-            params_kv, state, loss, aux = fused(params_kv, state, batch, *extra)
-            engine.set_tree_and_state(params_kv, state)
-            nbytes = sum(_nbytes(v) for v in params_kv.values())
-            self.bytes_pushed += nbytes
-            self.bytes_pulled += nbytes
-            self.step += 1
-            params = keymod.unflatten(treedef, params_kv, key_order)
+            with span(phases.STEP_RUN, step=self.step):
+                check_health()  # dead peer -> typed error, not a hung psum
+                params_kv, state = engine.get_tree_and_state()
+                with span(phases.STEP_LAUNCH, step=self.step):
+                    params_kv, state, loss, aux = fused_step(
+                        params_kv, state, batch, *extra)
+                engine.set_tree_and_state(params_kv, state)
+                nbytes = sum(_nbytes(v) for v in params_kv.values())
+                self.bytes_pushed += nbytes
+                self.bytes_pulled += nbytes
+                self.step += 1
+                params = keymod.unflatten(treedef, params_kv, key_order)
             if has_aux:
                 return loss, params, aux
             return loss, params
@@ -331,7 +343,8 @@ class KVStore:
             the exact model+optimizer arithmetic while 'bytes accessed' is an
             unfused upper bound. Benchmarks turn this into MFU."""
             params_kv, state = engine.get_tree_and_state()
-            return fused.lower(params_kv, state, batch, *extra).cost_analysis()
+            return fused_step.lower(
+                params_kv, state, batch, *extra).cost_analysis()
 
         def compiled_text(batch, *extra) -> str:
             """Post-GSPMD optimized HLO of the fused step, as text — the
@@ -340,7 +353,7 @@ class KVStore:
             placement regression in ``param_sharding`` is a loud failure,
             not a silent 8x traffic increase."""
             params_kv, state = engine.get_tree_and_state()
-            return fused.lower(params_kv, state, batch, *extra)\
+            return fused_step.lower(params_kv, state, batch, *extra)\
                 .compile().as_text()
 
         run.cost_analysis = cost_analysis

@@ -9,7 +9,10 @@ Three layers, each usable alone, wired through every transport hot path:
   Perfetto JSON, alignable across processes via
   :class:`~ps_tpu.obs.clock.ClockSync`. Off by default
   (``trace_sample`` / ``PS_TRACE_SAMPLE`` = 0): the unsampled path is a
-  no-op singleton and one dict lookup per hop.
+  no-op singleton and one dict lookup per hop. The fused steps and the
+  input prefetch record their few spans a step always
+  (``Tracer.program_span``; names in :mod:`ps_tpu.obs.phases`, which also
+  names the ``jax.named_scope`` phases inside the device program).
 - **Metrics** (:mod:`ps_tpu.obs.metrics`): counters, gauges, and
   log2-bucket latency histograms (p50/p99/p999) that ``TransportStats``
   feeds; exported in the extended STATS frame, rendered live by

@@ -1,0 +1,46 @@
+"""Names of the phases of a fused step and of the host spans around it.
+
+Constants only. The device phases are opened with ``jax.named_scope`` where
+the work is written (``kv/store.py``, ``train.py``, ``kv/sparse.py``,
+``ops/sparse_apply.py``) and land in the ``op_name`` of every HLO
+instruction traced under them; the host spans are recorded with
+``ps_tpu.obs.tracer().program_span`` (``kv/store.py``, ``train.py``,
+``data/prefetch.py``). ``benchmark/layer_metrics/scope.py`` and ``host.py``
+keep their own copy of the names they look up (the benchmark also runs on
+trees that lack this file); ``tests/test_phases.py`` holds them equal. Every
+span here has a reader there: a span that no metric reads is not recorded.
+
+One scope around ``jax.value_and_grad`` gives two phases: JAX writes the
+forward ops as ``ps.grad/jvp(...)`` and the backward ops as
+``ps.grad/transpose(jvp(...))`` in the same ``op_name``, or, for the ops of a
+``jax.custom_vjp`` backward rule (the flash attention's), as
+``ps.grad/transpose(ps.grad)/jvp(...)``.
+"""
+
+# -- device phases (jax.named_scope) -----------------------------------------
+GRAD = "ps.grad"                # loss forward and backward
+APPLY = "ps.apply"              # dense server-side apply: scale, opt.update, apply_updates
+LOOKUP = "ps.lookup"            # sparse pull: table[ids]
+ROW_APPLY = "ps.row_apply"      # sparse push, with the children below
+ROW_EXCHANGE = "ps.row_apply/exchange"  # all_gather / all_to_all of ids and row grads
+ROW_DEDUPE = "ps.row_apply/dedupe"      # sort and segment-sum at batch size
+ROW_GATHER = "ps.row_apply/gather"      # take of the touched rows and their state
+ROW_UPDATE = "ps.row_apply/update"      # the row-wise optimizer rule
+ROW_SCATTER = "ps.row_apply/scatter"    # .at[dst].set of table and state
+
+#: what tells a backward op from a forward op inside ``GRAD``: a transform
+#: of the name stack (the primitive ``transpose`` has no parenthesis)
+BACKWARD_MARK = "transpose("
+
+DEVICE_PHASES = (GRAD, APPLY, LOOKUP, ROW_APPLY, ROW_EXCHANGE, ROW_DEDUPE,
+                 ROW_GATHER, ROW_UPDATE, ROW_SCATTER)
+
+# -- host spans (Tracer.program_span) -----------------------------------------
+STEP_RUN = "step.run"                      # the whole of run(batch); step=n
+STEP_LAUNCH = "step.launch"                # the jitted call only; child of step.run
+INPUT_PLACE = "input.place"                # place(item) in device_prefetch; seq, nbytes
+INPUT_SOURCE_WAIT = "input.source_wait"    # the consumer's q.get(); seq
+INPUT_PRODUCE = "input.produce"            # next(batches) in the producer thread; seq
+
+HOST_SPANS = (STEP_RUN, STEP_LAUNCH, INPUT_PLACE, INPUT_SOURCE_WAIT,
+              INPUT_PRODUCE)

@@ -21,6 +21,14 @@ Sampling is decided ONCE, at the root span (the worker op): the
 creation, and every downstream hop simply follows the header — an
 unsampled op costs one dict lookup per hop and nothing else, so the off
 path stays off the profile.
+
+The program's own hot path is the exception: the few spans a training step
+opens around the fused step and the input prefetch
+(:mod:`ps_tpu.obs.phases`, ``HOST_SPANS``) are recorded by
+:meth:`Tracer.program_span` into the same ring with no sampling decision,
+because whoever reads them (the benchmark's ``host.*`` metrics) cannot
+switch tracing on. Every span keeps its start on ``time.perf_counter``
+(``Span.t0``) beside the wall clock, so it can be laid on a device trace.
 """
 
 from __future__ import annotations
@@ -97,7 +105,9 @@ class Span:
     and a monotonic duration, and lands in its tracer's ring on exit."""
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
-                 "args", "ts_us", "dur_us", "_t0", "_tracer", "_tid")
+                 "args", "ts_us", "dur_us", "t0", "_tracer", "_tid")
+    #: the thread-local stack of open spans that this kind nests on
+    _STACK = "stack"
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: str, span_id: str, parent_id: Optional[str]):
@@ -109,7 +119,9 @@ class Span:
         self.args: dict = {}
         self.ts_us = 0.0
         self.dur_us = 0.0
-        self._t0 = 0.0
+        #: start on ``time.perf_counter`` (seconds): the clock a device
+        #: trace is tied to, where ``ts_us`` is the wall clock
+        self.t0 = 0.0
         self._tracer = tracer
         self._tid = 0
 
@@ -128,13 +140,13 @@ class Span:
 
     def __enter__(self) -> "Span":
         self.ts_us = time.time() * 1e6
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         self._tid = threading.get_ident()
         self._tracer._push_current(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.dur_us = (time.perf_counter() - self._t0) * 1e6
+        self.dur_us = (time.perf_counter() - self.t0) * 1e6
         if exc_type is not None:
             self.args.setdefault("error", repr(exc))
         self._tracer._pop_current(self)
@@ -146,6 +158,13 @@ class Span:
 
 def _new_id() -> str:
     return os.urandom(8).hex()
+
+
+class _ProgramSpan(Span):
+    """A span of :meth:`Tracer.program_span`: off the sampled spans' stack."""
+
+    __slots__ = ()
+    _STACK = "program"
 
 
 class Tracer:
@@ -161,8 +180,11 @@ class Tracer:
                  sample: float = 0.0):
         import collections
 
+        import itertools
+
         self.service = service
         self.sample = float(sample)
+        self._program_ids = itertools.count(1)
         self.clock_offset_us = 0.0
         self.pid = os.getpid()
         self._ring = collections.deque(maxlen=int(capacity))
@@ -195,19 +217,42 @@ class Tracer:
         cur = self.current()
         return self.span(name, cat, parent=cur) if cur is not None else NOOP
 
+    def program_span(self, name: str, cat: str = "program", **args) -> Span:
+        """A span that is ALWAYS recorded: no sampling decision, ids from a
+        process-local counter. For the few spans a step that the program
+        puts on its own hot path (``ps_tpu.obs.phases.HOST_SPANS``), which
+        a benchmark reads without being able to switch tracing on. Child
+        of this thread's open program span, if any; ``args`` (``step=n``,
+        ``seq=n``) are the identifier the spans of one step share.
+
+        Program spans nest on a stack of their own: :meth:`current` and
+        :meth:`child` never see them, so a worker op issued inside
+        ``step.run`` still makes its own sampling decision and an unsampled
+        one puts no context on the wire."""
+        sid = f"p{next(self._program_ids):x}"
+        stack = getattr(self._tls, "program", None)
+        if stack:
+            up = stack[-1]
+            sp = _ProgramSpan(self, name, cat, up.trace_id, sid, up.span_id)
+        else:
+            sp = _ProgramSpan(self, name, cat, sid, sid, None)
+        sp.args = args
+        return sp
+
     def current(self) -> Optional[TraceContext]:
         """The innermost open span's context on this thread, if any."""
         stack = getattr(self._tls, "stack", None)
         return stack[-1].ctx() if stack else None
 
     def _push_current(self, span: Span) -> None:
-        stack = getattr(self._tls, "stack", None)
+        stack = getattr(self._tls, span._STACK, None)
         if stack is None:
-            stack = self._tls.stack = []
+            stack = []
+            setattr(self._tls, span._STACK, stack)
         stack.append(span)
 
     def _pop_current(self, span: Span) -> None:
-        stack = getattr(self._tls, "stack", None)
+        stack = getattr(self._tls, span._STACK, None)
         if stack and stack[-1] is span:
             stack.pop()
         elif stack and span in stack:  # exited out of order: still remove

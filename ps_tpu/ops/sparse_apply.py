@@ -44,6 +44,8 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ps_tpu.obs import phases
+
 TIERS = ("off", "jax")
 
 
@@ -133,20 +135,24 @@ def fused_sparse_apply(table: jax.Array, state: Any, ids: jax.Array,
     (``mode='drop'``)."""
     if ids.shape[0] == 0:  # empty push: nothing gathered, nothing written
         return table, state
-    uids, gsum, cnt = batch_segment_sum(ids, grads)
+    with jax.named_scope(phases.ROW_DEDUPE):
+        uids, gsum, cnt = batch_segment_sum(ids, grads)
     num_rows = table.shape[0]
-    slot = jnp.where(uids >= 0, uids, 0)
-    rows = jnp.take(table, slot, axis=0)
-    state_rows = jax.tree_util.tree_map(
-        lambda leaf: jnp.take(leaf, slot, axis=0), state)
-    new_rows, new_state_rows = opt.apply_rows(rows, state_rows, gsum, cnt)
-    dst = jnp.where(uids >= 0, uids, num_rows)  # filler drops off the end
-    new_table = table.at[dst].set(new_rows.astype(table.dtype),
-                                  mode="drop")
-    new_state = jax.tree_util.tree_map(
-        lambda leaf, nrows: leaf.at[dst].set(nrows.astype(leaf.dtype),
-                                             mode="drop"),
-        state, new_state_rows)
+    with jax.named_scope(phases.ROW_GATHER):
+        slot = jnp.where(uids >= 0, uids, 0)
+        rows = jnp.take(table, slot, axis=0)
+        state_rows = jax.tree_util.tree_map(
+            lambda leaf: jnp.take(leaf, slot, axis=0), state)
+    with jax.named_scope(phases.ROW_UPDATE):
+        new_rows, new_state_rows = opt.apply_rows(rows, state_rows, gsum, cnt)
+    with jax.named_scope(phases.ROW_SCATTER):
+        dst = jnp.where(uids >= 0, uids, num_rows)  # filler drops off the end
+        new_table = table.at[dst].set(new_rows.astype(table.dtype),
+                                      mode="drop")
+        new_state = jax.tree_util.tree_map(
+            lambda leaf, nrows: leaf.at[dst].set(nrows.astype(leaf.dtype),
+                                                 mode="drop"),
+            state, new_state_rows)
     return new_table, new_state
 
 

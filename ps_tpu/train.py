@@ -19,9 +19,11 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import optax
 
+from ps_tpu import obs
 from ps_tpu.kv import keys as keymod
 from ps_tpu.kv.sparse import SparseEmbedding
 from ps_tpu.kv.store import KVStore, _nbytes
+from ps_tpu.obs import phases
 
 
 def make_composite_step(
@@ -62,21 +64,25 @@ def make_composite_step(
         out = loss_fn(params, rows, batch, *extra)
         return out
 
+    # not named ``fused`` as before the scopes: see KVStore.make_step
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-    def fused(params_kv, state, tables, estates, batch, *extra):
+    def fused_composite_step(params_kv, state, tables, estates, batch,
+                             *extra):
         ids = ids_fn(batch)
         rows = {n: emb_stores[n].lookup(tables[n], ids[n]) for n in names}
-        if has_aux:
-            (loss, aux), (gkv, grows) = jax.value_and_grad(
-                kv_loss, argnums=(0, 1), has_aux=True
-            )(params_kv, rows, batch, *extra)
-        else:
-            loss, (gkv, grows) = jax.value_and_grad(
-                kv_loss, argnums=(0, 1)
-            )(params_kv, rows, batch, *extra)
-            aux = None
-        updates, state = opt.update(gkv, state, params_kv)
-        params_kv = optax.apply_updates(params_kv, updates)
+        with jax.named_scope(phases.GRAD):
+            if has_aux:
+                (loss, aux), (gkv, grows) = jax.value_and_grad(
+                    kv_loss, argnums=(0, 1), has_aux=True
+                )(params_kv, rows, batch, *extra)
+            else:
+                loss, (gkv, grows) = jax.value_and_grad(
+                    kv_loss, argnums=(0, 1)
+                )(params_kv, rows, batch, *extra)
+                aux = None
+        with jax.named_scope(phases.APPLY):
+            updates, state = opt.update(gkv, state, params_kv)
+            params_kv = optax.apply_updates(params_kv, updates)
         dropped = {}
         for n in names:
             store = emb_stores[n]
@@ -89,33 +95,38 @@ def make_composite_step(
 
     sizes: Dict[str, int] = {}
 
+    span = obs.tracer().program_span
+
     def run(batch, *extra):
         import numpy as np
 
-        if not sizes:  # id-list sizes are static; probe once for accounting
-            for n, ids in ids_fn(batch).items():
-                sizes[n] = int(np.prod(np.shape(ids)))
-        params_kv, state = engine.get_tree_and_state()
-        tables = {n: emb_stores[n].table for n in names}
-        estates = {n: emb_stores[n]._state for n in names}
-        params_kv, state, tables, estates, loss, aux, dropped = fused(
-            params_kv, state, tables, estates, batch, *extra
-        )
-        engine.set_tree_and_state(params_kv, state)
-        nbytes = sum(_nbytes(v) for v in params_kv.values())
-        dense_store.bytes_pushed += nbytes
-        dense_store.bytes_pulled += nbytes
-        dense_store.step += 1
-        for n in names:
-            store = emb_stores[n]
-            store._table, store._state = tables[n], estates[n]
-            store.record_dropped(dropped[n])  # sync-free; read at log time
-            row_bytes = sizes[n] * store.dim * np.dtype(store.dtype).itemsize
-            store.bytes_pushed += row_bytes   # row grads out
-            store.bytes_pulled += row_bytes   # gathered rows in
-            store._account_push(sizes[n])
-            store.push_count += 1
-        params = keymod.unflatten(treedef, params_kv, key_order)
+        with span(phases.STEP_RUN, step=dense_store.step):
+            if not sizes:  # id-list sizes are static; probe once for accounting
+                for n, ids in ids_fn(batch).items():
+                    sizes[n] = int(np.prod(np.shape(ids)))
+            params_kv, state = engine.get_tree_and_state()
+            tables = {n: emb_stores[n].table for n in names}
+            estates = {n: emb_stores[n]._state for n in names}
+            with span(phases.STEP_LAUNCH, step=dense_store.step):
+                (params_kv, state, tables, estates, loss, aux,
+                 dropped) = fused_composite_step(
+                    params_kv, state, tables, estates, batch, *extra)
+            engine.set_tree_and_state(params_kv, state)
+            nbytes = sum(_nbytes(v) for v in params_kv.values())
+            dense_store.bytes_pushed += nbytes
+            dense_store.bytes_pulled += nbytes
+            dense_store.step += 1
+            for n in names:
+                store = emb_stores[n]
+                store._table, store._state = tables[n], estates[n]
+                store.record_dropped(dropped[n])  # sync-free; read at log time
+                row_bytes = (sizes[n] * store.dim
+                             * np.dtype(store.dtype).itemsize)
+                store.bytes_pushed += row_bytes   # row grads out
+                store.bytes_pulled += row_bytes   # gathered rows in
+                store._account_push(sizes[n])
+                store.push_count += 1
+            params = keymod.unflatten(treedef, params_kv, key_order)
         if has_aux:
             return loss, params, aux
         return loss, params
@@ -128,8 +139,8 @@ def make_composite_step(
         params_kv, state = engine.get_tree_and_state()
         tables = {n: emb_stores[n].table for n in names}
         estates = {n: emb_stores[n]._state for n in names}
-        return fused.lower(params_kv, state, tables, estates,
-                           batch, *extra).cost_analysis()
+        return fused_composite_step.lower(
+            params_kv, state, tables, estates, batch, *extra).cost_analysis()
 
     run.cost_analysis = cost_analysis
     return run

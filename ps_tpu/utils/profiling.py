@@ -2,8 +2,9 @@
 
 SURVEY.md §6 "Tracing/profiling": the TPU-native mechanism is
 ``jax.profiler.trace`` (TensorBoard/Perfetto XPlane dumps, including ICI
-collective timelines on real TPUs) plus named annotations so PS phases
-(push/apply/pull) are findable in the trace. The analytic GB/s counters in
+collective timelines on real TPUs). The PS phases are findable in the trace
+by the ``jax.named_scope`` names of ``ps_tpu/obs/phases.py``, which the
+fused steps carry in every op's ``op_name``. The analytic GB/s counters in
 ps_tpu/parallel/collectives.py can be cross-checked against the profiler's
 ICI utilization on hardware.
 """
@@ -27,18 +28,3 @@ def trace(log_dir: Optional[str]) -> Iterator[None]:
 
     with jax.profiler.trace(log_dir):
         yield
-
-
-def annotate(name: str):
-    """Context manager naming the enclosed host region in profiler traces."""
-    import jax.profiler
-
-    return jax.profiler.TraceAnnotation(name)
-
-
-def start_server(port: int = 9999):
-    """Start the on-demand profiling server (connect with TensorBoard's
-    capture-profile button); returns the server object."""
-    import jax.profiler
-
-    return jax.profiler.start_server(port)

@@ -36,7 +36,8 @@ print(jax.config.jax_compilation_cache_dir)
 def _env(**extra):
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS")}
-    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=_REPO, **extra)
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=_REPO)
+    env.update(extra)
     return env
 
 
@@ -45,12 +46,22 @@ def _entries(path):
 
 
 def _run_cache_probe(tmp_path, **extra):
+    """Run the probe from a checkout of its own: a link to the package under
+    ``tmp_path``. The cache's home is found from the package's location, so
+    it is ``<tmp_path>/checkout/.jax_cache``, which no other test worker
+    writes (the repo's own ``.jax_cache`` gains entries from every worker
+    that calls ``ps.init`` meanwhile). Returns the cache directory the probe
+    reports and its checkout's cache home."""
+    checkout = tmp_path / "checkout"
+    checkout.mkdir()
+    os.symlink(os.path.join(_REPO, "ps_tpu"), checkout / "ps_tpu")
     salt = int.from_bytes(os.urandom(4), "little")
     proc = subprocess.run(
-        [sys.executable, "-c", _CACHE_PROBE, str(salt)], env=_env(**extra),
+        [sys.executable, "-c", _CACHE_PROBE, str(salt)],
+        env=_env(PYTHONPATH=str(checkout), **extra),
         cwd=tmp_path, capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr
-    return proc.stdout.strip().splitlines()[-1]
+    return proc.stdout.strip().splitlines()[-1], str(checkout / ".jax_cache")
 
 
 def test_chip_smoke_refuses_to_run_without_a_tpu():
@@ -64,19 +75,18 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
 
 
 def test_compile_cache_defaults_to_the_checkout(tmp_path):
-    home = os.path.join(_REPO, ".jax_cache")
-    before = _entries(home)
-    assert _run_cache_probe(tmp_path) == home
-    assert _entries(home) - before, "nothing was cached in the checkout"
+    reported, home = _run_cache_probe(tmp_path)
+    assert reported == home
+    assert _entries(home), "nothing was cached in the checkout"
 
 
 def test_compile_cache_env_is_left_alone(tmp_path):
-    home, placed = os.path.join(_REPO, ".jax_cache"), str(tmp_path / "cache")
-    before = _entries(home)
-    assert _run_cache_probe(
-        tmp_path, JAX_COMPILATION_CACHE_DIR=placed) == placed
+    placed = str(tmp_path / "cache")
+    reported, home = _run_cache_probe(
+        tmp_path, JAX_COMPILATION_CACHE_DIR=placed)
+    assert reported == placed
     assert _entries(placed), "nothing was cached where the variable points"
-    assert _entries(home) == before, "the checkout's cache was written too"
+    assert not _entries(home), "the checkout's cache was written too"
 
 
 def test_unknown_device_kind_is_an_error():

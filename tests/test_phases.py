@@ -1,0 +1,444 @@
+"""The phases inside the fused steps and the spans on the host path into
+them: ``ps_tpu/obs/phases.py``, ``Tracer.program_span``, and the benchmark's
+readers ``benchmark/layer_metrics/scope.py`` and ``host.py``.
+
+A tiny ``make_step`` and a tiny ``make_composite_step`` on the virtual mesh
+stand for the real ones: the scopes and spans are written in the step
+builders, not in the models.
+"""
+
+import contextlib
+import json
+import os
+import re
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import ps_tpu as ps
+from benchmark.layer_metrics import host, scope
+from ps_tpu import obs
+from ps_tpu.data.prefetch import device_prefetch, threaded_source
+from ps_tpu.kv.sparse import SparseEmbedding
+from ps_tpu.obs import phases
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_METADATA = re.compile(r',?\s*metadata=\{(?:[^{}"]|"[^"]*")*\}')
+#: the module's tables of source files, functions and stack frames
+_DEBUG_TABLES = re.compile(r"^FileNames\n.*?(?=^(?:%|ENTRY))", re.M | re.S)
+BATCH, ROWS, DIM = 16, 64, 4
+
+
+def _loaded():
+    return jax.devices()[0].client.live_executables()
+
+
+def _dense_step():
+    """``(run, batch)`` of a tiny ``KVStore.make_step``."""
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="momentum", learning_rate=0.1,
+                       placement="sharded")
+    store.init({"w": jnp.ones((8, 8)), "b": jnp.zeros((8,))})
+
+    def loss_fn(params, batch):
+        return jnp.mean(_sin(batch @ params["w"] + params["b"]) ** 2)
+
+    return store.make_step(loss_fn), store.shard_batch(jnp.ones((BATCH, 8)))
+
+
+@jax.custom_vjp
+def _sin(x):
+    """A backward rule of its own, as the flash attention has: JAX names
+    its ops ``transpose(ps.grad)/jvp(..)``, not ``transpose(jvp(..))``."""
+    return jnp.sin(x)
+
+
+_sin.defvjp(lambda x: (jnp.sin(x), x), lambda x, g: (g * jnp.cos(x),))
+
+
+def _composite_step():
+    """``(run, batch)`` of a tiny ``make_composite_step`` with one table."""
+    ps.init(backend="tpu")
+    dense = ps.KVStore(optimizer="adam", learning_rate=0.01,
+                       placement="sharded")
+    dense.init({"w": jnp.ones((DIM, 1))})
+    emb = SparseEmbedding(ROWS, DIM, optimizer="adagrad", learning_rate=0.05)
+    emb.init(jax.random.key(1))
+
+    def loss_fn(params, rows, batch):
+        return jnp.mean((rows["emb"] @ params["w"] - batch["y"]) ** 2)
+
+    run = ps.make_composite_step(dense, {"emb": emb}, loss_fn,
+                                 lambda batch: {"emb": batch["ids"]})
+    ids = (np.arange(BATCH, dtype=np.int32) * 5) % ROWS
+    ids[1] = ids[0]  # a duplicate row, so the dedupe has work
+    return run, dense.shard_batch({"ids": ids,
+                                   "y": np.ones((BATCH, 1), np.float32)})
+
+
+BUILDERS = {"make_step": _dense_step, "make_composite_step": _composite_step}
+PHASES_OF = {
+    "make_step": (phases.GRAD, phases.APPLY),
+    "make_composite_step": phases.DEVICE_PHASES,
+}
+
+
+def _step_hlo(kind):
+    """The optimized HLO of the step's executable, found as the benchmark
+    finds it: among the executables that its first call loaded."""
+    run, batch = BUILDERS[kind]()
+    before = _loaded()
+    run(batch)
+    texts = [m.to_string() for e in _loaded() if e not in before
+             for m in e.hlo_modules()]
+    ps.shutdown()
+    return max(texts, key=len)
+
+
+@pytest.fixture
+def no_compile_cache():
+    """The persistent compile cache off: its key leaves metadata out, so a
+    step compiled with the scopes would be served for the one without (and a
+    cache written by an older tree for either)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kind", sorted(BUILDERS))
+def test_step_hlo_carries_every_phase(kind, no_compile_cache):
+    op_names = list(scope.op_names_of(_step_hlo(kind)).values())
+    for phase in PHASES_OF[kind]:
+        assert any(phase in n for n in op_names), phase
+    grad = [n for n in op_names if phases.GRAD in n]
+    assert any(phases.BACKWARD_MARK in n for n in grad)
+    assert any(phases.BACKWARD_MARK not in n for n in grad)
+    # what the reader makes of them: every phase it reports has an op
+    found = {scope.phase_of(n) for n in op_names}
+    assert {"forward", "backward", "apply"} <= {p for p, _ in found}
+    if kind == "make_step":  # the custom_vjp rule's cos is a backward op
+        rule = [n for n in op_names if n.endswith("/cos")]
+        assert rule and all(scope.phase_of(n)[0] == "backward" for n in rule)
+    if kind == "make_composite_step":
+        assert {(p, c) for p, c in found if p == "row_apply"} >= {
+            ("row_apply", c) for c in (
+                phases.ROW_EXCHANGE, phases.ROW_DEDUPE, phases.ROW_GATHER,
+                phases.ROW_UPDATE, phases.ROW_SCATTER)}
+        assert ("lookup", None) in found
+
+
+def _without_metadata(hlo_text):
+    return _DEBUG_TABLES.sub("", _METADATA.sub("", hlo_text))
+
+
+@pytest.mark.parametrize("kind", sorted(BUILDERS))
+def test_marks_change_no_instruction(kind, monkeypatch, no_compile_cache):
+    marked = _step_hlo(kind)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _step_hlo(kind)
+    assert phases.GRAD in marked and phases.GRAD not in bare
+    assert "ENTRY" in _without_metadata(marked)
+    assert _without_metadata(marked) == _without_metadata(bare)
+
+
+def test_program_and_benchmark_share_their_names():
+    assert set(phases.DEVICE_PHASES) == set(scope.DEVICE_PHASES)
+    assert phases.BACKWARD_MARK == scope.BACKWARD_MARK
+    assert set(phases.HOST_SPANS) == set(host.HOST_SPANS)
+    for name in ("GRAD", "APPLY", "LOOKUP", "ROW_APPLY", "ROW_EXCHANGE",
+                 "ROW_DEDUPE", "ROW_GATHER", "ROW_UPDATE", "ROW_SCATTER"):
+        assert getattr(phases, name) == getattr(scope, name)
+    for name in ("STEP_RUN", "STEP_LAUNCH", "INPUT_PLACE",
+                 "INPUT_SOURCE_WAIT", "INPUT_PRODUCE"):
+        assert getattr(phases, name) == getattr(host, name)
+    # every span the program records has a metric that reads it
+    ring = [_span(name, 10.0, f"s{i}", "s0" if name == host.STEP_LAUNCH
+                  else None, nbytes=1)
+            for i, name in enumerate(phases.HOST_SPANS)]
+    for i, name in enumerate(phases.HOST_SPANS):
+        rest = [s for s in ring if s.name != name]
+        assert host.span_metrics(rest) != host.span_metrics(ring), name
+
+
+# -- scope.py on a hand-made result ------------------------------------------
+
+def _ev(own, opcode="fusion", shape="f32[8]"):
+    # as long as a real event's name: an HLO line with its operands
+    return (f"{own} = {shape} {opcode}(%p0), kind=kLoop, "
+            "calls=%fused_computation, " + "backend_config={} " * 8)
+
+
+_OPS = {  # seconds over 2 traced steps, on each of 2 chips
+    _ev("%fwd"): 0.007,
+    _ev("%fwd_transpose"): 0.003,  # the primitive, not the transform
+    _ev("%bwd"): 0.012,
+    _ev("%rule_of_custom_vjp"): 0.008,
+    _ev("%upd"): 0.002,
+    _ev("%take"): 0.003,
+    _ev("%sort"): 0.004,
+    _ev("%rows"): 0.005,
+    _ev("%put"): 0.006,
+    _ev("%rule"): 0.001,
+    _ev("%all-reduce.1", "all-reduce"): 0.008,
+    _ev("%all-gather.2", "all-gather"): 0.0005,
+    _ev("%bare"): 0.0015,    # an instruction XLA made without metadata
+    _ev("%alien"): 0.0005,   # not in any marked module
+}
+_OP_NAMES = {
+    "%fwd": "jit(fused)/ps.grad/jvp(M)/dot_general",
+    "%bwd": "jit(fused)/ps.grad/transpose(jvp(M))/dot_general",
+    "%rule_of_custom_vjp": "jit(fused)/ps.grad/transpose(ps.grad)/jvp(M)/exp",
+    "%fwd_transpose": "jit(fused)/ps.grad/jvp(M)/transpose",
+    "%upd": "jit(fused)/ps.apply/sub",
+    "%take": "jit(fused)/ps.lookup/jit(_take)/gather",
+    "%sort": "jit(fused)/ps.row_apply/shard_map/ps.row_apply/dedupe/sort",
+    "%rows": "jit(fused)/ps.row_apply/shard_map/ps.row_apply/gather/gather",
+    "%put": "jit(fused)/ps.row_apply/shard_map/ps.row_apply/scatter/scatter",
+    "%rule": "jit(fused)/ps.row_apply/shard_map/ps.row_apply/update/mul",
+    "%all-reduce.1": "jit(fused)/ps.grad/transpose(jvp(M))/psum",
+    "%all-gather.2": "jit(fused)/ps.apply/all_gather",
+    "%bare": "",
+}
+
+
+def _result(chips=2):
+    devices = {f"/device:TPU:{i}": {"ops": dict(_OPS)} for i in range(chips)}
+    return {"trace": {"devices": devices}, "traced_steps": 2, "chips": chips}
+
+
+def test_scope_phases_and_rest_add_up_to_the_busy_time():
+    out = scope.phase_times(_result(), _OP_NAMES)
+    busy_ms = 1e3 * sum(_OPS.values()) / 2
+    rest_ms = out["scope.unattributed_share"] / 100.0 * busy_ms
+    assert rest_ms == pytest.approx(1e3 * (0.0015 + 0.0005) / 2)
+    total = sum(out[m] for m in scope.PHASE_METRICS.values()) + rest_ms
+    assert total == pytest.approx(busy_ms)
+    assert out["scope.forward_ms"] == pytest.approx(5.0)
+    assert out["scope.backward_ms"] == pytest.approx(14.0)  # with its psum
+    assert out["scope.apply_ms"] == pytest.approx(1.25)
+    assert out["scope.lookup_ms"] == pytest.approx(1.5)
+    assert out["scope.row_apply_ms"] == pytest.approx(8.0)
+    assert out["scope.row_dedupe_ms"] == pytest.approx(2.0)
+    assert out["scope.row_gather_ms"] == pytest.approx(2.5)
+    assert out["scope.row_scatter_ms"] == pytest.approx(3.0)
+
+
+def test_scope_splits_collectives_by_phase(capsys):
+    out = scope.phase_times(_result(), _OP_NAMES)
+    assert out["scope.collective_backward_ms"] == pytest.approx(4.0)
+    assert out["scope.collective_apply_ms"] == pytest.approx(0.25)
+    assert out["scope.collective_forward_ms"] == 0.0
+    assert "collective time with no phase" in capsys.readouterr().err
+    assert not any("collective" in k
+                   for k in scope.phase_times(_result(chips=1), _OP_NAMES))
+
+
+def test_scope_op_without_op_name_is_unattributed(capsys):
+    out = scope.phase_times(_result(), _OP_NAMES)
+    assert out["scope.unattributed_share"] == pytest.approx(
+        100.0 * 0.002 / sum(_OPS.values()))
+    listed = capsys.readouterr().err.split("scope: unattributed")[1]
+    assert "%bare" in listed and "[<no op_name>]" in listed
+    assert "%alien" in listed and "[<not in a marked executable>]" in listed
+    assert "%fwd" not in listed
+
+
+def test_scope_without_any_mark_says_so(capsys):
+    out = scope.phase_times(_result(), None)
+    assert out["scope.unattributed_share"] == pytest.approx(100.0)
+    assert all(out[m] == 0.0 for m in scope.PHASE_METRICS.values())
+    err = capsys.readouterr().err
+    assert "no loaded executable carries a phase mark" in err
+    assert ".jax_cache" in err
+    assert scope.phase_times({"trace": None, "traced_steps": 0}, None) == {}
+
+
+def test_scope_reads_marks_from_the_loaded_executables(capsys):
+    run, batch = _dense_step()
+    run(batch)
+    names = scope.loaded_op_names()
+    assert names and any(phases.APPLY in v for v in names.values())
+    assert all(k.startswith("%") for k in names)
+    assert "carry a phase mark" in capsys.readouterr().err
+
+
+def test_scope_two_marked_executables_that_disagree(capsys):
+    """An instruction name is unique within one module only: where two
+    marked modules put one name in different phases, neither decides."""
+    step = {"%fusion.1": "jit(a)/ps.grad/jvp(M)/dot_general",
+            "%fusion.2": "jit(a)/ps.apply/sub",
+            "%copy.3": ""}
+    push = {"%fusion.1": "jit(b)/ps.row_apply/ps.row_apply/scatter/scatter",
+            "%fusion.2": "jit(b)/ps.apply/mul",   # the same phase: no clash
+            "%fusion.9": "jit(b)/ps.row_apply/ps.row_apply/gather/gather"}
+    pool = {"%fusion.2": "jit(make_pool)/iota", "%fusion.7": ""}  # no mark
+    for order in ((step, push, pool), (pool, push, step)):
+        names = scope.merge_marked(order)
+        assert names["%fusion.1"] == scope.CLASH
+        assert scope.phase_of(names["%fusion.1"]) == (None, None)
+        assert scope.phase_of(names["%fusion.2"])[0] == "apply"
+        assert scope.phase_of(names["%fusion.9"])[0] == "row_apply"
+        assert "%fusion.7" not in names
+        err = capsys.readouterr().err
+        assert "2 loaded executable(s)" in err and "%fusion.1" in err
+    assert scope.merge_marked([pool]) is None
+    # and the time of such an op is shown as unattributed, with the reason
+    r = {"trace": {"devices": {"d0": {"ops": {_ev("%fusion.1"): 0.002,
+                                              _ev("%fusion.2"): 0.006}}}},
+         "traced_steps": 1, "chips": 1}
+    out = scope.phase_times(r, scope.merge_marked([step, push]))
+    assert out["scope.unattributed_share"] == pytest.approx(25.0)
+    assert out["scope.apply_ms"] == pytest.approx(6.0)
+    assert f"[{scope.CLASH}]" in capsys.readouterr().err
+
+
+# -- the program's spans -------------------------------------------------------
+
+def _spans_since(mark, name):
+    return [s for s in obs.tracer().spans()
+            if s.name == name and s.t0 >= mark]
+
+
+@pytest.mark.parametrize("kind", sorted(BUILDERS))
+def test_each_step_leaves_a_run_and_a_launch_span(kind):
+    import time
+
+    run, batch = BUILDERS[kind]()
+    mark = time.perf_counter()
+    for _ in range(5):
+        run(batch)
+    runs = _spans_since(mark, phases.STEP_RUN)
+    launches = _spans_since(mark, phases.STEP_LAUNCH)
+    assert len(runs) == len(launches) == 5
+    assert [s.args["step"] for s in runs] == list(range(5))
+    for outer, inner in zip(runs, launches):
+        assert inner.parent_id == outer.span_id and outer.parent_id is None
+        assert inner.args["step"] == outer.args["step"]
+        assert outer.t0 <= inner.t0
+        assert inner.dur_us <= outer.dur_us
+    assert obs.tracer().sample == 0.0  # recorded with sampling off
+
+
+def test_input_spans_carry_the_batch_number_from_both_threads():
+    import threading
+    import time
+
+    mark = time.perf_counter()
+    batches = [{"x": np.ones((4, 8), np.float32)} for _ in range(4)]
+    out = list(device_prefetch(threaded_source(iter(batches))))
+    assert len(out) == 4
+    produced = _spans_since(mark, phases.INPUT_PRODUCE)
+    waited = _spans_since(mark, phases.INPUT_SOURCE_WAIT)
+    placed = _spans_since(mark, phases.INPUT_PLACE)
+    # the producer and the consumer each look once more and find the end
+    assert [s.args["seq"] for s in produced] == [0, 1, 2, 3, 4]
+    assert [s.args["seq"] for s in waited] == [0, 1, 2, 3, 4]
+    assert [s.args["seq"] for s in placed] == [0, 1, 2, 3]
+    assert {s.args["nbytes"] for s in placed} == {4 * 8 * 4}
+    here = threading.get_ident()
+    assert {s._tid for s in placed + waited} == {here}
+    assert here not in {s._tid for s in produced}
+    assert len({s.span_id for s in produced + waited + placed}) == 14
+
+
+def test_program_spans_reach_the_chrome_export():
+    tr = obs.Tracer(sample=0.0)
+    with tr.program_span("step.run", step=3) as outer:
+        with tr.program_span("step.launch", step=3):
+            # off the sampled spans' stack: an op issued in here makes its
+            # own sampling decision and puts no context on the wire
+            assert tr.current() is None and tr.child("hop") is obs.NOOP
+    assert tr.span("sampled.root") is obs.NOOP  # sampling is still off
+    events = [e for e in tr.chrome_events() if e["ph"] == "X"]
+    assert [e["name"] for e in events] == ["step.launch", "step.run"]
+    assert events[0]["args"]["parent_id"] == outer.span_id
+    assert events[0]["args"]["step"] == 3
+
+
+# -- host.py on a synthetic ring ----------------------------------------------
+
+def _span(name, dur_us, span_id, parent_id=None, t0=0.0, **args):
+    return types.SimpleNamespace(name=name, dur_us=dur_us, span_id=span_id,
+                                 parent_id=parent_id, t0=t0, args=args)
+
+
+def test_host_metrics_from_a_synthetic_ring():
+    ring = []
+    for i, (run_us, launch_us) in enumerate([(900, 700), (1000, 800),
+                                             (5000, 4700)]):
+        ring.append(_span(host.STEP_LAUNCH, launch_us, f"l{i}", f"r{i}",
+                          step=i))
+        ring.append(_span(host.STEP_RUN, run_us, f"r{i}", step=i))
+        ring.append(_span(host.INPUT_PLACE, 2000 + i, f"p{i}", seq=i,
+                          nbytes=3_000_000))
+    ring.append(_span(host.STEP_RUN, 123, "orphan", step=9))  # launch evicted
+    ring.append(_span("server_apply", 1e6, "x"))              # the van's
+    out = host.span_metrics(ring)
+    assert out == {
+        "host.step_launch_ms": pytest.approx(0.8),
+        "host.step_wrap_ms": pytest.approx(0.2),
+        "host.input_place_ms": pytest.approx(2.001),
+        "host.input_mb_per_step": pytest.approx(3.0),
+    }
+    ring.append(_span(host.INPUT_SOURCE_WAIT, 40, "w0", seq=0))
+    ring.append(_span(host.INPUT_PRODUCE, 70, "q0", seq=0))
+    more = host.span_metrics(ring)
+    assert more["host.input_source_wait_ms"] == pytest.approx(0.04)
+    assert more["host.input_produce_ms"] == pytest.approx(0.07)
+    assert host.span_metrics([]) == {}
+
+
+def test_host_metrics_count_the_measured_window_only(monkeypatch):
+    """Warm-up, the traced steps and the steps past the window started
+    outside ``[start + setup_s, start + setup_s + window_s)``."""
+    ring = []
+    for i, t0 in enumerate([100.5, 101.9,            # warm-up
+                            102.0, 103.0, 104.0,     # the window
+                            105.0, 106.0]):          # traced, past it
+        slow = 1 if 102.0 <= t0 < 105.0 else 50
+        ring.append(_span(host.STEP_LAUNCH, 700 * slow, f"l{i}", f"r{i}",
+                          t0=t0 + 1e-4, step=i))
+        ring.append(_span(host.STEP_RUN, 900 * slow, f"r{i}", t0=t0, step=i))
+    assert host.span_metrics(ring, (102.0, 105.0)) == {
+        "host.step_launch_ms": pytest.approx(0.7),
+        "host.step_wrap_ms": pytest.approx(0.2)}
+    assert host.span_metrics(ring)["host.step_launch_ms"] == \
+        pytest.approx(35.0)
+    r = {"setup_s": 2.0, "window_s": 3.0}
+    monkeypatch.setattr(sys.modules["__main__"], "_T_START", 100.0,
+                        raising=False)
+    assert host.window_of(r) == (102.0, 105.0)
+    monkeypatch.delattr(sys.modules["__main__"], "_T_START")
+    assert host.window_of(r) is None   # not under benchmark/run.py
+
+
+def test_benchmark_command_rehearses_the_host_metrics():
+    """The benchmark's own command on the CPU: a traced rehearsal of the
+    Wide&Deep cell lists the ``host.*`` metrics the cell is to report."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
+                        "JAX_COMPILATION_CACHE_DIR")}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "benchmark", "run.py"),
+         "--workload", "widedeep-criteo.b4096.zipf", "--rehearse",
+         "--trace", "1", "--seconds", "2"],
+        env=env, cwd=_REPO, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["metrics"] == {}
+    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
+        listed = {m["name"] for m in json.load(f)["per_layer"]
+                  if m["name"].startswith("host.")
+                  and "widedeep-criteo.b4096.zipf" in m.get(
+                      "workloads", ["widedeep-criteo.b4096.zipf"])}
+    assert listed and listed <= set(line["rehearsed"])

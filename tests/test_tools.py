@@ -218,17 +218,3 @@ def test_measure_flops_smoke():
     out = _run("measure_flops.py", "widedeep")
     assert out["model"] == "widedeep"
     assert out["slope_per_example"] > 0 and out["const_per_step"] > 0
-
-
-@pytest.mark.slow
-def test_characterize_smoke():
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "tools", "characterize.py"),
-         "--batch", "8", "--image-size", "64", "--steps", "2", "--no-trace"],
-        env=env, capture_output=True, text=True, timeout=420,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "throughput:" in proc.stdout and "flops/step (HLO):" in proc.stdout
