@@ -26,6 +26,7 @@ from ps_tpu.api import current_context
 from ps_tpu.kv import keys as keymod
 from ps_tpu.obs import phases
 from ps_tpu.optim import make_optimizer
+from ps_tpu.parallel.sharding import gathered_sharding
 
 
 def _nbytes(x) -> int:
@@ -218,7 +219,17 @@ class KVStore:
         On the tpu backend the whole PS protocol — gradient, aggregation
         collective, server apply, pull — compiles into ONE donated XLA
         program (the north-star fusion); on the local backend it runs the
-        explicit per-key protocol.
+        explicit per-key protocol. Under ``placement="sharded"`` on a mesh
+        whose data axis is larger than 1 that program states its own
+        shardings (``with_sharding_constraint`` under the ``ps.apply``
+        scope, ``out_shardings`` on the jit): parameters are all-gathered
+        to ``gathered_sharding`` before the loss reads them (the pull),
+        gradients are summed at that shape and constrained to the stored
+        sharding before the optimizer reads them (the push: a
+        reduce-scatter), and parameters and optimizer state leave in the
+        shardings they came in with. Activations stay split over the batch
+        from end to end. Under ``"replicated"``, or on one device, the
+        program holds no constraint.
 
         Donation note (tpu): each step donates the previous parameter and
         optimizer-state buffers. References obtained from earlier
@@ -295,20 +306,47 @@ class KVStore:
                 keymod.unflatten(treedef, params_kv, key_order), batch, *extra
             )
 
+        # ZeRO-1 across chips: the step states where each tensor lives
+        # instead of leaving it to GSPMD, which on BERT's shapes kept the
+        # weights split and moved the activations (PERF.md, PR 26). Neither
+        # fact is an option: the store holds both.
+        stored = gathered = out_shardings = None
+        # num_workers is the size of the mesh's data axis
+        if self.placement == "sharded" and engine.num_workers > 1:
+            stored, state_shardings = jax.tree_util.tree_map(
+                lambda x: x.sharding, engine.get_tree_and_state())
+            gathered = jax.tree_util.tree_map(gathered_sharding, stored)
+            out_shardings = (stored, state_shardings, None, None)
+
         # Not named ``fused`` as before the scopes: jax leaves metadata out
         # of the compile cache's key, so under the old name an executable
         # cached without the phase marks would be served for this one.
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        @functools.partial(jax.jit, donate_argnums=(0, 1),
+                           out_shardings=out_shardings)
         def fused_step(params_kv, state, batch, *extra):
+            pulled = params_kv
+            if gathered is not None:
+                # the pull: the tail of the server's apply in this protocol
+                with jax.named_scope(phases.APPLY):
+                    pulled = jax.lax.with_sharding_constraint(
+                        params_kv, gathered)
             with jax.named_scope(phases.GRAD):
                 if has_aux:
                     (loss, aux), grads = jax.value_and_grad(
-                        kv_loss, has_aux=True)(params_kv, batch, *extra)
+                        kv_loss, has_aux=True)(pulled, batch, *extra)
                 else:
                     loss, grads = jax.value_and_grad(kv_loss)(
-                        params_kv, batch, *extra)
+                        pulled, batch, *extra)
                     aux = None
             with jax.named_scope(phases.APPLY):
+                if stored is not None:
+                    # the push: a gradient is a sum over the chips' batch
+                    # slices at the shape its parameter was read in, and
+                    # each owner keeps its shard of it: all-reduce then
+                    # slice, which the compiler fuses to a reduce-scatter
+                    grads = jax.lax.with_sharding_constraint(
+                        jax.lax.with_sharding_constraint(grads, gathered),
+                        stored)
                 if grad_scale != 1.0:  # aggregate='sum' semantics
                     grads = jax.tree_util.tree_map(
                         lambda g: g * grad_scale, grads)
@@ -336,26 +374,35 @@ class KVStore:
                 return loss, params, aux
             return loss, params
 
+        def lower(batch, *extra):
+            """The fused step lowered for this batch, not compiled and not
+            run: a ``jax.stages.Lowered``. Its ``as_text()`` holds the
+            sharding constraints the step states, before the partitioner
+            resolves them."""
+            params_kv, state = engine.get_tree_and_state()
+            return fused_step.lower(params_kv, state, batch, *extra)
+
         def cost_analysis(batch, *extra):
             """XLA HLO cost analysis of the whole fused step (gradient +
             aggregation + server apply + pull) — no execution, no extra
             compile: lowering stops at pre-optimization HLO, so 'flops' is
             the exact model+optimizer arithmetic while 'bytes accessed' is an
             unfused upper bound. Benchmarks turn this into MFU."""
-            params_kv, state = engine.get_tree_and_state()
-            return fused_step.lower(
-                params_kv, state, batch, *extra).cost_analysis()
+            return lower(batch, *extra).cost_analysis()
 
         def compiled_text(batch, *extra) -> str:
             """Post-GSPMD optimized HLO of the fused step, as text — the
             compiled collective pattern (reduce-scatter/all-gather vs
             all-reduce) that tests/test_hlo_collectives.py pins so a
             placement regression in ``param_sharding`` is a loud failure,
-            not a silent 8x traffic increase."""
-            params_kv, state = engine.get_tree_and_state()
-            return fused_step.lower(params_kv, state, batch, *extra)\
-                .compile().as_text()
+            not a silent 8x traffic increase: on a two-matrix MLP
+            (``test_sharded_scatters_largest_grad_and_gathers_params``) and
+            on transformer shapes, where the weights' 'data' dim is the
+            output dim and the regression was activations moved in place
+            of weights (``test_sharded_transformer_moves_weights_only``)."""
+            return lower(batch, *extra).compile().as_text()
 
+        run.lower = lower
         run.cost_analysis = cost_analysis
         run.compiled_text = compiled_text
         return run

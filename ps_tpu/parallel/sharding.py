@@ -97,7 +97,14 @@ def param_sharding(mesh: Mesh, leaf: Any, placement: str,
     - 'sharded': split the largest dimension divisible by the data-axis size
       (ZeRO-1-style; grads reduce-scatter to the owner shard, the update runs
       shard-local, pulls all-gather). Falls back to replicated for tensors
-      with no evenly divisible dimension.
+      with no evenly divisible dimension. This function only chooses where a
+      tensor is STORED. The gather and the reduce-scatter are stated where
+      the fused step reads and writes it (``KVStore.make_step``): each
+      parameter is constrained to :func:`gathered_sharding` of its stored
+      sharding before the loss reads it, each gradient to the stored
+      sharding before the optimizer does. Left unstated, GSPMD resolves a
+      matmul whose operands are both split over 'data' by moving the
+      activations (PERF.md, PR 26).
 
     If the mesh carries a 'model' axis of size > 1, tensors additionally
     shard one dimension over it (tensor parallelism: GSPMD partitions the
@@ -131,6 +138,20 @@ def param_sharding(mesh: Mesh, leaf: Any, placement: str,
     if all(s is None for s in spec):
         return replicated(mesh)
     return NamedSharding(mesh, P(*spec))
+
+
+def gathered_sharding(stored: NamedSharding) -> NamedSharding:
+    """The sharding the loss reads a stored parameter under: the pull.
+
+    Every ``DATA_AXIS`` entry of the stored spec becomes ``None`` (the ZeRO
+    shards are all-gathered), every other entry stays (a tensor-parallel
+    split over ``MODEL_AXIS`` is the model's own and is not undone). On a
+    data-only mesh the result is replicated. ``KVStore.make_step`` states it
+    on every parameter before the loss reads it.
+    """
+    # param_sharding and its rules give a dim one axis or none
+    return NamedSharding(stored.mesh, P(*(
+        None if entry == DATA_AXIS else entry for entry in stored.spec)))
 
 
 def batch_sharding(mesh: Mesh, axis: str = DATA_AXIS) -> NamedSharding:

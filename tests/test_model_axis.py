@@ -185,3 +185,38 @@ def test_bare_string_spec_rejected():
         ps.KVStore(optimizer="sgd", learning_rate=0.1,
                    partition_rules=[(r"kernel$", "model")])
     ps.shutdown()
+
+
+# -- the pull's sharding: gathered over 'data', untouched over 'model' ---------
+
+@pytest.mark.parametrize("mesh_shape,placement,shape,rules,stored,gathered", [
+    # ZeRO only: the data shards are gathered, the result is replicated
+    ({"data": 8}, "sharded", (D, FF), None, P(None, "data"), P(None, None)),
+    # tensor parallel only: the split is the model's own and stays
+    ({"data": 4, "model": 2}, "replicated", (D, FF), None,
+     P(None, "model"), P(None, "model")),
+    # both: 'model' on the largest dim stays, 'data' on the next is gathered
+    ({"data": 4, "model": 2}, "sharded", (D, FF), None,
+     P("data", "model"), P(None, "model")),
+    # nothing divides: replicated in, replicated out
+    ({"data": 8}, "sharded", (10,), None, P(), P()),
+    # a rule-placed tensor: both axes where the rule put them
+    ({"data": 4, "model": 2}, "sharded", (4, D, FF),
+     [(r"experts/kernel$", ("data", "model", None))],
+     P("data", "model", None), P(None, "model", None)),
+])
+def test_gathered_sharding_table(mesh_shape, placement, shape, rules, stored,
+                                 gathered):
+    from jax.sharding import NamedSharding
+
+    from ps_tpu.parallel.mesh import make_mesh
+    from ps_tpu.parallel.sharding import gathered_sharding, param_sharding
+
+    mesh = make_mesh(mesh_shape)
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32)
+    sharding = param_sharding(mesh, leaf, placement, key="experts/kernel",
+                              rules=rules)
+    assert sharding.is_equivalent_to(NamedSharding(mesh, stored), len(shape))
+    out = gathered_sharding(sharding)
+    assert out.mesh is sharding.mesh
+    assert out.spec == gathered, (sharding.spec, out.spec)
