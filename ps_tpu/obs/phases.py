@@ -2,7 +2,7 @@
 
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/store.py``, ``train.py``, ``kv/sparse.py``,
-``ops/sparse_apply.py``) and land in the ``op_name`` of every HLO
+``ops/sparse_apply.py``, ``models/olmoe.py``) and land in the ``op_name`` of every HLO
 instruction traced under them; the host spans are recorded with
 ``ps_tpu.obs.tracer().program_span`` (``kv/store.py``, ``train.py``,
 ``data/prefetch.py``). ``benchmark/layer_metrics/scope.py`` and ``host.py``
@@ -34,6 +34,19 @@ BACKWARD_MARK = "transpose("
 
 DEVICE_PHASES = (GRAD, APPLY, LOOKUP, ROW_APPLY, ROW_EXCHANGE, ROW_DEDUPE,
                  ROW_GATHER, ROW_UPDATE, ROW_SCATTER)
+
+# -- scopes inside the loss of the expert model (models/olmoe.py) --------------
+# They nest under GRAD, so the phases above keep adding up; they are read by
+# ``benchmark/layer_metrics/moe.py``, which keeps its own copy. Never part of
+# DEVICE_PHASES: that tuple is what ``layer_metrics/scope.py`` knows.
+MOE_ROUTE = "ps.moe/route"        # router matmul, softmax, top-k, sort, group sizes
+MOE_DISPATCH = "ps.moe/dispatch"  # token rows permuted into expert order
+MOE_EXPERT = "ps.moe/expert"      # the three grouped matmuls and SwiGLU
+MOE_COMBINE = "ps.moe/combine"    # rows permuted back, weighted sum over top-k
+ATTN = "ps.attn"                  # q/k/v projections, QK-norm, RoPE, attention, out projection
+HEAD = "ps.head"                  # final norm, head matmul, cross entropy
+
+MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERT, MOE_COMBINE, ATTN, HEAD)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

@@ -7,19 +7,19 @@ the per-key apply is just an optax update compiled by XLA — state lives
 sharded exactly like the parameters ("next to" them in the PS sense).
 
 :func:`make_optimizer` accepts either a name ('sgd' | 'momentum' | 'adam' |
-'lamb') or any optax ``GradientTransformation``, so trainers can register
+'adamw' | 'lamb') or any optax ``GradientTransformation``, so trainers can register
 custom server optimizers the way the reference family allows.
 """
 
 from __future__ import annotations
 
-from typing import Union
+from typing import Optional, Union
 
 import optax
 
 from ps_tpu.optim.dc import delay_compensate
 
-__all__ = ["make_optimizer", "sgd", "momentum", "adam", "lamb", "delay_compensate"]
+__all__ = ["make_optimizer", "sgd", "momentum", "adam", "adamw", "lamb", "delay_compensate"]
 
 
 def sgd(learning_rate: Union[float, optax.Schedule] = 0.01) -> optax.GradientTransformation:
@@ -42,6 +42,25 @@ def adam(
     return optax.adam(learning_rate, b1=b1, b2=b2, eps=eps)
 
 
+def adamw(
+    learning_rate: Union[float, optax.Schedule] = 1e-3,
+    b1: float = 0.9,
+    b2: float = 0.999,
+    eps: float = 1e-8,
+    weight_decay: float = 1e-4,
+    clip_by_global_norm: Optional[float] = None,
+) -> optax.GradientTransformation:
+    """Adam with decoupled weight decay on every tensor, behind an optional
+    clip of the whole gradient tree to a global norm: the recipe of decoder
+    pre-training (OLMoE, arXiv:2409.02060). The clip is the one server-side
+    rule here that reads every gradient before it may write any parameter."""
+    opt = optax.adamw(learning_rate, b1=b1, b2=b2, eps=eps,
+                      weight_decay=weight_decay)
+    if clip_by_global_norm is None:
+        return opt
+    return optax.chain(optax.clip_by_global_norm(clip_by_global_norm), opt)
+
+
 def lamb(
     learning_rate: Union[float, optax.Schedule] = 1e-3,
     b1: float = 0.9,
@@ -60,6 +79,7 @@ _REGISTRY = {
     "sgd": sgd,
     "momentum": momentum,
     "adam": adam,
+    "adamw": adamw,
     "lamb": lamb,
 }
 

@@ -38,11 +38,49 @@ def run_steps(opt_name, steps=5, **kw):
     ("momentum", {"learning_rate": 0.1, "momentum": 0.9}),
     ("adam", {"learning_rate": 0.01}),
     ("lamb", {"learning_rate": 0.01, "weight_decay": 0.01}),
+    ("adamw", {"learning_rate": 0.01, "b2": 0.95, "weight_decay": 0.1}),
+    ("adamw", {"learning_rate": 0.01, "weight_decay": 0.1,
+               "clip_by_global_norm": 0.25}),
 ])
 def test_server_apply_matches_optax(opt_name, kw):
     ps_traj, ref_traj = run_steps(opt_name, **kw)
     for a, b in zip(ps_traj, ref_traj):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("clip", [None, 0.25, 100.0])
+def test_adamw_exact_math(clip):
+    """Decoupled decay on every tensor behind a clip of the WHOLE tree's
+    gradient norm: one step by hand. Adam's first step is lr * sign(g)
+    whatever the clip's scale, so the clip shows on the second."""
+    lr, b1, b2, eps, wd = 0.1, 0.9, 0.95, 1e-8, 0.1
+    opt = make_optimizer("adamw", learning_rate=lr, b1=b1, b2=b2, eps=eps,
+                         weight_decay=wd, clip_by_global_norm=clip)
+    params = {"a": np.array([1.0, -2.0]), "b": np.array([[3.0]])}
+    grads = [{"a": np.array([0.3, -0.4]), "b": np.array([[1.2]])},   # norm 1.3
+             {"a": np.array([0.03, 0.04]), "b": np.array([[0.0]])}]  # 0.05
+    tree = {k: jnp.asarray(v, jnp.float32) for k, v in params.items()}
+    state = opt.init(tree)
+    m = {k: np.zeros_like(v) for k, v in params.items()}
+    v = {k: np.zeros_like(x) for k, x in params.items()}
+    for t, g in enumerate(grads, start=1):
+        updates, state = opt.update(
+            {k: jnp.asarray(x, jnp.float32) for k, x in g.items()}, state,
+            tree)
+        tree = optax.apply_updates(tree, updates)
+        norm = np.sqrt(sum(np.sum(x * x) for x in g.values()))
+        scale = 1.0 if clip is None else min(1.0, clip / norm)
+        for k in params:
+            m[k] = b1 * m[k] + (1 - b1) * g[k] * scale
+            v[k] = b2 * v[k] + (1 - b2) * (g[k] * scale) ** 2
+            step = (m[k] / (1 - b1 ** t)) / (
+                np.sqrt(v[k] / (1 - b2 ** t)) + eps)
+            params[k] = params[k] - lr * (step + wd * params[k])
+            np.testing.assert_allclose(np.asarray(tree[k]), params[k],
+                                       rtol=1e-5)
+    if clip == 0.25:   # only the first gradient (norm 1.3) was scaled
+        assert m["a"][0] == pytest.approx(
+            b1 * (1 - b1) * 0.3 * 0.25 / 1.3 + (1 - b1) * 0.03)
 
 
 def test_sgd_exact_math():

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
-from benchmark.layer_metrics import host, scope
+from benchmark.layer_metrics import host, moe, scope
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
 from ps_tpu.kv.sparse import SparseEmbedding
@@ -168,6 +168,88 @@ def test_program_and_benchmark_share_their_names():
     for i, name in enumerate(phases.HOST_SPANS):
         rest = [s for s in ring if s.name != name]
         assert host.span_metrics(rest) != host.span_metrics(ring), name
+
+
+def _expert_step():
+    """``(run, batch)`` of ``make_step(has_aux=True)`` on a tiny OLMoE."""
+    from ps_tpu.models import olmoe
+
+    cfg = olmoe.OlmoeConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=16,
+        num_hidden_layers=1, num_attention_heads=2, num_experts=4,
+        num_experts_per_tok=2, dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: olmoe.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 17, dtype=np.int32).reshape(8, 17) * 7) % 64
+    return (store.make_step(olmoe.make_loss_fn(cfg), has_aux=True),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_expert_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """The scopes the model opens inside its loss nest under ``ps.grad``,
+    each with forward and backward ops, and the benchmark's reader keeps
+    the same names and finds them."""
+    assert phases.MOE_SCOPES == moe.MOE_SCOPES
+    for name in ("MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE",
+                 "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(moe, name)
+    assert not set(phases.MOE_SCOPES) & set(phases.DEVICE_PHASES)
+    assert set(moe.SCOPE_METRICS) == set(moe.MOE_SCOPES)
+    monkeypatch.setitem(BUILDERS, "expert", _expert_step)
+    names = scope.op_names_of(_step_hlo("expert"))
+    for s in phases.MOE_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+        # under ps.grad: scope.py counts them as forward or backward
+        assert {scope.phase_of(n)[0] for n in under} == {"forward",
+                                                         "backward"}, s
+    found = {moe.scope_of(own, n) for own, n in names.items()}
+    assert found == set(moe.MOE_SCOPES) | {None}
+    # XLA:TPU's grouped matmuls carry no op_name: taken by their own name
+    assert moe.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
+        == moe.MOE_EXPERT
+    assert moe.scope_of("%fusion.7", "jit(f)/ps.apply/mul") is None
+
+
+def test_moe_reader_on_a_hand_made_result():
+    ops = {_ev("%route"): 0.002, _ev("%sorted"): 0.001,
+           _ev("%back"): 0.003,
+           _ev("%ragged-dot-none.1", "custom-call") + (
+               'custom_call_target="tpu_custom_call"'): 0.020,
+           _ev("%flash", "custom-call") + (
+               'custom_call_target="tpu_custom_call"'): 0.010,
+           _ev("%qkv"): 0.004, _ev("%ce"): 0.006, _ev("%embed"): 0.0005,
+           _ev("%adam"): 0.007}
+    names = {"%route": "jit(f)/ps.grad/jvp(ps.moe/route)/dot_general",
+             "%sorted": "jit(f)/ps.grad/jvp(ps.moe/dispatch)/gather",
+             "%back": "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather",
+             "%ragged-dot-none.1": "ragged-dot-none",
+             "%flash": "jit(f)/ps.grad/jvp(ps.attn)/pallas_call",
+             "%qkv": "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general",
+             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
+             "%embed": "jit(f)/ps.grad/jvp()/gather",
+             "%adam": "jit(f)/ps.apply/mul"}
+    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
+         "facts": {"kernel_targets": ["tpu_custom_call"],
+                   "moe_expert_flops_per_step": 0.5e9,
+                   "moe_flash_flops": 1e9, "moe_flash_bytes": 1.0},
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12}}
+    out = moe.scope_times(r, names)
+    assert out["moe.route_ms"] == pytest.approx(1.0)
+    assert out["moe.dispatch_ms"] == pytest.approx(2.0)   # with combine
+    assert out["moe.expert_ms"] == pytest.approx(10.0)
+    assert out["moe.attn_ms"] == pytest.approx(7.0)
+    assert out["moe.head_ms"] == pytest.approx(3.0)
+    assert out["moe.expert_mxu_share"] == pytest.approx(5.0)   # 0.5 of 10 ms
+    assert out["moe.flash_roofline"] == pytest.approx(20.0)    # 1 of 5 ms
+    # a program without the scopes and without the grouped matmuls
+    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
+    assert moe.scope_times(r, {}) == {}
 
 
 # -- scope.py on a hand-made result ------------------------------------------
@@ -422,20 +504,47 @@ def test_host_metrics_count_the_measured_window_only(monkeypatch):
     assert host.window_of(r) is None   # not under benchmark/run.py
 
 
-def test_benchmark_command_rehearses_the_host_metrics():
-    """The benchmark's own command on the CPU: a traced rehearsal of the
-    Wide&Deep cell lists the ``host.*`` metrics the cell is to report."""
+def _rehearse(cell):
+    """The benchmark's own command on the CPU: the result line of a traced
+    rehearsal of ``cell``."""
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
                         "JAX_COMPILATION_CACHE_DIR")}
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "benchmark", "run.py"),
-         "--workload", "widedeep-criteo.b4096.zipf", "--rehearse",
-         "--trace", "1", "--seconds", "2"],
+         "--workload", cell, "--rehearse", "--trace", "1", "--seconds", "1"],
         env=env, cwd=_REPO, capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] and line["metrics"] == {}
+    return line
+
+
+@pytest.mark.parametrize("cell", ["olmoe-1b-7b.s4096.zipf",
+                                  "bert-base.s128.full"])
+def test_benchmark_command_rehearses_the_new_cells(cell):
+    """PR 28's cells: the OLMoE cell lists every ``moe.*`` metric, the BERT
+    cell without the kernel lists none of another cell's."""
+    line = _rehearse(cell)
+    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    expert = {m["name"] for m in manifest["per_layer"]
+              if m["name"].startswith("moe.")}
+    assert len(expert) == 10
+    assert all(m.get("workloads") == ["olmoe-1b-7b.s4096.zipf"]
+               for m in manifest["per_layer"] if m["name"] in expert)
+    if cell.startswith("olmoe"):
+        assert expert <= set(line["rehearsed"])
+    else:
+        assert not expert & set(line["rehearsed"])
+        assert {"entry.compile_s", "loop.dispatch_ms"} <= set(
+            line["rehearsed"])
+
+
+def test_benchmark_command_rehearses_the_host_metrics():
+    """A traced rehearsal of the Wide&Deep cell lists the ``host.*`` metrics
+    the cell is to report."""
+    line = _rehearse("widedeep-criteo.b4096.zipf")
     with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
         listed = {m["name"] for m in json.load(f)["per_layer"]
                   if m["name"].startswith("host.")

@@ -218,3 +218,20 @@ def test_measure_flops_smoke():
     out = _run("measure_flops.py", "widedeep")
     assert out["model"] == "widedeep"
     assert out["slope_per_example"] > 0 and out["const_per_step"] > 0
+
+
+def test_olmoe_grad_check_rehearses():
+    """tools/olmoe_grad_check.py at the configuration's tiny sizes: the
+    system's gradients are the reference's, and every knocked-out piece
+    moves the reference's loss or turns a witness's gradient."""
+    out = _run("olmoe_grad_check.py", "--rehearse")
+    assert out["worst"]["cosine"] > 1 - 1e-9
+    assert out["loss"]["rel_diff"] < 1e-5
+    assert out["pairs_on_another_expert"] == 0
+    assert set(out["knocked_out"]) == {
+        "renormalised_top_k", "no_qk_norm", "no_load_balance_term",
+        "no_z_loss_term", "reference_on_e4m3_weights"}
+    for name, found in out["knocked_out"].items():
+        cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
+        assert len(cosines) == 2
+        assert found["loss_rel_diff"] > 5e-5 or min(cosines) < 0.999, name
