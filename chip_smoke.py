@@ -237,8 +237,8 @@ def flash_parity(*, batch=32, seq=512):
     """The Pallas kernel against the einsum attention of models/bert.py:
     the same SelfAttention parameters and seeded input through both ``attn``
     settings, forward and gradients, under a padding mask that really pads.
-    Then the kernel at sequence-wide blocks against itself at the default
-    128."""
+    Then the kernel at the tiles it chooses from its shapes against itself
+    at forced 128-wide blocks."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -282,18 +282,23 @@ def flash_parity(*, batch=32, seq=512):
                  f"flash {name} differs from the einsum path by "
                  f"{err / (_BF16_U * scale):.1f} u x max|ref| (bound 8)")
 
-    # blocks above 128 (flash_attention.py's docstring): same math, other
-    # tiling, so only the f32 accumulation order and the bf16 rounding of
-    # the probabilities differ — 2 u of the largest entry
+    # the tiles forward_tiles chooses from the shapes (sequence-wide here)
+    # against forced 128-wide blocks: same math, other tiling, so only the
+    # f32 accumulation order and the bf16 rounding of the probabilities
+    # differ — 2 u of the largest entry
+    from ps_tpu.ops.flash_attention import forward_tiles
+
     q, k, v = (jnp.asarray(rng.standard_normal((batch, seq, 12, 64)),
                            jnp.bfloat16) for _ in range(3))
-    small = flash_attention(q, k, v, mask=mask)
-    big = flash_attention(q, k, v, mask=mask, block_q=seq, block_k=seq)
-    err, scale = _max_diff(big, small)
-    print(f"[{leg}] flash at {seq}-wide blocks vs 128: max|diff| {err:.3e} "
-          f"= {err / (_BF16_U * scale):.2f} u x max|ref|")
+    tiles = forward_tiles(seq, 64, q.dtype.itemsize, False)
+    chosen = flash_attention(q, k, v, mask=mask)
+    narrow = flash_attention(q, k, v, mask=mask, block_q=128, block_k=128)
+    err, scale = _max_diff(chosen, narrow)
+    print(f"[{leg}] flash at its chosen {tiles} blocks vs 128-wide: "
+          f"max|diff| {err:.3e} = {err / (_BF16_U * scale):.2f} u x max|ref|")
     _require(err <= 2 * _BF16_U * scale,
-             f"flash at {seq}-wide blocks differs from 128-wide blocks")
+             f"flash at its chosen {tiles} blocks differs from 128-wide "
+             f"blocks")
 
 
 def bert_leg(compiles, *, seq_len=512, per_chip_batch=32, num_layers=12,

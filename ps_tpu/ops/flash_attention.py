@@ -10,37 +10,65 @@ and an [S] logsumexp per (batch, head) — O(S) memory, same math.
 
 Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
 
-- FORWARD is the Pallas kernel: 3D grid (B*h, S/block_q, S/block_k) with
-  the key-block axis INNERMOST, so the running (max, sum, accumulator)
-  VMEM scratch persists across a query block's key steps while Mosaic
-  stages the next key block's [block_k, d] K/V DMA. Dots run in the
-  input dtype (bf16 on the MXU) with f32 accumulation. Causal masking
-  skips the compute of key blocks fully past the diagonal via pl.when
-  (their DMA still happens). Outputs: attention out and the logsumexp
-  rows.
+- FORWARD is the Pallas kernel: 3D grid (B*h, S/block_q, S/block_k),
+  ("parallel", "parallel", "arbitrary"), with the key-block axis INNERMOST,
+  so the running (max, sum, accumulator) VMEM scratch persists across a
+  query block's key steps while Mosaic stages the next key block's
+  [block_k, d] K/V DMA. Dots run in the input dtype (bf16 on the MXU) with
+  f32 accumulation. With one key block the step softmaxes whole rows and
+  the kernel has no scratch and no rescaling. Causal masking skips the
+  compute of key blocks fully past the diagonal via pl.when, and their
+  K/V and mask index maps stop at the last live block (``_last_live``, the
+  same expression as the skip), so a dead step copies nothing. Outputs:
+  attention out and the logsumexp rows.
+- The TILES are ``forward_tiles``' choice from (seq, head_dim, itemsize,
+  causal): among the divisors of seq that are multiples of 128, the widest
+  key block and then the widest query block whose grid step fits
+  ``_VMEM_BUDGET`` (13 MiB by ``forward_vmem_bytes``' count, of Mosaic's
+  16 MiB scoped default: the widest tile the rule can reach counts 12.9 MiB
+  and compiles; one counting 21.6 MiB is refused), a causal call keeping
+  block_k <= block_q. Key block first because a step's cost is mostly per
+  query row (the carry's read-modify-write, the lane reductions), whatever
+  the key width: at equal tile area (128, 512) takes 0.99 ms where
+  (256, 256) takes 1.68 and (512, 128) 2.75 (BERT's shape, below). Causal
+  block_k <= block_q because a key block wider than the query block
+  computes scores the mask throws away: (1024, 1024) 1.48 ms, (512, 2048)
+  1.80, (256, 4096) 2.43. ``block_q=`` / ``block_k=`` override the choice.
 - BACKWARD is a custom VJP in blockwise JAX (Rabe & Staats style): exact
   probabilities are recomputed per key block from the saved logsumexp —
   never the full [S, S] — inside a lax.scan that accumulates dq and emits
   per-block dk/dv. XLA fuses each block's four matmuls; peak memory is
-  O(S · block_k) per (b, h).
+  O(S · 128) per (b, h): the scan's key block is ``_BWD_BLOCK_K``, its own
+  constant, whatever tile the forward ran at.
 
 The padding mask is a [B, S] int/bool array (1 = attend), matching the
 BERT convention; causal and mask compose. Numerics: parity with the
 reference einsum attention is asserted to ~1e-5 f32 in
-tests/test_flash_attention.py (CPU interpret mode runs the same kernel).
+tests/test_flash_attention.py (CPU interpret mode runs the same kernel),
+at the chosen tiles and at forced ones.
 
-**On the current machine (TPU v5e, jax 0.9.0 / libtpu 0.0.34; PR 21's
-chip_smoke.py, single runs)**: the kernel compiles through Mosaic at the
-default 128-wide blocks and at 512-wide ones (the 128 ceiling BASELINE.md
-r5 recorded belonged to an older compiler), matches the einsum attention
-of models/bert.py at [32, 512, 12, 64] bf16 within 2 bf16 roundoffs of the
-largest entry, forward and gradients, and carries BERT-base seq 512 through
-``make_step`` on one chip and, inside ``shard_map``, on four. Its speed
-against XLA's fused attention is not measured on the current machine
-(ROADMAP.md S2 decides; BASELINE.md r5's 10.9 vs 17.6 ms/call predate it).
-What flash delivers regardless is the O(S) attention memory, so the default
-everywhere stays 'full'; switch to 'flash' when sequence length — not
-arithmetic — is the binding constraint.
+**Measured (TPU v5e, jax 0.9.0 / libtpu 0.0.34; my chip runs, PR 29: the
+forward call alone, bf16, median of 5 chains of 16 calls)**, against the
+128 x 128 tiles every call ran at before:
+
+| shape [B*h, S, d] | tiles, grid | ms a call | before | of its roofline |
+|---|---|---|---|---|
+| [384, 512, 64], BERT's padding mask | (512, 512), (384, 1, 1) | 0.539 | 4.672 | 24% (0.131 ms of MXU) |
+| [32, 4096, 128], causal | (1024, 1024), (32, 4, 4), 10 of 16 steps live | 1.481 | 15.563 | 47% (0.70 ms) |
+
+Both agree with an f32 einsum attention within 0.6 bf16 roundoffs of the
+largest entry at every tile tried; inside the fused step the traces read
+0.50 and 1.48 ms. A grid step costs about 0.4 us before it computes
+anything, 0.15 ms of BERT's call: blocking several heads into one step
+would return at most that and was not built. Measured and left for the
+issue that writes the backward, whose kernel decides the residual's
+layout: the logsumexp as a [BH, 1, S] row (one in-kernel transpose) in
+place of the [BH, S, 1] column, which HBM pads to 128 lanes (100 MB a call
+at BERT's shape) and XLA re-lays out afterwards: 0.29 ms a BERT layer,
+values bit-identical (PERF.md section 7). The kernel compiles through
+Mosaic inside ``shard_map`` on four chips as on one (PR 21). What flash
+delivers besides is the O(S) attention memory; the default everywhere
+stays 'full', and ROADMAP.md D4 holds the comparison at seq 512.
 """
 
 from __future__ import annotations
@@ -59,51 +87,96 @@ from ps_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 _NEG_INF = -1e30
 
+# Key block of the backward's lax.scan. Its own constant, not the forward's
+# tile: at a sequence-wide block_k the scan would have one iteration and
+# materialise the [BH, S, S] score, probability and gradient tensors in f32.
+_BWD_BLOCK_K = 128
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *,
-                scale: float, causal: bool, block_k: int):
+# What one grid step may hold in VMEM by forward_vmem_bytes' count, of the
+# 16 MiB Mosaic scopes to a kernel on a v5e by default. The count leaves out
+# Mosaic's own temporaries (the iota and select masks, the bf16 copy of the
+# probabilities), hence the distance.
+_VMEM_BUDGET = 13 * 2 ** 20
+
+
+def forward_vmem_bytes(block_q: int, block_k: int, head_dim: int,
+                       itemsize: int) -> int:
+    """VMEM one forward grid step keeps live, as the kernel below lays it
+    out: q, k, v, mask, out and lse blocks double-buffered by the pipeline,
+    the running (max, sum, accumulator) scratch, and the f32 score and
+    probability tiles. The minor dimension is padded to the 128 lanes."""
+    lanes = -(-head_dim // 128) * 128
+    q_o = 2 * 2 * block_q * lanes * itemsize
+    k_v = 2 * 2 * block_k * lanes * itemsize
+    mask = 2 * 8 * block_k * 4          # [1, block_k] int32 on 8 sublanes
+    lse = 2 * block_q * 128 * 4         # [block_q, 1] f32 on 128 lanes
+    scratch = block_q * (2 * 128 + lanes) * 4
+    tiles = 2 * block_q * block_k * 4
+    return q_o + k_v + mask + lse + scratch + tiles
+
+
+def forward_tiles(seq: int, head_dim: int, itemsize: int,
+                  causal: bool) -> tuple[int, int]:
+    """(block_q, block_k) of the forward kernel, from the operands' shapes
+    alone: the widest key block, then the widest query block, among the
+    divisors of ``seq`` that are multiples of 128 and keep
+    ``forward_vmem_bytes`` within ``_VMEM_BUDGET``. A causal call keeps
+    block_k <= block_q, so that a query block's diagonal tile, the one that
+    computes masked scores, is no wider than the block itself."""
+    if seq % 128:
+        raise ValueError(
+            f"seq len {seq} must be divisible by 128 (pad the sequence)")
+    sizes = [b for b in range(seq, 0, -128) if seq % b == 0]
+    for block_k in sizes:
+        for block_q in sizes:
+            if causal and block_k > block_q:
+                continue
+            if forward_vmem_bytes(block_q, block_k, head_dim,
+                                  itemsize) <= _VMEM_BUDGET:
+                return block_q, block_k
+    raise ValueError(
+        f"no forward tile fits {_VMEM_BUDGET} B of VMEM at head_dim "
+        f"{head_dim}, itemsize {itemsize}")
+
+
+def _last_live(qi, block_q: int, block_k: int):
+    """The last key block a causal query block ``qi`` can see: the one
+    that holds the key position of the block's last row."""
+    return ((qi + 1) * block_q - 1) // block_k
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
+                scale: float, causal: bool):
     """One (batch·head, q-block, kv-block) grid step. The kv dimension is
     the INNERMOST grid axis, so the (m, l, acc) VMEM scratch persists
     across a q-block's kv steps while Mosaic pipelines the next kv
     block's DMA behind this step's MXU work — the canonical flash
     structure. Dots run in the input dtype (bf16 on the MXU) with f32
-    accumulation via preferred_element_type."""
-    block_q = q_ref.shape[0]
+    accumulation via preferred_element_type. With one kv block there is
+    no carry: ``_flash_fwd`` passes no scratch, and the step softmaxes its
+    rows whole and writes them."""
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
     qi = pl.program_id(1)
     j = pl.program_id(2)
-    num_k = pl.num_programs(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # causal: key blocks fully past this q block's diagonal contribute
-    # nothing — skip their compute (their DMA still happens; acceptable)
-    live = (j * block_k <= (qi + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _step():
+    def fold(m, l, acc):
+        """This key block folded into the running (max, sum, accumulator)."""
         s = jax.lax.dot_general(
             q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k] f32
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
         if causal:
+            kpos = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
             qpos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
             )
             s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        # padding mask: column-broadcast of this block's key validity
-        # (mask ref is [block_k, 1] — the trailing 1 satisfies TPU tiling)
-        valid = mask_ref[:].astype(jnp.int32)
-        s = jnp.where(valid.reshape(1, block_k) > 0, s, _NEG_INF)
+        # padding mask: this block's key validity as a [1, block_k] row,
+        # broadcast over the query rows
+        s = jnp.where(mask_ref[:] > 0, s, _NEG_INF)
 
-        m = m_scr[:, :1]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         # gate, don't trust exp: on a fully-masked row m_new is _NEG_INF
@@ -112,21 +185,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         # and makes the finalize zero-guard real (and consistent with the
         # backward's identical gate)
         p = jnp.where(s > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-        l_scr[:, :1] = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1,
-                                                      keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_scr[:, :1] = m_new
+        return m_new, l_new, acc_new
 
-    @pl.when(j == num_k - 1)
-    def _finalize():
-        l = l_scr[:, :1]
+    def write(m, l, acc):
         # fully-masked rows (all-pad keys) have l == 0: zeros, not NaN
         safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[:] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        lse_ref[:] = m_scr[:, :1] + jnp.log(safe_l)  # [block_q, 1]
+        o_ref[:] = (acc / safe_l).astype(o_ref.dtype)
+        lse_ref[:] = m + jnp.log(safe_l)  # [block_q, 1]
+
+    if not scratch:
+        write(*fold(_NEG_INF, 0.0, 0.0))
+        return
+    m_scr, l_scr, acc_scr = scratch
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # causal: key blocks fully past this q block's diagonal contribute
+    # nothing. Their compute is skipped here and their DMA in _flash_fwd,
+    # whose index maps stop at the same _last_live block.
+    live = (j <= _last_live(qi, block_q, block_k)) if causal else True
+
+    @pl.when(live)
+    def _step():
+        m, l, acc = fold(m_scr[:, :1], l_scr[:, :1], acc_scr[:])
+        m_scr[:, :1] = m
+        l_scr[:, :1] = l
+        acc_scr[:] = acc
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        write(m_scr[:, :1], l_scr[:, :1], acc_scr[:])
 
 
 def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
@@ -135,22 +232,31 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
     bh, seq, d = q.shape
     b = mask.shape[0]
     heads = bh // b
-    grid = (bh, seq // block_q, seq // block_k)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_k=block_k
-    )
+    num_k = seq // block_k
+
+    def kv_block(i, j):
+        if not causal:
+            return j
+        # a step past the diagonal names the block the step before it held,
+        # and the pipeline copies nothing for an index that stays
+        return jnp.minimum(j, _last_live(i, block_q, block_k))
+
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, seq // block_q, num_k),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh_, i, j: (bh_, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda bh_, i, j: (bh_, j, 0),
+            pl.BlockSpec((None, block_k, d),
+                         lambda bh_, i, j: (bh_, kv_block(i, j), 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda bh_, i, j: (bh_, j, 0),
+            pl.BlockSpec((None, block_k, d),
+                         lambda bh_, i, j: (bh_, kv_block(i, j), 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, 1),
-                         lambda bh_, i, j: (bh_ // heads, j, 0),
+            # [B, 1, S]: keys along the lanes, as the score tile has them
+            pl.BlockSpec((None, 1, block_k),
+                         lambda bh_, i, j: (bh_ // heads, 0, kv_block(i, j)),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -165,13 +271,16 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if num_k == 1 else [
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
+        # the scratch carries across the kv axis only
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, mask[..., None])
+    )(q, k, v, mask.astype(jnp.int32)[:, None, :])
     return out, lse[..., 0]
 
 
@@ -242,7 +351,8 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, mask, out, lse = res
     heads = q.shape[0] // mask.shape[0]
     dq, dk, dv = _blockwise_bwd(q, k, v, mask, out, lse, do, scale=scale,
-                                causal=causal, block_k=block_k, heads=heads)
+                                causal=causal, block_k=_BWD_BLOCK_K,
+                                heads=heads)
     return dq, dk, dv, None
 
 
@@ -250,17 +360,21 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
-                    causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None,
+                    causal: bool = False, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    interpret: Optional[bool] = None,
                     mesh: Optional[Mesh] = None) -> jax.Array:
     """Fused flash attention. ``q/k/v``: [B, S, h, d] (the model-side
     layout of ps_tpu/models/{bert,lm}.py); ``mask``: optional [B, S] with
     1 = attend (BERT padding convention); ``causal`` composes with it.
     Returns [B, S, h, d].
 
-    ``interpret`` defaults to True off-TPU so tests exercise the same
-    kernel logic on CPU. Sequence length must be divisible by the block
-    sizes (pad to 128 — XLA-side attention pads the same way in practice).
+    ``block_q`` / ``block_k`` tile the forward kernel; left at None they
+    are ``forward_tiles``' choice from the operands' shapes. ``interpret``
+    defaults to True off-TPU so tests exercise the same kernel logic on
+    CPU. Sequence length must be divisible by 128, the backward's key
+    block, and by the forward's blocks (pad to 128 — XLA-side attention
+    pads the same way in practice).
 
     ``mesh`` defaults to the one ``ps_tpu.init`` built, if any. Under a
     mesh the kernel runs inside ``shard_map`` — batch over 'data', heads
@@ -270,10 +384,13 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     dimension its axis does not divide is computed replicated.
     """
     b, seq, h, d = q.shape
-    if seq % block_q or seq % block_k:
+    if block_q is None or block_k is None:
+        chosen = forward_tiles(seq, d, q.dtype.itemsize, causal)
+        block_q, block_k = block_q or chosen[0], block_k or chosen[1]
+    if seq % block_q or seq % block_k or seq % _BWD_BLOCK_K:
         raise ValueError(
-            f"seq len {seq} must be divisible by block_q={block_q} and "
-            f"block_k={block_k} (pad the sequence)"
+            f"seq len {seq} must be divisible by block_q={block_q}, "
+            f"block_k={block_k} and {_BWD_BLOCK_K} (pad the sequence)"
         )
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"

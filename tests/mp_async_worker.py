@@ -102,6 +102,24 @@ def run_server(port: int, out_dir: str, nworkers: int, cycles: int,
     return 0
 
 
+def _start_line(out_dir: str, worker: int) -> None:
+    """Hold this worker until all ``$MP_ASYNC_START_LINE`` workers of the
+    test have connected. Process start-up (importing jax) skews by seconds
+    on a loaded host while a worker's cycles last a tenth of one, so
+    without a common start the pushes of a late worker meet nobody else's
+    and no staleness accrues. Unset: no line (the partition tests)."""
+    n = int(os.environ.get("MP_ASYNC_START_LINE", "0"))
+    if not n:
+        return
+    open(os.path.join(out_dir, f"ready{worker}"), "w").close()
+    deadline = time.monotonic() + 120
+    while sum(f.startswith("ready") for f in os.listdir(out_dir)) < n:
+        if time.monotonic() > deadline:
+            raise TimeoutError(f"worker {worker}: fewer than {n} workers "
+                               f"reached the start line in 120 s")
+        time.sleep(0.001)
+
+
 def run_worker(ports: str, out_dir: str, worker: int, cycles: int) -> int:
     import jax
 
@@ -113,6 +131,7 @@ def run_worker(ports: str, out_dir: str, worker: int, cycles: int) -> int:
     uri = ",".join(f"127.0.0.1:{p}" for p in ports.split(","))
     w = connect_async(uri, worker, params)
     versions = []
+    _start_line(out_dir, worker)
     w.pull_all()
     for c in range(cycles):
         # jitter so the three workers' pushes interleave (staleness > 0)

@@ -38,6 +38,8 @@ def _spawn(role, port, out_dir, a, b):
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
+    # the workers start their cycles together (mp_async_worker._start_line)
+    env["MP_ASYNC_START_LINE"] = str(NWORKERS)
     return subprocess.Popen(
         [sys.executable, _WORKER, role, str(port), str(out_dir),
          str(a), str(b)],
