@@ -1,27 +1,14 @@
-"""Driver benchmark entrypoint — prints ONE JSON line.
+"""CPU host-plane drills — not the benchmark.
 
-Headline metric (BASELINE.json): ResNet-50 images/sec/chip, sync
-data-parallel PS step (fused psum + sharded server apply) on whatever
-devices are visible — the real TPU chip under the driver, virtual/CPU
-devices elsewhere. The JSON carries the full metric line the baseline
-names: throughput, MFU against the detected chip peak (flops from XLA HLO
-cost analysis), push/pull + ICI GB/s from the collective-bytes algebra, and
-the final loss (loss-curve parity itself is asserted by
-tests/test_mnist_parity.py and tests/test_resnet.py).
-
-``--model bert`` benches BERT-base MLM with server-side LAMB (reference
-workload config 3 — the MXU-bound workload) and ``--model widedeep`` the
-sparse composite step (config 4); both follow the same policy as resnet:
-pre-placed batches, two timed repetitions, best-of, identical JSON shape.
-
-``vs_baseline`` is null because the reference publishes no numbers
-(BASELINE.json ``"published": {}``; see BASELINE.md — which also records the
-r3 profiler-trace characterization the resnet ``note`` summarizes).
-
-Modes: default pre-places a few batches and cycles them (pure device-step
-metric). ``--streaming`` (resnet only) feeds every step through the 2-deep
-host→device prefetch (ps_tpu/data/prefetch.py) — the number real trainers
-see; the gap between the two is the input-path cost.
+The benchmark is ``benchmark/run.py`` over ``BENCHMARK.json`` (PERF.md): it
+alone runs the models on the chip and reports device metrics. This file
+keeps the drills of the host plane that ``tools/ci_bench_smoke.sh`` and
+``tests/test_tools.py`` run on sandbox CPUs, each printing ONE JSON line in
+the shape of ``_emit``: the van's data plane (``transport``, ``serve``,
+``online``), replication and membership (``failover``, ``rebalance``,
+``chaos``) and two in-process sparse A/Bs (``sparse_apply``, ``tiered``).
+Every figure they print is a host count of the machine they ran on and is
+never a device metric (README "Running on the chip").
 """
 
 from __future__ import annotations
@@ -37,46 +24,7 @@ import jax
 import jax.numpy as jnp
 
 import ps_tpu as ps
-from ps_tpu.parallel.sharding import replicated
 from ps_tpu.utils.chips import peak_bf16_tflops
-from ps_tpu.utils.metrics import TrainMetrics
-
-# HLO cost analyses of THE fused steps at the bench shapes (batch-axis
-# slope + constant, measured on the CPU backend where pre-compile cost
-# analysis is available; resnet derivation in BASELINE.md §r3, bert/widedeep
-# in §r5). Used only when the live platform's lowering returns no analysis
-# AND the shapes are the TPU defaults below. That is every TPU run today:
-# jax 0.9.0 implements Lowered.cost_analysis on no PJRT C-API backend, and
-# chip_smoke.py saw "no flops" from it on the TPU v5e (PR 21).
-_FLOPS_RESNET_IMAGE_224 = 23.745e9
-_FLOPS_RESNET_CONST = 0.154e9   # per-step optimizer/loss constant
-# tools/measure_flops.py bert @ bs {8,16}, seq 128, bf16, LAMB (post the
-# r5 logsumexp-CE rewrite):
-# flops = 85.763e9 * batch + 3.061e9 (6*N*T sanity: 6*110e6*128 = 84.5e9 ✓)
-_FLOPS_BERT_SEQ_128 = 85.763407872e9
-_FLOPS_BERT_CONST = 3.060924416e9
-# same derivation @ bs {4,8}, seq 512, post-rewrite (the attention-
-# quadratic term shows: 4x tokens -> 4.26x flops)
-_FLOPS_BERT_SEQ_512 = 365.279281152e9
-_FLOPS_BERT_512_CONST = 3.044016128e9
-# tools/measure_flops.py widedeep @ bs {8,16}, vocab 100k x 26, dim 16:
-# flops = 909520 * batch + 220.37e6 (const = full-table optimizer scan)
-_FLOPS_WD_EXAMPLE = 909520.0
-_FLOPS_WD_CONST = 220.36656e6
-
-
-def _flops_per_step(run, batch, extra, batch_size: int, slope, const,
-                    shapes_match: bool):
-    """(flops, source) — live HLO analysis, or the measured CPU constant."""
-    try:
-        ca = run.cost_analysis(batch, *extra)
-    except Exception:
-        ca = None
-    if ca and ca.get("flops"):
-        return float(ca["flops"]), "hlo_cost_analysis"
-    if shapes_match and slope is not None:
-        return slope * batch_size + (const or 0.0), "measured_cpu_hlo"
-    return None, None
 
 
 def _emit(metric: str, per_chip_rate: float, unit: str, *, ndev, dev,
@@ -116,242 +64,6 @@ def _emit(metric: str, per_chip_rate: float, unit: str, *, ndev, dev,
         "vs_baseline": None,
         "detail": detail,
     }))
-
-
-def _timed_loop(run, batches, steps, metrics, *, extra_state=None):
-    """Warmup (compile, and the recompile bench_resnet notes) then ONE timed
-    rep over pre-placed batches; returns (dt, loss, final_extra_state)."""
-    warmup = 2
-    t0 = None
-    state = extra_state
-    for step in range(steps + warmup):
-        b = batches[step % len(batches)]
-        if state is not None:
-            loss, _, state = run(b, state)
-        else:
-            out = run(b)
-            loss = out[0] if isinstance(out, tuple) else out
-        if step == warmup - 1:
-            loss.block_until_ready()
-            if metrics is not None:
-                metrics.mark_compiled()
-            t0 = time.time()
-        elif step >= warmup and metrics is not None:
-            metrics.step(loss)
-    loss.block_until_ready()
-    return max(time.time() - t0, 1e-9), loss, state
-
-
-def _second_rep(run, batches, steps, done, *, extra_state=None):
-    """The second timed repetition (best-of policy). ``done`` blocks on the
-    store's final params."""
-    state = extra_state
-    t1 = time.time()
-    for step in range(steps):
-        b = batches[step % len(batches)]
-        if state is not None:
-            loss, _, state = run(b, state)
-        else:
-            out = run(b)
-            loss = out[0] if isinstance(out, tuple) else out
-    loss.block_until_ready()
-    done()
-    return round(max(time.time() - t1, 1e-9), 4)
-
-
-# -- resnet -------------------------------------------------------------------
-
-
-def bench_resnet(args):
-    from ps_tpu.data.prefetch import device_prefetch
-    from ps_tpu.data.synthetic import imagenet_batches
-    from ps_tpu.models.resnet import ResNet50, make_loss_fn
-
-    steps, per_chip_batch, image_size = args.steps, args.per_chip_batch, args.image_size
-    ndev = len(jax.devices())
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    if not on_tpu:
-        # keep CPU smoke runs tractable
-        per_chip_batch, image_size, steps = 8, 64, 4
-    batch_size = per_chip_batch * ndev
-
-    ctx = ps.init(backend="tpu")
-    model = ResNet50(dtype=jnp.bfloat16 if on_tpu else jnp.float32)
-    variables = model.init(
-        jax.random.key(0), jnp.zeros((2, image_size, image_size, 3)), train=False
-    )
-    params, model_state = variables["params"], variables["batch_stats"]
-    model_state = jax.device_put(model_state, replicated(ctx.mesh))
-
-    store = ps.KVStore(optimizer="momentum", learning_rate=0.1, momentum=0.9,
-                       placement="sharded" if ndev > 1 else "replicated")
-    store.init(params)
-
-    run = store.make_step(make_loss_fn(model, label_smoothing=0.1), has_aux=True)
-    metrics = TrainMetrics(store, batch_size=batch_size, num_chips=ndev)
-
-    # step 0 compiles. On more than one device step 1 compiles again: the
-    # BN state goes in replicated and comes back sharded over 'data' by the
-    # partitioner's choice, so step 1 is a new input sharding (seen by
-    # chip_smoke.py on four chips, PR 21). On one device step 1 reuses step 0.
-    warmup = 2
-    if args.streaming:
-        stream = device_prefetch(
-            imagenet_batches(batch_size, image_size=image_size,
-                             steps=steps + warmup),
-            place=store.shard_batch,
-        )
-        t0 = None
-        batch = None
-        for step in range(steps + warmup):
-            batch = next(stream)
-            loss, _, model_state = run(batch, model_state)
-            if step == warmup - 1:
-                loss.block_until_ready()
-                metrics.mark_compiled()
-                t0 = time.time()
-            if step >= warmup:
-                metrics.step(loss)
-        loss.block_until_ready()
-        jax.block_until_ready(store.params())
-        dt = max(time.time() - t0, 1e-9)
-        rep_times = [round(dt, 4)]
-    else:
-        # Pre-generate and pre-place a few distinct batches: the default
-        # metric is the device step (fused psum + sharded apply), not host
-        # RNG / host->device transfer; --streaming measures the full path.
-        batches = [
-            store.shard_batch(b) for b in imagenet_batches(
-                batch_size, image_size=image_size, steps=min(steps, 3)
-            )
-        ]
-        jax.block_until_ready(batches)
-        dt, loss, model_state = _timed_loop(run, batches, steps, metrics,
-                                            extra_state=model_state)
-        jax.block_until_ready(store.params())
-        rep_times = [round(dt, 4)]
-        # anchor everything that DESCRIBES the run (loss, GB/s window) to
-        # the first repetition — the extra timing rep below must not skew
-        summary = metrics.summary()
-        final_loss = round(float(loss), 4)
-        rep_times.append(_second_rep(
-            run, batches, steps,
-            lambda: jax.block_until_ready(store.params()),
-            extra_state=model_state,
-        ))
-        dt = min(rep_times)
-        batch = batches[0]
-
-    if args.streaming:
-        summary = metrics.summary()
-        final_loss = round(float(loss), 4)
-    if on_tpu:
-        flops, flops_src = _flops_per_step(
-            run, batch, (model_state,), batch_size,
-            _FLOPS_RESNET_IMAGE_224, _FLOPS_RESNET_CONST,
-            shapes_match=(image_size == 224),
-        )
-    else:
-        flops, flops_src = None, None  # CPU smoke: skip the extra trace
-    _emit(
-        "resnet50_images_per_sec_per_chip",
-        steps * batch_size / dt / ndev, "images/sec/chip",
-        ndev=ndev, dev=dev, batch_size=batch_size, timed_steps=steps,
-        rep_times=rep_times,
-        input_mode="streaming_prefetch" if args.streaming else "preplaced",
-        loss=final_loss, flops=flops, flops_src=flops_src,
-        dt=dt, summary=summary,
-        extra_detail={"image_size": image_size},
-        note=(
-            "r3 trace (BASELINE.md): every top op HBM-bound at 630-770 "
-            "GB/s of the v5e's 819 GB/s peak — top sinks: bwd convs "
-            "(~45%), residual adds, select_and_scatter (maxpool bwd); "
-            "roofline caps MFU near 30% for this model on this chip. "
-            "reference published no numbers (BASELINE.json published={})"
-        ),
-    )
-
-
-# -- bert ---------------------------------------------------------------------
-
-
-def bench_bert(args):
-    from ps_tpu.data.synthetic import mlm_batches
-    from ps_tpu.models.bert import BertConfig, BertMLM, make_mlm_loss_fn
-
-    steps, per_chip_batch, seq_len = args.steps, args.per_chip_batch, args.seq_len
-    ndev = len(jax.devices())
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    if not on_tpu:
-        per_chip_batch, seq_len, steps = 4, 64, 4
-    batch_size = per_chip_batch * ndev
-
-    ps.init(backend="tpu")
-    cfg = (BertConfig(dtype=jnp.bfloat16, attn=args.attn) if on_tpu
-           else BertConfig.tiny())
-    model = BertMLM(cfg)
-    shape = (2, seq_len)
-    params = model.init(
-        jax.random.key(0),
-        jnp.zeros(shape, jnp.int32), jnp.ones(shape, jnp.int32),
-    )["params"]
-
-    store = ps.KVStore(optimizer="lamb", learning_rate=1e-3,
-                       weight_decay=0.01,
-                       placement="sharded" if ndev > 1 else "replicated")
-    store.init(params)
-    run = store.make_step(make_mlm_loss_fn(model))
-    metrics = TrainMetrics(store, batch_size=batch_size, num_chips=ndev)
-
-    batches = [
-        store.shard_batch(b)
-        for b in mlm_batches(batch_size, seq_len, vocab_size=cfg.vocab_size,
-                             steps=min(steps, 3))
-    ]
-    jax.block_until_ready(batches)
-    dt, loss, _ = _timed_loop(run, batches, steps, metrics)
-    jax.block_until_ready(store.params())
-    rep_times = [round(dt, 4)]
-    # first-rep anchoring, as in bench_resnet
-    summary = metrics.summary()
-    final_loss = round(float(loss), 4)
-    rep_times.append(_second_rep(
-        run, batches, steps, lambda: jax.block_until_ready(store.params())
-    ))
-    dt = min(rep_times)
-
-    if on_tpu:
-        slope, const = {
-            128: (_FLOPS_BERT_SEQ_128, _FLOPS_BERT_CONST),
-            512: (_FLOPS_BERT_SEQ_512, _FLOPS_BERT_512_CONST),
-        }.get(seq_len, (None, None))
-        flops, flops_src = _flops_per_step(
-            run, batches[0], (), batch_size, slope, const,
-            shapes_match=slope is not None,
-        )
-    else:
-        flops, flops_src = None, None
-    _emit(
-        "bert_base_mlm_seqs_per_sec_per_chip",
-        steps * batch_size / dt / ndev, "seqs/sec/chip",
-        ndev=ndev, dev=dev, batch_size=batch_size, timed_steps=steps,
-        rep_times=rep_times, input_mode="preplaced",
-        loss=final_loss, flops=flops, flops_src=flops_src,
-        dt=dt, summary=summary,
-        extra_detail={
-            "seq_len": seq_len,
-            "attn": args.attn,
-            "tokens_per_sec_per_chip": round(
-                steps * batch_size * seq_len / dt / ndev, 1),
-        },
-        note=(
-            "BERT-base MLM, server-side LAMB as a sharded fused apply "
-            "(reference workload config 3). reference published no numbers "
-            "(BASELINE.json published={})"
-        ),
-    )
 
 
 # -- transport ----------------------------------------------------------------
@@ -3059,118 +2771,6 @@ def bench_chaos(args):
     print(json.dumps(out))
 
 
-# -- widedeep -----------------------------------------------------------------
-
-
-def bench_widedeep(args):
-    from ps_tpu.data.synthetic import criteo_batches
-    from ps_tpu.kv.sparse import SparseEmbedding
-    from ps_tpu.models.wide_deep import (
-        WideDeep, WideDeepConfig, make_ids_fn, make_wide_deep_loss_fn,
-    )
-    from ps_tpu.train import make_composite_step
-
-    steps, batch_size = args.steps, args.per_chip_batch
-    ndev = len(jax.devices())
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    vocab, dim = 100_000, 16
-    if not on_tpu:
-        batch_size, steps, vocab = 64, 4, 1000
-    batch_size *= ndev
-
-    ps.init(backend="tpu")
-    cfg = WideDeepConfig(per_feature_vocab=vocab, embed_dim=dim)
-    model = WideDeep(cfg)
-    batch0 = next(criteo_batches(2, vocab_size=cfg.per_feature_vocab))
-    rows_shape = (2, cfg.num_sparse, cfg.embed_dim)
-    params = model.init(
-        jax.random.key(0), jnp.asarray(batch0["dense"]),
-        jnp.zeros(rows_shape), jnp.zeros(rows_shape[:2] + (1,)),
-    )["params"]
-
-    dense = ps.KVStore(optimizer="adam", learning_rate=1e-3,
-                       placement="sharded" if ndev > 1 else "replicated")
-    dense.init(params)
-    deep = SparseEmbedding(cfg.total_rows, cfg.embed_dim,
-                           optimizer="adagrad", learning_rate=0.05)
-    deep.init(jax.random.key(1), scale=0.01)
-    wide = SparseEmbedding(cfg.total_rows, 1, optimizer="sgd",
-                           learning_rate=0.05)
-    wide.init(jax.random.key(2), scale=0.01)
-
-    run = make_composite_step(
-        dense, {"deep": deep, "wide": wide},
-        make_wide_deep_loss_fn(model), make_ids_fn(cfg),
-    )
-    metrics = TrainMetrics(dense, batch_size=batch_size, num_chips=ndev)
-    batches = [
-        dense.shard_batch(b)
-        for b in criteo_batches(batch_size, vocab_size=cfg.per_feature_vocab,
-                                steps=min(steps, 3))
-    ]
-    jax.block_until_ready(batches)
-    dt, loss, _ = _timed_loop(run, batches, steps, metrics)
-    jax.block_until_ready(dense.params())
-    rep_times = [round(dt, 4)]
-    # first-rep anchoring, as in bench_resnet. Row traffic is exactly
-    # linear per step (static shapes), so scale the total — which includes
-    # the 2 warmup steps — down to the timed window.
-    summary = metrics.summary()
-    final_loss = round(float(loss), 4)
-    total_row = (deep.bytes_pushed + deep.bytes_pulled
-                 + wide.bytes_pushed + wide.bytes_pulled)
-    row_gb = total_row * steps / (steps + 2) / 1e9
-    rep_times.append(_second_rep(
-        run, batches, steps, lambda: jax.block_until_ready(dense.params())
-    ))
-    dt = min(rep_times)
-
-    if on_tpu:
-        flops, flops_src = _flops_per_step(
-            run, batches[0], (), batch_size,
-            _FLOPS_WD_EXAMPLE, _FLOPS_WD_CONST, shapes_match=True,
-        )
-    else:
-        flops, flops_src = None, None
-    # sparse-apply trajectory (README "Sparse apply"): rows applied per
-    # second through whichever tier the tables resolved to, plus the
-    # analytic HBM bytes/apply under the gathered-slab vs full-table
-    # designs — so the fused-path claim is a recorded number per round,
-    # not a one-off log line (the focused A/B lives in --model sparse_apply)
-    from ps_tpu.ops.sparse_apply import hbm_bytes_model
-    rows_per_push = ((deep.rows_pushed + wide.rows_pushed)
-                     / max(deep.push_count, 1))
-    batch_rows = batch_size * cfg.num_sparse  # ids per push per table
-    _emit(
-        "widedeep_examples_per_sec_per_chip",
-        steps * batch_size / dt / ndev, "examples/sec/chip",
-        ndev=ndev, dev=dev, batch_size=batch_size, timed_steps=steps,
-        rep_times=rep_times, input_mode="preplaced",
-        loss=final_loss, flops=flops, flops_src=flops_src,
-        dt=dt, summary=summary,
-        extra_detail={
-            "embed_rows_total": cfg.total_rows,
-            "embed_dim": cfg.embed_dim,
-            "sparse_row_traffic_gb": round(row_gb, 4),
-            "sparse_apply": {
-                "tier": deep.fused_tier,
-                "rows_applied_per_s": round(
-                    rows_per_push * steps / dt, 1),
-                "hbm_bytes_per_apply": hbm_bytes_model(
-                    cfg.total_rows, cfg.embed_dim, batch_rows, deep._opt),
-            },
-        },
-        note=(
-            "Wide&Deep composite step: sharded-table row gather + dense "
-            "psum/apply + row-grad exchange + scatter-apply in ONE XLA "
-            "program (reference workload config 4). Embedding-bound: MFU "
-            "is not the figure of merit here — examples/s and row GB/s "
-            "are. reference published no numbers"
-        ),
-    )
-
-
 # -- sparse_apply -------------------------------------------------------------
 
 
@@ -3393,10 +2993,9 @@ def bench_tiered(args):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="resnet",
-                    choices=["resnet", "bert", "widedeep", "transport",
-                             "failover", "rebalance", "serve", "online",
-                             "sparse_apply", "tiered", "chaos"])
+    ap.add_argument("--model", required=True,
+                    choices=["transport", "failover", "rebalance", "serve",
+                             "online", "sparse_apply", "tiered", "chaos"])
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--transport-mb", type=float, default=96.0,
                     help="(transport) parameter-tree size for the van "
@@ -3430,35 +3029,17 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true",
                     help="(transport, chaos, online) <60s smoke: small "
                          "tree / short drills (tools/ci_bench_smoke.sh)")
-    ap.add_argument("--per-chip-batch", type=int, default=None)
-    ap.add_argument("--image-size", type=int, default=224)
-    ap.add_argument("--seq-len", type=int, default=128)
-    ap.add_argument("--attn", default="full", choices=["full", "flash"],
-                    help="(bert) attention op; 'flash' is the Pallas "
-                         "kernel — the memory regime's choice, see "
-                         "BASELINE.md")
     ap.add_argument("--table-mult", type=int, default=256,
                     help="(sparse_apply, tiered) table rows as a "
                          "multiple of the push id-set — both sparse "
                          "legs sweep the same table/batch shapes "
                          "(recorded in BENCH detail.table_mult)")
-    ap.add_argument("--streaming", action="store_true",
-                    help="(resnet) feed steps through the host->device "
-                         "prefetch instead of cycling pre-placed batches")
     args = ap.parse_args(argv)
-    if args.per_chip_batch is None:
-        args.per_chip_batch = {"resnet": 256, "bert": 128,
-                               "widedeep": 4096, "transport": 0,
-                               "failover": 0, "rebalance": 0,
-                               "serve": 0, "online": 0, "sparse_apply": 0,
-                               "tiered": 0, "chaos": 0}[args.model]
 
     if args.model == "transport" and args.fleet:
         bench_fleet(args)
         return
-    {"resnet": bench_resnet, "bert": bench_bert,
-     "widedeep": bench_widedeep,
-     "transport": bench_transport,
+    {"transport": bench_transport,
      "failover": bench_failover,
      "rebalance": bench_rebalance,
      "serve": bench_serve,

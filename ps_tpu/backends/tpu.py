@@ -388,6 +388,7 @@ class TpuServer(PeekMixin, CheckpointMixin):
         # analytic ICI traffic (bytes per device) accumulated across updates
         self.collective_bytes = 0
         self._apply_fn = None
+        self._update_bytes = 0  # per-device ICI bytes of one update
         self.apply_count = 0
 
     # -- registration -------------------------------------------------------
@@ -415,21 +416,23 @@ class TpuServer(PeekMixin, CheckpointMixin):
             rules=self.partition_rules,
         )
 
+        # what one update moves over ICI per device: a constant of the tree
+        k = self.num_workers
+        if self.placement == "replicated":
+            # grads were all-reduced across the data axis
+            self._update_bytes = collectives.allreduce_bytes(self._params, k)
+        else:
+            # reduce-scatter grads to owners + all-gather params for next fwd
+            self._update_bytes = (
+                collectives.reduce_scatter_bytes(self._params, k)
+                + collectives.all_gather_bytes(self._params, k))
+
         # No donation here: this apply backs the per-key/push_pull
         # compatibility path, whose callers may legitimately hold pulled
         # arrays across steps. The fused make_step path owns its buffers
         # exclusively and donates there instead (2x transient memory here is
         # the price of the compatibility semantics).
-        scale = self.grad_scale
-
-        @jax.jit
-        def apply_fn(params, state, grads):
-            if scale != 1.0:
-                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-            updates, new_state = self._opt.update(grads, state, params)
-            return optax.apply_updates(params, updates), new_state
-
-        self._apply_fn = apply_fn
+        self._apply_fn = jax.jit(self.apply_rule)
         from ps_tpu.kv import keys as keymod
 
         return keymod.unflatten(treedef, self._params, key_order)
@@ -447,6 +450,17 @@ class TpuServer(PeekMixin, CheckpointMixin):
         count when worker batches are equal — parity tested)."""
         return float(self.num_workers) if self.aggregate == "sum" else 1.0
 
+    def apply_rule(self, params, state, grads):
+        """The server's update rule, ``(params, state)`` after ``grads``:
+        scale, ``opt.update``, ``optax.apply_updates``. A pure function,
+        traced by the per-key path's jitted apply and, inside its
+        ``ps.apply`` scope, by the fused step (ps_tpu/kv/fused.py)."""
+        scale = self.grad_scale
+        if scale != 1.0:  # aggregate='sum' semantics
+            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+        updates, state = self._opt.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
     def update_tree(self, grads_kv: Dict[str, Any]) -> Dict[str, Any]:
         """One server step: aggregate(implicit) + apply; returns new params.
 
@@ -454,20 +468,9 @@ class TpuServer(PeekMixin, CheckpointMixin):
         batch — XLA already reduced them inside the caller's jitted grad
         computation, which is where the reference's NCCL+ZMQ push lived).
         """
-        self._params, self._state = self._apply_fn(self._params, self._state, grads_kv)
-        self.apply_count += 1
-        self._account_update()
+        self.set_tree_and_state(
+            *self._apply_fn(self._params, self._state, grads_kv))
         return dict(self._params)
-
-    def _account_update(self):
-        k = self.num_workers
-        if self.placement == "replicated":
-            # grads were all-reduced across the data axis
-            self.collective_bytes += collectives.allreduce_bytes(self._params, k)
-        else:
-            # reduce-scatter grads to owners + all-gather params for next fwd
-            self.collective_bytes += collectives.reduce_scatter_bytes(self._params, k)
-            self.collective_bytes += collectives.all_gather_bytes(self._params, k)
 
     # -- per-key protocol (stages, flushes at full-tree granularity) --------
 
@@ -545,9 +548,10 @@ class TpuServer(PeekMixin, CheckpointMixin):
         return dict(self._params), self._state
 
     def set_tree_and_state(self, params, state):
+        """Adopt one update's result and count it."""
         self._params, self._state = dict(params), state
         self.apply_count += 1
-        self._account_update()
+        self.collective_bytes += self._update_bytes
 
 
 # Coordination-service handles parked by shutdown(abort=True): destroying one

@@ -293,8 +293,18 @@ class SparseEmbedding:
         if self._jit_lookup is None:
             self._jit_lookup = jax.jit(self.lookup)
         rows = self._jit_lookup(self.table, ids)
-        self.bytes_pulled += rows.size * rows.dtype.itemsize
+        self.count_pull(ids.size)
         return rows
+
+    def rows_nbytes(self, n_ids: int) -> int:
+        """Bytes of ``n_ids`` rows in the table's dtype: what a lookup
+        returns, and what the gradient with respect to it weighs."""
+        return n_ids * self.dim * np.dtype(self.dtype).itemsize
+
+    def count_pull(self, n_ids: int) -> None:
+        """Count a lookup of ``n_ids`` rows: :meth:`pull`'s, or that of a
+        program that gathers the rows itself (ps_tpu/kv/fused.py)."""
+        self.bytes_pulled += self.rows_nbytes(n_ids)
 
     def push(self, ids, row_grads) -> None:
         """Send (ids, row_grads); server scatter-applies immediately."""
@@ -319,7 +329,7 @@ class SparseEmbedding:
                 [row_grads, jnp.zeros((pad, self.dim), row_grads.dtype)]
             )
         if self._jit_apply is None:
-            # fused tiers donate like the composite step (ps_tpu/train.py):
+            # fused tiers donate like the fused step (ps_tpu/kv/fused.py):
             # the old table/state buffers die with the call, so the
             # batch-sized scatter is a true in-place update instead of a
             # full-table output copy (references from earlier pull()s are
@@ -330,16 +340,22 @@ class SparseEmbedding:
             # holding .table across a push keeps a readable array there.
             donate = (0, 1) if self.fused_tier != "off" else ()
             self._jit_apply = jax.jit(self.apply, donate_argnums=donate)
-        self._table, self._state, dropped = self._jit_apply(
-            self.table, self._state, ids, row_grads
-        )
-        self.record_dropped(dropped)
-        self.bytes_pushed += row_grads.size * row_grads.dtype.itemsize
-        self.push_count += 1
+        self.adopt_push(
+            *self._jit_apply(self.table, self._state, ids, row_grads),
+            ids.shape[0], row_grads.nbytes)
         self.row_version[touched] = self.push_count
-        self._account_push(ids.shape[0])
 
-    def _account_push(self, n_ids: int) -> None:
+    def adopt_push(self, table: jax.Array, state: Any, dropped,
+                   n_ids: int, nbytes: int) -> None:
+        """Take over what one :meth:`apply` of ``n_ids`` row gradients
+        weighing ``nbytes`` returned, and count it: the tail of
+        :meth:`push` and of the fused step (ps_tpu/kv/fused.py).
+        ``dropped`` may stay on the device. ``row_version`` is not stamped
+        here: only :meth:`push` has the ids on the host."""
+        self._table, self._state = table, state
+        self.record_dropped(dropped)  # sync-free; read at log time
+        self.bytes_pushed += nbytes
+        self.push_count += 1
         # arithmetic only — each routed row is (id:int32 + dim f32 grads)
         self.rows_pushed += n_ids
         row_bytes = 4 * (self.dim + 1)

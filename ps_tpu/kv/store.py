@@ -14,19 +14,16 @@ reference reports (BASELINE.json metric line).
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, List, Optional, Union
 
 import jax
 import numpy as np
 import optax
 
-from ps_tpu import obs
 from ps_tpu.api import current_context
+from ps_tpu.kv import fused
 from ps_tpu.kv import keys as keymod
-from ps_tpu.obs import phases
 from ps_tpu.optim import make_optimizer
-from ps_tpu.parallel.sharding import gathered_sharding
 
 
 def _nbytes(x) -> int:
@@ -114,6 +111,8 @@ class KVStore:
         kv, treedef = keymod.flatten_with_keys(params)
         self._treedef = treedef
         self._key_order = list(kv)
+        # what one whole-tree push or pull moves: a constant from here on
+        self._tree_bytes = sum(_nbytes(v) for v in kv.values())
         if hasattr(self._engine, "register_tree"):
             return self._engine.register_tree(kv, treedef, self._key_order)
         for k, v in kv.items():
@@ -243,8 +242,6 @@ class KVStore:
                 "make_step is the sync fused path; in async mode use "
                 "make_async_step (or push_all/pull_all directly)"
             )
-        treedef, key_order = self._treedef, self._key_order
-
         if not hasattr(engine, "get_tree_and_state"):
             grad_fn = jax.jit(jax.value_and_grad(loss_fn, has_aux=has_aux))
             nw = engine.num_workers
@@ -298,114 +295,11 @@ class KVStore:
 
             return run_local
 
-        opt = self._opt
-        grad_scale = float(getattr(engine, "grad_scale", 1.0))
-
-        def kv_loss(params_kv, batch, *extra):
-            return loss_fn(
-                keymod.unflatten(treedef, params_kv, key_order), batch, *extra
-            )
-
-        # ZeRO-1 across chips: the step states where each tensor lives
-        # instead of leaving it to GSPMD, which on BERT's shapes kept the
-        # weights split and moved the activations (PERF.md, PR 26). Neither
-        # fact is an option: the store holds both.
-        stored = gathered = out_shardings = None
-        # num_workers is the size of the mesh's data axis
-        if self.placement == "sharded" and engine.num_workers > 1:
-            stored, state_shardings = jax.tree_util.tree_map(
-                lambda x: x.sharding, engine.get_tree_and_state())
-            gathered = jax.tree_util.tree_map(gathered_sharding, stored)
-            out_shardings = (stored, state_shardings, None, None)
-
-        # Not named ``fused`` as before the scopes: jax leaves metadata out
-        # of the compile cache's key, so under the old name an executable
-        # cached without the phase marks would be served for this one.
-        @functools.partial(jax.jit, donate_argnums=(0, 1),
-                           out_shardings=out_shardings)
-        def fused_step(params_kv, state, batch, *extra):
-            pulled = params_kv
-            if gathered is not None:
-                # the pull: the tail of the server's apply in this protocol
-                with jax.named_scope(phases.APPLY):
-                    pulled = jax.lax.with_sharding_constraint(
-                        params_kv, gathered)
-            with jax.named_scope(phases.GRAD):
-                if has_aux:
-                    (loss, aux), grads = jax.value_and_grad(
-                        kv_loss, has_aux=True)(pulled, batch, *extra)
-                else:
-                    loss, grads = jax.value_and_grad(kv_loss)(
-                        pulled, batch, *extra)
-                    aux = None
-            with jax.named_scope(phases.APPLY):
-                if stored is not None:
-                    # the push: a gradient is a sum over the chips' batch
-                    # slices at the shape its parameter was read in, and
-                    # each owner keeps its shard of it: all-reduce then
-                    # slice, which the compiler fuses to a reduce-scatter
-                    grads = jax.lax.with_sharding_constraint(
-                        jax.lax.with_sharding_constraint(grads, gathered),
-                        stored)
-                if grad_scale != 1.0:  # aggregate='sum' semantics
-                    grads = jax.tree_util.tree_map(
-                        lambda g: g * grad_scale, grads)
-                updates, state = opt.update(grads, state, params_kv)
-                params_kv = optax.apply_updates(params_kv, updates)
-            return params_kv, state, loss, aux
-
-        check_health = self._check_health
-        span = obs.tracer().program_span
-
-        def run(batch, *extra):
-            with span(phases.STEP_RUN, step=self.step):
-                check_health()  # dead peer -> typed error, not a hung psum
-                params_kv, state = engine.get_tree_and_state()
-                with span(phases.STEP_LAUNCH, step=self.step):
-                    params_kv, state, loss, aux = fused_step(
-                        params_kv, state, batch, *extra)
-                engine.set_tree_and_state(params_kv, state)
-                nbytes = sum(_nbytes(v) for v in params_kv.values())
-                self.bytes_pushed += nbytes
-                self.bytes_pulled += nbytes
-                self.step += 1
-                params = keymod.unflatten(treedef, params_kv, key_order)
-            if has_aux:
-                return loss, params, aux
-            return loss, params
-
-        def lower(batch, *extra):
-            """The fused step lowered for this batch, not compiled and not
-            run: a ``jax.stages.Lowered``. Its ``as_text()`` holds the
-            sharding constraints the step states, before the partitioner
-            resolves them."""
-            params_kv, state = engine.get_tree_and_state()
-            return fused_step.lower(params_kv, state, batch, *extra)
-
-        def cost_analysis(batch, *extra):
-            """XLA HLO cost analysis of the whole fused step (gradient +
-            aggregation + server apply + pull) — no execution, no extra
-            compile: lowering stops at pre-optimization HLO, so 'flops' is
-            the exact model+optimizer arithmetic while 'bytes accessed' is an
-            unfused upper bound. Benchmarks turn this into MFU."""
-            return lower(batch, *extra).cost_analysis()
-
-        def compiled_text(batch, *extra) -> str:
-            """Post-GSPMD optimized HLO of the fused step, as text — the
-            compiled collective pattern (reduce-scatter/all-gather vs
-            all-reduce) that tests/test_hlo_collectives.py pins so a
-            placement regression in ``param_sharding`` is a loud failure,
-            not a silent 8x traffic increase: on a two-matrix MLP
-            (``test_sharded_scatters_largest_grad_and_gathers_params``) and
-            on transformer shapes, where the weights' 'data' dim is the
-            output dim and the regression was activations moved in place
-            of weights (``test_sharded_transformer_moves_weights_only``)."""
-            return lower(batch, *extra).compile().as_text()
-
-        run.lower = lower
-        run.cost_analysis = cost_analysis
-        run.compiled_text = compiled_text
-        return run
+        # the fused step with no table: no rows to look up or to push
+        return fused.make_fused_step(
+            self, {},
+            lambda params, rows, batch, *extra: loss_fn(params, batch, *extra),
+            lambda batch: {}, has_aux)
 
     def make_async_step(self, loss_fn, has_aux: bool = False):
         """Build the async worker cycle ``run(batch, *extra, worker=w)``.

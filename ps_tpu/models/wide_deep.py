@@ -72,7 +72,7 @@ def bce_loss(logits, labels):
 
 
 def make_wide_deep_loss_fn(model: WideDeep):
-    """Composite-step loss closure for ps_tpu.train.make_composite_step:
+    """Composite-step loss closure for ps_tpu.make_composite_step:
     ``loss_fn(dense_params, rows, batch)`` with rows = {'deep', 'wide'}."""
 
     def loss_fn(params, rows, batch):

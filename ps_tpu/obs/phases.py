@@ -1,10 +1,10 @@
 """Names of the phases of a fused step and of the host spans around it.
 
 Constants only. The device phases are opened with ``jax.named_scope`` where
-the work is written (``kv/store.py``, ``train.py``, ``kv/sparse.py``,
+the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``) and land in the ``op_name`` of every HLO
 instruction traced under them; the host spans are recorded with
-``ps_tpu.obs.tracer().program_span`` (``kv/store.py``, ``train.py``,
+``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``). ``benchmark/layer_metrics/scope.py`` and ``host.py``
 keep their own copy of the names they look up (the benchmark also runs on
 trees that lack this file); ``tests/test_phases.py`` holds them equal. Every

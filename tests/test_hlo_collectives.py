@@ -42,6 +42,7 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
+from ps_tpu.kv.sparse import SparseEmbedding
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device virtual mesh"
@@ -322,6 +323,7 @@ def test_sharded_matches_replicated_on_transformer_shapes():
         sh, rep)
 
 
+@pytest.mark.parametrize("kind", ["make_step", "make_composite_step"])
 @pytest.mark.parametrize("placement,devices,stated", [
     ("replicated", 8, False),
     ("replicated", 1, False),
@@ -329,16 +331,28 @@ def test_sharded_matches_replicated_on_transformer_shapes():
     ("sharded", 8, True),
 ])
 def test_step_states_shardings_only_when_sharded_across_devices(
-        placement, devices, stated):
+        placement, devices, stated, kind):
     """Plain data parallel and one-device steps lower without a single
     sharding constraint (their program is what it was before the step
-    stated anything); ZeRO-1 across devices lowers with them."""
+    stated anything); ZeRO-1 across devices lowers with them. With tables
+    or without: it is one step (ps_tpu/kv/fused.py), and the composite
+    step's dense tower is placed by the same lines."""
     ps.init(backend="tpu", mesh_shape={"data": devices})
     store = ps.KVStore(optimizer="momentum", learning_rate=0.1,
                        placement=placement)
     store.init({"w1": jnp.zeros(W1), "w2": jnp.zeros(W2)})
-    run = store.make_step(
-        lambda p, b: jnp.mean((jnp.tanh(b[0] @ p["w1"]) @ p["w2"] - b[1]) ** 2))
     batch = store.shard_batch((jnp.zeros((64, W1[0])), jnp.zeros((64, W2[1]))))
+    if kind == "make_step":
+        run = store.make_step(lambda p, b: jnp.mean(
+            (jnp.tanh(b[0] @ p["w1"]) @ p["w2"] - b[1]) ** 2))
+    else:
+        emb = SparseEmbedding(64, W1[0], optimizer="sgd")
+        emb.init(jax.random.key(0))
+        run = ps.make_composite_step(
+            store, {"emb": emb},
+            lambda p, rows, b: jnp.mean(
+                (jnp.tanh((b[0] + rows["emb"]) @ p["w1"]) @ p["w2"]
+                 - b[1]) ** 2),
+            lambda b: {"emb": jnp.arange(64, dtype=jnp.int32)})
     txt = run.lower(batch).as_text()
     assert ("sharding_constraint" in txt or "@Sharding" in txt) == stated

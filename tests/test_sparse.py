@@ -193,7 +193,15 @@ def test_widedeep_composite_training_decreases_loss():
 
 
 def test_widedeep_composite_shard_parity():
-    """Full composite step on an 8-way mesh == on a 1-device mesh."""
+    """Full composite step on an 8-way mesh == on a 1-device mesh.
+
+    Since the dense tower takes the fused step's ZeRO-1 constraints
+    (ps_tpu/kv/fused.py) its gradients are sums of eight per-device
+    partial sums, where GSPMD left alone had gathered the activations and
+    contracted over the whole batch in one device's order. Loss and dense
+    parameters hold their old tolerances. Of the deep table's 10,400
+    elements one near zero is 1.36e-6 off (the sandbox's CPU, PR 30), so
+    the table's atol alone goes from 1e-6 to 2e-6."""
     results = {}
     for k in (1, 8):
         cfg, dense, deep, wide, run = _widedeep_setup({"data": k})
@@ -209,7 +217,7 @@ def test_widedeep_composite_shard_parity():
         )
         ps.shutdown()
     np.testing.assert_allclose(results[1][0], results[8][0], rtol=1e-5)
-    np.testing.assert_allclose(results[1][1], results[8][1], rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(results[1][1], results[8][1], rtol=1e-4, atol=2e-6)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6),
         results[1][2], results[8][2],

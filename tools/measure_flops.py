@@ -1,8 +1,8 @@
-"""Measure exact per-step HLO FLOPs of the fused bench steps on the CPU
+"""Measure exact per-step HLO FLOPs of the fused steps on the CPU
 backend (where pre-compile cost analysis exists — on the TPU jax 0.9.0's
 Lowered.cost_analysis returns none), at two batch sizes to separate the per-example slope from
-the per-step constant. Feeds the `_FLOPS_*` fallbacks in bench.py; the
-derivations are recorded in BASELINE.md.
+the per-step constant. The provenance of the ``flops`` constants in
+``benchmark/configs/*.json``; the derivations are recorded in BASELINE.md.
 
 Run:  python tools/measure_flops.py bert|widedeep|resnet
 """
@@ -74,8 +74,8 @@ def measure(model: str, batch_sizes=(8, 16)) -> dict:
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
             ca = run.cost_analysis(batch)
         elif model == "resnet":
-            # reproduces the r3 derivation behind bench.py's
-            # _FLOPS_RESNET_* constants (BASELINE.md)
+            # reproduces the r3 derivation behind
+            # benchmark/configs/resnet50.json's constants (BASELINE.md)
             from ps_tpu.data.synthetic import imagenet_batches
             from ps_tpu.models.resnet import ResNet50, make_loss_fn
             from ps_tpu.parallel.sharding import replicated
