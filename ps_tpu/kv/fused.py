@@ -69,7 +69,7 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
         stored, state_shardings = jax.tree_util.tree_map(
             lambda x: x.sharding, engine.get_tree_and_state())
         gathered = jax.tree_util.tree_map(gathered_sharding, stored)
-        # tables, their state, loss, aux, dropped: left to the compiler
+        # tables, their state, loss, aux, row counts: left to the compiler
         out_shardings = (stored, state_shardings) + (None,) * (
             2 + 3 * len(names))
 
@@ -102,19 +102,19 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
                     jax.lax.with_sharding_constraint(grads, gathered),
                     stored)
             params_kv, state = engine.apply_rule(params_kv, state, grads)
-        new_tables, new_estates, dropped = [], [], []
+        new_tables, new_estates, row_counts = [], [], []
         for n in names:
             store = emb_stores[n]
-            table, estate, lost = store.apply(
+            table, estate, counts = store.apply(
                 tables[n], estates[n], ids[n].reshape(-1),
                 grows[n].reshape(-1, store.dim))
             new_tables.append(table)
             new_estates.append(estate)
-            dropped.append(lost)
+            row_counts.append(counts)
         # flat, so that with no table the results are the dense step's,
         # place for place (a result's place is in the compile cache's key)
         return (params_kv, state, *new_tables, *new_estates, loss, aux,
-                *dropped)
+                *row_counts)
 
     def step_args(batch, extra):
         params_kv, state = engine.get_tree_and_state()
@@ -142,11 +142,11 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
             dense_store.bytes_pushed += tree_bytes  # the gradients out
             dense_store.bytes_pulled += tree_bytes  # the parameters back
             dense_store.step += 1
-            for n, table, estate, lost in zip(
+            for n, table, estate, counts in zip(
                     names, rest[:k], rest[k:2 * k], rest[2 * k + 2:]):
                 store = emb_stores[n]
                 store.count_pull(n_ids[n])  # the program's own lookup
-                store.adopt_push(table, estate, lost, n_ids[n],
+                store.adopt_push(table, estate, counts, n_ids[n],
                                  store.rows_nbytes(n_ids[n]))
             params = keymod.unflatten(treedef, params_kv, key_order)
         if has_aux:

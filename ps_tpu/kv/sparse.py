@@ -111,7 +111,7 @@ class SparseEmbedding:
         self.bytes_pulled = 0
         self.collective_bytes = 0
         self.push_count = 0
-        self.rows_pushed = 0
+        self.rows_pushed = 0  # raw (id, gradient) pairs, filler included
         # per-row change stamps for the conditional read path (README
         # "Read path"): row i's last-touching push, in push_count units —
         # the same version the serving layer stamps on READ replies, so
@@ -121,22 +121,39 @@ class SparseEmbedding:
         # restore stamps everything at push_count (conservatively "all
         # changed"), which can only widen a delta, never lose a row.
         self.row_version = np.zeros((num_rows,), np.int64)
-        # a2a overflow counts: device scalars accumulate sync-free; reading
-        # .dropped_rows materializes them (read at logging boundaries)
-        self._dropped_base = 0
-        self._dropped_pending: list = []
+        # what a push reports beside its rows, ``[dropped, applied]`` (see
+        # :meth:`apply`): device values accumulate sync-free; reading
+        # .dropped_rows or .rows_applied materializes them (read at logging
+        # boundaries)
+        self._counts_base = np.zeros((2,), np.int64)
+        self._counts_pending: list = []
 
-    def record_dropped(self, dropped) -> None:
-        """Accumulate a (possibly device-resident) dropped-update count without
-        forcing a host sync on the hot path. Pending counts fold into one
-        device scalar periodically so a long run that never reads
-        :attr:`dropped_rows` holds O(1) buffers, not one per step."""
-        self._dropped_pending.append(dropped)
-        if len(self._dropped_pending) >= 32:
-            total = self._dropped_pending[0]
-            for x in self._dropped_pending[1:]:
+    def record_counts(self, counts) -> None:
+        """Accumulate one push's (possibly device-resident) ``[dropped,
+        applied]`` counts without forcing a host sync on the hot path.
+        Pending counts fold into one device value periodically so a long
+        run that never reads :attr:`dropped_rows` or :attr:`rows_applied`
+        holds O(1) buffers, not one per step: one fold for both counts."""
+        self._counts_pending.append(counts)
+        if len(self._counts_pending) >= 32:
+            oldest = self._counts_pending[0]
+            if getattr(oldest, "is_ready", lambda: True)():
+                # 31 pushes old and computed: its copy to the host waits
+                # for nothing, and the int32 fold on the device never
+                # holds more than 32 pushes' rows
+                self._counts_base += np.asarray(oldest, np.int64)
+                del self._counts_pending[0]
+            total = self._counts_pending[0]
+            for x in self._counts_pending[1:]:
                 total = total + x  # device-side adds: still no host sync
-            self._dropped_pending = [total]
+            self._counts_pending = [total]
+
+    def _read_counts(self) -> np.ndarray:
+        if self._counts_pending:
+            pending, self._counts_pending = self._counts_pending, []
+            for x in pending:
+                self._counts_base += np.asarray(x, np.int64)
+        return self._counts_base
 
     @property
     def dropped_rows(self) -> int:
@@ -146,10 +163,18 @@ class SparseEmbedding:
         the rate is acceptable; reading this syncs any pending device
         counts. (Checkpoints from before the r3 dedupe stored the count in
         routed-row units; counts resumed from them mix units.)"""
-        if self._dropped_pending:
-            pending, self._dropped_pending = self._dropped_pending, []
-            self._dropped_base += sum(int(x) for x in pending)
-        return self._dropped_base
+        return int(self._read_counts()[0])
+
+    @property
+    def rows_applied(self) -> int:
+        """Total DISTINCT rows the pushes wrote: each push counts a row
+        once however many of its :attr:`rows_pushed` pairs named it, so
+        ``rows_applied / rows_pushed`` is the live share of the push's
+        slots (what the fused apply's loop walks; ops/sparse_apply.py).
+        Not the sparse server's STATS ``rows_applied``, which counts raw
+        pairs as :attr:`rows_pushed` does. Reading this syncs any pending
+        device counts."""
+        return int(self._read_counts()[1])
 
     @property
     def dropped_fraction(self) -> float:
@@ -219,10 +244,11 @@ class SparseEmbedding:
         ``row_grads``: [N, D] grads w.r.t. the *gathered rows* (the sparse
         push payload — never a dense table grad).
 
-        Returns ``(table, state, dropped)`` — ``dropped`` is the global
-        count of real rows lost to a2a bucket overflow this push (always 0
-        for the lossless gather exchange); the observable signal
-        ``capacity_factor`` is tuned from.
+        Returns ``(table, state, counts)`` — ``counts`` is int32
+        ``[dropped, applied]``, both global and replicated: the real rows
+        lost to a2a bucket overflow this push (always 0 for the lossless
+        gather exchange; the observable signal ``capacity_factor`` is
+        tuned from), and the distinct rows this push wrote.
 
         Apply tier (README "Sparse apply"): with ``fused_tier`` 'off'
         the owner shard builds a TABLE-SIZED ``gsum``/``cnt`` and the
@@ -230,8 +256,9 @@ class SparseEmbedding:
         HBM passes per push); 'jax' routes through
         :func:`~ps_tpu.ops.sparse_apply.fused_sparse_apply` — dedupe at
         batch size, gather only the touched rows + state, apply the
-        dense-rows rule, scatter back — so apply cost is O(batch ids),
-        not O(rows_per_shard). Same math by the parity contract.
+        dense-rows rule, scatter back, over the distinct rows of the
+        batch and no further — so apply cost is O(distinct ids), not
+        O(rows_per_shard). Same math by the parity contract.
         """
         rps, dim, axis, k = self.rows_per_shard, self.dim, self.axis, self.k
         opt, tier = self._opt, self.fused_tier
@@ -248,7 +275,6 @@ class SparseEmbedding:
                         ids_loc, grads_loc, k, axis, rps,
                         self.capacity_factor
                     )
-                dropped = jax.lax.psum(dropped, axis)  # global, replicated
             lo = jax.lax.axis_index(axis) * rps
             local = all_ids - lo
             ok = (local >= 0) & (local < rps)
@@ -262,13 +288,16 @@ class SparseEmbedding:
                 new_table, new_state = opt.apply(
                     table_shard, state_shard, gsum, cnt > 0
                 )
+                applied = jnp.sum(cnt > 0, dtype=jnp.int32)
             else:
                 ids_m = jnp.where(ok, local, -1)
                 g = jnp.where(ok[:, None], all_grads, 0).astype(jnp.float32)
-                new_table, new_state = fused_sparse_apply(
+                new_table, new_state, applied = fused_sparse_apply(
                     table_shard, state_shard, ids_m, g, opt
                 )
-            return new_table, new_state, dropped
+            # one reduction for both counts: global, replicated
+            counts = jax.lax.psum(jnp.stack([dropped, applied]), axis)
+            return new_table, new_state, counts
 
         state_specs = self._state_specs()
         fn = shard_map(
@@ -345,15 +374,15 @@ class SparseEmbedding:
             ids.shape[0], row_grads.nbytes)
         self.row_version[touched] = self.push_count
 
-    def adopt_push(self, table: jax.Array, state: Any, dropped,
+    def adopt_push(self, table: jax.Array, state: Any, counts,
                    n_ids: int, nbytes: int) -> None:
         """Take over what one :meth:`apply` of ``n_ids`` row gradients
         weighing ``nbytes`` returned, and count it: the tail of
         :meth:`push` and of the fused step (ps_tpu/kv/fused.py).
-        ``dropped`` may stay on the device. ``row_version`` is not stamped
+        ``counts`` may stay on the device. ``row_version`` is not stamped
         here: only :meth:`push` has the ids on the host."""
         self._table, self._state = table, state
-        self.record_dropped(dropped)  # sync-free; read at log time
+        self.record_counts(counts)  # sync-free; read at log time
         self.bytes_pushed += nbytes
         self.push_count += 1
         # arithmetic only — each routed row is (id:int32 + dim f32 grads)
@@ -428,6 +457,7 @@ class SparseEmbedding:
             "collective_bytes": self.collective_bytes,
             "rows_pushed": self.rows_pushed,
             "dropped_rows": self.dropped_rows,
+            "rows_applied": self.rows_applied,
         }
         ckpt.save(path, arrays, meta)
 
@@ -471,8 +501,10 @@ class SparseEmbedding:
         self.bytes_pulled = int(meta["bytes_pulled"])
         self.collective_bytes = int(meta["collective_bytes"])
         self.rows_pushed = int(meta.get("rows_pushed", 0))
-        self._dropped_base = int(meta.get("dropped_rows", 0))
-        self._dropped_pending = []
+        self._counts_base = np.array(
+            [meta.get("dropped_rows", 0), meta.get("rows_applied", 0)],
+            np.int64)
+        self._counts_pending = []
         return self._table
 
 

@@ -8,16 +8,21 @@ optimizer updates the ENTIRE shard under a ``touched`` mask. Apply cost
 is O(num_rows) even when a batch touches 0.1% of rows — exactly the
 regime out-of-HBM tiered tables (ROADMAP item 3) will live in. This
 module makes apply cost O(batch): dedupe/segment-sum the pushed ids at
-BATCH size, gather only the touched rows and their per-row optimizer
-state, apply the dense-rows rule (``RowwiseOptimizer.apply_rows``), and
-scatter rows+state back.
+BATCH size into a compact list, the distinct rows in front; gather only
+those rows and their per-row optimizer state, apply the dense-rows rule
+(``RowwiseOptimizer.apply_rows``), and scatter rows+state back, in a loop
+that stops at the last distinct row: a slot that names no row (a
+duplicate's, filler's) costs a gather and a scatter what a live one does
+on the TPU, and two thirds of a Criteo-like batch's slots name none.
 
 Two tiers, selected by ``PS_FUSED_APPLY`` (``Config.fused_apply``,
 ``off|jax|auto``; README "Sparse apply"):
 
-- ``jax`` — the batch-sized path: take/gather the touched rows + state,
-  ``apply_rows``, ``.at[].set(mode='drop')`` scatter (filler ids redirect
-  out of range and drop). O(batch) traffic, XLA-scheduled.
+- ``jax`` — the batch-sized path: chunk by chunk over the distinct rows,
+  take/gather rows + state, ``apply_rows``, ``.at[].set(mode='drop')``
+  scatter, every index list stated sorted and unique (the last chunk's
+  filler tail redirects out of range and drops). Traffic and slots
+  O(distinct rows), XLA-scheduled.
 - ``off`` — the legacy masked full-table path, byte-for-byte today's
   behavior (the caller keeps its own code path; this module is not
   involved).
@@ -67,34 +72,50 @@ def resolve_tier(requested: Optional[str]) -> str:
 
 
 def batch_segment_sum(ids: jax.Array, grads: jax.Array
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Batch-sized dedupe + segment sum of a push's (ids, grads).
+                      ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Batch-sized dedupe + segment sum of a push's (ids, grads), compact.
 
-    ``ids`` [N] int32 with duplicates and -1 filler allowed; ``grads``
-    [N, D]. Returns ``(uids, gsum, cnt)`` all length N: each unique real
-    id survives at one slot with its duplicates' grads summed (f32, in
-    stable-sorted arrival order — the fixed reduction order the bitwise
-    parity contract names), duplicates and filler become ``uid=-1,
-    gsum=0, cnt=0``. The table never appears: this is the O(batch) twin
-    of the legacy table-sized ``zeros(rps).at[slot].add`` build.
+    ``ids`` [N] int32 with duplicates and negative filler (-1) allowed;
+    ``grads`` [N, D]. Returns ``(uids, gsum, cnt, n_unique)``, the first
+    three of length N (static) and ``n_unique`` an int32 scalar ``U``:
+    slots ``0..U-1`` hold the distinct real ids in ascending order, each
+    with its duplicates' grads summed (f32, in stable-sorted arrival
+    order: the fixed reduction order the bitwise parity contract names)
+    and their count; every slot from ``U`` on is filler, ``uid=-1,
+    cnt=0``, ``gsum`` the zero grads filler carries. The table never
+    appears: this is the O(batch) twin of the legacy table-sized
+    ``zeros(rps).at[slot].add`` build.
     """
     n = ids.shape[0]
     if n == 0:
-        return ids, grads.astype(jnp.float32), jnp.zeros((0,), jnp.int32)
-    order = jnp.argsort(ids)  # stable: duplicates keep arrival order
-    ids_s = ids[order]
+        return (ids, grads.astype(jnp.float32), jnp.zeros((0,), jnp.int32),
+                jnp.int32(0))
+    # read as unsigned, filler sorts behind every real id: one sort leaves
+    # the distinct real ids as segments 0..U-1. Stable, so a row's
+    # duplicates keep arrival order.
+    key_s, order = jax.lax.sort_key_val(
+        jax.lax.bitcast_convert_type(ids, jnp.uint32),
+        jnp.arange(n, dtype=jnp.int32))
     grads_s = grads[order].astype(jnp.float32)
     first = jnp.concatenate(
-        [jnp.ones((1,), bool), ids_s[1:] != ids_s[:-1]])
+        [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])
     seg = jnp.cumsum(first) - 1
-    summed = jnp.zeros(grads_s.shape, jnp.float32).at[seg].add(grads_s)
-    seg_cnt = jnp.zeros((n,), jnp.int32).at[seg].add(
-        (ids_s >= 0).astype(jnp.int32))
-    real = first & (ids_s >= 0)
-    uids = jnp.where(real, ids_s, -1)
-    gsum = jnp.where(real[:, None], summed[seg], 0.0)
-    cnt = jnp.where(real, seg_cnt[seg], 0)
-    return uids, gsum, cnt
+    real = key_s <= jnp.uint32(2**31 - 1)
+    n_unique = jnp.sum(first & real, dtype=jnp.int32)
+    # segment i's sum and count land in slot i: already compact. The hint
+    # changes no bit of a sum (the chip, PR 31: both tables of the cell).
+    gsum = jnp.zeros(grads_s.shape, jnp.float32).at[seg].add(
+        grads_s, indices_are_sorted=True)
+    cnt = jnp.zeros((n,), jnp.int32).at[seg].add(
+        real.astype(jnp.int32), indices_are_sorted=True)
+    # and its id: the first of each real run, everything else sent behind
+    # them by a second sort of N keys (0.1 ms on the chip, where a scatter
+    # of N ids costs 0.9)
+    uids = jax.lax.bitcast_convert_type(
+        jnp.sort(jnp.where(first & real, key_s, jnp.uint32(2**32 - 1))),
+        jnp.int32)
+    uids = jnp.where(jnp.arange(n) < n_unique, uids, -1)
+    return uids, gsum, cnt, n_unique
 
 
 def segment_sum_np(ids, grads):
@@ -122,38 +143,124 @@ def segment_sum_np(ids, grads):
     return uids, gsum, cnt.astype(np.int32)
 
 
+#: Chunk length of the apply loop, from the push's length N alone:
+#: ``ceil(N / _CHUNK_PARTS)`` slots, at least ``_CHUNK_FLOOR``, in whole
+#: sublanes of 8 and never more than N. Measured on the chip (PR 31, PERF.md
+#: §6; f32[33800000,32] with Adagrad, N = 106,496): an iteration's fixed cost
+#: is below what a run resolves (the same 36,864 slots as 18 chunks of 2,048
+#: or 9 of 4,096: 9.005 against 9.004 ms a push), so a chunk only has to be
+#: short against N: the filler tail of the last chunk is C / 2 slots on
+#: average at 0.16 us a slot, under 2% of an all-distinct push at N / 32
+#: (8.45 ms a push on the cell's ids at N / 32, 8.92 at N / 16, 9.56 at
+#: N / 8). The floor keeps a short push (the eager ``push``, a rehearsal) in
+#: one chunk or two: no chunk under 2,048 slots was measured.
+_CHUNK_PARTS = 32
+_CHUNK_FLOOR = 1024
+
+
+def chunk_len(n: int) -> int:
+    """Slots one iteration of :func:`fused_sparse_apply`'s loop applies,
+    for a push of ``n`` (ids, grads) pairs."""
+    c = max(_CHUNK_FLOOR, -(-n // _CHUNK_PARTS))
+    return min(-(-c // 8) * 8, -(-n // 8) * 8)
+
+
 def fused_sparse_apply(table: jax.Array, state: Any, ids: jax.Array,
-                       grads: jax.Array, opt) -> Tuple[jax.Array, Any]:
+                       grads: jax.Array, opt
+                       ) -> Tuple[jax.Array, Any, jax.Array]:
     """THE entry point every fused sparse apply routes through
     (``kv/sparse``'s shard_apply, and through it the remote sparse server
     and the mesh backend). ``ids`` [N] are SHARD-LOCAL row indices with -1
     filler (out-of-range/padding already masked by the caller), ``grads``
-    [N, D] with filler rows zeroed. Returns the updated (table, state);
-    only touched rows' bytes move: batch-sized gather → apply_rows →
-    scatter. Filler slots gather row 0 (harmless: cnt 0 and gsum 0 make
-    apply_rows the identity for them) and scatter out of range
-    (``mode='drop'``)."""
-    if ids.shape[0] == 0:  # empty push: nothing gathered, nothing written
-        return table, state
+    [N, D] with filler rows zeroed. Returns the updated ``(table, state)``
+    and ``U``, the number of distinct rows written (int32 scalar).
+
+    Only the touched rows' bytes move, and only their slots are paid for:
+    :func:`batch_segment_sum` leaves the ``U`` distinct rows of the push in
+    front, and a loop of ``ceil(U / C)`` iterations, read from the data,
+    walks that prefix ``C = chunk_len(N)`` slots at a time: gather ->
+    ``apply_rows`` -> scatter. Inside a chunk the ids are ascending and
+    distinct, which the gathers and the scatters state; the filler tail of
+    the last chunk is sent to ``num_rows + position`` (still ascending and
+    distinct, out of range): its gathers clip to the last row and its
+    scatters drop. There is no collective in the loop, so the shards of a
+    ``shard_map`` may run different trip counts.
+
+    Two things the TPU's compiler does to rows one scalar wide shape the
+    walk (the chip, PR 31, 33.8M rows; PERF.md §6). It gathers from and
+    scatters into ``[V, 1]`` as the vector ``[V]`` and re-lays the array out
+    to get there, 2.1 ms an iteration if that happens inside the loop: so
+    table and state leaves of width 1 are walked as vectors, reshaped once
+    on either side. And its scatter into a vector costs a pass over the
+    vector a call (0.40 ms) beside 5 ns a slot, where a scatter of wider
+    rows costs 119 ns a slot and nothing a call: so the loop only collects
+    the vector leaves' new values, and one scatter of all N slots after it
+    writes them (the filler dropped), while wider leaves are written chunk
+    by chunk."""
+    n = ids.shape[0]
+    if n == 0:  # empty push: nothing gathered, nothing written
+        return table, state, jnp.int32(0)
+    c = chunk_len(n)
+    if n % c:  # whole chunks, so that no slice is clamped onto another
+        ids = jnp.pad(ids, (0, -n % c), constant_values=-1)
+        grads = jnp.pad(grads, ((0, -n % c), (0, 0)))
+        n = ids.shape[0]
     with jax.named_scope(phases.ROW_DEDUPE):
-        uids, gsum, cnt = batch_segment_sum(ids, grads)
+        uids, gsum, cnt, n_unique = batch_segment_sum(ids, grads)
     num_rows = table.shape[0]
-    with jax.named_scope(phases.ROW_GATHER):
-        slot = jnp.where(uids >= 0, uids, 0)
-        rows = jnp.take(table, slot, axis=0)
-        state_rows = jax.tree_util.tree_map(
-            lambda leaf: jnp.take(leaf, slot, axis=0), state)
-    with jax.named_scope(phases.ROW_UPDATE):
-        new_rows, new_state_rows = opt.apply_rows(rows, state_rows, gsum, cnt)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    idx = jnp.where(pos < n_unique, uids, num_rows + pos)
+    hints = dict(indices_are_sorted=True, unique_indices=True)
+
+    leaves, treedef = jax.tree_util.tree_flatten((table, state))
+    shapes = [leaf.shape for leaf in leaves]
+    leaves = [leaf.reshape(-1) if leaf.shape[1:] == (1,) else leaf
+              for leaf in leaves]
+    vector = [leaf.ndim == 1 for leaf in leaves]
+
+    def apply_chunk(i, carry):
+        leaves, collected = carry
+        lo = i * c
+        at = jax.lax.dynamic_slice_in_dim(idx, lo, c)
+        live = jax.lax.dynamic_slice_in_dim(pos, lo, c) < n_unique
+        with jax.named_scope(phases.ROW_GATHER):
+            rows, state_rows = jax.tree_util.tree_unflatten(treedef, [
+                jnp.take(leaf, at, axis=0, mode="clip", **hints
+                         ).reshape((c,) + shape[1:])
+                for leaf, shape in zip(leaves, shapes)])
+        with jax.named_scope(phases.ROW_UPDATE):
+            # filler is untouched to the rule, as its contract says
+            new = jax.tree_util.tree_leaves(opt.apply_rows(
+                rows, state_rows,
+                jnp.where(live[:, None],
+                          jax.lax.dynamic_slice_in_dim(gsum, lo, c), 0.0),
+                jnp.where(live, jax.lax.dynamic_slice_in_dim(cnt, lo, c), 0)))
+        with jax.named_scope(phases.ROW_SCATTER):
+            new = [x.astype(leaf.dtype).reshape((c,) + leaf.shape[1:])
+                   for x, leaf in zip(new, leaves)]
+            collected = [
+                jax.lax.dynamic_update_slice_in_dim(buf, x, lo, 0) if v
+                else None for buf, x, v in zip(collected, new, vector)]
+            leaves = [leaf if v else leaf.at[at].set(x, mode="drop", **hints)
+                      for leaf, x, v in zip(leaves, new, vector)]
+        return leaves, collected
+
+    # a vector leaf's new values, N of them. Zeros made of ``cnt``: under
+    # ``shard_map`` as varying as the loop's results are, which a loop's
+    # carry has to be from the start
+    carry = (leaves, [jnp.zeros_like(cnt, leaf.dtype) if v else None
+                      for leaf, v in zip(leaves, vector)])
+    if n == c:  # a short push is one chunk: no loop to stop early
+        leaves, collected = apply_chunk(0, carry)
+    else:
+        leaves, collected = jax.lax.fori_loop(0, -(-n_unique // c),
+                                              apply_chunk, carry)
     with jax.named_scope(phases.ROW_SCATTER):
-        dst = jnp.where(uids >= 0, uids, num_rows)  # filler drops off the end
-        new_table = table.at[dst].set(new_rows.astype(table.dtype),
-                                      mode="drop")
-        new_state = jax.tree_util.tree_map(
-            lambda leaf, nrows: leaf.at[dst].set(nrows.astype(leaf.dtype),
-                                                 mode="drop"),
-            state, new_state_rows)
-    return new_table, new_state
+        leaves = [leaf.at[idx].set(buf, mode="drop", **hints) if v else leaf
+                  for leaf, buf, v in zip(leaves, collected, vector)]
+    table, state = jax.tree_util.tree_unflatten(treedef, [
+        leaf.reshape(shape) for leaf, shape in zip(leaves, shapes)])
+    return table, state, n_unique
 
 
 # -- HBM traffic model -------------------------------------------------------

@@ -24,6 +24,8 @@ from ps_tpu.config import Config
 from ps_tpu.kv.sparse import SparseEmbedding, _dedupe_rows
 from ps_tpu.ops.sparse_apply import (
     batch_segment_sum,
+    chunk_len,
+    fused_sparse_apply,
     hbm_bytes_model,
     resolve_tier,
 )
@@ -103,23 +105,243 @@ def test_fused_parity_sharded_a2a():
 
 
 def test_batch_segment_sum_orders_and_counts():
+    """The compact contract: the U distinct real ids in front, ascending,
+    each with its duplicates' sum and count; filler behind."""
     ids = jnp.asarray([5, -1, 2, 5, 5, 2], jnp.int32)
     grads = jnp.asarray(np.arange(6 * D, dtype=np.float32).reshape(6, D))
-    uids, gsum, cnt = batch_segment_sum(ids, grads)
-    uids, gsum, cnt = map(np.asarray, (uids, gsum, cnt))
-    # one surviving slot per unique id, with duplicate counts
-    assert sorted(uids[uids >= 0].tolist()) == [2, 5]
-    got = {int(u): (gsum[i], int(cnt[i]))
-           for i, u in enumerate(uids) if u >= 0}
-    np.testing.assert_allclose(got[2][0],
-                               np.asarray(grads)[[2, 5]].sum(0))
-    np.testing.assert_allclose(got[5][0],
-                               np.asarray(grads)[[0, 3, 4]].sum(0))
-    assert got[2][1] == 2 and got[5][1] == 3
-    # filler slots are inert: no id, no grads, no count
-    dead = uids < 0
-    assert dead.sum() == 4
-    assert np.all(gsum[dead] == 0) and np.all(cnt[dead] == 0)
+    uids, gsum, cnt, n_unique = map(np.asarray,
+                                    batch_segment_sum(ids, grads))
+    assert n_unique == 2 and n_unique.dtype == np.int32
+    assert uids.tolist() == [2, 5, -1, -1, -1, -1]
+    assert cnt.tolist() == [2, 3, 0, 0, 0, 0]
+    g = np.asarray(grads)
+    # duplicates summed in arrival order (the stable sort keeps it)
+    np.testing.assert_array_equal(gsum[0], g[2] + g[5])
+    np.testing.assert_array_equal(gsum[1], (g[0] + g[3]) + g[4])
+    # the filler's slot carries the filler's own gradients and no count
+    np.testing.assert_array_equal(gsum[2], g[1])
+    assert np.all(gsum[3:] == 0)
+    # no real id at all: nothing in front
+    none = batch_segment_sum(jnp.full((4,), -1, jnp.int32),
+                             jnp.zeros((4, D), jnp.float32))
+    assert int(none[3]) == 0 and np.asarray(none[0]).tolist() == [-1] * 4
+
+
+# -- the loop over the distinct rows (ISSUE 31) ------------------------------
+
+# The CPU backend contracts ``a * b + c`` into one FMA or not, fusion by
+# fusion, and a loop body fuses unlike straight-line code: one rounding more
+# or less in ``rows - lr * g`` and in ``sum(g * g)``. These tests are about
+# which slots the loop applies, so they draw gradients in eighths and take a
+# learning rate of 1/8: every product and every sum of them is exact in f32
+# and both tiers round the same values. Rounding order with arbitrary values
+# is ``test_fused_tier_parity_sweep``'s (and, on the chip,
+# ``chip_smoke.py::_tier_parity``'s).
+LR_EXACT = 0.125
+
+
+def _eighths(rng, shape):
+    return (rng.integers(-2, 3, size=shape) / 8).astype(np.float32)
+
+
+#: a push long enough for the loop: three chunks of C
+N_LOOP = 3 * 1024
+C_LOOP = chunk_len(N_LOOP)
+V_LOOP = 4096
+N_FILLER = 500
+
+
+def _push_of(n_unique, filler, rng, num_rows=V_LOOP, n=N_LOOP):
+    """``n`` (id, gradient) pairs naming ``n_unique`` distinct rows, the
+    table's last row among them, ``N_FILLER`` of them -1 with ``filler``
+    (all of them where no row is named)."""
+    n_real = (n - N_FILLER if filler else n) if n_unique else 0
+    assert n_unique <= n_real
+    ids = np.full((n,), -1, np.int32)
+    if n_unique:
+        rows = np.concatenate([
+            rng.choice(num_rows - 1, n_unique - 1, replace=False),
+            [num_rows - 1]]).astype(np.int32)
+        real = np.concatenate(
+            [rows, rng.choice(rows, n_real - n_unique)]).astype(np.int32)
+        ids[rng.choice(n, n_real, replace=False)] = rng.permutation(real)
+    grads = _eighths(rng, (n, D))
+    grads[ids < 0] = 0
+    return ids, grads
+
+
+def _assert_tiers_agree(optimizer, got, want):
+    if optimizer in ("sgd", "adagrad"):
+        jax.tree_util.tree_map(np.testing.assert_array_equal, got, want)
+    else:
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6,
+                                                    atol=1e-7), got, want)
+
+
+def _apply_once(tier, optimizer, table0, ids, grads, mesh_shape=None):
+    """One ``SparseEmbedding.push`` at one tier: ``(table, state)`` as
+    numpy, and the rows it counted."""
+    ps.init(backend="tpu", mesh_shape=mesh_shape)
+    emb = SparseEmbedding(table0.shape[0], D, optimizer=optimizer,
+                          fused_apply=tier, learning_rate=LR_EXACT)
+    emb.init(table0)
+    emb.push(ids, grads)
+    out = jax.tree_util.tree_map(np.asarray, (emb.table, emb.state()))
+    applied = emb.rows_applied
+    ps.shutdown()
+    return out, applied
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adagrad", "adam"])
+@pytest.mark.parametrize("n_unique,filler", [
+    (0, True), (1, False), (1, True),
+    (C_LOOP - 1, False), (C_LOOP - 1, True), (C_LOOP, False),
+    (C_LOOP, True), (C_LOOP + 1, False), (C_LOOP + 1, True),
+    (2 * C_LOOP, False), (2 * C_LOOP, True),
+    (N_LOOP - N_FILLER, True), (N_LOOP, False)])
+def test_fused_apply_stops_at_the_distinct_rows(optimizer, n_unique, filler):
+    """The loop's edges: U distinct rows of N pairs at 0, 1, one either
+    side of a chunk's end, two chunks and all N, with and without filler,
+    the table's last row among them. Against the 'off' tier under the
+    module's contract; the count is U."""
+    assert N_LOOP > 2 * C_LOOP  # a loop, not the one-chunk path
+    rng = np.random.default_rng(n_unique + filler)
+    table0 = rng.normal(size=(V_LOOP, D)).astype(np.float32)
+    ids, grads = _push_of(n_unique, filler, rng)
+    assert np.unique(ids[ids >= 0]).size == n_unique
+    one = {"data": 1}
+    want, _ = _apply_once("off", optimizer, table0, ids, grads, one)
+    got, applied = _apply_once("jax", optimizer, table0, ids, grads, one)
+    _assert_tiers_agree(optimizer, got, want)
+    assert applied == n_unique
+    untouched = np.setdiff1d(np.arange(V_LOOP), ids)
+    np.testing.assert_array_equal(got[0][untouched], table0[untouched])
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adagrad", "adam"])
+def test_fused_apply_shards_stop_at_their_own_rows(optimizer):
+    """Eight shards under ``shard_map``, each owning another number of
+    the push's distinct rows (none on the first, two chunks and more on
+    the last): every shard's loop stops at its own count."""
+    k, rps = 8, 4096
+    rng = np.random.default_rng(5)
+    table0 = rng.normal(size=(k * rps, D)).astype(np.float32)
+    per_shard = [0, 1, 200, C_LOOP - 1, C_LOOP, C_LOOP + 1, 300,
+                 2 * C_LOOP + 7]
+    rows = np.concatenate([
+        s * rps + rng.choice(rps, u, replace=False)
+        for s, u in enumerate(per_shard)]).astype(np.int32)
+    n = 8192
+    ids = rng.permutation(np.concatenate(
+        [rows, rng.choice(rows, n - rows.size)]).astype(np.int32))
+    grads = _eighths(rng, (n, D))
+    mesh = {"data": k}
+    want, _ = _apply_once("off", optimizer, table0, ids, grads, mesh)
+    got, applied = _apply_once("jax", optimizer, table0, ids, grads, mesh)
+    _assert_tiers_agree(optimizer, got, want)
+    assert applied == sum(per_shard)
+
+
+def test_lowered_apply_states_what_the_sort_proved():
+    """Every gather and scatter of rows carries ``indices_are_sorted`` and
+    ``unique_indices`` (Adagrad: the table's inside the loop, the
+    accumulator vector's one scatter after it), the walk is a ``while``,
+    and the donated table is updated in place: no table-shaped ``copy`` in
+    the optimized HLO."""
+    opt = make_rowwise("adagrad", learning_rate=0.1)
+    table = jnp.zeros((V_LOOP, D), jnp.float32)
+    ids = jnp.zeros((N_LOOP,), jnp.int32)
+    grads = jnp.zeros((N_LOOP, D), jnp.float32)
+    lowered = jax.jit(
+        lambda t, s, i, g: fused_sparse_apply(t, s, i, g, opt),
+        donate_argnums=(0, 1)).lower(table, opt.init(table), ids, grads)
+    text = lowered.as_text()
+    assert "stablehlo.while" in text
+    # the dedupe's own scatter-adds come before the loop
+    walk = text[text.index("stablehlo.while"):].splitlines()
+    scatters = [ln for ln in walk if "stablehlo.scatter" in ln]
+    gathers = [ln for ln in walk if "stablehlo.gather" in ln]
+    assert len(scatters) == 2 and len(gathers) == 2  # table and state
+    for ln in scatters:
+        assert "indices_are_sorted = true" in ln, ln
+        assert "unique_indices = true" in ln, ln
+    for ln in gathers:
+        assert "indices_are_sorted = true" in ln, ln
+    compiled = lowered.compile().as_text()
+    assert " while(" in compiled
+    copies = [ln for ln in compiled.splitlines()
+              if f"= f32[{V_LOOP},{D}]" in ln and " copy(" in ln]
+    assert not copies, copies
+
+
+def _unique_real(ids, num_rows):
+    ids = np.asarray(ids).reshape(-1)
+    return np.unique(ids[(ids >= 0) & (ids < num_rows)]).size
+
+
+def test_rows_applied_counts_distinct_rows_eager():
+    """``rows_applied`` is ``np.unique`` of each push's ids, summed over
+    the pushes, through the fold of the pending device counts (40 pushes:
+    past the 32nd) and with nothing read in between."""
+    ps.init(backend="tpu", mesh_shape={"data": 8})
+    emb = SparseEmbedding(V, D, optimizer="adagrad", learning_rate=0.1)
+    emb.init(_table0())
+    rng = np.random.default_rng(11)
+    want = pushed = 0
+    for step in range(40):
+        ids = rng.integers(-1, V, size=(8 * (1 + step % 5),)).astype(np.int32)
+        emb.push(ids, rng.normal(size=(ids.size, D)).astype(np.float32))
+        want += _unique_real(ids, V)
+        pushed += ids.size
+        assert all(isinstance(x, jax.Array)
+                   for x in emb._counts_pending)  # still on the device
+    assert len(emb._counts_pending) < 32  # folded, not one buffer a push
+    assert (emb.rows_applied, emb.rows_pushed) == (want, pushed)
+    assert emb.dropped_rows == 0
+    assert emb.rows_applied == want  # reading twice counts once
+    ps.shutdown()
+
+
+@pytest.mark.parametrize("tier", ["off", "jax"])
+def test_rows_applied_through_composite_step(tier):
+    """The fused step hands the count out as a device value beside the
+    dropped count; both tiers count the same rows."""
+    from ps_tpu.models.wide_deep import (WideDeep, WideDeepConfig,
+                                         make_ids_fn, make_wide_deep_loss_fn)
+
+    ps.init(backend="tpu", mesh_shape={"data": 8})
+    cfg = WideDeepConfig(num_dense=4, num_sparse=3, per_feature_vocab=50,
+                         embed_dim=D, mlp=(16,))
+    model = WideDeep(cfg)
+    shape = (2, cfg.num_sparse, cfg.embed_dim)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, cfg.num_dense)),
+                        jnp.zeros(shape), jnp.zeros(shape[:2] + (1,))
+                        )["params"]
+    dense = ps.KVStore(optimizer="sgd", learning_rate=0.1)
+    dense.init(params)
+    tables = {}
+    for i, (name, dim) in enumerate((("deep", cfg.embed_dim), ("wide", 1))):
+        tables[name] = SparseEmbedding(cfg.total_rows, dim,
+                                       optimizer="adagrad", fused_apply=tier)
+        tables[name].init(jax.random.key(i + 1))
+    step = ps.make_composite_step(dense, tables,
+                                  make_wide_deep_loss_fn(model),
+                                  make_ids_fn(cfg))
+    rng = np.random.default_rng(3)
+    want = 0
+    for _ in range(5):
+        sparse = rng.zipf(1.5, size=(16, cfg.num_sparse)) % 50
+        batch = {"dense": rng.normal(size=(16, 4)).astype(np.float32),
+                 "sparse": sparse.astype(np.int32),
+                 "label": rng.integers(0, 2, 16).astype(np.float32)}
+        step(dense.shard_batch(batch))
+        want += _unique_real(cfg.global_ids(sparse), cfg.total_rows)
+    for emb in tables.values():
+        assert len(emb._counts_pending) == 5  # nothing read on the way
+        assert (emb.rows_applied, emb.rows_pushed) == (want, 5 * 16 * 3)
+        assert emb.dropped_rows == 0
+    ps.shutdown()
 
 
 # -- satellite: _dedupe_rows / _a2a_route edge cases -------------------------
