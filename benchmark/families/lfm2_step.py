@@ -1,0 +1,403 @@
+"""Family of the fused step over LFM2-MoE, one chip's share of an
+expert-parallel group: ``ps.init`` -> ``KVStore`` (AdamW behind a global-norm
+clip) -> ``make_step(loss_fn, has_aux=True)`` -> ``shard_batch``, the calls of
+``families/moe_step.py`` with the loss of ``ps_tpu/models/lfm2.py``. The
+router's selection bias is state the step updates by a rule of its own: it
+goes in as the step's extra argument and comes back in ``aux`` as a device
+value, every step, with the step's expert counts; no host read in the window.
+
+The yardstick's own pieces live here and beside this file: the stream of
+Zipf ids that never hands out a batch twice (``moe_step.fresh_batches``);
+the plain reference, the benchmark's own copy
+(``families/lfm2_reference.py``, letter for letter the tests'
+``tests/lfm2_reference.py``); the limits of the step-0 checks with their
+measured reasons; and the functions that give operations and bytes from
+shapes (``dense_flops``, ``pair_flops``, ``step_flops``,
+``conv_gate_bytes``, ``flash_forward_cost``).
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from benchmark.families import lfm2_reference as reference
+from benchmark.families.moe_step import (adamw_first_step, cosine,
+                                         fresh_batches, zipf_entropy)
+from benchmark.harness import stats
+from benchmark.harness.loop import Cell, seed_key
+
+# -- the limits of the step-0 checks, with what was measured ------------------
+# The fused step computes in bf16 as the configuration states, with the Pallas
+# flash kernel at 32 query heads on 8 K/V heads, the gated convolution and the
+# grouped matmuls over the held experts; the reference in f32 at "highest"
+# with none of them. All readings: my chip runs, PR 32, TPU v5 lite, published
+# widths, 16,384 tokens. "seen": the system against the reference over 27
+# seeds of the cell (the q projection over 16). "e4m3": the reference on
+# weights rounded to an 8-bit float (the nearest precision below bfloat16, a
+# lower bound of computing in one) against the whole reference, at three
+# seeds (tools/lfm2_grad_check.py; q at one). Each limit lies between the two.
+#
+#              loss     flips a layer  cos conv W_in  cos gate  cos router  cos q
+#  seen, worst 3.68e-5  167 (0.25%)    .999809        .98883    .97735      .999658
+#  LIMIT       1e-4     393 (0.6%)     .9985          .97       .94         .998
+#  e4m3        8.8e-5,  704 .. 1033    .98917 ..      .8796 ..  .7708 ..    .9748
+#              1.4e-4,  (1.1-1.6%)     .98988         .8808     .7809
+#              4.4e-4
+#
+# e4m3 is "not correct" by the counts and by every cosine at every seed, by
+# the loss at two of three (the loss is the blunt one). The expert
+# stack's and the router's gradients sit lower than any of OLMoE's witnesses
+# because 94 to 167 of a layer's 65,536 pairs flip between bf16 and f32
+# activations, an eighth of them on held experts, and under Zipf ids the
+# flipped tokens are copies of a few hot ids whose contributions add up
+# coherently: every tensor of an expert layer reads 0.977-0.993, every other
+# tensor of the model 0.9972 or better (the table of tools/lfm2_grad_check.py).
+# Other changes to the reference, for scale: picks not renormalised move the
+# gate's and the router's lengths 3.2 and 3.6 times; the convolution without
+# its output gate turns every witness to cosine 0.03. A missing QK-norm moves
+# the three witnesses of ISSUE 32 by less than bf16 does (0.02-normal weights
+# make the attention layer's scores small either way), which is why the q
+# projection is a fourth: without the QK-norm its cosine is 0.977.
+TOLERANCE = (1e-4,
+             "bf16 compute with top-4 flips against an f32 reference: 2.7x "
+             "the largest of 27 seeds (3.68e-5); the reference on e4m3 "
+             "weights moves 8.8e-5, 1.4e-4 and 4.4e-4. Blunt (0.02-normal "
+             "weights give every token nearly the entropy of the "
+             "vocabulary), so after_step0 holds the counts, the gradient, "
+             "the clip, the apply and the bias")
+#: token-expert pairs, of T * top_k a layer, that may sit on another expert
+#: than the reference's (top-4 flips between bf16 and f32 activations):
+#: half the sum over the 64 experts of |count - reference count|, per layer.
+#: Seen: 94 to 167 of 65,536; e4m3: 704 to 1,033
+FLIP_SHARE = 0.006
+#: leaves (the store's keys) whose gradient witnesses the backward pass, with
+#: the lowest cosine to the reference's jax.grad that passes: the dense
+#: layer's conv in-projection (upstream of everything: its gradient comes
+#: back through the head, four expert layers with their recomputation, the
+#: flash kernel's grouped backward, three gated convolutions and the dense
+#: SwiGLU), an expert stack of a conv layer (the grouped matmul's gradient
+#: over the held groups), the router of the attention layer (where the
+#: sigmoid, the renormalisation over all four picks and the absent experts'
+#: zero weights act) and the attention layer's q projection (the per-head
+#: QK-norm, RoPE and the kernel's backward).
+#: Read from AdamW's first moment: no hook in the step.
+GRAD_COSINE = {"layer0/conv/in_proj/kernel": 0.9985,
+               "layer2/moe/gate": 0.97,
+               "layer1/moe/router/kernel": 0.94,
+               "layer1/attn/q/kernel": 0.998}
+#: how far a witness's length over the reference's may lie from the
+#: witnesses' mean (the clip's scale is common to them; it scaled by 0.367
+#: to 0.406). Seen: 0.0113 at most (0.0057 over the first thirteen seeds);
+#: e4m3's lengths lie as near (1.2%), so this limit tells no precision
+#: apart: it catches a witness that is scaled (picks not renormalised:
+#: the gate's and the router's lengths 3.2 and 3.6 times the reference's)
+GRAD_NORM_TOLERANCE = 0.025
+#: the updated witnesses against AdamW's rule applied by numpy in f64 to the
+#: store's own moments: the largest distance beyond the f32 rounding of the
+#: parameter itself (half an ulp of the result), in units of step 0's
+#: learning rate. Under the warm-up that rate is 5e-8, where a 0.02-normal
+#: f32 parameter rounds by up to 3.7e-9, 0.07 of the rate (OLMoE's constant
+#: 4e-4 reads 9.7e-6 rounding included): without the allowance the limit
+#: would have to pass a step that is 7% off. What no f32 step at this rate
+#: can show is the weight decay (1e-2 of the rate, a sixth of an ulp);
+#: tests/test_olmoe.py holds the store's AdamW to the whole rule. Seen
+#: beyond the rounding: 1.1e-7 to 4.0e-7 of the rate
+APPLY_TOLERANCE = 1e-5
+
+#: the steps n at which a run says its mean loss over n-7..n on stderr: the
+#: values ISSUE 32 lets the traffic's ``loss_step`` take
+LOSS_STEPS = (48, 64, 96)
+
+
+# -- operations and bytes from shapes -----------------------------------------
+
+def pair_flops(config):
+    """Forward and backward of one token-expert pair through its expert:
+    three matrices, 3 x 2 x D x F."""
+    return 3 * 6.0 * config["hidden_size"] * config["moe_intermediate_size"]
+
+
+def dense_flops(config, tokens, seq_len):
+    """Operations of one training step outside the experts, that the model
+    requires: forward and backward (3 x 2 a parameter a token) over the
+    matmuls every token passes (the conv mixers' two projections, the
+    attention layers' four, the dense SwiGLU, the routers, the tied head)
+    plus attention's quadratic term (QK^T and PV, forward and backward)
+    halved for the causal mask. The gates and taps are not counted, nor is
+    recomputation."""
+    d = config["hidden_size"]
+    kv = d // config["num_attention_heads"] * config["num_key_value_heads"]
+    per_token = 6.0 * d * config["vocab_size"]
+    for i, kind in enumerate(config["layer_types"]):
+        if kind == "conv":
+            per_token += 6.0 * 4 * d * d
+        else:
+            per_token += 6.0 * (2 * d * d + 2 * d * kv) \
+                + 3 * 4 * seq_len * d / 2
+        if i < config["num_dense_layers"]:
+            per_token += 6.0 * 3 * d * config["intermediate_size"]
+        else:
+            per_token += 6.0 * d * config["router_width"]
+    return float(tokens * per_token)
+
+
+def step_flops(config, tokens, seq_len, live_pairs):
+    """``dense_flops`` plus the pairs the step computed here."""
+    return dense_flops(config, tokens, seq_len) \
+        + live_pairs * pair_flops(config)
+
+
+def conv_gate_bytes(config, tokens, itemsize=2):
+    """HBM bytes of ``ops/gated_conv.py`` in one step, from its shapes: a
+    conv layer's forward reads B, C, X and writes y, its backward reads
+    those three and dy and writes their three gradients: 11 arrays of
+    [tokens, D]. The filter and its gradient are 2048 x 3."""
+    layers = sum(kind == "conv" for kind in config["layer_types"])
+    return float(layers * 11 * tokens * config["hidden_size"] * itemsize)
+
+
+def flash_forward_cost(batch, heads, kv_heads, seq, head_dim, layers,
+                       itemsize=2):
+    """Operations and HBM bytes of the causal grouped-query forward kernel's
+    calls of one step (the backward is a scan in plain XLA): QK^T and PV are
+    2 * S * S * d multiply-adds each a query head, halved for the causal
+    mask; q is read and o written at ``heads``, k and v are read at
+    ``kv_heads`` (the kernel's index map reads head h // group: no repeated
+    copy is made or counted), and the f32 logsumexp is written."""
+    flops = layers * 4.0 * batch * heads * seq * seq * head_dim / 2
+    nbytes = layers * (
+        2.0 * batch * (heads + kv_heads) * seq * head_dim * itemsize
+        + 4.0 * batch * heads * seq)
+    return flops, nbytes
+
+
+def learning_rate(optimizer, warmup_steps):
+    """The configuration's rate as the store takes it, and AdamW's rule as
+    step 0 applies it: with ``warmup_steps`` the rate climbs linearly to
+    ``learning_rate``, ``(step + 1) / warmup_steps`` of it at ``step``, so
+    that step 0 moves the parameters too (the apply check reads them)."""
+    peak = float(optimizer["learning_rate"])
+    if not warmup_steps:
+        return peak, dict(optimizer)
+
+    def rate(count):
+        return peak * jnp.minimum(count + 1, warmup_steps) / warmup_steps
+
+    return rate, {**optimizer, "learning_rate": peak / warmup_steps}
+
+
+def bias_by_sign_rule(counts, rate):
+    """Step 0's selection bias from zeros, by numpy: per layer
+    ``rate * sign(mean(c) - c_e)`` over the step's own counts, f32."""
+    c = np.asarray(counts, np.float64)
+    return (np.float32(rate)
+            * np.sign(c.mean(axis=-1, keepdims=True) - c).astype(np.float32))
+
+
+def step0_checks(got, want, witnesses, clipped_norm, rule, pairs, rate):
+    """What ``correct`` holds beyond step 0's loss. ``got`` / ``want``: the
+    step's and the reference's aux. ``witnesses``: per name ``before`` and
+    ``after`` (the parameter around step 0), ``mu`` and ``nu`` (the store's
+    moments after it) and ``reference_grad``. ``clipped_norm``: the global
+    norm of the clipped gradient. ``pairs``: T * top_k, a layer. Returns
+    the loop's ``{"checks": .., "detail": ..}``."""
+    counts = np.asarray(got["expert_tokens"], np.int64)        # [L, 64]
+    ref_counts = np.asarray(want["expert_tokens"], np.int64)
+    held = np.asarray(got["held_tokens"], np.int64)            # [L, held]
+    moved = np.abs(counts - ref_counts).sum(axis=-1) // 2      # a layer
+    detail = {"pairs_routed_per_layer": counts.sum(axis=-1).tolist(),
+              "pairs_held_per_layer": held.sum(axis=-1).tolist(),
+              "reference_pairs_held_per_layer": np.asarray(
+                  want["held_tokens"], np.int64).sum(axis=-1).tolist(),
+              "pairs_on_another_expert_than_reference": moved.tolist(),
+              "clipped_gradient_norm": clipped_norm}
+    clip = rule["clip_by_global_norm"]
+    scales = []
+    for name, w in witnesses.items():
+        grad = np.asarray(w["mu"], np.float64) / (1 - rule["b1"])
+        detail[f"grad_cosine.{name}"] = cosine(grad, w["reference_grad"])
+        scales.append(np.linalg.norm(grad)
+                      / np.linalg.norm(np.asarray(w["reference_grad"],
+                                                  np.float64)))
+        after = np.asarray(w["after"], np.float32)
+        off = np.abs(after.astype(np.float64) - adamw_first_step(
+            w["before"], w["mu"], w["nu"], **rule))
+        detail[f"apply_error_lr.{name}"] = float(np.max(np.maximum(
+            off - 0.5 * np.spacing(np.abs(after)).astype(np.float64), 0.0))
+            / rule["learning_rate"])
+    detail["grad_norm_over_reference"] = [float(s) for s in scales]
+    detail["clip_scale"] = scale = float(np.mean(scales))
+    clipped_to_limit = abs(clipped_norm - clip) <= 1e-3 * clip
+    bias = np.asarray(got["expert_bias"], np.float32)
+    return {"checks": {
+        "no_dropped_tokens": bool((counts.sum(axis=-1) == pairs).all()),
+        "expert_counts_match_reference":
+            bool((moved <= FLIP_SHARE * pairs).all()),
+        "gradient_matches_reference": all(
+            detail[f"grad_cosine.{name}"] >= GRAD_COSINE[name]
+            for name in witnesses) and bool(max(
+                abs(s / scale - 1) for s in scales) <= GRAD_NORM_TOLERANCE),
+        "gradient_clipped_to_global_norm":
+            clipped_norm <= clip * (1 + 1e-3) and (
+                clipped_to_limit or abs(scale - 1) <= GRAD_NORM_TOLERANCE),
+        "adamw_apply_matches_rule": all(
+            detail[f"apply_error_lr.{name}"] <= APPLY_TOLERANCE
+            for name in witnesses),
+        # exactly the rule, on the step's own counts: the bias is not the
+        # optimizer's and nothing rounds on the way
+        "expert_bias_follows_sign_rule":
+            bool(np.array_equal(bias, bias_by_sign_rule(counts, rate)))},
+        "detail": detail}
+
+
+def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
+    import ps_tpu as ps
+    from ps_tpu.data.prefetch import device_prefetch
+    from ps_tpu.models.lfm2 import (Lfm2Config, init_expert_bias,
+                                    init_params, make_loss_fn)
+    from ps_tpu.parallel.sharding import replicated
+
+    if config["model"] != "lfm2_moe":
+        raise ValueError(f"lfm2_step knows no model {config['model']!r}")
+    if traffic["ids"]["kind"] != "zipf":
+        raise ValueError(f"unknown id distribution {traffic['ids']['kind']!r}")
+    if traffic["input"] != "direct":
+        raise ValueError(f"unknown input mode {traffic['input']!r}")
+    if traffic["pool"] != "fresh":
+        raise ValueError(f"lfm2_step re-uses no batch: pool {traffic['pool']!r}")
+    t_start = time.perf_counter()
+    ctx = ps.init(backend="tpu")
+    cfg = Lfm2Config.from_dict(config)
+    per_chip = int(traffic["per_chip_batch"])
+    batch = per_chip * chips
+    seq = int(traffic["seq_len"])
+    tokens = per_chip * seq                      # a chip, a step
+    pairs = tokens * cfg.num_experts_per_tok     # a chip, a step, a layer
+
+    opt = dict(config["optimizer"])
+    warmup_steps = opt.pop("warmup_steps", 0)
+    rate, rule = learning_rate(opt, warmup_steps)
+    store = ps.KVStore(optimizer=opt.pop("name"), placement="replicated",
+                       **{**opt, "learning_rate": rate})
+    # the weights are made on the device from the seed; the store keeps its
+    # own buffers (it donates them every step), so the tree made here goes
+    params = jax.block_until_ready(
+        jax.jit(lambda k: init_params(k, cfg))(seed_key(seed)))
+    t_weights = time.perf_counter()
+    jax.block_until_ready(store.init(params))
+    del params
+    t_store = time.perf_counter()
+    fused = store.make_step(make_loss_fn(cfg, attn=traffic["attn"]),
+                            has_aux=True)
+    batches = fresh_batches(batch, seq, cfg.vocab_size, traffic["ids"]["s"],
+                            seed)
+    # the state that is not the optimizer's: one device value, handed from
+    # each step to the next
+    state = {"expert_bias": jax.device_put(init_expert_bias(cfg),
+                                           replicated(ctx.mesh))}
+
+    # device values, read at the end only: a scalar, [L, 64] and [L, held]
+    losses, expert_tokens, held_tokens = [], [], []
+    first = {}
+
+    def step(b):
+        loss, _, aux = fused(b, state["expert_bias"])
+        if not expert_tokens:
+            first["system"] = aux
+        state["expert_bias"] = aux["expert_bias"]
+        losses.append(loss)
+        expert_tokens.append(aux["expert_tokens"])
+        held_tokens.append(aux["held_tokens"])
+        return loss
+
+    plain = jax.jit(lambda params, b, bias: reference.witness_grads(
+        params, b, bias, config, GRAD_COSINE))
+
+    def reference_loss(b):
+        params = store.params()
+        with jax.default_matmul_precision("highest"):
+            (loss, aux), grads = plain(params, b, state["expert_bias"])
+        first["reference"] = jax.device_get(aux)
+        first["witnesses"] = {
+            # the store donates its buffers to step 0: copies, on the host
+            name: {"before": np.asarray(store.pull(name)),
+                   "reference_grad": np.asarray(grad)}
+            for name, grad in grads.items()}
+        return float(loss)
+
+    def after_step0():
+        """More than step 0's loss: ``step0_checks`` on what the store
+        holds once step 0 is done."""
+        def moment(key, which):
+            return optax.tree_utils.tree_get(store.optimizer_state(key),
+                                             which)
+
+        for name, w in first["witnesses"].items():
+            w.update(after=np.asarray(store.pull(name)),
+                     mu=np.asarray(moment(name, "mu")),
+                     nu=np.asarray(moment(name, "nu")))
+        clipped_norm = float(jnp.sqrt(sum(
+            jnp.vdot(m, m) for m in (moment(k, "mu") for k in store.keys())))
+        ) / (1 - rule["b1"])
+        return step0_checks(jax.device_get(first["system"]),
+                            first["reference"], first["witnesses"],
+                            clipped_norm, rule, pairs * chips,
+                            cfg.bias_update_rate)
+
+    def counters():
+        values = [float(x) for x in jax.device_get(losses)]
+        print("lfm2_step: mean loss of steps n-7..n " + json.dumps(
+            {n: stats.loss_at_n(values, n) for n in LOSS_STEPS
+             if n < len(values)}), file=sys.stderr)
+        counts = np.asarray(jax.device_get(expert_tokens), np.float64)
+        held = np.asarray(jax.device_get(held_tokens), np.float64)
+        routed = pairs * chips * cfg.num_expert_layers * len(counts)
+        print("lfm2_step: held share of the pairs, by layer "
+              + json.dumps((held.sum(axis=(0, 2))
+                            / counts.sum(axis=(0, 2))).round(5).tolist())
+              + f"; final expert_bias range "
+              f"{float(jnp.min(state['expert_bias'])):+.4f} .. "
+              f"{float(jnp.max(state['expert_bias'])):+.4f}",
+              file=sys.stderr)
+        return {"lfm2_dropped_tokens": float(routed - counts.sum()),
+                "lfm2_load_max_over_mean":
+                float(np.mean(counts.max(axis=-1) / counts.mean(axis=-1))),
+                "lfm2_held_pair_share": float(held.sum() / counts.sum()),
+                # all expert layers of one chip, a step
+                "lfm2_live_pairs_per_step":
+                float(held.sum() / len(held) / chips)}
+
+    heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
+    facts = {
+        "lfm2_dense_flops_per_step": dense_flops(config, tokens, seq),
+        "lfm2_flops_per_pair": pair_flops(config),
+        "lfm2_conv_gate_bytes_per_step": conv_gate_bytes(
+            config, tokens, np.dtype(cfg.dtype).itemsize),
+        "lfm2_expected_flops_per_step": step_flops(
+            config, tokens, seq, pairs * cfg.num_expert_layers
+            * cfg.num_experts / cfg.router_width),
+        "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
+                                             traffic["ids"]["s"]),
+        # where set-up's build phase goes, seconds
+        "build_s": {"init_and_weights": t_weights - t_start,
+                    "store_init": t_store - t_weights},
+    }
+    if traffic["attn"] == "flash":
+        facts["lfm2_flash_flops"], facts["lfm2_flash_bytes"] = \
+            flash_forward_cost(
+                per_chip, heads, kv_heads, seq, cfg.head_dim,
+                sum(k == "full_attention" for k in cfg.layer_types),
+                np.dtype(cfg.dtype).itemsize)
+        facts["kernel_targets"] = config["kernel_targets"]
+    stream = device_prefetch(batches, place=store.shard_batch)
+    return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,
+                reference_loss=reference_loss, tolerance=TOLERANCE,
+                counters=counters, facts=facts, close=ps.shutdown,
+                after_step0=after_step0)

@@ -2,7 +2,7 @@
 
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
-``ops/sparse_apply.py``, ``models/olmoe.py``) and land in the ``op_name`` of every HLO
+``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``) and land in the ``op_name`` of every HLO
 instruction traced under them; the host spans are recorded with
 ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``). ``benchmark/layer_metrics/scope.py`` and ``host.py``
@@ -47,6 +47,15 @@ ATTN = "ps.attn"                  # q/k/v projections, QK-norm, RoPE, attention,
 HEAD = "ps.head"                  # final norm, head matmul, cross entropy
 
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERT, MOE_COMBINE, ATTN, HEAD)
+
+# -- scopes of the hybrid decoder (models/lfm2.py), beside the six above -------
+# Read by ``benchmark/layer_metrics/lfm2.py``, which keeps its own copy.
+# CONV_GATE nests under CONV, so CONV's time holds it.
+CONV = "ps.conv"                  # the conv mixer: in projection, gates and taps, out projection
+CONV_GATE = "ps.conv/gate"        # ops/gated_conv.py alone: the two gates and the causal taps
+FFN = "ps.ffn"                    # the dense SwiGLU of the leading layers
+
+LFM2_SCOPES = MOE_SCOPES + (CONV, CONV_GATE, FFN)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

@@ -41,6 +41,14 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   O(S · 128) per (b, h): the scan's key block is ``_BWD_BLOCK_K``, its own
   constant, whatever tile the forward ran at.
 
+- GROUPED-QUERY attention (fewer K/V heads than query heads, LFM2's 32 on
+  8): ``k`` and ``v`` come in at their own head count and the forward's K/V
+  index maps read head ``bh // group``; no repeated copy of K and V exists
+  in HBM (at [2, 8192, 32, 64] bf16 the two copies would be 134 MB a layer,
+  written and read again). The backward scan repeats the two in f32 as it
+  casts them and sums dk and dv over each group. With equal head counts the
+  program is the one it was.
+
 The padding mask is a [B, S] int/bool array (1 = attend), matching the
 BERT convention; causal and mask compose. Numerics: parity with the
 reference einsum attention is asserted to ~1e-5 f32 in
@@ -228,11 +236,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
 
 def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
                interpret):
-    """q/k/v: [BH, S, d]; mask: [B, S] routed per program."""
+    """q: [BH, S, d]; k/v: [BH / group, S, d]; mask: [B, S] routed per
+    program."""
     bh, seq, d = q.shape
     b = mask.shape[0]
     heads = bh // b
+    group = bh // k.shape[0]
     num_k = seq // block_k
+
+    def kv_head(bh_):
+        # query heads b * h + i of one group are consecutive, so their K/V
+        # head b * (h / group) + i // group is bh_ // group
+        return bh_ if group == 1 else bh_ // group
 
     def kv_block(i, j):
         if not causal:
@@ -249,10 +264,10 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
             pl.BlockSpec((None, block_q, d), lambda bh_, i, j: (bh_, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((None, block_k, d),
-                         lambda bh_, i, j: (bh_, kv_block(i, j), 0),
+                         lambda bh_, i, j: (kv_head(bh_), kv_block(i, j), 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((None, block_k, d),
-                         lambda bh_, i, j: (bh_, kv_block(i, j), 0),
+                         lambda bh_, i, j: (kv_head(bh_), kv_block(i, j), 0),
                          memory_space=pltpu.VMEM),
             # [B, 1, S]: keys along the lanes, as the score tile has them
             pl.BlockSpec((None, 1, block_k),
@@ -289,9 +304,13 @@ def _blockwise_bwd(q, k, v, mask, o, lse, do, *, scale, causal, block_k,
     """Exact flash backward, blockwise over keys — recomputes per-block
     probabilities from the saved logsumexp; never forms [S, S]."""
     bh, seq, d = q.shape
+    group = bh // k.shape[0]
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
+    if group > 1:
+        # each K/V head once for every query head it serves
+        kf, vf = (jnp.repeat(x, group, axis=0) for x in (kf, vf))
     dof = do.astype(jnp.float32)
     # D_i = sum_d dO_i * O_i  — the softmax-jacobian row term
     delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)  # [BH, S]
@@ -328,6 +347,9 @@ def _blockwise_bwd(q, k, v, mask, o, lse, do, *, scale, causal, block_k,
     )
     dk = jnp.moveaxis(dk_blocks, 0, 1).reshape(bh, seq, d)
     dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(bh, seq, d)
+    if group > 1:
+        dk, dv = (jnp.sum(x.reshape(bh // group, group, seq, d), axis=1)
+                  for x in (dk, dv))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -364,10 +386,12 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mesh: Optional[Mesh] = None) -> jax.Array:
-    """Fused flash attention. ``q/k/v``: [B, S, h, d] (the model-side
-    layout of ps_tpu/models/{bert,lm}.py); ``mask``: optional [B, S] with
-    1 = attend (BERT padding convention); ``causal`` composes with it.
-    Returns [B, S, h, d].
+    """Fused flash attention. ``q``: [B, S, h, d] (the model-side layout
+    of ps_tpu/models/{bert,lm}.py); ``k/v``: [B, S, h_kv, d] with ``h_kv``
+    dividing ``h`` (each K/V head serves ``h / h_kv`` consecutive query
+    heads; equal for plain multi-head attention); ``mask``: optional
+    [B, S] with 1 = attend (BERT padding convention); ``causal`` composes
+    with it. Returns [B, S, h, d].
 
     ``block_q`` / ``block_k`` tile the forward kernel; left at None they
     are ``forward_tiles``' choice from the operands' shapes. ``interpret``
@@ -384,6 +408,10 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     dimension its axis does not divide is computed replicated.
     """
     b, seq, h, d = q.shape
+    h_kv = k.shape[2]
+    if h % h_kv or v.shape != k.shape:
+        raise ValueError(f"{h} query heads on K/V of shapes {k.shape}, "
+                         f"{v.shape}: the K/V heads must divide them")
     if block_q is None or block_k is None:
         chosen = forward_tiles(seq, d, q.dtype.itemsize, causal)
         block_q, block_k = block_q or chosen[0], block_k or chosen[1]
@@ -402,8 +430,9 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
         lb = q.shape[0]
         lh = q.shape[2]
 
-        def pack(x):  # [B, S, h, d] -> [B*h, S, d]
-            return jnp.transpose(x, (0, 2, 1, 3)).reshape(lb * lh, seq, d)
+        def pack(x):  # [B, S, h, d] -> [B*h, S, d], at x's own head count
+            return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+                lb * x.shape[2], seq, d)
 
         out = _flash(pack(q), pack(k), pack(v), mask, scale, causal,
                      block_q, block_k, interpret)
@@ -420,7 +449,8 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
         size = mesh.shape.get(name, 1)
         return name if size > 1 and n % size == 0 else None
 
-    spec = P(axis(DATA_AXIS, b), None, axis(MODEL_AXIS, h), None)
+    # what divides the K/V heads divides the query heads they serve
+    spec = P(axis(DATA_AXIS, b), None, axis(MODEL_AXIS, h_kv), None)
     # check_vma off: jax 0.9.0 types the kernel's VMEM scratch as unvarying
     # and refuses to mix it with the varying loads inside the kernel body
     # ("Primitive mul requires varying manual axes to match")

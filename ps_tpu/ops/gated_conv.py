@@ -1,0 +1,78 @@
+"""The double-gated short causal convolution of LFM2's ``conv`` layers.
+
+Between the mixer's two projections, over ``bcx = h @ W_in`` [B, S, 3 * D]::
+
+    B, C, X = split(bcx, 3)             # three gates' worth of channels
+    u = B * X                           # the input gate
+    v_t = sum_j w[:, j] * u_{t-(L-1)+j} # depthwise, causal, zero left pad
+    y = C * v                           # the output gate
+
+with ``w`` [D, L] one filter of ``L`` taps a channel (``conv_L_cache``, 3 in
+the published models; ``conv_bias`` false). No position enters it, and
+output ``t`` reads inputs ``t-L+1 .. t`` only. All of it is element-wise work
+over ``[tokens, D]`` arrays: bandwidth's, between two matmuls that are the
+MXU's.
+
+Written as one ``jax.custom_vjp`` in plain ``jax.numpy`` so that XLA makes
+one fusion of each direction and nothing but ``bcx`` and ``w`` is kept for
+the backward pass, which recomputes ``u`` and ``v`` (left to autodiff, B, X,
+C, u and v would each be a residual of ``[tokens, D]``). By its shapes the
+forward reads three ``D``-wide arrays and writes one, the backward reads
+four and writes three: 11 x tokens x D x itemsize bytes a layer, which
+``benchmark/families/lfm2_step.py::conv_gate_bytes`` counts. The products
+and the taps' sum run in f32 and the results are cast to ``bcx``'s dtype.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def _shift(u, by: int):
+    """``u`` [B, S, D] moved ``by`` positions to the right along S (to the
+    left where negative), zeros coming in."""
+    if by == 0:
+        return u
+    pad = jnp.zeros_like(u[:, :abs(by)])
+    if by > 0:
+        return jnp.concatenate([pad, u[:, :-by]], axis=1)
+    return jnp.concatenate([u[:, -by:], pad], axis=1)
+
+
+def _taps(u, w, sign: int):
+    """``sum_j w[:, j] * u_{t - sign * (L-1-j)}``: the causal filter
+    (``sign`` 1) or its transpose (-1)."""
+    taps = w.shape[-1]
+    return sum(w[:, j] * _shift(u, sign * (taps - 1 - j))
+               for j in range(taps))
+
+
+@jax.custom_vjp
+def gated_short_conv(bcx, w):
+    """``bcx`` [B, S, 3 * D], ``w`` [D, L] -> ``C * conv(B * X)`` [B, S, D]
+    in ``bcx``'s dtype."""
+    b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    return (c * _taps(b * x, w.astype(jnp.float32), 1)).astype(bcx.dtype)
+
+
+def _fwd(bcx, w):
+    return gated_short_conv(bcx, w), (bcx, w)
+
+
+def _bwd(res, dy):
+    bcx, w = res
+    b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    wf = w.astype(jnp.float32)
+    dy = dy.astype(jnp.float32)
+    u = b * x
+    dv = dy * c
+    du = _taps(dv, wf, -1)
+    taps = w.shape[-1]
+    dw = jnp.stack([jnp.sum(dv * _shift(u, taps - 1 - j), axis=(0, 1))
+                    for j in range(taps)], axis=-1)
+    dbcx = jnp.concatenate([du * x, dy * _taps(u, wf, 1), du * b], axis=-1)
+    return dbcx.astype(bcx.dtype), dw.astype(w.dtype)
+
+
+gated_short_conv.defvjp(_fwd, _bwd)

@@ -20,6 +20,20 @@ Permutations move rows with gathers in both directions: the backward pass of
 a gather by a permutation is a gather by its inverse, which two
 ``custom_vjp`` rules state (``permute``, ``_dispatch``); left to autodiff it
 would be a scatter-add of ``T * top_k`` rows.
+
+**A layer that holds a share of the experts** (``route(..., held=(start,
+count))``: one chip of an expert-parallel group, without its exchange). The
+router keeps its published width and every token its ``top_k`` picks over all
+``E``; the pairs whose expert is held are sorted to the front in expert
+order, the grouped matmuls run over ``[count, D, F]`` stacks with the held
+experts' group sizes, and ``combine`` gives every absent pair the weight 0:
+the layer's output is the part of the whole layer's that its own experts
+give, and the shares of all chips add up to it. Dropless still. The row
+buffers keep their worst-case length ``T * top_k``; what the grouped matmul
+leaves in the rows past the last group is unspecified, so those rows are
+masked with ``where`` (never by a product: 0 x NaN) where they enter
+(``dispatch``, which also zeroes the cotangent on its way back to the
+tokens) and where they leave (``combine``).
 """
 
 from __future__ import annotations
@@ -35,12 +49,17 @@ class Routing(NamedTuple):
     """What ``route`` decides for ``T`` tokens, ``E`` experts, ``k`` picks."""
 
     logits: jax.Array       # [T, E] f32 router logits
-    probs: jax.Array        # [T, E] f32 softmax over all E
-    weights: jax.Array      # [T, k] f32 probabilities of the picks, as they are
+    probs: jax.Array        # [T, E] f32 scores over all E (softmax or sigmoid)
+    weights: jax.Array      # [T, k] f32 weights of the picks
     experts: jax.Array      # [T, k] int32 picked experts, best first
-    group_sizes: jax.Array  # [E] int32 pairs per expert; sums to T * k
+    group_sizes: jax.Array  # [held] int32 pairs per held expert
     order: jax.Array        # [T * k] pair indices (t * k + j) sorted by expert
     inverse: jax.Array      # [T * k] position of pair (t * k + j) in that order
+    #: [E] int32 pairs per expert over all E; sums to T * k. ``group_sizes``
+    #: itself where every expert is held
+    counts: jax.Array = None
+    #: [T, k] bool, the pair's expert is held; None where every expert is
+    live: jax.Array = None
 
 
 @jax.custom_vjp
@@ -62,37 +81,86 @@ def _permute_bwd(res, g):
 permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def route(x, router, top_k: int, renormalize: bool = False) -> Routing:
-    """Router of ``x`` [T, D] with ``router`` [D, E]: logits and softmax in
-    f32 over all ``E`` experts, the ``top_k`` largest probabilities and their
-    experts. The picks' probabilities are used as they are
-    (``norm_topk_prob: false``); ``renormalize`` divides them by their sum.
+def route(x, router, top_k: int, renormalize: bool = False, *,
+          scoring: str = "softmax", bias=None, renorm_eps: float = 0.0,
+          scaling: float = 1.0, held=None) -> Routing:
+    """Router of ``x`` [T, D] with ``router`` [D, E]: logits and scores in
+    f32 over all ``E`` experts, the ``top_k`` largest and their experts.
+    ``scoring`` is 'softmax' or 'sigmoid'. ``bias`` [E], if given, is added
+    to the scores for the selection only: it carries no gradient and never
+    enters a weight (loss-free balancing, ``balance_bias``). The picks'
+    scores are used as they are (``norm_topk_prob: false``); ``renormalize``
+    divides them by their sum ``+ renorm_eps``, over all ``top_k`` picks
+    whether or not their experts are held; ``scaling`` multiplies them
+    (``routed_scaling_factor``). ``held = (start, count)`` says which
+    experts this layer computes (module docstring); None is all of them.
     The matmul runs at the highest precision: 2 * T * D * E operations, and
     which expert a token goes to should not hang on a bf16 pass."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     num_experts = probs.shape[-1]
-    _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs), top_k)
+    select = jax.lax.stop_gradient(probs)
+    if bias is not None:
+        select = select + jax.lax.stop_gradient(bias).astype(select.dtype)
+    _, experts = jax.lax.top_k(select, top_k)
     # the picks' probabilities through a 0/1 mask, so that their cotangent
     # is a dense product and not a scatter into [T, E]
     picked = jax.nn.one_hot(experts, num_experts, dtype=probs.dtype)
     weights = jnp.einsum("te,tke->tk", probs, picked)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + renorm_eps if renorm_eps else total)
+    if scaling != 1.0:
+        weights = weights * scaling
     flat = experts.reshape(-1)
-    order = jnp.argsort(flat, stable=True)
+    if held is None or tuple(held) == (0, num_experts):
+        order = jnp.argsort(flat, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+        return Routing(logits, probs, weights, experts.astype(jnp.int32),
+                       group_sizes, order, inverse, group_sizes)
+    start, count = held
+    if not 0 <= start <= start + count <= num_experts:
+        raise ValueError(f"held experts {held} lie outside 0..{num_experts}")
+    # held pairs first, in expert order; the absent ones after them
+    local = flat - start
+    is_held = (local >= 0) & (local < count)
+    order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
     inverse = jnp.argsort(order)
-    group_sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+    counts = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
     return Routing(logits, probs, weights, experts.astype(jnp.int32),
-                   group_sizes, order, inverse)
+                   counts[start:start + count], order, inverse, counts,
+                   is_held.reshape(experts.shape))
+
+
+def balance_bias(bias, counts, rate: float):
+    """Loss-free balancing (Wang et al. 2024, arXiv:2408.15664): the
+    selection bias of each expert moves by ``rate`` towards the mean load,
+    ``b_e += rate * sign(mean(c) - c_e)`` with ``c`` the step's pairs per
+    expert over all ``E`` (last axis). A rule of its own, not the
+    optimizer's: the bias has no gradient."""
+    c = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)
 
 
 def dispatch(x, routing: Routing):
     """Rows of ``x`` [T, D] in expert order: [T * k, D], each token's row
     once for each of its picks."""
     top_k = routing.experts.shape[-1]
-    return _dispatch(x, routing.order, routing.inverse, top_k)
+    rows = _dispatch(x, routing.order, routing.inverse, top_k)
+    if routing.live is None:
+        return rows
+    # rows past the last group belong to no held expert: zeros in, and on
+    # the way back whatever the grouped matmuls' gradients left there is
+    # dropped before it is summed into a token
+    in_group = jnp.arange(rows.shape[0]) < jnp.sum(routing.group_sizes)
+    return jnp.where(in_group[:, None], rows, 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -117,7 +185,8 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def expert_ffn(rows, gate, up, down, group_sizes):
     """SwiGLU of every expert over its group of ``rows`` [T * k, D]:
     ``(silu(rows @ gate[e]) * (rows @ up[e])) @ down[e]`` with ``gate``,
-    ``up`` [E, D, F] and ``down`` [E, F, D]."""
+    ``up`` [E, D, F] and ``down`` [E, F, D], ``E`` the experts held. Rows
+    past ``sum(group_sizes)`` come out unspecified."""
     g = jax.lax.ragged_dot(rows, gate, group_sizes)
     u = jax.lax.ragged_dot(rows, up, group_sizes)
     return jax.lax.ragged_dot(jax.nn.silu(g) * u, down, group_sizes)
@@ -127,9 +196,12 @@ def combine(rows, routing: Routing):
     """The experts' outputs ``rows`` [T * k, D] back in token order and
     summed over each token's picks with the router's weights: [T, D]."""
     t, top_k = routing.experts.shape
-    back = permute(rows, routing.inverse, routing.order)
-    out = jnp.einsum("tkd,tk->td", back.reshape(t, top_k, -1),
-                     routing.weights.astype(rows.dtype),
+    back = permute(rows, routing.inverse, routing.order).reshape(t, top_k, -1)
+    weights = routing.weights
+    if routing.live is not None:
+        back = jnp.where(routing.live[..., None], back, 0)
+        weights = jnp.where(routing.live, weights, 0)
+    out = jnp.einsum("tkd,tk->td", back, weights.astype(rows.dtype),
                      preferred_element_type=jnp.float32)
     return out.astype(rows.dtype)
 

@@ -1,0 +1,79 @@
+"""Kernels of the main path compiled for a described TPU v5e at the widths
+the benchmark runs them at: what Mosaic refuses (a tile over its VMEM, an
+index map it cannot lower) shows here and costs no chip time. Nothing runs,
+so nothing here says a word about values or time. One file, and the
+topology described inside a fixture: only one process may hold libtpu, and
+only the worker that is given this file loads it.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ps_tpu.ops import flash_attention
+from ps_tpu.ops.gated_conv import gated_short_conv
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:
+        pytest.skip(f"no v5e topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+#: [B, S, query heads, K/V heads, head dim], causal: the three cells' calls
+CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True),
+         "olmoe-1b-7b.s4096.zipf": ((2, 4096, 16, 16, 128), True),
+         "bert-base.s512.flash": ((32, 512, 12, 12, 64), False)}
+
+
+@pytest.mark.parametrize("cell", sorted(CALLS))
+def test_flash_forward_and_backward_compile_at_the_cells_shapes(
+        cell, one_chip, no_compile_cache):
+    (b, s, h, h_kv, d), causal = CALLS[cell]
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
+    assert "tpu_custom_call" in text          # the Mosaic kernel is in it
+
+
+def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
+    bcx = jax.ShapeDtypeStruct((2, 8192, 3 * 2048), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=one_chip)
+
+    def loss(bcx, w):
+        return jnp.sum(gated_short_conv(bcx, w).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(bcx, w).compile()
+    # XLA keeps f32 intermediates of [tokens, D] between the two fusions
+    # (873 MB where bcx is 201): the room PERF.md section 7 names
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

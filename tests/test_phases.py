@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
-from benchmark.layer_metrics import host, moe, scope
+from benchmark.layer_metrics import host, lfm2 as lfm2_metrics, moe, scope
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
 from ps_tpu.kv.sparse import SparseEmbedding
@@ -214,6 +214,115 @@ def test_expert_scopes_reach_the_step_hlo_forward_and_backward(
     assert moe.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
         == moe.MOE_EXPERT
     assert moe.scope_of("%fusion.7", "jit(f)/ps.apply/mul") is None
+
+
+def _hybrid_step():
+    """``(run, batch, bias)`` of ``make_step(has_aux=True)`` on a tiny
+    LFM2: a dense conv layer, an attention and a conv layer with experts,
+    two of eight held."""
+    from ps_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=3,
+        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
+        num_attention_heads=4, num_key_value_heads=2, router_width=8,
+        num_experts=2, expert_start=2, num_experts_per_tok=2,
+        dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: lfm2.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 17, dtype=np.int32).reshape(8, 17) * 7) % 64
+    step = store.make_step(lfm2.make_loss_fn(cfg), has_aux=True)
+    bias = lfm2.init_expert_bias(cfg)
+    return (lambda batch: step(batch, bias),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_hybrid_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What LFM2 adds to the scopes (``ps.conv``, ``ps.conv/gate``,
+    ``ps.ffn``) and the six it shares with OLMoE: each in the lowered step's
+    ``op_name``s under ``ps.grad``, forward and backward, the expert
+    layer's also inside its recomputation; the reader's copy is equal."""
+    assert phases.LFM2_SCOPES == lfm2_metrics.LFM2_SCOPES
+    assert phases.LFM2_SCOPES[:6] == phases.MOE_SCOPES
+    for name in ("CONV", "CONV_GATE", "FFN", "MOE_ROUTE", "MOE_DISPATCH",
+                 "MOE_EXPERT", "MOE_COMBINE", "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(lfm2_metrics, name)
+    assert not set(phases.LFM2_SCOPES) & set(phases.DEVICE_PHASES)
+    assert set(lfm2_metrics.SCOPE_METRICS) == set(phases.LFM2_SCOPES)
+    monkeypatch.setitem(BUILDERS, "hybrid", _hybrid_step)
+    names = scope.op_names_of(_step_hlo("hybrid"))
+    for s in phases.LFM2_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {lfm2_metrics.scope_of(own, n) for own, n in names.items()}
+    assert found == set(phases.LFM2_SCOPES) | {None}
+    # the gate is the innermost scope of its ops, and the mixer's holds it
+    assert lfm2_metrics.scope_of(
+        "%fusion.1", "jit(f)/ps.grad/jvp(ps.conv)/ps.conv/gate/mul") \
+        == lfm2_metrics.CONV_GATE
+    assert lfm2_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
+        == lfm2_metrics.MOE_EXPERT
+
+
+def test_lfm2_reader_on_a_hand_made_result(monkeypatch):
+    call = 'custom_call_target="tpu_custom_call"'
+    ops = {_ev("%in"): 0.004, _ev("%gate"): 0.002, _ev("%swiglu"): 0.006,
+           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
+           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
+           _ev("%flash", "custom-call") + call: 0.010,
+           _ev("%qkv"): 0.004, _ev("%ce"): 0.005, _ev("%embed"): 0.001,
+           _ev("%adam"): 0.007}
+    names = {"%in": "jit(f)/ps.grad/jvp(ps.conv)/dot_general",
+             "%gate": "jit(f)/ps.grad/transpose(jvp(ps.conv))/ps.conv/gate/m",
+             "%swiglu": "jit(f)/ps.grad/jvp(ps.ffn)/dot_general",
+             "%route": "jit(f)/ps.grad/jvp(ps.moe/route)/dot_general",
+             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
+             "%back": "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather",
+             "%ragged-dot-none.1": "ragged-dot-none",
+             "%flash": "jit(f)/ps.grad/jvp(ps.attn)/pallas_call",
+             "%qkv": "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general",
+             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
+             "%embed": "jit(f)/ps.grad/jvp()/gather",
+             "%adam": "jit(f)/ps.apply/mul"}
+    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
+         "counters": {"lfm2_live_pairs_per_step": 1000.0,
+                      "lfm2_held_pair_share": 0.125,
+                      "lfm2_load_max_over_mean": 3.0,
+                      "lfm2_dropped_tokens": 0.0},
+         "facts": {"kernel_targets": ["tpu_custom_call"],
+                   "lfm2_flops_per_pair": 1e6,
+                   "lfm2_dense_flops_per_step": 4e9,
+                   "lfm2_conv_gate_bytes_per_step": 0.5e9,
+                   "lfm2_flash_flops": 1e9, "lfm2_flash_bytes": 1.0},
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
+         "steps": 10, "window_s": 1.0}
+    out = lfm2_metrics.scope_times(r, names)
+    assert out["lfm2.conv_ms"] == pytest.approx(3.0)        # with its gate
+    assert out["lfm2.conv_gate_ms"] == pytest.approx(1.0)
+    assert out["lfm2.dense_ffn_ms"] == pytest.approx(3.0)
+    assert out["lfm2.route_ms"] == pytest.approx(0.5)
+    assert out["lfm2.dispatch_ms"] == pytest.approx(2.0)    # with combine
+    assert out["lfm2.expert_ms"] == pytest.approx(4.0)
+    assert out["lfm2.attn_ms"] == pytest.approx(7.0)
+    assert out["lfm2.head_ms"] == pytest.approx(2.5)
+    assert out["lfm2.conv_gate_hbm_share"] == pytest.approx(50.0)
+    assert out["lfm2.expert_mxu_share"] == pytest.approx(25.0)  # 1 of 4 ms
+    assert out["lfm2.flash_roofline"] == pytest.approx(20.0)    # 1 of 5 ms
+    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
+    whole = lfm2_metrics.read(r)
+    assert whole["lfm2.mfu"] == pytest.approx(5.0)   # 5e9 x 10 / s of 1e12
+    assert whole["lfm2.held_pair_share"] == 0.125
+    assert len([k for k in whole if k.startswith("lfm2.")]) == 15
+    # a program without the scopes, the counters or the grouped matmuls
+    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
+    assert lfm2_metrics.scope_times(r, {}) == {}
+    assert lfm2_metrics.read({"counters": {}, "facts": {}}) == {}
 
 
 def test_moe_reader_on_a_hand_made_result():
@@ -518,6 +627,18 @@ def _rehearse(cell):
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] and line["metrics"] == {}
     return line
+
+
+def test_benchmark_command_rehearses_the_lfm2_cell():
+    """PR 32's cell on the CPU, as OLMoE's: the command's own control flow
+    at the tiny sizes, ``correct`` with every step-0 check, and all fifteen
+    ``lfm2.*`` metrics listed."""
+    line = _rehearse("lfm2-24b-a2b.s8192.zipf")
+    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
+        listed = {m["name"] for m in json.load(f)["per_layer"]
+                  if m["name"].startswith("lfm2.")}
+    assert len(listed) == 15 and listed <= set(line["rehearsed"])
+    assert not {n for n in line["rehearsed"] if n.startswith("moe.")}
 
 
 @pytest.mark.parametrize("cell", ["olmoe-1b-7b.s4096.zipf",

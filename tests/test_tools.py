@@ -235,3 +235,23 @@ def test_olmoe_grad_check_rehearses():
         cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
         assert len(cosines) == 2
         assert found["loss_rel_diff"] > 5e-5 or min(cosines) < 0.999, name
+
+
+def test_lfm2_grad_check_rehearses():
+    """tools/lfm2_grad_check.py at the configuration's tiny sizes: the
+    system's gradients are the reference's, and every changed piece moves
+    the reference's loss or turns or stretches a witness's gradient."""
+    out = _run("lfm2_grad_check.py", "--rehearse")
+    assert out["worst"]["cosine"] > 1 - 1e-9
+    assert out["loss"]["rel_diff"] < 1e-5
+    assert out["pairs_on_another_expert"] == [0, 0, 0, 0]
+    assert set(out["knocked_out"]) == {
+        "reference_on_e4m3_weights", "picks_not_renormalised", "no_qk_norm",
+        "conv_without_output_gate"}
+    for name, found in out["knocked_out"].items():
+        cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
+        ratios = [v for k, v in found.items()
+                  if k.startswith("grad_norm_ratio")]
+        assert len(cosines) == len(ratios) == 4
+        assert found["loss_rel_diff"] > 5e-5 or min(cosines) < 0.999 \
+            or max(abs(r - 1) for r in ratios) > 0.01, name
