@@ -238,7 +238,9 @@ def flash_parity(*, batch=32, seq=512):
     the same SelfAttention parameters and seeded input through both ``attn``
     settings, forward and gradients, under a padding mask that really pads.
     Then the kernel at the tiles it chooses from its shapes against itself
-    at forced 128-wide blocks."""
+    at forced 128-wide blocks, and the causal call on fewer K/V heads
+    (LFM2's 4:1 at head 64) against an f32 einsum attention on repeated
+    K and V, output and all three gradients."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -299,6 +301,43 @@ def flash_parity(*, batch=32, seq=512):
     _require(err <= 2 * _BF16_U * scale,
              f"flash at its chosen {tiles} blocks differs from 128-wide "
              f"blocks")
+
+    # causal, four query heads to a K/V head, several tiles a head in both
+    # backward calls: dk and dv are summed over the group inside the
+    # kernel. The kernel rounds p and dS to bf16 and each gradient once
+    # more: 4 u of the largest entry against f32 on the same inputs.
+    gq, gseq, heads, kv_heads = 4, 2048, 8, 2
+    q = jnp.asarray(rng.standard_normal((gq, gseq, heads, 64)), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.standard_normal((gq, gseq, kv_heads, 64)),
+                        jnp.bfloat16) for _ in range(2))
+
+    def einsum_attention(q, k, v):
+        k, v = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / 8.0
+        s = jnp.where(jnp.tril(jnp.ones((gseq, gseq), bool)), s, -1e30)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v,
+                          precision="highest")
+
+    def out_and_grads(attn, *args):
+        def fn(q, k, v):
+            out = attn(q, k, v).astype(jnp.float32)
+            return jnp.sum(jnp.square(out)), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            fn, argnums=(0, 1, 2), has_aux=True))(*args)
+        return (out,) + grads
+
+    got = out_and_grads(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                        q, k, v)
+    want = out_and_grads(einsum_attention,
+                         *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        err, scale = _max_diff(g, w)
+        print(f"[{leg}] causal flash on {kv_heads} K/V heads for {heads} vs "
+              f"f32 einsum, {name}: max|diff| {err:.3e} = "
+              f"{err / (_BF16_U * scale):.2f} u x max|ref|")
+        _require(err <= 4 * _BF16_U * scale,
+                 f"grouped causal flash {name} differs from the f32 einsum "
+                 f"by {err / (_BF16_U * scale):.1f} u x max|ref| (bound 4)")
 
 
 def bert_leg(compiles, *, seq_len=512, per_chip_batch=32, num_layers=12,

@@ -20,7 +20,7 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   compute of key blocks fully past the diagonal via pl.when, and their
   K/V and mask index maps stop at the last live block (``_last_live``, the
   same expression as the skip), so a dead step copies nothing. Outputs:
-  attention out and the logsumexp rows.
+  attention out and the logsumexp, a [1, block_q] row a query block.
 - The TILES are ``forward_tiles``' choice from (seq, head_dim, itemsize,
   causal): among the divisors of seq that are multiples of 128, the widest
   key block and then the widest query block whose grid step fits
@@ -34,20 +34,47 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   block_k <= block_q because a key block wider than the query block
   computes scores the mask throws away: (1024, 1024) 1.48 ms, (512, 2048)
   1.80, (256, 4096) 2.43. ``block_q=`` / ``block_k=`` override the choice.
-- BACKWARD is a custom VJP in blockwise JAX (Rabe & Staats style): exact
-  probabilities are recomputed per key block from the saved logsumexp —
-  never the full [S, S] — inside a lax.scan that accumulates dq and emits
-  per-block dk/dv. XLA fuses each block's four matmuls; peak memory is
-  O(S · 128) per (b, h): the scan's key block is ``_BWD_BLOCK_K``, its own
-  constant, whatever tile the forward ran at.
+- BACKWARD is the custom VJP's two Pallas kernels, the forward's mathematics
+  run the other way: exact probabilities recomputed a tile at a time from
+  the saved logsumexp, never [S, S] in HBM, operands in their own dtype
+  (bf16 on the MXU) with f32 accumulation, p and dS rounded to it before
+  their second matmuls, exp, the gate, delta = rowsum(dO * O), the scale
+  and the accumulators in f32. The dk / dv call has the grid
+  (B*h_kv, S/block_k, group * S/block_q), its last axis innermost and
+  "arbitrary": it walks the query heads a K/V head serves and their query
+  blocks with dk and dv in f32 VMEM scratch, written once. Its tile is the
+  transposed one (keys down the sublanes), so the logsumexp and delta come
+  in as [1, block_q] rows, lane-dense in HBM, and the four matmuls contract
+  as the MXU takes them; no tile is transposed. Causal: query blocks
+  before ``_first_live`` (the mirror of ``_last_live``) are skipped by
+  pl.when and the q / dO / row index maps start there, so a dead step
+  copies nothing; live tiles wholly under the diagonal skip the position
+  mask. The dq call has the forward's grid and index maps, dq in f32
+  scratch, and turns the two rows into columns once a query block. Where
+  one tile spans the sequence and the head counts are equal (BERT), one
+  call gives all three gradients from the same dS, carries nothing and
+  computes delta itself (sum_k p dP), so neither delta nor the forward's
+  output is read: against the two calls 99.5 against 103.5 ms a step and
+  610 MB less (delta from XLA instead: 97.6 ms, but the packed output
+  stays live in every layer, 610 MB more). The gate is the forward's,
+  said of the predicate: a masked entry is 0 whatever exp gives, so a
+  fully masked row (logsumexp -1e30) has zero gradients.
+- The backward's TILES are ``backward_tiles``', by the forward's rule
+  under ``backward_vmem_bytes``' count (four f32 tiles where the forward
+  has two): (512, 512) at BERT's shape, (1024, 512) at both causal ones.
+  The tile matters little here, nothing is rescaled a step: at LFM2's
+  shape (1024, 512) 15.7 + 13.0 ms, (512, 1024) 15.6 + 13.2, (512, 512)
+  16.3 + 14.0, (1024, 256) 17.2 + 15.1, (2048, 512) 16.9 + 13.4;
+  (1024, 1024) 14.9 + 12.3 compiles at 16 MiB's edge, over the count, and
+  is not taken.
 
 - GROUPED-QUERY attention (fewer K/V heads than query heads, LFM2's 32 on
-  8): ``k`` and ``v`` come in at their own head count and the forward's K/V
-  index maps read head ``bh // group``; no repeated copy of K and V exists
-  in HBM (at [2, 8192, 32, 64] bf16 the two copies would be 134 MB a layer,
-  written and read again). The backward scan repeats the two in f32 as it
-  casts them and sums dk and dv over each group. With equal head counts the
-  program is the one it was.
+  8): ``k`` and ``v`` come in at their own head count and the K/V index
+  maps of the forward and of dq read head ``bh // group``; no repeated copy
+  of K and V exists in HBM (at [2, 8192, 32, 64] bf16 the two copies would
+  be 134 MB a layer, written and read again). The dk / dv call's grid is
+  over the K/V heads and sums each group in its scratch. With equal head
+  counts the program is the one it was.
 
 The padding mask is a [B, S] int/bool array (1 = attend), matching the
 BERT convention; causal and mask compose. Numerics: parity with the
@@ -68,15 +95,35 @@ Both agree with an f32 einsum attention within 0.6 bf16 roundoffs of the
 largest entry at every tile tried; inside the fused step the traces read
 0.50 and 1.48 ms. A grid step costs about 0.4 us before it computes
 anything, 0.15 ms of BERT's call: blocking several heads into one step
-would return at most that and was not built. Measured and left for the
-issue that writes the backward, whose kernel decides the residual's
-layout: the logsumexp as a [BH, 1, S] row (one in-kernel transpose) in
-place of the [BH, S, 1] column, which HBM pads to 128 lanes (100 MB a call
-at BERT's shape) and XLA re-lays out afterwards: 0.29 ms a BERT layer,
-values bit-identical (PERF.md section 7). The kernel compiles through
-Mosaic inside ``shard_map`` on four chips as on one (PR 21). What flash
-delivers besides is the O(S) attention memory; the default everywhere
-stays 'full', and ROADMAP.md D4 holds the comparison at seq 512.
+would return at most that and was not built.
+
+**The backward calls (my chip runs, PR 33: alone, median of 5 chains of 16
+calls, and inside the fused step from the cells' traces)**, with the
+plain-XLA scan over 128-key blocks they replace (f32 operands, every key
+block against all queries, K and V repeated a group, dk / dv stacked by
+dynamic-update-slice). FLOPs are the calls' own, the causal half where
+causal: four matmuls in dk / dv, three in dq, five in the one call:
+
+| shape [B*h, S, d] | call, tiles, grid | ms alone | ms in the step | the scan | of its roofline |
+|---|---|---|---|---|---|
+| [384, 512, 64], BERT's padding mask | one call, (512, 512), (384, 1, 1) | host-bound | 0.82 | 3.10 alone, 2.45 in the step | 40% (0.327 ms of MXU) |
+| [32, 4096, 128], causal | dk / dv (1024, 512), (32, 8, 4), 20 of 32 steps live | 2.21 | 3.90 both | 18.25 alone, 17.6 | 63% (1.40 ms) |
+| | dq, (32, 4, 8) | 1.86 | | | 56% (1.05 ms) |
+| [64, 8192, 64] on [16, 8192, 64], causal | dk / dv (1024, 512), (16, 16, 32), 72 of 128 steps live a head | 15.67 | 15.07 | 153.7 alone, 154 | 37% (5.58 ms) |
+| | dq, (64, 8, 16) | 12.97 | 12.30 | | 34% (4.19 ms) |
+
+All three gradients agree with an f32 einsum attention on the same bf16
+inputs within 1.0-1.3 roundoffs of their largest entry at every tile
+tried, as the scan's did (0.8-1.2). Skipping the position mask under the
+diagonal is 8% of the dk / dv call at LFM2's shape and 1% of dq's. The
+logsumexp is a [BH, 1, S] row (the forward transposes its column once a
+query block): the [BH, S, 1] column, which HBM pads to 128 lanes and XLA
+re-lays out, costs BERT 3.1 ms a step (319.8 against 310.1 samples/s/chip
+on one chip, 308.0 against 299.6 on four, and 1.2 GB more there; PERF.md
+section 7). The kernels compile through Mosaic inside
+``shard_map`` on four chips as on one (PR 21). What flash delivers besides
+is the O(S) attention memory; the default everywhere stays 'full', and
+ROADMAP.md D4 holds the comparison at seq 512.
 """
 
 from __future__ import annotations
@@ -95,11 +142,6 @@ from ps_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 _NEG_INF = -1e30
 
-# Key block of the backward's lax.scan. Its own constant, not the forward's
-# tile: at a sequence-wide block_k the scan would have one iteration and
-# materialise the [BH, S, S] score, probability and gradient tensors in f32.
-_BWD_BLOCK_K = 128
-
 # What one grid step may hold in VMEM by forward_vmem_bytes' count, of the
 # 16 MiB Mosaic scopes to a kernel on a v5e by default. The count leaves out
 # Mosaic's own temporaries (the iota and select masks, the bf16 copy of the
@@ -117,20 +159,17 @@ def forward_vmem_bytes(block_q: int, block_k: int, head_dim: int,
     q_o = 2 * 2 * block_q * lanes * itemsize
     k_v = 2 * 2 * block_k * lanes * itemsize
     mask = 2 * 8 * block_k * 4          # [1, block_k] int32 on 8 sublanes
-    lse = 2 * block_q * 128 * 4         # [block_q, 1] f32 on 128 lanes
+    lse = 2 * block_q * 128 * 4         # the [block_q, 128] its row is cut from
     scratch = block_q * (2 * 128 + lanes) * 4
     tiles = 2 * block_q * block_k * 4
     return q_o + k_v + mask + lse + scratch + tiles
 
 
-def forward_tiles(seq: int, head_dim: int, itemsize: int,
-                  causal: bool) -> tuple[int, int]:
-    """(block_q, block_k) of the forward kernel, from the operands' shapes
-    alone: the widest key block, then the widest query block, among the
-    divisors of ``seq`` that are multiples of 128 and keep
-    ``forward_vmem_bytes`` within ``_VMEM_BUDGET``. A causal call keeps
-    block_k <= block_q, so that a query block's diagonal tile, the one that
-    computes masked scores, is no wider than the block itself."""
+def _widest_tiles(vmem_bytes, what: str, seq: int, head_dim: int,
+                  itemsize: int, causal: bool) -> tuple[int, int]:
+    """The widest key block, then the widest query block, among the
+    divisors of ``seq`` that are multiples of 128 and keep ``vmem_bytes``
+    within ``_VMEM_BUDGET``; a causal call keeps block_k <= block_q."""
     if seq % 128:
         raise ValueError(
             f"seq len {seq} must be divisible by 128 (pad the sequence)")
@@ -139,18 +178,39 @@ def forward_tiles(seq: int, head_dim: int, itemsize: int,
         for block_q in sizes:
             if causal and block_k > block_q:
                 continue
-            if forward_vmem_bytes(block_q, block_k, head_dim,
-                                  itemsize) <= _VMEM_BUDGET:
+            if vmem_bytes(block_q, block_k, head_dim,
+                          itemsize) <= _VMEM_BUDGET:
                 return block_q, block_k
     raise ValueError(
-        f"no forward tile fits {_VMEM_BUDGET} B of VMEM at head_dim "
+        f"no {what} tile fits {_VMEM_BUDGET} B of VMEM at head_dim "
         f"{head_dim}, itemsize {itemsize}")
+
+
+def forward_tiles(seq: int, head_dim: int, itemsize: int,
+                  causal: bool) -> tuple[int, int]:
+    """(block_q, block_k) of the forward kernel, from the operands' shapes
+    alone: ``_widest_tiles`` under ``forward_vmem_bytes``. A causal call
+    keeps block_k <= block_q, so that a query block's diagonal tile, the
+    one that computes masked scores, is no wider than the block itself."""
+    return _widest_tiles(forward_vmem_bytes, "forward", seq, head_dim,
+                         itemsize, causal)
 
 
 def _last_live(qi, block_q: int, block_k: int):
     """The last key block a causal query block ``qi`` can see: the one
     that holds the key position of the block's last row."""
     return ((qi + 1) * block_q - 1) // block_k
+
+
+def _visible(qi, j, shape, q_axis: int):
+    """Causal visibility of a score tile of query block ``qi`` and key
+    block ``j``: the query position reaches the key position. The queries
+    run along ``q_axis`` of ``shape``, the keys along the other."""
+    qpos = qi * shape[q_axis] + jax.lax.broadcasted_iota(
+        jnp.int32, shape, q_axis)
+    kpos = j * shape[1 - q_axis] + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1 - q_axis)
+    return qpos >= kpos
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
@@ -174,13 +234,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k] f32
         if causal:
-            kpos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
+            s = jnp.where(_visible(qi, j, s.shape, 0), s, _NEG_INF)
         # padding mask: this block's key validity as a [1, block_k] row,
         # broadcast over the query rows
         s = jnp.where(mask_ref[:] > 0, s, _NEG_INF)
@@ -204,7 +258,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
         # fully-masked rows (all-pad keys) have l == 0: zeros, not NaN
         safe_l = jnp.where(l > 0, l, 1.0)
         o_ref[:] = (acc / safe_l).astype(o_ref.dtype)
-        lse_ref[:] = m + jnp.log(safe_l)  # [block_q, 1]
+        # the column as a [1, block_q] row, the layout the backward reads
+        # and HBM does not pad
+        lse_ref[:] = jnp.broadcast_to(m + jnp.log(safe_l),
+                                      (block_q, 128)).T[:1]
 
     if not scratch:
         write(*fold(_NEG_INF, 0.0, 0.0))
@@ -234,15 +291,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
         write(m_scr[:, :1], l_scr[:, :1], acc_scr[:])
 
 
-def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
-               interpret):
-    """q: [BH, S, d]; k/v: [BH / group, S, d]; mask: [B, S] routed per
-    program."""
-    bh, seq, d = q.shape
-    b = mask.shape[0]
+def _vmem(shape, index_map):
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
+
+
+def _query_major_specs(bh: int, b: int, group: int, d: int, block_q: int,
+                       block_k: int, causal: bool):
+    """Block specs of the grid (B*h, S/block_q, S/block_k), keys innermost,
+    that the forward and the dq call run on: a query-side [block_q, d]
+    block, a [1, block_q] row of a [BH, 1, S] array (block (1, block_q)
+    satisfies the TPU tiling rule: second-to-last equal to the array dim,
+    last a multiple of 128), a K/V [block_k, d] block at head
+    ``bh // group`` and the [1, block_k] row of the [B, 1, S] mask, keys
+    along the lanes as the score tile has them."""
     heads = bh // b
-    group = bh // k.shape[0]
-    num_k = seq // block_k
 
     def kv_head(bh_):
         # query heads b * h + i of one group are consecutive, so their K/V
@@ -256,35 +318,32 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
         # and the pipeline copies nothing for an index that stays
         return jnp.minimum(j, _last_live(i, block_q, block_k))
 
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal)
-    out, lse = pl.pallas_call(
-        kernel,
+    return (
+        _vmem((None, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
+        _vmem((None, 1, block_q), lambda bh_, i, j: (bh_, 0, i)),
+        _vmem((None, block_k, d),
+              lambda bh_, i, j: (kv_head(bh_), kv_block(i, j), 0)),
+        _vmem((None, 1, block_k),
+              lambda bh_, i, j: (bh_ // heads, 0, kv_block(i, j))),
+    )
+
+
+def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
+               interpret):
+    """q: [BH, S, d]; k/v: [BH / group, S, d]; mask: [B, S] routed per
+    program. Returns out [BH, S, d] and the logsumexp [BH, 1, S]."""
+    bh, seq, d = q.shape
+    num_k = seq // block_k
+    q_spec, row_spec, kv_spec, mask_spec = _query_major_specs(
+        bh, mask.shape[0], bh // k.shape[0], d, block_q, block_k, causal)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal),
         grid=(bh, seq // block_q, num_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh_, i, j: (bh_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d),
-                         lambda bh_, i, j: (kv_head(bh_), kv_block(i, j), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d),
-                         lambda bh_, i, j: (kv_head(bh_), kv_block(i, j), 0),
-                         memory_space=pltpu.VMEM),
-            # [B, 1, S]: keys along the lanes, as the score tile has them
-            pl.BlockSpec((None, 1, block_k),
-                         lambda bh_, i, j: (bh_ // heads, 0, kv_block(i, j)),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh_, i, j: (bh_, i, 0),
-                         memory_space=pltpu.VMEM),
-            # [BH, S, 1]: block (block_q, 1) satisfies the TPU tiling rule
-            # (second-to-last divisible by 8, last equal to the array dim)
-            pl.BlockSpec((None, block_q, 1), lambda bh_, i, j: (bh_, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
         scratch_shapes=[] if num_k == 1 else [
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
@@ -296,61 +355,311 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, mask.astype(jnp.int32)[:, None, :])
-    return out, lse[..., 0]
 
 
-def _blockwise_bwd(q, k, v, mask, o, lse, do, *, scale, causal, block_k,
-                   heads):
-    """Exact flash backward, blockwise over keys — recomputes per-block
-    probabilities from the saved logsumexp; never forms [S, S]."""
+def backward_vmem_bytes(block_q: int, block_k: int, head_dim: int,
+                        itemsize: int) -> int:
+    """VMEM one backward grid step keeps live, the larger of the two calls
+    below: every block in or out double-buffered by the pipeline, the f32
+    scratch, and the f32 s / p / dP / dS tiles. Mosaic overlays some of
+    the four: (1024, 1024) at head 64, 21 MiB by this count, compiles
+    under its 16, and (2048, 1024) does not."""
+    lanes = -(-head_dim // 128) * 128
+    q_side = 2 * block_q * lanes * itemsize   # one [block_q, d] block
+    k_side = 2 * block_k * lanes * itemsize   # one [block_k, d] block
+    rows = 2 * 2 * 8 * block_q * 4            # logsumexp, delta: [1, block_q]
+    tiles = 4 * block_q * block_k * 4
+    # dk / dv: q, dO in; k, v in, dk, dv out; the [block_k, 1] mask column
+    # on 128 lanes; the dk and dv accumulators
+    dkv = (2 * q_side + 4 * k_side + rows + 2 * block_k * 128 * 4
+           + 2 * block_k * lanes * 4)
+    # dq: q, dO in, dq out; k, v in; the [1, block_k] mask row; the dq
+    # accumulator and the two columns made of the rows
+    dq = (3 * q_side + 2 * k_side + rows + 2 * 8 * block_k * 4
+          + block_q * (lanes + 2 * 128) * 4)
+    return max(dkv, dq) + tiles
+
+
+def backward_tiles(seq: int, head_dim: int, itemsize: int,
+                   causal: bool) -> tuple[int, int]:
+    """(block_q, block_k) of the two backward kernels, by the forward's
+    rule: ``_widest_tiles`` under ``backward_vmem_bytes``."""
+    return _widest_tiles(backward_vmem_bytes, "backward", seq, head_dim,
+                         itemsize, causal)
+
+
+def _first_live(j, block_q: int, block_k: int):
+    """The first query block that sees causal key block ``j``: the one
+    that holds the row of the block's first key. The mirror of
+    ``_last_live``: the dk / dv kernel's compute skip and the clamp of its
+    query-side index maps."""
+    return (j * block_k) // block_q
+
+
+def _probabilities(s, lse, keep):
+    """Exact probabilities of a score tile from the saved logsumexp. The
+    gate is the forward's ``s > _NEG_INF / 2`` said of the predicate: a
+    masked entry is 0 whatever exp gives, so a fully masked row, whose
+    logsumexp is ``_NEG_INF`` itself, has zero gradients."""
+    return jnp.where(keep, jnp.exp(s - lse), 0.0)
+
+
+def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
+                  block_k: int):
+    """Calls ``step(when, diagonal)`` for the tile of query block ``qi``
+    and key block ``j``: once where nothing is causal; else once for the
+    live tiles the diagonal crosses, which need the position mask, and once
+    for those wholly under it, which do not (8% of the dk / dv call at
+    LFM2's shape, 1% of the dq call). Dead tiles run neither."""
+    if not causal:
+        step(True, False)
+        return
+    under = (j + 1) * block_k - 1 <= qi * block_q
+    step(jnp.logical_and(live, jnp.logical_not(under)), True)
+    step(under, False)
+
+
+def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
+                scale: float, causal: bool, num_q: int, fused: bool):
+    """One (batch x K/V head, key block, query head of the group x query
+    block) grid step of dk and dv. The tile is the transposed one, keys
+    down the sublanes and queries along the lanes, so that the logsumexp
+    and delta are [1, block_q] rows and all four matmuls contract as the
+    MXU takes them (k q^T and v dO^T over the head dimension, p^T dO and
+    dS^T q over the queries): nothing is transposed in VMEM. ``rest`` is
+    the delta row, the dk and dv blocks and their f32 scratch; or, where
+    the one tile spans the sequence (``fused``), the dk, dv and dq blocks:
+    nothing is carried, delta is computed here and the same dS gives dq
+    as well."""
+    if fused:
+        dk_ref, dv_ref, dq_ref = rest
+    else:
+        delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    j = pl.program_id(1)
+    qi = pl.program_id(2) % num_q
+
+    def tile(diagonal):
+        st = jax.lax.dot_general(
+            k_ref[:], q_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [block_k, block_q] f32
+        keep = mask_ref[:] > 0  # [block_k, 1]: this block's key validity
+        if diagonal:
+            keep = jnp.logical_and(keep, _visible(qi, j, st.shape, 1))
+        pt = _probabilities(st, lse_ref[:], keep)
+        dpt = jax.lax.dot_general(
+            v_ref[:], do_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if fused:
+            # every key of a query is in this tile, so its delta is here
+            # too: sum_k p dP, which equals rowsum(dO * O)
+            delta = jnp.sum(pt * dpt, axis=0, keepdims=True)
+        else:
+            delta = delta_ref[:]
+        dst = (pt * (dpt - delta)).astype(q_ref.dtype)
+        dv = jax.lax.dot_general(
+            pt.astype(do_ref.dtype), do_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk = jax.lax.dot_general(
+            dst, q_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return dk, dv, dst
+
+    def write(dk, dv):
+        # the softmax scale of dS, once a key row and not once a score
+        dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv.astype(dv_ref.dtype)
+
+    if fused:
+        dk, dv, dst = tile(causal)
+        write(dk, dv)
+        # dS k with dS as its transpose: contracted over the keys, dim 0
+        dq_ref[:] = (jax.lax.dot_general(
+            dst, k_ref[:], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale).astype(dq_ref.dtype)
+        return
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def step(when, diagonal):
+        @pl.when(when)
+        def _step():
+            dk, dv, _ = tile(diagonal)
+            dk_scr[:] += dk
+            dv_scr[:] += dv
+
+    # causal: query blocks wholly before this key block see none of it.
+    # Their compute is skipped here and their DMA in _flash_dkv, whose
+    # index maps start at the same _first_live block.
+    _causal_steps(step, causal, qi >= _first_live(j, block_q, block_k),
+                  qi, j, block_q, block_k)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _finalize():
+        write(dk_scr[:], dv_scr[:])
+
+
+def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
+               dq_ref, dq_scr, lse_scr, delta_scr, *, scale: float,
+               causal: bool):
+    """One (batch x head, query block, key block) grid step of dq, the
+    forward's grid and index maps. The tile has the queries down the
+    sublanes, so the logsumexp and delta rows are turned into columns,
+    once a query block."""
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+
+    def column(row_ref):  # [1, block_q] -> [block_q, 128], every lane alike
+        return jnp.broadcast_to(row_ref[:], (128, block_q)).T
+
+    @pl.when(j == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        lse_scr[:] = column(lse_ref)
+        delta_scr[:] = column(delta_ref)
+
+    def tile(diagonal):
+        s = jax.lax.dot_general(
+            q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [block_q, block_k] f32
+        keep = mask_ref[:] > 0  # [1, block_k]
+        if diagonal:
+            keep = jnp.logical_and(keep, _visible(qi, j, s.shape, 0))
+        p = _probabilities(s, lse_scr[:, :1], keep)
+        dp = jax.lax.dot_general(
+            do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta_scr[:, :1])
+        return jax.lax.dot_general(
+            ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    def step(when, diagonal):
+        @pl.when(when)
+        def _step():
+            dq_scr[:] += tile(diagonal)
+
+    _causal_steps(step, causal, j <= _last_live(qi, block_q, block_k),
+                  qi, j, block_q, block_k)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[:] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+
+
+def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
+               block_k, interpret):
+    """dk, dv: grid (B * h_kv, S / block_k, group * S / block_q). The
+    innermost axis walks the query heads a K/V head serves and, within
+    each, the query blocks from the first live one. With ``delta`` None
+    (``_flash_bwd`` says when) the call is the whole backward: one step a
+    head that computes delta itself and returns dq as well, else None."""
+    fused = delta is None
     bh, seq, d = q.shape
-    group = bh // k.shape[0]
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    if group > 1:
-        # each K/V head once for every query head it serves
-        kf, vf = (jnp.repeat(x, group, axis=0) for x in (kf, vf))
-    dof = do.astype(jnp.float32)
-    # D_i = sum_d dO_i * O_i  — the softmax-jacobian row term
-    delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)  # [BH, S]
-    qpos = jnp.arange(seq)[:, None]
-    mask_bh = jnp.repeat(mask.astype(jnp.int32), heads, axis=0)  # [BH, S]
+    bh_kv = k.shape[0]
+    group = bh // bh_kv
+    kv_heads = bh_kv // mask.shape[0]
+    num_q = seq // block_q
+    steps = group * num_q
 
-    num_blocks = seq // block_k
+    def q_head(g, t):
+        return g * group + t // num_q
 
-    def body(dq, j):
-        sl = jax.lax.dynamic_slice_in_dim
-        kj = sl(kf, j * block_k, block_k, axis=1)     # [BH, bk, d]
-        vj = sl(vf, j * block_k, block_k, axis=1)
-        mj = sl(mask_bh, j * block_k, block_k, axis=1)  # [BH, bk]
-        s = jnp.einsum("bqd,bkd->bqk", qf, kj) * scale  # [BH, S, bk]
-        kpos = j * block_k + jnp.arange(block_k)[None, :]
-        if causal:
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        s = jnp.where(mj[:, None, :] > 0, s, _NEG_INF)
-        # exact probs; the explicit gate keeps masked entries at 0 even on
-        # fully-masked rows, where lse is itself _NEG_INF and the naive
-        # exp(s - lse) would be exp(0) = 1
-        p = jnp.where(s > _NEG_INF / 2,
-                      jnp.exp(s - lse[..., None]), 0.0)
-        dvj = jnp.einsum("bqk,bqd->bkd", p, dof)
-        dp = jnp.einsum("bqd,bkd->bqk", dof, vj)
-        ds = p * (dp - delta[..., None]) * scale
-        dq = dq + jnp.einsum("bqk,bkd->bqd", ds, kj)
-        dkj = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        return dq, (dkj, dvj)
+    def q_block(j, t):
+        i = t % num_q
+        if not causal:
+            return i
+        # a step before the diagonal names the first live block, which the
+        # step that reaches it names again: one copy
+        return jnp.maximum(i, _first_live(j, block_q, block_k))
 
-    dq0 = jnp.zeros_like(qf)
-    dq, (dk_blocks, dv_blocks) = jax.lax.scan(
-        body, dq0, jnp.arange(num_blocks)
-    )
-    dk = jnp.moveaxis(dk_blocks, 0, 1).reshape(bh, seq, d)
-    dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(bh, seq, d)
-    if group > 1:
-        dk, dv = (jnp.sum(x.reshape(bh // group, group, seq, d), axis=1)
-                  for x in (dk, dv))
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    q_spec = _vmem((None, block_q, d),
+                   lambda g, j, t: (q_head(g, t), q_block(j, t), 0))
+    row_spec = _vmem((None, 1, block_q),
+                     lambda g, j, t: (q_head(g, t), 0, q_block(j, t)))
+    kv_spec = _vmem((None, block_k, d), lambda g, j, t: (g, j, 0))
+    dk, dv, *dq = pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                          num_q=num_q, fused=fused),
+        grid=(bh_kv, seq // block_k, steps),
+        in_specs=[
+            q_spec, q_spec, row_spec, kv_spec, kv_spec,
+            # [B, S, 1]: keys down the sublanes, as the transposed tile
+            # has them
+            _vmem((None, block_k, 1), lambda g, j, t: (g // kv_heads, j, 0)),
+        ] + [row_spec] * (not fused),
+        out_specs=[kv_spec, kv_spec] + [q_spec] * fused,
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        + [jax.ShapeDtypeStruct(q.shape, q.dtype)] * fused,
+        scratch_shapes=[] if fused else [
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(q, do, lse, k, v, mask[:, :, None], *[delta] * (not fused))
+    return dk, dv, dq[0] if fused else None
+
+
+def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
+              block_k, interpret):
+    """dq: the forward's grid, K/V head ``bh // group`` and the clamp at
+    ``_last_live``."""
+    bh, seq, d = q.shape
+    q_spec, row_spec, kv_spec, mask_spec = _query_major_specs(
+        bh, mask.shape[0], bh // k.shape[0], d, block_q, block_k, causal)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal),
+        grid=(bh, seq // block_q, seq // block_k),
+        in_specs=[q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec,
+                  mask_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),    # dq accumulator
+            pltpu.VMEM((block_q, 128), jnp.float32),  # logsumexp column
+            pltpu.VMEM((block_q, 128), jnp.float32),  # delta column
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(q, do, lse, delta, k, v, mask[:, None, :])
+
+
+def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, interpret):
+    """dq, dk, dv of ``_flash_fwd`` by two Mosaic calls, or by one where
+    one tile spans the sequence and each K/V head serves one query head.
+    q, out, do: [BH, S, d]; k, v: [BH / group, S, d]; mask: [B, S]; lse:
+    [BH, 1, S]. Exact probabilities are recomputed per tile from the
+    logsumexp; no [S, S] tensor reaches HBM."""
+    seq, d = q.shape[1:]
+    block_q, block_k = backward_tiles(seq, d, q.dtype.itemsize, causal)
+    delta = None
+    if not (q.shape == k.shape and block_q == block_k == seq):
+        # D_i = sum_d dO_i * O_i, the softmax jacobian's row term, as a row
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)[:, None, :]
+    args = (q, do, lse, delta, k, v, mask.astype(jnp.int32))
+    kwargs = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, interpret=interpret)
+    dk, dv, dq = _flash_dkv(*args, **kwargs)
+    if dq is None:
+        dq = _flash_dq(*args, **kwargs)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -370,11 +679,8 @@ def _flash_vjp_fwd(q, k, v, mask, scale, causal, block_q, block_k,
 
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, do):
-    q, k, v, mask, out, lse = res
-    heads = q.shape[0] // mask.shape[0]
-    dq, dk, dv = _blockwise_bwd(q, k, v, mask, out, lse, do, scale=scale,
-                                causal=causal, block_k=_BWD_BLOCK_K,
-                                heads=heads)
+    dq, dk, dv = _flash_bwd(*res, do, scale=scale, causal=causal,
+                            interpret=interpret)
     return dq, dk, dv, None
 
 
@@ -415,10 +721,10 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     if block_q is None or block_k is None:
         chosen = forward_tiles(seq, d, q.dtype.itemsize, causal)
         block_q, block_k = block_q or chosen[0], block_k or chosen[1]
-    if seq % block_q or seq % block_k or seq % _BWD_BLOCK_K:
+    if seq % block_q or seq % block_k or seq % 128:
         raise ValueError(
             f"seq len {seq} must be divisible by block_q={block_q}, "
-            f"block_k={block_k} and {_BWD_BLOCK_K} (pad the sequence)"
+            f"block_k={block_k} and 128 (pad the sequence)"
         )
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"

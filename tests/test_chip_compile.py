@@ -41,16 +41,18 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-#: [B, S, query heads, K/V heads, head dim], causal: the three cells' calls
-CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True),
-         "olmoe-1b-7b.s4096.zipf": ((2, 4096, 16, 16, 128), True),
-         "bert-base.s512.flash": ((32, 512, 12, 12, 64), False)}
+#: [B, S, query heads, K/V heads, head dim], causal, Mosaic calls of the
+#: gradient: the three cells' calls. The forward, dk / dv and dq; at BERT's
+#: shape one backward tile spans the sequence and one call gives all three
+CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True, 3),
+         "olmoe-1b-7b.s4096.zipf": ((2, 4096, 16, 16, 128), True, 3),
+         "bert-base.s512.flash": ((32, 512, 12, 12, 64), False, 2)}
 
 
 @pytest.mark.parametrize("cell", sorted(CALLS))
 def test_flash_forward_and_backward_compile_at_the_cells_shapes(
         cell, one_chip, no_compile_cache):
-    (b, s, h, h_kv, d), causal = CALLS[cell]
+    (b, s, h, h_kv, d), causal, calls = CALLS[cell]
 
     def arg(heads):
         return jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16,
@@ -62,7 +64,9 @@ def test_flash_forward_and_backward_compile_at_the_cells_shapes(
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
-    assert "tpu_custom_call" in text          # the Mosaic kernel is in it
+    # the backward is Mosaic kernels too, and no loop of XLA's is left
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert " while(" not in text
 
 
 def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):

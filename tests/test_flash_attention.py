@@ -1,11 +1,14 @@
 """Pallas flash attention ≡ the reference einsum attention.
 
-The kernel runs in interpret mode on CPU — the same online-softmax loop,
-block structure, and masking logic as on the chip — and must match the
-models' `_full_attention` (ps_tpu/models/lm.py) in both the forward
-output and every input gradient, causal and padded, including the
-numerically delicate cases (fully-masked rows, block-boundary diagonals).
+The kernels, forward and backward, run in interpret mode on CPU — the same
+online-softmax loop, block structure, and masking logic as on the chip —
+and must match the models' `_full_attention` (ps_tpu/models/lm.py) in both
+the forward output and every input gradient, causal and padded, including
+the numerically delicate cases (fully-masked rows, block-boundary
+diagonals).
 """
+
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +17,13 @@ import pytest
 
 from ps_tpu.models.lm import _full_attention
 from ps_tpu.ops import flash_attention
-from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _last_live,
-                                        forward_tiles, forward_vmem_bytes)
+from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_live,
+                                        _last_live, backward_tiles,
+                                        backward_vmem_bytes, forward_tiles,
+                                        forward_vmem_bytes)
+
+# the module itself: ``ps_tpu.ops.flash_attention`` names the function
+fa = importlib.import_module("ps_tpu.ops.flash_attention")
 
 B, S, H, D = 2, 256, 4, 64
 
@@ -190,28 +198,192 @@ def test_causal_clamp_and_live_test_agree(block_q, block_k):
         assert 0 <= last and last * block_k <= last_row
 
 
-def _scan_lengths(jaxpr):
+def _grads(attn, q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _ref_grouped(q, k, v, mask=None, causal=False):
+    """The einsum attention on K/V repeated for the query heads they
+    serve: its k / v gradients sum over each group."""
+    group = q.shape[2] // k.shape[2]
+    return _ref(q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+                mask=mask, causal=causal)
+
+
+# (seq, batch, heads, K/V heads, head_dim, causal, backward block_q,
+# block_k); None = backward_tiles' choice. The forward runs at its own
+# chosen tile throughout.
+BACKWARD_CASES = [
+    pytest.param(512, 2, 2, 2, 64, True, 256, 128, id="causal-q256-k128"),
+    pytest.param(512, 2, 2, 2, 64, True, 128, 256, id="causal-q128-k256"),
+    pytest.param(512, 1, 2, 2, 64, True, 512, 128, id="causal-q512-k128"),
+    pytest.param(512, 1, 2, 2, 64, True, 128, 512, id="causal-q128-k512"),
+    pytest.param(512, 1, 4, 1, 64, True, None, None, id="grouped-4to1-chosen"),
+    pytest.param(512, 2, 8, 2, 64, True, 256, 128, id="grouped-4to1-q256-k128"),
+    pytest.param(512, 1, 4, 1, 64, True, 128, 256, id="grouped-4to1-q128-k256"),
+    pytest.param(256, 2, 4, 2, 64, False, 128, 128, id="grouped-2to1-padded"),
+    pytest.param(1024, 1, 2, 2, 128, True, None, None, id="d128-s1024-chosen"),
+    pytest.param(1024, 1, 2, 2, 128, True, 512, 256, id="d128-s1024-q512-k256"),
+    pytest.param(512, 2, 2, 2, 64, False, 256, 128, id="padded-q256-k128"),
+    pytest.param(512, 2, 2, 2, 64, False, 128, 512, id="padded-q128-k512"),
+]
+
+
+@pytest.mark.parametrize("seq,b,h,h_kv,d,causal,block_q,block_k",
+                         BACKWARD_CASES)
+def test_backward_tiles_match_reference_gradients(monkeypatch, seq, b, h,
+                                                  h_kv, d, causal, block_q,
+                                                  block_k):
+    """Whatever tiles the two backward kernels run at, chosen or forced:
+    dq, dk and dv are the einsum attention's, dk and dv summed over the
+    query heads a K/V head serves."""
+    if block_q is not None:
+        monkeypatch.setattr(fa, "backward_tiles",
+                            lambda *shape: (block_q, block_k))
+    q, _, _ = _qkv(21, s=seq, b=b, h=h, d=d)
+    _, k, v = _qkv(22, s=seq, b=b, h=h_kv, d=d)
+    mask = jnp.asarray(_padding(23, b, seq))
+    got = _grads(lambda q, k, v: flash_attention(
+        q, k, v, mask=mask, causal=causal), q, k, v)
+    want = _grads(lambda q, k, v: _ref_grouped(
+        q, k, v, mask=mask, causal=causal), q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=5e-4, atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(512, 512), (256, 512),
+                                             (512, 128)])
+def test_backward_with_a_fully_masked_row(monkeypatch, block_q, block_k):
+    """Batch row 1 is all padding, whose logsumexp is -1e30 itself: exactly
+    zero gradients there at a sequence-wide backward tile (no scratch in
+    either call) and at narrower ones, the reference's on row 0."""
+    monkeypatch.setattr(fa, "backward_tiles",
+                        lambda *shape: (block_q, block_k))
+    seq = 512
+    q, k, v = _qkv(24, s=seq, h=2)
+    mask = jnp.asarray(np.stack([_padding(25, 1, seq)[0],
+                                 np.zeros(seq, np.int32)]))
+    got = _grads(lambda q, k, v: flash_attention(q, k, v, mask=mask), q, k, v)
+    want = _grads(lambda q, k, v: _ref(q, k, v, mask=mask), q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_array_equal(np.asarray(g)[1], 0.0, err_msg=name)
+        np.testing.assert_allclose(np.asarray(g)[0], np.asarray(w)[0],
+                                   rtol=5e-4, atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("h_kv,causal", [(4, False), (1, True)])
+def test_bf16_gradients_within_roundoffs_of_the_f32_reference(h_kv, causal):
+    """bf16 operands as every cell feeds them: p and dS are rounded to
+    bf16 before their second matmuls and the gradients once more when
+    written, so each agrees with the f32 einsum attention on the same
+    (bf16-valued) inputs to 2 roundoffs (2**-8) of its largest entry; a
+    wrong block or mask is off by O(1) of it."""
+    q, _, _ = _qkv(26, s=512, b=1)
+    _, k, v = _qkv(27, s=512, b=1, h=h_kv)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    got = _grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal).astype(jnp.float32), q, k, v)
+    want = _grads(lambda q, k, v: _ref_grouped(q, k, v, causal=causal),
+                  *(x.astype(jnp.float32) for x in (q, k, v)))
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.dtype == jnp.bfloat16
+        err = np.abs(np.asarray(g, np.float32) - np.asarray(w)).max()
+        assert err <= 2 * 2.0 ** -8 * np.abs(np.asarray(w)).max(), name
+
+
+@pytest.mark.parametrize("seq,head_dim,itemsize,causal,want", [
+    (512, 64, 2, False, (512, 512)),     # bert-base.s512.flash and .x4
+    (4096, 128, 2, True, (1024, 512)),   # olmoe-1b-7b.s4096.zipf
+    (8192, 64, 2, True, (1024, 512)),    # lfm2-24b-a2b.s8192.zipf
+    (256, 64, 4, False, (256, 256)),     # this file's S
+])
+def test_backward_tiles_are_pinned_and_fit_the_budget(seq, head_dim,
+                                                      itemsize, causal, want):
+    got = backward_tiles(seq, head_dim, itemsize, causal)
+    assert got == want
+    assert backward_vmem_bytes(*got, head_dim, itemsize) <= _VMEM_BUDGET
+    assert not causal or got[1] <= got[0]
+
+
+def _live_steps(seq, block_q, block_k):
+    """Grid steps of one head's causal backward that compute, by the
+    definition: the tile's last query row sees its first key."""
+    return sum((i + 1) * block_q - 1 >= j * block_k
+               for i in range(seq // block_q) for j in range(seq // block_k))
+
+
+@pytest.mark.parametrize("seq,head_dim,live,steps", [
+    (4096, 128, 20, 32),    # olmoe-1b-7b.s4096.zipf
+    (8192, 64, 72, 128),    # lfm2-24b-a2b.s8192.zipf
+])
+def test_live_share_of_the_causal_backward_grid_is_pinned(seq, head_dim,
+                                                          live, steps):
+    """The mechanism's engagement is a pure function of the tiles: the
+    share of a head's grid steps that the causal skip leaves, the same
+    count from the dk / dv call's side (_first_live) and from the dq
+    call's (_last_live)."""
+    block_q, block_k = backward_tiles(seq, head_dim, 2, True)
+    num_q, num_k = seq // block_q, seq // block_k
+    assert num_q * num_k == steps
+    assert _live_steps(seq, block_q, block_k) == live
+    assert sum(num_q - _first_live(j, block_q, block_k)
+               for j in range(num_k)) == live
+    assert sum(_last_live(i, block_q, block_k) + 1
+               for i in range(num_q)) == live
+
+
+@pytest.mark.parametrize("block_q,block_k", [
+    (128, 128), (256, 128), (128, 256), (512, 128), (128, 512), (1024, 512),
+    (512, 1024)])
+def test_first_live_is_the_skip_and_the_clamp(block_q, block_k):
+    """_first_live is both the dk / dv kernel's compute skip and its
+    query-side index maps' clamp: query block i sees key block j exactly
+    when its last row reaches the block's first key, the same tiles
+    _last_live keeps, and a dead step names the first live block."""
+    seq = 2048
+    for j in range(seq // block_k):
+        first = _first_live(j, block_q, block_k)
+        for i in range(seq // block_q):
+            live = (i + 1) * block_q - 1 >= j * block_k
+            assert (i >= first) == live
+            assert (j <= _last_live(i, block_q, block_k)) == live
+            # the index map: max(i, first) is i on a live step and the
+            # block the first live step copies on a dead one
+            assert max(i, first) == (i if live else first)
+        assert first < seq // block_q
+
+
+def _primitives(jaxpr):
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            found.append(eqn.params["length"])
+        found.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue  # the kernel's own body
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _scan_lengths(sub)
+            found += _primitives(sub)
     return found
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("seq,calls", [(512, 2), (2048, 3)])
 @pytest.mark.parametrize("block_q,block_k", [(None, None), (128, 128),
                                              (256, 512)])
-def test_backward_scan_keeps_its_own_key_block(block_q, block_k):
-    """The VJP scans the keys 128 at a time whatever tile the forward ran
-    at: a sequence-wide forward block must not turn the scan into one
-    iteration over [BH, S, S] tensors."""
-    seq = 512
+def test_vjp_is_pallas_calls_and_no_scan(block_q, block_k, seq, calls,
+                                         causal):
+    """Whatever tile the forward ran at, the gradient is the forward call
+    and the backward's own: dk / dv and dq, or the one call that gives all
+    three where one backward tile spans the sequence. No scan, no while."""
+    assert (backward_tiles(seq, D, 4, causal) == (seq, seq)) == (calls == 2)
     q, k, v = _qkv(15, s=seq, b=1, h=2)
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, block_q=block_q, block_k=block_k)), argnums=(0, 1, 2)))(
-            q, k, v)
-    assert _scan_lengths(jaxpr.jaxpr) == [seq // 128]
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k)),
+        argnums=(0, 1, 2)))(q, k, v)
+    names = _primitives(jaxpr.jaxpr)
+    assert names.count("pallas_call") == calls
+    assert not {"scan", "while"} & set(names)
 
 
 def test_matches_lm_full_attention_op():
@@ -255,14 +427,17 @@ def test_block_divisibility_validated():
         flash_attention(q, k, v)
 
 
-def test_under_a_mesh_the_kernel_runs_sharded_and_agrees():
+@pytest.mark.parametrize("h_kv", [4, 2])
+def test_under_a_mesh_the_kernel_runs_sharded_and_agrees(h_kv):
     """GSPMD cannot partition a Mosaic kernel, so under ps.init's mesh the
-    call goes through shard_map: batch over 'data' and heads over 'model'
-    where they divide, replicated where they do not — same values and
-    gradients as the plain call either way."""
+    call goes through shard_map, the backward's calls with it: batch over
+    'data' and the K/V heads over 'model' where they divide, replicated
+    where they do not — same values and gradients as the plain call either
+    way, on as many K/V heads as query heads or on half."""
     import ps_tpu as ps
 
-    q, k, v = _qkv(8, s=128)  # B=2, H=4
+    q, _, _ = _qkv(8, s=128)  # B=2, H=4
+    _, k, v = _qkv(9, s=128, h=h_kv)
     mask = np.ones((B, 128), np.int32)
     mask[1, 70:] = 0
     mask = jnp.asarray(mask)
