@@ -2,7 +2,8 @@
 
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
-``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``) and land in the ``op_name`` of every HLO
+``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
+``models/kimi_linear.py``) and land in the ``op_name`` of every HLO
 instruction traced under them; the host spans are recorded with
 ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``). ``benchmark/layer_metrics/scope.py`` and ``host.py``
@@ -56,6 +57,17 @@ CONV_GATE = "ps.conv/gate"        # ops/gated_conv.py alone: the two gates and t
 FFN = "ps.ffn"                    # the dense SwiGLU of the leading layers
 
 LFM2_SCOPES = MOE_SCOPES + (CONV, CONV_GATE, FFN)
+
+# -- scopes of Kimi-Linear (models/kimi_linear.py), beside the six and FFN ------
+# Read by ``benchmark/layer_metrics/kimi.py``, which keeps its own copy. MLA
+# is under ATTN, the dense SwiGLU under FFN. KDA_CONV and KDA_CORE nest under
+# KDA, so KDA's time holds them.
+KDA = "ps.kda"                    # the KDA mixer: projections, taps, gates, the rule, norm, out projection
+KDA_CONV = "ps.kda/conv"          # the three depthwise causal convolutions and their SiLU
+KDA_CORE = "ps.kda/core"          # ops/kda.py alone: the chunked gated delta rule
+MOE_SHARED = "ps.moe/shared"      # the shared expert, a SwiGLU every token passes
+
+KIMI_SCOPES = MOE_SCOPES + (FFN, KDA, KDA_CONV, KDA_CORE, MOE_SHARED)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

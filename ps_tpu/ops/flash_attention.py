@@ -75,6 +75,19 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   be 134 MB a layer, written and read again). The dk / dv call's grid is
   over the K/V heads and sums each group in its scratch. With equal head
   counts the program is the one it was.
+- VALUES OF THEIR OWN WIDTH (latent attention: Kimi-Linear's keys are
+  128 + 64 wide, its values 128): q, k, dq and dk blocks are ``d`` wide, v,
+  the output, dO, dv and the output accumulator ``d_v``; the scale is
+  ``d ** -0.5``. No zero-padded v of 192 exists (at [1, 8192, 32, .] bf16 it
+  would be 34 MB more a tensor, four tensors, and a third more PV and dv
+  work). ``forward_vmem_bytes`` / ``backward_vmem_bytes`` pad the lanes per
+  operand (192 counts as 256), and ``forward_tiles`` / ``backward_tiles``
+  take ``v_head_dim``: at 8,192 causal, 192 / 128, (1024, 512) and
+  (512, 512), where 128 / 128 takes (1024, 1024) and (1024, 512). With
+  ``d_v == d`` counts, tiles and lowered kernels are the ones they were.
+  The 64 channels all heads share come in broadcast inside the 192-wide k
+  (``models/kimi_linear.py``); reading them at one head through a grouped
+  index map (a second score matmul a tile) was not built: ROADMAP R3.
 
 The padding mask is a [B, S] int/bool array (1 = attend), matching the
 BERT convention; causal and mask compose. Numerics: parity with the
@@ -149,24 +162,32 @@ _NEG_INF = -1e30
 _VMEM_BUDGET = 13 * 2 ** 20
 
 
+def _lanes(width: int) -> int:
+    """``width`` as VMEM holds a minor dimension: padded to the 128 lanes."""
+    return -(-width // 128) * 128
+
+
 def forward_vmem_bytes(block_q: int, block_k: int, head_dim: int,
-                       itemsize: int) -> int:
+                       itemsize: int, v_head_dim: Optional[int] = None) -> int:
     """VMEM one forward grid step keeps live, as the kernel below lays it
     out: q, k, v, mask, out and lse blocks double-buffered by the pipeline,
     the running (max, sum, accumulator) scratch, and the f32 score and
-    probability tiles. The minor dimension is padded to the 128 lanes."""
-    lanes = -(-head_dim // 128) * 128
-    q_o = 2 * 2 * block_q * lanes * itemsize
-    k_v = 2 * 2 * block_k * lanes * itemsize
+    probability tiles. The minor dimension is padded to the 128 lanes, each
+    operand's own: q and k are ``head_dim`` wide, v, the output and its
+    accumulator ``v_head_dim`` (``head_dim`` where None)."""
+    qk, vo = _lanes(head_dim), _lanes(v_head_dim or head_dim)
+    q_o = 2 * block_q * (qk + vo) * itemsize
+    k_v = 2 * block_k * (qk + vo) * itemsize
     mask = 2 * 8 * block_k * 4          # [1, block_k] int32 on 8 sublanes
     lse = 2 * block_q * 128 * 4         # the [block_q, 128] its row is cut from
-    scratch = block_q * (2 * 128 + lanes) * 4
+    scratch = block_q * (2 * 128 + vo) * 4
     tiles = 2 * block_q * block_k * 4
     return q_o + k_v + mask + lse + scratch + tiles
 
 
 def _widest_tiles(vmem_bytes, what: str, seq: int, head_dim: int,
-                  itemsize: int, causal: bool) -> tuple[int, int]:
+                  itemsize: int, causal: bool,
+                  v_head_dim: Optional[int]) -> tuple[int, int]:
     """The widest key block, then the widest query block, among the
     divisors of ``seq`` that are multiples of 128 and keep ``vmem_bytes``
     within ``_VMEM_BUDGET``; a causal call keeps block_k <= block_q."""
@@ -178,22 +199,22 @@ def _widest_tiles(vmem_bytes, what: str, seq: int, head_dim: int,
         for block_q in sizes:
             if causal and block_k > block_q:
                 continue
-            if vmem_bytes(block_q, block_k, head_dim,
-                          itemsize) <= _VMEM_BUDGET:
+            if vmem_bytes(block_q, block_k, head_dim, itemsize,
+                          v_head_dim) <= _VMEM_BUDGET:
                 return block_q, block_k
     raise ValueError(
         f"no {what} tile fits {_VMEM_BUDGET} B of VMEM at head_dim "
-        f"{head_dim}, itemsize {itemsize}")
+        f"{head_dim} (v {v_head_dim or head_dim}), itemsize {itemsize}")
 
 
-def forward_tiles(seq: int, head_dim: int, itemsize: int,
-                  causal: bool) -> tuple[int, int]:
+def forward_tiles(seq: int, head_dim: int, itemsize: int, causal: bool,
+                  v_head_dim: Optional[int] = None) -> tuple[int, int]:
     """(block_q, block_k) of the forward kernel, from the operands' shapes
     alone: ``_widest_tiles`` under ``forward_vmem_bytes``. A causal call
     keeps block_k <= block_q, so that a query block's diagonal tile, the
     one that computes masked scores, is no wider than the block itself."""
     return _widest_tiles(forward_vmem_bytes, "forward", seq, head_dim,
-                         itemsize, causal)
+                         itemsize, causal, v_head_dim)
 
 
 def _last_live(qi, block_q: int, block_k: int):
@@ -295,7 +316,7 @@ def _vmem(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
-def _query_major_specs(bh: int, b: int, group: int, d: int, block_q: int,
+def _query_major_specs(bh: int, b: int, group: int, d: int, *, block_q: int,
                        block_k: int, causal: bool):
     """Block specs of the grid (B*h, S/block_q, S/block_k), keys innermost,
     that the forward and the dq call run on: a query-side [block_q, d]
@@ -328,27 +349,41 @@ def _query_major_specs(bh: int, b: int, group: int, d: int, block_q: int,
     )
 
 
+def _qkv_specs(q, k, v, mask, **tiles):
+    """``_query_major_specs`` of packed operands: the query block, the row,
+    the key block and the mask row at q's width, then the query-side and
+    key-side blocks at v's (the output or dO, and v)."""
+    bh = q.shape[0]
+    args = (bh, mask.shape[0], bh // k.shape[0])
+    q_spec, row_spec, k_spec, mask_spec = _query_major_specs(
+        *args, q.shape[-1], **tiles)
+    o_spec, _, v_spec, _ = _query_major_specs(*args, v.shape[-1], **tiles)
+    return q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec
+
+
 def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
                interpret):
-    """q: [BH, S, d]; k/v: [BH / group, S, d]; mask: [B, S] routed per
-    program. Returns out [BH, S, d] and the logsumexp [BH, 1, S]."""
-    bh, seq, d = q.shape
+    """q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v];
+    mask: [B, S] routed per program. Returns out [BH, S, d_v] and the
+    logsumexp [BH, 1, S]."""
+    bh, seq, _ = q.shape
+    d_v = v.shape[-1]
     num_k = seq // block_k
-    q_spec, row_spec, kv_spec, mask_spec = _query_major_specs(
-        bh, mask.shape[0], bh // k.shape[0], d, block_q, block_k, causal)
+    q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _qkv_specs(
+        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal),
         grid=(bh, seq // block_q, num_k),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, mask_spec],
+        out_specs=[o_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
         scratch_shapes=[] if num_k == 1 else [
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # output accumulator
         ],
         # the scratch carries across the kv axis only
         compiler_params=pltpu.CompilerParams(
@@ -358,34 +393,37 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
 
 
 def backward_vmem_bytes(block_q: int, block_k: int, head_dim: int,
-                        itemsize: int) -> int:
+                        itemsize: int,
+                        v_head_dim: Optional[int] = None) -> int:
     """VMEM one backward grid step keeps live, the larger of the two calls
     below: every block in or out double-buffered by the pipeline, the f32
     scratch, and the f32 s / p / dP / dS tiles. Mosaic overlays some of
     the four: (1024, 1024) at head 64, 21 MiB by this count, compiles
-    under its 16, and (2048, 1024) does not."""
-    lanes = -(-head_dim // 128) * 128
-    q_side = 2 * block_q * lanes * itemsize   # one [block_q, d] block
-    k_side = 2 * block_k * lanes * itemsize   # one [block_k, d] block
+    under its 16, and (2048, 1024) does not. q, k, dq and dk are
+    ``head_dim`` wide, dO, v and dv ``v_head_dim`` (``head_dim`` where
+    None), each padded to the lanes on its own."""
+    qk, vo = _lanes(head_dim), _lanes(v_head_dim or head_dim)
+    q_side = 2 * block_q * itemsize           # a lane of a [block_q, .] block
+    k_side = 2 * block_k * itemsize           # a lane of a [block_k, .] block
     rows = 2 * 2 * 8 * block_q * 4            # logsumexp, delta: [1, block_q]
     tiles = 4 * block_q * block_k * 4
     # dk / dv: q, dO in; k, v in, dk, dv out; the [block_k, 1] mask column
     # on 128 lanes; the dk and dv accumulators
-    dkv = (2 * q_side + 4 * k_side + rows + 2 * block_k * 128 * 4
-           + 2 * block_k * lanes * 4)
+    dkv = (q_side * (qk + vo) + 2 * k_side * (qk + vo) + rows
+           + 2 * block_k * 128 * 4 + block_k * (qk + vo) * 4)
     # dq: q, dO in, dq out; k, v in; the [1, block_k] mask row; the dq
     # accumulator and the two columns made of the rows
-    dq = (3 * q_side + 2 * k_side + rows + 2 * 8 * block_k * 4
-          + block_q * (lanes + 2 * 128) * 4)
+    dq = (q_side * (2 * qk + vo) + k_side * (qk + vo) + rows
+          + 2 * 8 * block_k * 4 + block_q * (qk + 2 * 128) * 4)
     return max(dkv, dq) + tiles
 
 
-def backward_tiles(seq: int, head_dim: int, itemsize: int,
-                   causal: bool) -> tuple[int, int]:
+def backward_tiles(seq: int, head_dim: int, itemsize: int, causal: bool,
+                   v_head_dim: Optional[int] = None) -> tuple[int, int]:
     """(block_q, block_k) of the two backward kernels, by the forward's
     rule: ``_widest_tiles`` under ``backward_vmem_bytes``."""
     return _widest_tiles(backward_vmem_bytes, "backward", seq, head_dim,
-                         itemsize, causal)
+                         itemsize, causal, v_head_dim)
 
 
 def _first_live(j, block_q: int, block_k: int):
@@ -585,28 +623,35 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
         # step that reaches it names again: one copy
         return jnp.maximum(i, _first_live(j, block_q, block_k))
 
-    q_spec = _vmem((None, block_q, d),
-                   lambda g, j, t: (q_head(g, t), q_block(j, t), 0))
+    def q_side(width):
+        return _vmem((None, block_q, width),
+                     lambda g, j, t: (q_head(g, t), q_block(j, t), 0))
+
+    def k_side(width):
+        return _vmem((None, block_k, width), lambda g, j, t: (g, j, 0))
+
+    d_v = v.shape[-1]
+    q_spec, do_spec, k_spec, v_spec = (q_side(d), q_side(d_v), k_side(d),
+                                       k_side(d_v))
     row_spec = _vmem((None, 1, block_q),
                      lambda g, j, t: (q_head(g, t), 0, q_block(j, t)))
-    kv_spec = _vmem((None, block_k, d), lambda g, j, t: (g, j, 0))
     dk, dv, *dq = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           num_q=num_q, fused=fused),
         grid=(bh_kv, seq // block_k, steps),
         in_specs=[
-            q_spec, q_spec, row_spec, kv_spec, kv_spec,
+            q_spec, do_spec, row_spec, k_spec, v_spec,
             # [B, S, 1]: keys down the sublanes, as the transposed tile
             # has them
             _vmem((None, block_k, 1), lambda g, j, t: (g // kv_heads, j, 0)),
         ] + [row_spec] * (not fused),
-        out_specs=[kv_spec, kv_spec] + [q_spec] * fused,
+        out_specs=[k_spec, v_spec] + [q_spec] * fused,
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)]
         + [jax.ShapeDtypeStruct(q.shape, q.dtype)] * fused,
         scratch_shapes=[] if fused else [
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -620,12 +665,12 @@ def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
     """dq: the forward's grid, K/V head ``bh // group`` and the clamp at
     ``_last_live``."""
     bh, seq, d = q.shape
-    q_spec, row_spec, kv_spec, mask_spec = _query_major_specs(
-        bh, mask.shape[0], bh // k.shape[0], d, block_q, block_k, causal)
+    q_spec, row_spec, k_spec, mask_spec, do_spec, v_spec = _qkv_specs(
+        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal),
         grid=(bh, seq // block_q, seq // block_k),
-        in_specs=[q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec,
+        in_specs=[q_spec, do_spec, row_spec, row_spec, k_spec, v_spec,
                   mask_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -643,11 +688,13 @@ def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
 def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, interpret):
     """dq, dk, dv of ``_flash_fwd`` by two Mosaic calls, or by one where
     one tile spans the sequence and each K/V head serves one query head.
-    q, out, do: [BH, S, d]; k, v: [BH / group, S, d]; mask: [B, S]; lse:
-    [BH, 1, S]. Exact probabilities are recomputed per tile from the
-    logsumexp; no [S, S] tensor reaches HBM."""
+    q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v]; out,
+    do: [BH, S, d_v]; mask: [B, S]; lse: [BH, 1, S]. Exact probabilities
+    are recomputed per tile from the logsumexp; no [S, S] tensor reaches
+    HBM."""
     seq, d = q.shape[1:]
-    block_q, block_k = backward_tiles(seq, d, q.dtype.itemsize, causal)
+    block_q, block_k = backward_tiles(seq, d, q.dtype.itemsize, causal,
+                                      v.shape[-1])
     delta = None
     if not (q.shape == k.shape and block_q == block_k == seq):
         # D_i = sum_d dO_i * O_i, the softmax jacobian's row term, as a row
@@ -693,11 +740,14 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None,
                     mesh: Optional[Mesh] = None) -> jax.Array:
     """Fused flash attention. ``q``: [B, S, h, d] (the model-side layout
-    of ps_tpu/models/{bert,lm}.py); ``k/v``: [B, S, h_kv, d] with ``h_kv``
-    dividing ``h`` (each K/V head serves ``h / h_kv`` consecutive query
-    heads; equal for plain multi-head attention); ``mask``: optional
-    [B, S] with 1 = attend (BERT padding convention); ``causal`` composes
-    with it. Returns [B, S, h, d].
+    of ps_tpu/models/{bert,lm}.py); ``k``: [B, S, h_kv, d] and ``v``:
+    [B, S, h_kv, d_v] with ``h_kv`` dividing ``h`` (each K/V head serves
+    ``h / h_kv`` consecutive query heads; equal for plain multi-head
+    attention) and ``d_v`` the values' own width (latent attention's keys
+    are 192 wide and its values 128: no zero-padded v is made; the scale is
+    ``d ** -0.5``, the keys'); ``mask``: optional [B, S] with 1 = attend
+    (BERT padding convention); ``causal`` composes with it. Returns
+    [B, S, h, d_v].
 
     ``block_q`` / ``block_k`` tile the forward kernel; left at None they
     are ``forward_tiles``' choice from the operands' shapes. ``interpret``
@@ -714,12 +764,13 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     dimension its axis does not divide is computed replicated.
     """
     b, seq, h, d = q.shape
-    h_kv = k.shape[2]
-    if h % h_kv or v.shape != k.shape:
-        raise ValueError(f"{h} query heads on K/V of shapes {k.shape}, "
-                         f"{v.shape}: the K/V heads must divide them")
+    h_kv, d_v = k.shape[2], v.shape[3]
+    if h % h_kv or v.shape[:3] != k.shape[:3] or k.shape[3] != d:
+        raise ValueError(f"{h} query heads of width {d} on K/V of shapes "
+                         f"{k.shape}, {v.shape}: the K/V heads must divide "
+                         f"them, and k be as wide as q")
     if block_q is None or block_k is None:
-        chosen = forward_tiles(seq, d, q.dtype.itemsize, causal)
+        chosen = forward_tiles(seq, d, q.dtype.itemsize, causal, d_v)
         block_q, block_k = block_q or chosen[0], block_k or chosen[1]
     if seq % block_q or seq % block_k or seq % 128:
         raise ValueError(
@@ -738,11 +789,11 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
 
         def pack(x):  # [B, S, h, d] -> [B*h, S, d], at x's own head count
             return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-                lb * x.shape[2], seq, d)
+                lb * x.shape[2], seq, x.shape[3])
 
         out = _flash(pack(q), pack(k), pack(v), mask, scale, causal,
                      block_q, block_k, interpret)
-        return jnp.transpose(out.reshape(lb, lh, seq, d), (0, 2, 1, 3))
+        return jnp.transpose(out.reshape(lb, lh, seq, d_v), (0, 2, 1, 3))
 
     if mesh is None:
         from ps_tpu import api

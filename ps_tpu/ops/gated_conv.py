@@ -21,6 +21,10 @@ forward reads three ``D``-wide arrays and writes one, the backward reads
 four and writes three: 11 x tokens x D x itemsize bytes a layer, which
 ``benchmark/families/lfm2_step.py::conv_gate_bytes`` counts. The products
 and the taps' sum run in f32 and the results are cast to ``bcx``'s dtype.
+
+The taps themselves (``causal_taps``) are public: Kimi Delta Attention's
+depthwise convolutions (``models/kimi_linear.py``) are the same shifted
+products with four taps, differentiated by autodiff there.
 """
 
 from __future__ import annotations
@@ -29,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 
-def _shift(u, by: int):
+def shift(u, by: int):
     """``u`` [B, S, D] moved ``by`` positions to the right along S (to the
     left where negative), zeros coming in."""
     if by == 0:
@@ -40,11 +44,14 @@ def _shift(u, by: int):
     return jnp.concatenate([u[:, -by:], pad], axis=1)
 
 
-def _taps(u, w, sign: int):
-    """``sum_j w[:, j] * u_{t - sign * (L-1-j)}``: the causal filter
-    (``sign`` 1) or its transpose (-1)."""
+def causal_taps(u, w, sign: int = 1):
+    """``sum_j w[:, j] * u_{t - sign * (L-1-j)}`` for ``u`` [B, S, D] and
+    ``w`` [D, L]: the depthwise causal filter (``sign`` 1; zero left pad, no
+    bias) or its transpose (-1). The public helper of both short-convolution
+    mixers: ``gated_short_conv`` below (LFM2's three taps between two gates)
+    and ``models/kimi_linear.py``'s four taps before a SiLU on q, k and v."""
     taps = w.shape[-1]
-    return sum(w[:, j] * _shift(u, sign * (taps - 1 - j))
+    return sum(w[:, j] * shift(u, sign * (taps - 1 - j))
                for j in range(taps))
 
 
@@ -53,7 +60,7 @@ def gated_short_conv(bcx, w):
     """``bcx`` [B, S, 3 * D], ``w`` [D, L] -> ``C * conv(B * X)`` [B, S, D]
     in ``bcx``'s dtype."""
     b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
-    return (c * _taps(b * x, w.astype(jnp.float32), 1)).astype(bcx.dtype)
+    return (c * causal_taps(b * x, w.astype(jnp.float32))).astype(bcx.dtype)
 
 
 def _fwd(bcx, w):
@@ -67,11 +74,11 @@ def _bwd(res, dy):
     dy = dy.astype(jnp.float32)
     u = b * x
     dv = dy * c
-    du = _taps(dv, wf, -1)
+    du = causal_taps(dv, wf, -1)
     taps = w.shape[-1]
-    dw = jnp.stack([jnp.sum(dv * _shift(u, taps - 1 - j), axis=(0, 1))
+    dw = jnp.stack([jnp.sum(dv * shift(u, taps - 1 - j), axis=(0, 1))
                     for j in range(taps)], axis=-1)
-    dbcx = jnp.concatenate([du * x, dy * _taps(u, wf, 1), du * b], axis=-1)
+    dbcx = jnp.concatenate([du * x, dy * causal_taps(u, wf), du * b], axis=-1)
     return dbcx.astype(bcx.dtype), dw.astype(w.dtype)
 
 

@@ -13,6 +13,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ps_tpu.ops import flash_attention
 from ps_tpu.ops.gated_conv import gated_short_conv
+from ps_tpu.ops.kda import kda
 
 
 @pytest.fixture(scope="module")
@@ -41,21 +42,24 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-#: [B, S, query heads, K/V heads, head dim], causal, Mosaic calls of the
-#: gradient: the three cells' calls. The forward, dk / dv and dq; at BERT's
-#: shape one backward tile spans the sequence and one call gives all three
+#: [B, S, query heads, K/V heads, head dim (, the values' own)], causal,
+#: Mosaic calls of the gradient: the four cells' calls. The forward, dk / dv
+#: and dq; at BERT's shape one backward tile spans the sequence and one call
+#: gives all three. Kimi's latent attention has keys of 192 and values of 128
 CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True, 3),
          "olmoe-1b-7b.s4096.zipf": ((2, 4096, 16, 16, 128), True, 3),
-         "bert-base.s512.flash": ((32, 512, 12, 12, 64), False, 2)}
+         "bert-base.s512.flash": ((32, 512, 12, 12, 64), False, 2),
+         "kimi-linear-48b-a3b.s8192.b1.zipf":
+             ((1, 8192, 32, 32, 192, 128), True, 3)}
 
 
 @pytest.mark.parametrize("cell", sorted(CALLS))
 def test_flash_forward_and_backward_compile_at_the_cells_shapes(
         cell, one_chip, no_compile_cache):
-    (b, s, h, h_kv, d), causal, calls = CALLS[cell]
+    (b, s, h, h_kv, d, *d_v), causal, calls = CALLS[cell]
 
-    def arg(heads):
-        return jax.ShapeDtypeStruct((b, s, heads, d), jnp.bfloat16,
+    def arg(heads, width=d):
+        return jax.ShapeDtypeStruct((b, s, heads, width), jnp.bfloat16,
                                     sharding=one_chip)
 
     def loss(q, k, v):
@@ -63,7 +67,7 @@ def test_flash_forward_and_backward_compile_at_the_cells_shapes(
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
+        arg(h), arg(h_kv), arg(h_kv, *d_v)).compile().as_text()
     # the backward is Mosaic kernels too, and no loop of XLA's is left
     assert text.count('custom_call_target="tpu_custom_call"') == calls
     assert " while(" not in text
@@ -81,3 +85,27 @@ def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     # XLA keeps f32 intermediates of [tokens, D] between the two fusions
     # (873 MB where bcx is 201): the room PERF.md section 7 names
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
+    """``ops/kda.py`` at [1, 8192, 32, 128], forward and backward: plain
+    XLA, one ``while`` for the groups' internals and one for the scan over
+    the chunks in each pass (the recomputed forward and the backward: the
+    loss here needs no output of the first forward, which XLA drops), and
+    with the groups recomputed the [16, 16, 128] pair tensors of all 128
+    chunks (2.1 GB in f32, several of them) are never alive at once."""
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = (1, 8192, 32, 128)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda(q, k, v, g, beta).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg(*wide), arg(*wide), arg(*wide), arg(*wide, dtype=jnp.float32),
+        arg(*wide[:3], dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 4
+    assert "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30

@@ -21,7 +21,8 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
-from benchmark.layer_metrics import host, lfm2 as lfm2_metrics, moe, scope
+from benchmark.layer_metrics import (host, kimi as kimi_metrics,
+                                     lfm2 as lfm2_metrics, moe, scope)
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
 from ps_tpu.kv.sparse import SparseEmbedding
@@ -323,6 +324,129 @@ def test_lfm2_reader_on_a_hand_made_result(monkeypatch):
     r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
     assert lfm2_metrics.scope_times(r, {}) == {}
     assert lfm2_metrics.read({"counters": {}, "facts": {}}) == {}
+
+
+def _kimi_step():
+    """``(run, batch)`` of ``make_step(has_aux=True)`` on a tiny Kimi-Linear:
+    a dense KDA layer, a KDA and a latent-attention layer with experts, two
+    of eight held, beside the shared one."""
+    from ps_tpu.models import kimi_linear
+
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=3, kda_layers=(1, 2),
+        full_attn_layers=(3,), kda_num_heads=2, kda_head_dim=16,
+        gate_low_rank=8, num_attention_heads=2, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        router_width=8, num_experts=2, expert_start=2,
+        num_experts_per_token=2, dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: kimi_linear.init_params(k, cfg))(
+        jax.random.key(0)))
+    # 64 tokens: one chunk of the rule
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    step = store.make_step(kimi_linear.make_loss_fn(cfg), has_aux=True)
+    bias = kimi_linear.init_expert_bias(cfg)
+    return (lambda batch: step(batch, bias),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_kimi_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Kimi-Linear adds to the scopes (``ps.kda``, ``ps.kda/conv``,
+    ``ps.kda/core``, ``ps.moe/shared``) beside the six it shares with OLMoE
+    and ``ps.ffn``: each in the lowered step's ``op_name``s under
+    ``ps.grad``, forward and backward; the reader's copy is equal."""
+    assert phases.KIMI_SCOPES == kimi_metrics.KIMI_SCOPES
+    assert phases.KIMI_SCOPES[:6] == phases.MOE_SCOPES
+    for name in ("KDA", "KDA_CONV", "KDA_CORE", "MOE_SHARED", "FFN",
+                 "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE",
+                 "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(kimi_metrics, name)
+    assert not set(phases.KIMI_SCOPES) & set(phases.DEVICE_PHASES)
+    assert set(kimi_metrics.SCOPE_METRICS) == set(phases.KIMI_SCOPES)
+    monkeypatch.setitem(BUILDERS, "kimi", _kimi_step)
+    names = scope.op_names_of(_step_hlo("kimi"))
+    for s in phases.KIMI_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {kimi_metrics.scope_of(own, n) for own, n in names.items()}
+    assert found == set(phases.KIMI_SCOPES) | {None}
+    # the rule and the taps are the innermost scopes of their ops, and the
+    # mixer's holds them; the shared expert is not the routed ones'
+    for inner in (kimi_metrics.KDA_CORE, kimi_metrics.KDA_CONV):
+        assert kimi_metrics.scope_of(
+            "%fusion.1", f"jit(f)/ps.grad/jvp(ps.kda)/checkpoint/{inner}/mul"
+        ) == inner
+    assert kimi_metrics.scope_of(
+        "%fusion.2", "jit(f)/ps.grad/jvp(ps.moe/shared)/dot_general") \
+        == kimi_metrics.MOE_SHARED
+    assert kimi_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
+        == kimi_metrics.MOE_EXPERT
+
+
+def test_kimi_reader_on_a_hand_made_result(monkeypatch):
+    call = 'custom_call_target="tpu_custom_call"'
+    ops = {_ev("%qkv"): 0.004, _ev("%taps"): 0.002, _ev("%scan"): 0.010,
+           _ev("%swiglu"): 0.006, _ev("%shared"): 0.002,
+           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
+           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
+           _ev("%flash", "custom-call") + call: 0.010,
+           _ev("%latent"): 0.004, _ev("%ce"): 0.005, _ev("%embed"): 0.001,
+           _ev("%adam"): 0.007}
+    names = {"%qkv": "jit(f)/ps.grad/jvp(ps.kda)/dot_general",
+             "%taps": "jit(f)/ps.grad/jvp(ps.kda)/checkpoint/ps.kda/conv/mul",
+             "%scan": "jit(f)/ps.grad/transpose(jvp(ps.kda))/checkpoint/"
+                      "ps.kda/core/while/body/dot_general",
+             "%swiglu": "jit(f)/ps.grad/jvp(ps.ffn)/dot_general",
+             "%shared": "jit(f)/ps.grad/jvp(ps.moe/shared)/dot_general",
+             "%route": "jit(f)/ps.grad/jvp(ps.moe/route)/dot_general",
+             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
+             "%back": "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather",
+             "%ragged-dot-none.1": "ragged-dot-none",
+             "%flash": "jit(f)/ps.grad/jvp(ps.attn)/pallas_call",
+             "%latent": "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general",
+             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
+             "%embed": "jit(f)/ps.grad/jvp()/gather",
+             "%adam": "jit(f)/ps.apply/mul"}
+    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
+         "counters": {"kimi_live_pairs_per_step": 1000.0,
+                      "kimi_held_pair_share": 0.03125,
+                      "kimi_load_max_over_mean": 3.0,
+                      "kimi_dropped_tokens": 0.0},
+         "facts": {"kernel_targets": ["tpu_custom_call"],
+                   "kimi_flops_per_pair": 1e6,
+                   "kimi_dense_flops_per_step": 4e9,
+                   "kimi_kda_core_flops": 1.0, "kimi_kda_core_bytes": 1e9,
+                   "kimi_flash_flops": 2e9, "kimi_flash_bytes": 1.0},
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
+         "steps": 10, "window_s": 1.0}
+    out = kimi_metrics.scope_times(r, names)
+    assert out["kimi.kda_ms"] == pytest.approx(8.0)     # with taps and rule
+    assert out["kimi.kda_conv_ms"] == pytest.approx(1.0)
+    assert out["kimi.kda_core_ms"] == pytest.approx(5.0)
+    assert out["kimi.dense_ffn_ms"] == pytest.approx(3.0)
+    assert out["kimi.shared_ffn_ms"] == pytest.approx(1.0)
+    assert out["kimi.route_ms"] == pytest.approx(0.5)
+    assert out["kimi.dispatch_ms"] == pytest.approx(2.0)    # with combine
+    assert out["kimi.expert_ms"] == pytest.approx(4.0)
+    assert out["kimi.mla_ms"] == pytest.approx(7.0)
+    assert out["kimi.head_ms"] == pytest.approx(2.5)
+    assert out["kimi.kda_core_roofline"] == pytest.approx(20.0)  # 1 of 5 ms
+    assert out["kimi.expert_mxu_share"] == pytest.approx(25.0)   # 1 of 4 ms
+    assert out["kimi.flash_roofline"] == pytest.approx(40.0)     # 2 of 5 ms
+    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
+    whole = kimi_metrics.read(r)
+    assert whole["kimi.mfu"] == pytest.approx(5.0)   # 5e9 x 10 / s of 1e12
+    assert whole["kimi.held_pair_share"] == 0.03125
+    assert len([k for k in whole if k.startswith("kimi.")]) == 17
+    # a program without the scopes, the counters or the grouped matmuls
+    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
+    assert kimi_metrics.scope_times(r, {}) == {}
+    assert kimi_metrics.read({"counters": {}, "facts": {}}) == {}
 
 
 def test_moe_reader_on_a_hand_made_result():

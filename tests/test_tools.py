@@ -255,3 +255,17 @@ def test_lfm2_grad_check_rehearses():
         assert len(cosines) == len(ratios) == 4
         assert found["loss_rel_diff"] > 5e-5 or min(cosines) < 0.999 \
             or max(abs(r - 1) for r in ratios) > 0.01, name
+
+
+def test_kimi_grad_check_rehearses():
+    """tools/kimi_grad_check.py at the configuration's tiny sizes: the
+    system's gradients are the reference's, and the reference on 8-bit
+    weights turns every witness's gradient."""
+    out = _run("kimi_grad_check.py", "--rehearse")
+    assert out["worst"]["cosine"] > 1 - 1e-9
+    assert out["loss"]["rel_diff"] < 1e-5
+    assert out["pairs_on_another_expert"] == [0, 0, 0, 0]
+    found = out["reference_on_e4m3_weights"]
+    cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
+    assert len(cosines) == 6 and max(cosines) < 0.999
+    assert found["loss_rel_diff"] > 5e-5
