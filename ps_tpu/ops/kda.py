@@ -44,29 +44,46 @@ exponent too, so no gradient is 0 x inf.
 products of [C, C] matrices at the highest precision, batched over every chunk
 and head, in place of ``solve_triangular``'s forward substitution (the table).
 
-**Differentiable by autodiff**, not a ``custom_vjp``: the backward of the
-chunked form is four more recurrences to derive, and forty lines of
+**Two realisations, chosen by shape** (``path``): where a head's keys and
+values fill whole 128-lane tiles and the chunk is 64 (the benchmark's cell:
+32 heads of 128), the rule runs as the Mosaic kernels of
+``ops/kda_mosaic.py`` behind a ``custom_vjp``: the state stays in VMEM
+across the chunks of a head, a chunk's internals live and die there, the
+operands are read as the projections write them ([B, T, H * K], no
+transposed f32 copy), and the forward rule keeps for the backward only the
+state entering each chunk and the chunk's inverse. Everything else (the
+tests' models at widths 16 and 32) runs the plain XLA form below, which is
+also the kernels' second oracle beside the token-by-token recurrence. One
+algorithm, the same arithmetic classes ("Precision"), no switch to set.
+
+**The plain form is differentiable by autodiff**: forty lines of
 ``jax.numpy`` that equal the token-by-token reference are differentiated
 right by construction (``tests/test_kimi_linear.py`` holds all five
-gradients to the recurrence's). The whole op is under ``jax.checkpoint``:
-between the layers only ``q, k, v, g, beta`` live on (40 KB a token a layer
-where the chunk's internals would be 130), and the batched part runs in
-groups of ``GROUP`` chunks, each under ``jax.checkpoint`` again, so that the
-[16, 16, K] pair tensors of one group are the most that is alive (34 MB at
-32 heads and two chunks) and not those of all 128 chunks (2.1 GB).
+gradients of both realisations to the recurrence's). The whole op is under
+``jax.checkpoint``: between the layers only ``q, k, v, g, beta`` live on (40
+KB a token a layer where the chunk's internals would be 130), and the plain
+form's batched part runs in groups of ``GROUP`` chunks, each under
+``jax.checkpoint`` again, so that the [16, 16, K] pair tensors of one group
+are the most that is alive (34 MB at 32 heads and two chunks) and not those
+of all 128 chunks (2.1 GB).
 
 Precision: every array in f32 (the inputs may be bf16: they are the
 configuration's compute dtype), the state carried in f32; the matmuls at the
 default precision (one bf16 pass on the MXU with f32 accumulation) but the
-inverse's, whose errors compound.
+inverse's, whose errors compound. The kernels keep each class: what is an
+f32 product on the VPU here (the pairs inside a sub-block, the cumulated
+sums) is a highest-precision product on the MXU there, and Mosaic reads no
+``jax.default_matmul_precision``, so ``kda`` hands the kernels the operand
+dtype of their default-class products (``_mxu_dtype``).
 
-Plain XLA. ``benchmark/families/kimi_step.py::kda_core_cost`` counts the
-operations and bytes of this form from the shapes; a Pallas kernel that keeps
-``S`` in VMEM across the chunks is ROADMAP R3's.
+``benchmark/families/kimi_step.py::kda_core_cost`` counts the operations and
+bytes of the chunked form from the shapes (the plain form's: the kernels'
+six-pass products, their halving levels and what they keep for the backward
+are not in it).
 
-**Measured (TPU v5e, jax 0.9.0; my chip runs, PR 34: the op alone at
-[1, 8192, 32, 128], bf16 q / k / v, median of 5 calls on the host clock, and
-inside the fused step from the cell's traces, four layers)**:
+**Measured (TPU v5e, jax 0.9.0): the op alone at [1, 8192, 32, 128], bf16
+q / k / v, and inside the fused step from the cell's traces, four layers.**
+The plain form (my chip runs, PR 34; median of 5 calls on the host clock):
 
 | what | forward, ms | forward + backward, ms | ``kimi.kda_core_ms`` / ``step.device_ms`` |
 |---|---|---|---|
@@ -81,19 +98,52 @@ Small groups win because XLA then keeps a group's intermediates in the
 faster memory space (``S(1)`` in the optimized HLO). Of the 50.65 ms of
 device time of a call (groups of 4) the four fusions over the pair tensors
 are 10.6; the rest is a long tail of the two loops' slices, copies and small
-matmuls, none over 1.1 ms: the op is bound by its loops, not by one fusion,
-at 1.6-2.1% of its roofline (HBM-bound, 5.6 ms a step).
+matmuls, none over 1.1 ms: the plain form is bound by its loops, not by one
+fusion, at 1.6-2.1% of its roofline (HBM-bound, 5.6 ms a step).
+
+The kernels (my chip runs, PR 35; four calls chained in one program, so a
+call is 12 ms or more of device work behind one dispatch; the last column
+from the cell's traces). "Forward + backward" is the forward that keeps the
+states and the backward, what ``jax.grad`` of the op runs:
+
+| what | forward, ms | forward + backward, ms | ``kimi.kda_core_ms`` / ``step.device_ms`` |
+|---|---|---|---|
+| the plain form, this harness | 13.60 | 53.57 | 266.70 / 706.17 (ledger, PR 34) |
+| ``jax.vjp`` of the whole chunk in the body, Mosaic's six-pass products for the run sums, 1 / 2 / 4 heads a step | 16.21 / 15.78 / 15.52 | 50.69 / 49.80 / 49.33 | not run |
+| + ``_solve``'s own rule with the inverse kept (the squarings are not transposed), the run sums in three exact passes, 2 heads | 12.07 | 26.03 | 145.38 / 568.25 |
+| the same with the masks handed in as tables, not made of iotas | 12.33 | 26.64 | not run |
+| **as landed: 4 heads a step** (2: 12.11 / 26.16) | **11.91** | **25.92** | **144.00 / 566.70** |
+
+Where a call's time goes (ablations of the 2-head form, forward / forward +
+backward, ms): the inverse's ten [64, 64] products at the highest precision
+5.3 / 5.3 (forward only: the backward loads it), the four levels inside a
+sub-block 1.6 / 7.2, the run sums 1.0 / 3.8, the products with the state
+1.0 / 2.8, the solve 0.3 / 1.8, the two levels across sub-blocks 0.1 / 1.3,
+the exponentials under 0.3, and 1.7 / 5.0 that is left with all of these
+taken out (blocks in and out, casts, a grid step's fixed cost). A [64, 64]
+product costs 104 cycles at the highest precision and 54 in one bf16 pass,
+and two independent chains take twice one: throughput, not latency, so more
+heads a step buy only the step's fixed cost (0.6 us). The six terms of a
+highest-precision product written by hand over three bf16 pieces cost what
+Mosaic's own do (0.94 against 1.11 us for the inverse).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
-#: tokens of a sub-block, inside which every pair gets its own decay
-SUB = 16
+from ps_tpu.ops import kda_mosaic
+from ps_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+#: tokens of a sub-block, inside which every pair gets its own decay (and
+#: its own f32 product: the kernels' split of the arithmetic classes too)
+SUB = kda_mosaic.SUB
 #: chunks whose batched part is computed (and, in the backward pass,
 #: recomputed) together
 GROUP = 2
@@ -202,7 +252,70 @@ def _kda(q, k, v, g, beta, chunk: int):
     return out.astype(v.dtype)
 
 
-def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True):
+def path(q, k, v, chunk: int) -> str:
+    """Which realisation of the rule operands of these shapes take:
+    ``"kernel"`` (``ops/kda_mosaic.py``) where a head's keys and values fill
+    whole 128-lane tiles and the chunk is the kernels' 64, else ``"plain"``
+    (this module's XLA form). Read from the shapes alone."""
+    lanes = q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+    whole = q.shape[1] % chunk == 0 and q.shape == k.shape
+    return "kernel" if lanes and whole and chunk == 64 else "plain"
+
+
+def _mxu_dtype():
+    """The operand dtype of the kernels' default-class products: bf16 (one
+    pass, what the chip's default precision makes of the plain form's) unless
+    the caller's ``jax.default_matmul_precision`` asks for more."""
+    asked = jax.config.jax_default_matmul_precision
+    return (jnp.bfloat16 if asked in (None, "default", "bfloat16")
+            else jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_kernel(q, k, v, g, beta, chunk, mxu, interpret):
+    return kda_mosaic.forward(q, k, v, g, beta, chunk=chunk, mxu=mxu,
+                              interpret=interpret, keep=False)[0]
+
+
+def _kda_kernel_fwd(q, k, v, g, beta, chunk, mxu, interpret):
+    out, kept = kda_mosaic.forward(q, k, v, g, beta, chunk=chunk, mxu=mxu,
+                                   interpret=interpret, keep=True)
+    return out, (q, k, v, g, beta, kept)
+
+
+def _kda_kernel_bwd(chunk, mxu, interpret, res, do):
+    return kda_mosaic.backward(*res, do, chunk=chunk, mxu=mxu,
+                               interpret=interpret)
+
+
+_kda_kernel.defvjp(_kda_kernel_fwd, _kda_kernel_bwd)
+
+
+def _under_mesh(run, batch: int, heads: int):
+    """``run`` inside ``shard_map`` over the mesh ``ps_tpu.init`` built, if
+    any: batch over 'data' and heads over 'model' wherever the axis exists
+    and divides (what it does not divide is computed replicated), as
+    ``ops/flash_attention.py`` runs its kernels: GSPMD cannot partition a
+    Mosaic call, and the plain form it replaces at these shapes could be."""
+    from ps_tpu import api
+
+    if not api.is_initialized() or api.current_context().mesh is None:
+        return run
+    mesh = api.current_context().mesh
+
+    def axis(name, n):
+        size = mesh.shape.get(name, 1)
+        return name if size > 1 and n % size == 0 else None
+
+    wide = P(axis(DATA_AXIS, batch), None, axis(MODEL_AXIS, heads), None)
+    # check_vma off for the reason flash_attention gives: jax 0.9.0 types
+    # a kernel's VMEM scratch as unvarying
+    return shard_map(run, mesh=mesh, in_specs=(wide,) * 4 + (P(*wide[:3]),),
+                     out_specs=wide, check_vma=False)
+
+
+def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True,
+        interpret: Optional[bool] = None):
     """``q``, ``k`` [B, T, H, K], ``v`` [B, T, H, V], ``g`` [B, T, H, K] the
     log-decays (<= 0, f32), ``beta`` [B, T, H] -> ``o`` [B, T, H, V] in
     ``v``'s dtype: the recurrence of the module docstring from a zero state,
@@ -212,10 +325,21 @@ def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True):
     is the caller's to add and cut). ``checkpoint=False`` leaves the
     recomputation to a caller that has a wider ``jax.checkpoint`` of its own
     around the call (``models/kimi_linear.py``): two nested ones would run
-    the forward pass three times."""
+    the forward pass three times. ``path`` says which realisation runs;
+    ``interpret`` is the kernels' (None: off the chip the same kernels run in
+    interpret mode), and under ``ps_tpu.init``'s mesh they run in
+    ``shard_map`` (``_under_mesh``)."""
     t = q.shape[1]
     if chunk % SUB or t % chunk:
         raise ValueError(f"kda: {t} tokens in chunks of {chunk}, sub-blocks "
                          f"of {SUB}: each must divide the one before")
-    run = functools.partial(_kda, chunk=chunk)
+    if path(q, k, v, chunk) == "kernel":
+        if interpret is None:
+            interpret = jax.devices()[0].platform != "tpu"
+        run = _under_mesh(
+            functools.partial(_kda_kernel, chunk=chunk, mxu=_mxu_dtype(),
+                              interpret=interpret),
+            q.shape[0], q.shape[2])
+    else:
+        run = functools.partial(_kda, chunk=chunk)
     return (jax.checkpoint(run) if checkpoint else run)(q, k, v, g, beta)

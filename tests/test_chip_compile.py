@@ -13,7 +13,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ps_tpu.ops import flash_attention
 from ps_tpu.ops.gated_conv import gated_short_conv
-from ps_tpu.ops.kda import kda
+from ps_tpu.ops.kda import kda, path
 
 
 @pytest.fixture(scope="module")
@@ -88,24 +88,30 @@ def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
 
 
 def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
-    """``ops/kda.py`` at [1, 8192, 32, 128], forward and backward: plain
-    XLA, one ``while`` for the groups' internals and one for the scan over
-    the chunks in each pass (the recomputed forward and the backward: the
-    loss here needs no output of the first forward, which XLA drops), and
-    with the groups recomputed the [16, 16, 128] pair tensors of all 128
-    chunks (2.1 GB in f32, several of them) are never alive at once."""
+    """``ops/kda.py`` at [1, 8192, 32, 128], forward and backward: the shape
+    takes the Mosaic kernels (``kda.path``), two calls in the gradient (the
+    forward that keeps each chunk's entering state and inverse, recomputed
+    under the op's ``jax.checkpoint``, and the backward; the loss here needs
+    no output of the first forward, which XLA drops) and no loop of XLA's:
+    the state is carried in VMEM along the grid. Between the two calls live
+    the states (268 MB) and the inverses, where the plain form's four
+    ``while`` loops kept 600 MB of the chunks' internals."""
     def arg(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     wide = (1, 8192, 32, 128)
+    args = (arg(*wide), arg(*wide), arg(*wide),
+            arg(*wide, dtype=jnp.float32),
+            arg(*wide[:3], dtype=jnp.float32))
+    assert path(*args[:3], 64) == "kernel"
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(kda(q, k, v, g, beta).astype(jnp.float32))
+        return jnp.sum(kda(q, k, v, g, beta,
+                           interpret=False).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        arg(*wide), arg(*wide), arg(*wide), arg(*wide, dtype=jnp.float32),
-        arg(*wide[:3], dtype=jnp.float32)).compile()
+        *args).compile()
     text = compiled.as_text()
-    assert text.count(" while(") == 4
-    assert "tpu_custom_call" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30

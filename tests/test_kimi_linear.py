@@ -170,6 +170,12 @@ def test_fused_step_matches_reference():
 
 # -- the chunked rule against the recurrence ----------------------------------
 
+#: the width at which each realisation of the rule runs (``kda.path``): the
+#: XLA form below 128 lanes, the Mosaic kernels (interpret mode here) at them
+WIDTH = {"plain": 32, "kernel": 128}
+PATHS = sorted(WIDTH)
+
+
 def _rule_inputs(seq, heads=3, width=32, batch=2, seed=0):
     """Unit q and k, decays as strong as the configuration's strongest head
     gives (``exp(A_log)`` 16, a softplus about 0.1: 1.6 nats a token, 102 a
@@ -185,16 +191,26 @@ def _rule_inputs(seq, heads=3, width=32, batch=2, seed=0):
     return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
 
 
+def _path_inputs(path, seq):
+    """``_rule_inputs`` at the width that takes ``path``; the kernels with
+    two heads, one grid step's worth (``kda_mosaic.heads_a_step``)."""
+    args = _rule_inputs(seq, width=WIDTH[path],
+                        **(dict(heads=2) if path == "kernel" else {}))
+    assert seq % 64 or kda_ops.path(*args[:3], 64) == path
+    return args
+
+
 def _recurrence(q, k, v, g, beta):
     return jax.vmap(reference.delta_rule)(q, k, v, g, beta)
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("seq", [64, 192, 512],
                          ids=["one_chunk", "three_chunks", "eight_chunks"])
-def test_chunked_kda_equals_the_token_by_token_recurrence(seq):
+def test_chunked_kda_equals_the_token_by_token_recurrence(seq, path):
     """Forward and all five gradients, at decays that overflow a chunk
     whose cumulated decay is divided out (``exp(-G)`` is not an f32)."""
-    args = _rule_inputs(seq)
+    args = _path_inputs(path, seq)
     lost = -np.cumsum(np.asarray(args[3])[:, :64], axis=1)
     with np.errstate(over="ignore"):
         assert np.isinf(np.exp(lost.astype(np.float32))).any()
@@ -213,23 +229,26 @@ def test_chunked_kda_equals_the_token_by_token_recurrence(seq):
         assert _rel(g, r) <= F32_TOL, (name, _rel(g, r))
 
 
-def test_kda_refuses_a_sequence_its_chunks_do_not_divide():
-    args = _rule_inputs(100)
+@pytest.mark.parametrize("path", PATHS)
+def test_kda_refuses_a_sequence_its_chunks_do_not_divide(path):
+    args = _path_inputs(path, 100)
     with pytest.raises(ValueError, match="must divide"):
         kda_ops.kda(*args)
     with pytest.raises(ValueError, match="must divide"):
-        kda_ops.kda(*_rule_inputs(96), chunk=24)
+        kda_ops.kda(*_rule_inputs(96, width=WIDTH[path]), chunk=24)
     # the documented pad: tokens of beta 0 and g 0 behind the sequence
     # change nothing before them
     padded = [jnp.pad(x, ((0, 0), (0, 28)) + ((0, 0),) * (x.ndim - 2))
               for x in args]
+    assert kda_ops.path(*padded[:3], 64) == path
     with jax.default_matmul_precision("highest"):
         got = kda_ops.kda(*padded)[:, :100]
         assert _rel(got, _recurrence(*args)) <= F32_TOL
 
 
-def test_kda_is_causal_and_keeps_bf16_in_bf16_out():
-    args = _rule_inputs(128)
+@pytest.mark.parametrize("path", PATHS)
+def test_kda_is_causal_and_keeps_bf16_in_bf16_out(path):
+    args = _path_inputs(path, 128)
     out = kda_ops.kda(*args)
     t = 70
     moved = kda_ops.kda(args[0], args[1], args[2].at[:, t].add(1.0),
@@ -243,6 +262,79 @@ def test_kda_is_causal_and_keeps_bf16_in_bf16_out():
     # with and without its own checkpoint: the same values
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(kda_ops.kda(*args, checkpoint=False)))
+
+
+def test_under_a_mesh_the_kernels_run_sharded_and_agree():
+    """GSPMD cannot partition a Mosaic call, so under ``ps.init``'s mesh
+    the kernels go through ``shard_map``, batch over 'data' and heads over
+    'model' where they divide, replicated where they do not: the same
+    values and gradients as without a mesh either way."""
+    args = _path_inputs("kernel", 128)     # B = 2, H = 2
+
+    def value_and_grads():
+        return jax.value_and_grad(lambda *a: jnp.sum(kda_ops.kda(*a) ** 2),
+                                  argnums=(0, 1, 2, 3, 4))(*args)
+
+    want = value_and_grads()
+    for mesh_shape in ({"data": 2, "model": 2}, {"data": 8}):
+        ps.init(backend="tpu", mesh_shape=mesh_shape)  # 8 cannot divide B
+        try:
+            got = jax.jit(value_and_grads)()
+        finally:
+            ps.shutdown()
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
+                                                    atol=1e-5), got, want)
+
+
+def test_the_shapes_alone_say_which_realisation_runs():
+    """``kda.path``: the kernels at the cell's shape (32 heads of 128, 8,192
+    tokens in chunks of 64), the XLA form at every width of this file's
+    models and wherever a head does not fill whole lane tiles."""
+    def shapes(t, h, width, v_width=None):
+        x = jax.ShapeDtypeStruct((1, t, h, width), jnp.bfloat16)
+        return x, x, jax.ShapeDtypeStruct((1, t, h, v_width or width),
+                                          jnp.bfloat16)
+
+    assert kda_ops.path(*shapes(8192, 32, 128), 64) == "kernel"
+    assert kda_ops.path(*shapes(128, 2, 256, 128), 64) == "kernel"
+    for width in (SIZES["linear_attn_config"]["head_dim"], 32, 64, 192):
+        assert kda_ops.path(*shapes(128, 4, width), 64) == "plain"
+    assert kda_ops.path(*shapes(128, 4, 128, 64), 64) == "plain"
+    assert kda_ops.path(*shapes(128, 4, 128), 32) == "plain"
+
+
+def test_kernel_and_plain_form_agree_at_bf16_operands():
+    """The cell's dtypes (q, k, v in bf16, decays and strengths in f32) and
+    its one-pass products (no ``highest`` here: the kernels round the
+    operands of their default-class products to bf16, as the chip does to
+    the plain form's): both realisations are the recurrence to within the
+    rounding of a bf16 output, which the plain form's own distance reads,
+    and so is their distance to each other; the gradients likewise."""
+    args = _rule_inputs(192, heads=2, width=128)
+    q, k, v = (x.astype(jnp.bfloat16) for x in args[:3])
+    args = [q, k, v, *args[3:]]
+    exact = [x.astype(jnp.float32) for x in args]
+    weights = jnp.asarray(np.random.default_rng(9).normal(
+        size=v.shape), jnp.float32)
+
+    def both(f, *a):
+        loss = lambda *a: jnp.sum(f(*a).astype(jnp.float32) * weights)
+        return f(*a).astype(jnp.float32), jax.grad(
+            loss, argnums=(0, 1, 2, 3, 4))(*a)
+
+    plain = functools.partial(kda_ops._kda, chunk=64)
+    (want, want_grads), (ours, our_grads), (theirs, their_grads) = (
+        both(_recurrence, *exact), both(kda_ops.kda, *args),
+        both(plain, *args))
+    assert kda_ops.path(q, k, v, 64) == "kernel"
+    bound = 4 * _rel(theirs, want)
+    assert 1e-3 < bound < 4e-2
+    assert _rel(ours, want) <= bound and _rel(ours, theirs) <= bound
+    for name, g, p, r in zip("q k v g beta".split(), our_grads, their_grads,
+                             want_grads):
+        g, p = g.astype(jnp.float32), p.astype(jnp.float32)
+        assert _rel(g, r) <= max(4 * _rel(p, r), bound), name
 
 
 def test_causal_taps_are_the_convolution_of_both_mixers():
