@@ -29,6 +29,10 @@ ZMQ van / scheduler         XLA collectives (data) + host control plane
 ==========================  =================================================
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # the start of the ``setup.import`` span
+
 from ps_tpu.config import Config
 from ps_tpu.api import init, shutdown, is_initialized, current_context
 from ps_tpu.kv.store import KVStore
@@ -84,3 +88,10 @@ __all__ = [
     "flash_attention",
     "__version__",
 ]
+
+# this import's own span (ps_tpu/obs/phases.py, SETUP_SPANS); importing
+# ps_tpu.obs has put the listener of the compiler's events in place
+from ps_tpu import obs as _obs  # noqa: E402
+
+_obs.tracer().record_program(_obs.phases.SETUP_IMPORT, _T_IMPORT,
+                             _time.perf_counter() - _T_IMPORT)

@@ -17,7 +17,9 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
+from ps_tpu import obs
 from ps_tpu.config import Config
+from ps_tpu.obs import phases
 
 
 class Context:
@@ -51,7 +53,7 @@ def init(backend: Optional[str] = None, config: Optional[Config] = None, **overr
         ``mesh_shape={'data': 8}``.
     """
     global _context
-    with _lock:
+    with _lock, obs.tracer().program_span(phases.SETUP_INIT) as span:
         if _context is not None:
             raise RuntimeError("ps_tpu already initialized; call shutdown() first")
         if config is None:
@@ -71,6 +73,9 @@ def init(backend: Optional[str] = None, config: Optional[Config] = None, **overr
 
             be = TpuBackend(config)  # pslint: disable=PSL101 -- single-shot process init: the module lock exists to serialize exactly this construction (distributed rendezvous + detector warm-up); nothing else ever contends for it mid-job
             _context = Context(config, be, mesh=be.mesh)
+        mesh = _context.mesh
+        span.set(backend=config.backend,
+                 devices=1 if mesh is None else mesh.size)
         return _context
 
 

@@ -1465,6 +1465,8 @@ class VanService:
                     ts_us=time.time() * 1e6
                     - (fr["age_ns"] + total_ns) / 1e3,
                     dur_us=total_ns / 1e3,
+                    t0=time.perf_counter()
+                    - (fr["age_ns"] + total_ns) / 1e9,
                     conn=fr["conn"], wire_kind=tv.kind_name(fr["kind"]),
                     size=fr["size"],
                     read_us=round(fr["read_ns"] / 1e3, 1),

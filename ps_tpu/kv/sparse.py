@@ -47,6 +47,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax import shard_map
 
+from ps_tpu import obs
 from ps_tpu.api import current_context
 from ps_tpu.obs import phases
 from ps_tpu.ops.sparse_apply import fused_sparse_apply, resolve_tier
@@ -192,6 +193,12 @@ class SparseEmbedding:
         row-range over the mesh. Returns the placed table."""
         if self._table is not None:
             raise RuntimeError("SparseEmbedding.init already called")
+        with obs.tracer().program_span(
+                phases.SETUP_TABLE_INIT, rows=self.num_rows, dim=self.dim,
+                nbytes=self.rows_nbytes(self.padded_rows)):
+            return self._make_table(rng_or_table, scale)
+
+    def _make_table(self, rng_or_table, scale: float) -> jax.Array:
         is_prng_key = isinstance(rng_or_table, jax.Array) and jnp.issubdtype(
             rng_or_table.dtype, jax.dtypes.prng_key
         )

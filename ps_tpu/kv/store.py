@@ -20,9 +20,11 @@ import jax
 import numpy as np
 import optax
 
+from ps_tpu import obs
 from ps_tpu.api import current_context
 from ps_tpu.kv import fused
 from ps_tpu.kv import keys as keymod
+from ps_tpu.obs import phases
 from ps_tpu.optim import make_optimizer
 
 
@@ -108,16 +110,19 @@ class KVStore:
         the server placed them (device-put/sharded for the tpu backend)."""
         if self._treedef is not None:
             raise RuntimeError("KVStore.init already called")
-        kv, treedef = keymod.flatten_with_keys(params)
-        self._treedef = treedef
-        self._key_order = list(kv)
-        # what one whole-tree push or pull moves: a constant from here on
-        self._tree_bytes = sum(_nbytes(v) for v in kv.values())
-        if hasattr(self._engine, "register_tree"):
-            return self._engine.register_tree(kv, treedef, self._key_order)
-        for k, v in kv.items():
-            self._engine.register(k, v)
-        return self.params()
+        with obs.tracer().program_span(phases.SETUP_STORE_INIT) as span:
+            kv, treedef = keymod.flatten_with_keys(params)
+            self._treedef = treedef
+            self._key_order = list(kv)
+            # what one whole-tree push or pull moves: a constant from here on
+            self._tree_bytes = sum(_nbytes(v) for v in kv.values())
+            span.set(leaves=len(kv), nbytes=self._tree_bytes)
+            if hasattr(self._engine, "register_tree"):
+                return self._engine.register_tree(kv, treedef,
+                                                  self._key_order)
+            for k, v in kv.items():
+                self._engine.register(k, v)
+            return self.params()
 
     def keys(self) -> List[str]:
         return list(self._key_order)

@@ -10,9 +10,12 @@ Three layers, each usable alone, wired through every transport hot path:
   :class:`~ps_tpu.obs.clock.ClockSync`. Off by default
   (``trace_sample`` / ``PS_TRACE_SAMPLE`` = 0): the unsampled path is a
   no-op singleton and one dict lookup per hop. The fused steps and the
-  input prefetch record their few spans a step always
-  (``Tracer.program_span``; names in :mod:`ps_tpu.obs.phases`, which also
-  names the ``jax.named_scope`` phases inside the device program).
+  input prefetch record their few spans a step always, and a process the
+  dozen of its set-up (``Tracer.program_span``; names in
+  :mod:`ps_tpu.obs.phases`, which also names the ``jax.named_scope`` phases
+  inside the device program); jax's own trace / lower / compile events
+  become their children, four ``ps_compile_*`` counters and the
+  ``recompile`` flight event (:mod:`ps_tpu.obs.compiles`).
 - **Metrics** (:mod:`ps_tpu.obs.metrics`): counters, gauges, and
   log2-bucket latency histograms (p50/p99/p999) that ``TransportStats``
   feeds; exported in the extended STATS frame, rendered live by
@@ -41,6 +44,7 @@ import os
 import threading
 from typing import Optional
 
+from ps_tpu.obs import compiles as compiles  # noqa: F401 — re-export the module
 from ps_tpu.obs import trace as trace  # noqa: F401 — re-export the module
 from ps_tpu.obs.breakdown import PHASES, TraceBreakdown, breakdown
 from ps_tpu.obs.clock import ClockSync

@@ -1,4 +1,5 @@
-"""Names of the phases of a fused step and of the host spans around it.
+"""Names of the phases of a fused step, of the host spans around it and of
+the spans of a process's set-up.
 
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
@@ -6,10 +7,14 @@ the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``models/kimi_linear.py``) and land in the ``op_name`` of every HLO
 instruction traced under them; the host spans are recorded with
 ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
-``data/prefetch.py``). ``benchmark/layer_metrics/scope.py`` and ``host.py``
-keep their own copy of the names they look up (the benchmark also runs on
-trees that lack this file); ``tests/test_phases.py`` holds them equal. Every
-span here has a reader there: a span that no metric reads is not recorded.
+``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
+``api.py``, ``kv/store.py``, ``kv/sparse.py``), and the compiler's spans by
+the one listener on ``jax.monitoring`` (``obs/compiles.py``).
+``benchmark/layer_metrics/scope.py``, ``host.py`` and ``setup.py`` keep
+their own copy of the names they look up (the benchmark also runs on trees
+that lack this file); ``tests/test_phases.py`` holds them equal. Every span
+here has a reader there, ``SETUP_SPANS`` and ``COMPILE_SPANS`` too
+(``setup.*``): a span that no metric reads is not recorded.
 
 One scope around ``jax.value_and_grad`` gives two phases: JAX writes the
 forward ops as ``ps.grad/jvp(...)`` and the backward ops as
@@ -78,3 +83,26 @@ INPUT_PRODUCE = "input.produce"            # next(batches) in the producer threa
 
 HOST_SPANS = (STEP_RUN, STEP_LAUNCH, INPUT_PLACE, INPUT_SOURCE_WAIT,
               INPUT_PRODUCE)
+
+# -- set-up spans (Tracer.program_span; a dozen a process, none per step) ------
+# Read by ``benchmark/layer_metrics/setup.py``, which keeps its own copy.
+SETUP_IMPORT = "setup.import"          # the import of the ps_tpu package, first line to last
+SETUP_INIT = "setup.init"              # ps.init whole; backend, devices
+SETUP_STORE_INIT = "setup.store_init"  # KVStore.init; leaves, nbytes
+SETUP_TABLE_INIT = "setup.table_init"  # SparseEmbedding.init; rows, dim, nbytes
+
+SETUP_SPANS = (SETUP_IMPORT, SETUP_INIT, SETUP_STORE_INIT, SETUP_TABLE_INIT)
+
+# -- the compiler's own events as spans (obs/compiles.py) ----------------------
+# Recorded when jax says the interval is over, with t0 = now - duration, on
+# the thread that asked for the program and as a child of the program span
+# that is open on that thread's program stack (step 0's ``step.launch``; a
+# set-up span; none). Trace events nest (``matmul`` inside ``my_step``) and
+# the cache's load lies inside the backend's interval: read unions, not sums.
+COMPILE_TRACE = "compile.trace"            # Python to jaxpr; fun
+COMPILE_LOWER = "compile.lower"            # jaxpr to an MLIR module; fun
+COMPILE_BACKEND = "compile.backend"        # XLA's compile, or the load from the persistent cache; fun, cache
+COMPILE_CACHE_LOAD = "compile.cache_load"  # the retrieval alone, inside compile.backend on a hit; fun
+
+COMPILE_SPANS = (COMPILE_TRACE, COMPILE_LOWER, COMPILE_BACKEND,
+                 COMPILE_CACHE_LOAD)

@@ -22,13 +22,24 @@ creation, and every downstream hop simply follows the header — an
 unsampled op costs one dict lookup per hop and nothing else, so the off
 path stays off the profile.
 
-The program's own hot path is the exception: the few spans a training step
-opens around the fused step and the input prefetch
-(:mod:`ps_tpu.obs.phases`, ``HOST_SPANS``) are recorded by
-:meth:`Tracer.program_span` into the same ring with no sampling decision,
-because whoever reads them (the benchmark's ``host.*`` metrics) cannot
-switch tracing on. Every span keeps its start on ``time.perf_counter``
-(``Span.t0``) beside the wall clock, so it can be laid on a device trace.
+The program's own path is the exception: the few spans a training step
+opens around the fused step and the input prefetch, the dozen a process
+opens while it sets up, and jax's own trace / lower / compile events
+(:mod:`ps_tpu.obs.phases`: ``HOST_SPANS``, ``SETUP_SPANS``,
+``COMPILE_SPANS``) are recorded by :meth:`Tracer.program_span` and
+:meth:`Tracer.record_program` into the same ring with no sampling decision,
+because whoever reads them (the benchmark's ``host.*`` and ``setup.*``
+metrics) cannot switch tracing on. Every span keeps its start on
+``time.perf_counter`` (``Span.t0``) beside the wall clock, so it can be laid
+on a device trace.
+
+A span that somebody else timed (:mod:`ps_tpu.obs.compiles` hears of a
+compile when it is over, on the thread that asked for it) is recorded from
+its start and its length by :meth:`Tracer.record_program`, as a child of
+the program span that is open **on the calling thread, on the program
+spans' own stack** (never the sampled spans' stack): step 0's
+``step.launch`` gets its ``compile.*`` children with no span opened inside
+the step.
 """
 
 from __future__ import annotations
@@ -220,8 +231,9 @@ class Tracer:
     def program_span(self, name: str, cat: str = "program", **args) -> Span:
         """A span that is ALWAYS recorded: no sampling decision, ids from a
         process-local counter. For the few spans a step that the program
-        puts on its own hot path (``ps_tpu.obs.phases.HOST_SPANS``), which
-        a benchmark reads without being able to switch tracing on. Child
+        puts on its own hot path and the dozen of its set-up
+        (``ps_tpu.obs.phases``: ``HOST_SPANS``, ``SETUP_SPANS``), which a
+        benchmark reads without being able to switch tracing on. Child
         of this thread's open program span, if any; ``args`` (``step=n``,
         ``seq=n``) are the identifier the spans of one step share.
 
@@ -238,6 +250,26 @@ class Tracer:
             sp = _ProgramSpan(self, name, cat, sid, sid, None)
         sp.args = args
         return sp
+
+    def record_program(self, name: str, t0: float, dur_s: float,
+                       **args) -> Span:
+        """A program span from its start on ``time.perf_counter`` and its
+        length in seconds, for an interval that was not timed by a ``with``
+        of this tracer: jax's monitoring events name a duration once it is
+        over, and the package's import starts before any tracer exists.
+        Recorded now; child of this thread's open program span, like
+        :meth:`program_span`."""
+        sp = self.program_span(name, **args)
+        sp.ts_us = (time.time() - (time.perf_counter() - t0)) * 1e6
+        sp.dur_us = max(dur_s, 0.0) * 1e6
+        sp.t0 = t0
+        sp._tid = threading.get_ident()
+        self._record(sp)
+        return sp
+
+    def open_program_spans(self) -> tuple:
+        """This thread's open program spans, outermost first."""
+        return tuple(getattr(self._tls, "program", None) or ())
 
     def current(self) -> Optional[TraceContext]:
         """The innermost open span's context on this thread, if any."""
@@ -266,18 +298,20 @@ class Tracer:
 
     def record_external(self, name: str, cat: str, trace_id: str,
                         parent_id: Optional[str], ts_us: float,
-                        dur_us: float, **args) -> "Span":
+                        dur_us: float, t0: float = 0.0, **args) -> "Span":
         """Record a span whose timing happened OUTSIDE Python — e.g. the
         native event loop's slow-frame capture, whose per-stage stamps
         were taken with no interpreter anywhere near the work. The span
         joins the given trace (always recorded: the propagated context
         means the root already paid the sampling decision) with explicit
         wall-clock start and duration instead of the context-manager
-        timing."""
+        timing. ``t0`` is the same start on ``time.perf_counter``, where
+        the caller has it (0.0: not known, as a span that never began)."""
         sp = Span(self, name, cat, str(trace_id), _new_id(),
                   None if parent_id is None else str(parent_id))
         sp.ts_us = float(ts_us)
         sp.dur_us = max(float(dur_us), 0.0)
+        sp.t0 = float(t0)
         sp._tid = threading.get_ident()
         sp.args.update(args)
         self._record(sp)

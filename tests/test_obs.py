@@ -280,7 +280,10 @@ def test_untraced_frames_carry_no_tc(request):
         w.pull_all()
         grads = {k: jnp.full_like(v, 0.1) for k, v in params.items()}
         w.push_pull(grads)
-        assert obs.tracer().spans() == []
+        # but for what the program records of its own set-up and compiles
+        # (ps_tpu/obs/phases.py), with no sampling decision
+        assert [s for s in obs.tracer().spans()
+                if s.cat != "program"] == []
     finally:
         w.close()
         svc.stop()

@@ -8,11 +8,13 @@ builders, not in the models.
 """
 
 import contextlib
+import functools
 import json
 import os
 import re
 import subprocess
 import sys
+import time
 import types
 
 import jax
@@ -23,6 +25,7 @@ import pytest
 import ps_tpu as ps
 from benchmark.layer_metrics import (host, kimi as kimi_metrics,
                                      lfm2 as lfm2_metrics, moe, scope)
+from benchmark.layer_metrics import setup as setup_metrics
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
 from ps_tpu.kv.sparse import SparseEmbedding
@@ -737,9 +740,10 @@ def test_host_metrics_count_the_measured_window_only(monkeypatch):
     assert host.window_of(r) is None   # not under benchmark/run.py
 
 
+@functools.lru_cache(maxsize=None)
 def _rehearse(cell):
     """The benchmark's own command on the CPU: the result line of a traced
-    rehearsal of ``cell``."""
+    rehearsal of ``cell`` (run once a process, whoever asks)."""
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
                         "JAX_COMPILATION_CACHE_DIR")}
@@ -796,3 +800,370 @@ def test_benchmark_command_rehearses_the_host_metrics():
                   and "widedeep-criteo.b4096.zipf" in m.get(
                       "workloads", ["widedeep-criteo.b4096.zipf"])}
     assert listed and listed <= set(line["rehearsed"])
+
+
+# -- set-up from inside the program -------------------------------------------
+
+SETUP_METRICS = (
+    "setup.before_program_s", "setup.import_s", "setup.init_s",
+    "setup.store_init_s", "setup.step_trace_lower_s",
+    "setup.step_compile_or_load_s", "setup.other_compile_s",
+    "setup.cache_misses", "setup.unspanned_s")
+
+
+def test_program_and_benchmark_share_the_set_up_names():
+    assert phases.SETUP_SPANS == setup_metrics.SETUP_SPANS
+    assert phases.COMPILE_SPANS == setup_metrics.COMPILE_SPANS
+    for name in ("SETUP_IMPORT", "SETUP_INIT", "SETUP_STORE_INIT",
+                 "SETUP_TABLE_INIT", "COMPILE_TRACE", "COMPILE_LOWER",
+                 "COMPILE_BACKEND", "COMPILE_CACHE_LOAD"):
+        assert getattr(phases, name) == getattr(setup_metrics, name)
+    assert set(setup_metrics.PROGRAM_SPANS) == set(
+        phases.SETUP_SPANS + phases.COMPILE_SPANS + phases.HOST_SPANS)
+    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
+        listed = [m for m in json.load(f)["per_layer"]
+                  if m["name"].startswith("setup.")]
+    assert tuple(m["name"] for m in listed) == SETUP_METRICS
+    for m in listed:  # every cell reports setup_s, so every cell reads them
+        assert "workloads" not in m and m["moves"] == "setup_s"
+        assert (m["layer"], m["source"]) == ("entry", "program_span")
+    # every set-up span the program records has a metric that reads it, and
+    # so has each of the compiler's but the load, which is a row of the table
+    ring = _set_up_ring()
+    whole = setup_metrics.span_metrics(ring, 100.0, 10.0)
+    for name in phases.SETUP_SPANS[1:] + phases.COMPILE_SPANS[:3]:
+        rest = [s for s in ring if s.name != name]
+        assert setup_metrics.span_metrics(rest, 100.0, 10.0) != whole, name
+    assert setup_metrics.span_metrics(
+        [s for s in ring if s.name != phases.SETUP_IMPORT], 100.0, 10.0
+    ) is None
+
+
+def test_the_import_of_the_package_leaves_its_span():
+    """In a process of its own (this one's ring may have been cleared): the
+    first span in the ring is the import's, from the package's first line,
+    and the listener is in place when the import returns."""
+    code = (
+        "import time; t = time.perf_counter()\n"
+        "import ps_tpu; from ps_tpu import obs; import jax.numpy as jnp\n"
+        "first = obs.tracer().spans()[0]\n"
+        "jnp.ones(3) + 1\n"
+        "names = {s.name for s in obs.tracer().spans()}\n"
+        "print(first.name, first.parent_id, first.t0 == ps_tpu._T_IMPORT, "
+        "t <= first.t0, first.t0 + 1e-6 * first.dur_us <= "
+        "time.perf_counter(), abs((time.time() - 1e-6 * first.ts_us) - "
+        "(time.perf_counter() - first.t0)) < 0.05, "
+        "'compile.backend' in names)")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=_REPO,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == [phases.SETUP_IMPORT, "None"] + ["True"] * 5
+
+
+def test_record_program_is_a_child_of_the_open_program_span():
+    tr = obs.Tracer(sample=0.0)
+    now = time.perf_counter()
+    root = tr.record_program("compile.backend", now - 2.0, 0.5, fun="f")
+    assert (root.t0, root.dur_us, root.parent_id) == (now - 2.0, 5e5, None)
+    assert root.args == {"fun": "f"} and root.trace_id == root.span_id
+    with tr.program_span("step.run", step=4) as outer:
+        with tr.span("sampled", parent=obs.TraceContext("t", "s")):
+            # the sampled spans' stack is not the one it is parented on
+            kid = tr.record_program("compile.trace", now - 0.2, 0.1)
+        assert tr.open_program_spans() == (outer,)
+    assert kid.parent_id == outer.span_id and kid.trace_id == outer.trace_id
+    assert tr.open_program_spans() == ()
+    assert [s.name for s in tr.spans()] == [
+        "compile.backend", "compile.trace", "sampled", "step.run"]
+    assert abs(kid.ts_us - 1e6 * (time.time() - 0.2)) < 5e4
+    # a span timed outside Python keeps its start on perf_counter too
+    ext = tr.record_external("slow_frame", "server", "t", None,
+                             ts_us=1.0, dur_us=2.0, t0=now - 1.0, conn=3)
+    assert (ext.t0, ext.args) == (now - 1.0, {"conn": 3})
+    assert tr.record_external("slow_frame", "server", "t", None,
+                              ts_us=1.0, dur_us=2.0).t0 == 0.0
+
+
+def test_the_local_backend_leaves_its_set_up_spans_too():
+    mark = time.perf_counter()
+    ps.init(backend="local")
+    store = ps.KVStore(optimizer="sgd", learning_rate=0.1)
+    store.init({"w": jnp.ones((4, 2))})
+    (init,) = _spans_since(mark, phases.SETUP_INIT)
+    assert init.args == {"backend": "local", "devices": 1}
+    (placed,) = _spans_since(mark, phases.SETUP_STORE_INIT)
+    assert placed.args == {"leaves": 1, "nbytes": 4 * 2 * 4}
+
+
+def _counters():
+    return {k: c.value for k, c in obs.compiles.COUNTERS.items()}
+
+
+def _recompiles_since(wall):
+    return [e for e in obs.flight().events()
+            if e["kind"] == "recompile" and e["t"] >= wall]
+
+
+def _inside(kid, parent):
+    return parent.t0 <= kid.t0 and (
+        kid.t0 + 1e-6 * kid.dur_us <= parent.t0 + 1e-6 * parent.dur_us)
+
+
+def test_set_up_and_the_compilers_spans_of_a_tiny_step():
+    """``ps.init`` and ``KVStore.init`` leave their spans; step 0's
+    ``step.run`` gets the compiler's trace, lowering and compile as
+    children, through the listener alone; a warm step leaves none; a batch
+    of another shape at step n leaves them under step n, a ``recompile``
+    flight event and the counters moved."""
+    tracer, mark = obs.tracer(), time.perf_counter()
+    run, batch = _dense_step()
+    (init,) = _spans_since(mark, phases.SETUP_INIT)
+    assert init.args == {"backend": "tpu", "devices": 8}
+    (placed,) = _spans_since(mark, phases.SETUP_STORE_INIT)
+    assert placed.args == {"leaves": 2, "nbytes": (8 * 8 + 8) * 4}
+    assert init.t0 + 1e-6 * init.dur_us <= placed.t0
+
+    def compiler_spans(since):
+        return [s for s in tracer.spans()
+                if s.name in phases.COMPILE_SPANS and s.t0 >= since]
+
+    before, mark = _counters(), time.perf_counter()
+    run(batch)
+    (run0,) = _spans_since(mark, phases.STEP_RUN)
+    (launch0,) = _spans_since(mark, phases.STEP_LAUNCH)
+    assert run0.args["step"] == 0
+    for name, fun in ((phases.COMPILE_TRACE, "fused_step"),
+                      (phases.COMPILE_LOWER, "jit(fused_step)"),
+                      (phases.COMPILE_BACKEND, "jit(fused_step)")):
+        (kid,) = [s for s in compiler_spans(mark)
+                  if s.name == name and s.args["fun"] == fun]
+        assert kid.parent_id == launch0.span_id
+        assert kid.trace_id == run0.trace_id
+        assert _inside(kid, launch0) and _inside(launch0, run0)
+        assert kid._tid == run0._tid
+    # the step's own trace holds every jitted function it calls: one span
+    assert [s.args["fun"] for s in compiler_spans(mark)
+            if s.name == phases.COMPILE_TRACE
+            and _inside(s, launch0)] == ["fused_step"]
+    after = _counters()
+    assert after["compiles"] >= before["compiles"] + 1
+    assert after["seconds"] > before["seconds"]
+
+    # warm: the listener is silent, as in a measured window
+    before, mark, wall = _counters(), time.perf_counter(), time.time()
+    for _ in range(3):
+        run(batch)
+    assert len(_spans_since(mark, phases.STEP_RUN)) == 3
+    assert compiler_spans(mark) == [] and _counters() == before
+    assert _recompiles_since(wall) == []
+
+    # another shape at step 4: a retrace in the middle of a job
+    wider = jnp.ones((2 * BATCH, 8))
+    before, mark = _counters(), time.perf_counter()
+    run(wider)
+    (run4,) = _spans_since(mark, phases.STEP_RUN)
+    (launch4,) = _spans_since(mark, phases.STEP_LAUNCH)
+    assert run4.args["step"] == 4
+    (again,) = [s for s in compiler_spans(mark)
+                if s.name == phases.COMPILE_BACKEND
+                and s.parent_id == launch4.span_id]
+    assert again.args["fun"] == "jit(fused_step)" and _inside(again, run4)
+    (event,) = _recompiles_since(wall)
+    assert (event["step"], event["fun"]) == (4, "jit(fused_step)")
+    assert event["seconds"] == pytest.approx(1e-6 * again.dur_us, abs=1e-5)
+    assert _counters()["compiles"] == before["compiles"] + 1
+    assert _counters()["seconds"] == pytest.approx(
+        before["seconds"] + 1e-6 * again.dur_us, abs=1e-5)
+    text = obs.default_registry().render_prometheus()
+    for name in ("ps_compile_total", "ps_compile_seconds_total",
+                 "ps_compile_cache_hits_total",
+                 "ps_compile_cache_misses_total"):
+        assert f"\n{name} " in text
+
+
+@pytest.fixture
+def own_compile_cache(tmp_path):
+    """A persistent compile cache of this test's own that takes every
+    program, however small and however fast it compiled."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    old = [getattr(jax.config, n) for n in names]
+    for n, v in zip(names, (str(tmp_path), 0.0, -1)):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    yield
+    for n, v in zip(names, old):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+def test_a_compile_says_whether_the_cache_had_it(own_compile_cache):
+    """``compile.backend`` carries the cache's last word on its thread, the
+    hit's retrieval is a ``compile.cache_load`` inside it, the two counters
+    move, and a step past 0 that only loads still says ``recompile``."""
+    tracer = obs.tracer()
+
+    def program(x):
+        return jnp.tanh(x) * 3.0 + x[::-1]
+
+    seen, x = [], jnp.arange(5.0)
+    for step in (0, 7):
+        before, mark, wall = _counters(), time.perf_counter(), time.time()
+        with tracer.program_span(phases.STEP_RUN, step=step) as outer:
+            jax.jit(program)(x)
+        (backend,) = [s for s in _spans_since(mark, phases.COMPILE_BACKEND)
+                      if s.args["fun"] == "jit(program)"]
+        assert backend.parent_id == outer.span_id
+        seen.append(backend.args["cache"])
+        loads = _spans_since(mark, phases.COMPILE_CACHE_LOAD)
+        moved = {k: v - before[k] for k, v in _counters().items()}
+        if step == 0:
+            assert loads == [] and (moved["hit"], moved["miss"]) == (0, 1)
+            assert _recompiles_since(wall) == []  # step 0 may compile
+        else:
+            (load,) = loads
+            assert load.args["fun"] == "jit(program)"
+            assert load.parent_id == outer.span_id
+            assert _inside(load, backend)
+            assert (moved["hit"], moved["miss"]) == (1, 0)
+            (event,) = _recompiles_since(wall)
+            assert (event["step"], event["cache"]) == (7, "hit")
+        assert moved["compiles"] == 1
+        jax.clear_caches()  # the next jit of it asks the persistent cache
+    assert seen == ["miss", "hit"]
+
+
+def _off_main(span):
+    span._tid = 77
+    return span
+
+
+def _set_up_ring(t=100.0):
+    """Hand-made spans of a set-up of 10 s that began at ``t``: 2 s before
+    the program, an import of 1.5, ``ps.init`` 0.5, a store's init of 1
+    that compiles for 0.4, a table's of 0.05, a reference that compiles on
+    its own (0.6, a miss), step 0 of 3 s whose launch traces (a nested trace inside),
+    lowers and loads, a warm-up step, and a producer thread."""
+    return [
+        _span(phases.SETUP_IMPORT, 1.5e6, "a", t0=t + 2.0),
+        _span(phases.SETUP_INIT, 0.5e6, "b", t0=t + 3.5, backend="tpu"),
+        _span(phases.COMPILE_BACKEND, 0.4e6, "d", "c", t0=t + 4.2,
+              fun="jit(zeros)"),
+        _span(phases.SETUP_STORE_INIT, 1.0e6, "c", t0=t + 4.0, leaves=2),
+        _span(phases.SETUP_TABLE_INIT, 0.05e6, "t", t0=t + 5.9, rows=64),
+        _span(phases.COMPILE_TRACE, 0.1e6, "e", t0=t + 5.0, fun="ref"),
+        _span(phases.COMPILE_LOWER, 0.1e6, "f", t0=t + 5.1, fun="jit(ref)"),
+        _span(phases.COMPILE_BACKEND, 0.6e6, "g", t0=t + 5.2,
+              fun="jit(ref)", cache="miss"),
+        _span(phases.COMPILE_TRACE, 0.2e6, "j", "i", t0=t + 6.3,
+              fun="matmul"),                      # inside the step's trace
+        _span(phases.COMPILE_TRACE, 1.0e6, "k", "i", t0=t + 6.1,
+              fun="fused_step"),
+        _span(phases.COMPILE_LOWER, 0.5e6, "l", "i", t0=t + 7.1,
+              fun="jit(fused_step)"),
+        _span(phases.COMPILE_BACKEND, 1.2e6, "m", "i", t0=t + 7.6,
+              fun="jit(fused_step)", cache="hit"),
+        _span(phases.COMPILE_CACHE_LOAD, 1.0e6, "n", "i", t0=t + 7.7,
+              fun="jit(fused_step)"),
+        _span(phases.STEP_LAUNCH, 2.8e6, "i", "h", t0=t + 6.1, step=0),
+        _span(phases.STEP_RUN, 3.0e6, "h", t0=t + 6.0, step=0),
+        _span(phases.STEP_LAUNCH, 0.1e6, "p", "o", t0=t + 9.5, step=1),
+        _span(phases.STEP_RUN, 0.2e6, "o", t0=t + 9.5, step=1),
+        # not the main thread's: never in the table; its compile counts
+        _off_main(_span(phases.INPUT_PRODUCE, 5e6, "q", t0=t + 4.0)),
+        _off_main(_span(phases.COMPILE_BACKEND, 0.25e6, "r", t0=t + 8.0,
+                        fun="jit(augment)", cache="miss")),
+        # past set-up: the window's
+        _span(phases.STEP_RUN, 0.2e6, "s", t0=t + 10.5, step=2),
+        _span("server_apply", 1e6, "x", t0=t + 1.0),   # the van's
+    ]
+
+
+def test_set_up_metrics_from_a_hand_made_ring():
+    ring = _set_up_ring()
+    out = setup_metrics.span_metrics(ring, 100.0, 10.0)
+    assert set(out) == set(SETUP_METRICS)
+    want = {
+        "setup.before_program_s": 2.0, "setup.import_s": 1.5,
+        "setup.init_s": 0.5, "setup.store_init_s": 1.0 + 0.05,
+        "setup.step_trace_lower_s": 1.5,      # the nested trace once
+        "setup.step_compile_or_load_s": 1.2,  # the load is inside it
+        "setup.other_compile_s": 0.4 + 0.6 + 0.25,
+        "setup.cache_misses": 2.0,
+        # 8 s of program less import, init, store and table, 0.8 of
+        # reference, 3.2 of steps
+        "setup.unspanned_s": 8.0 - 1.5 - 0.5 - 1.05 - 0.8 - 3.2,
+    }
+    for name, value in want.items():
+        assert out[name] == pytest.approx(value), name
+    # the table: every instant of set-up in one row, phase by phase
+    ends = [105.0, 106.0, 109.5, 110.0]
+    main = [s for s in ring if s.name in setup_metrics.PROGRAM_SPANS
+            and getattr(s, "_tid", None) is None and s.t0 < 110.0]
+    rows = setup_metrics.self_times(main, 100.0, ends, 102.0)
+    by_phase = [sum(r[i] for r in rows.values()) for i in range(4)]
+    assert by_phase == pytest.approx([5.0, 1.0, 3.5, 0.5])
+    assert sum(by_phase) == pytest.approx(10.0, abs=1e-9)
+    total = {name: sum(r) for name, r in rows.items()}
+    assert total[setup_metrics.BEFORE] == pytest.approx(2.0)
+    assert total[setup_metrics.UNSPANNED] == pytest.approx(
+        out["setup.unspanned_s"])
+    assert total[phases.SETUP_STORE_INIT] == pytest.approx(0.6)  # self time
+    assert total[phases.COMPILE_TRACE] == pytest.approx(0.1 + 1.0)
+    assert total[phases.COMPILE_BACKEND] == pytest.approx(
+        0.4 + 0.6 + 0.2)
+    assert total[phases.COMPILE_CACHE_LOAD] == pytest.approx(1.0)
+    assert total[phases.STEP_LAUNCH] == pytest.approx(0.1 + 0.1)
+    assert total[phases.STEP_RUN] == pytest.approx(0.2 + 0.1)
+    assert rows[phases.COMPILE_BACKEND] == pytest.approx(
+        [0.4, 0.6, 0.2, 0.0])
+    text = setup_metrics.table(rows, ["a", "b", "c", "d"])
+    assert text.splitlines()[-1].split() == [
+        "total", "5.000", "1.000", "3.500", "0.500", "10.000"]
+    assert text.splitlines()[1].startswith(setup_metrics.BEFORE)
+    assert setup_metrics.union_s([(0, 2), (1, 3), (5, 6), (5.5, 5.7)]) == 4
+
+
+def test_set_up_reader_on_the_process_ring(monkeypatch, capsys):
+    """``read`` on this process's own tracer: the nine metrics with the
+    table on stderr; nothing where the program is not under
+    ``benchmark/run.py``; nothing, and a word on stderr, once the ring has
+    turned over and dropped the import's span."""
+    r = {"setup_s": 3.0, "setup_phases_s": {"imports_and_build": 1.0,
+         "reference": 0.5, "step0": 1.0, "warmup": 0.5}}
+    assert setup_metrics.read(r) == {}        # no _T_START
+    tr = obs.Tracer(sample=0.0, capacity=16)
+    monkeypatch.setattr(obs, "tracer", lambda: tr)
+    now = time.perf_counter()
+    monkeypatch.setattr(sys.modules["__main__"], "_T_START", now - 4.0,
+                        raising=False)
+    tr.record_program(phases.SETUP_IMPORT, now - 3.5, 0.5)
+    tr.record_program(phases.COMPILE_BACKEND, now - 2.5, 0.25, fun="jit(f)",
+                      cache="miss")
+    out = setup_metrics.read(r)
+    assert set(out) == set(SETUP_METRICS)
+    assert out["setup.before_program_s"] == pytest.approx(0.5)
+    assert out["setup.other_compile_s"] == pytest.approx(0.25)
+    assert out["setup.unspanned_s"] == pytest.approx(3.0 - 0.5 - 0.75)
+    err = capsys.readouterr().err
+    last = [line for line in err.splitlines() if line.startswith("total")]
+    assert last[0].split() == ["total", "1.000", "0.500", "1.000", "0.500",
+                               "3.000"]
+    assert "2 spans in the ring" in err and "0 dropped" in err
+    for step in range(16):                    # a full ring: the import goes
+        with tr.program_span(phases.STEP_RUN, step=step):
+            pass
+    assert tr.dropped == 2
+    assert setup_metrics.read(r) == {}
+    assert "it turned over: 2 spans dropped" in capsys.readouterr().err
+
+
+def test_benchmark_command_rehearses_the_set_up_metrics():
+    """A traced rehearsal of the Wide&Deep cell lists the nine ``setup.*``
+    metrics, as every cell's does: they name no ``workloads``."""
+    line = _rehearse("widedeep-criteo.b4096.zipf")
+    assert set(SETUP_METRICS) <= set(line["rehearsed"])
