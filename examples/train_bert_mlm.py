@@ -8,6 +8,14 @@ ZeRO-1 'sharded' placement XLA inserts the per-tensor norm reduces
 (SURVEY.md §8 hard part (b); the parity test in tests/test_bert.py asserts
 shard-exact numerics).
 
+The MLM head runs on the labelled positions only: ``make_mlm_loss_fn`` picks
+them inside groups of whole sequences, a quarter of a group's positions a
+trip (at 15% masking one trip does), as the published trainer gathers its
+``masked_lm_positions``; ``BertMLM.apply`` without ``positions`` still
+returns every position's logits ``[B, S, V]``. The trips a batch takes
+beyond its first are counted here, on the host, into
+``ps_mlm_head_overflow_total``.
+
 Run (CPU: XLA_FLAGS=--xla_force_host_platform_device_count=8):
     python examples/train_bert_mlm.py --steps 20 --batch-size 32 --seq-len 128
 """
@@ -22,8 +30,8 @@ import numpy as np
 
 import ps_tpu as ps
 from ps_tpu.data.synthetic import mlm_batches
-from ps_tpu.models.bert import (BertConfig, BertMLM,
-                                bert_partition_rules, make_mlm_loss_fn)
+from ps_tpu.models.bert import (BertConfig, BertMLM, bert_partition_rules,
+                                count_head_overflow, make_mlm_loss_fn)
 from ps_tpu.utils import StepLogger, TrainMetrics, trace
 
 
@@ -92,6 +100,7 @@ def main():
     log = StepLogger(every=10, jsonl=args.jsonl)
     with trace(args.profile_dir):
         for step, batch in enumerate(stream):
+            count_head_overflow(batch["labels"])
             loss, _ = run(store.shard_batch(batch))
             if step == 0:
                 loss.block_until_ready()

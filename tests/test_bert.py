@@ -10,10 +10,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 import ps_tpu as ps
+from ps_tpu import obs
 from ps_tpu.data.synthetic import mlm_batches
-from ps_tpu.models.bert import BertConfig, BertMLM, make_mlm_loss_fn, mlm_loss
+from ps_tpu.models.bert import (BertConfig, BertMLM, _head_slots,
+                                count_head_overflow, head_groups,
+                                make_mlm_loss_fn, mlm_loss)
 
 
 def _tiny_model_and_batch(batch_size=16, seq_len=32):
@@ -176,3 +180,131 @@ def test_bert_tensor_parallel_lamb_matches_pure_dp():
     assert spec["layer_0/output/kernel"] == P("model", None)
     assert spec["layer_0/output/bias"] == P()
     ps.shutdown()
+
+
+# -- the head on the labelled positions only ---------------------------------
+
+_B, _S = 32, 64  # two groups of 16 sequences (1,024 positions), 256 rows a trip
+
+
+def _labels_with(counts, rng, ignore_index=-100, bunched=False):
+    """[_B, _S] labels with ``counts[g]`` labelled positions in group ``g``:
+    anywhere in the group, or ``bunched`` from the group's fourth sequence
+    on, position after position."""
+    per_group, _ = head_groups(_B, _S)
+    labels = np.full((_B // per_group, per_group * _S), ignore_index, np.int32)
+    for g, n in enumerate(counts):
+        at = (3 * _S + np.arange(n) if bunched
+              else rng.choice(labels.shape[1], size=n, replace=False))
+        labels[g, at] = rng.integers(1, 512, size=n)
+    return labels.reshape(_B, _S)
+
+
+_HEAD_CASES = {
+    # name: (labels a group, extra trips, ignore_index, bunched)
+    "no_label": ((0, 0), 0, -100, False),
+    "one_label": ((0, 1), 0, -100, False),
+    "exactly_a_trip": ((256, 37), 0, -100, False),
+    "a_trip_and_one": ((150, 257), 1, -100, False),
+    "every_position": ((1024, 1024), 3, -100, False),
+    "bunched_in_one_sequence": ((64, 0), 0, -100, True),
+    "bunched_over_a_trip": ((0, 300), 1, -100, True),
+    "ignore_index_zero": ((160, 140), 0, 0, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HEAD_CASES))
+def test_labelled_head_matches_full_head(case):
+    """Loss and every gradient leaf of ``make_mlm_loss_fn`` (the head on the
+    labelled positions, trip by trip) against ``BertMLM.apply`` +
+    ``mlm_loss`` (the head on every position), in f32. A batch with more
+    labels in a group than a trip holds can only come out equal if the
+    trips beyond the first ran."""
+    counts, extra_trips, ignore_index, bunched = _HEAD_CASES[case]
+    rng = np.random.default_rng(sorted(_HEAD_CASES).index(case))
+    model = BertMLM(BertConfig.tiny())
+    labels = _labels_with(counts, rng, ignore_index, bunched)
+    batch = {"input_ids": jnp.asarray(rng.integers(0, 512, size=(_B, _S)),
+                                      jnp.int32),
+             "attention_mask": jnp.ones((_B, _S), jnp.int32),
+             "labels": jnp.asarray(labels)}
+    params = model.init(jax.random.key(1), batch["input_ids"][:2],
+                        batch["attention_mask"][:2])["params"]
+    # an all-zero bias hides a wrong gradient of it
+    params["mlm_bias"] = jnp.asarray(rng.normal(0, 0.1, 512), jnp.float32)
+
+    def full(params, batch):
+        logits = model.apply({"params": params}, batch["input_ids"],
+                             batch["attention_mask"])
+        return mlm_loss(logits, batch["labels"], ignore_index)
+
+    per_group, rows = head_groups(_B, _S)
+    assert (per_group, rows) == (16, 256)
+    _, trips = _head_slots(jnp.asarray(labels).reshape(2, -1), rows,
+                           ignore_index)
+    assert max(int(trips) - 1, 0) == extra_trips
+    before = obs.default_registry().snapshot()["ps_mlm_head_overflow_total"]
+    assert count_head_overflow(labels, ignore_index) == extra_trips
+    after = obs.default_registry().snapshot()["ps_mlm_head_overflow_total"]
+    assert after - before == extra_trips
+
+    want_loss, want = jax.jit(jax.value_and_grad(full))(params, batch)
+    loss, got = jax.jit(jax.value_and_grad(
+        make_mlm_loss_fn(model, ignore_index)))(params, batch)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=2e-6)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-7),
+        got, want)
+    if not sum(counts):
+        assert float(loss) == 0.0
+        assert all(not np.any(g) for g in jax.tree_util.tree_leaves(got))
+
+
+def test_logits_at_positions_are_the_full_logits_there():
+    """``positions`` runs the head on those positions' hidden states;
+    without it ``BertMLM.apply`` returns every position's logits as ever."""
+    model, params, batch = _tiny_model_and_batch(batch_size=4, seq_len=32)
+    positions = jnp.asarray(
+        np.random.default_rng(0).integers(0, 32, size=(4, 5)), jnp.int32)
+    full = model.apply({"params": params}, batch["input_ids"],
+                       batch["attention_mask"])
+    picked = model.apply({"params": params}, batch["input_ids"],
+                         batch["attention_mask"], positions=positions)
+    assert picked.shape == (4, 5, model.cfg.vocab_size)
+    np.testing.assert_allclose(
+        picked, jnp.take_along_axis(full, positions[..., None], axis=1),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("batch_size,seq_len,per_group,rows", [
+    (32, 512, 2, 256),    # the benchmark's cells: 16 groups of 1,024
+    (128, 128, 8, 256),
+    (128, 512, 2, 256),   # four chips' batch: 64 groups, 16 a chip
+    (16, 32, 16, 128),    # under 1,024 positions in all: one group
+    (6, 500, 3, 376),     # 1,500 positions: a quarter in whole sublanes
+    (4, 1, 4, 4),         # a trip never holds more than the group
+])
+def test_head_groups_follow_the_shapes(batch_size, seq_len, per_group, rows):
+    assert head_groups(batch_size, seq_len) == (per_group, rows)
+
+
+def test_head_gauges_say_what_a_step_runs():
+    """Set when the loss is traced: rows of the head's first trip over all
+    groups, and the batch's positions; 512 / 2048 reads a share of 0.25."""
+    model = BertMLM(BertConfig.tiny())
+    batch = {k: jax.ShapeDtypeStruct((_B, _S), jnp.int32)
+             for k in ("input_ids", "attention_mask", "labels")}
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((2, _S), jnp.int32),
+                             jnp.ones((2, _S), jnp.int32))["params"],
+        jax.random.key(0))
+    jax.eval_shape(make_mlm_loss_fn(model), params, batch)
+    snap = obs.default_registry().snapshot()
+    assert snap["ps_mlm_head_rows"] == 512
+    assert snap["ps_mlm_head_positions"] == 2048
+    text = obs.default_registry().render_prometheus()
+    for name in ("ps_mlm_head_rows", "ps_mlm_head_positions",
+                 "ps_mlm_head_overflow_total"):
+        assert name in text

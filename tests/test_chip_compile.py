@@ -115,3 +115,47 @@ def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert " while(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+@pytest.mark.parametrize("batch,seq", [(32, 512), (128, 128)])
+def test_labelled_mlm_head_compiles_at_the_cells_shapes(batch, seq, one_chip,
+                                                        no_compile_cache):
+    """BERT-base's embeddings and MLM head (no encoder layer) through
+    ``make_mlm_loss_fn``, forward and backward, at the BERT cells' batches
+    and the published vocabulary: the head runs on a quarter of the 16,384
+    positions a trip, so nothing as wide as the vocabulary has more rows
+    than that (the parent's logits were ``f32[16384, 30522]``, 2 GB); the
+    later trips are ``while`` loops the labels' count bounds, never a
+    second head at full size."""
+    import re
+
+    import numpy as np
+
+    from ps_tpu.models.bert import (BertConfig, BertMLM, head_groups,
+                                    make_mlm_loss_fn)
+
+    model = BertMLM(BertConfig(num_layers=0))
+    vocab = model.cfg.vocab_size
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(described, jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((2, seq), jnp.int32),
+                             jnp.ones((2, seq), jnp.int32))["params"],
+        jax.random.key(0)))
+    batch_shapes = {k: jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                            sharding=one_chip)
+                    for k in ("input_ids", "labels", "attention_mask")}
+    text = jax.jit(jax.value_and_grad(make_mlm_loss_fn(model))).lower(
+        params, batch_shapes).compile().as_text()
+    per_group, rows = head_groups(batch, seq)
+    head_rows = batch // per_group * rows
+    assert head_rows == 4096
+    wide = {tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"\w+\[([0-9,]+)\]", text)
+            if str(vocab) in dims.split(",")}
+    assert (16, 256, vocab) in wide  # a trip's logits
+    too_tall = [d for d in wide if np.prod(d) // vocab > head_rows]
+    assert not too_tall, too_tall
+    assert text.count(" while(") == 2 and " conditional(" not in text

@@ -303,6 +303,57 @@ def test_sharded_transformer_moves_weights_only():
         engine._state))
 
 
+def test_sharded_bert_labelled_head_moves_weights_only():
+    """The four-device sharded BERT step with the head on the labelled
+    positions (``make_mlm_loss_fn``): the rows are picked inside groups of
+    whole consecutive sequences, so the batch's sharding carries through the
+    selection and the collectives stay weights, gradients and scalars (the
+    labels' count, the trips). No collective carries the sequence, a
+    group's positions, a trip's rows or the batch's positions; and the loss
+    is the one-device loss."""
+    from ps_tpu.models.bert import (BertConfig, BertMLM, head_groups,
+                                    make_mlm_loss_fn)
+
+    b, s = 8, 512  # four groups of two sequences, one a device
+    cfg = BertConfig.tiny(vocab_size=600, hidden_size=48, max_len=s,
+                          intermediate_size=96)
+    model = BertMLM(cfg)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(110, 600, size=(b, s)).astype(np.int32)
+    masked = rng.random((b, s)) < 0.15
+    batch = {"input_ids": np.where(masked, 103, ids).astype(np.int32),
+             "labels": np.where(masked, ids, -100).astype(np.int32),
+             "attention_mask": np.ones_like(ids)}
+    params = model.init(jax.random.key(0), jnp.zeros((2, s), jnp.int32),
+                        jnp.ones((2, s), jnp.int32))["params"]
+    loss_fn = make_mlm_loss_fn(model)
+    want = float(jax.jit(loss_fn)(params, batch))
+
+    ps.init(backend="tpu", mesh_shape={"data": 4})
+    store = ps.KVStore(optimizer="lamb", learning_rate=1e-3,
+                       placement="sharded")
+    store.init(params)
+    run = store.make_step(loss_fn)
+    placed = store.shard_batch(batch)
+    per_group, rows = head_groups(b, s)
+    assert (b // per_group, rows) == (4, 256)
+    # no parameter has one of these as a dim (600, 48, 96, 4, 12, 2); the
+    # sequence is a dim of the position embedding and of nothing else moved
+    activation_dims = {s, rows, per_group * s, b * s}
+    txt = run.compiled_text(placed)
+    coll = _collective_shapes(txt)
+    moved = [line for _, shapes, line in coll for dims in shapes
+             if activation_dims & set(dims)
+             and dims not in ((s, 48), (1, s, 48))]
+    assert not moved, moved
+    assert not re.search(r" (all-to-all|collective-permute)(-start)?\(", txt)
+    gathered = {dims for op, shapes, _ in coll if op == "all-gather"
+                for dims in shapes}
+    assert (600, 48) in gathered, gathered  # the tied embedding, whole
+    loss, _ = run(placed)
+    np.testing.assert_allclose(float(loss), want, rtol=1e-5)
+
+
 def test_sharded_matches_replicated_on_transformer_shapes():
     """Eight LAMB steps: stating the shardings changes where the sums are
     taken, not the numbers (tests/test_bert.py's tolerance for sharded
