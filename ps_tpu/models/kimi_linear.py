@@ -19,8 +19,8 @@ and the equations of each part are written out in the plain reference's
 docstring (``tests/kimi_reference.py``), which this module is held to. What
 differs here is how they are computed:
 
-- ``kda_block``: q, k and v each through the four causal taps of
-  ``ops/gated_conv.py::causal_taps`` and a SiLU, q and k L2-normalised a head;
+- ``kda_block``: q, k and v each through the four causal taps and the SiLU
+  of ``ops/gated_conv.py::conv_silu``, q and k L2-normalised a head;
   the log-decay ``-exp(A_log) * softplus((x Wfa) Wfb + dt_bias)``, the write
   strength ``sigmoid(x Wb)``; the rule itself in its chunked form
   (``ops/kda.py``, chunks of 64); the gated per-head RMSNorm; the out
@@ -75,7 +75,7 @@ from ps_tpu.models.lm import make_attn_fn, token_ce
 from ps_tpu.models.olmoe import rms_norm
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
-from ps_tpu.ops.gated_conv import causal_taps, shift
+from ps_tpu.ops.gated_conv import conv_silu
 from ps_tpu.ops.kda import kda
 
 
@@ -226,37 +226,6 @@ def init_expert_bias(config: KimiLinearConfig):
     """The selection bias at step 0: zeros, one row an expert layer."""
     return jnp.zeros((config.num_expert_layers, config.router_width),
                      jnp.float32)
-
-
-@jax.custom_vjp
-def conv_silu(x, w):
-    """``silu`` of the depthwise causal convolution of ``x`` [B, S, C] with
-    the filter ``w`` [C, taps] (zero left pad, no bias), in f32, the result
-    in ``x``'s dtype. One rule in each direction as
-    ``ops/gated_conv.py::gated_short_conv``'s: only ``x`` and ``w`` are kept,
-    and the backward pass is the transposed taps, not autodiff's pads and
-    slices of a concatenation."""
-    taps = causal_taps(x.astype(jnp.float32), w.astype(jnp.float32))
-    return jax.nn.silu(taps).astype(x.dtype)
-
-
-def _conv_silu_fwd(x, w):
-    return conv_silu(x, w), (x, w)
-
-
-def _conv_silu_bwd(res, dy):
-    x, w = res
-    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
-    z = causal_taps(xf, wf)
-    gate = jax.nn.sigmoid(z)
-    dz = dy.astype(jnp.float32) * gate * (1 + z * (1 - gate))
-    taps = w.shape[-1]
-    dw = jnp.stack([jnp.sum(dz * shift(xf, taps - 1 - j), axis=(0, 1))
-                    for j in range(taps)], axis=-1)
-    return causal_taps(dz, wf, -1).astype(x.dtype), dw.astype(w.dtype)
-
-
-conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(2, 3))

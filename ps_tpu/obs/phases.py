@@ -4,9 +4,9 @@ the spans of a process's set-up.
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
-``models/kimi_linear.py``) and land in the ``op_name`` of every HLO
-instruction traced under them; the host spans are recorded with
-``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
+``models/kimi_linear.py``, ``models/nemotron_h.py``) and land in the
+``op_name`` of every HLO instruction traced under them; the host spans are
+recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
 ``api.py``, ``kv/store.py``, ``kv/sparse.py``), and the compiler's spans by
 the one listener on ``jax.monitoring`` (``obs/compiles.py``).
@@ -73,6 +73,18 @@ KDA_CORE = "ps.kda/core"          # ops/kda.py alone: the chunked gated delta ru
 MOE_SHARED = "ps.moe/shared"      # the shared expert, a SwiGLU every token passes
 
 KIMI_SCOPES = MOE_SCOPES + (FFN, KDA, KDA_CONV, KDA_CORE, MOE_SHARED)
+
+# -- scopes of Nemotron-H (models/nemotron_h.py), beside the six ----------------
+# Read by ``benchmark/layer_metrics/nemo.py``, which keeps its own copy. The
+# attention layer is under ATTN, the shared expert under MOE_SHARED.
+# MAMBA_CONV and MAMBA_SSD nest under MAMBA, so MAMBA's time holds them.
+MAMBA = "ps.mamba"                # the Mamba-2 mixer: in projection, filter, scan, gated norm, out projection
+MAMBA_CONV = "ps.mamba/conv"      # the depthwise causal filter over x, B and C, its bias and SiLU
+MAMBA_SSD = "ps.mamba/ssd"        # ops/ssd.py alone: the chunked scalar-decay scan
+MOE_LATENT = "ps.moe/latent"      # the two projections between the model's width and the experts' latent
+
+NEMOTRON_SCOPES = MOE_SCOPES + (MAMBA, MAMBA_CONV, MAMBA_SSD, MOE_LATENT,
+                                MOE_SHARED)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

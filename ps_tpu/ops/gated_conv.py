@@ -22,9 +22,12 @@ four and writes three: 11 x tokens x D x itemsize bytes a layer, which
 ``benchmark/families/lfm2_step.py::conv_gate_bytes`` counts. The products
 and the taps' sum run in f32 and the results are cast to ``bcx``'s dtype.
 
-The taps themselves (``causal_taps``) are public: Kimi Delta Attention's
-depthwise convolutions (``models/kimi_linear.py``) are the same shifted
-products with four taps, differentiated by autodiff there.
+The taps themselves (``causal_taps``) are public, and so is the other short
+convolution built on them, ``conv_silu``: a depthwise causal filter, an
+optional bias and a SiLU, with one rule in each direction. Kimi Delta
+Attention runs it on q, k and v (four taps, no bias, ``models/kimi_linear.py``)
+and the Mamba-2 mixer on its x, B and C channels (four taps and a bias,
+``models/nemotron_h.py``).
 """
 
 from __future__ import annotations
@@ -83,3 +86,43 @@ def _bwd(res, dy):
 
 
 gated_short_conv.defvjp(_fwd, _bwd)
+
+
+@jax.custom_vjp
+def _conv_silu(x, w, b):
+    taps = causal_taps(x.astype(jnp.float32), w.astype(jnp.float32))
+    if b is not None:
+        taps = taps + b.astype(jnp.float32)
+    return jax.nn.silu(taps).astype(x.dtype)
+
+
+def _conv_silu_fwd(x, w, b):
+    return _conv_silu(x, w, b), (x, w, b)
+
+
+def _conv_silu_bwd(res, dy):
+    x, w, b = res
+    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+    z = causal_taps(xf, wf)
+    if b is not None:
+        z = z + b.astype(jnp.float32)
+    gate = jax.nn.sigmoid(z)
+    dz = dy.astype(jnp.float32) * gate * (1 + z * (1 - gate))
+    taps = w.shape[-1]
+    dw = jnp.stack([jnp.sum(dz * shift(xf, taps - 1 - j), axis=(0, 1))
+                    for j in range(taps)], axis=-1)
+    db = None if b is None else jnp.sum(dz, axis=(0, 1)).astype(b.dtype)
+    return causal_taps(dz, wf, -1).astype(x.dtype), dw.astype(w.dtype), db
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def conv_silu(x, w, b=None):
+    """``silu`` of the depthwise causal convolution of ``x`` [B, S, C] with
+    the filter ``w`` [C, taps] (zero left pad) plus the bias ``b`` [C] if
+    there is one, in f32, the result in ``x``'s dtype. One rule in each
+    direction as ``gated_short_conv``'s: only ``x``, ``w`` and ``b`` are
+    kept, and the backward pass is the transposed taps, not autodiff's pads
+    and slices of a concatenation."""
+    return _conv_silu(x, w, b)

@@ -150,7 +150,7 @@ GROUP = 2
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _masked_exp(x, keep):
+def masked_exp(x, keep):
     """``exp(x)`` where ``keep``, else 0, with no overflow and no NaN in the
     gradient behind the mask."""
     return jnp.where(keep, jnp.exp(jnp.where(keep, x, 0.0)), 0.0)
@@ -191,11 +191,11 @@ def _chunk_internals(q, k, v, g, beta):
     kb = blocks(k)
     # keys in front of sub-block i, carried to its boundary: [.., nb, C, K]
     before = (jnp.arange(c) < SUB * jnp.arange(nb)[:, None])[..., None]
-    carried = k[..., None, :, :] * _masked_exp(
+    carried = k[..., None, :, :] * masked_exp(
         edge[..., :, None, :] - cum[..., None, :, :], before)
     # every pair of one sub-block: [.., nb, SUB, SUB, K]
     lower = jnp.tril(jnp.ones((SUB, SUB), bool))[..., None]
-    pair = _masked_exp(cum_b[..., :, None, :] - cum_b[..., None, :, :], lower)
+    pair = masked_exp(cum_b[..., :, None, :] - cum_b[..., None, :, :], lower)
     same_block = jnp.eye(nb, dtype=q.dtype)
 
     def against_keys(x):

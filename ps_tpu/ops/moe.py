@@ -29,11 +29,30 @@ order, the grouped matmuls run over ``[count, D, F]`` stacks with the held
 experts' group sizes, and ``combine`` gives every absent pair the weight 0:
 the layer's output is the part of the whole layer's that its own experts
 give, and the shares of all chips add up to it. Dropless still. The row
-buffers keep their worst-case length ``T * top_k``; what the grouped matmul
-leaves in the rows past the last group is unspecified, so those rows are
-masked with ``where`` (never by a product: 0 x NaN) where they enter
-(``dispatch``, which also zeroes the cotangent on its way back to the
-tokens) and where they leave (``combine``).
+buffers keep their worst-case length: a token's picks are distinct experts,
+so no more than ``count`` of them are held, and where that is fewer than
+``top_k`` (22 picks on 8 held experts) ``route`` keeps each token's held
+picks and as many absent ones as fill ``count`` places. Everything after it
+sees a routing of ``min(top_k, count)`` picks a token and buffers of ``T *
+min(top_k, count)`` rows. What the grouped matmul leaves in the rows past the
+last group is unspecified, so those rows are masked with ``where`` (never by
+a product: 0 x NaN) where they enter (``dispatch``, which also zeroes the
+cotangent on its way back to the tokens) and where they leave (``combine``).
+
+**The grouped matmuls' time follows the live rows**, not the buffer: on the
+v5e 0.5-0.6 us a row over ``relu2`` experts of 1,024 x 2,688, forward and
+backward (PR 39, fourteen seeds: the step's time against the held pairs), so
+a share's step is as fast as its experts are unpopular at the seed.
+``expert_ffn(..., expected_rows=R)`` makes it the same for every load up to
+``R``: the last expert's group takes the zero rows after the live ones up to
+``R`` (zeros in, zeros out, a zero gradient: no value changes by a bit).
+With more live rows than ``R`` nothing is added and the time follows them
+again. Dropless as ever: the buffers keep their length.
+
+The rows need not be as wide as the router's input: ``route`` reads the
+tokens the router was trained on, ``dispatch`` and ``combine`` move whatever
+rows they are given (a model whose experts work in a latent hands them the
+tokens' latent projection).
 """
 
 from __future__ import annotations
@@ -50,6 +69,8 @@ class Routing(NamedTuple):
 
     logits: jax.Array       # [T, E] f32 router logits
     probs: jax.Array        # [T, E] f32 scores over all E (softmax or sigmoid)
+    #: ``k`` below is ``top_k``, or the number of held experts where that is
+    #: smaller
     weights: jax.Array      # [T, k] f32 weights of the picks
     experts: jax.Array      # [T, k] int32 picked experts, best first
     group_sizes: jax.Array  # [held] int32 pairs per held expert
@@ -94,6 +115,8 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     whether or not their experts are held; ``scaling`` multiplies them
     (``routed_scaling_factor``). ``held = (start, count)`` says which
     experts this layer computes (module docstring); None is all of them.
+    Where fewer experts are held than a token picks, the routing returned is
+    of ``count`` picks a token, every held one among them.
     The matmul runs at the highest precision: 2 * T * D * E operations, and
     which expert a token goes to should not hang on a bf16 pass."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
@@ -128,12 +151,20 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     start, count = held
     if not 0 <= start <= start + count <= num_experts:
         raise ValueError(f"held experts {held} lie outside 0..{num_experts}")
+    counts = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+    if count < top_k:
+        # a token's picks are distinct, so at most ``count`` are held: its
+        # held picks first (in their order), the row buffers ``count`` a token
+        absent = (experts < start) | (experts >= start + count)
+        kept = jnp.argsort(absent, axis=-1, stable=True)[:, :count]
+        experts = jnp.take_along_axis(experts, kept, axis=-1)
+        weights = jnp.take_along_axis(weights, kept, axis=-1)
+        flat = experts.reshape(-1)
     # held pairs first, in expert order; the absent ones after them
     local = flat - start
     is_held = (local >= 0) & (local < count)
     order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
     inverse = jnp.argsort(order)
-    counts = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
     return Routing(logits, probs, weights, experts.astype(jnp.int32),
                    counts[start:start + count], order, inverse, counts,
                    is_held.reshape(experts.shape))
@@ -182,14 +213,30 @@ def _dispatch_bwd(top_k, inverse, g):
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def expert_ffn(rows, gate, up, down, group_sizes):
-    """SwiGLU of every expert over its group of ``rows`` [T * k, D]:
-    ``(silu(rows @ gate[e]) * (rows @ up[e])) @ down[e]`` with ``gate``,
-    ``up`` [E, D, F] and ``down`` [E, F, D], ``E`` the experts held. Rows
-    past ``sum(group_sizes)`` come out unspecified."""
+def expert_ffn(rows, gate, up, down, group_sizes, activation="swiglu",
+               expected_rows=None):
+    """Every expert's feed-forward over its group of ``rows`` [T * k, D],
+    with ``gate``, ``up`` [E, D, F] and ``down`` [E, F, D], ``E`` the experts
+    held. ``activation`` 'swiglu': ``(silu(rows @ gate[e]) * (rows @ up[e]))
+    @ down[e]``; 'relu2', the ungated form (``up`` is None):
+    ``relu(rows @ gate[e]) ** 2 @ down[e]``. Rows past ``sum(group_sizes)``
+    come out unspecified. ``expected_rows``, for rows that are zero past the
+    last group (as ``dispatch`` leaves a share's): the grouped matmuls do the
+    work of at least that many rows whatever the groups' sizes (module
+    docstring)."""
+    if activation not in ("swiglu", "relu2") or (
+            (activation == "relu2") != (up is None)):
+        raise ValueError(f"expert_ffn: activation {activation!r} with "
+                         f"{'no' if up is None else 'an'} up matrix")
+    if expected_rows is not None:
+        spare = min(expected_rows, rows.shape[0]) - jnp.sum(group_sizes)
+        group_sizes = group_sizes.at[-1].add(jnp.maximum(spare, 0))
     g = jax.lax.ragged_dot(rows, gate, group_sizes)
-    u = jax.lax.ragged_dot(rows, up, group_sizes)
-    return jax.lax.ragged_dot(jax.nn.silu(g) * u, down, group_sizes)
+    if up is None:
+        hidden = jnp.square(jax.nn.relu(g))
+    else:
+        hidden = jax.nn.silu(g) * jax.lax.ragged_dot(rows, up, group_sizes)
+    return jax.lax.ragged_dot(hidden, down, group_sizes)
 
 
 def combine(rows, routing: Routing):

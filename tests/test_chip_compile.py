@@ -14,6 +14,7 @@ from jax.sharding import SingleDeviceSharding
 from ps_tpu.ops import flash_attention
 from ps_tpu.ops.gated_conv import gated_short_conv
 from ps_tpu.ops.kda import kda, path
+from ps_tpu.ops.ssd import ssd
 
 
 @pytest.fixture(scope="module")
@@ -43,14 +44,17 @@ def no_compile_cache():
 
 
 #: [B, S, query heads, K/V heads, head dim (, the values' own)], causal,
-#: Mosaic calls of the gradient: the four cells' calls. The forward, dk / dv
+#: Mosaic calls of the gradient: the five cells' calls. The forward, dk / dv
 #: and dq; at BERT's shape one backward tile spans the sequence and one call
-#: gives all three. Kimi's latent attention has keys of 192 and values of 128
+#: gives all three. Kimi's latent attention has keys of 192 and values of 128;
+#: Nemotron-H's share is 4 query heads on 1 K/V head
 CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True, 3),
          "olmoe-1b-7b.s4096.zipf": ((2, 4096, 16, 16, 128), True, 3),
          "bert-base.s512.flash": ((32, 512, 12, 12, 64), False, 2),
          "kimi-linear-48b-a3b.s8192.b1.zipf":
-             ((1, 8192, 32, 32, 192, 128), True, 3)}
+             ((1, 8192, 32, 32, 192, 128), True, 3),
+         "nemotron-3-super-120b-a12b.s8192.b1.zipf":
+             ((1, 8192, 4, 1, 128), True, 3)}
 
 
 @pytest.mark.parametrize("cell", sorted(CALLS))
@@ -115,6 +119,30 @@ def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert " while(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+def test_chunked_ssd_compiles_at_the_cells_shape(one_chip, no_compile_cache):
+    """``ops/ssd.py`` at the Nemotron cell's share, [1, 8192, 16, 64] on one
+    B/C group of state 128 in chunks of 128, forward and backward: plain XLA
+    (no Mosaic call), the state carried by ``while`` loops over the 64
+    chunks, and the chunks' [128, 128] decay matrices of all heads (67 MB in
+    f32) a few times over, not a buffer a token pair."""
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg(1, 8192, 16, 64), arg(1, 8192, 16, dtype=jnp.float32),
+            arg(16, dtype=jnp.float32), arg(1, 8192, 1, 128),
+            arg(1, 8192, 1, 128))
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(ssd(x, dt, a, b, c, chunk=128).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert " while(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 @pytest.mark.parametrize("batch,seq", [(32, 512), (128, 128)])

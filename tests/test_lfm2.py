@@ -205,7 +205,9 @@ def test_the_shares_add_up():
 
 def test_renormalisation_runs_over_all_four_picks():
     sizes, lp, x, bias = _layer()
-    _, routing = _share(sizes, lp, x, bias, 6, 2)
+    # four held of four picks: a routing that keeps all of a token's picks
+    # (fewer held than picks keeps the held ones, ops/moe.py)
+    _, routing = _share(sizes, lp, x, bias, 6, 4)
     scores = jax.nn.sigmoid(jnp.dot(x[0], lp["router"]["kernel"],
                                     precision="highest"))
     picked = jnp.take_along_axis(scores, routing.experts, axis=-1)

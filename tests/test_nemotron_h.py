@@ -1,0 +1,833 @@
+"""Nemotron-H (``ps_tpu/models/nemotron_h.py``; the chunked state-space scan of
+``ps_tpu/ops/ssd.py``; the four-tap filter with its bias in
+``ps_tpu/ops/gated_conv.py``; the ungated experts, the latent rows and the
+shorter row buffers of ``ps_tpu/ops/moe.py``) against its plain reference
+(``tests/nemotron_h_reference.py``: the scan token by token, whole rows of
+attention, a masked loop over the held experts), at small sizes on the CPU
+with seeded weights; the shares of every layer added up to the uncut layer;
+the benchmark's own copy of that reference held equal to it; then the family's
+pieces.
+
+Tolerances. Both sides compute in f32 here and differ only in the order of
+their sums: losses agree to a few f32 roundoffs, gradients to 1e-5 of their
+largest entry (seen: under 9e-6). The weights are scaled up from the cell's
+0.02 so that every mixer and every expert moves the loss by far more than
+that.
+"""
+
+import functools
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import nemotron_h_reference as reference
+from benchmark.families import nemotron_h_reference as benchmark_copy
+from benchmark.families import nemotron_h_step
+from benchmark.layer_metrics import nemo as nemo_metrics
+from ps_tpu.models import nemotron_h
+from ps_tpu.models.lm import make_attn_fn
+from ps_tpu.ops import moe
+from ps_tpu.ops.gated_conv import conv_silu
+from ps_tpu.ops.ssd import ssd
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+F32_TOL = 1e-5
+CELL = "nemotron-3-super-120b-a12b.s8192.b1.zipf"
+CONFIG = "benchmark/configs/nemotron-3-super-120b-a12b.json"
+#: the cell's eleven-layer pattern in small: 4 of 32 Mamba heads with 2 of 16
+#: B/C groups, 4 query heads on 2 K/V heads, 2 of 16 experts held, 6 picks
+SIZES = dict(
+    vocab_size=256, hidden_size=64, num_hidden_layers=11,
+    hybrid_override_pattern="MEMEMEMEM*E", mamba_num_heads=4,
+    mamba_head_dim=8, n_groups=2, ssm_state_size=16, conv_kernel=4,
+    chunk_size=32, time_step_min=1e-3, time_step_max=1e-1,
+    time_step_floor=1e-4, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, router_width=16, n_routed_experts=2, expert_start=4,
+    num_experts_per_tok=6, moe_latent_size=32, moe_intermediate_size=24,
+    moe_shared_expert_intermediate_size=48, n_shared_experts=1,
+    norm_topk_prob=True, routed_scaling_factor=5.0, bias_update_rate=1e-3,
+    layer_norm_epsilon=1e-5, n_group=1, topk_group=1, mlp_hidden_act="relu2",
+    mamba_hidden_act="silu", tie_word_embeddings=False,
+    num_nextn_predict_layers=0, use_conv_bias=True, dtype="float32")
+
+
+def _setup(seed=0, batch=2, seq=128, **changes):
+    sizes = {**SIZES, **changes}
+    cfg = nemotron_h.NemotronHConfig.from_dict(sizes)
+    params = jax.jit(lambda k: nemotron_h.init_params(k, cfg))(
+        jax.random.key(seed))
+    # away from the cell's 0.02: every layer then matters to the loss
+    params = jax.tree_util.tree_map(lambda x: 5 * x if x.ndim > 1 else x,
+                                    params)
+    rng = np.random.default_rng(seed)
+    for i, kind in enumerate(cfg.hybrid_override_pattern):
+        if kind == "M":   # a filter bias that is not nothing
+            bias = params[f"layer{i}"]["mamba"]["conv"]
+            bias["bias"] = jnp.asarray(0.3 * rng.normal(
+                size=bias["bias"].shape), jnp.float32)
+    ids = rng.integers(0, sizes["vocab_size"],
+                       size=(batch, seq + 1)).astype(np.int32)
+    bias = jnp.asarray(0.1 * rng.normal(size=(
+        cfg.num_expert_layers, cfg.router_width)), jnp.float32)
+    return sizes, cfg, params, {"inputs": ids[:, :-1],
+                                "targets": ids[:, 1:]}, bias
+
+
+def _system(cfg, params, batch, bias, attn="full"):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            nemotron_h.make_loss_fn(cfg, attn=attn), has_aux=True))(
+                params, batch, bias)
+
+
+def _plain(sizes, params, batch, bias, module=reference):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            lambda p: module.loss_fn(p, batch, bias, sizes), has_aux=True))(
+                params)
+
+
+@functools.lru_cache(maxsize=None)
+def _base():
+    sizes, cfg, params, batch, bias = _setup()
+    return sizes, cfg, params, batch, bias, _plain(sizes, params, batch, bias)
+
+
+def _rel(got, want):
+    return float(jnp.max(jnp.abs(got - want))
+                 / (jnp.max(jnp.abs(want)) + 1e-30))
+
+
+def _assert_grads_close(grads, ref_grads, tol=F32_TOL):
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
+        assert _rel(g, r) <= tol, (jax.tree_util.keystr(path), _rel(g, r))
+
+
+# -- (b) the model against the reference --------------------------------------
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_system_matches_reference(attn):
+    """Loss, logits, counts, the next bias and every gradient, for the
+    eleven-layer pattern with two of sixteen experts held and six picks a
+    token (more picks than held experts: the shorter row buffers)."""
+    sizes, cfg, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
+    (loss, aux), grads = _system(cfg, params, batch, bias, attn)
+    assert abs(float(loss) - float(ref_loss)) <= F32_TOL * float(ref_loss)
+    for name in ("expert_tokens", "held_tokens", "expert_bias"):
+        np.testing.assert_array_equal(np.asarray(aux[name]),
+                                      np.asarray(ref_aux[name]))
+    assert aux["expert_tokens"].shape == (5, 16)
+    assert aux["held_tokens"].shape == (5, 2)
+    assert np.all(np.asarray(aux["expert_tokens"]).sum(-1) == 2 * 128 * 6)
+    # every tensor has a gradient that is not nothing
+    assert all(float(jnp.max(jnp.abs(g))) > 0
+               for g in jax.tree_util.tree_leaves(ref_grads))
+    _assert_grads_close(grads, ref_grads)
+    with jax.default_matmul_precision("highest"):
+        hidden, _, _ = nemotron_h.apply(params, batch["inputs"], cfg, bias,
+                                        make_attn_fn(attn),
+                                        grouped=attn == "flash")
+        logits = nemotron_h.logits_of(params, hidden, cfg)
+        want = reference.logits_fn(params, batch["inputs"], bias, sizes)
+    assert logits.shape == (2, 128, 256)
+    assert _rel(logits, want) <= F32_TOL
+
+
+def test_fused_step_matches_reference():
+    """Through ``KVStore.make_step(has_aux=True)`` with the bias as the
+    step's extra argument: the loss, the aux and, read from AdamW's first
+    moment behind a clip that does not bite, every gradient; then AdamW's
+    rule on the parameters. A batch of eight: the test mesh has eight
+    devices along ``data``."""
+    import optax
+
+    import ps_tpu as ps
+
+    sizes, cfg, params, batch, bias = _setup(seed=1, batch=8, seq=64)
+    (ref_loss, ref_aux), ref_grads = _plain(sizes, params, batch, bias)
+    rule = dict(learning_rate=1e-3, b1=0.9, b2=0.95, eps=1e-8,
+                weight_decay=0.1)
+    ps.init(backend="tpu")
+    try:
+        store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1e9,
+                           placement="replicated", **rule)
+        store.init(params)
+        step = store.make_step(nemotron_h.make_loss_fn(cfg), has_aux=True)
+        with jax.default_matmul_precision("highest"):
+            loss, _, aux = step(store.shard_batch(batch), bias)
+        assert abs(float(loss) - float(ref_loss)) <= F32_TOL * float(ref_loss)
+        for name in ("expert_tokens", "held_tokens", "expert_bias"):
+            np.testing.assert_array_equal(np.asarray(aux[name]),
+                                          np.asarray(ref_aux[name]))
+        flat = jax.tree_util.tree_leaves_with_path(ref_grads)
+        assert len(flat) == len(store.keys())
+        for path, r in flat:
+            key = "/".join(p.key for p in path)
+            state = store.optimizer_state(key)
+            mu = optax.tree_utils.tree_get(state, "mu")
+            # a head's A_log, dt_bias and D sum over every token, with both
+            # signs: sums in another order lose more of them
+            assert _rel(mu / 0.1, r) <= (10 if r.ndim == 1 else 1) * F32_TOL, \
+                key
+            before = functools.reduce(lambda t, p: t[p.key], path, params)
+            want = nemotron_h_step.adamw_first_step(
+                before, mu, optax.tree_utils.tree_get(state, "nu"), **rule)
+            np.testing.assert_allclose(store.pull(key), want, atol=1e-6)
+    finally:
+        ps.shutdown()
+
+
+# -- (a) the chunked scan against the recurrence ------------------------------
+
+def _scan_inputs(seq, heads=4, width=8, groups=2, state=16, batch=2, seed=0):
+    """Steps and rates as strong as the configuration's strongest head gives
+    and more (``exp(A_log)`` 16, steps up to 3: 48 nats a token), beside a
+    weak head."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(batch, seq, heads, width))
+    dt = rng.uniform(0.05, 3.0, size=(batch, seq, heads))
+    a = -np.array([16.0, 4.0, 1.0, 0.25])[:heads]
+    b, c = (rng.normal(size=(batch, seq, groups, state)) for _ in range(2))
+    return [jnp.asarray(t, jnp.float32) for t in (x, dt, a, b, c)]
+
+
+def _recurrence(x, dt, a, b, c):
+    per_group = x.shape[2] // b.shape[2]
+    b, c = (jnp.repeat(t, per_group, axis=2) for t in (b, c))
+    return jax.vmap(reference.selective_scan, in_axes=(0, 0, None, 0, 0))(
+        x, dt, a, b, c)
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("seq", [64, 192],
+                         ids=["one_or_two_chunks", "three_or_six_chunks"])
+def test_chunked_ssd_equals_the_token_by_token_recurrence(seq, chunk):
+    """Forward and all five gradients (x, dt, A, B, C), at decays that
+    overflow where the mask comes after the exponential."""
+    args = _scan_inputs(seq)
+    lost = -np.cumsum(np.asarray(args[1] * args[2])[:, :chunk], axis=1)
+    with np.errstate(over="ignore"):
+        assert np.isinf(np.exp(lost.astype(np.float32))).any()
+    weights = jnp.asarray(np.random.default_rng(9).normal(
+        size=args[0].shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = ssd(*args, chunk=chunk)
+        want = _recurrence(*args)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert _rel(got, want) <= F32_TOL
+        grads, ref_grads = (
+            jax.grad(lambda *a: jnp.sum(f(*a) * weights),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+            for f in (functools.partial(ssd, chunk=chunk), _recurrence))
+    for name, g, r in zip("x dt A B C".split(), grads, ref_grads):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        assert _rel(g, r) <= 10 * F32_TOL, (name, _rel(g, r))
+
+
+def test_ssd_refuses_what_its_chunks_and_groups_do_not_divide():
+    x, dt, a, b, c = _scan_inputs(96)
+    with pytest.raises(ValueError, match="chunk must divide"):
+        ssd(x, dt, a, b, c, chunk=64)
+    with pytest.raises(ValueError, match="groups the heads"):
+        ssd(x, dt, a, b[:, :, :1].repeat(3, axis=2),
+            c[:, :, :1].repeat(3, axis=2), chunk=32)
+
+
+def test_ssd_is_causal_and_keeps_bf16_in_bf16_out():
+    x, dt, a, b, c = _scan_inputs(128)
+    with jax.default_matmul_precision("highest"):
+        whole = ssd(x, dt, a, b, c, chunk=32)
+        changed = ssd(x.at[:, 70:].set(0.0), dt, a, b.at[:, 70:].set(1.0), c,
+                      chunk=32)
+    np.testing.assert_array_equal(np.asarray(whole[:, :70]),
+                                  np.asarray(changed[:, :70]))
+    out = ssd(x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+              c.astype(jnp.bfloat16), chunk=32)
+    assert out.dtype == jnp.bfloat16
+    assert _rel(out.astype(jnp.float32), whole) <= 0.05
+
+
+def test_conv_silu_with_a_bias_is_the_reference_filter():
+    """``ops/gated_conv.py::conv_silu`` at four taps with and without the
+    bias, value and its own backward rule against autodiff of the
+    reference's padded ``nn.Conv1d``."""
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(2, 40, 8)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(8,)), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+
+    def plain(x, w, b):
+        return jnp.stack([reference.conv_silu(s, w, b) for s in x])
+
+    np.testing.assert_allclose(conv_silu(x, w, b), plain(x, w, b), atol=1e-6)
+    np.testing.assert_allclose(conv_silu(x, w), plain(x, w, 0.0), atol=1e-6)
+    got = jax.grad(lambda *a: jnp.sum(conv_silu(*a) * ct),
+                   argnums=(0, 1, 2))(x, w, b)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct),
+                    argnums=(0, 1, 2))(x, w, b)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=2e-5)
+    no_bias = jax.grad(lambda x, w: jnp.sum(conv_silu(x, w) * ct),
+                       argnums=(0, 1))(x, w)
+    for g, r in zip(no_bias, jax.grad(
+            lambda x, w: jnp.sum(plain(x, w, 0.0) * ct),
+            argnums=(0, 1))(x, w)):
+        np.testing.assert_allclose(g, r, atol=2e-5)
+
+
+# -- (c) the shares add up ----------------------------------------------------
+
+def _w(rng, *shape, scale=0.2):
+    return jnp.asarray(rng.normal(size=shape) * scale, jnp.float32)
+
+
+def test_the_eight_head_shares_of_a_mamba_mixer_add_up():
+    """An uncut mixer of 16 heads on 8 B/C groups, and its eight shares of 2
+    heads with 1 group each, their weights sliced from the uncut ones by
+    ``mamba_head_start``: the shares' outputs sum to the uncut reference
+    mixer's (the norm over each group makes that exact), and each share
+    equals the reference given the same share."""
+    heads, groups, p, n, d, taps = 16, 8, 8, 16, 32, 4
+    whole = {**SIZES, "mamba_num_heads": heads, "n_groups": groups,
+             "mamba_head_dim": p, "ssm_state_size": n, "hidden_size": d}
+    inner, gn = heads * p, groups * n
+    rng = np.random.default_rng(5)
+    lp = {"in_proj": {"kernel": _w(rng, d, 2 * inner + 2 * gn + heads)},
+          "conv": {"kernel": _w(rng, inner + 2 * gn, taps, scale=0.5),
+                   "bias": _w(rng, inner + 2 * gn)},
+          "dt_bias": _w(rng, heads, scale=1.0),
+          "A_log": jnp.log(jnp.asarray(rng.uniform(1, 16, heads),
+                                       jnp.float32)),
+          "D": _w(rng, heads, scale=1.0),
+          "out_norm": {"scale": 1 + _w(rng, inner)},
+          "out_proj": {"kernel": _w(rng, inner, d)}}
+    x = _w(rng, 1, 64, d, scale=1.0)
+    with jax.default_matmul_precision("highest"):
+        want = reference.mamba_mixer(lp, x[0], whole)
+    per, total = heads // groups, jnp.zeros_like(want)
+    for share in range(groups):
+        start = share * per                      # mamba_head_start
+        rows = np.arange(start * p, (start + per) * p)      # of d_inner
+        cols = np.concatenate([
+            rows, inner + rows, 2 * inner + share * n + np.arange(n),
+            2 * inner + gn + share * n + np.arange(n),
+            2 * inner + 2 * gn + start + np.arange(per)])
+        conv = np.concatenate([rows, inner + share * n + np.arange(n),
+                               inner + gn + share * n + np.arange(n)])
+        mine = {"in_proj": {"kernel": lp["in_proj"]["kernel"][:, cols]},
+                "conv": {"kernel": lp["conv"]["kernel"][conv],
+                         "bias": lp["conv"]["bias"][conv]},
+                "dt_bias": lp["dt_bias"][start:start + per],
+                "A_log": lp["A_log"][start:start + per],
+                "D": lp["D"][start:start + per],
+                "out_norm": {"scale": lp["out_norm"]["scale"][rows]},
+                "out_proj": {"kernel": lp["out_proj"]["kernel"][rows]}}
+        sizes = {**whole, "mamba_num_heads": per, "n_groups": 1,
+                 "mamba_head_start": start}
+        cfg = nemotron_h.NemotronHConfig.from_dict(sizes)
+        with jax.default_matmul_precision("highest"):
+            out = nemotron_h.mamba_block(mine, x, cfg)[0]
+            np.testing.assert_allclose(
+                out, reference.mamba_mixer(mine, x[0], sizes), atol=2e-5)
+        total = total + out
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    # the uncut mixer runs on the normal path too: more than one group a chip
+    cfg = nemotron_h.NemotronHConfig.from_dict(whole)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(nemotron_h.mamba_block(lp, x, cfg)[0],
+                                   want, atol=5e-5)
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_the_eight_head_shares_of_the_attention_layer_add_up(attn):
+    """16 query heads on 2 K/V heads, eight shares of 2 query heads on the
+    K/V head that serves them (``attention_head_start // 8``)."""
+    heads, kv_heads, dim, d = 16, 2, 16, 32
+    whole = {**SIZES, "num_attention_heads": heads,
+             "num_key_value_heads": kv_heads, "head_dim": dim,
+             "hidden_size": d}
+    rng = np.random.default_rng(6)
+    lp = {"q": {"kernel": _w(rng, d, heads * dim)},
+          "k": {"kernel": _w(rng, d, kv_heads * dim)},
+          "v": {"kernel": _w(rng, d, kv_heads * dim)},
+          "out": {"kernel": _w(rng, heads * dim, d)}}
+    x = _w(rng, 1, 128, d, scale=1.0)
+    with jax.default_matmul_precision("highest"):
+        want = reference.attention_mixer(lp, x[0], whole)
+    per, total = heads // 8, jnp.zeros_like(want)
+    for share in range(8):
+        start = share * per                      # attention_head_start
+        q = np.arange(start * dim, (start + per) * dim)
+        kv = (start // (heads // kv_heads)) * dim + np.arange(dim)
+        mine = {"q": {"kernel": lp["q"]["kernel"][:, q]},
+                "k": {"kernel": lp["k"]["kernel"][:, kv]},
+                "v": {"kernel": lp["v"]["kernel"][:, kv]},
+                "out": {"kernel": lp["out"]["kernel"][q]}}
+        cfg = nemotron_h.NemotronHConfig.from_dict(
+            {**whole, "num_attention_heads": per, "num_key_value_heads": 1,
+             "attention_head_start": start})
+        with jax.default_matmul_precision("highest"):
+            total = total + nemotron_h.attention_block(
+                mine, x, cfg, make_attn_fn(attn), attn == "flash")[0]
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    np.testing.assert_allclose(total, want, atol=5e-5)
+
+
+def _expert_layer(seed=3, tokens=96):
+    sizes = {**SIZES, "n_routed_experts": 16, "expert_start": 0}
+    rng = np.random.default_rng(seed)
+    d, latent, f, fs, e = 64, 32, 24, 48, 16
+    lp = {"router": {"kernel": _w(rng, d, e, scale=0.3)},
+          "latent_down": {"kernel": _w(rng, d, latent)},
+          "latent_up": {"kernel": _w(rng, latent, d)},
+          "w1": _w(rng, e, latent, f), "w2": _w(rng, e, f, latent),
+          "shared": {"w1": {"kernel": _w(rng, d, fs)},
+                     "w2": {"kernel": _w(rng, fs, d)}}}
+    x = _w(rng, 1, tokens, d, scale=1.0)
+    bias = _w(rng, e, scale=0.1)
+    return sizes, lp, x, bias
+
+
+def _expert_share(sizes, lp, x, bias, start, count):
+    cfg = nemotron_h.NemotronHConfig.from_dict(
+        {**sizes, "n_routed_experts": count, "expert_start": start})
+    held = {**lp, **{n: lp[n][start:start + count] for n in ("w1", "w2")}}
+    with jax.default_matmul_precision("highest"):
+        return nemotron_h.moe_block(held, x, cfg, bias)
+
+
+def test_the_expert_shares_add_up_with_the_shared_expert_counted_once():
+    """The latent expert layer run four times, each holding four of the
+    sixteen experts under six picks a token (more picks than held experts):
+    the routed parts and ONE shared expert sum to the uncut reference layer;
+    each share equals the reference's share."""
+    sizes, lp, x, bias = _expert_layer()
+    with jax.default_matmul_precision("highest"):
+        whole, mask = reference.experts(lp, x[0], bias, sizes)
+        shared = reference.relu2_ffn(lp["shared"], x[0])
+    routed = jnp.zeros_like(whole)
+    for start in range(0, 16, 4):
+        out, routing = _expert_share(sizes, lp, x, bias, start, 4)
+        with jax.default_matmul_precision("highest"):
+            want, _ = reference.experts(
+                {**lp, **{n: lp[n][start:start + 4] for n in ("w1", "w2")}},
+                x[0], bias, {**sizes, "n_routed_experts": 4,
+                             "expert_start": start})
+        np.testing.assert_allclose(out[0], want, rtol=1e-5, atol=2e-5)
+        np.testing.assert_array_equal(
+            np.asarray(routing.counts), np.asarray(mask.sum(0), np.int32))
+        # the row buffers are four a token, and hold every held pick
+        assert routing.experts.shape == (96, 4)
+        assert int(routing.group_sizes.sum()) == int(
+            mask[:, start:start + 4].sum()) == int(routing.live.sum())
+        # a token none of whose picks is held gets the shared expert alone
+        nothing = ~np.asarray(routing.live).any(axis=-1)
+        np.testing.assert_allclose(np.asarray(out[0])[nothing],
+                                   np.asarray(shared)[nothing], atol=1e-6)
+        routed = routed + (out[0] - shared)
+    assert float(jnp.max(jnp.abs(shared))) > 0.1
+    np.testing.assert_allclose(routed + shared, whole, rtol=1e-5, atol=5e-5)
+    # every share's output summed counts the shared expert four times
+    assert float(jnp.max(jnp.abs(routed + 4 * shared - whole))) > 0.1
+
+
+def test_routing_is_sigmoid_top_k_renormalised_over_all_picks_and_scaled():
+    sizes, lp, x, bias = _expert_layer()
+    _, routing = _expert_share(sizes, lp, x, bias, 4, 4)
+    scores = jax.nn.sigmoid(jnp.dot(x[0], lp["router"]["kernel"],
+                                    precision="highest"))
+    _, picks = jax.lax.top_k(scores + bias, 6)
+    picked = jnp.take_along_axis(scores, picks, axis=-1)
+    weights = 5.0 * picked / picked.sum(-1, keepdims=True)
+    # the kept picks: every held one, with the weight it has among all six
+    held = (np.asarray(picks) >= 4) & (np.asarray(picks) < 8)
+    live = np.asarray(routing.live)
+    assert live.sum() == held.sum()
+    for t in range(96):
+        kept = dict(zip(np.asarray(routing.experts)[t][live[t]].tolist(),
+                        np.asarray(routing.weights)[t][live[t]].tolist()))
+        want = dict(zip(np.asarray(picks)[t][held[t]].tolist(),
+                        np.asarray(weights)[t][held[t]].tolist()))
+        assert kept.keys() == want.keys()
+        assert all(abs(kept[e] - want[e]) <= 1e-6 for e in want)
+    # the bias carries no gradient, in the model's loss either
+    sizes, cfg, params, batch, bias, _ = _base()
+    g = jax.grad(lambda b: nemotron_h.make_loss_fn(cfg)(
+        params, batch, b)[0])(bias)
+    assert float(jnp.max(jnp.abs(g))) == 0.0
+
+
+@pytest.mark.parametrize("held,top_k,expected_rows", [
+    ((4, 4), 6, None), ((14, 2), 4, None), ((0, 8), 4, None),
+    (None, 3, None), ((4, 4), 6, 192), ((4, 4), 6, 16)],
+    ids=["fewer_held_than_picks", "two_held_at_the_end_of_the_range",
+         "more_held_than_picks", "all_held",
+         "live_rows_within_the_expected", "more_live_rows_than_expected"])
+def test_row_buffers_are_as_long_as_the_held_picks_can_be(held, top_k,
+                                                          expected_rows):
+    """``route`` keeps ``min(top_k, held)`` picks a token;
+    dispatch, experts and combine over them give what the dense masked sum
+    gives, and the gradients of tokens, router and experts are the dense
+    sum's: with the experts' work fixed at ``expected_rows`` too, whether the
+    live rows stay within them (to the bit of the unfixed) or not."""
+    rng = np.random.default_rng(7)
+    t, d, f, e = 64, 16, 12, 16
+    x, router = _w(rng, t, d, scale=1.0), _w(rng, d, e, scale=0.5)
+    start, count = held or (0, e)
+    w1, w2 = _w(rng, count, d, f), _w(rng, count, f, d)
+
+    def sparse(x, router, w1, w2, expected_rows=expected_rows):
+        routing = moe.route(x, router, top_k, renormalize=True,
+                            scoring="sigmoid", scaling=2.0, held=held)
+        rows = moe.expert_ffn(moe.dispatch(x, routing), w1, None, w2,
+                              routing.group_sizes, activation="relu2",
+                              expected_rows=expected_rows)
+        return moe.combine(rows, routing), routing
+
+    def dense(x, router, w1, w2):
+        scores = jax.nn.sigmoid(jnp.dot(x, router, precision="highest"))
+        _, picks = jax.lax.top_k(scores, top_k)
+        mask = jax.nn.one_hot(picks, e).sum(1)
+        weights = 2.0 * scores * mask / (scores * mask).sum(-1, keepdims=True)
+        return sum(weights[:, start + i, None]
+                   * (jnp.square(jax.nn.relu(x @ w1[i])) @ w2[i])
+                   for i in range(count))
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                        argnums=(0, 1, 2, 3))(x, router, w1, w2)
+
+    with jax.default_matmul_precision("highest"):
+        out, routing = sparse(x, router, w1, w2)
+        np.testing.assert_allclose(out, dense(x, router, w1, w2), atol=2e-5)
+        assert routing.experts.shape == (t, min(top_k, count))
+        assert routing.order.shape == (t * min(top_k, count),)
+        got, want = grads(lambda *a: sparse(*a)[0]), grads(dense)
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(g, r, atol=5e-5)
+        if expected_rows is None:
+            return
+        live = int(routing.group_sizes.sum())
+        assert (live <= expected_rows) == (expected_rows == 192)
+        free = grads(lambda *a: sparse(*a, expected_rows=None)[0])
+        for g, r in zip(got, free):
+            np.testing.assert_array_equal(g, r)
+
+
+# -- (g) the ungated experts --------------------------------------------------
+
+def test_ungated_expert_ffn_is_a_loop_over_the_groups():
+    rng = np.random.default_rng(8)
+    sizes = np.array([5, 0, 9, 2], np.int32)
+    rows, w1, w3, w2 = (_w(rng, 20, 8, scale=1.0), _w(rng, 4, 8, 6),
+                        _w(rng, 4, 8, 6), _w(rng, 4, 6, 8))
+    with jax.default_matmul_precision("highest"):
+        got = moe.expert_ffn(rows, w1, None, w2, jnp.asarray(sizes),
+                             activation="relu2")
+        gated = moe.expert_ffn(rows, w1, w3, w2, jnp.asarray(sizes))
+    edges = np.concatenate([[0], np.cumsum(sizes)])
+    for i in range(4):
+        mine = rows[edges[i]:edges[i + 1]]
+        np.testing.assert_allclose(
+            got[edges[i]:edges[i + 1]],
+            jnp.square(jax.nn.relu(mine @ w1[i])) @ w2[i], atol=1e-5)
+        np.testing.assert_allclose(
+            gated[edges[i]:edges[i + 1]],
+            (jax.nn.silu(mine @ w1[i]) * (mine @ w3[i])) @ w2[i], atol=1e-5)
+    with pytest.raises(ValueError, match="activation"):
+        moe.expert_ffn(rows, w1, w3, w2, jnp.asarray(sizes),
+                       activation="relu2")
+    with pytest.raises(ValueError, match="activation"):
+        moe.expert_ffn(rows, w1, None, w2, jnp.asarray(sizes),
+                       activation="gelu")
+
+
+# -- (f) the reference's own pieces -------------------------------------------
+
+def test_the_two_copies_of_the_reference_are_equal():
+    """``tests/nemotron_h_reference.py`` and the benchmark's own
+    ``benchmark/families/nemotron_h_reference.py``: the same text, and loaded
+    as two modules the same values to the last bit."""
+    with open(reference.__file__) as f, open(benchmark_copy.__file__) as g:
+        text = f.read()
+        assert text == g.read()
+    assert "ps_tpu" not in text.split('"""')[2]     # no import of the program
+    assert "cumsum" not in text.split('"""')[2]     # no cumulated sum
+    assert reference is not benchmark_copy
+    sizes, _, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
+    (loss, aux), grads = _plain(sizes, params, batch, bias, benchmark_copy)
+    assert float(loss) == float(ref_loss)
+    for name in ref_aux:
+        np.testing.assert_array_equal(np.asarray(aux[name]),
+                                      np.asarray(ref_aux[name]))
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(ref_grads)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_reference_in_blocks_as_in_one(monkeypatch):
+    """The reference's attention in blocks of query rows and its recurrence
+    in blocks of tokens (what lets 8,192 positions fit on the chip) are the
+    attention and the recurrence in one block."""
+    sizes, _, params, batch, bias, ((ref_loss, _), ref_grads) = _base()
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 32)
+    monkeypatch.setattr(reference, "TOKEN_BLOCK", 16)
+    (loss, _), grads = _plain(sizes, params, batch, bias)
+    assert abs(float(loss) - float(ref_loss)) <= 1e-6 * float(ref_loss)
+    _assert_grads_close(grads, ref_grads)
+
+
+def test_witness_grads_are_the_reference_gradients_of_those_leaves():
+    sizes, _, params, batch, bias, ((ref_loss, _), ref_grads) = _base()
+    assert set(nemotron_h_step.GRAD_COSINE) == {
+        "layer0/mamba/in_proj/kernel", "layer0/mamba/A_log",
+        "layer4/mamba/dt_bias", "layer9/attn/q/kernel",
+        "layer1/moe/latent_down/kernel", "layer5/moe/router/kernel",
+        "layer3/moe/w1", "layer7/moe/shared/w1/kernel"}
+    with jax.default_matmul_precision("highest"):
+        (loss, _), grads = jax.jit(lambda p: reference.witness_grads(
+            p, batch, bias, sizes, nemotron_h_step.GRAD_COSINE))(params)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    for name, g in grads.items():
+        want = functools.reduce(lambda t, part: t[part], name.split("/"),
+                                ref_grads)
+        np.testing.assert_allclose(g, want, atol=1e-6)
+
+
+# -- the family's pieces ------------------------------------------------------
+
+def _step0_inputs(fault=None):
+    """What ``nemotron_h_step.step0_checks`` reads, made by hand: two layers
+    of 512 experts, witnesses whose gradient is the reference's, AdamW applied
+    by the rule; ``fault`` spoils one thing."""
+    rng = np.random.default_rng(0)
+    rule = {"name": "adamw", "learning_rate": 4e-4, "b1": 0.9, "b2": 0.95,
+            "eps": 1e-8, "weight_decay": 0.1, "clip_by_global_norm": 1.0}
+    pairs, rate = 180224, 1e-3
+    counts = rng.multinomial(pairs, np.ones(512) / 512, size=2)
+    ref_counts = counts.copy()
+    if fault == "routed_elsewhere":
+        counts[0, 0] += 2000         # over FLIP_SHARE of the pairs
+        counts[0, 1:401] -= 5
+    if fault == "dropped":
+        counts[1, 5] -= 1
+        ref_counts[1, 5] -= 1
+    bias = nemotron_h_step.bias_by_sign_rule(counts, rate)
+    if fault == "bias":
+        bias[1, 7] += np.float32(1e-3)
+    got = {"expert_tokens": counts, "held_tokens": counts[:, :8],
+           "expert_bias": bias}
+    want = {"expert_tokens": ref_counts, "held_tokens": ref_counts[:, :8]}
+    witnesses = {}
+    scale = 0.5                      # the clip halved the gradient
+    for name in nemotron_h_step.GRAD_COSINE:
+        before = rng.normal(size=(16, 8)) * 0.02
+        ref_grad = rng.normal(size=(16, 8))
+        grad = ref_grad * scale
+        if fault == "direction" and name.endswith("A_log"):
+            grad = grad + 0.5 * scale * rng.normal(size=grad.shape)
+        if fault == "length" and name.endswith("router/kernel"):
+            grad = grad * 1.2
+        mu, nu = (1 - rule["b1"]) * grad, (1 - rule["b2"]) * grad ** 2
+        after = nemotron_h_step.adamw_first_step(before, mu, nu, **rule)
+        if fault == "apply" and name.endswith("attn/q/kernel"):
+            # the first moment applied without its bias correction
+            after = nemotron_h_step.adamw_first_step(
+                before, (1 - rule["b1"]) * mu, nu, **rule)
+        witnesses[name] = {"before": before, "after": after, "mu": mu,
+                           "nu": nu, "reference_grad": ref_grad}
+    clipped = 1.3 if fault == "clip" else 1.0
+    return got, want, witnesses, clipped, rule, pairs, rate
+
+
+STEP0_FAULTS = {None: None,
+                "routed_elsewhere": "expert_counts_match_reference",
+                "dropped": "no_dropped_tokens",
+                "bias": "expert_bias_follows_sign_rule",
+                "direction": "gradient_matches_reference",
+                "length": "gradient_matches_reference",
+                "apply": "adamw_apply_matches_rule",
+                "clip": "gradient_clipped_to_global_norm"}
+
+
+@pytest.mark.parametrize("fault", STEP0_FAULTS, ids=str)
+def test_step0_checks_name_the_fault(fault):
+    checks = nemotron_h_step.step0_checks(*_step0_inputs(fault))["checks"]
+    failed = {name for name, ok in checks.items() if not ok}
+    assert failed == ({STEP0_FAULTS[fault]} if fault else set())
+
+
+def _json(path):
+    with open(os.path.join(_REPO, path)) as f:
+        return json.load(f)
+
+
+def test_the_cell_is_what_issue_39_named():
+    """One configuration, one cell on one chip under the Kimi cell's traffic
+    with a later ``loss_step`` and nothing else changed (ISSUE 39's
+    ``s8192.b1.zipf.n<k>``: n = 48 spread over 0.75% in one set of four), the
+    seventeen ``nemo.*`` metrics and no other entry."""
+    manifest = _json("BENCHMARK.json")
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "nemotron-3-super-120b-a12b", "s8192.b1.zipf.n96", 1)
+    kimis, ours = (_json(f"benchmark/traffic/{name}.json") for name in (
+        "s8192.b1.zipf", "s8192.b1.zipf.n96"))
+    assert {k for k in kimis.keys() | ours.keys()
+            if kimis.get(k) != ours.get(k)} == {"loss_step", "loss_step_why"}
+    assert [w["name"] for w in manifest["workloads"]
+            if w["config"] == cell["config"]] == [CELL]
+    assert manifest["workloads"][-1] == cell
+    entry = manifest["configs"][-1]
+    assert entry["name"] == cell["config"] and entry["file"] == CONFIG
+    assert entry["source"] == ("https://huggingface.co/nvidia/NVIDIA-Nemotron"
+                               "-3-Super-120B-A12B-BF16/blob/main/config.json")
+    assert set(entry["reduced"]) == {
+        "num_hidden_layers", "hybrid_override_pattern", "mamba_num_heads",
+        "n_groups", "num_attention_heads", "num_key_value_heads",
+        "n_routed_experts", "vocab_size", "num_nextn_predict_layers"}
+    listed = [m for m in manifest["per_layer"]
+              if m["name"].startswith("nemo.")]
+    assert len(listed) == 17 and manifest["per_layer"][-17:] == listed
+    assert all(m["workloads"] == [CELL] for m in listed)
+    assert {m["name"] for m in listed} \
+        == set(nemo_metrics.SCOPE_METRICS.values()) | {
+            "nemo.ssd_roofline", "nemo.flash_roofline",
+            "nemo.expert_mxu_share", "nemo.mfu", "nemo.held_pair_share",
+            "nemo.load_max_over_mean", "nemo.dropped_tokens"}
+    assert {m["moves"] for m in listed} == {"throughput", "loss_at_n"}
+    four = [w for w in manifest["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(manifest["workloads"]) // 4)
+    assert ours["loss_step"] == 96
+
+
+def test_configuration_holds_the_published_widths():
+    """Every key of the catalog's ``config`` as published; the cuts and only
+    the cuts differ; 700,862,960 parameters in the store."""
+    config = _json(CONFIG)
+    published = {
+        "attention_bias": False, "chunk_size": 128, "conv_kernel": 4,
+        "expand": 2, "head_dim": 128, "hidden_size": 4096,
+        "intermediate_size": 2688, "layer_norm_epsilon": 1e-05,
+        "mamba_head_dim": 64, "mamba_hidden_act": "silu",
+        "mamba_proj_bias": False, "max_position_embeddings": 262144,
+        "mlp_bias": False, "mlp_hidden_act": "relu2",
+        "model_type": "nemotron_h", "moe_intermediate_size": 2688,
+        "moe_latent_size": 1024, "moe_shared_expert_intermediate_size": 5376,
+        "moe_shared_expert_overlap": False,
+        "mtp_hybrid_override_pattern": "*E", "n_group": 1,
+        "n_shared_experts": 1, "norm_eps": 1e-05, "norm_topk_prob": True,
+        "num_experts_per_tok": 22, "num_logits_to_keep": 1,
+        "partial_rotary_factor": 1, "rescale_prenorm_residual": True,
+        "residual_in_fp32": False, "rope_theta": 10000,
+        "routed_scaling_factor": 5, "sliding_window": None,
+        "ssm_state_size": 128, "tie_word_embeddings": False,
+        "time_step_floor": 0.0001, "time_step_max": 0.1,
+        "time_step_min": 0.001, "topk_group": 1, "use_bias": False,
+        "use_conv_bias": True, "use_mamba_kernels": True}
+    assert {k: config[k] for k in published} == published
+    cut = {"num_hidden_layers": (88, 11),
+           "mamba_num_heads": (128, 16), "n_groups": (8, 1),
+           "num_attention_heads": (32, 4), "num_key_value_heads": (2, 1),
+           "n_routed_experts": (512, 8), "vocab_size": (131072, 16384),
+           "num_nextn_predict_layers": (1, 0)}
+    was = config["published"]
+    assert {k: (was[k], config[k]) for k in cut} == cut
+    assert set(was) == set(cut) | {"hybrid_override_pattern"} == set(
+        _json("BENCHMARK.json")["configs"][-1]["reduced"])
+    # one whole period, published layers 28-38 counted from 1
+    pattern = was["hybrid_override_pattern"]
+    assert len(pattern) == 88 and (pattern.count("M"), pattern.count("E"),
+                                   pattern.count("*")) == (40, 40, 8)
+    assert config["hybrid_override_pattern"] == pattern[27:38] \
+        == "MEMEMEMEM*E"
+    assert (config["router_width"], config["expert_start"],
+            config["mamba_head_start"], config["attention_head_start"],
+            config["rescale_depth"]) == (512, 0, 0, 0, 88)
+    assert len(config["reduced"]) == 7 and len(config["assumed"]) >= 9
+    assert "64 chips share each layer" in config["deployment"]
+    cfg = nemotron_h.NemotronHConfig.from_dict(config)
+    assert (cfg.mamba_inner, cfg.conv_dim, cfg.held,
+            cfg.num_expert_layers) == (1024, 1280, (0, 8), 5)
+    shapes = jax.eval_shape(lambda k: nemotron_h.init_params(k, cfg),
+                            jax.random.key(0))
+
+    def count(tree):
+        return sum(int(np.prod(x.shape))
+                   for x in jax.tree_util.tree_leaves(tree))
+
+    bias = int(np.prod(nemotron_h.init_expert_bias(cfg).shape))
+    assert (count(shapes), bias) == (700_862_960, 2_560)
+    assert {kind: count(shapes[layer]) for layer, kind in (
+        ("layer0", "M"), ("layer9", "*"), ("layer1", "E"))} == {
+            "M": 13_708_592, "*": 5_246_976, "E": 98_570_240}
+    assert count({k: shapes[k] for k in ("embed", "head", "final_norm")}) \
+        == 134_221_824
+    assert shapes["layer0"]["mamba"]["in_proj"]["kernel"].shape == (4096,
+                                                                    2320)
+    # the operations from shapes, at the cell's sizes: ISSUE 39's arithmetic
+    tokens = 8192
+    live = 5 * tokens * 22 / 64
+    flops = nemotron_h_step.step_flops(config, tokens, 8192, live)
+    assert flops == pytest.approx(21.0e12, rel=0.01)
+    assert nemotron_h_step.pair_flops(config) == 12 * 1024 * 2688
+    scan_flops, scan_bytes = nemotron_h_step.ssd_cost(1, 8192, 16, 64, 1, 128,
+                                                      128, 5)
+    per_chunk = 128 * 128 * 128 + 16 * (128 * 128 * 64 + 4 * 128 * 64 * 128)
+    assert scan_flops == 3 * per_chunk * 5 * 64
+    moved = (16 * 64 + 2 * 128) * 2 + 4 * 16
+    assert scan_bytes == 5 * 8192 * (3 * moved + 2 * 16 * 64 * 2)
+    kernel_flops, _ = nemotron_h_step.flash_cost(1, 4, 8192, 128, 128, 1)
+    assert kernel_flops == 4 * 8192 * 8192 * 9 * 128
+
+
+@pytest.mark.parametrize("change", [
+    {"n_group": 8}, {"topk_group": 4}, {"mlp_hidden_act": "silu"},
+    {"mamba_hidden_act": "gelu"}, {"tie_word_embeddings": True},
+    {"num_nextn_predict_layers": 1}, {"attention_bias": True},
+    {"mamba_proj_bias": True}, {"mlp_bias": True}, {"use_bias": True},
+    {"use_conv_bias": False}, {"n_shared_experts": 2},
+    {"hybrid_override_pattern": "MEMEMEMEM*"},
+    {"hybrid_override_pattern": "MEMEMEMEM*-"},
+    {"n_groups": 3}, {"num_key_value_heads": 3}],
+    ids=lambda c: "{}={}".format(*next(iter(c.items()))))
+def test_config_refuses_what_the_model_does_not_compute(change):
+    with pytest.raises(ValueError):
+        nemotron_h.NemotronHConfig.from_dict({**SIZES, **change})
+
+
+def test_family_refuses_a_pool_it_would_have_to_cycle():
+    config = _json(CONFIG)
+    traffic = _json("benchmark/traffic/s8192.b1.zipf.n96.json")
+    with pytest.raises(ValueError, match="re-uses no batch"):
+        nemotron_h_step.build(config, {**traffic, "pool": 16}, 1, 0)
+
+
+def test_benchmark_command_rehearses_the_cell():
+    """The benchmark's own command on the CPU: the cell's control flow at
+    the tiny sizes, ``correct`` with every step-0 check, all seventeen
+    ``nemo.*`` metrics listed and none of another configuration's."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
+                        "JAX_COMPILATION_CACHE_DIR")}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "benchmark", "run.py"),
+         "--workload", CELL, "--rehearse", "--trace", "1", "--seconds", "1"],
+        env=env, cwd=_REPO, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["metrics"] == {}
+    listed = {m["name"] for m in _json("BENCHMARK.json")["per_layer"]
+              if m["name"].startswith("nemo.")}
+    assert len(listed) == 17 and listed <= set(line["rehearsed"])
+    assert not {n for n in line["rehearsed"]
+                if n.split(".")[0] in ("kimi", "lfm2", "moe")}

@@ -24,7 +24,8 @@ import pytest
 
 import ps_tpu as ps
 from benchmark.layer_metrics import (host, kimi as kimi_metrics,
-                                     lfm2 as lfm2_metrics, moe, scope)
+                                     lfm2 as lfm2_metrics, moe,
+                                     nemo as nemo_metrics, scope)
 from benchmark.layer_metrics import setup as setup_metrics
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
@@ -450,6 +451,131 @@ def test_kimi_reader_on_a_hand_made_result(monkeypatch):
     r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
     assert kimi_metrics.scope_times(r, {}) == {}
     assert kimi_metrics.read({"counters": {}, "facts": {}}) == {}
+
+
+def _nemotron_step():
+    """``(run, batch)`` of ``make_step(has_aux=True)`` on a tiny Nemotron-H:
+    a Mamba-2 layer, an expert layer with two of eight held in a latent, an
+    attention layer."""
+    from ps_tpu.models import nemotron_h
+
+    cfg = nemotron_h.NemotronHConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=3,
+        hybrid_override_pattern="ME*", mamba_num_heads=2, mamba_head_dim=8,
+        n_groups=1, ssm_state_size=8, chunk_size=32, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=16, router_width=8,
+        n_routed_experts=2, expert_start=2, num_experts_per_tok=3,
+        moe_latent_size=16, moe_intermediate_size=12,
+        moe_shared_expert_intermediate_size=24, dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: nemotron_h.init_params(k, cfg))(
+        jax.random.key(0)))
+    # 64 tokens: two chunks of the scan
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    step = store.make_step(nemotron_h.make_loss_fn(cfg), has_aux=True)
+    bias = nemotron_h.init_expert_bias(cfg)
+    return (lambda batch: step(batch, bias),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_nemotron_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Nemotron-H adds to the scopes (``ps.mamba``, ``ps.mamba/conv``,
+    ``ps.mamba/ssd``, ``ps.moe/latent``) beside the six it shares with OLMoE
+    and Kimi-Linear's ``ps.moe/shared``: each in the lowered step's
+    ``op_name``s under ``ps.grad``, forward and backward, though every layer
+    is under a ``jax.checkpoint``; the reader's copy is equal."""
+    assert phases.NEMOTRON_SCOPES == nemo_metrics.NEMOTRON_SCOPES
+    assert phases.NEMOTRON_SCOPES[:6] == phases.MOE_SCOPES
+    for name in ("MAMBA", "MAMBA_CONV", "MAMBA_SSD", "MOE_LATENT",
+                 "MOE_SHARED", "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT",
+                 "MOE_COMBINE", "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(nemo_metrics, name)
+    assert not set(phases.NEMOTRON_SCOPES) & set(phases.DEVICE_PHASES)
+    assert set(nemo_metrics.SCOPE_METRICS) == set(phases.NEMOTRON_SCOPES)
+    monkeypatch.setitem(BUILDERS, "nemotron", _nemotron_step)
+    names = scope.op_names_of(_step_hlo("nemotron"))
+    for s in phases.NEMOTRON_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {nemo_metrics.scope_of(own, n) for own, n in names.items()}
+    assert found == set(phases.NEMOTRON_SCOPES) | {None}
+    # the scan and the filter are the innermost scopes of their ops, and the
+    # mixer's holds them; the latent projections are not the routed experts'
+    for inner in (nemo_metrics.MAMBA_SSD, nemo_metrics.MAMBA_CONV):
+        assert nemo_metrics.scope_of(
+            "%fusion.1",
+            f"jit(f)/ps.grad/jvp()/checkpoint/ps.mamba/{inner}/mul") == inner
+    assert nemo_metrics.scope_of(
+        "%fusion.2", "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/latent/dot") \
+        == nemo_metrics.MOE_LATENT
+    assert nemo_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
+        == nemo_metrics.MOE_EXPERT
+
+
+def test_nemo_reader_on_a_hand_made_result(monkeypatch):
+    call = 'custom_call_target="tpu_custom_call"'
+    ops = {_ev("%in_proj"): 0.004, _ev("%taps"): 0.002, _ev("%scan"): 0.010,
+           _ev("%down"): 0.003, _ev("%shared"): 0.002,
+           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
+           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
+           _ev("%flash", "custom-call") + call: 0.010,
+           _ev("%qkv"): 0.004, _ev("%ce"): 0.005, _ev("%embed"): 0.001,
+           _ev("%adam"): 0.007}
+    names = {"%in_proj": "jit(f)/ps.grad/jvp()/checkpoint/ps.mamba/dot",
+             "%taps": "jit(f)/ps.grad/jvp()/checkpoint/ps.mamba/"
+                      "ps.mamba/conv/mul",
+             "%scan": "jit(f)/ps.grad/transpose(jvp())/checkpoint/ps.mamba/"
+                      "ps.mamba/ssd/while/body/dot_general",
+             "%down": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/latent/dot",
+             "%shared": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/shared/dot",
+             "%route": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/route/dot",
+             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
+             "%back": "jit(f)/ps.grad/transpose(jvp())/ps.moe/combine/gather",
+             "%ragged-dot-none.1": "ragged-dot-none",
+             "%flash": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/pallas_call",
+             "%qkv": "jit(f)/ps.grad/transpose(jvp())/ps.attn/dot_general",
+             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
+             "%embed": "jit(f)/ps.grad/jvp()/gather",
+             "%adam": "jit(f)/ps.apply/mul"}
+    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
+         "counters": {"nemo_live_pairs_per_step": 1000.0,
+                      "nemo_held_pair_share": 0.015625,
+                      "nemo_load_max_over_mean": 3.0,
+                      "nemo_dropped_tokens": 0.0},
+         "facts": {"kernel_targets": ["tpu_custom_call"],
+                   "nemo_flops_per_pair": 1e6,
+                   "nemo_dense_flops_per_step": 4e9,
+                   "nemo_ssd_flops": 1.0, "nemo_ssd_bytes": 1e9,
+                   "nemo_flash_flops": 2e9, "nemo_flash_bytes": 1.0},
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
+         "steps": 10, "window_s": 1.0}
+    out = nemo_metrics.scope_times(r, names)
+    assert out["nemo.mamba_ms"] == pytest.approx(8.0)   # with filter and scan
+    assert out["nemo.mamba_conv_ms"] == pytest.approx(1.0)
+    assert out["nemo.ssd_ms"] == pytest.approx(5.0)
+    assert out["nemo.latent_ms"] == pytest.approx(1.5)
+    assert out["nemo.shared_ffn_ms"] == pytest.approx(1.0)
+    assert out["nemo.route_ms"] == pytest.approx(0.5)
+    assert out["nemo.dispatch_ms"] == pytest.approx(2.0)    # with combine
+    assert out["nemo.expert_ms"] == pytest.approx(4.0)
+    assert out["nemo.attn_ms"] == pytest.approx(7.0)
+    assert out["nemo.head_ms"] == pytest.approx(2.5)
+    assert out["nemo.ssd_roofline"] == pytest.approx(20.0)       # 1 of 5 ms
+    assert out["nemo.expert_mxu_share"] == pytest.approx(25.0)   # 1 of 4 ms
+    assert out["nemo.flash_roofline"] == pytest.approx(40.0)     # 2 of 5 ms
+    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
+    whole = nemo_metrics.read(r)
+    assert whole["nemo.mfu"] == pytest.approx(5.0)   # 5e9 x 10 / s of 1e12
+    assert whole["nemo.held_pair_share"] == 0.015625
+    assert len([k for k in whole if k.startswith("nemo.")]) == 17
+    # a program without the scopes, the counters or the grouped matmuls
+    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
+    assert nemo_metrics.scope_times(r, {}) == {}
+    assert nemo_metrics.read({"counters": {}, "facts": {}}) == {}
 
 
 def test_moe_reader_on_a_hand_made_result():

@@ -269,3 +269,22 @@ def test_kimi_grad_check_rehearses():
     cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
     assert len(cosines) == 6 and max(cosines) < 0.999
     assert found["loss_rel_diff"] > 5e-5
+
+
+def test_nemotron_grad_check_rehearses():
+    """tools/nemotron_grad_check.py at the configuration's tiny sizes: the
+    system's gradients are the reference's, and the reference on 8-bit
+    weights turns every witness's gradient."""
+    out = _run("nemotron_grad_check.py", "--rehearse", "--table")
+    assert out["worst"]["cosine"] > 1 - 1e-9
+    assert out["loss"]["rel_diff"] < 1e-5
+    assert out["pairs_on_another_expert"] == [0, 0, 0, 0, 0]
+    found = out["reference_on_e4m3_weights"]
+    cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
+    assert len(cosines) == 8 and max(cosines) < 0.999
+    assert sum(found["pairs_on_another_expert"]) > 0
+    # the fault the limit on the lengths is there for, and only it
+    from benchmark.families.nemotron_h_step import GRAD_NORM_TOLERANCE as limit
+    for fault in ("picks_not_scaled", "picks_not_renormalised"):
+        assert out[f"reference_with_{fault}"]["lengths_apart"] > 2 * limit
+
