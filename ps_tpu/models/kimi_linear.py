@@ -310,9 +310,7 @@ def moe_block(lp: Dict, x, config: KimiLinearConfig, bias):
             tokens, lp["router"]["kernel"], c.num_experts_per_token,
             renormalize=c.moe_renormalize, scoring="sigmoid", bias=bias,
             renorm_eps=1e-20, scaling=c.routed_scaling_factor, held=c.held)
-    out = _experts_of(
-        tokens, *(lp[n].astype(x.dtype) for n in ("gate", "up", "down")),
-        routing)
+    out = _experts_of(tokens, lp["gate"], lp["up"], lp["down"], routing)
     with jax.named_scope(phases.MOE_SHARED):
         out = out + dense_ffn(lp["shared"], tokens)
     return out.reshape(b, s, d), routing
@@ -360,7 +358,9 @@ def make_loss_fn(config: KimiLinearConfig, attn: str = "full", **attn_kw):
     'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers, num_experts],
-    those computed here; ``expert_bias``, the bias for the next step."""
+    those computed here; ``expert_windows`` [expert layers], the windows of
+    rows each layer ran (1 unless its held pairs overflowed the first);
+    ``expert_bias``, the bias for the next step."""
     attn_fn = make_attn_fn(attn, **attn_kw)
 
     def loss_fn(params, batch, expert_bias):
@@ -372,9 +372,10 @@ def make_loss_fn(config: KimiLinearConfig, attn: str = "full", **attn_kw):
         with jax.named_scope(phases.MOE_ROUTE):
             counts = jnp.stack([r.counts for r in routings])
             held = jnp.stack([r.group_sizes for r in routings])
+            windows = jnp.stack([moe.live_windows(r) for r in routings])
             new_bias = moe.balance_bias(expert_bias, counts,
                                         config.bias_update_rate)
         return ce, {"ce": ce, "expert_tokens": counts, "held_tokens": held,
-                    "expert_bias": new_bias}
+                    "expert_windows": windows, "expert_bias": new_bias}
 
     return loss_fn

@@ -210,16 +210,25 @@ def dense_ffn(lp: Dict, x):
     return (jax.nn.silu(x @ w("w1")) * (x @ w("w3"))) @ w("w2")
 
 
-@jax.checkpoint
-def _experts_of(tokens, gate, up, down, routing: moe.Routing):
-    """Dispatch, the held experts and combine, recomputed in the backward
-    pass: between two layers only ``tokens`` and ``routing`` live on."""
+def _window_of(routing: moe.Routing, tokens, gate, up, down):
     with jax.named_scope(phases.MOE_DISPATCH):
         rows = moe.dispatch(tokens, routing)
     with jax.named_scope(phases.MOE_EXPERT):
         rows = moe.expert_ffn(rows, gate, up, down, routing.group_sizes)
     with jax.named_scope(phases.MOE_COMBINE):
         return moe.combine(rows, routing)
+
+
+@jax.checkpoint
+def _experts_of(tokens, gate, up, down, routing: moe.Routing):
+    """Dispatch, the held experts and combine over the windows of the held
+    pairs, recomputed in the backward pass: between two layers only
+    ``tokens`` and ``routing`` live on. The stacks are cast to the tokens'
+    precision in here, so their copies are made again for the backward and
+    not kept from the forward (twelve of 38 MB in a Kimi step's peak)."""
+    return moe.over_windows(
+        _window_of, routing, tokens,
+        *(stack.astype(tokens.dtype) for stack in (gate, up, down)))
 
 
 def moe_block(lp: Dict, x, config: Lfm2Config, bias):
@@ -234,9 +243,7 @@ def moe_block(lp: Dict, x, config: Lfm2Config, bias):
             tokens, lp["router"]["kernel"], c.num_experts_per_tok,
             renormalize=c.norm_topk_prob, scoring="sigmoid", bias=bias,
             renorm_eps=1e-6, scaling=c.routed_scaling_factor, held=c.held)
-    out = _experts_of(
-        tokens, *(lp[n].astype(x.dtype) for n in ("gate", "up", "down")),
-        routing)
+    out = _experts_of(tokens, lp["gate"], lp["up"], lp["down"], routing)
     return out.reshape(b, s, d), routing
 
 
@@ -284,7 +291,9 @@ def make_loss_fn(config: Lfm2Config, attn: str = "full", **attn_kw):
     'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers, num_experts],
-    those computed here; ``expert_bias``, the bias for the next step."""
+    those computed here; ``expert_windows`` [expert layers], the windows of
+    rows each layer ran (1 unless its held pairs overflowed the first);
+    ``expert_bias``, the bias for the next step."""
     attn_fn = make_attn_fn(attn, **attn_kw)
 
     def loss_fn(params, batch, expert_bias):
@@ -296,11 +305,12 @@ def make_loss_fn(config: Lfm2Config, attn: str = "full", **attn_kw):
         with jax.named_scope(phases.MOE_ROUTE):
             counts = jnp.stack([r.counts for r in routings])
             held = jnp.stack([r.group_sizes for r in routings])
+            windows = jnp.stack([moe.live_windows(r) for r in routings])
             new_bias = expert_bias
             if config.use_expert_bias:
                 new_bias = moe.balance_bias(expert_bias, counts,
                                             config.bias_update_rate)
         return ce, {"ce": ce, "expert_tokens": counts, "held_tokens": held,
-                    "expert_bias": new_bias}
+                    "expert_windows": windows, "expert_bias": new_bias}
 
     return loss_fn

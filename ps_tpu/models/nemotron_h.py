@@ -40,17 +40,18 @@ are computed here:
   from the model-wide activations, the top ``num_experts_per_tok`` of ``score
   + expert_bias[layer]`` (the bias selects only), weights renormalised over all
   picks (+ 1e-20) times ``routed_scaling_factor``; the tokens projected to the
-  latent, dispatched, through the held experts' ``relu(z W1)**2 W2`` as grouped
-  matmuls over a fixed count of rows (``HELD_ROWS_OVER_EVEN``), combined,
-  projected back; **plus the shared expert** at the model's width, whole on
-  every chip.
+  latent; a window of the sorted pairs at a time (``ops/moe.py::over_windows``:
+  three times an even load, 8,704 rows in the cell) dispatched, through the
+  held experts' ``relu(z W1)**2 W2`` as grouped matmuls that do the whole
+  window's work whatever is live, combined; projected back; **plus the
+  shared expert** at the model's width, whole on every chip.
 - a final RMSNorm and an untied head.
 
 Every layer runs under one ``jax.checkpoint``: between layers only the
 residual stream lives on (8 KB a token a layer in bf16), and a layer's
-working set (an expert layer's 22 picks a token, its row buffers at 2,688
-wide, the shared expert's 5,376) exists once, while that layer's gradient is
-computed.
+working set (an expert layer's 22 picks a token, a window's row buffers at
+2,688 wide, the shared expert's 5,376) exists once, while that layer's
+gradient is computed.
 
 What the model does not compute, ``NemotronHConfig.from_dict`` refuses.
 
@@ -83,15 +84,6 @@ from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.gated_conv import conv_silu
 from ps_tpu.ops.ssd import ssd
-
-
-#: The held experts' grouped matmuls do at least this many times the rows an
-#: even load brings them (tokens x picks x held / router_width). Their time
-#: follows the live rows, so without it a step is as fast as this chip's
-#: experts are unpopular at the seed: 338.1 to 343.5 ms over fourteen seeds
-#: whose layers held 0.54 to 2.19 times an even share of the pairs, 356.43 to
-#: 356.63 ms over twelve with it (my chip runs, PR 39).
-HELD_ROWS_OVER_EVEN = 3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,15 +142,6 @@ class NemotronHConfig:
     @property
     def held(self) -> Tuple[int, int]:
         return self.expert_start, self.n_routed_experts
-
-    def expected_rows(self, tokens: int) -> int:
-        """The rows the held experts' grouped matmuls do at the least
-        (``ops/moe.py::expert_ffn``'s ``expected_rows``):
-        ``HELD_ROWS_OVER_EVEN`` times the even load of ``tokens``, in whole
-        tiles of the grouped matmul's 512 rows."""
-        even = tokens * self.num_experts_per_tok * self.n_routed_experts \
-            / self.router_width
-        return 512 * math.ceil(HELD_ROWS_OVER_EVEN * even / 512)
 
     @classmethod
     def from_dict(cls, d: Dict) -> "NemotronHConfig":
@@ -322,6 +305,17 @@ def relu2_ffn(lp: Dict, x):
     return hidden @ lp["w2"]["kernel"].astype(x.dtype)
 
 
+def _window_of(routing: moe.Routing, latent, w1, w2):
+    with jax.named_scope(phases.MOE_DISPATCH):
+        rows = moe.dispatch(latent, routing)
+    with jax.named_scope(phases.MOE_EXPERT):
+        # the grouped matmuls do the whole window's work, whatever is live
+        rows = moe.expert_ffn(rows, w1, None, w2, routing.group_sizes,
+                              activation="relu2", expected_rows=rows.shape[0])
+    with jax.named_scope(phases.MOE_COMBINE):
+        return moe.combine(rows, routing)
+
+
 def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
     """The latent expert layer on normed activations ``x`` [B, S, D] with the
     layer's selection ``bias`` [router_width] or None: the held experts' part
@@ -337,15 +331,9 @@ def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
             renorm_eps=1e-20, scaling=c.routed_scaling_factor, held=c.held)
     with jax.named_scope(phases.MOE_LATENT):
         latent = tokens @ lp["latent_down"]["kernel"].astype(x.dtype)
-    with jax.named_scope(phases.MOE_DISPATCH):
-        rows = moe.dispatch(latent, routing)
-    with jax.named_scope(phases.MOE_EXPERT):
-        rows = moe.expert_ffn(rows, lp["w1"].astype(x.dtype), None,
-                              lp["w2"].astype(x.dtype), routing.group_sizes,
-                              activation="relu2",
-                              expected_rows=c.expected_rows(b * s))
-    with jax.named_scope(phases.MOE_COMBINE):
-        latent = moe.combine(rows, routing)
+    latent = moe.over_windows(_window_of, routing, latent,
+                              lp["w1"].astype(x.dtype),
+                              lp["w2"].astype(x.dtype))
     with jax.named_scope(phases.MOE_LATENT):
         out = latent @ lp["latent_up"]["kernel"].astype(x.dtype)
     with jax.named_scope(phases.MOE_SHARED):
@@ -358,39 +346,41 @@ def _layer(lp: Dict, x, bias, kind: str, config: NemotronHConfig,
            attn_fn: Callable, grouped: bool):
     """One layer, ``x + f(rms_norm(x))``, recomputed in the backward pass:
     the stream out and, of an expert layer, its counts over all experts and
-    over the held ones (None of the others)."""
+    over the held ones and the windows of rows it ran (None of the
+    others)."""
     h = rms_norm(x, lp["norm"]["scale"], config.layer_norm_epsilon)
     if kind == "M":
         with jax.named_scope(phases.MAMBA):
-            return x + mamba_block(lp["mamba"], h, config), None, None
+            return x + mamba_block(lp["mamba"], h, config), None, None, None
     if kind == "*":
         with jax.named_scope(phases.ATTN):
             return x + attention_block(lp["attn"], h, config, attn_fn,
-                                       grouped), None, None
+                                       grouped), None, None, None
     out, routing = moe_block(lp["moe"], h, config, bias)
-    return x + out, routing.counts, routing.group_sizes
+    return (x + out, routing.counts, routing.group_sizes,
+            moe.live_windows(routing))
 
 
 def apply(params: Dict, tokens, config: NemotronHConfig, expert_bias=None,
           attn_fn: Callable = None, grouped: bool = False):
     """``tokens`` [B, S] int32 -> (final hidden states [B, S, D] before the
     final norm, each expert layer's pairs per expert over all of them
-    [expert layers, router_width], and over the held ones [expert layers,
-    n_routed_experts])."""
+    [expert layers, router_width], over the held ones [expert layers,
+    n_routed_experts], and the windows of rows it ran [expert layers])."""
     c = config
     attn_fn = attn_fn or make_attn_fn("full")
     x = jnp.take(params["embed"]["tokens"], tokens, axis=0).astype(c.dtype)
-    counts, held = [], []
+    counts, held, windows = [], [], []
     for i, kind in enumerate(c.hybrid_override_pattern):
         bias = None
         if kind == "E" and expert_bias is not None:
             bias = expert_bias[len(counts)]
-        x, layer_counts, layer_held = _layer(params[f"layer{i}"], x, bias,
-                                             kind, c, attn_fn, grouped)
+        x, *of_experts = _layer(params[f"layer{i}"], x, bias, kind, c,
+                                attn_fn, grouped)
         if kind == "E":
-            counts.append(layer_counts)
-            held.append(layer_held)
-    return x, jnp.stack(counts), jnp.stack(held)
+            for seen, one in zip((counts, held, windows), of_experts):
+                seen.append(one)
+    return x, jnp.stack(counts), jnp.stack(held), jnp.stack(windows)
 
 
 def logits_of(params: Dict, hidden, config: NemotronHConfig):
@@ -407,14 +397,15 @@ def make_loss_fn(config: NemotronHConfig, attn: str = "full", **attn_kw):
     'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers,
-    n_routed_experts], those computed here; ``expert_bias``, the bias for the
-    next step."""
+    n_routed_experts], those computed here; ``expert_windows`` [expert
+    layers], the windows of rows each layer ran (1 unless its held pairs
+    overflowed the first); ``expert_bias``, the bias for the next step."""
     attn_fn = make_attn_fn(attn, **attn_kw)
 
     def loss_fn(params, batch, expert_bias):
-        hidden, counts, held = apply(params, batch["inputs"], config,
-                                     expert_bias, attn_fn,
-                                     grouped=attn == "flash")
+        hidden, counts, held, windows = apply(
+            params, batch["inputs"], config, expert_bias, attn_fn,
+            grouped=attn == "flash")
         with jax.named_scope(phases.HEAD):
             ce = token_ce(logits_of(params, hidden, config),
                           batch["targets"])
@@ -422,6 +413,6 @@ def make_loss_fn(config: NemotronHConfig, attn: str = "full", **attn_kw):
             new_bias = moe.balance_bias(expert_bias, counts,
                                         config.bias_update_rate)
         return ce, {"ce": ce, "expert_tokens": counts, "held_tokens": held,
-                    "expert_bias": new_bias}
+                    "expert_windows": windows, "expert_bias": new_bias}
 
     return loss_fn

@@ -28,26 +28,50 @@ router keeps its published width and every token its ``top_k`` picks over all
 order, the grouped matmuls run over ``[count, D, F]`` stacks with the held
 experts' group sizes, and ``combine`` gives every absent pair the weight 0:
 the layer's output is the part of the whole layer's that its own experts
-give, and the shares of all chips add up to it. Dropless still. The row
-buffers keep their worst-case length: a token's picks are distinct experts,
-so no more than ``count`` of them are held, and where that is fewer than
-``top_k`` (22 picks on 8 held experts) ``route`` keeps each token's held
-picks and as many absent ones as fill ``count`` places. Everything after it
-sees a routing of ``min(top_k, count)`` picks a token and buffers of ``T *
-min(top_k, count)`` rows. What the grouped matmul leaves in the rows past the
-last group is unspecified, so those rows are masked with ``where`` (never by
-a product: 0 x NaN) where they enter (``dispatch``, which also zeroes the
-cotangent on its way back to the tokens) and where they leave (``combine``).
+give, and the shares of all chips add up to it. A token's picks are distinct
+experts, so no more than ``count`` of them are held, and where that is fewer
+than ``top_k`` (22 picks on 8 held experts) ``route`` keeps each token's held
+picks and as many absent ones as fill ``count`` places: everything after it
+sees a routing of ``k = min(top_k, count)`` picks a token and ``T * k`` sorted
+pairs, of which the live ones, the held experts' groups, come first.
+
+**A share moves a window of rows, not the pairs it could hold.** Of the
+``T * k`` pairs an even load leaves a share ``T * top_k * count / E`` live
+(an 8th, a 32nd, a 64th in the three cells that hold one), so ``dispatch``,
+the experts and ``combine`` work on ``R = window_rows(..)`` rows of the
+sorted pairs at a time: ``HELD_ROWS_OVER_EVEN`` times the even load in whole
+tiles of the grouped matmul, a constant of the shapes and of nothing the
+step can observe (24,576 / 6,144 / 8,704 of 65,536 in the LFM2, Kimi and
+Nemotron cells). ``over_windows`` runs the first window always, so a step's
+time does not follow how popular the held experts are at the seed, and the
+windows behind it in a ``while_loop`` that is entered only when the live rows
+overflow the first (the group sizes clipped to each window, the partial
+outputs added): dropless still, by the same path, and no capacity factor.
+Its ``custom_vjp`` computes a window again for its gradient, so a loop's
+trips keep no residual. With every expert held the window is all the pairs
+and ``over_windows`` is one call of the layer.
+
+Inside a window, the row side (``dispatch``, and ``combine``'s cotangent)
+gathers ``R`` rows by token and zeroes those past the live count with
+``where`` (never by a product: 0 x NaN; what the grouped matmul leaves past
+its last group is unspecified). The token side (``combine``, and
+``dispatch``'s cotangent: "sum a token's live rows") gathers the ``R`` rows
+into pair order, where a token's rows are at most ``k`` neighbours, sums each
+run on the MXU (``_sum_rows``: a block of 128 rows times the block's 0 /
+weight matrix, bf16 rows and weights, f32 accumulation) and gathers the
+runs' first rows by token: ``R + T`` rows moved, no scatter. Timed alone on
+the v5e at the three cells' shapes (PR 40): 1.55 / 0.32 / 0.26 ms against
+3.06 / 1.50 / 0.47 for a sorted scatter-add of the same rows and 3.74 / 3.28
+/ 1.53 for the gather of all ``T * k`` with its masked weighted sum.
 
 **The grouped matmuls' time follows the live rows**, not the buffer: on the
 v5e 0.5-0.6 us a row over ``relu2`` experts of 1,024 x 2,688, forward and
-backward (PR 39, fourteen seeds: the step's time against the held pairs), so
-a share's step is as fast as its experts are unpopular at the seed.
+backward (PR 39, fourteen seeds: the step's time against the held pairs).
 ``expert_ffn(..., expected_rows=R)`` makes it the same for every load up to
 ``R``: the last expert's group takes the zero rows after the live ones up to
-``R`` (zeros in, zeros out, a zero gradient: no value changes by a bit).
-With more live rows than ``R`` nothing is added and the time follows them
-again. Dropless as ever: the buffers keep their length.
+``R`` (zeros in, zeros out, a zero gradient: no value changes by a bit). With
+``R`` the window's length (Nemotron) the grouped matmuls do the whole
+window's work whatever is live.
 
 The rows need not be as wide as the router's input: ``route`` reads the
 tokens the router was trained on, ``dispatch`` and ``combine`` move whatever
@@ -58,6 +82,7 @@ tokens' latent projection).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -81,6 +106,29 @@ class Routing(NamedTuple):
     counts: jax.Array = None
     #: [T, k] bool, the pair's expert is held; None where every expert is
     live: jax.Array = None
+    #: [R] int32, the rows of ``order`` that ``dispatch`` moves and ``combine``
+    #: brings back: 0 .. R-1 as ``route`` returns it, ``R = window_rows(..)``;
+    #: None where every expert is held
+    window: jax.Array = None
+
+
+#: A share's expert layer moves this many times the rows an even load brings
+#: it (tokens x picks x held / router width) at a time. PR 39 chose it from
+#: fourteen seeds of the Nemotron cell, whose layers held 0.34 to 3.4 times an
+#: even share of the pairs.
+HELD_ROWS_OVER_EVEN = 3
+#: the rows of one tile of XLA:TPU's grouped matmul
+GROUPED_MATMUL_ROWS = 512
+
+
+def window_rows(tokens: int, top_k: int, held: int, num_experts: int) -> int:
+    """``R``, the rows a layer that holds ``held`` of ``num_experts`` experts
+    moves at a time: ``HELD_ROWS_OVER_EVEN`` times the even load in whole
+    tiles of the grouped matmul, and never more than the ``tokens x min(top_k,
+    held)`` pairs there are."""
+    even = tokens * top_k * held / num_experts
+    tiles = math.ceil(HELD_ROWS_OVER_EVEN * even / GROUPED_MATMUL_ROWS)
+    return min(tokens * min(top_k, held), GROUPED_MATMUL_ROWS * tiles)
 
 
 @jax.custom_vjp
@@ -116,7 +164,8 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     (``routed_scaling_factor``). ``held = (start, count)`` says which
     experts this layer computes (module docstring); None is all of them.
     Where fewer experts are held than a token picks, the routing returned is
-    of ``count`` picks a token, every held one among them.
+    of ``count`` picks a token, every held one among them. A share's routing
+    carries the window of rows its layer moves at a time (``window_rows``).
     The matmul runs at the highest precision: 2 * T * D * E operations, and
     which expert a token goes to should not hang on a bf16 pass."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
@@ -165,9 +214,11 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     is_held = (local >= 0) & (local < count)
     order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
     inverse = jnp.argsort(order)
+    rows = window_rows(x.shape[0], top_k, count, num_experts)
     return Routing(logits, probs, weights, experts.astype(jnp.int32),
                    counts[start:start + count], order, inverse, counts,
-                   is_held.reshape(experts.shape))
+                   is_held.reshape(experts.shape),
+                   jnp.arange(rows, dtype=jnp.int32))
 
 
 def balance_bias(bias, counts, rate: float):
@@ -181,17 +232,13 @@ def balance_bias(bias, counts, rate: float):
 
 
 def dispatch(x, routing: Routing):
-    """Rows of ``x`` [T, D] in expert order: [T * k, D], each token's row
-    once for each of its picks."""
-    top_k = routing.experts.shape[-1]
-    rows = _dispatch(x, routing.order, routing.inverse, top_k)
+    """Rows of ``x`` [T, D] in expert order, each token's row once for each
+    of its picks: [T * k, D] where every expert is held, the ``R`` rows of
+    ``routing``'s window where a share is (zeros past the live ones)."""
     if routing.live is None:
-        return rows
-    # rows past the last group belong to no held expert: zeros in, and on
-    # the way back whatever the grouped matmuls' gradients left there is
-    # dropped before it is summed into a token
-    in_group = jnp.arange(rows.shape[0]) < jnp.sum(routing.group_sizes)
-    return jnp.where(in_group[:, None], rows, 0)
+        top_k = routing.experts.shape[-1]
+        return _dispatch(x, routing.order, routing.inverse, top_k)
+    return _dispatch_window(x, _window_index(routing))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -211,6 +258,112 @@ def _dispatch_bwd(top_k, inverse, g):
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+class _WindowIndex(NamedTuple):
+    """Where one window's ``R`` rows come from and go to, ``T`` tokens of
+    ``k`` picks. The row side is in expert order; the token side has the same
+    rows in pair order (by token, then by pick), the dead ones last."""
+
+    pair: jax.Array     # [R] int32 the pair of each row, t * k + j
+    live: jax.Array     # [R] bool, the row belongs to a held expert's group
+    by_pair: jax.Array  # [R] int32 the rows in pair order
+    sorted: jax.Array   # [R] int32 their pairs, ascending; T * k in the dead
+    start: jax.Array    # [T] int32 where a token's run begins in pair order
+    here: jax.Array     # [T, k] bool, the pair has a live row in this window
+
+
+def _window_index(routing: Routing) -> _WindowIndex:
+    t, k = routing.experts.shape
+    pairs, rows = routing.order.shape[0], routing.window.shape[0]
+    first = routing.window[0]
+    live_rows = jnp.sum(routing.group_sizes)
+    # the last window may reach past the pairs: those rows are never live
+    order = jnp.pad(routing.order, (0, -pairs % rows))
+    pair = jax.lax.dynamic_slice_in_dim(order, first, rows)
+    live = routing.window - first < live_rows
+    in_order, by_pair = jax.lax.sort(
+        (jnp.where(live, pair, pairs), routing.window - first), num_keys=1)
+    row = routing.inverse.reshape(t, k) - first
+    here = (row >= 0) & (row < jnp.minimum(live_rows, rows))
+    count = jnp.sum(here, axis=-1, dtype=jnp.int32)
+    return _WindowIndex(pair, live, by_pair, in_order,
+                        jnp.cumsum(count) - count, here)
+
+
+def _take_rows(x, index: _WindowIndex):
+    """``x`` [T, D] -> [R, D]: each live row its token's row, zeros in the
+    dead ones."""
+    k = index.here.shape[-1]
+    rows = jnp.take(x, index.pair // k, axis=0)
+    return jnp.where(index.live[:, None], rows, 0)
+
+
+#: rows of pair order summed by one product: the MXU's width
+_RUN_BLOCK = 128
+
+
+def _sum_rows(rows, index: _WindowIndex, weights=None):
+    """``rows`` [R, D] -> [T, D]: the sum of each token's live rows, each
+    times its pair's weight if ``weights`` [T, k] are given (at the rows'
+    precision, the products accumulated in f32); a token with no live row
+    here reads zero. ``R + T`` rows are gathered. First the rows into pair
+    order, where a token's rows are neighbours, at most ``k`` of them. There
+    a block of 128 rows times the 0 / weight matrix of "row j belongs to row
+    i's token" puts a token's sum into each of its rows, on the MXU; a run
+    that crosses into the next block finds its last rows in that block's
+    head. Then the runs' first rows are gathered by token."""
+    t, k = index.here.shape
+    pairs, block = t * k, _RUN_BLOCK
+    head = 16 * -(-(k - 1) // 16)   # whole bf16 tiles that hold k - 1 rows
+    if head > block:
+        raise ValueError(f"{k} picks a token do not fit a block of {block}")
+    pad = (0, -index.sorted.shape[0] % block)
+    in_order = jnp.pad(index.sorted, pad, constant_values=pairs)
+    z = jnp.take(rows, jnp.pad(index.by_pair, pad), axis=0)
+    # whatever the grouped matmuls left in the dead rows stays out
+    z = jnp.where((in_order < pairs)[:, None], z, 0)
+    z = z.reshape(-1, block, z.shape[-1])
+    owner = (in_order // k).reshape(-1, block)
+    scale = jnp.ones(owner.shape, rows.dtype)
+    if weights is not None:
+        scale = jnp.take(weights.reshape(-1).astype(rows.dtype),
+                         jnp.minimum(in_order, pairs - 1)).reshape(owner.shape)
+
+    def run_sums(their_owner, their_scale, their_rows):
+        same = owner[:, :, None] == their_owner[:, None, :]
+        return jnp.einsum(
+            "bij,bjd->bid", jnp.where(same, their_scale[:, None, :], 0),
+            their_rows, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    def next_head(a, fill):
+        return jnp.pad(a[1:, :head], ((0, 1),) + ((0, 0),) * (a.ndim - 1),
+                       constant_values=fill)
+
+    total = run_sums(owner, scale, z)
+    if head:
+        total = total + run_sums(next_head(owner, -1), next_head(scale, 0),
+                                 next_head(z, 0))
+    total = total.astype(rows.dtype).reshape(-1, total.shape[-1])
+    first = jnp.take(total, index.start, axis=0, mode="clip")
+    return jnp.where(jnp.any(index.here, axis=-1)[:, None], first, 0)
+
+
+@jax.custom_vjp
+def _dispatch_window(x, index: _WindowIndex):
+    return _take_rows(x, index)
+
+
+def _dispatch_window_fwd(x, index):
+    return _take_rows(x, index), index
+
+
+def _dispatch_window_bwd(index, g):
+    return _sum_rows(g, index), None
+
+
+_dispatch_window.defvjp(_dispatch_window_fwd, _dispatch_window_bwd)
 
 
 def expert_ffn(rows, gate, up, down, group_sizes, activation="swiglu",
@@ -240,17 +393,126 @@ def expert_ffn(rows, gate, up, down, group_sizes, activation="swiglu",
 
 
 def combine(rows, routing: Routing):
-    """The experts' outputs ``rows`` [T * k, D] back in token order and
-    summed over each token's picks with the router's weights: [T, D]."""
+    """The experts' outputs ``rows`` (``dispatch``'s shape) back in token
+    order and summed over each token's picks with the router's weights:
+    [T, D]; of a share, the part its window's live rows give."""
+    if routing.live is not None:
+        return _combine_window(rows, routing.weights, _window_index(routing))
     t, top_k = routing.experts.shape
     back = permute(rows, routing.inverse, routing.order).reshape(t, top_k, -1)
-    weights = routing.weights
-    if routing.live is not None:
-        back = jnp.where(routing.live[..., None], back, 0)
-        weights = jnp.where(routing.live, weights, 0)
-    out = jnp.einsum("tkd,tk->td", back, weights.astype(rows.dtype),
+    out = jnp.einsum("tkd,tk->td", back, routing.weights.astype(rows.dtype),
                      preferred_element_type=jnp.float32)
     return out.astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _combine_window(rows, weights, index: _WindowIndex):
+    return _sum_rows(rows, index, weights)
+
+
+def _combine_window_fwd(rows, weights, index):
+    return _combine_window(rows, weights, index), (rows, weights, index)
+
+
+def _combine_window_bwd(res, g):
+    rows, weights, index = res
+    by_token = _take_rows(g, index).astype(jnp.float32)
+    # the weights entered the sum at the rows' precision
+    rounded = weights.astype(rows.dtype).astype(jnp.float32)
+    scale = jnp.take(rounded.reshape(-1), index.pair)
+    dots = jnp.sum(by_token * jnp.where(index.live[:, None], rows, 0), axis=-1)
+    # a live row's pair is its own: R scalars set, not T * k gathered
+    d_weights = jnp.zeros(weights.size, jnp.float32).at[
+        jnp.where(index.live, index.pair, weights.size)].set(
+            dots, mode="drop", unique_indices=True)
+    return ((by_token * scale[:, None]).astype(rows.dtype),
+            d_weights.reshape(weights.shape).astype(weights.dtype), None)
+
+
+_combine_window.defvjp(_combine_window_fwd, _combine_window_bwd)
+
+
+def num_windows(routing: Routing) -> int:
+    """The windows a layer's pairs fill, from its shapes: 1 where every
+    expert is held."""
+    if routing.live is None:
+        return 1
+    return -(-routing.order.shape[0] // routing.window.shape[0])
+
+
+def live_windows(routing: Routing):
+    """The windows ``over_windows`` runs for this routing, int32: those that
+    hold a live row, the first one always."""
+    if routing.live is None:
+        return jnp.int32(1)
+    rows = routing.window.shape[0]
+    return jnp.maximum((jnp.sum(routing.group_sizes) + rows - 1) // rows, 1)
+
+
+def _window(routing: Routing, i) -> Routing:
+    """``routing`` for the ``i``-th window of its sorted pairs: the group
+    sizes clipped to the window's rows."""
+    rows = routing.window.shape[0]
+    first = i * rows
+    ends = jnp.cumsum(routing.group_sizes)
+    starts = ends - routing.group_sizes
+    return routing._replace(
+        group_sizes=(jnp.clip(ends, first, first + rows)
+                     - jnp.clip(starts, first, first + rows)),
+        window=first + jnp.arange(rows, dtype=jnp.int32))
+
+
+def over_windows(layer, routing: Routing, *operands):
+    """``layer(routing, *operands)`` -> [T, D] (dispatch, the experts,
+    combine) over every window of ``routing`` that holds a live row, summed.
+    One window, and ``layer`` is called once on ``routing`` as it is; of more
+    the first always runs and a loop takes the others only while live rows
+    are left (module docstring). ``layer`` reads nothing that needs a
+    gradient but ``routing.weights`` and ``operands``."""
+    if num_windows(routing) == 1:
+        return layer(routing, *operands)
+    return _over_windows(layer, routing, operands)
+
+
+def _while_live(routing, first, more):
+    """``first`` joined by ``more(i)`` for the live windows 1, 2, ..."""
+    live = live_windows(routing)
+    return jax.lax.while_loop(
+        lambda s: s[0] < live,
+        lambda s: (s[0] + 1, jax.tree.map(jnp.add, s[1], more(s[0]))),
+        (jnp.int32(1), first))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _over_windows(layer, routing, operands):
+    def run(i):
+        return layer(_window(routing, i), *operands)
+
+    return _while_live(routing, run(0), run)
+
+
+def _over_windows_fwd(layer, routing, operands):
+    return _over_windows(layer, routing, operands), (routing, operands)
+
+
+def _over_windows_bwd(layer, res, g):
+    # a window is computed again for its gradient: between two layers only
+    # the operands and the routing live on, and a loop's trips keep nothing
+    routing, operands = res
+
+    def pull(i):
+        def run(weights, operands):
+            window = _window(routing, i)._replace(weights=weights)
+            return layer(window, *operands)
+
+        return jax.vjp(run, routing.weights, operands)[1](g)
+
+    d_weights, d_operands = _while_live(routing, pull(0), pull)
+    nothing = jax.tree.map(lambda _: None, routing)
+    return nothing._replace(weights=d_weights), d_operands
+
+
+_over_windows.defvjp(_over_windows_fwd, _over_windows_bwd)
 
 
 def load_balance_loss(routing: Routing):

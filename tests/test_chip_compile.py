@@ -6,12 +6,14 @@ topology described inside a fixture: only one process may hold libtpu, and
 only the worker that is given this file loads it.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ps_tpu.ops import flash_attention
+from ps_tpu.ops import flash_attention, moe
 from ps_tpu.ops.gated_conv import gated_short_conv
 from ps_tpu.ops.kda import kda, path
 from ps_tpu.ops.ssd import ssd
@@ -145,6 +147,65 @@ def test_chunked_ssd_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+#: (tokens, width of the rows, width of an expert, held, router width,
+#: picks, activation), the window's rows, and the temp bytes the same block
+#: compiled to before PR 40 (its row buffers 65,536 long), read once from
+#: commit 83c8eb2: the bound
+EXPERT_BLOCKS = {
+    "lfm2-24b-a2b.s8192.zipf":
+        ((16384, 2048, 1536, 8, 64, 4, "swiglu"), 24576, 1197941248),
+    "kimi-linear-48b-a3b.s8192.b1.zipf":
+        ((8192, 2304, 1024, 8, 256, 8, "swiglu"), 6144, 1064713216),
+    "nemotron-3-super-120b-a12b.s8192.b1.zipf":
+        ((8192, 1024, 2688, 8, 512, 22, "relu2"), 8704, 994259456)}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_BLOCKS))
+def test_a_shares_expert_block_moves_a_window_of_rows(cell, one_chip,
+                                                      no_compile_cache):
+    """Route, dispatch, the held experts and combine of ``ops/moe.py`` at
+    the three share cells' shapes, forward and backward: nothing 65,536 rows
+    long and as wide as a row is left (no gather, no ``where``, no buffer of
+    the experts), the windows behind the first are a ``while`` loop that a
+    step with no overflow never enters (the backward's: the gradient of this
+    sum needs no forward value, so the forward's loop is gone), and the
+    block needs no more temp bytes than it did with whole buffers."""
+    (t, d, f, held, width, top_k, activation), rows, parent_temp = (
+        EXPERT_BLOCKS[cell])
+    gated = activation == "swiglu"
+
+    def window_of(routing, x, gate, down, *up):
+        buffer = moe.dispatch(x, routing)
+        buffer = moe.expert_ffn(
+            buffer, gate, *(up or (None,)), down, routing.group_sizes,
+            activation=activation,
+            expected_rows=None if gated else buffer.shape[0])
+        return moe.combine(buffer, routing)
+
+    def loss(x, router, *stacks):
+        routing = moe.route(x, router, top_k, renormalize=True,
+                            scoring="sigmoid", held=(0, held))
+        assert routing.window.shape == (rows,)
+        out = moe.over_windows(window_of, routing, x, *stacks)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg(t, d), arg(d, width, dtype=jnp.float32), arg(held, d, f),
+            arg(held, f, d)) + ((arg(held, d, f),) if gated else ())
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    whole = t * min(top_k, held)
+    assert whole == 65536
+    tall = re.findall(rf"\w+\[{whole},\d{{3,}}\]", text)
+    assert not tall, sorted(set(tall))
+    assert f"bf16[{rows},{d}]" in text and f"bf16[{rows},{f}]" in text
+    assert text.count(" while(") == 1 and " conditional(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
+
+
 @pytest.mark.parametrize("batch,seq", [(32, 512), (128, 128)])
 def test_labelled_mlm_head_compiles_at_the_cells_shapes(batch, seq, one_chip,
                                                         no_compile_cache):
@@ -155,8 +216,6 @@ def test_labelled_mlm_head_compiles_at_the_cells_shapes(batch, seq, one_chip,
     than that (the parent's logits were ``f32[16384, 30522]``, 2 GB); the
     later trips are ``while`` loops the labels' count bounds, never a
     second head at full size."""
-    import re
-
     import numpy as np
 
     from ps_tpu.models.bert import (BertConfig, BertMLM, head_groups,

@@ -31,7 +31,7 @@ from benchmark.families import kimi_step
 from benchmark.layer_metrics import kimi as kimi_metrics
 from ps_tpu.models import kimi_linear
 from ps_tpu.models.lm import _full_attention, make_attn_fn
-from ps_tpu.ops import flash_attention, kda as kda_ops
+from ps_tpu.ops import flash_attention, kda as kda_ops, moe
 from ps_tpu.ops.flash_attention import (backward_tiles, backward_vmem_bytes,
                                         forward_tiles, forward_vmem_bytes)
 from ps_tpu.ops.gated_conv import causal_taps
@@ -411,6 +411,87 @@ def test_the_shares_add_up_with_the_shared_expert_counted_once():
     np.testing.assert_allclose(routed + shared, whole, atol=2e-5)
     # every share's output summed counts the shared expert four times
     assert float(jnp.max(jnp.abs(routed + 4 * shared - whole))) > 0.1
+
+
+#: enough tokens for two windows where four of sixteen experts are held at
+#: four picks: three times an even load in whole tiles is 1,536 rows of the
+#: 2,048 pairs the row buffers used to hold
+WINDOW_TOKENS = 512
+
+
+@pytest.mark.parametrize("boost,windows", [(0.0, 1), (8.0, 2), (-8.0, 1)],
+                         ids=["even_load", "overflow", "no_live_row"])
+def test_a_share_in_windows_is_the_references_values_and_gradients(boost,
+                                                                   windows):
+    """The held experts over a window of ``R`` rows, beside the shared
+    expert, against the plain masked loop, values and every gradient: at a
+    load the first window holds; with every token sent to the held experts,
+    so that a second window runs and nothing is dropped; and with no live
+    row at all (the shared expert alone)."""
+    sizes, lp, x, bias = _layer(tokens=WINDOW_TOKENS)
+    start, count = 4, 4
+    bias = bias.at[start:start + count].add(boost)
+    share = {**sizes, "num_experts": count, "expert_start": start}
+    cfg = kimi_linear.KimiLinearConfig.from_dict(share)
+
+    def held(lp):
+        return {**lp, **{n: lp[n][start:start + count]
+                         for n in ("gate", "up", "down")}}
+
+    def system(lp, x):
+        return kimi_linear.moe_block(held(lp), x, cfg, bias)
+
+    def plain(lp, x):
+        return reference.experts(held(lp), x[0], bias, share)[0][None]
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda lp, x: jnp.sum(jnp.sin(f(lp, x))),
+                                argnums=(0, 1)))(lp, x)
+
+    with jax.default_matmul_precision("highest"):
+        out, routing = jax.jit(system)(lp, x)
+        assert routing.window.shape == (1536,)
+        assert moe.num_windows(routing) == 2
+        assert int(moe.live_windows(routing)) == windows
+        live = int(routing.group_sizes.sum())
+        assert live == int(routing.live.sum())
+        if boost:
+            assert live == (4 * WINDOW_TOKENS if boost > 0 else 0)
+        else:
+            assert 0 < live <= 1536
+        assert _rel(out, plain(lp, x)) <= F32_TOL
+        _assert_grads_close(grads(lambda lp, x: system(lp, x)[0]),
+                            grads(plain))
+
+
+def test_every_expert_held_is_one_window_of_the_whole_buffer():
+    """With all sixteen experts held the window is the ``T x k`` pairs: no
+    loop, and the routed part is, to the bit, the whole-buffer gather written
+    out here."""
+    sizes, lp, x, bias = _layer(tokens=WINDOW_TOKENS)
+    with jax.default_matmul_precision("highest"):
+        out, routing = _share(sizes, lp, x, bias, 0, 16)
+        assert routing.live is None and routing.window is None
+        assert moe.num_windows(routing) == 1
+        rows = jnp.take(x[0], routing.order // 4, axis=0)
+        rows = moe.expert_ffn(rows, lp["gate"], lp["up"], lp["down"],
+                              routing.group_sizes)
+        back = jnp.take(rows, routing.inverse, axis=0).reshape(
+            WINDOW_TOKENS, 4, -1)
+        want = jnp.einsum("tkd,tk->td", back, routing.weights,
+                          preferred_element_type=jnp.float32)
+        want = want + kimi_linear.dense_ffn(lp["shared"], x[0])
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(want))
+
+
+def test_aux_counts_the_windows_each_expert_layer_ran():
+    sizes, cfg, params, batch, bias, _ = _base()
+    (_, aux), _ = _system(cfg, params, batch, bias)
+    np.testing.assert_array_equal(np.asarray(aux["expert_windows"]),
+                                  np.ones(cfg.num_expert_layers, np.int32))
+    assert aux["expert_windows"].dtype == jnp.int32
+    # the cell's shapes: 8,192 tokens, eight picks, 8 of 256 held
+    assert moe.window_rows(8192, 8, 8, 256) == 6144
 
 
 def test_routing_is_sigmoid_top8_renormalised_and_scaled():

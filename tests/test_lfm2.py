@@ -203,6 +203,86 @@ def test_the_shares_add_up():
     np.testing.assert_allclose(out[0], whole, atol=1e-5)
 
 
+#: enough tokens for two windows where two of sixteen experts are held at
+#: four picks: three times an even load in whole tiles is 1,536 rows of the
+#: 2,048 pairs the row buffers used to hold
+WINDOW_TOKENS = 1024
+
+
+@pytest.mark.parametrize("boost,windows", [(0.0, 1), (8.0, 2), (-8.0, 1)],
+                         ids=["even_load", "overflow", "no_live_row"])
+def test_a_share_in_windows_is_the_references_values_and_gradients(boost,
+                                                                   windows):
+    """The held experts over a window of ``R`` rows against the plain
+    masked loop, values and every gradient: at a load the first window holds;
+    with every token sent to the held experts, so that a second window runs
+    and nothing is dropped; and with no live row at all."""
+    sizes, lp, x, bias = _layer(tokens=WINDOW_TOKENS)
+    start, count = 4, 2
+    bias = bias.at[start:start + count].add(boost)
+    share = {**sizes, "num_experts": count, "expert_start": start}
+    cfg = lfm2.Lfm2Config.from_dict(share)
+
+    def held(lp):
+        return {"router": lp["router"], **{
+            n: lp[n][start:start + count] for n in ("gate", "up", "down")}}
+
+    def system(lp, x):
+        return lfm2.moe_block(held(lp), x, cfg, bias)
+
+    def plain(lp, x):
+        return reference.experts(held(lp), x[0], bias, share)[0][None]
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda lp, x: jnp.sum(jnp.sin(f(lp, x))),
+                                argnums=(0, 1)))(lp, x)
+
+    with jax.default_matmul_precision("highest"):
+        out, routing = jax.jit(system)(lp, x)
+        assert routing.window.shape == (1536,)
+        assert moe.num_windows(routing) == 2
+        assert int(moe.live_windows(routing)) == windows
+        live = int(routing.group_sizes.sum())
+        assert live == int(routing.live.sum())
+        if boost:
+            assert live == (2 * WINDOW_TOKENS if boost > 0 else 0)
+        else:
+            assert 0 < live <= 1536
+        np.testing.assert_allclose(out, plain(lp, x), atol=2e-6)
+        _assert_grads_close(grads(lambda lp, x: system(lp, x)[0]),
+                            grads(plain))
+
+
+def test_every_expert_held_is_one_window_of_the_whole_buffer():
+    """With all sixteen experts held the window is the ``T x k`` pairs: no
+    loop, and the output is, to the bit, the whole-buffer gather written out
+    here."""
+    sizes, lp, x, bias = _layer(tokens=WINDOW_TOKENS)
+    with jax.default_matmul_precision("highest"):
+        out, routing = _share(sizes, lp, x, bias, 0, 16)
+        assert routing.live is None and routing.window is None
+        assert moe.num_windows(routing) == 1
+        assert moe.window_rows(WINDOW_TOKENS, 4, 16, 16) == 4 * WINDOW_TOKENS
+        rows = jnp.take(x[0], routing.order // 4, axis=0)
+        rows = moe.expert_ffn(rows, lp["gate"], lp["up"], lp["down"],
+                              routing.group_sizes)
+        back = jnp.take(rows, routing.inverse, axis=0).reshape(
+            WINDOW_TOKENS, 4, -1)
+        want = jnp.einsum("tkd,tk->td", back, routing.weights,
+                          preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(want))
+
+
+def test_aux_counts_the_windows_each_expert_layer_ran():
+    sizes, cfg, params, batch, bias, _ = _base()
+    (_, aux), _ = _system(cfg, params, batch, bias)
+    np.testing.assert_array_equal(np.asarray(aux["expert_windows"]),
+                                  np.ones(cfg.num_expert_layers, np.int32))
+    assert aux["expert_windows"].dtype == jnp.int32
+    # the cell's shapes: 16,384 tokens, four picks, 8 of 64 held
+    assert moe.window_rows(16384, 4, 8, 64) == 24576
+
+
 def test_renormalisation_runs_over_all_four_picks():
     sizes, lp, x, bias = _layer()
     # four held of four picks: a routing that keeps all of a token's picks

@@ -131,9 +131,9 @@ def test_system_matches_reference(attn):
                for g in jax.tree_util.tree_leaves(ref_grads))
     _assert_grads_close(grads, ref_grads)
     with jax.default_matmul_precision("highest"):
-        hidden, _, _ = nemotron_h.apply(params, batch["inputs"], cfg, bias,
-                                        make_attn_fn(attn),
-                                        grouped=attn == "flash")
+        hidden, *_ = nemotron_h.apply(params, batch["inputs"], cfg, bias,
+                                       make_attn_fn(attn),
+                                       grouped=attn == "flash")
         logits = nemotron_h.logits_of(params, hidden, cfg)
         want = reference.logits_fn(params, batch["inputs"], bias, sizes)
     assert logits.shape == (2, 128, 256)
@@ -382,10 +382,11 @@ def test_the_eight_head_shares_of_the_attention_layer_add_up(attn):
     np.testing.assert_allclose(total, want, atol=5e-5)
 
 
-def _expert_layer(seed=3, tokens=96):
-    sizes = {**SIZES, "n_routed_experts": 16, "expert_start": 0}
+def _expert_layer(seed=3, tokens=96, experts=16):
+    sizes = {**SIZES, "router_width": experts, "n_routed_experts": experts,
+             "expert_start": 0}
     rng = np.random.default_rng(seed)
-    d, latent, f, fs, e = 64, 32, 24, 48, 16
+    d, latent, f, fs, e = 64, 32, 24, 48, experts
     lp = {"router": {"kernel": _w(rng, d, e, scale=0.3)},
           "latent_down": {"kernel": _w(rng, d, latent)},
           "latent_up": {"kernel": _w(rng, latent, d)},
@@ -438,6 +439,90 @@ def test_the_expert_shares_add_up_with_the_shared_expert_counted_once():
     np.testing.assert_allclose(routed + shared, whole, rtol=1e-5, atol=5e-5)
     # every share's output summed counts the shared expert four times
     assert float(jnp.max(jnp.abs(routed + 4 * shared - whole))) > 0.1
+
+
+#: enough tokens for two windows where four of 32 experts are held at six
+#: picks: three times an even load in whole tiles is 2,560 rows of the 4,096
+#: pairs the row buffers used to hold
+WINDOW_TOKENS = 1024
+
+
+@pytest.mark.parametrize("boost,windows", [(0.0, 1), (8.0, 2), (-8.0, 1)],
+                         ids=["even_load", "overflow", "no_live_row"])
+def test_a_share_in_windows_is_the_references_values_and_gradients(boost,
+                                                                   windows):
+    """The held experts over a window of ``R`` rows of the latent, beside
+    the shared expert, against the plain masked loop, values and every
+    gradient: at a load the first window holds; with every token sent to the
+    held experts, so that a second window runs and nothing is dropped; and
+    with no live row at all (the shared expert alone)."""
+    sizes, lp, x, bias = _expert_layer(tokens=WINDOW_TOKENS, experts=32)
+    start, count = 4, 4
+    bias = bias.at[start:start + count].add(boost)
+    share = {**sizes, "n_routed_experts": count, "expert_start": start}
+    cfg = nemotron_h.NemotronHConfig.from_dict(share)
+
+    def held(lp):
+        return {**lp, **{n: lp[n][start:start + count] for n in ("w1", "w2")}}
+
+    def system(lp, x):
+        return nemotron_h.moe_block(held(lp), x, cfg, bias)
+
+    def plain(lp, x):
+        return reference.experts(held(lp), x[0], bias, share)[0][None]
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda lp, x: jnp.sum(jnp.sin(f(lp, x))),
+                                argnums=(0, 1)))(lp, x)
+
+    with jax.default_matmul_precision("highest"):
+        out, routing = jax.jit(system)(lp, x)
+        assert routing.window.shape == (2560,)
+        assert moe.num_windows(routing) == 2
+        assert int(moe.live_windows(routing)) == windows
+        live = int(routing.group_sizes.sum())
+        assert live == int(routing.live.sum())
+        if boost:
+            assert live == (4 * WINDOW_TOKENS if boost > 0 else 0)
+        else:
+            assert 0 < live <= 2560
+        assert _rel(out, plain(lp, x)) <= F32_TOL
+        _assert_grads_close(grads(lambda lp, x: system(lp, x)[0]),
+                            grads(plain))
+
+
+def test_every_expert_held_is_one_window_of_the_whole_buffer():
+    """With all sixteen experts held the window is the ``T x k`` pairs: no
+    loop, and the routed part in the latent is, to the bit, the whole-buffer
+    gather written out here."""
+    sizes, lp, x, bias = _expert_layer(tokens=WINDOW_TOKENS)
+    with jax.default_matmul_precision("highest"):
+        out, routing = _expert_share(sizes, lp, x, bias, 0, 16)
+        assert routing.live is None and routing.window is None
+        assert moe.num_windows(routing) == 1
+        latent = x[0] @ lp["latent_down"]["kernel"]
+        rows = jnp.take(latent, routing.order // 6, axis=0)
+        rows = moe.expert_ffn(rows, lp["w1"], None, lp["w2"],
+                              routing.group_sizes, activation="relu2",
+                              expected_rows=rows.shape[0])
+        back = jnp.take(rows, routing.inverse, axis=0).reshape(
+            WINDOW_TOKENS, 6, -1)
+        latent = jnp.einsum("tkd,tk->td", back, routing.weights,
+                            preferred_element_type=jnp.float32)
+        want = (latent @ lp["latent_up"]["kernel"]
+                + nemotron_h.relu2_ffn(lp["shared"], x[0]))
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(want))
+
+
+def test_aux_counts_the_windows_each_expert_layer_ran():
+    sizes, cfg, params, batch, bias, _ = _base()
+    (_, aux), _ = _system(cfg, params, batch, bias)
+    np.testing.assert_array_equal(np.asarray(aux["expert_windows"]),
+                                  np.ones(cfg.num_expert_layers, np.int32))
+    assert aux["expert_windows"].dtype == jnp.int32
+    # the cell's shapes: 8,192 tokens, 22 picks, 8 of 512 held, and the
+    # grouped matmuls' fixed work is the window's
+    assert moe.window_rows(8192, 22, 8, 512) == 8704
 
 
 def test_routing_is_sigmoid_top_k_renormalised_over_all_picks_and_scaled():

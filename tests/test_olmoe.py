@@ -29,6 +29,7 @@ import olmoe_reference as reference
 import ps_tpu as ps
 from benchmark.families import moe_step, olmoe_reference as benchmark_copy
 from ps_tpu.models import olmoe
+from ps_tpu.ops import moe
 
 F32_TOL = 1e-5
 BF16_TOL = 2.0 ** -14
@@ -174,6 +175,43 @@ def test_system_without_a_piece_fails_the_reference(piece, monkeypatch):
     assert abs(float(loss) - float(ref_loss)) \
         > 5 * F32_TOL * abs(float(ref_loss)), piece
     assert _worst(grads, ref_grads) > 1000 * F32_TOL, piece
+
+
+def test_every_expert_held_is_one_window_of_the_whole_buffer():
+    """OLMoE holds every expert: its routing has no window to run in turns,
+    and the layer's output is, to the bit, the whole-buffer gather written
+    out here."""
+    _, cfg, params, _, _, _ = _base()
+    lp = params["layer0"]["moe"]
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 64, 64)),
+                    jnp.float32)
+    out, routing = olmoe.moe_block(lp, x, cfg)
+    assert routing.live is None and routing.window is None
+    assert moe.num_windows(routing) == 1
+    assert int(moe.live_windows(routing)) == 1
+    tokens = x.reshape(128, 64)
+    rows = jnp.take(tokens, routing.order // 2, axis=0)
+    rows = moe.expert_ffn(rows, lp["gate"], lp["up"], lp["down"],
+                          routing.group_sizes)
+    back = jnp.take(rows, routing.inverse, axis=0).reshape(128, 2, 64)
+    want = jnp.einsum("tkd,tk->td", back, routing.weights,
+                      preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(out).reshape(128, 64),
+                                  np.asarray(want))
+
+
+@pytest.mark.parametrize("cell,shapes,rows", [
+    ("olmoe-1b-7b.s4096.zipf", (8192, 8, 64, 64), 65536),
+    ("lfm2-24b-a2b.s8192.zipf", (16384, 4, 8, 64), 24576),
+    ("kimi-linear-48b-a3b.s8192.b1.zipf", (8192, 8, 8, 256), 6144),
+    ("nemotron-3-super-120b-a12b.s8192.b1.zipf", (8192, 22, 8, 512), 8704)])
+def test_window_rows_at_the_cells_shapes(cell, shapes, rows):
+    """``R`` from (tokens, picks, held, router width): three times an even
+    load in whole tiles of 512 rows; with every expert held, every pair."""
+    assert moe.window_rows(*shapes) == rows
+    tokens, top_k, held, _ = shapes
+    assert rows % moe.GROUPED_MATMUL_ROWS == 0
+    assert rows <= tokens * min(top_k, held)
 
 
 def test_qk_norm_makes_the_scale_of_q_immaterial():
