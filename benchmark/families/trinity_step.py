@@ -1,0 +1,429 @@
+"""Family of the fused step over Trinity, one chip's share of an
+expert-parallel group: ``ps.init`` -> ``KVStore`` (AdamW behind a global-norm
+clip, warmed up) -> ``make_step(loss_fn, has_aux=True)`` -> ``shard_batch``,
+the calls of ``families/kimi_step.py`` with the loss of
+``ps_tpu/models/trinity.py``. The router's selection bias goes in as the
+step's extra argument and comes back in ``aux`` as a device value, every step,
+with the step's expert counts; no host read in the window.
+
+The yardstick's own pieces live here and beside this file: the stream of Zipf
+ids (``moe_step.fresh_batches``); the plain reference, the benchmark's own copy
+(``families/trinity_reference.py``, letter for letter the tests'
+``tests/trinity_reference.py``); the limits of the step-0 checks with their
+measured reasons; and the functions that give operations and bytes from shapes
+(``seen_pairs``, ``flash_cost`` for a band and for a triangle,
+``live_step_share``, ``dense_flops``, ``pair_flops``, ``step_flops``). The
+warm-up and the sign rule are LFM2's (``lfm2_step.learning_rate``,
+``lfm2_step.bias_by_sign_rule``), the step-0 checks Nemotron-H's with this
+family's limits.
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from benchmark.families import trinity_reference as reference
+from benchmark.families.lfm2_step import bias_by_sign_rule, learning_rate
+from benchmark.families.moe_step import (adamw_first_step, cosine,
+                                         fresh_batches, zipf_entropy)
+from benchmark.families.nemotron_h_step import lengths_apart
+from benchmark.harness import stats
+from benchmark.harness.loop import Cell, seed_key
+
+WINDOWED, FULL = "sliding_attention", "full_attention"
+
+# -- the limits of the step-0 checks, with what was measured ------------------
+# The fused step computes in bf16 as the configuration states, with the Pallas
+# flash kernels at 32 query heads on 4 K/V heads, a band of 2,048 keys in four
+# layers and the triangle in the fifth, and the grouped matmuls over the held
+# experts; the reference in f32 at "highest" with an explicit [S, S] mask
+# under a softmax and none of the kernels. All readings: my chip runs, PR 41,
+# TPU v5 lite, published widths, 16,384 tokens. "seen": the system against the
+# reference over 22 seeds (the cell's nineteen runs, chiprun_out/pr41_{t1,a,b}_*,
+# and the tool's three). "e4m3": the reference on weights rounded to an 8-bit float (the
+# nearest precision below bfloat16, a lower bound of computing in one) against
+# the whole reference, at three seeds (tools/trinity_grad_check.py, seeds
+# 4100000021-23). "fault": the system with one fault of ISSUE 41's 6 (a)
+# planted from outside (the same tool, seed 4100000021): the window ignored on
+# the windowed layers / a rotation on the full layer / the gate left out / the
+# norm behind layer 1's attention left out.
+#
+#               loss      flips a layer  gate     full q   window k  router   stack    shared w1  dense w1  lengths apart
+#  seen, worst  6.18e-5   642 (0.49%)    .99993   .99982   .99984    .95518   .99529   .99991     .99992    0.0543
+#  LIMIT        1.5e-4    1,179 (0.9%)   .997     .995     .995      .90      .985     .996       .996      0.15
+#  e4m3, best   3.30e-4   5,223          .99049   .97755   .98270    .89830   .97861   .99089     .99076    (0.0555)
+#  window       1.15e-4   8,750          .97021   .93262   .76522    .46886   .93960   .96838     .96901    0.2912
+#  rotated      4.4e-6    1,795          .99826   .25959   .99802    .90697   .99460   .99772     .99788    0.6119
+#  no gate      3.57e-3   14,556         nan      .66046   .75465    .21442   .84418   .92198     .91023    1.0
+#  no norm      2.41e-3   25,248         .80386   .54958   .70651    .05074   .45313   .63892     .73670    1.3947
+#
+# e4m3 is "not correct" at each of its three seeds by the loss, by the counts
+# of every layer and by the cosines of the full layer's q and the windowed
+# layer's k (.97497-.97755 and .98197-.98270 under .995); every fault by the
+# counts, by the lengths and by the witness that sits on it (the window
+# ignored turns the windowed layer's k to .765, a rotation on the full layer
+# its q to .260 while every other witness stays above .99, the gate left out
+# leaves its own gradient at nothing). The router's cosine has the heavy tail
+# of LFM2's, Kimi-Linear's and Nemotron's: 258-642 of a layer's 131,072 pairs
+# flip between bf16 and f32 activations, an eighth of them on held experts,
+# and under Zipf ids the flipped tokens are copies of a few hot ids whose
+# contributions add up coherently: .95518 at one seed of 22, .9745 at the
+# next, .9864-.9987 at the others. Its limit is the siblings' .90, which e4m3's best seed (.8983)
+# misses by little and its worst (.687) by far: the router's is the one limit
+# with little room on the lower side, and e4m3 is told apart by the others.
+# The last column tells no precision apart, as in the older families (e4m3's
+# lengths lie .0555-.0809 from their mean, the system's up to .0543: the
+# router's length reads 6% long at such a seed, .0875 -> .0929 of the
+# reference's, all others within 0.8% of each other); its upper reading is the
+# least of the faults, .2912: the limit has 2.8 times of room above what was
+# seen and 1.9 under the fault.
+TOLERANCE = (1.5e-4,
+             "bf16 compute with top-8 flips against an f32 reference whose "
+             "attention is an explicit mask under a softmax: 2.4x the "
+             "largest of 22 seeds (6.18e-5; the next 5.63e-5); the "
+             "reference on e4m3 weights moves 3.30e-4 to 5.43e-4 at three "
+             "seeds. Blunt (0.02-normal weights give every token nearly the "
+             "entropy of the vocabulary), so after_step0 holds the counts, "
+             "the gradient, the clip, the apply and the bias")
+#: token-expert pairs, of T * top_k a layer, that may sit on another expert
+#: than the reference's (top-8 flips between bf16 and f32 activations): half
+#: the sum over the 128 experts of |count - reference count|, per layer.
+#: Seen: 258 to 642 of 131,072; e4m3: 5,223 to 8,456; the faults 1,795 and up
+FLIP_SHARE = 0.009
+#: leaves (the store's keys) whose gradient witnesses the backward pass, with
+#: the lowest cosine to the reference's jax.grad that passes: a windowed
+#: layer's output gate (the gate's own path, behind the band's kernel), the
+#: full layer's q projection (the triangle's dq, no rotation before it), a
+#: windowed layer's k projection (the band's dk summed over a group of
+#: eight, through the rotation and the head norm), a router (the sigmoid,
+#: the renormalisation over all eight picks, the scale and the absent
+#: experts' zero weights), a held expert stack (the grouped matmul's gradient
+#: over the window of rows), a shared expert's first matrix (the dense branch
+#: beside the routed one) and the dense layer's (upstream of everything: its
+#: gradient comes back through the head, four expert layers, five attention
+#: cores and twenty norms).
+#: Read from AdamW's first moment: no hook in the step.
+GRAD_COSINE = {"layer1/attn/gate/kernel": 0.997,
+               "layer4/attn/q/kernel": 0.995,
+               "layer2/attn/k/kernel": 0.995,
+               "layer3/moe/router/kernel": 0.90,
+               "layer2/moe/gate": 0.985,
+               "layer1/moe/shared/w1/kernel": 0.996,
+               "layer0/ffn/w1/kernel": 0.996}
+#: how far a witness's length over the reference's may lie from the
+#: witnesses' mean (the clip's scale is common to them; it scaled by 0.0771
+#: to 0.0884). Seen: 0.0543 at most; the least of the planted faults 0.2912
+#: (the table above). It tells no precision apart
+GRAD_NORM_TOLERANCE = 0.15
+#: the updated witnesses against AdamW's rule applied by numpy in f64 to the
+#: store's own moments: the largest distance beyond the f32 rounding of the
+#: parameter itself (half an ulp of the result), in units of step 0's
+#: learning rate (5e-8 under the warm-up: lfm2_step.py says why the rounding
+#: is allowed for). Seen beyond the rounding: 0.9e-7 to 3.8e-7 of the rate
+APPLY_TOLERANCE = 1e-5
+
+#: the steps n at which a run says its mean loss over n-7..n on stderr: the
+#: values ISSUE 41 lets the traffic's ``loss_step`` take
+LOSS_STEPS = (32, 48, 64, 96)
+
+
+# -- operations and bytes from shapes -----------------------------------------
+
+def pair_flops(config):
+    """Forward and backward of one token-expert pair through its expert:
+    three matrices, 3 x 2 x D x F."""
+    return 3 * 6.0 * config["hidden_size"] * config["moe_intermediate_size"]
+
+
+def seen_pairs(seq, window=None):
+    """Query-key pairs one head of one sequence attends over: the triangle
+    with its diagonal, or the band of ``window`` keys a row (itself and the
+    ``window - 1`` before it), whose first rows see fewer."""
+    if window is None or window >= seq:
+        return seq * (seq + 1) // 2
+    return window * (window + 1) // 2 + (seq - window) * window
+
+
+def flash_cost(batch, heads, kv_heads, seq, dim, layers, window=None,
+               itemsize=2):
+    """Operations and HBM bytes of the flash kernel's three calls in one
+    step, **forward and backward**, over what the layers see, a band
+    (``window``) or the triangle: a matmul over the scores of one head is
+    2 x pairs x d. Forward: QK^T and PV. dk / dv: the scores, dP, dv and dk.
+    dq: the scores, dP and dq: nine in all. Bytes: the forward reads q, k, v
+    and writes the output and the f32 logsumexp; each backward call reads q,
+    dO, k, v and the two f32 rows and writes its gradients; K and V at their
+    own head count."""
+    flops = layers * batch * heads * 2.0 * seen_pairs(seq, window) * 9 * dim
+    q_side, k_side = dim * itemsize * heads, dim * itemsize * kv_heads
+    rows = 2 * 4 * heads
+    per_token = ((2 * q_side + 2 * k_side + 4 * heads)
+                 + (2 * q_side + 4 * k_side + rows)
+                 + (3 * q_side + 2 * k_side + rows))
+    return flops, float(layers * batch * seq * per_token)
+
+
+def live_step_share(seq, window, tiles):
+    """Grid steps a windowed forward call computes over those the causal
+    call at the same tiles would, by the definition: a tile is live when
+    some row of it sees some key of it."""
+    block_q, block_k = tiles
+    blocks = [(i, j) for i in range(seq // block_q)
+              for j in range(seq // block_k)]
+    causal = sum((i + 1) * block_q - 1 >= j * block_k for i, j in blocks)
+    band = sum((i + 1) * block_q - 1 >= j * block_k
+               and i * block_q - (window - 1) <= (j + 1) * block_k - 1
+               for i, j in blocks)
+    return band / causal
+
+
+def dense_flops(config, tokens, seq_len):
+    """Operations of one training step outside the routed experts, that the
+    model requires: forward and backward (3 x 2 a parameter a token) over the
+    matmuls every token passes (an attention layer's five projections; the
+    dense SwiGLU; the routers and the shared experts; the untied head) and
+    attention's quadratic term over what each layer sees (QK^T and PV,
+    forward and backward: 3 x 2 matmuls x 2 x pairs x head_dim a head). The
+    rotation, gates and norms are not counted, nor is recomputation."""
+    d = config["hidden_size"]
+    heads, kv_heads, dim = (config["num_attention_heads"],
+                            config["num_key_value_heads"],
+                            config["head_dim"])
+    per_token = 6.0 * d * config["vocab_size"]
+    cores = 0.0
+    for i, kind in enumerate(config["layer_types"]):
+        per_token += 6.0 * d * dim * (3 * heads + 2 * kv_heads)
+        window = config["sliding_window"] if kind == WINDOWED else None
+        cores += 3 * 4.0 * heads * dim * seen_pairs(seq_len, window)
+        if i < config["num_dense_layers"]:
+            per_token += 6.0 * 3 * d * config["intermediate_size"]
+        else:
+            per_token += 6.0 * (d * config["router_width"] + 3 * d
+                                * config["moe_intermediate_size"]
+                                * config["num_shared_experts"])
+    return float(tokens * per_token) + cores * (tokens // seq_len)
+
+
+def step_flops(config, tokens, seq_len, live_pairs):
+    """``dense_flops`` plus the pairs the step computed here."""
+    return dense_flops(config, tokens, seq_len) \
+        + live_pairs * pair_flops(config)
+
+
+def step0_checks(got, want, witnesses, clipped_norm, rule, pairs, rate):
+    """What ``correct`` holds beyond step 0's loss, as
+    ``nemotron_h_step.step0_checks`` with this family's limits. ``got`` /
+    ``want``: the step's and the reference's aux. ``witnesses``: per name
+    ``before`` and ``after`` (the parameter around step 0), ``mu`` and ``nu``
+    (the store's moments after it) and ``reference_grad``. ``clipped_norm``:
+    the global norm of the clipped gradient. ``pairs``: T * top_k, a layer.
+    Returns the loop's ``{"checks": .., "detail": ..}``."""
+    counts = np.asarray(got["expert_tokens"], np.int64)        # [L, 128]
+    ref_counts = np.asarray(want["expert_tokens"], np.int64)
+    held = np.asarray(got["held_tokens"], np.int64)            # [L, held]
+    moved = np.abs(counts - ref_counts).sum(axis=-1) // 2      # a layer
+    detail = {"pairs_routed_per_layer": counts.sum(axis=-1).tolist(),
+              "pairs_held_per_layer": held.sum(axis=-1).tolist(),
+              "reference_pairs_held_per_layer": np.asarray(
+                  want["held_tokens"], np.int64).sum(axis=-1).tolist(),
+              "pairs_on_another_expert_than_reference": moved.tolist(),
+              "clipped_gradient_norm": clipped_norm}
+    clip = rule["clip_by_global_norm"]
+    scales = []
+    for name, w in witnesses.items():
+        grad = np.asarray(w["mu"], np.float64) / (1 - rule["b1"])
+        detail[f"grad_cosine.{name}"] = cosine(grad, w["reference_grad"])
+        scales.append(np.linalg.norm(grad)
+                      / np.linalg.norm(np.asarray(w["reference_grad"],
+                                                  np.float64)))
+        after = np.asarray(w["after"], np.float32)
+        off = np.abs(after.astype(np.float64) - adamw_first_step(
+            w["before"], w["mu"], w["nu"], **rule))
+        detail[f"apply_error_lr.{name}"] = float(np.max(np.maximum(
+            off - 0.5 * np.spacing(np.abs(after)).astype(np.float64), 0.0))
+            / rule["learning_rate"])
+    detail["grad_norm_over_reference"] = [float(s) for s in scales]
+    detail["clip_scale"] = scale = float(np.mean(scales))
+    detail["lengths_apart"] = lengths_apart(scales)
+    clipped_to_limit = abs(clipped_norm - clip) <= 1e-3 * clip
+    bias = np.asarray(got["expert_bias"], np.float32)
+    return {"checks": {
+        "no_dropped_tokens": bool((counts.sum(axis=-1) == pairs).all()),
+        "expert_counts_match_reference":
+            bool((moved <= FLIP_SHARE * pairs).all()),
+        "gradient_matches_reference": all(
+            detail[f"grad_cosine.{name}"] >= GRAD_COSINE[name]
+            for name in witnesses)
+        and detail["lengths_apart"] <= GRAD_NORM_TOLERANCE,
+        "gradient_clipped_to_global_norm":
+            clipped_norm <= clip * (1 + 1e-3) and (
+                clipped_to_limit or abs(scale - 1) <= GRAD_NORM_TOLERANCE),
+        "adamw_apply_matches_rule": all(
+            detail[f"apply_error_lr.{name}"] <= APPLY_TOLERANCE
+            for name in witnesses),
+        # exactly the rule, on the step's own counts: the bias is not the
+        # optimizer's and nothing rounds on the way
+        "expert_bias_follows_sign_rule":
+            bool(np.array_equal(bias, bias_by_sign_rule(counts, rate)))},
+        "detail": detail}
+
+
+def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
+    import ps_tpu as ps
+    from ps_tpu.data.prefetch import device_prefetch
+    from ps_tpu.models.trinity import (TrinityConfig, init_expert_bias,
+                                       init_params, make_loss_fn)
+    from ps_tpu.ops.flash_attention import forward_tiles
+    from ps_tpu.parallel.sharding import replicated
+
+    if config["model"] != "trinity":
+        raise ValueError(f"trinity_step knows no model {config['model']!r}")
+    if traffic["ids"]["kind"] != "zipf":
+        raise ValueError(f"unknown id distribution {traffic['ids']['kind']!r}")
+    if traffic["input"] != "direct":
+        raise ValueError(f"unknown input mode {traffic['input']!r}")
+    if traffic["pool"] != "fresh":
+        raise ValueError(
+            f"trinity_step re-uses no batch: pool {traffic['pool']!r}")
+    t_start = time.perf_counter()
+    ctx = ps.init(backend="tpu")
+    cfg = TrinityConfig.from_dict(config)
+    per_chip = int(traffic["per_chip_batch"])
+    batch = per_chip * chips
+    seq = int(traffic["seq_len"])
+    tokens = per_chip * seq                      # a chip, a step
+    pairs = tokens * cfg.num_experts_per_tok     # a chip, a step, a layer
+
+    opt = dict(config["optimizer"])
+    rate, rule = learning_rate(opt, opt.pop("warmup_steps", 0))
+    store = ps.KVStore(optimizer=opt.pop("name"), placement="replicated",
+                       **{**opt, "learning_rate": rate})
+    # the weights are made on the device from the seed; the store keeps its
+    # own buffers (it donates them every step), so the tree made here goes
+    params = jax.block_until_ready(
+        jax.jit(lambda k: init_params(k, cfg))(seed_key(seed)))
+    t_weights = time.perf_counter()
+    jax.block_until_ready(store.init(params))
+    del params
+    t_store = time.perf_counter()
+    fused = store.make_step(make_loss_fn(cfg, attn=traffic["attn"]),
+                            has_aux=True)
+    batches = fresh_batches(batch, seq, cfg.vocab_size, traffic["ids"]["s"],
+                            seed)
+    # the state that is not the optimizer's: one device value, handed from
+    # each step to the next
+    state = {"expert_bias": jax.device_put(init_expert_bias(cfg),
+                                           replicated(ctx.mesh))}
+
+    # device values, read at the end only: a scalar, [L, 128], [L, held], [L]
+    losses, expert_tokens, held_tokens, windows = [], [], [], []
+    first = {}
+
+    def step(b):
+        loss, _, aux = fused(b, state["expert_bias"])
+        if not expert_tokens:
+            first["system"] = aux
+        state["expert_bias"] = aux["expert_bias"]
+        losses.append(loss)
+        expert_tokens.append(aux["expert_tokens"])
+        held_tokens.append(aux["held_tokens"])
+        windows.append(aux["expert_windows"])
+        return loss
+
+    plain = jax.jit(lambda params, b, bias: reference.witness_grads(
+        params, b, bias, config, GRAD_COSINE))
+
+    def reference_loss(b):
+        params = store.params()
+        with jax.default_matmul_precision("highest"):
+            (loss, aux), grads = plain(params, b, state["expert_bias"])
+        first["reference"] = jax.device_get(aux)
+        first["witnesses"] = {
+            # the store donates its buffers to step 0: copies, on the host
+            name: {"before": np.asarray(store.pull(name)),
+                   "reference_grad": np.asarray(grad)}
+            for name, grad in grads.items()}
+        return float(loss)
+
+    def after_step0():
+        """More than step 0's loss: ``step0_checks`` on what the store
+        holds once step 0 is done."""
+        def moment(key, which):
+            return optax.tree_utils.tree_get(store.optimizer_state(key),
+                                             which)
+
+        for name, w in first["witnesses"].items():
+            w.update(after=np.asarray(store.pull(name)),
+                     mu=np.asarray(moment(name, "mu")),
+                     nu=np.asarray(moment(name, "nu")))
+        clipped_norm = float(jnp.sqrt(sum(
+            jnp.vdot(m, m) for m in (moment(k, "mu") for k in store.keys())))
+        ) / (1 - rule["b1"])
+        return step0_checks(jax.device_get(first["system"]),
+                            first["reference"], first["witnesses"],
+                            clipped_norm, rule, pairs * chips,
+                            cfg.load_balance_coeff)
+
+    def counters():
+        values = [float(x) for x in jax.device_get(losses)]
+        print("trinity_step: mean loss of steps n-7..n " + json.dumps(
+            {n: stats.loss_at_n(values, n) for n in LOSS_STEPS
+             if n < len(values)}), file=sys.stderr)
+        counts = np.asarray(jax.device_get(expert_tokens), np.float64)
+        held = np.asarray(jax.device_get(held_tokens), np.float64)
+        routed = pairs * chips * cfg.num_expert_layers * len(counts)
+        fullest = float(np.mean(counts.max(axis=-1) / counts.mean(axis=-1)))
+        print("trinity_step: held share of the pairs, by layer "
+              + json.dumps((held.sum(axis=(0, 2))
+                            / counts.sum(axis=(0, 2))).round(5).tolist())
+              + f", over the run {held.sum() / counts.sum():.5f}; fullest "
+              f"expert over the mean {fullest:.3f}; most windows a layer ran "
+              f"{int(np.max(jax.device_get(windows)))}; final expert_bias "
+              f"range {float(jnp.min(state['expert_bias'])):+.4f} .. "
+              f"{float(jnp.max(state['expert_bias'])):+.4f}",
+              file=sys.stderr)
+        return {"trinity_dropped_tokens": float(routed - counts.sum()),
+                "trinity_load_max_over_mean": fullest,
+                "trinity_held_pair_share": float(held.sum() / counts.sum()),
+                # all expert layers of one chip, a step
+                "trinity_live_pairs_per_step":
+                float(held.sum() / len(held) / chips)}
+
+    itemsize = np.dtype(cfg.dtype).itemsize
+    facts = {
+        "trinity_dense_flops_per_step": dense_flops(config, tokens, seq),
+        "trinity_flops_per_pair": pair_flops(config),
+        "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
+                                             traffic["ids"]["s"]),
+        # where set-up's build phase goes, seconds
+        "build_s": {"init_and_weights": t_weights - t_start,
+                    "store_init": t_store - t_weights},
+    }
+    if traffic["attn"] == "flash":
+        shape = (per_chip, cfg.num_attention_heads, cfg.num_key_value_heads,
+                 seq, cfg.head_dim)
+        for name, kind, window in (("window", WINDOWED, cfg.sliding_window),
+                                   ("full", FULL, None)):
+            layers = cfg.layer_types.count(kind)
+            if layers:
+                (facts[f"trinity_{name}_flash_flops"],
+                 facts[f"trinity_{name}_flash_bytes"]) = flash_cost(
+                     *shape, layers, window, itemsize)
+        if WINDOWED in cfg.layer_types and cfg.sliding_window < seq:
+            # the tiles are the kernel's own choice from the shapes
+            facts["trinity_window_live_step_share"] = live_step_share(
+                seq, cfg.sliding_window,
+                forward_tiles(seq, cfg.head_dim, itemsize, True))
+        facts["kernel_targets"] = config["kernel_targets"]
+    stream = device_prefetch(batches, place=store.shard_batch)
+    return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,
+                reference_loss=reference_loss, tolerance=TOLERANCE,
+                counters=counters, facts=facts, close=ps.shutdown,
+                after_step0=after_step0)

@@ -67,26 +67,32 @@ def _rmsnorm(x, scale):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
 
 
-def _full_attention(q, k, v, causal=True, **_):
+def _full_attention(q, k, v, causal=True, window=None, **_):
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
     if causal:
         t = q.shape[1]
-        s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s, -1e30)
+        seen = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            # query i sees keys i - window < j <= i
+            seen = seen & ~jnp.tril(jnp.ones((t, t), bool), -window)
+        s = jnp.where(seen[None, None], s, -1e30)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
 
 
 def make_attn_fn(attn: str = "full", mesh=None, **kw) -> Callable:
     """'full' | 'flash' | 'ring' | 'ulysses'. 'flash' is the single-device
     Pallas kernel (O(S) attention memory; seq must be a multiple of 128);
-    'ring'/'ulysses' need a 'seq' mesh axis and activations sharded
+    both take ``window=`` on a causal call (query i sees keys i - window <
+    j <= i), which 'flash' passes to the kernel. 'ring'/'ulysses' need a 'seq' mesh axis and activations sharded
     P(batch, 'seq')."""
     if attn == "full":
         return _full_attention
     if attn == "flash":
         from ps_tpu.ops import flash_attention
 
-        def flash_fn(q, k, v, causal=True):
-            return flash_attention(q, k, v, causal=causal, **kw)
+        def flash_fn(q, k, v, causal=True, window=None):
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   **kw)
 
         return flash_fn
     from ps_tpu.parallel import ring_attention, ulysses_attention

@@ -4,7 +4,8 @@ the spans of a process's set-up.
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
-``models/kimi_linear.py``, ``models/nemotron_h.py``) and land in the
+``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``)
+and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
@@ -85,6 +86,18 @@ MOE_LATENT = "ps.moe/latent"      # the two projections between the model's widt
 
 NEMOTRON_SCOPES = MOE_SCOPES + (MAMBA, MAMBA_CONV, MAMBA_SSD, MOE_LATENT,
                                 MOE_SHARED)
+
+# -- scopes of Trinity (models/trinity.py), beside the six, FFN and MOE_SHARED ---
+# Read by ``benchmark/layer_metrics/trinity.py``, which keeps its own copy.
+# The three nest under ATTN, so ATTN's time holds them: the attention call of
+# each kind of layer (with 'flash' the Mosaic kernels and the packing around
+# them) and the sigmoid gate on its output.
+ATTN_WINDOW = "ps.attn/window"    # the core of a layer that sees a window of keys, rotated
+ATTN_FULL = "ps.attn/full"        # the core of a layer that sees every earlier key, not rotated
+ATTN_GATE = "ps.attn/gate"        # sigmoid of the gate projection times the core's output
+
+TRINITY_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
+                               ATTN_GATE)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

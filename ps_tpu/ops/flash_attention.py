@@ -89,8 +89,27 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   (``models/kimi_linear.py``); reading them at one head through a grouped
   index map (a second score matmul a tile) was not built: ROADMAP R3.
 
+- A WINDOW (``window=``, causal calls: Trinity's sliding layers see 2,048
+  keys of 16,384): query ``i`` sees the keys ``j`` with ``0 <= i - j <
+  window``. Each causal bound gets its mirror: in the forward and dq kernels
+  ``_first_key`` beside ``_last_live`` (the first key block a query block's
+  first row can see), in dk / dv ``_last_query`` beside ``_first_live``
+  (the last query block that sees a key block's last key), each both the
+  ``pl.when`` skip and the index maps' clamp, so a step outside the band
+  computes nothing and copies nothing; the band's lower edge is masked
+  inside its tiles as the diagonal is (``_visible``), and the backward's
+  unmasked fast path runs on the tiles wholly between the two edges. The
+  call's work follows ``S * window``, not ``S ** 2 / 2``. The TILES are the
+  causal call's, from the shapes alone (``forward_tiles`` and
+  ``backward_tiles`` take no window): a narrower block puts more of what it
+  computes inside the band but adds grid steps, and the wide one won at
+  every window measured, 256 to 2,048 (the table below).
+  ``window=None`` is the program it was, to the jaxpr
+  (``tests/test_flash_attention.py`` pins it); a window that spans the
+  sequence is the causal call.
+
 The padding mask is a [B, S] int/bool array (1 = attend), matching the
-BERT convention; causal and mask compose. Numerics: parity with the
+BERT convention; causal, window and mask compose. Numerics: parity with the
 reference einsum attention is asserted to ~1e-5 f32 in
 tests/test_flash_attention.py (CPU interpret mode runs the same kernel),
 at the chosen tiles and at forced ones.
@@ -124,6 +143,38 @@ causal: four matmuls in dk / dv, three in dq, five in the one call:
 | | dq, (32, 4, 8) | 1.86 | | | 56% (1.05 ms) |
 | [64, 8192, 64] on [16, 8192, 64], causal | dk / dv (1024, 512), (16, 16, 32), 72 of 128 steps live a head | 15.67 | 15.07 | 153.7 alone, 154 | 37% (5.58 ms) |
 | | dq, (64, 8, 16) | 12.97 | 12.30 | | 34% (4.19 ms) |
+
+**Under a window (my chip runs, PR 41: alone, median of 5 chains of 8
+calls; [32, 16384, 128] on [4, 16384, 128], causal, bf16).** Live steps are
+the forward grid's, of those the causal call at the same tiles computes;
+the band holds 23.4% of the causal pairs. FLOPs for the
+roofline are the band's pairs', two matmuls forward, seven backward:
+
+| window | tiles forward / backward | live steps a head | forward ms | backward ms (both calls) | forward, backward of its roofline |
+|---|---|---|---|---|---|
+| none (the full layer) | (1024, 1024) / (1024, 512) | 136 of 256 | 19.51 | 49.28 | 57% (11.2 ms), 79% (39.1 ms) |
+| 2,048 | **(1024, 1024) / (1024, 512)**, the rule's | 45 of 136 | 8.30 | 21.55 (at forward (1024, 512): 13.74) | 32% (2.62 ms), 43% (9.16 ms) |
+| 2,048 | (1024, 1024) / (1024, 1024) | 45 of 136 | 8.30 | 19.31 | compiles at 16 MiB's edge, over the count, not taken |
+| 2,048 | (512, 512) / (512, 512) | 150 of 528 | 12.66 | 23.17 | 21%, 40% |
+| 2,048 | (512, 256) / (512, 256) | 300 of 1,056 | 22.62 | 33.10 | |
+| 2,048 | (256, 256) / (256, 256) | 540 of 2,080 | 30.00 | 50.08 | |
+| 2,048 | (256, 128) / (256, 128) | 1,080 of 4,160 | 48.38 | 89.01 | |
+
+A forward grid step takes 5.8 / 2.6 / 1.7 us at 1024^2 / 512^2 / 256^2
+entries, so the tile that wastes a third of its scores (three key blocks of
+1,024 for a row's 2,048 keys) beats the one that wastes a fifth (five of
+512) by a third. Narrower windows at the same shape, forward / backward ms:
+1,024: (1024, 1024) / (1024, 512) **6.46 / 17.19**, (512, 512) 9.76 / 18.34,
+(256, 256) 23.90 / 43.11; 512: **6.47 / 14.47**, 8.25 / 15.83, 20.59 /
+39.51; 256: **6.48 / 14.47**, 8.25 / 15.84, 18.97 / 37.64: the causal call's
+tiles win at each, and under a window of 1,024 keys the forward stops
+getting faster (two key blocks of 1,024 a query block whatever the window:
+the floor of these tiles). Against the causal call on the same operands the
+windowed forward at 2,048 takes 43% of the time for 33% of the live steps
+and 23% of the pairs: the skip returns what the tiles let it. Inside the
+fused step (the cell's trace, seed 4100000501) a windowed layer's calls take
+36.3 ms (dk / dv 15.49, the forward twice for the layer's recomputation) and
+the full layer's 87.1 (dk / dv 33.88, dq 26.98, the forward 24.0 twice).
 
 All three gradients agree with an f32 einsum attention on the same bf16
 inputs within 1.0-1.3 roundoffs of their largest entry at every tile
@@ -212,7 +263,10 @@ def forward_tiles(seq: int, head_dim: int, itemsize: int, causal: bool,
     """(block_q, block_k) of the forward kernel, from the operands' shapes
     alone: ``_widest_tiles`` under ``forward_vmem_bytes``. A causal call
     keeps block_k <= block_q, so that a query block's diagonal tile, the
-    one that computes masked scores, is no wider than the block itself."""
+    one that computes masked scores, is no wider than the block itself. A
+    windowed call runs at the causal call's tiles, whatever its window: a
+    narrower block puts more of what it computes inside the band and still
+    loses to the grid steps it adds (the module docstring's table)."""
     return _widest_tiles(forward_vmem_bytes, "forward", seq, head_dim,
                          itemsize, causal, v_head_dim)
 
@@ -223,19 +277,39 @@ def _last_live(qi, block_q: int, block_k: int):
     return ((qi + 1) * block_q - 1) // block_k
 
 
-def _visible(qi, j, shape, q_axis: int):
+def _first_key(qi, block_q: int, block_k: int, window: int):
+    """The first key block a windowed query block ``qi`` can see: the one
+    that holds the key ``window - 1`` positions before the block's first
+    row. Beside ``_last_live`` the other bound of the forward and dq
+    kernels' compute skip and of their K/V index maps' clamp."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _last_query(j, block_q: int, block_k: int, window: int, num_q: int):
+    """The last query block that sees windowed key block ``j``: the one
+    that holds the row ``window - 1`` positions after the block's last
+    key. Beside ``_first_live`` the other bound of the dk / dv kernel's
+    skip and clamp."""
+    return jnp.minimum(((j + 1) * block_k + window - 2) // block_q,
+                       num_q - 1)
+
+
+def _visible(qi, j, shape, q_axis: int, window: Optional[int] = None):
     """Causal visibility of a score tile of query block ``qi`` and key
-    block ``j``: the query position reaches the key position. The queries
-    run along ``q_axis`` of ``shape``, the keys along the other."""
+    block ``j``: the query position reaches the key position and, under a
+    window, lies fewer than ``window`` past it. The queries run along
+    ``q_axis`` of ``shape``, the keys along the other."""
     qpos = qi * shape[q_axis] + jax.lax.broadcasted_iota(
         jnp.int32, shape, q_axis)
     kpos = j * shape[1 - q_axis] + jax.lax.broadcasted_iota(
         jnp.int32, shape, 1 - q_axis)
-    return qpos >= kpos
+    if window is None:
+        return qpos >= kpos
+    return jnp.logical_and(qpos >= kpos, qpos - kpos < window)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
-                scale: float, causal: bool):
+                scale: float, causal: bool, window: Optional[int]):
     """One (batch·head, q-block, kv-block) grid step. The kv dimension is
     the INNERMOST grid axis, so the (m, l, acc) VMEM scratch persists
     across a q-block's kv steps while Mosaic pipelines the next kv
@@ -255,7 +329,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k] f32
         if causal:
-            s = jnp.where(_visible(qi, j, s.shape, 0), s, _NEG_INF)
+            s = jnp.where(_visible(qi, j, s.shape, 0, window), s, _NEG_INF)
         # padding mask: this block's key validity as a [1, block_k] row,
         # broadcast over the query rows
         s = jnp.where(mask_ref[:] > 0, s, _NEG_INF)
@@ -299,6 +373,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
     # nothing. Their compute is skipped here and their DMA in _flash_fwd,
     # whose index maps stop at the same _last_live block.
     live = (j <= _last_live(qi, block_q, block_k)) if causal else True
+    if window is not None:
+        # and key blocks wholly before the window, by the other bound
+        live = jnp.logical_and(
+            live, j >= _first_key(qi, block_q, block_k, window))
 
     @pl.when(live)
     def _step():
@@ -317,7 +395,7 @@ def _vmem(shape, index_map):
 
 
 def _query_major_specs(bh: int, b: int, group: int, d: int, *, block_q: int,
-                       block_k: int, causal: bool):
+                       block_k: int, causal: bool, window: Optional[int]):
     """Block specs of the grid (B*h, S/block_q, S/block_k), keys innermost,
     that the forward and the dq call run on: a query-side [block_q, d]
     block, a [1, block_q] row of a [BH, 1, S] array (block (1, block_q)
@@ -337,7 +415,12 @@ def _query_major_specs(bh: int, b: int, group: int, d: int, *, block_q: int,
             return j
         # a step past the diagonal names the block the step before it held,
         # and the pipeline copies nothing for an index that stays
-        return jnp.minimum(j, _last_live(i, block_q, block_k))
+        last = jnp.minimum(j, _last_live(i, block_q, block_k))
+        if window is None:
+            return last
+        # and a step before the window names the first block inside it,
+        # which the step that reaches it names again: one copy
+        return jnp.maximum(last, _first_key(i, block_q, block_k, window))
 
     return (
         _vmem((None, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
@@ -361,7 +444,7 @@ def _qkv_specs(q, k, v, mask, **tiles):
     return q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec
 
 
-def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
+def _flash_fwd(q, k, v, mask, *, scale, causal, window, block_q, block_k,
                interpret):
     """q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v];
     mask: [B, S] routed per program. Returns out [BH, S, d_v] and the
@@ -370,9 +453,11 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
     d_v = v.shape[-1]
     num_k = seq // block_k
     q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _qkv_specs(
-        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal)
+        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal,
+        window=window)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal),
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          window=window),
         grid=(bh, seq // block_q, num_k),
         in_specs=[q_spec, k_spec, v_spec, mask_spec],
         out_specs=[o_spec, row_spec],
@@ -443,22 +528,28 @@ def _probabilities(s, lse, keep):
 
 
 def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
-                  block_k: int):
+                  block_k: int, window: Optional[int] = None):
     """Calls ``step(when, diagonal)`` for the tile of query block ``qi``
     and key block ``j``: once where nothing is causal; else once for the
-    live tiles the diagonal crosses, which need the position mask, and once
-    for those wholly under it, which do not (8% of the dk / dv call at
-    LFM2's shape, 1% of the dq call). Dead tiles run neither."""
+    live tiles the diagonal or the window's lower edge crosses, which need
+    the position mask, and once for those wholly under the one and inside
+    the other, which do not (8% of the dk / dv call at LFM2's shape, 1% of
+    the dq call). Dead tiles run neither."""
     if not causal:
         step(True, False)
         return
     under = (j + 1) * block_k - 1 <= qi * block_q
+    if window is not None:
+        # the tile's last row still sees its first key
+        under = jnp.logical_and(
+            under, (qi + 1) * block_q - 1 - j * block_k < window)
     step(jnp.logical_and(live, jnp.logical_not(under)), True)
     step(under, False)
 
 
 def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
-                scale: float, causal: bool, num_q: int, fused: bool):
+                scale: float, causal: bool, window: Optional[int],
+                num_q: int, fused: bool):
     """One (batch x K/V head, key block, query head of the group x query
     block) grid step of dk and dv. The tile is the transposed one, keys
     down the sublanes and queries along the lanes, so that the logsumexp
@@ -484,7 +575,8 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
         ) * scale  # [block_k, block_q] f32
         keep = mask_ref[:] > 0  # [block_k, 1]: this block's key validity
         if diagonal:
-            keep = jnp.logical_and(keep, _visible(qi, j, st.shape, 1))
+            keep = jnp.logical_and(keep,
+                                   _visible(qi, j, st.shape, 1, window))
         pt = _probabilities(st, lse_ref[:], keep)
         dpt = jax.lax.dot_general(
             v_ref[:], do_ref[:], (((1,), (1,)), ((), ())),
@@ -537,8 +629,12 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
     # causal: query blocks wholly before this key block see none of it.
     # Their compute is skipped here and their DMA in _flash_dkv, whose
     # index maps start at the same _first_live block.
-    _causal_steps(step, causal, qi >= _first_live(j, block_q, block_k),
-                  qi, j, block_q, block_k)
+    live = qi >= _first_live(j, block_q, block_k)
+    if window is not None:
+        # and query blocks wholly past the window, by the other bound
+        live = jnp.logical_and(
+            live, qi <= _last_query(j, block_q, block_k, window, num_q))
+    _causal_steps(step, causal, live, qi, j, block_q, block_k, window)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finalize():
@@ -547,7 +643,7 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
 
 def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
                dq_ref, dq_scr, lse_scr, delta_scr, *, scale: float,
-               causal: bool):
+               causal: bool, window: Optional[int]):
     """One (batch x head, query block, key block) grid step of dq, the
     forward's grid and index maps. The tile has the queries down the
     sublanes, so the logsumexp and delta rows are turned into columns,
@@ -572,7 +668,8 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
         ) * scale  # [block_q, block_k] f32
         keep = mask_ref[:] > 0  # [1, block_k]
         if diagonal:
-            keep = jnp.logical_and(keep, _visible(qi, j, s.shape, 0))
+            keep = jnp.logical_and(keep,
+                                   _visible(qi, j, s.shape, 0, window))
         p = _probabilities(s, lse_scr[:, :1], keep)
         dp = jax.lax.dot_general(
             do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
@@ -589,16 +686,19 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
         def _step():
             dq_scr[:] += tile(diagonal)
 
-    _causal_steps(step, causal, j <= _last_live(qi, block_q, block_k),
-                  qi, j, block_q, block_k)
+    live = j <= _last_live(qi, block_q, block_k)
+    if window is not None:
+        live = jnp.logical_and(
+            live, j >= _first_key(qi, block_q, block_k, window))
+    _causal_steps(step, causal, live, qi, j, block_q, block_k, window)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[:] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
-               block_k, interpret):
+def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
+               block_q, block_k, interpret):
     """dk, dv: grid (B * h_kv, S / block_k, group * S / block_q). The
     innermost axis walks the query heads a K/V head serves and, within
     each, the query blocks from the first live one. With ``delta`` None
@@ -621,7 +721,12 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
             return i
         # a step before the diagonal names the first live block, which the
         # step that reaches it names again: one copy
-        return jnp.maximum(i, _first_live(j, block_q, block_k))
+        first = jnp.maximum(i, _first_live(j, block_q, block_k))
+        if window is None:
+            return first
+        # and a step past the window names the last block inside it
+        return jnp.minimum(first,
+                           _last_query(j, block_q, block_k, window, num_q))
 
     def q_side(width):
         return _vmem((None, block_q, width),
@@ -637,7 +742,7 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
                      lambda g, j, t: (q_head(g, t), 0, q_block(j, t)))
     dk, dv, *dq = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          num_q=num_q, fused=fused),
+                          window=window, num_q=num_q, fused=fused),
         grid=(bh_kv, seq // block_k, steps),
         in_specs=[
             q_spec, do_spec, row_spec, k_spec, v_spec,
@@ -660,15 +765,17 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
     return dk, dv, dq[0] if fused else None
 
 
-def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
-              block_k, interpret):
-    """dq: the forward's grid, K/V head ``bh // group`` and the clamp at
-    ``_last_live``."""
+def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, window,
+              block_q, block_k, interpret):
+    """dq: the forward's grid, K/V head ``bh // group`` and the clamps at
+    ``_last_live`` and, under a window, ``_first_key``."""
     bh, seq, d = q.shape
     q_spec, row_spec, k_spec, mask_spec, do_spec, v_spec = _qkv_specs(
-        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal)
+        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal,
+        window=window)
     return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal),
+        functools.partial(_dq_kernel, scale=scale, causal=causal,
+                          window=window),
         grid=(bh, seq // block_q, seq // block_k),
         in_specs=[q_spec, do_spec, row_spec, row_spec, k_spec, v_spec,
                   mask_spec],
@@ -685,7 +792,8 @@ def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
     )(q, do, lse, delta, k, v, mask[:, None, :])
 
 
-def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, interpret):
+def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, window,
+               interpret):
     """dq, dk, dv of ``_flash_fwd`` by two Mosaic calls, or by one where
     one tile spans the sequence and each K/V head serves one query head.
     q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v]; out,
@@ -701,33 +809,35 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, interpret):
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)[:, None, :]
     args = (q, do, lse, delta, k, v, mask.astype(jnp.int32))
-    kwargs = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, interpret=interpret)
+    kwargs = dict(scale=scale, causal=causal, window=window,
+                  block_q=block_q, block_k=block_k, interpret=interpret)
     dk, dv, dq = _flash_dkv(*args, **kwargs)
     if dq is None:
         dq = _flash_dq(*args, **kwargs)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, mask, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, mask, scale, causal, window, block_q, block_k,
+           interpret):
     out, _ = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
-                        block_q=block_q, block_k=block_k,
+                        window=window, block_q=block_q, block_k=block_k,
                         interpret=interpret)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, mask, scale, causal, block_q, block_k,
+def _flash_vjp_fwd(q, k, v, mask, scale, causal, window, block_q, block_k,
                    interpret):
     out, lse = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
+                          window=window, block_q=block_q, block_k=block_k,
                           interpret=interpret)
     return out, (q, k, v, mask, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(scale, causal, window, block_q, block_k, interpret, res,
+                   do):
     dq, dk, dv = _flash_bwd(*res, do, scale=scale, causal=causal,
-                            interpret=interpret)
+                            window=window, interpret=interpret)
     return dq, dk, dv, None
 
 
@@ -735,7 +845,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
-                    causal: bool = False, block_q: Optional[int] = None,
+                    causal: bool = False, window: Optional[int] = None,
+                    block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mesh: Optional[Mesh] = None) -> jax.Array:
@@ -746,8 +857,12 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     attention) and ``d_v`` the values' own width (latent attention's keys
     are 192 wide and its values 128: no zero-padded v is made; the scale is
     ``d ** -0.5``, the keys'); ``mask``: optional [B, S] with 1 = attend
-    (BERT padding convention); ``causal`` composes with it. Returns
-    [B, S, h, d_v].
+    (BERT padding convention); ``causal`` composes with it. ``window``
+    (causal calls only): query ``i`` sees the keys ``j`` with
+    ``0 <= i - j < window``, itself and the ``window - 1`` before it; the
+    kernels skip, and do not fetch, the blocks wholly outside the band, so
+    the call's work follows ``S * window`` and not ``S ** 2 / 2``. One that
+    spans the sequence is the causal call. Returns [B, S, h, d_v].
 
     ``block_q`` / ``block_k`` tile the forward kernel; left at None they
     are ``forward_tiles``' choice from the operands' shapes. ``interpret``
@@ -769,6 +884,12 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
         raise ValueError(f"{h} query heads of width {d} on K/V of shapes "
                          f"{k.shape}, {v.shape}: the K/V heads must divide "
                          f"them, and k be as wide as q")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window} needs causal=True and at "
+                             f"least the query's own position")
+        if window >= seq:
+            window = None   # every earlier key is inside it
     if block_q is None or block_k is None:
         chosen = forward_tiles(seq, d, q.dtype.itemsize, causal, d_v)
         block_q, block_k = block_q or chosen[0], block_k or chosen[1]
@@ -792,7 +913,7 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                 lb * x.shape[2], seq, x.shape[3])
 
         out = _flash(pack(q), pack(k), pack(v), mask, scale, causal,
-                     block_q, block_k, interpret)
+                     window, block_q, block_k, interpret)
         return jnp.transpose(out.reshape(lb, lh, seq, d_v), (0, 2, 1, 3))
 
     if mesh is None:

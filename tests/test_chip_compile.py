@@ -56,7 +56,15 @@ CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True, 3),
          "kimi-linear-48b-a3b.s8192.b1.zipf":
              ((1, 8192, 32, 32, 192, 128), True, 3),
          "nemotron-3-super-120b-a12b.s8192.b1.zipf":
-             ((1, 8192, 4, 1, 128), True, 3)}
+             ((1, 8192, 4, 1, 128), True, 3),
+         # Trinity-Mini's 32 query heads on 4 K/V heads at 16,384: the one
+         # layer that sees every earlier key, and the four that see WINDOWS'
+         "trinity-mini.s16384.b1.zipf, full":
+             ((1, 16384, 32, 4, 128), True, 3),
+         "trinity-mini.s16384.b1.zipf, windowed":
+             ((1, 16384, 32, 4, 128), True, 3)}
+#: the window of a cell's call, where it has one
+WINDOWS = {"trinity-mini.s16384.b1.zipf, windowed": 2048}
 
 
 @pytest.mark.parametrize("cell", sorted(CALLS))
@@ -69,7 +77,8 @@ def test_flash_forward_and_backward_compile_at_the_cells_shapes(
                                     sharding=one_chip)
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=causal, interpret=False)
+        out = flash_attention(q, k, v, causal=causal,
+                              window=WINDOWS.get(cell), interpret=False)
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(

@@ -17,10 +17,10 @@ import pytest
 
 from ps_tpu.models.lm import _full_attention
 from ps_tpu.ops import flash_attention
-from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_live,
-                                        _last_live, backward_tiles,
-                                        backward_vmem_bytes, forward_tiles,
-                                        forward_vmem_bytes)
+from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_key,
+                                        _first_live, _last_live, _last_query,
+                                        backward_tiles, backward_vmem_bytes,
+                                        forward_tiles, forward_vmem_bytes)
 
 # the module itself: ``ps_tpu.ops.flash_attention`` names the function
 fa = importlib.import_module("ps_tpu.ops.flash_attention")
@@ -37,12 +37,16 @@ def _qkv(seed, s=S, b=B, h=H, d=D):
     )
 
 
-def _ref(q, k, v, mask=None, causal=False):
-    """The models' einsum attention, with the BERT-style [B, S] mask."""
+def _ref(q, k, v, mask=None, causal=False, window=None):
+    """The models' einsum attention, with the BERT-style [B, S] mask; under
+    ``window`` a plain band mask: query i sees keys i - window < j <= i."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
     if causal:
         t = q.shape[1]
-        s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s, -1e30)
+        seen = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            seen = seen & ~jnp.tril(jnp.ones((t, t), bool), -window)
+        s = jnp.where(seen[None, None], s, -1e30)
     if mask is not None:
         s = jnp.where(mask[:, None, None, :] > 0, s, -1e30)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
@@ -102,22 +106,34 @@ def test_gradients_match_reference(causal):
 # diagonal mid-way through a block, in both orders, with key blocks past
 # the diagonal whose fetch is clamped.
 TILE_CASES = [
-    pytest.param(256, 2, 4, 64, False, None, None, id="chosen-s256"),
-    pytest.param(512, 2, 2, 64, False, None, None, id="chosen-s512-one-block"),
-    pytest.param(512, 2, 2, 64, True, None, None, id="chosen-s512-causal"),
-    pytest.param(1024, 1, 2, 128, True, None, None, id="chosen-s1024-d128-causal"),
-    pytest.param(512, 2, 2, 64, True, 256, 128, id="causal-q256-k128"),
-    pytest.param(512, 2, 2, 64, True, 128, 256, id="causal-q128-k256"),
-    pytest.param(512, 1, 2, 64, True, 512, 128, id="causal-q512-k128"),
-    pytest.param(512, 1, 2, 64, True, 128, 512, id="causal-q128-k512"),
-    pytest.param(512, 2, 2, 64, False, 128, 256, id="padded-q128-k256"),
-    pytest.param(256, 2, 4, 64, True, 128, 128, id="causal-q128-k128"),
+    pytest.param(256, 2, 4, 64, False, None, None, None, id="chosen-s256"),
+    pytest.param(512, 2, 2, 64, False, None, None, None, id="chosen-s512-one-block"),
+    pytest.param(512, 2, 2, 64, True, None, None, None, id="chosen-s512-causal"),
+    pytest.param(1024, 1, 2, 128, True, None, None, None, id="chosen-s1024-d128-causal"),
+    pytest.param(512, 2, 2, 64, True, 256, 128, None, id="causal-q256-k128"),
+    pytest.param(512, 2, 2, 64, True, 128, 256, None, id="causal-q128-k256"),
+    pytest.param(512, 1, 2, 64, True, 512, 128, None, id="causal-q512-k128"),
+    pytest.param(512, 1, 2, 64, True, 128, 512, None, id="causal-q128-k512"),
+    pytest.param(512, 2, 2, 64, False, 128, 256, None, id="padded-q128-k256"),
+    pytest.param(256, 2, 4, 64, True, 128, 128, None, id="causal-q128-k128"),
+    # a window: smaller than a block, a block, wider and off the blocks'
+    # edges, the chooser's tiles under it, and wider than the sequence
+    # (the causal call)
+    pytest.param(512, 2, 2, 64, True, 128, 128, 64, id="window64-q128-k128"),
+    pytest.param(512, 2, 2, 64, True, 128, 128, 128, id="window128-q128-k128"),
+    pytest.param(512, 1, 2, 64, True, 256, 128, 200, id="window200-q256-k128"),
+    pytest.param(512, 1, 2, 64, True, 128, 256, 200, id="window200-q128-k256"),
+    pytest.param(512, 1, 2, 64, True, 512, 512, 100, id="window100-one-block"),
+    pytest.param(1024, 1, 2, 128, True, None, None, 512, id="window512-chosen-s1024-d128"),
+    pytest.param(512, 1, 2, 64, True, 128, 128, 1024, id="window1024-past-the-sequence"),
 ]
 
 
-@pytest.mark.parametrize("seq,b,h,d,causal,block_q,block_k", TILE_CASES)
+@pytest.mark.parametrize("seq,b,h,d,causal,block_q,block_k,window",
+                         TILE_CASES)
 def test_tiles_match_reference_forward_and_gradients(seq, b, h, d, causal,
-                                                     block_q, block_k):
+                                                     block_q, block_k,
+                                                     window):
     """Whatever tiles the forward runs at, chosen or forced: the output and
     the gradients of q, k and v are the einsum attention's."""
     q, k, v = _qkv(11, s=seq, b=b, h=h, d=d)
@@ -128,10 +144,11 @@ def test_tiles_match_reference_forward_and_gradients(seq, b, h, d, causal,
 
     def flash(q, k, v):
         return flash_attention(q, k, v, mask=mask, causal=causal,
-                               block_q=block_q, block_k=block_k)
+                               window=window, block_q=block_q,
+                               block_k=block_k)
 
     def ref(q, k, v):
-        return _ref(q, k, v, mask=mask, causal=causal)
+        return _ref(q, k, v, mask=mask, causal=causal, window=window)
 
     np.testing.assert_allclose(np.asarray(flash(q, k, v)),
                                np.asarray(ref(q, k, v)),
@@ -203,38 +220,49 @@ def _grads(attn, q, k, v):
                     argnums=(0, 1, 2))(q, k, v)
 
 
-def _ref_grouped(q, k, v, mask=None, causal=False):
+def _ref_grouped(q, k, v, mask=None, causal=False, window=None):
     """The einsum attention on K/V repeated for the query heads they
     serve: its k / v gradients sum over each group."""
     group = q.shape[2] // k.shape[2]
     return _ref(q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
-                mask=mask, causal=causal)
+                mask=mask, causal=causal, window=window)
 
 
 # (seq, batch, heads, K/V heads, head_dim, causal, backward block_q,
 # block_k); None = backward_tiles' choice. The forward runs at its own
 # chosen tile throughout.
 BACKWARD_CASES = [
-    pytest.param(512, 2, 2, 2, 64, True, 256, 128, id="causal-q256-k128"),
-    pytest.param(512, 2, 2, 2, 64, True, 128, 256, id="causal-q128-k256"),
-    pytest.param(512, 1, 2, 2, 64, True, 512, 128, id="causal-q512-k128"),
-    pytest.param(512, 1, 2, 2, 64, True, 128, 512, id="causal-q128-k512"),
-    pytest.param(512, 1, 4, 1, 64, True, None, None, id="grouped-4to1-chosen"),
-    pytest.param(512, 2, 8, 2, 64, True, 256, 128, id="grouped-4to1-q256-k128"),
-    pytest.param(512, 1, 4, 1, 64, True, 128, 256, id="grouped-4to1-q128-k256"),
-    pytest.param(256, 2, 4, 2, 64, False, 128, 128, id="grouped-2to1-padded"),
-    pytest.param(1024, 1, 2, 2, 128, True, None, None, id="d128-s1024-chosen"),
-    pytest.param(1024, 1, 2, 2, 128, True, 512, 256, id="d128-s1024-q512-k256"),
-    pytest.param(512, 2, 2, 2, 64, False, 256, 128, id="padded-q256-k128"),
-    pytest.param(512, 2, 2, 2, 64, False, 128, 512, id="padded-q128-k512"),
+    pytest.param(512, 2, 2, 2, 64, True, 256, 128, None, id="causal-q256-k128"),
+    pytest.param(512, 2, 2, 2, 64, True, 128, 256, None, id="causal-q128-k256"),
+    pytest.param(512, 1, 2, 2, 64, True, 512, 128, None, id="causal-q512-k128"),
+    pytest.param(512, 1, 2, 2, 64, True, 128, 512, None, id="causal-q128-k512"),
+    pytest.param(512, 1, 4, 1, 64, True, None, None, None, id="grouped-4to1-chosen"),
+    pytest.param(512, 2, 8, 2, 64, True, 256, 128, None, id="grouped-4to1-q256-k128"),
+    pytest.param(512, 1, 4, 1, 64, True, 128, 256, None, id="grouped-4to1-q128-k256"),
+    pytest.param(256, 2, 4, 2, 64, False, 128, 128, None, id="grouped-2to1-padded"),
+    pytest.param(1024, 1, 2, 2, 128, True, None, None, None, id="d128-s1024-chosen"),
+    pytest.param(1024, 1, 2, 2, 128, True, 512, 256, None, id="d128-s1024-q512-k256"),
+    pytest.param(512, 2, 2, 2, 64, False, 256, 128, None, id="padded-q256-k128"),
+    pytest.param(512, 2, 2, 2, 64, False, 128, 512, None, id="padded-q128-k512"),
+    # a window, the backward's tiles forced: the dk / dv call's second
+    # bound (_last_query) and the dq call's (_first_key), grouped 8 to 1 as
+    # the 32 query heads on 4 K/V heads that bring it
+    pytest.param(512, 1, 2, 2, 64, True, 128, 128, 64, id="window64-q128-k128"),
+    pytest.param(512, 1, 2, 2, 64, True, 128, 128, 128, id="window128-q128-k128"),
+    pytest.param(512, 1, 2, 2, 64, True, 256, 128, 200, id="window200-q256-k128"),
+    pytest.param(512, 1, 2, 2, 64, True, 128, 256, 200, id="window200-q128-k256"),
+    pytest.param(512, 1, 8, 1, 64, True, 128, 128, 200, id="window200-grouped-8to1"),
+    pytest.param(512, 1, 32, 4, 128, True, None, None, 256, id="window256-32on4-d128-chosen"),
+    pytest.param(512, 1, 2, 2, 64, True, 512, 512, 100, id="window100-one-call"),
+    pytest.param(512, 1, 2, 2, 64, True, 128, 128, 512, id="window512-the-sequence"),
 ]
 
 
-@pytest.mark.parametrize("seq,b,h,h_kv,d,causal,block_q,block_k",
+@pytest.mark.parametrize("seq,b,h,h_kv,d,causal,block_q,block_k,window",
                          BACKWARD_CASES)
 def test_backward_tiles_match_reference_gradients(monkeypatch, seq, b, h,
                                                   h_kv, d, causal, block_q,
-                                                  block_k):
+                                                  block_k, window):
     """Whatever tiles the two backward kernels run at, chosen or forced:
     dq, dk and dv are the einsum attention's, dk and dv summed over the
     query heads a K/V head serves."""
@@ -245,9 +273,9 @@ def test_backward_tiles_match_reference_gradients(monkeypatch, seq, b, h,
     _, k, v = _qkv(22, s=seq, b=b, h=h_kv, d=d)
     mask = jnp.asarray(_padding(23, b, seq))
     got = _grads(lambda q, k, v: flash_attention(
-        q, k, v, mask=mask, causal=causal), q, k, v)
+        q, k, v, mask=mask, causal=causal, window=window), q, k, v)
     want = _grads(lambda q, k, v: _ref_grouped(
-        q, k, v, mask=mask, causal=causal), q, k, v)
+        q, k, v, mask=mask, causal=causal, window=window), q, k, v)
     for g, w, name in zip(got, want, "qkv"):
         assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -308,10 +336,13 @@ def test_backward_tiles_are_pinned_and_fit_the_budget(seq, head_dim,
     assert not causal or got[1] <= got[0]
 
 
-def _live_steps(seq, block_q, block_k):
-    """Grid steps of one head's causal backward that compute, by the
-    definition: the tile's last query row sees its first key."""
+def _live_steps(seq, block_q, block_k, window=None):
+    """Grid steps of one head's causal call that compute, by the
+    definition: the tile's last query row sees its first key and, under a
+    window, its first row's window reaches its last key."""
     return sum((i + 1) * block_q - 1 >= j * block_k
+               and (window is None
+                    or i * block_q - (window - 1) <= (j + 1) * block_k - 1)
                for i in range(seq // block_q) for j in range(seq // block_k))
 
 
@@ -427,27 +458,39 @@ def test_block_divisibility_validated():
         flash_attention(q, k, v)
 
 
-@pytest.mark.parametrize("h_kv", [4, 2])
-def test_under_a_mesh_the_kernel_runs_sharded_and_agrees(h_kv):
+@pytest.mark.parametrize("h_kv,window", [(4, None), (2, None), (2, 40)],
+                         ids=["h_kv4", "h_kv2", "h_kv2-window40"])
+def test_under_a_mesh_the_kernel_runs_sharded_and_agrees(h_kv, window):
     """GSPMD cannot partition a Mosaic kernel, so under ps.init's mesh the
     call goes through shard_map, the backward's calls with it: batch over
     'data' and the K/V heads over 'model' where they divide, replicated
     where they do not — same values and gradients as the plain call either
-    way, on as many K/V heads as query heads or on half."""
+    way, on as many K/V heads as query heads or on half, and under a
+    window."""
     import ps_tpu as ps
 
     q, _, _ = _qkv(8, s=128)  # B=2, H=4
     _, k, v = _qkv(9, s=128, h=h_kv)
     mask = np.ones((B, 128), np.int32)
-    mask[1, 70:] = 0
+    # under the window no row may lose every key it sees to the padding (a
+    # degenerate row: _padding's docstring)
+    mask[1, (70 if window is None else 100):] = 0
     mask = jnp.asarray(mask)
 
     def value_and_grads():
         return jax.value_and_grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, mask=mask) ** 2),
+            flash_attention(q, k, v, mask=mask, causal=window is not None,
+                            window=window) ** 2),
             argnums=(0, 1, 2))(q, k, v)
 
     want = value_and_grads()
+    if window is not None:
+        ref = jax.value_and_grad(lambda q, k, v: jnp.sum(_ref_grouped(
+            q, k, v, mask=mask, causal=True, window=window) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=5e-4,
+                                                    atol=5e-4), want, ref)
     for mesh_shape in ({"data": 2, "model": 4}, {"data": 8}):
         ps.init(backend="tpu", mesh_shape=mesh_shape)  # 8 cannot divide B
         got = jax.jit(value_and_grads)()
@@ -480,3 +523,133 @@ def test_bert_flash_matches_full():
         rtol=2e-4, atol=2e-4,
     )
     ps.shutdown()
+
+# -- attention that sees a window ---------------------------------------------
+
+@pytest.mark.parametrize("block_q,block_k", [
+    (128, 128), (256, 128), (128, 256), (512, 128), (128, 512), (512, 512),
+    (1024, 512)])
+@pytest.mark.parametrize("window", [1, 64, 128, 200, 512, 2047])
+def test_window_bounds_are_the_skips_and_the_clamps(block_q, block_k, window):
+    """_first_key beside _last_live (forward, dq) and _last_query beside
+    _first_live (dk / dv): a tile is live exactly when some row of it sees
+    some key of it, from either side, and the clamped index of a dead step
+    names a live block."""
+    seq = 2048
+    num_q, num_k = seq // block_q, seq // block_k
+    live = 0
+    for i in range(num_q):
+        first, last = (int(_first_key(i, block_q, block_k, window)),
+                       _last_live(i, block_q, block_k))
+        assert 0 <= first <= last < num_k
+        for j in range(num_k):
+            # the band crosses the tile: its first row's window reaches the
+            # tile's last key, and its last row reaches the tile's first
+            seen = (i * block_q - (window - 1) <= (j + 1) * block_k - 1
+                    and (i + 1) * block_q - 1 >= j * block_k)
+            assert (first <= j <= last) == seen
+            lo = _first_live(j, block_q, block_k)
+            hi = int(_last_query(j, block_q, block_k, window, num_q))
+            assert lo <= hi < num_q
+            assert (lo <= i <= hi) == seen
+            assert (min(max(j, first), last) == j) == seen
+            assert (min(max(i, lo), hi) == i) == seen
+            live += seen
+    assert _live_steps(seq, block_q, block_k, window) == live
+
+
+@pytest.mark.parametrize("seq,window,live,causal_live", [
+    # trinity-mini.s16384.b1.zipf's windowed layers at the forward's
+    # (1024, 1024): three key blocks a query block
+    (16384, 2048, 45, 136),
+    (16384, 512, 31, 136),
+    (4096, 2048, 9, 10),
+])
+def test_a_windowed_calls_live_steps_are_pinned(seq, window, live,
+                                                causal_live):
+    """The tiles come from the shapes alone, the causal call's (the wide
+    block won at every window measured on the chip), and the share of the
+    causal grid's live steps that the second bound leaves is a pure function
+    of them and the window."""
+    tiles = forward_tiles(seq, 128, 2, True)
+    assert tiles == (1024, 1024)
+    assert _live_steps(seq, *tiles, window) == live
+    assert _live_steps(seq, *tiles) == causal_live
+    # the benchmark's own count of the same share
+    from benchmark.families.trinity_step import live_step_share
+    assert live_step_share(seq, window, tiles) == live / causal_live
+
+
+def test_window_with_values_of_their_own_width():
+    """Keys 96 wide, values 64, 4 query heads on 2 K/V heads, a window off
+    every block's edge: the output and the three gradients."""
+    seq, window = 512, 200
+    rng = np.random.default_rng(31)
+    q = jnp.asarray(rng.normal(0, 1, (1, seq, 4, 96)).astype(np.float32))
+    k = jnp.asarray(rng.normal(0, 1, (1, seq, 2, 96)).astype(np.float32))
+    v = jnp.asarray(rng.normal(0, 1, (1, seq, 2, 64)).astype(np.float32))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=128, block_k=128)
+
+    def ref(q, k, v):
+        return _ref_grouped(q, k, v, causal=True, window=window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)), rtol=2e-5, atol=2e-5)
+    for g, w, name in zip(_grads(flash, q, k, v), _grads(ref, q, k, v),
+                          "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=5e-4,
+                                   atol=5e-4, err_msg=name)
+
+
+def test_window_past_the_sequence_is_the_causal_program():
+    q, k, v = _qkv(33, s=512, b=1, h=2)
+
+    def jaxpr(**kw):
+        return str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, causal=True, **kw)), argnums=(0, 1, 2)))(
+                q, k, v))
+
+    assert jaxpr(window=512) == jaxpr(window=4096) == jaxpr()
+    assert jaxpr(window=511) != jaxpr()
+
+
+def test_window_is_refused_without_causal_or_below_one():
+    q, k, v = _qkv(34)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=64)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=True, window=0)
+
+
+# sha256 (first 16 hex digits) of the text of value_and_grad's jaxpr at
+# window=None, addresses struck out, as the tree before the window gave it
+# (commit 79ddf58, jax 0.9.0): [B, S, h, d] bf16, K/V heads, v's width, causal
+PROGRAMS_BEFORE_THE_WINDOW = {
+    "bert": ((2, 512, 12, 64), 12, 64, False, "c2b5c6db6099ca06"),
+    "olmoe": ((1, 4096, 16, 128), 16, 128, True, "6cf1771998371f68"),
+    "kimi": ((1, 1024, 4, 192), 4, 128, True, "4f8113987517734f"),
+    "lfm2": ((1, 1024, 8, 64), 2, 64, True, "f8e821b91e8d6443"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PROGRAMS_BEFORE_THE_WINDOW))
+def test_without_a_window_the_program_is_the_one_it_was(cell):
+    """``window=None`` traces, forward and backward, to the jaxpr the
+    kernels gave before they knew a window: the six flash cells run the
+    program they ran."""
+    import hashlib
+    import re
+
+    shape, h_kv, d_v, causal, want = PROGRAMS_BEFORE_THE_WINDOW[cell]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct(shape[:2] + (h_kv, shape[3]), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:2] + (h_kv, d_v), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, interpret=False).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, k, v))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

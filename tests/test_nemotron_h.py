@@ -771,9 +771,9 @@ def test_the_cell_is_what_issue_39_named():
             if kimis.get(k) != ours.get(k)} == {"loss_step", "loss_step_why"}
     assert [w["name"] for w in manifest["workloads"]
             if w["config"] == cell["config"]] == [CELL]
-    assert manifest["workloads"][-1] == cell
-    entry = manifest["configs"][-1]
-    assert entry["name"] == cell["config"] and entry["file"] == CONFIG
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == cell["config"])
+    assert entry["file"] == CONFIG
     assert entry["source"] == ("https://huggingface.co/nvidia/NVIDIA-Nemotron"
                                "-3-Super-120B-A12B-BF16/blob/main/config.json")
     assert set(entry["reduced"]) == {
@@ -782,7 +782,9 @@ def test_the_cell_is_what_issue_39_named():
         "n_routed_experts", "vocab_size", "num_nextn_predict_layers"}
     listed = [m for m in manifest["per_layer"]
               if m["name"].startswith("nemo.")]
-    assert len(listed) == 17 and manifest["per_layer"][-17:] == listed
+    first = manifest["per_layer"].index(listed[0])
+    assert len(listed) == 17 \
+        and manifest["per_layer"][first:first + 17] == listed
     assert all(m["workloads"] == [CELL] for m in listed)
     assert {m["name"] for m in listed} \
         == set(nemo_metrics.SCOPE_METRICS.values()) | {
@@ -828,7 +830,8 @@ def test_configuration_holds_the_published_widths():
     was = config["published"]
     assert {k: (was[k], config[k]) for k in cut} == cut
     assert set(was) == set(cut) | {"hybrid_override_pattern"} == set(
-        _json("BENCHMARK.json")["configs"][-1]["reduced"])
+        next(c for c in _json("BENCHMARK.json")["configs"]
+             if c["name"] == "nemotron-3-super-120b-a12b")["reduced"])
     # one whole period, published layers 28-38 counted from 1
     pattern = was["hybrid_override_pattern"]
     assert len(pattern) == 88 and (pattern.count("M"), pattern.count("E"),

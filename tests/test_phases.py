@@ -27,6 +27,7 @@ from benchmark.layer_metrics import (host, kimi as kimi_metrics,
                                      lfm2 as lfm2_metrics, moe,
                                      nemo as nemo_metrics, scope)
 from benchmark.layer_metrics import setup as setup_metrics
+from benchmark.layer_metrics import trinity as trinity_metrics
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
 from ps_tpu.kv.sparse import SparseEmbedding
@@ -576,6 +577,140 @@ def test_nemo_reader_on_a_hand_made_result(monkeypatch):
     r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
     assert nemo_metrics.scope_times(r, {}) == {}
     assert nemo_metrics.read({"counters": {}, "facts": {}}) == {}
+
+
+def _trinity_step():
+    """``(run, batch)`` of ``make_step(has_aux=True)`` on a tiny Trinity: a
+    dense layer and an expert layer with two of eight held, the first seeing
+    a window of 16 keys, the second every earlier key."""
+    from ps_tpu.models import trinity
+
+    cfg = trinity.TrinityConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        layer_types=("sliding_attention", "full_attention"),
+        num_dense_layers=1, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, sliding_window=16, router_width=8, num_experts=2,
+        expert_start=2, num_experts_per_tok=3, dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: trinity.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    step = store.make_step(trinity.make_loss_fn(cfg), has_aux=True)
+    bias = trinity.init_expert_bias(cfg)
+    return (lambda batch: step(batch, bias),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_trinity_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Trinity adds to the scopes (``ps.attn/window``, ``ps.attn/full``,
+    ``ps.attn/gate``, all inside ``ps.attn``) beside the six it shares with
+    OLMoE, LFM2's ``ps.ffn`` and Kimi-Linear's ``ps.moe/shared``: each in the
+    lowered step's ``op_name``s under ``ps.grad``, forward and backward,
+    though every layer is under a ``jax.checkpoint``; the reader's copy is
+    equal."""
+    assert phases.TRINITY_SCOPES == trinity_metrics.TRINITY_SCOPES
+    assert phases.TRINITY_SCOPES[:6] == phases.MOE_SCOPES
+    for name in ("ATTN_WINDOW", "ATTN_FULL", "ATTN_GATE", "FFN", "MOE_SHARED",
+                 "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE",
+                 "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(trinity_metrics, name)
+    assert not set(phases.TRINITY_SCOPES) & set(phases.DEVICE_PHASES)
+    assert set(trinity_metrics.SCOPE_METRICS) == set(phases.TRINITY_SCOPES)
+    monkeypatch.setitem(BUILDERS, "trinity", _trinity_step)
+    names = scope.op_names_of(_step_hlo("trinity"))
+    for s in phases.TRINITY_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {trinity_metrics.scope_of(own, n) for own, n in names.items()}
+    assert found == set(phases.TRINITY_SCOPES) | {None}
+    # the cores and the gate are the innermost scopes of their ops, and the
+    # attention's holds them
+    for inner in (trinity_metrics.ATTN_WINDOW, trinity_metrics.ATTN_FULL,
+                  trinity_metrics.ATTN_GATE):
+        assert trinity_metrics.scope_of(
+            "%fusion.1",
+            f"jit(f)/ps.grad/jvp()/checkpoint/ps.attn/{inner}/mul") == inner
+    assert trinity_metrics.scope_of(
+        "%fusion.2", "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/dot") \
+        == trinity_metrics.ATTN
+    assert trinity_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
+        == trinity_metrics.MOE_EXPERT
+
+
+def test_trinity_reader_on_a_hand_made_result(monkeypatch):
+    call = 'custom_call_target="tpu_custom_call"'
+    ops = {_ev("%qkv"): 0.004, _ev("%gate"): 0.002, _ev("%pack"): 0.001,
+           _ev("%band", "custom-call") + call: 0.008,
+           _ev("%triangle", "custom-call") + call: 0.010,
+           _ev("%dense"): 0.003, _ev("%shared"): 0.002,
+           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
+           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
+           _ev("%ce"): 0.005, _ev("%embed"): 0.001, _ev("%adam"): 0.007}
+    names = {"%qkv": "jit(f)/ps.grad/transpose(jvp())/ps.attn/dot_general",
+             "%gate": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/"
+                      "ps.attn/gate/mul",
+             "%pack": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/"
+                      "ps.attn/window/transpose",
+             "%band": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/"
+                      "ps.attn/window/pallas_call",
+             "%triangle": "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
+                          "ps.attn/ps.attn/full/pallas_call",
+             "%dense": "jit(f)/ps.grad/jvp()/checkpoint/ps.ffn/dot",
+             "%shared": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/shared/dot",
+             "%route": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/route/dot",
+             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
+             "%back": "jit(f)/ps.grad/transpose(jvp())/ps.moe/combine/gather",
+             "%ragged-dot-none.1": "ragged-dot-none",
+             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
+             "%embed": "jit(f)/ps.grad/jvp()/gather",
+             "%adam": "jit(f)/ps.apply/mul"}
+    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
+         "counters": {"trinity_live_pairs_per_step": 1000.0,
+                      "trinity_held_pair_share": 0.125,
+                      "trinity_load_max_over_mean": 3.0,
+                      "trinity_dropped_tokens": 0.0},
+         "facts": {"kernel_targets": ["tpu_custom_call"],
+                   "trinity_flops_per_pair": 1e6,
+                   "trinity_dense_flops_per_step": 4e9,
+                   "trinity_window_flash_flops": 1e9,
+                   "trinity_window_flash_bytes": 1.0,
+                   "trinity_full_flash_flops": 1.0,
+                   "trinity_full_flash_bytes": 2e9,
+                   "trinity_window_live_step_share": 0.284},
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
+         "steps": 10, "window_s": 1.0}
+    out = trinity_metrics.scope_times(r, names)
+    assert out["trinity.attn_ms"] == pytest.approx(12.5)  # cores and gate in
+    assert out["trinity.window_core_ms"] == pytest.approx(4.5)
+    assert out["trinity.full_core_ms"] == pytest.approx(5.0)
+    assert out["trinity.attn_gate_ms"] == pytest.approx(1.0)
+    assert out["trinity.dense_ffn_ms"] == pytest.approx(1.5)
+    assert out["trinity.shared_ffn_ms"] == pytest.approx(1.0)
+    assert out["trinity.route_ms"] == pytest.approx(0.5)
+    assert out["trinity.dispatch_ms"] == pytest.approx(2.0)   # with combine
+    assert out["trinity.expert_ms"] == pytest.approx(4.0)
+    assert out["trinity.head_ms"] == pytest.approx(2.5)
+    # the kernels alone in the denominators, each kind over its own calls
+    assert out["trinity.window_flash_roofline"] == pytest.approx(25.0)
+    assert out["trinity.full_flash_roofline"] == pytest.approx(40.0)
+    assert out["trinity.expert_mxu_share"] == pytest.approx(25.0)
+    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
+    whole = trinity_metrics.read(r)
+    assert whole["trinity.mfu"] == pytest.approx(5.0)  # 5e9 x 10 / s of 1e12
+    assert whole["trinity.window_live_step_share"] == 0.284
+    assert len([k for k in whole if k.startswith("trinity.")]) == 18
+    assert set(whole) == {m["name"] for m in json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "BENCHMARK.json")))["per_layer"] if m["name"].startswith("trinity.")}
+    # a program without the scopes, the counters or the grouped matmuls
+    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
+    assert trinity_metrics.scope_times(r, {}) == {}
+    assert trinity_metrics.read({"counters": {}, "facts": {}}) == {}
 
 
 def test_moe_reader_on_a_hand_made_result():

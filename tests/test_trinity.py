@@ -1,0 +1,638 @@
+"""Trinity (``ps_tpu/models/trinity.py``: attention of two kinds, a window
+with rotary positions or every earlier key without any, behind a sigmoid gate
+and between two norms) against its plain reference
+(``tests/trinity_reference.py``), at small sizes on the CPU, and the pieces of
+its benchmark family (``benchmark/families/trinity_step.py``): the limits of
+the step-0 checks, the operations from shapes, the configuration and the cell.
+"""
+
+import dataclasses
+import functools
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import trinity_reference as reference
+from benchmark.families import trinity_reference as benchmark_copy
+from benchmark.families import trinity_step
+from benchmark.layer_metrics import trinity as trinity_metrics
+from ps_tpu.models import trinity
+from ps_tpu.models.lm import make_attn_fn
+from ps_tpu.ops import moe
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+F32_TOL = 1e-5
+CELL = "trinity-mini.s16384.b1.zipf"
+CONFIG = "benchmark/configs/trinity-mini.json"
+WINDOWED, FULL = "sliding_attention", "full_attention"
+#: the cell's five-layer stack in small: a window of 48 keys in 128, 4 query
+#: heads on 2 K/V heads, 4 of 16 experts held (experts 4-7), 4 picks
+SIZES = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=32, num_hidden_layers=5,
+    layer_types=[WINDOWED] * 4 + [FULL], num_dense_layers=1,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    sliding_window=48, router_width=16, num_experts=4, expert_start=4,
+    num_experts_per_tok=4, num_shared_experts=1, route_norm=True,
+    route_scale=2.826, load_balance_coeff=1e-3, mup_enabled=True,
+    rms_norm_eps=1e-5, rope_theta=10000.0, n_group=1, topk_group=1,
+    rope_scaling=None, score_func="sigmoid", hidden_act="silu",
+    tie_word_embeddings=False, dtype="float32")
+
+
+def _setup(seed=0, batch=2, seq=128, **changes):
+    sizes = {**SIZES, **changes}
+    cfg = trinity.TrinityConfig.from_dict(sizes)
+    params = jax.jit(lambda k: trinity.init_params(k, cfg))(
+        jax.random.key(seed))
+    # away from the cell's 0.02: every layer then matters to the loss
+    params = jax.tree_util.tree_map(lambda x: 5 * x if x.ndim > 1 else x,
+                                    params)
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, sizes["vocab_size"],
+                       size=(batch, seq + 1)).astype(np.int32)
+    bias = jnp.asarray(0.1 * rng.normal(size=(
+        cfg.num_expert_layers, cfg.router_width)), jnp.float32)
+    return sizes, cfg, params, {"inputs": ids[:, :-1],
+                                "targets": ids[:, 1:]}, bias
+
+
+def _system(cfg, params, batch, bias, attn="full"):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            trinity.make_loss_fn(cfg, attn=attn), has_aux=True))(
+                params, batch, bias)
+
+
+def _plain(sizes, params, batch, bias, module=reference):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            lambda p: module.loss_fn(p, batch, bias, sizes), has_aux=True))(
+                params)
+
+
+@functools.lru_cache(maxsize=None)
+def _base():
+    sizes, cfg, params, batch, bias = _setup()
+    return sizes, cfg, params, batch, bias, _plain(sizes, params, batch, bias)
+
+
+def _rel(got, want):
+    return float(jnp.max(jnp.abs(got - want))
+                 / (jnp.max(jnp.abs(want)) + 1e-30))
+
+
+def _assert_grads_close(grads, ref_grads, tol=F32_TOL):
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, g), r in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
+        assert _rel(g, r) <= tol, (jax.tree_util.keystr(path), _rel(g, r))
+
+
+# -- the model against the reference ------------------------------------------
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_system_matches_reference(attn):
+    """Loss, logits, counts, the next bias and every gradient, for the
+    five-layer stack with four of sixteen experts held; with 'flash' the
+    windowed kernels forward and backward, K and V at their own head
+    count."""
+    sizes, cfg, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
+    (loss, aux), grads = _system(cfg, params, batch, bias, attn)
+    assert abs(float(loss) - float(ref_loss)) <= F32_TOL * float(ref_loss)
+    for name in ("expert_tokens", "held_tokens", "expert_bias"):
+        np.testing.assert_array_equal(np.asarray(aux[name]),
+                                      np.asarray(ref_aux[name]))
+    assert aux["expert_tokens"].shape == (4, 16)
+    assert aux["held_tokens"].shape == (4, 4)
+    assert aux["expert_windows"].shape == (4,)
+    assert np.all(np.asarray(aux["expert_tokens"]).sum(-1) == 2 * 128 * 4)
+    # every tensor has a gradient that is not nothing
+    assert all(float(jnp.max(jnp.abs(g))) > 0
+               for g in jax.tree_util.tree_leaves(ref_grads))
+    _assert_grads_close(grads, ref_grads)
+    with jax.default_matmul_precision("highest"):
+        hidden, *_ = trinity.apply(params, batch["inputs"], cfg, bias,
+                                   make_attn_fn(attn),
+                                   grouped=attn == "flash")
+        logits = trinity.logits_of(params, hidden, cfg)
+        want = reference.logits_fn(params, batch["inputs"], bias, sizes)
+    assert logits.shape == (2, 128, 256)
+    assert _rel(logits, want) <= F32_TOL
+
+
+def test_fused_step_matches_reference():
+    """Through ``KVStore.make_step(has_aux=True)`` with the bias as the
+    step's extra argument: the loss, the aux and, read from AdamW's first
+    moment behind a clip that does not bite, every gradient; then AdamW's
+    rule on the parameters. A batch of eight: the test mesh has eight
+    devices along ``data``."""
+    import optax
+
+    import ps_tpu as ps
+
+    sizes, cfg, params, batch, bias = _setup(seed=1, batch=8, seq=64)
+    (ref_loss, ref_aux), ref_grads = _plain(sizes, params, batch, bias)
+    rule = dict(learning_rate=1e-3, b1=0.9, b2=0.95, eps=1e-8,
+                weight_decay=0.1)
+    ps.init(backend="tpu")
+    try:
+        store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1e9,
+                           placement="replicated", **rule)
+        store.init(params)
+        step = store.make_step(trinity.make_loss_fn(cfg), has_aux=True)
+        with jax.default_matmul_precision("highest"):
+            loss, _, aux = step(store.shard_batch(batch), bias)
+        assert abs(float(loss) - float(ref_loss)) <= F32_TOL * float(ref_loss)
+        for name in ("expert_tokens", "held_tokens", "expert_bias"):
+            np.testing.assert_array_equal(np.asarray(aux[name]),
+                                          np.asarray(ref_aux[name]))
+        flat = jax.tree_util.tree_leaves_with_path(ref_grads)
+        assert len(flat) == len(store.keys())
+        for path, r in flat:
+            key = "/".join(p.key for p in path)
+            state = store.optimizer_state(key)
+            mu = optax.tree_utils.tree_get(state, "mu")
+            assert _rel(mu / 0.1, r) <= F32_TOL, key
+            before = functools.reduce(lambda t, p: t[p.key], path, params)
+            want = trinity_step.adamw_first_step(
+                before, mu, optax.tree_utils.tree_get(state, "nu"), **rule)
+            np.testing.assert_allclose(store.pull(key), want, atol=1e-6)
+    finally:
+        ps.shutdown()
+
+
+# -- what a layer sees, and whether it rotates --------------------------------
+
+def _attention_inputs(seed=5, seq=128):
+    sizes, cfg, params, _, _ = _setup(seed=seed)
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(1, seq, 64)), jnp.float32)
+    return sizes, cfg, params["layer2"]["attn"], x
+
+
+def _attend(cfg, lp, x, kind, attn="full"):
+    with jax.default_matmul_precision("highest"):
+        return trinity.attention_block(lp, x, cfg, kind, make_attn_fn(attn),
+                                       attn == "flash")[0]
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_a_windowed_layer_differs_from_a_full_one_on_the_pairs_past_the_window(
+        attn):
+    """Rows whose every earlier key is inside the window (the first 48) are
+    the rows of a layer whose window spans the sequence, to the bit of the
+    f32 tolerance; every later row differs. Both layers rotate: only what
+    they see differs."""
+    sizes, cfg, lp, x = _attention_inputs()
+    windowed = _attend(cfg, lp, x, WINDOWED, attn)
+    spanning = _attend(dataclasses.replace(cfg, sliding_window=128), lp, x,
+                       WINDOWED, attn)
+    window = sizes["sliding_window"]
+    np.testing.assert_allclose(windowed[:window], spanning[:window],
+                               rtol=1e-5, atol=1e-6)
+    apart = np.abs(np.asarray(windowed - spanning)).max(axis=-1)
+    assert (apart[window:] > 1e-4).all()
+    # and it is the reference's band
+    with jax.default_matmul_precision("highest"):
+        want = reference.attention(lp, x[0], WINDOWED, sizes)
+    np.testing.assert_allclose(windowed, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_the_full_layer_rotates_nothing_and_a_windowed_one_does(attn):
+    """The full layer's result is the reference's without a rotation and is
+    not the rotated one's; a windowed layer's the reverse."""
+    sizes, cfg, lp, x = _attention_inputs(seed=6)
+    # a window that spans the sequence: only the rotation tells the kinds
+    # apart
+    wide = {**sizes, "sliding_window": 128}
+    wide_cfg = dataclasses.replace(cfg, sliding_window=128)
+    with jax.default_matmul_precision("highest"):
+        plain = reference.attention(lp, x[0], FULL, wide)
+        rotated = reference.attention(lp, x[0], WINDOWED, wide)
+    assert float(jnp.max(jnp.abs(plain - rotated))) > 1e-3
+    full = _attend(wide_cfg, lp, x, FULL, attn)
+    windowed = _attend(wide_cfg, lp, x, WINDOWED, attn)
+    np.testing.assert_allclose(full, plain, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(windowed, rotated, rtol=1e-5, atol=1e-5)
+    assert float(jnp.max(jnp.abs(full - rotated))) > 1e-3
+    assert float(jnp.max(jnp.abs(windowed - plain))) > 1e-3
+
+
+def test_the_gate_and_each_of_the_four_norms_matter():
+    """The loss without the gate's sigmoid, or with any one of a layer's
+    four norms at another scale, is another loss: none of them is dead."""
+    sizes, cfg, params, batch, bias, ((ref_loss, _), _) = _base()
+
+    def loss_of(p):
+        (loss, _), _ = _system(cfg, p, batch, bias)
+        return float(loss)
+
+    assert loss_of(params) == pytest.approx(float(ref_loss), rel=1e-5)
+    for name in ("input_norm", "post_attn_norm", "pre_mlp_norm",
+                 "post_mlp_norm"):
+        changed = {**params, "layer1": {**params["layer1"], name: {
+            "scale": 2.0 * params["layer1"][name]["scale"]}}}
+        assert abs(loss_of(changed) - float(ref_loss)) > 1e-4, name
+    # a gate that is shut lets nothing through: the layer's attention is gone
+    shut = jax.tree_util.tree_map(lambda x: x, params)
+    shut["layer1"]["attn"]["gate"] = {"kernel": jnp.zeros_like(
+        params["layer1"]["attn"]["gate"]["kernel"])}
+    assert abs(loss_of(shut) - float(ref_loss)) > 1e-4
+
+
+# -- the shares ---------------------------------------------------------------
+
+def _w(rng, *shape, scale=0.2):
+    return jnp.asarray(scale * rng.normal(size=shape), jnp.float32)
+
+
+def _expert_layer(seed=3, tokens=96, experts=16):
+    sizes = {**SIZES, "router_width": experts, "num_experts": experts,
+             "expert_start": 0}
+    rng = np.random.default_rng(seed)
+    d, f, e = 64, 32, experts
+
+    def swiglu():
+        return {"w1": {"kernel": _w(rng, d, f)}, "w3": {"kernel": _w(rng, d, f)},
+                "w2": {"kernel": _w(rng, f, d)}}
+
+    lp = {"router": {"kernel": _w(rng, d, e, scale=0.3)},
+          "gate": _w(rng, e, d, f), "up": _w(rng, e, d, f),
+          "down": _w(rng, e, f, d), "shared": swiglu()}
+    x = _w(rng, 1, tokens, d, scale=1.0)
+    bias = _w(rng, e, scale=0.1)
+    return sizes, lp, x, bias
+
+
+def test_the_eight_expert_shares_add_up_with_the_shared_expert_counted_once():
+    """The expert layer run eight times, each holding two of the sixteen
+    experts under four picks a token: the routed parts and ONE shared expert
+    sum to the uncut reference layer; each share equals the reference's
+    share."""
+    sizes, lp, x, bias = _expert_layer()
+    with jax.default_matmul_precision("highest"):
+        whole, mask = reference.experts(lp, x[0], bias, sizes)
+        shared = reference.swiglu(lp["shared"], x[0])
+    routed = jnp.zeros_like(whole)
+    for start in range(0, 16, 2):
+        cut = {"num_experts": 2, "expert_start": start}
+        held = {**lp, **{n: lp[n][start:start + 2]
+                         for n in ("gate", "up", "down")}}
+        cfg = trinity.TrinityConfig.from_dict({**sizes, **cut})
+        with jax.default_matmul_precision("highest"):
+            out, routing = trinity.moe_block(held, x, cfg, bias)
+            want, _ = reference.experts(held, x[0], bias, {**sizes, **cut})
+        np.testing.assert_allclose(out[0], want, rtol=1e-5, atol=2e-5)
+        np.testing.assert_array_equal(
+            np.asarray(routing.counts), np.asarray(mask.sum(0), np.int32))
+        assert int(routing.group_sizes.sum()) == int(
+            mask[:, start:start + 2].sum()) == int(routing.live.sum())
+        # a token none of whose picks is held gets the shared expert alone
+        nothing = ~np.asarray(routing.live).any(axis=-1)
+        np.testing.assert_allclose(np.asarray(out[0])[nothing],
+                                   np.asarray(shared)[nothing], atol=1e-6)
+        routed = routed + (out[0] - shared)
+    assert float(jnp.max(jnp.abs(shared))) > 0.1
+    np.testing.assert_allclose(routed + shared, whole, rtol=1e-5, atol=5e-5)
+    # every share's output summed counts the shared expert eight times
+    assert float(jnp.max(jnp.abs(routed + 8 * shared - whole))) > 0.1
+
+
+def test_routing_is_sigmoid_top_k_renormalised_over_all_picks_and_scaled():
+    sizes, lp, x, bias = _expert_layer(seed=4)
+    cfg = trinity.TrinityConfig.from_dict(
+        {**sizes, "num_experts": 4, "expert_start": 8})
+    _, routing = trinity.moe_block(
+        {**lp, **{n: lp[n][8:12] for n in ("gate", "up", "down")}}, x, cfg,
+        bias)
+    scores = jax.nn.sigmoid(x[0] @ lp["router"]["kernel"])
+    _, picks = jax.lax.top_k(scores + bias, 4)
+    np.testing.assert_array_equal(np.sort(np.asarray(routing.experts), -1),
+                                  np.sort(np.asarray(picks), -1))
+    # the weights of a token's picks sum to route_scale, whatever is held
+    np.testing.assert_allclose(np.asarray(routing.weights).sum(-1), 2.826,
+                               rtol=1e-5)
+    assert routing.window.shape == (moe.window_rows(96, 4, 4, 16),)
+
+
+# -- the reference itself -----------------------------------------------------
+
+def test_the_two_copies_of_the_reference_are_equal():
+    """``tests/trinity_reference.py`` and the benchmark's own
+    ``benchmark/families/trinity_reference.py``: the same text, and loaded as
+    two modules the same values to the last bit."""
+    with open(reference.__file__) as f, open(benchmark_copy.__file__) as g:
+        text = f.read()
+        assert text == g.read()
+    assert "ps_tpu" not in text.split('"""')[2]     # no import of the program
+    assert "pallas" not in text.split('"""')[2]     # and no kernel
+    assert reference is not benchmark_copy
+    sizes, _, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
+    (loss, aux), grads = _plain(sizes, params, batch, bias, benchmark_copy)
+    assert float(loss) == float(ref_loss)
+    for name in ref_aux:
+        np.testing.assert_array_equal(np.asarray(aux[name]),
+                                      np.asarray(ref_aux[name]))
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(ref_grads)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_reference_in_blocks_as_in_one(monkeypatch):
+    """The reference's attention in blocks of query rows and its logits in
+    blocks of positions (what lets 16,384 positions fit on the chip) are the
+    attention and the logits in one block."""
+    sizes, _, params, batch, bias, ((ref_loss, _), ref_grads) = _base()
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 32)
+    monkeypatch.setattr(reference, "LOGIT_BLOCK", 64)
+    (loss, _), grads = _plain(sizes, params, batch, bias)
+    assert abs(float(loss) - float(ref_loss)) <= 1e-6 * float(ref_loss)
+    _assert_grads_close(grads, ref_grads)
+
+
+def test_the_references_mask_is_a_band_or_the_triangle():
+    """The explicit mask by its definition: row i of a windowed layer
+    averages the values of keys i - window + 1 .. i, of the full layer keys
+    0 .. i, where every score is equal."""
+    sizes, _, lp, x = _attention_inputs(seed=7, seq=64)
+    flat = {**lp, "q": {"kernel": jnp.zeros_like(lp["q"]["kernel"])},
+            # a gate of one half everywhere, an out projection that copies
+            "gate": {"kernel": jnp.zeros_like(lp["gate"]["kernel"])},
+            "out": {"kernel": jnp.eye(64, dtype=jnp.float32)}}
+    v = (x[0] @ lp["v"]["kernel"]).reshape(64, 2, 16)
+    v = jnp.repeat(v, 2, axis=1).reshape(64, 64)
+    for kind, reach in ((WINDOWED, 16), (FULL, 64)):
+        with jax.default_matmul_precision("highest"):
+            got = reference.attention(flat, x[0], kind,
+                                      {**sizes, "sliding_window": 16})
+        want = jnp.stack([0.5 * jnp.mean(v[max(0, i - reach + 1):i + 1], 0)
+                          for i in range(64)])
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_witness_grads_are_the_reference_gradients_of_those_leaves():
+    sizes, _, params, batch, bias, ((ref_loss, _), ref_grads) = _base()
+    assert set(trinity_step.GRAD_COSINE) == {
+        "layer1/attn/gate/kernel", "layer4/attn/q/kernel",
+        "layer2/attn/k/kernel", "layer3/moe/router/kernel",
+        "layer2/moe/gate", "layer1/moe/shared/w1/kernel",
+        "layer0/ffn/w1/kernel"}
+    with jax.default_matmul_precision("highest"):
+        (loss, _), grads = jax.jit(lambda p: reference.witness_grads(
+            p, batch, bias, sizes, trinity_step.GRAD_COSINE))(params)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-6)
+    for name, g in grads.items():
+        want = functools.reduce(lambda t, part: t[part], name.split("/"),
+                                ref_grads)
+        np.testing.assert_allclose(g, want, atol=1e-6)
+
+
+# -- the family's pieces ------------------------------------------------------
+
+def _step0_inputs(fault=None):
+    """What ``trinity_step.step0_checks`` reads, made by hand: two layers of
+    128 experts, witnesses whose gradient is the reference's, AdamW applied
+    by the rule; ``fault`` spoils one thing. The last five are what each
+    fault of ISSUE 41's 6 (a) does to a witness: the window ignored turns a
+    windowed layer's k, a rotation on the full layer its q, the gate left
+    out its gate's gradient to nothing, a norm left out rescales everything
+    upstream of it, and bf16 where the file says f32 flips picks."""
+    rng = np.random.default_rng(0)
+    rule = {"name": "adamw", "learning_rate": 4e-4, "b1": 0.9, "b2": 0.95,
+            "eps": 1e-8, "weight_decay": 0.1, "clip_by_global_norm": 1.0}
+    pairs, rate = 131072, 1e-3
+    counts = rng.multinomial(pairs, np.ones(128) / 128, size=2)
+    ref_counts = counts.copy()
+    if fault in ("routed_elsewhere", "bf16_router"):
+        counts[0, 0] += 1500         # over FLIP_SHARE of the pairs
+        counts[0, 1:101] -= 15
+    if fault == "dropped":
+        counts[1, 5] -= 1
+        ref_counts[1, 5] -= 1
+    bias = trinity_step.bias_by_sign_rule(counts, rate)
+    if fault == "bias":
+        bias[1, 7] += np.float32(1e-3)
+    got = {"expert_tokens": counts, "held_tokens": counts[:, :16],
+           "expert_bias": bias}
+    want = {"expert_tokens": ref_counts, "held_tokens": ref_counts[:, :16]}
+    witnesses = {}
+    scale = 0.5                      # the clip halved the gradient
+    turned = {"direction": "layer0/ffn/w1/kernel",
+              "window_ignored": "layer2/attn/k/kernel",
+              "full_layer_rotated": "layer4/attn/q/kernel"}
+    for name in trinity_step.GRAD_COSINE:
+        before = rng.normal(size=(16, 8)) * 0.02
+        ref_grad = rng.normal(size=(16, 8))
+        grad = ref_grad * scale
+        if turned.get(fault) == name:
+            grad = grad + 0.5 * scale * rng.normal(size=grad.shape)
+        if fault == "gate_left_out" and name == "layer1/attn/gate/kernel":
+            grad = 1e-9 * rng.normal(size=grad.shape)
+        if fault == "length" and name.endswith("router/kernel"):
+            grad = grad * 1.2
+        if fault == "norm_left_out" and name == "layer0/ffn/w1/kernel":
+            grad = grad * 1.5
+        mu, nu = (1 - rule["b1"]) * grad, (1 - rule["b2"]) * grad ** 2
+        after = trinity_step.adamw_first_step(before, mu, nu, **rule)
+        if fault == "apply" and name.endswith("attn/q/kernel"):
+            # the first moment applied without its bias correction
+            after = trinity_step.adamw_first_step(
+                before, (1 - rule["b1"]) * mu, nu, **rule)
+        witnesses[name] = {"before": before, "after": after, "mu": mu,
+                           "nu": nu, "reference_grad": ref_grad}
+    clipped = 1.3 if fault == "clip" else 1.0
+    return got, want, witnesses, clipped, rule, pairs, rate
+
+
+STEP0_FAULTS = {None: None,
+                "routed_elsewhere": "expert_counts_match_reference",
+                "dropped": "no_dropped_tokens",
+                "bias": "expert_bias_follows_sign_rule",
+                "direction": "gradient_matches_reference",
+                "length": "gradient_matches_reference",
+                "apply": "adamw_apply_matches_rule",
+                "clip": "gradient_clipped_to_global_norm",
+                "window_ignored": "gradient_matches_reference",
+                "full_layer_rotated": "gradient_matches_reference",
+                "gate_left_out": "gradient_matches_reference",
+                "norm_left_out": "gradient_matches_reference",
+                "bf16_router": "expert_counts_match_reference"}
+
+
+@pytest.mark.parametrize("fault", STEP0_FAULTS, ids=str)
+def test_step0_checks_name_the_fault(fault):
+    checks = trinity_step.step0_checks(*_step0_inputs(fault))["checks"]
+    failed = {name for name, ok in checks.items() if not ok}
+    assert failed == ({STEP0_FAULTS[fault]} if fault else set())
+
+
+def _json(path):
+    with open(os.path.join(_REPO, path)) as f:
+        return json.load(f)
+
+
+def test_the_cell_is_what_issue_41_named():
+    """One configuration, one cell on one chip under a traffic file of its
+    own, the eighteen ``trinity.*`` metrics, each with the cell as its
+    ``workloads``, at the end of their lists, and no other entry."""
+    manifest = _json("BENCHMARK.json")
+    cell = manifest["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        CELL, "trinity-mini", "s16384.b1.zipf.n96", 1)
+    traffic = _json("benchmark/traffic/s16384.b1.zipf.n96.json")
+    assert {k: traffic[k] for k in (
+        "per_chip_batch", "seq_len", "attn", "ids", "input", "pool",
+        "block_steps")} == {
+            "per_chip_batch": 1, "seq_len": 16384, "attn": "flash",
+            "ids": {"kind": "zipf", "s": 1.0}, "input": "direct",
+            "pool": "fresh", "block_steps": 2}
+    assert 2 <= traffic["warmup_steps"] <= 4
+    # the smallest of the four ISSUE 41 allows that spread under half the
+    # bound in two sets of six; not 48, so the file's name carries it
+    assert traffic["loss_step"] == 96 in trinity_step.LOSS_STEPS
+    assert "block_steps_why" in traffic and "loss_step_why" in traffic
+    assert [w["name"] for w in manifest["workloads"]
+            if w["config"] == "trinity-mini"] == [CELL]
+    assert len(manifest["workloads"]) == 12
+    entry = manifest["configs"][-1]
+    assert entry["name"] == "trinity-mini" and entry["file"] == CONFIG
+    assert entry["source"] == ("https://huggingface.co/arcee-ai/Trinity-Mini"
+                               "/blob/main/config.json")
+    assert entry["reduced"] == ["num_hidden_layers", "layer_types",
+                                "num_dense_layers", "num_experts",
+                                "vocab_size"]
+    listed = [m for m in manifest["per_layer"]
+              if m["name"].startswith("trinity.")]
+    assert len(listed) == 18 and manifest["per_layer"][-18:] == listed
+    assert all(m["workloads"] == [CELL] for m in listed)
+    assert {m["name"] for m in listed} \
+        == set(trinity_metrics.SCOPE_METRICS.values()) | {
+            "trinity.window_flash_roofline", "trinity.full_flash_roofline",
+            "trinity.window_live_step_share", "trinity.expert_mxu_share",
+            "trinity.mfu", "trinity.held_pair_share",
+            "trinity.load_max_over_mean", "trinity.dropped_tokens"}
+    assert {m["moves"] for m in listed} == {"throughput", "loss_at_n"}
+    four = [w for w in manifest["workloads"] if w["chips"] == 4]
+    assert len(four) == 2 <= max(1, len(manifest["workloads"]) // 4)
+
+
+def test_configuration_holds_the_published_widths():
+    """Every key of the catalog's ``config`` as published; the cuts and only
+    the cuts differ; 705,473,792 parameters in the store."""
+    config = _json(CONFIG)
+    published = {
+        "global_attn_every_n_layers": 4, "head_dim": 128,
+        "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 6144,
+        "load_balance_coeff": 0.001, "max_position_embeddings": 131072,
+        "model_type": "afmoe", "moe_intermediate_size": 1024,
+        "mup_enabled": True, "n_group": 1, "num_attention_heads": 32,
+        "num_expert_groups": 1, "num_experts_per_tok": 8,
+        "num_key_value_heads": 4, "num_limited_groups": 1,
+        "num_shared_experts": 1, "rms_norm_eps": 1e-05, "rope_scaling": None,
+        "rope_theta": 10000, "route_norm": True, "route_scale": 2.826,
+        "score_func": "sigmoid", "sliding_window": 2048,
+        "tie_word_embeddings": False, "topk_group": 1,
+        "use_grouped_mm": True}
+    assert {k: config[k] for k in published} == published
+    cut = {"num_hidden_layers": (32, 5), "num_dense_layers": (2, 1),
+           "num_experts": (128, 16), "vocab_size": (200192, 25024)}
+    was = config["published"]
+    assert {k: (was[k], config[k]) for k in cut} == cut
+    assert set(was) == set(cut) | {"layer_types"} == set(
+        _json("BENCHMARK.json")["configs"][-1]["reduced"])
+    # published layer 2 and then one whole period, published layers 5-8
+    types = was["layer_types"]
+    assert len(types) == 32 and (types.count(WINDOWED),
+                                 types.count(FULL)) == (24, 8)
+    assert types == ([WINDOWED] * 3 + [FULL]) * 8
+    assert config["layer_types"] == [types[1]] + types[4:8] \
+        == [WINDOWED] * 4 + [FULL]
+    assert (config["router_width"], config["expert_start"]) == (128, 0)
+    assert len(config["reduced"]) == 5 and len(config["assumed"]) >= 6
+    assert "eight chips share each layer" in config["deployment"]
+    cfg = trinity.TrinityConfig.from_dict(config)
+    assert (cfg.held, cfg.num_expert_layers, cfg.sliding_window,
+            cfg.route_scale) == ((0, 16), 4, 2048, 2.826)
+    shapes = jax.eval_shape(lambda k: trinity.init_params(k, cfg),
+                            jax.random.key(0))
+
+    def count(tree):
+        return sum(int(np.prod(x.shape))
+                   for x in jax.tree_util.tree_leaves(tree))
+
+    bias = int(np.prod(trinity.init_expert_bias(cfg).shape))
+    assert (count(shapes), bias) == (705_473_792, 512)
+    assert (count(shapes["layer0"]), count(shapes["layer1"]),
+            count(shapes["layer1"]["attn"])) == (65_020_160, 134_488_320,
+                                                 27_263_232)
+    assert count({k: shapes[k] for k in ("embed", "head", "final_norm")}) \
+        == 102_500_352
+    # the operations from shapes, at the cell's sizes: ISSUE 41's arithmetic
+    tokens = seq = 16384
+    assert trinity_step.seen_pairs(seq, 2048) == 31_458_304
+    assert trinity_step.seen_pairs(seq) == 134_225_920
+    assert trinity_step.seen_pairs(seq, 16384) == 134_225_920
+    live = 4 * tokens * 8 / 8
+    assert moe.window_rows(tokens, 8, 16, 128) == 49152
+    flops = trinity_step.step_flops(config, tokens, seq, live)
+    assert flops == pytest.approx(3 * 13.3e12, rel=0.01)
+    assert trinity_step.pair_flops(config) == 18 * 2048 * 1024
+    band, _ = trinity_step.flash_cost(1, 32, 4, seq, 128, 1, 2048)
+    triangle, _ = trinity_step.flash_cost(1, 32, 4, seq, 128, 1)
+    assert band == 31_458_304 * 32 * 2304
+    assert triangle == 134_225_920 * 32 * 2304
+    # the band at the kernel's (1024, 1024): 45 of the triangle's 136 live
+    # steps, where the pairs are 23.4%
+    assert trinity_step.live_step_share(seq, 2048, (1024, 1024)) \
+        == pytest.approx(45 / 136)
+    assert trinity_step.live_step_share(seq, 2048, (512, 512)) \
+        == pytest.approx(150 / 528)
+
+
+@pytest.mark.parametrize("change", [
+    {"n_group": 8}, {"topk_group": 4}, {"num_expert_groups": 2},
+    {"num_limited_groups": 2},
+    {"rope_scaling": {"type": "yarn", "factor": 4}},
+    {"score_func": "softmax"}, {"hidden_act": "gelu"},
+    {"tie_word_embeddings": True}, {"mup_enabled": False},
+    {"layer_types": [WINDOWED] * 4 + ["chunked_attention"]},
+    {"layer_types": [WINDOWED] * 4}],
+    ids=lambda c: "{}={}".format(*next(iter(c.items())))[:40])
+def test_config_refuses_what_the_model_does_not_compute(change):
+    with pytest.raises(ValueError):
+        trinity.TrinityConfig.from_dict({**SIZES, **change})
+
+
+def test_family_refuses_a_pool_it_would_have_to_cycle():
+    config = _json(CONFIG)
+    traffic = _json("benchmark/traffic/s16384.b1.zipf.n96.json")
+    with pytest.raises(ValueError, match="re-uses no batch"):
+        trinity_step.build(config, {**traffic, "pool": 16}, 1, 0)
+
+
+def test_benchmark_command_rehearses_the_cell():
+    """The benchmark's own command on the CPU: the cell's control flow at
+    the tiny sizes, ``correct`` with every step-0 check, all eighteen
+    ``trinity.*`` metrics listed and none of another configuration's."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
+                        "JAX_COMPILATION_CACHE_DIR")}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "benchmark", "run.py"),
+         "--workload", CELL, "--rehearse", "--trace", "1", "--seconds", "1"],
+        env=env, cwd=_REPO, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] and line["metrics"] == {}
+    listed = {m["name"] for m in _json("BENCHMARK.json")["per_layer"]
+              if m["name"].startswith("trinity.")}
+    assert len(listed) == 18 and listed <= set(line["rehearsed"])
+    assert not {n for n in line["rehearsed"]
+                if n.split(".")[0] in ("kimi", "lfm2", "moe", "nemo")}
