@@ -47,11 +47,15 @@ are computed here:
   shared expert** at the model's width, whole on every chip.
 - a final RMSNorm and an untied head.
 
-Every layer runs under one ``jax.checkpoint``: between layers only the
-residual stream lives on (8 KB a token a layer in bf16), and a layer's
+Every layer runs under one ``jax.checkpoint``: between layers the residual
+stream lives on (8 KB a token a layer in bf16) and, of the attention layer's
+flash call, its output and logsumexp (``ops/flash_attention.py::KEPT``, the
+two residuals that only the forward kernel can produce: 8.4 MB in the cell),
+so the recomputation does not run that kernel a second time; a layer's
 working set (an expert layer's 22 picks a token, a window's row buffers at
 2,688 wide, the shared expert's 5,376) exists once, while that layer's
-gradient is computed.
+gradient is computed. The policy on ``_layer`` is the list of what a layer
+keeps; with ``attn='full'`` nothing bears a name and only the stream is kept.
 
 What the model does not compute, ``NemotronHConfig.from_dict`` refuses.
 
@@ -82,6 +86,7 @@ from ps_tpu.models.lm import make_attn_fn, token_ce
 from ps_tpu.models.olmoe import rms_norm
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
+from ps_tpu.ops.flash_attention import KEPT
 from ps_tpu.ops.gated_conv import conv_silu
 from ps_tpu.ops.ssd import ssd
 
@@ -341,7 +346,8 @@ def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
     return out.reshape(b, s, d), routing
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6))
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6),
+                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 def _layer(lp: Dict, x, bias, kind: str, config: NemotronHConfig,
            attn_fn: Callable, grouped: bool):
     """One layer, ``x + f(rms_norm(x))``, recomputed in the backward pass:

@@ -38,7 +38,12 @@ What differs here is how they are computed:
   whatever is live (``expected_rows``), plus the shared expert, whole on every
   chip of the group.
 - every layer is recomputed in the backward pass (one ``jax.checkpoint`` a
-  layer: between two layers only the stream lives on).
+  layer): between two layers the stream lives on and, of a flash call, the
+  two residuals that only its forward kernel can produce, its output and
+  logsumexp (``ops/flash_attention.py::KEPT``, 136 MB a layer in the cell),
+  so the recomputation does not run that kernel a second time. The policy on
+  ``_layer`` is the list of what a layer keeps; with ``attn='full'`` nothing
+  in a layer bears a name and nothing but the stream is kept.
 - a final RMSNorm and an untied head.
 
 Departures from the published model are the reference's (its docstring lists
@@ -72,6 +77,7 @@ from ps_tpu.models.lm import make_attn_fn, token_ce
 from ps_tpu.models.olmoe import rms_norm, rope
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
+from ps_tpu.ops.flash_attention import KEPT
 
 WINDOWED, FULL = "sliding_attention", "full_attention"
 
@@ -252,7 +258,8 @@ def moe_block(lp: Dict, x, config: TrinityConfig, bias):
     return out.reshape(b, s, d), routing
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6))
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6),
+                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 def _layer(lp: Dict, x, bias, kind: str, config: TrinityConfig,
            attn_fn: Callable, grouped: bool):
     """One layer, both branches between their two norms, recomputed in the

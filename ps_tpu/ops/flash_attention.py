@@ -104,9 +104,26 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   ``backward_tiles`` take no window): a narrower block puts more of what it
   computes inside the band but adds grid steps, and the wide one won at
   every window measured, 256 to 2,048 (the table below).
-  ``window=None`` is the program it was, to the jaxpr
-  (``tests/test_flash_attention.py`` pins it); a window that spans the
-  sequence is the causal call.
+  ``window=None`` is the program it was, to the jaxpr but for the two
+  names below (``tests/test_flash_attention.py`` pins it); a window that
+  spans the sequence is the causal call.
+
+- THE RESIDUALS' NAMES (``KEPT``). The custom VJP keeps ``(q, k, v, mask,
+  out, lse)``. Under a ``jax.checkpoint`` around the call none of the six
+  lives from forward to backward, and the backward pass runs the forward
+  kernel again only to get ``out`` and ``lse`` back: q, k and v are a
+  projection away, these two are the whole call away. So ``_flash_vjp_fwd``
+  passes the two through ``checkpoint_name`` before they enter the residual
+  tuple, and a checkpoint whose policy is ``save_only_these_names(*KEPT)``
+  keeps them (at [32, 16384, 128] bf16 134 MB and 2 MB a layer) and its
+  recomputation holds no forward call: ``models/trinity.py`` and
+  ``models/nemotron_h.py`` say so for their layers. What follows the call in
+  such a layer is then recomputed from the forward pass's own output: in
+  bf16 on the chip a second call reads re-rounded q, k, v and returns other
+  bits (``PERF.md`` §6, PR 42: the gradient moves by up to 0.14 of a leaf's
+  largest entry, towards the one no checkpoint gives). Under no checkpoint, or
+  one that does not list them, the names are identity and the optimized
+  program is the one it was (BERT, OLMoE, LFM2, Kimi-Linear).
 
 The padding mask is a [B, S] int/bool array (1 = attend), matching the
 BERT convention; causal, window and mask compose. Numerics: parity with the
@@ -172,9 +189,10 @@ getting faster (two key blocks of 1,024 a query block whatever the window:
 the floor of these tiles). Against the causal call on the same operands the
 windowed forward at 2,048 takes 43% of the time for 33% of the live steps
 and 23% of the pairs: the skip returns what the tiles let it. Inside the
-fused step (the cell's trace, seed 4100000501) a windowed layer's calls take
-36.3 ms (dk / dv 15.49, the forward twice for the layer's recomputation) and
-the full layer's 87.1 (dk / dv 33.88, dq 26.98, the forward 24.0 twice).
+fused step (the cell's traces, my chip runs, PR 42, seed 4200000101) a
+windowed layer's calls take 29.0 ms (the forward 7.31, dk / dv 12.39, dq
+9.33) and the full layer's 67.9 (19.19, 27.12, 21.58); a layer's
+``jax.checkpoint`` that does not keep ``KEPT`` runs each forward twice.
 
 All three gradients agree with an f32 einsum attention on the same bf16
 inputs within 1.0-1.3 roundoffs of their largest entry at every tile
@@ -198,6 +216,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -205,6 +224,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ps_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 _NEG_INF = -1e30
+
+# The names of the two residuals that only the forward call can produce, its
+# output and logsumexp, for the policy of a ``jax.checkpoint`` around the call
+# (the docstring's THE RESIDUALS' NAMES).
+KEPT = ("flash_out", "flash_lse")
 
 # What one grid step may hold in VMEM by forward_vmem_bytes' count, of the
 # 16 MiB Mosaic scopes to a kernel on a v5e by default. The count leaves out
@@ -831,6 +855,9 @@ def _flash_vjp_fwd(q, k, v, mask, scale, causal, window, block_q, block_k,
     out, lse = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
                           interpret=interpret)
+    # named on the variables the backward reads: a name on the call's
+    # result, outside the custom_vjp, leaves these two unnamed
+    out, lse = map(checkpoint_name, (out, lse), KEPT)
     return out, (q, k, v, mask, out, lse)
 
 

@@ -11,10 +11,12 @@ diagonals).
 import importlib
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jaxpr_tools import primitives
 from ps_tpu.models.lm import _full_attention
 from ps_tpu.ops import flash_attention
 from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_key,
@@ -387,17 +389,6 @@ def test_first_live_is_the_skip_and_the_clamp(block_q, block_k):
         assert first < seq // block_q
 
 
-def _primitives(jaxpr):
-    found = []
-    for eqn in jaxpr.eqns:
-        found.append(eqn.primitive.name)
-        if eqn.primitive.name == "pallas_call":
-            continue  # the kernel's own body
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _primitives(sub)
-    return found
-
-
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("seq,calls", [(512, 2), (2048, 3)])
 @pytest.mark.parametrize("block_q,block_k", [(None, None), (128, 128),
@@ -412,9 +403,38 @@ def test_vjp_is_pallas_calls_and_no_scan(block_q, block_k, seq, calls,
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k)),
         argnums=(0, 1, 2)))(q, k, v)
-    names = _primitives(jaxpr.jaxpr)
+    names = primitives(jaxpr.jaxpr)
     assert names.count("pallas_call") == calls
     assert not {"scan", "while"} & set(names)
+
+
+@pytest.mark.parametrize("window", [None, 256])
+@pytest.mark.parametrize("seq,calls", [(512, 2), (2048, 3)])
+def test_a_checkpoint_that_keeps_the_named_residuals_drops_the_forward_call(
+        seq, calls, window):
+    """Under a ``jax.checkpoint`` the backward pass runs the forward call
+    again only for its output and logsumexp; one whose policy keeps the two
+    by the names the kernel gives them (``KEPT``) holds one call fewer, and
+    its gradients are the same bits."""
+    assert (backward_tiles(seq, D, 4, True) == (seq, seq)) == (calls == 2)
+    q, k, v = _qkv(16, s=seq, b=1, h=2)
+
+    def grad(**checkpoint):
+        return jax.grad(jax.checkpoint(
+            lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+                q, k, v, causal=True, window=window))), **checkpoint),
+            argnums=(0, 1, 2))
+
+    plain, keeps = grad(), grad(
+        policy=jax.checkpoint_policies.save_only_these_names(*fa.KEPT))
+    for fn, want in ((plain, calls + 1), (keeps, calls)):
+        names = primitives(jax.make_jaxpr(fn)(q, k, v).jaxpr)
+        assert names.count("pallas_call") == want
+    for got, want, name in zip(jax.jit(keeps)(q, k, v),
+                               jax.jit(plain)(q, k, v), "qkv"):
+        assert float(jnp.max(jnp.abs(want))) > 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
 
 
 def test_matches_lm_full_attention_op():
@@ -635,11 +655,34 @@ PROGRAMS_BEFORE_THE_WINDOW = {
 }
 
 
+def _without_names(jaxpr):
+    """``jaxpr`` with its ``name`` equations taken out, each result read as
+    its operand, and the names they gave."""
+    alias, eqns, names = {}, [], []
+
+    def see(v):
+        if isinstance(v, jax.extend.core.Literal):
+            return v
+        return alias.get(v, v)
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            alias[eqn.outvars[0]] = see(eqn.invars[0])
+            names.append(eqn.params["name"])
+        else:
+            eqns.append(eqn.replace(invars=[see(v) for v in eqn.invars]))
+    return jaxpr.replace(eqns=eqns,
+                         outvars=[see(v) for v in jaxpr.outvars]), names
+
+
 @pytest.mark.parametrize("cell", sorted(PROGRAMS_BEFORE_THE_WINDOW))
-def test_without_a_window_the_program_is_the_one_it_was(cell):
+def test_without_a_window_the_program_is_the_one_it_was(monkeypatch, cell):
     """``window=None`` traces, forward and backward, to the jaxpr the
-    kernels gave before they knew a window: the six flash cells run the
-    program they ran."""
+    kernels gave before they knew a window, once the two residuals' names
+    (``KEPT``: identity outside a checkpoint that lists them) are left out:
+    the hash is of the trace with ``checkpoint_name`` made identity, and the
+    trace as it is differs from that one by two ``name`` equations a call
+    and nothing else."""
     import hashlib
     import re
 
@@ -647,9 +690,21 @@ def test_without_a_window_the_program_is_the_one_it_was(cell):
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     k = jax.ShapeDtypeStruct(shape[:2] + (h_kv, shape[3]), jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape[:2] + (h_kv, d_v), jnp.bfloat16)
-    text = str(jax.make_jaxpr(jax.value_and_grad(
-        lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=causal, interpret=False).astype(jnp.float32)),
-        argnums=(0, 1, 2)))(q, k, v))
-    text = re.sub(r"0x[0-9a-f]+", "0x", text)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+    def trace():
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=causal, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    def text(jaxpr):
+        return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+
+    named = trace()
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    plain = trace()
+    assert hashlib.sha256(text(plain).encode()).hexdigest()[:16] == want
+    stripped, names = _without_names(named.jaxpr)
+    assert tuple(names) == fa.KEPT
+    assert text(stripped) == text(plain.jaxpr)
+    assert text(named.jaxpr) != text(plain.jaxpr)

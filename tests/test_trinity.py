@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jaxpr_tools import layers_keep_the_flash_residuals_alone
 import trinity_reference as reference
 from benchmark.families import trinity_reference as benchmark_copy
 from benchmark.families import trinity_step
@@ -124,6 +125,19 @@ def test_system_matches_reference(attn):
         want = reference.logits_fn(params, batch["inputs"], bias, sizes)
     assert logits.shape == (2, 128, 256)
     assert _rel(logits, want) <= F32_TOL
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_a_layers_checkpoint_keeps_the_flash_residuals_and_nothing_else(
+        monkeypatch, attn):
+    """With 'flash' the loss's gradient holds three kernel calls an
+    attention layer, forward, dk / dv and dq, where a ``jax.checkpoint``
+    without a policy holds four, the forward run again for its output and
+    logsumexp; loss and every gradient are the same bits. With 'full'
+    nothing in a layer bears a name and the trace is the policy-less one."""
+    _, *loss_args = _setup()
+    layers_keep_the_flash_residuals_alone(monkeypatch, trinity, loss_args,
+                                          attn, attention_layers=5)
 
 
 def test_fused_step_matches_reference():
