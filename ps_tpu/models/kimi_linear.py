@@ -26,9 +26,12 @@ differs here is how they are computed:
   (``ops/kda.py``, chunks of 64); the gated per-head RMSNorm; the out
   projection. Taps, normalisation, gates, decays, state and norms in f32, the
   projections in ``dtype``. Everything between the projections is recomputed in
-  the backward pass (one ``jax.checkpoint``: the projections' outputs live on,
-  25 KB a token a layer in bf16, where q, k, v behind the taps and the f32
-  decays would be 41 more and the chunks' internals 130).
+  the backward pass but the rule's kernel call (one ``jax.checkpoint`` whose
+  policy keeps what only that call can produce, ``ops/kda.py::KEPT``: the
+  projections' outputs live on, 25 KB a token a layer in bf16, and the rule's
+  output, states and inverses, 49 KB, where q, k, v behind the taps and the
+  f32 decays would be 41 more and the chunks' internals 130; the plain form
+  of the rule bears no names and is recomputed whole).
 - ``mla_block``: K and V expanded from the normalised 512-wide latent, the 64
   position-free channels every head shares broadcast to the 32 heads and
   concatenated behind the head's own 128 (one 192-wide operand: the kernel
@@ -76,7 +79,7 @@ from ps_tpu.models.olmoe import rms_norm
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.gated_conv import conv_silu
-from ps_tpu.ops.kda import kda
+from ps_tpu.ops.kda import KEPT, kda
 
 
 @dataclasses.dataclass(frozen=True)
@@ -228,12 +231,14 @@ def init_expert_bias(config: KimiLinearConfig):
                      jnp.float32)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(2, 3))
+@functools.partial(jax.checkpoint, static_argnums=(2, 3),
+                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 def _kda_of(projected, weights, heads: int, eps: float):
     """Everything of the KDA mixer between its projections: ``projected``
     the activations behind q, k, v [B, S, H * K], the two low-rank gates'
     inner sides [B, S, r] and the write strength's logits [B, S, H];
-    ``weights`` the f32 leaves used here. Recomputed in the backward pass."""
+    ``weights`` the f32 leaves used here. Recomputed in the backward pass,
+    but the rule's forward kernel call, whose named residuals are kept."""
     q, k, v, f_inner, g_inner, b_logits = projected
     batch, seq = q.shape[:2]
 

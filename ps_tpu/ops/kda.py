@@ -50,22 +50,43 @@ values fill whole 128-lane tiles and the chunk is 64 (the benchmark's cell:
 ``ops/kda_mosaic.py`` behind a ``custom_vjp``: the state stays in VMEM
 across the chunks of a head, a chunk's internals live and die there, the
 operands are read as the projections write them ([B, T, H * K], no
-transposed f32 copy), and the forward rule keeps for the backward only the
-state entering each chunk and the chunk's inverse. Everything else (the
-tests' models at widths 16 and 32) runs the plain XLA form below, which is
-also the kernels' second oracle beside the token-by-token recurrence. One
-algorithm, the same arithmetic classes ("Precision"), no switch to set.
+transposed f32 copy), and the forward rule keeps for the backward, beside
+its operands, only the state entering each chunk and the chunk's inverse.
+Everything else (the tests' models at widths 16 and 32) runs the plain XLA
+form below, which is also the kernels' second oracle beside the
+token-by-token recurrence. One algorithm, the same arithmetic classes
+("Precision"), no switch to set.
+
+**The residuals' names (``KEPT``).** Under a ``jax.checkpoint`` around the
+call none of ``(q, k, v, g, beta, states, inverses)`` lives from forward to
+backward, and the backward pass runs the forward kernel again only to have
+the states, the inverses and the output ``o`` (which whatever follows the
+call is recomputed from): the operands are a projection and a few
+elementwise passes away, these three are the whole call away. So
+``_kda_kernel_fwd`` passes the three through ``checkpoint_name`` before they
+enter the residual tuple, as ``ops/flash_attention.py::_flash_vjp_fwd``
+does its two, and a checkpoint whose policy is
+``save_only_these_names(*KEPT)`` keeps them and recomputes no forward call:
+``models/kimi_linear.py::_kda_of`` says so for the mixer. At 32 heads of 128
+that is 49 KB a token a layer more between forward and backward (``o`` in
+bf16 8 KB, the states in f32 32 KB, the inverses 8 KB: 403 MB a layer at
+8,192 tokens) and one forward call of 11.4 ms a layer less. Under no
+checkpoint, or one that does not list them (``kda``'s own), the names are
+identity. The plain form has no ``custom_vjp`` and no names: a caller's
+policy recomputes it whole.
 
 **The plain form is differentiable by autodiff**: forty lines of
 ``jax.numpy`` that equal the token-by-token reference are differentiated
 right by construction (``tests/test_kimi_linear.py`` holds all five
 gradients of both realisations to the recurrence's). The whole op is under
-``jax.checkpoint``: between the layers only ``q, k, v, g, beta`` live on (40
-KB a token a layer where the chunk's internals would be 130), and the plain
-form's batched part runs in groups of ``GROUP`` chunks, each under
-``jax.checkpoint`` again, so that the [16, 16, K] pair tensors of one group
-are the most that is alive (34 MB at 32 heads and two chunks) and not those
-of all 128 chunks (2.1 GB).
+``jax.checkpoint`` (its own, ``checkpoint=True``, or the caller's wider
+one): under a policy-less one only ``q, k, v, g, beta`` live on between
+forward and backward (40 KB a token a layer where the chunk's internals
+would be 130), under one that lists ``KEPT`` those and the kernels' three
+(89 KB), and the plain form's batched part runs in groups of ``GROUP``
+chunks, each under ``jax.checkpoint`` again, so that the [16, 16, K] pair
+tensors of one group are the most that is alive (34 MB at 32 heads and two
+chunks) and not those of all 128 chunks (2.1 GB).
 
 Precision: every array in f32 (the inputs may be bf16: they are the
 configuration's compute dtype), the state carried in f32; the matmuls at the
@@ -104,7 +125,10 @@ fusion, at 1.6-2.1% of its roofline (HBM-bound, 5.6 ms a step).
 The kernels (my chip runs, PR 35; four calls chained in one program, so a
 call is 12 ms or more of device work behind one dispatch; the last column
 from the cell's traces). "Forward + backward" is the forward that keeps the
-states and the backward, what ``jax.grad`` of the op runs:
+states and the backward, what ``jax.grad`` of the op runs, and all the
+cell's step runs under a checkpoint that keeps ``KEPT`` (a policy-less one
+runs the forward a second time, and 5.0 ms a layer of copies and casts of
+its [8192, 4096] operands with it: the last two rows):
 
 | what | forward, ms | forward + backward, ms | ``kimi.kda_core_ms`` / ``step.device_ms`` |
 |---|---|---|---|
@@ -113,6 +137,7 @@ states and the backward, what ``jax.grad`` of the op runs:
 | + ``_solve``'s own rule with the inverse kept (the squarings are not transposed), the run sums in three exact passes, 2 heads | 12.07 | 26.03 | 145.38 / 568.25 |
 | the same with the masks handed in as tables, not made of iotas | 12.33 | 26.64 | not run |
 | **as landed: 4 heads a step** (2: 12.11 / 26.16) | **11.91** | **25.92** | **144.00 / 566.70** |
+| the same kernels, the mixer's checkpoint keeping ``KEPT`` (PR 44): 8 calls a step where there were 12, each at its time inside the step | 11.39 | 24.58 | **99.30 / 430.36** (my chip run, PR 44; 143.99 / 495.80 on the parent beside it) |
 
 Where a call's time goes (ablations of the 2-head form, forward / forward +
 backward, ms): the inverse's ten [64, 64] products at the highest precision
@@ -136,6 +161,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ps_tpu.ops import kda_mosaic
@@ -148,6 +174,10 @@ SUB = kda_mosaic.SUB
 #: recomputed) together
 GROUP = 2
 _HIGHEST = jax.lax.Precision.HIGHEST
+#: the names of what only the kernels' forward call can produce (its output,
+#: the state entering each chunk, each chunk's inverse): a caller's
+#: ``jax.checkpoint`` keeps them with ``save_only_these_names(*KEPT)``
+KEPT = ("kda_out", "kda_states", "kda_inverses")
 
 
 def masked_exp(x, keep):
@@ -280,7 +310,9 @@ def _kda_kernel(q, k, v, g, beta, chunk, mxu, interpret):
 def _kda_kernel_fwd(q, k, v, g, beta, chunk, mxu, interpret):
     out, kept = kda_mosaic.forward(q, k, v, g, beta, chunk=chunk, mxu=mxu,
                                    interpret=interpret, keep=True)
-    return out, (q, k, v, g, beta, kept)
+    # named on the variables the backward reads, as ``_flash_vjp_fwd`` does
+    out, states, inverses = map(checkpoint_name, (out, *kept), KEPT)
+    return out, (q, k, v, g, beta, (states, inverses))
 
 
 def _kda_kernel_bwd(chunk, mxu, interpret, res, do):
@@ -325,7 +357,11 @@ def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True,
     is the caller's to add and cut). ``checkpoint=False`` leaves the
     recomputation to a caller that has a wider ``jax.checkpoint`` of its own
     around the call (``models/kimi_linear.py``): two nested ones would run
-    the forward pass three times. ``path`` says which realisation runs;
+    the forward pass three times. That caller's policy decides what of the
+    kernels lives from forward to backward: ``save_only_these_names(*KEPT)``
+    keeps the output, states and inverses (49 KB a token a layer at 32 heads
+    of 128) and the forward call runs once; no policy keeps nothing and it
+    runs twice. ``path`` says which realisation runs;
     ``interpret`` is the kernels' (None: off the chip the same kernels run in
     interpret mode), and under ``ps_tpu.init``'s mesh they run in
     ``shard_map`` (``_under_mesh``)."""

@@ -6,18 +6,27 @@ import jax
 import numpy as np
 
 
-def primitives(jaxpr):
-    """The names of ``jaxpr``'s primitives in order, those of the programs
-    its equations hold included; a ``pallas_call`` counts as one, without
-    its kernel's own body."""
-    found = []
+def equations(jaxpr):
+    """``jaxpr``'s equations in order, those of the programs its equations
+    hold included, but a ``pallas_call``'s kernel body."""
     for eqn in jaxpr.eqns:
-        found.append(eqn.primitive.name)
+        yield eqn
         if eqn.primitive.name == "pallas_call":
             continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += primitives(sub)
-    return found
+            yield from equations(sub)
+
+
+def primitives(jaxpr):
+    """The names of ``jaxpr``'s primitives in order (``equations``); a
+    ``pallas_call`` counts as one."""
+    return [eqn.primitive.name for eqn in equations(jaxpr)]
+
+
+def checkpoint_names(jaxpr):
+    """The set of names ``checkpoint_name`` gave inside ``jaxpr``."""
+    return {eqn.params["name"] for eqn in equations(jaxpr)
+            if eqn.primitive.name == "name"}
 
 
 def layers_keep_the_flash_residuals_alone(monkeypatch, model, loss_args,

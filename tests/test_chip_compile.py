@@ -6,6 +6,7 @@ topology described inside a fixture: only one process may hold libtpu, and
 only the worker that is given this file loads it.
 """
 
+import functools
 import re
 
 import jax
@@ -126,6 +127,49 @@ def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
+        one_chip, no_compile_cache, monkeypatch):
+    """``models/kimi_linear.py::_kda_of`` at the cell's shape ([1, 8192,
+    4096] projections, 32 heads), value and gradient: its checkpoint keeps
+    the rule's output, states and inverses by name (``ops/kda.py::KEPT``),
+    so the program holds the forward call once and the backward call, where
+    a policy-less checkpoint holds a second forward (three calls and 3.43e9
+    B of temporaries; the value is asked for so that XLA cannot drop the
+    first). Temporaries 2.83e9 B: the three kept arrays are 0.40e9 of them,
+    the rest the recomputed taps, decays and gates in f32."""
+    from ps_tpu.models import kimi_linear
+
+    # ``_kda_of`` leaves ``interpret`` to ``jax.devices()``, the CPU's here
+    monkeypatch.setattr(kimi_linear, "kda",
+                        functools.partial(kda, interpret=False))
+    heads, width, rank, tokens = 32, 128, 128, (1, 8192)
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def projection(n):
+        return arg(*tokens, n, dtype=jnp.bfloat16)
+
+    wide = heads * width
+    projected = (projection(wide),) * 3 + (projection(rank),) * 2 \
+        + (projection(heads),)
+    weights = {**{f"{n}_conv": arg(wide, 4) for n in "qkv"},
+               "f_b": {"kernel": arg(rank, wide)},
+               "g_b": {"kernel": arg(rank, wide)}, "dt_bias": arg(wide),
+               "A_log": arg(heads), "out_norm": {"scale": arg(width)}}
+
+    def loss(projected, weights):
+        return jnp.sum(kimi_linear._kda_of(projected, weights, heads, 1e-5)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        projected, weights).compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert " while(" not in text

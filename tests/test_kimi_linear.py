@@ -29,6 +29,7 @@ import ps_tpu as ps
 from benchmark.families import kimi_reference as benchmark_copy
 from benchmark.families import kimi_step
 from benchmark.layer_metrics import kimi as kimi_metrics
+from jaxpr_tools import checkpoint_names, primitives
 from ps_tpu.models import kimi_linear
 from ps_tpu.models.lm import _full_attention, make_attn_fn
 from ps_tpu.ops import flash_attention, kda as kda_ops, moe
@@ -285,6 +286,87 @@ def test_under_a_mesh_the_kernels_run_sharded_and_agree():
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5,
                                                     atol=1e-5), got, want)
+
+
+@pytest.mark.parametrize("mesh_shape", [None, {"data": 2, "model": 2}],
+                         ids=["no_mesh", "data2_model2"])
+def test_a_checkpoint_that_keeps_the_named_residuals_drops_the_forward_call(
+        mesh_shape):
+    """Under a caller's ``jax.checkpoint`` the backward pass runs the
+    forward kernel again only for its output, states and inverses; one whose
+    policy keeps the three by the names the kernel gives them (``KEPT``)
+    holds one call fewer, inside ``shard_map`` as outside, and all five
+    gradients are the same bits."""
+    args = _path_inputs("kernel", 128)     # B = 2, H = 2
+
+    def grad(**checkpoint):
+        return jax.grad(jax.checkpoint(
+            lambda *a: jnp.sum(jnp.sin(kda_ops.kda(*a, checkpoint=False))),
+            **checkpoint), argnums=(0, 1, 2, 3, 4))
+
+    plain, keeps = grad(), grad(
+        policy=jax.checkpoint_policies.save_only_these_names(*kda_ops.KEPT))
+    if mesh_shape:
+        ps.init(backend="tpu", mesh_shape=mesh_shape)
+    for fn, calls in ((plain, 3), (keeps, 2)):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+        names = primitives(jaxpr)
+        assert names.count("pallas_call") == calls
+        assert ("shard_map" in names) == bool(mesh_shape)
+        assert checkpoint_names(jaxpr) == set(kda_ops.KEPT)
+    for got, want, name in zip(jax.jit(keeps)(*args), jax.jit(plain)(*args),
+                               "q k v g beta".split()):
+        assert float(jnp.max(jnp.abs(want))) > 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+
+
+def test_the_mixers_checkpoint_keeps_its_inputs_and_the_named_three(capsys):
+    """``models/kimi_linear.py::_kda_of`` at a kernel-path width: its
+    gradient holds the forward call once and the backward call, and what
+    lives from the forward pass to the backward pass is its inputs and the
+    kernel's three named residuals (``KEPT``), nothing of the taps, the
+    normalisation, the decays, the gates or the gated norm."""
+    heads, width, batch, seq, rank = 2, 128, 2, 128, 16
+    rng = np.random.default_rng(0)
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    wide = (batch, seq, heads * width)
+    projected = (normal(*wide), normal(*wide), normal(*wide),
+                 normal(batch, seq, rank), normal(batch, seq, rank),
+                 normal(batch, seq, heads))
+    weights = {**{f"{n}_conv": normal(heads * width, 4) for n in "qkv"},
+               "f_b": {"kernel": normal(rank, heads * width)},
+               "g_b": {"kernel": normal(rank, heads * width)},
+               "dt_bias": normal(heads * width),
+               "A_log": jnp.log(jnp.asarray([16.0, 1.0])),
+               "out_norm": {"scale": 1 + 0.1 * normal(width)}}
+
+    def loss(*args):  # linear in the mixer's output: it keeps nothing itself
+        return jnp.sum(kimi_linear._kda_of(*args, heads, 1e-5))
+
+    args = (projected, weights)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(*args).jaxpr
+    assert primitives(jaxpr).count("pallas_call") == 2
+    assert checkpoint_names(jaxpr) == set(kda_ops.KEPT)
+    jax.ad_checkpoint.print_saved_residuals(loss, *args)
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line]
+    chunks = seq // 64
+    # the output is read by the gated norm too, so jax rounds what it keeps
+    # of it (a ``reduce_precision`` that hides its name in this listing)
+    for line, (shape, what) in zip(kept, (
+            ((batch, seq, heads, width), "reduce_precision"),
+            ((batch, heads, chunks, width, width), "named 'kda_states'"),
+            ((batch, heads, chunks, 64, 64), "named 'kda_inverses'"))):
+        assert line.startswith(f"f32[{','.join(map(str, shape))}]"), line
+        assert what in line, line
+    assert len(kept) == 3, kept
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1)))(*args)
+    assert all(bool(jnp.all(jnp.isfinite(g))) and float(jnp.max(jnp.abs(g)))
+               for g in jax.tree_util.tree_leaves(grads))
 
 
 def test_the_shapes_alone_say_which_realisation_runs():
