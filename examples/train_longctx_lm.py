@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import ps_tpu as ps
 from ps_tpu.models import lm
+from ps_tpu.models.blocks import make_attn_fn
 from ps_tpu.parallel.mesh import parse_mesh
 from ps_tpu.utils import StepLogger, TrainMetrics
 
@@ -91,7 +92,7 @@ def main():
           f"attn={args.attn}, T={args.seq_len}")
 
     rules = lm.lm_partition_rules() if mesh_shape.get("model", 1) > 1 else None
-    attn_fn = lm.make_attn_fn(args.attn, mesh=ctx.mesh)
+    attn_fn = make_attn_fn(args.attn, mesh=ctx.mesh)
     if pp > 1:
         # heterogeneous dp x pp: blocks stack on 'pipe', embed/readout
         # stay dense (ps_tpu/models/lm.py) — parity vs non-pipelined is

@@ -1,0 +1,139 @@
+"""The blocks more than one model computes alike. No model imports another:
+a block two of them need stands here, a block one needs in that model's file.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable, Dict
+
+import jax
+import jax.numpy as jnp
+
+from ps_tpu.obs import phases
+from ps_tpu.ops import moe
+
+
+def rms_norm(x, scale, eps):
+    """Statistics in f32, result in ``x``'s dtype, as the published code."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (scale * xf).astype(x.dtype)
+
+
+def rope(x, theta):
+    """Rotary positions on ``x`` [B, S, h, d], halves rotated against each
+    other (``rotate_half``), angles in f32."""
+    seq, dim = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return (xf * cos + rotated * sin).astype(x.dtype)
+
+
+def _full_attention(q, k, v, causal=True, window=None, **_):
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if kv_heads != heads:
+        k, v = (jnp.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    if causal:
+        t = q.shape[1]
+        seen = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            # query i sees keys i - window < j <= i
+            seen = seen & ~jnp.tril(jnp.ones((t, t), bool), -window)
+        s = jnp.where(seen[None, None], s, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def make_attn_fn(attn: str = "full", mesh=None, **kw) -> Callable:
+    """'full' | 'flash' | 'ring' | 'ulysses'. 'flash' is the single-device
+    Pallas kernel (O(S) attention memory; seq must be a multiple of 128).
+    Both take ``k``, ``v`` [B, S, h_kv, d] at their own head count (the
+    kernel reads head ``h // group``, 'full' repeats them) and ``window=``
+    on a causal call (query i sees keys i - window < j <= i). 'ring' /
+    'ulysses' need a 'seq' mesh axis, activations sharded P(batch, 'seq')
+    and equal head counts."""
+    if attn == "full":
+        return _full_attention
+    if attn == "flash":
+        from ps_tpu.ops import flash_attention
+
+        def flash_fn(q, k, v, causal=True, window=None):
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   **kw)
+
+        return flash_fn
+    from ps_tpu.parallel import ring_attention, ulysses_attention
+
+    op = {"ring": ring_attention, "ulysses": ulysses_attention}[attn]
+
+    def fn(q, k, v, causal=True):
+        if k.shape[2] != q.shape[2]:
+            raise ValueError(f"make_attn_fn({attn!r}): {k.shape[2]} K/V heads "
+                             f"on {q.shape[2]} query heads, must be equal")
+        return op(q, k, v, mesh, causal=causal, **kw)
+
+    return fn
+
+
+def dense_ffn(lp: Dict, x):
+    """``W2(silu(W1 x) * W3 x)``."""
+    def w(name):
+        return lp[name]["kernel"].astype(x.dtype)
+
+    return (jax.nn.silu(x @ w("w1")) * (x @ w("w3"))) @ w("w2")
+
+
+def window_of(routing: moe.Routing, tokens, gate, up, down, *,
+              whole_window: bool):
+    """Dispatch, the held experts and combine over one window of
+    ``routing``'s sorted pairs: ``ops/moe.py::over_windows``' ``layer``.
+    ``up`` None: ungated ``relu(x W1)**2 W2`` experts, else SwiGLU.
+    ``whole_window``: the grouped matmuls do the whole window's work,
+    whatever is live."""
+    with jax.named_scope(phases.MOE_DISPATCH):
+        rows = moe.dispatch(tokens, routing)
+    with jax.named_scope(phases.MOE_EXPERT):
+        rows = moe.expert_ffn(
+            rows, gate, up, down, routing.group_sizes,
+            activation="relu2" if up is None else "swiglu",
+            expected_rows=rows.shape[0] if whole_window else None)
+    with jax.named_scope(phases.MOE_COMBINE):
+        return moe.combine(rows, routing)
+
+
+#: ``over_windows``' ``layer`` must be the same object from call to call
+LIVE_ROWS = functools.partial(window_of, whole_window=False)
+WHOLE_WINDOW = functools.partial(window_of, whole_window=True)
+
+
+@jax.checkpoint
+def experts_of(tokens, gate, up, down, routing: moe.Routing):
+    """Dispatch, the held SwiGLU experts and combine over the windows of the
+    held pairs, recomputed in the backward pass: between two layers only
+    ``tokens`` and ``routing`` live on. The stacks are cast to the tokens'
+    precision in here, so their copies are made again for the backward and
+    not kept from the forward (twelve of 38 MB in a Kimi step's peak)."""
+    return moe.over_windows(
+        LIVE_ROWS, routing, tokens,
+        *(stack.astype(tokens.dtype) for stack in (gate, up, down)))
+
+
+def init_expert_bias(config):
+    """The selection bias at step 0: zeros, one row an expert layer. The
+    share models re-export it: the benchmark imports it from each."""
+    return jnp.zeros((config.num_expert_layers, config.router_width),
+                     jnp.float32)
+
+
+def token_ce(logits, targets):
+    """Mean next-token CE in logsumexp form — no [B, T, V] f32
+    log-probability tensor is materialized (see bert.mlm_loss)."""
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), -1)
+    tok = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
+    return jnp.mean(lse - tok.astype(jnp.float32))

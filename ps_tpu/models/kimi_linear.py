@@ -11,13 +11,15 @@ routed one. As LFM2's, the expert layer holds a share of the experts:
 ``num_experts`` of ``router_width``, from ``expert_start`` on, one chip of an
 expert-parallel group without its exchange (``ops/moe.py``).
 
-Pure functions over a parameter dict, as ``models/lfm2.py``. A block is::
+Pure functions over a parameter dict, as ``models/lfm2.py``; ``rms_norm``,
+``dense_ffn`` and the checkpointed ``experts_of`` are ``models/blocks.py``'s.
+A block is::
 
     x += mixer(rms_norm(x));  x += ffn(rms_norm(x))
 
 and the equations of each part are written out in the plain reference's
-docstring (``tests/kimi_reference.py``), which this module is held to. What
-differs here is how they are computed:
+docstring (``benchmark/families/kimi_reference.py``), which this module is
+held to. What differs here is how they are computed:
 
 - ``kda_block``: q, k and v each through the four causal taps and the SiLU
   of ``ops/gated_conv.py::conv_silu``, q and k L2-normalised a head;
@@ -73,9 +75,9 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ps_tpu.models.lfm2 import _experts_of, dense_ffn
-from ps_tpu.models.lm import make_attn_fn, token_ce
-from ps_tpu.models.olmoe import rms_norm
+from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
+from ps_tpu.models.blocks import (dense_ffn, experts_of, make_attn_fn,
+                                  rms_norm, token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.gated_conv import conv_silu
@@ -225,12 +227,6 @@ def init_params(key, config: KimiLinearConfig) -> Dict:
     return params
 
 
-def init_expert_bias(config: KimiLinearConfig):
-    """The selection bias at step 0: zeros, one row an expert layer."""
-    return jnp.zeros((config.num_expert_layers, config.router_width),
-                     jnp.float32)
-
-
 @functools.partial(jax.checkpoint, static_argnums=(2, 3),
                    policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 def _kda_of(projected, weights, heads: int, eps: float):
@@ -259,7 +255,7 @@ def _kda_of(projected, weights, heads: int, eps: float):
         + weights["dt_bias"]))
     beta = jax.nn.sigmoid(b_logits.astype(jnp.float32))
     with jax.named_scope(phases.KDA_CORE):
-        o = kda(q, k, v, decay, beta, checkpoint=False)
+        o = kda(q, k, v, decay, beta)
     gate = head_wise(jnp.dot(g_inner.astype(jnp.float32),
                              weights["g_b"]["kernel"]))
     o = rms_norm(o.astype(jnp.float32), weights["out_norm"]["scale"], eps) \
@@ -315,7 +311,7 @@ def moe_block(lp: Dict, x, config: KimiLinearConfig, bias):
             tokens, lp["router"]["kernel"], c.num_experts_per_token,
             renormalize=c.moe_renormalize, scoring="sigmoid", bias=bias,
             renorm_eps=1e-20, scaling=c.routed_scaling_factor, held=c.held)
-    out = _experts_of(tokens, lp["gate"], lp["up"], lp["down"], routing)
+    out = experts_of(tokens, lp["gate"], lp["up"], lp["down"], routing)
     with jax.named_scope(phases.MOE_SHARED):
         out = out + dense_ffn(lp["shared"], tokens)
     return out.reshape(b, s, d), routing
@@ -360,7 +356,7 @@ def make_loss_fn(config: KimiLinearConfig, attn: str = "full", **attn_kw):
     """``loss_fn(params, batch, expert_bias) -> (loss, aux)`` for
     pre-shifted ``batch = {"inputs": [B, S], "targets": [B, S]}``, for
     ``KVStore.make_step(loss_fn, has_aux=True)``. ``attn`` is 'full' or
-    'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
+    'flash' (``models/blocks.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers, num_experts],
     those computed here; ``expert_windows`` [expert layers], the windows of

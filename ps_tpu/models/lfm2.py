@@ -7,8 +7,9 @@ whose layers differ, and its first expert layer that holds a share of the
 experts: ``num_experts`` of ``router_width``, from ``expert_start`` on, one
 chip of an expert-parallel group without its exchange (``ops/moe.py``).
 
-Pure functions over a parameter dict, as ``models/olmoe.py``, whose
-``rms_norm`` and ``rope`` these are. A block is::
+Pure functions over a parameter dict, as ``models/olmoe.py``; ``rms_norm``,
+``rope``, ``dense_ffn`` and the checkpointed ``experts_of`` are
+``models/blocks.py``'s. A block is::
 
     x += mixer(rms_norm(x));  x += ffn(rms_norm(x))
 
@@ -17,9 +18,9 @@ Pure functions over a parameter dict, as ``models/olmoe.py``, whose
 - ``full_attention`` mixer: q on ``num_attention_heads``, k and v on
   ``num_key_value_heads`` (no bias), RMSNorm over each head's own width on q
   and k, RoPE, causal softmax attention with each K/V head serving a group
-  of query heads, out projection. With ``attn='flash'`` K and V enter the
-  kernel at their own head count (``ops/flash_attention.py`` reads head
-  ``h // group``); any other attention gets them repeated.
+  of query heads, out projection. K and V go to the attention closure at
+  their own head count (``models/blocks.py::make_attn_fn``: 'flash' reads
+  head ``h // group`` where it lies, 'full' repeats them).
 - dense feed-forward, layers below ``num_dense_layers``:
   ``W2(silu(W1 h) * W3 h)`` of width ``intermediate_size``.
 - expert layer, the others: sigmoid scores in f32, the top
@@ -53,8 +54,9 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ps_tpu.models.lm import make_attn_fn, token_ce
-from ps_tpu.models.olmoe import rms_norm, rope
+from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
+from ps_tpu.models.blocks import (dense_ffn, experts_of, make_attn_fn,
+                                  rms_norm, rope, token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.gated_conv import gated_short_conv
@@ -167,12 +169,6 @@ def init_params(key, config: Lfm2Config) -> Dict:
     return params
 
 
-def init_expert_bias(config: Lfm2Config):
-    """The selection bias at step 0: zeros, one row an expert layer."""
-    return jnp.zeros((config.num_expert_layers, config.router_width),
-                     jnp.float32)
-
-
 def conv_block(lp: Dict, x):
     """The conv mixer on normed activations ``x`` [B, S, D]."""
     bcx = x @ lp["in_proj"]["kernel"].astype(x.dtype)
@@ -181,10 +177,9 @@ def conv_block(lp: Dict, x):
     return y @ lp["out_proj"]["kernel"].astype(x.dtype)
 
 
-def attention_block(lp: Dict, x, config: Lfm2Config, attn_fn: Callable,
-                    grouped: bool):
-    """Grouped-query attention of the normed activations ``x`` [B, S, D].
-    ``grouped``: ``attn_fn`` takes K and V at their own head count."""
+def attention_block(lp: Dict, x, config: Lfm2Config, attn_fn: Callable):
+    """Grouped-query attention of the normed activations ``x`` [B, S, D]:
+    K and V reach ``attn_fn`` at their own head count."""
     c = config
     b, s, d = x.shape
     heads, kv_heads = c.num_attention_heads, c.num_key_value_heads
@@ -196,39 +191,8 @@ def attention_block(lp: Dict, x, config: Lfm2Config, attn_fn: Callable,
     k = rms_norm(proj("k", kv_heads), lp["k_norm"]["scale"], c.norm_eps)
     v = proj("v", kv_heads)
     q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
-    if not grouped and kv_heads != heads:
-        k, v = (jnp.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
     a = attn_fn(q, k, v, causal=True)
     return a.reshape(b, s, d) @ lp["out"]["kernel"].astype(x.dtype)
-
-
-def dense_ffn(lp: Dict, x):
-    """``W2(silu(W1 x) * W3 x)``."""
-    def w(name):
-        return lp[name]["kernel"].astype(x.dtype)
-
-    return (jax.nn.silu(x @ w("w1")) * (x @ w("w3"))) @ w("w2")
-
-
-def _window_of(routing: moe.Routing, tokens, gate, up, down):
-    with jax.named_scope(phases.MOE_DISPATCH):
-        rows = moe.dispatch(tokens, routing)
-    with jax.named_scope(phases.MOE_EXPERT):
-        rows = moe.expert_ffn(rows, gate, up, down, routing.group_sizes)
-    with jax.named_scope(phases.MOE_COMBINE):
-        return moe.combine(rows, routing)
-
-
-@jax.checkpoint
-def _experts_of(tokens, gate, up, down, routing: moe.Routing):
-    """Dispatch, the held experts and combine over the windows of the held
-    pairs, recomputed in the backward pass: between two layers only
-    ``tokens`` and ``routing`` live on. The stacks are cast to the tokens'
-    precision in here, so their copies are made again for the backward and
-    not kept from the forward (twelve of 38 MB in a Kimi step's peak)."""
-    return moe.over_windows(
-        _window_of, routing, tokens,
-        *(stack.astype(tokens.dtype) for stack in (gate, up, down)))
 
 
 def moe_block(lp: Dict, x, config: Lfm2Config, bias):
@@ -243,12 +207,12 @@ def moe_block(lp: Dict, x, config: Lfm2Config, bias):
             tokens, lp["router"]["kernel"], c.num_experts_per_tok,
             renormalize=c.norm_topk_prob, scoring="sigmoid", bias=bias,
             renorm_eps=1e-6, scaling=c.routed_scaling_factor, held=c.held)
-    out = _experts_of(tokens, lp["gate"], lp["up"], lp["down"], routing)
+    out = experts_of(tokens, lp["gate"], lp["up"], lp["down"], routing)
     return out.reshape(b, s, d), routing
 
 
 def apply(params: Dict, tokens, config: Lfm2Config, expert_bias=None,
-          attn_fn: Callable = None, grouped: bool = False):
+          attn_fn: Callable = None):
     """``tokens`` [B, S] int32 -> (final hidden states [B, S, D] before the
     final norm, the list of each expert layer's ``Routing``)."""
     c = config
@@ -263,7 +227,7 @@ def apply(params: Dict, tokens, config: Lfm2Config, expert_bias=None,
                 x = x + conv_block(lp["conv"], h)
         else:
             with jax.named_scope(phases.ATTN):
-                x = x + attention_block(lp["attn"], h, c, attn_fn, grouped)
+                x = x + attention_block(lp["attn"], h, c, attn_fn)
         h = rms_norm(x, lp["ffn_norm"]["scale"], c.norm_eps)
         if i < c.num_dense_layers:
             with jax.named_scope(phases.FFN):
@@ -288,7 +252,7 @@ def make_loss_fn(config: Lfm2Config, attn: str = "full", **attn_kw):
     """``loss_fn(params, batch, expert_bias) -> (loss, aux)`` for
     pre-shifted ``batch = {"inputs": [B, S], "targets": [B, S]}``, for
     ``KVStore.make_step(loss_fn, has_aux=True)``. ``attn`` is 'full' or
-    'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
+    'flash' (``models/blocks.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers, num_experts],
     those computed here; ``expert_windows`` [expert layers], the windows of
@@ -298,7 +262,7 @@ def make_loss_fn(config: Lfm2Config, attn: str = "full", **attn_kw):
 
     def loss_fn(params, batch, expert_bias):
         hidden, routings = apply(params, batch["inputs"], config, expert_bias,
-                                 attn_fn, grouped=attn == "flash")
+                                 attn_fn)
         with jax.named_scope(phases.HEAD):
             ce = token_ce(logits_of(params, hidden, config),
                           batch["targets"])

@@ -2,12 +2,13 @@
 
 Written TPU-first as pure functions over a flat-friendly param dict (the
 same tree the PS store shards by key), so the Megatron partition rules in
-:func:`lm_partition_rules` apply verbatim and the attention op is pluggable:
-``attn='full'`` for single-device/small contexts, ``'ring'`` or ``'ulysses'``
-(ps_tpu/parallel/ring_attention.py) when activations are sharded over a
-'seq' mesh axis. Pre-norm blocks, learned positions, weight-tied readout —
-small on purpose: the model is the vehicle for the parallelism, the PS
-protocol around it is identical to every other workload.
+:func:`lm_partition_rules` apply verbatim and the attention op is pluggable
+(``models/blocks.py::make_attn_fn``): ``'full'`` for single-device/small
+contexts, ``'ring'`` or ``'ulysses'`` (ps_tpu/parallel/ring_attention.py)
+when activations are sharded over a 'seq' mesh axis. Pre-norm blocks,
+learned positions, weight-tied readout — small on purpose: the model is the
+vehicle for the parallelism, the PS protocol around it is identical to every
+other workload.
 """
 
 from __future__ import annotations
@@ -18,6 +19,8 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ps_tpu.models.blocks import _full_attention, token_ce
 
 
 def init_params(rng: np.random.Generator, *, vocab: int, d_model: int,
@@ -65,44 +68,6 @@ def lm_partition_rules():
 
 def _rmsnorm(x, scale):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
-
-
-def _full_attention(q, k, v, causal=True, window=None, **_):
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
-    if causal:
-        t = q.shape[1]
-        seen = jnp.tril(jnp.ones((t, t), bool))
-        if window is not None:
-            # query i sees keys i - window < j <= i
-            seen = seen & ~jnp.tril(jnp.ones((t, t), bool), -window)
-        s = jnp.where(seen[None, None], s, -1e30)
-    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-
-
-def make_attn_fn(attn: str = "full", mesh=None, **kw) -> Callable:
-    """'full' | 'flash' | 'ring' | 'ulysses'. 'flash' is the single-device
-    Pallas kernel (O(S) attention memory; seq must be a multiple of 128);
-    both take ``window=`` on a causal call (query i sees keys i - window <
-    j <= i), which 'flash' passes to the kernel. 'ring'/'ulysses' need a 'seq' mesh axis and activations sharded
-    P(batch, 'seq')."""
-    if attn == "full":
-        return _full_attention
-    if attn == "flash":
-        from ps_tpu.ops import flash_attention
-
-        def flash_fn(q, k, v, causal=True, window=None):
-            return flash_attention(q, k, v, causal=causal, window=window,
-                                   **kw)
-
-        return flash_fn
-    from ps_tpu.parallel import ring_attention, ulysses_attention
-
-    op = {"ring": ring_attention, "ulysses": ulysses_attention}[attn]
-
-    def fn(q, k, v, causal=True):
-        return op(q, k, v, mesh, causal=causal, **kw)
-
-    return fn
 
 
 def block_apply(lp: Dict, x: jax.Array, *, n_heads: int,
@@ -242,14 +207,6 @@ def make_loss_fn(*, n_heads: int, attn_fn: Callable = _full_attention):
         return token_ce(logits, batch["targets"])
 
     return loss_fn
-
-
-def token_ce(logits, targets):
-    """Mean next-token CE in logsumexp form — no [B, T, V] f32
-    log-probability tensor is materialized (see bert.mlm_loss)."""
-    lse = jax.nn.logsumexp(logits.astype(jnp.float32), -1)
-    tok = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
-    return jnp.mean(lse - tok.astype(jnp.float32))
 
 
 def lm_batches(batch_size: int, seq_len: int, *, vocab: int = 256,

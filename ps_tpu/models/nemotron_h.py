@@ -21,10 +21,11 @@ part; the shares of all chips add up to the uncut layer, with what every chip
 computes alike (the shared expert) counted once. Neither the sum nor the
 exchange of tokens is here, and nothing stands in for them.
 
-Pure functions over a parameter dict, as ``models/kimi_linear.py``. The
-equations of each part are written out in the plain reference's docstring
-(``tests/nemotron_h_reference.py``), which this module is held to. How they
-are computed here:
+Pure functions over a parameter dict, as ``models/kimi_linear.py``;
+``rms_norm`` and the expert layer's window (``WHOLE_WINDOW``) are
+``models/blocks.py``'s. The equations of each part are written out in the
+plain reference's docstring (``benchmark/families/nemotron_h_reference.py``),
+which this module is held to. How they are computed here:
 
 - ``mamba_block``: ``[z | xBC | dt] = u W_in``; the x, B and C channels through
   the four causal taps, the bias and the SiLU of
@@ -82,8 +83,9 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ps_tpu.models.lm import make_attn_fn, token_ce
-from ps_tpu.models.olmoe import rms_norm
+from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
+from ps_tpu.models.blocks import (WHOLE_WINDOW, make_attn_fn, rms_norm,
+                                  token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.flash_attention import KEPT
@@ -250,12 +252,6 @@ def init_params(key, config: NemotronHConfig) -> Dict:
     return params
 
 
-def init_expert_bias(config: NemotronHConfig):
-    """The selection bias at step 0: zeros, one row an expert layer."""
-    return jnp.zeros((config.num_expert_layers, config.router_width),
-                     jnp.float32)
-
-
 def mamba_block(lp: Dict, x, config: NemotronHConfig):
     """The Mamba-2 mixer on normed activations ``x`` [B, S, D]: the held
     heads' part of the sum after the out projection."""
@@ -284,12 +280,10 @@ def mamba_block(lp: Dict, x, config: NemotronHConfig):
         @ lp["out_proj"]["kernel"].astype(x.dtype)
 
 
-def attention_block(lp: Dict, x, config: NemotronHConfig, attn_fn: Callable,
-                    grouped: bool):
+def attention_block(lp: Dict, x, config: NemotronHConfig, attn_fn: Callable):
     """Grouped-query attention without positions of the normed activations
     ``x`` [B, S, D]: the held heads' part of the sum after the out
-    projection. ``grouped``: ``attn_fn`` takes K and V at their own head
-    count."""
+    projection. K and V reach ``attn_fn`` at their own head count."""
     c = config
     b, s, _ = x.shape
     heads, kv_heads = c.num_attention_heads, c.num_key_value_heads
@@ -298,8 +292,6 @@ def attention_block(lp: Dict, x, config: NemotronHConfig, attn_fn: Callable,
         return (x @ lp[name]["kernel"].astype(x.dtype)).reshape(b, s, n, -1)
 
     q, k, v = proj("q", heads), proj("k", kv_heads), proj("v", kv_heads)
-    if not grouped and kv_heads != heads:
-        k, v = (jnp.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
     a = attn_fn(q, k, v, causal=True)
     return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
 
@@ -308,17 +300,6 @@ def relu2_ffn(lp: Dict, x):
     """``relu(x W1) ** 2 W2``."""
     hidden = jnp.square(jax.nn.relu(x @ lp["w1"]["kernel"].astype(x.dtype)))
     return hidden @ lp["w2"]["kernel"].astype(x.dtype)
-
-
-def _window_of(routing: moe.Routing, latent, w1, w2):
-    with jax.named_scope(phases.MOE_DISPATCH):
-        rows = moe.dispatch(latent, routing)
-    with jax.named_scope(phases.MOE_EXPERT):
-        # the grouped matmuls do the whole window's work, whatever is live
-        rows = moe.expert_ffn(rows, w1, None, w2, routing.group_sizes,
-                              activation="relu2", expected_rows=rows.shape[0])
-    with jax.named_scope(phases.MOE_COMBINE):
-        return moe.combine(rows, routing)
 
 
 def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
@@ -336,8 +317,8 @@ def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
             renorm_eps=1e-20, scaling=c.routed_scaling_factor, held=c.held)
     with jax.named_scope(phases.MOE_LATENT):
         latent = tokens @ lp["latent_down"]["kernel"].astype(x.dtype)
-    latent = moe.over_windows(_window_of, routing, latent,
-                              lp["w1"].astype(x.dtype),
+    latent = moe.over_windows(WHOLE_WINDOW, routing, latent,
+                              lp["w1"].astype(x.dtype), None,
                               lp["w2"].astype(x.dtype))
     with jax.named_scope(phases.MOE_LATENT):
         out = latent @ lp["latent_up"]["kernel"].astype(x.dtype)
@@ -346,10 +327,10 @@ def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
     return out.reshape(b, s, d), routing
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6),
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5),
                    policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 def _layer(lp: Dict, x, bias, kind: str, config: NemotronHConfig,
-           attn_fn: Callable, grouped: bool):
+           attn_fn: Callable):
     """One layer, ``x + f(rms_norm(x))``, recomputed in the backward pass:
     the stream out and, of an expert layer, its counts over all experts and
     over the held ones and the windows of rows it ran (None of the
@@ -360,15 +341,15 @@ def _layer(lp: Dict, x, bias, kind: str, config: NemotronHConfig,
             return x + mamba_block(lp["mamba"], h, config), None, None, None
     if kind == "*":
         with jax.named_scope(phases.ATTN):
-            return x + attention_block(lp["attn"], h, config, attn_fn,
-                                       grouped), None, None, None
+            return (x + attention_block(lp["attn"], h, config, attn_fn),
+                    None, None, None)
     out, routing = moe_block(lp["moe"], h, config, bias)
     return (x + out, routing.counts, routing.group_sizes,
             moe.live_windows(routing))
 
 
 def apply(params: Dict, tokens, config: NemotronHConfig, expert_bias=None,
-          attn_fn: Callable = None, grouped: bool = False):
+          attn_fn: Callable = None):
     """``tokens`` [B, S] int32 -> (final hidden states [B, S, D] before the
     final norm, each expert layer's pairs per expert over all of them
     [expert layers, router_width], over the held ones [expert layers,
@@ -382,7 +363,7 @@ def apply(params: Dict, tokens, config: NemotronHConfig, expert_bias=None,
         if kind == "E" and expert_bias is not None:
             bias = expert_bias[len(counts)]
         x, *of_experts = _layer(params[f"layer{i}"], x, bias, kind, c,
-                                attn_fn, grouped)
+                                attn_fn)
         if kind == "E":
             for seen, one in zip((counts, held, windows), of_experts):
                 seen.append(one)
@@ -400,7 +381,7 @@ def make_loss_fn(config: NemotronHConfig, attn: str = "full", **attn_kw):
     """``loss_fn(params, batch, expert_bias) -> (loss, aux)`` for
     pre-shifted ``batch = {"inputs": [B, S], "targets": [B, S]}``, for
     ``KVStore.make_step(loss_fn, has_aux=True)``. ``attn`` is 'full' or
-    'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
+    'flash' (``models/blocks.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers,
     n_routed_experts], those computed here; ``expert_windows`` [expert
@@ -410,8 +391,7 @@ def make_loss_fn(config: NemotronHConfig, attn: str = "full", **attn_kw):
 
     def loss_fn(params, batch, expert_bias):
         hidden, counts, held, windows = apply(
-            params, batch["inputs"], config, expert_bias, attn_fn,
-            grouped=attn == "flash")
+            params, batch["inputs"], config, expert_bias, attn_fn)
         with jax.named_scope(phases.HEAD):
             ce = token_ce(logits_of(params, hidden, config),
                           batch["targets"])

@@ -2,8 +2,9 @@
 eight of them a token (Muennighoff et al. 2024, arXiv:2409.02060;
 ``model_type: olmoe``). The store's first expert layer.
 
-Pure functions over a parameter dict, as ``models/lm.py``: the tree the
-store shards by key. A block is pre-norm attention (RMSNorm, q/k/v without
+Pure functions over a parameter dict, the tree the store shards by key;
+``rms_norm``, ``rope``, the attention closure and the loss are
+``models/blocks.py``'s. A block is pre-norm attention (RMSNorm, q/k/v without
 bias, RMSNorm over the whole q and k projections before the heads are split,
 rotary positions, causal softmax attention, out projection) and a pre-norm
 expert layer (``ops/moe.py``): router over all experts in f32, top-k of the
@@ -33,7 +34,7 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 
-from ps_tpu.models.lm import make_attn_fn, token_ce
+from ps_tpu.models.blocks import make_attn_fn, rms_norm, rope, token_ce
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 
@@ -108,27 +109,6 @@ def init_params(key, config: OlmoeConfig) -> Dict:
     return params
 
 
-def rms_norm(x, scale, eps):
-    """Statistics in f32, result in ``x``'s dtype, as the published code."""
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (scale * xf).astype(x.dtype)
-
-
-def rope(x, theta):
-    """Rotary positions on ``x`` [B, S, h, d], halves rotated against each
-    other (``rotate_half``), angles in f32."""
-    seq, dim = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
-    cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = jnp.split(xf, 2, axis=-1)
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return (xf * cos + rotated * sin).astype(x.dtype)
-
-
 def attention_block(lp: Dict, x, config: OlmoeConfig, attn_fn: Callable):
     """Attention of the normed activations ``x`` [B, S, D] -> [B, S, D]."""
     c = config
@@ -148,7 +128,8 @@ def attention_block(lp: Dict, x, config: OlmoeConfig, attn_fn: Callable):
 
 def moe_block(lp: Dict, x, config: OlmoeConfig):
     """The expert layer on normed activations ``x`` [B, S, D]: the output
-    [B, S, D] and the layer's ``Routing``."""
+    [B, S, D] and the layer's ``Routing``. Not ``blocks.window_of``: every
+    expert held, no window, the stacks cast inside ``ps.moe/expert``."""
     c = config
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
@@ -197,7 +178,7 @@ def make_loss_fn(config: OlmoeConfig, attn: str = "full", **attn_kw):
     """``loss_fn(params, batch) -> (loss, aux)`` for pre-shifted
     ``batch = {"inputs": [B, S], "targets": [B, S]}``, for
     ``KVStore.make_step(loss_fn, has_aux=True)``. ``attn`` is 'full' or
-    'flash' (``models/lm.py::make_attn_fn``)."""
+    'flash' (``models/blocks.py::make_attn_fn``)."""
     attn_fn = make_attn_fn(attn, **attn_kw)
 
     def loss_fn(params, batch):

@@ -12,15 +12,15 @@ the expert layer holds a share of the experts: ``num_experts`` of
 ``router_width``, from ``expert_start`` on, one chip of an expert-parallel
 group without its exchange (``ops/moe.py``).
 
-Pure functions over a parameter dict, as ``models/lfm2.py``, whose
-``dense_ffn`` the three SwiGLUs are; ``rms_norm`` and ``rope`` are
-``models/olmoe.py``'s. A layer is::
+Pure functions over a parameter dict, as ``models/lfm2.py``; the three
+SwiGLUs are ``models/blocks.py``'s ``dense_ffn``, and ``rms_norm``, ``rope``
+and the expert layer's window (``WHOLE_WINDOW``) are its too. A layer is::
 
     h = x + norm2(attn(norm1(x)));  y = h + norm4(ffn(norm3(h)))
 
 and the equations of each part are written out in the plain reference's
-docstring (``tests/trinity_reference.py``), which this module is held to.
-What differs here is how they are computed:
+docstring (``benchmark/families/trinity_reference.py``), which this module is
+held to. What differs here is how they are computed:
 
 - the embedding is scaled by ``sqrt(hidden_size)`` in f32 (``mup_enabled``).
 - ``attention_block``: q on ``num_attention_heads``, k and v on
@@ -72,9 +72,9 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ps_tpu.models.lfm2 import dense_ffn
-from ps_tpu.models.lm import make_attn_fn, token_ce
-from ps_tpu.models.olmoe import rms_norm, rope
+from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
+from ps_tpu.models.blocks import (WHOLE_WINDOW, dense_ffn, make_attn_fn,
+                                  rms_norm, rope, token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.flash_attention import KEPT
@@ -188,17 +188,11 @@ def init_params(key, config: TrinityConfig) -> Dict:
     return params
 
 
-def init_expert_bias(config: TrinityConfig):
-    """The selection bias at step 0: zeros, one row an expert layer."""
-    return jnp.zeros((config.num_expert_layers, config.router_width),
-                     jnp.float32)
-
-
 def attention_block(lp: Dict, x, config: TrinityConfig, kind: str,
-                    attn_fn: Callable, grouped: bool):
+                    attn_fn: Callable):
     """Gated grouped-query attention of the normed activations ``x``
-    [B, S, D], of the layer's ``kind``. ``grouped``: ``attn_fn`` takes K and
-    V at their own head count."""
+    [B, S, D], of the layer's ``kind``. K and V reach ``attn_fn`` at their
+    own head count."""
     c = config
     b, s, _ = x.shape
     heads, kv_heads = c.num_attention_heads, c.num_key_value_heads
@@ -216,25 +210,12 @@ def attention_block(lp: Dict, x, config: TrinityConfig, kind: str,
         # earlier key rotates nothing
         q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
         window = c.sliding_window
-    if not grouped and kv_heads != heads:
-        k, v = (jnp.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
     with jax.named_scope(phases.ATTN_WINDOW if window else phases.ATTN_FULL):
         a = attn_fn(q, k, v, causal=True, window=window)
     with jax.named_scope(phases.ATTN_GATE):
         a = (a.reshape(b, s, -1).astype(jnp.float32)
              * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
     return a @ lp["out"]["kernel"].astype(x.dtype)
-
-
-def _window_of(routing: moe.Routing, tokens, gate, up, down):
-    with jax.named_scope(phases.MOE_DISPATCH):
-        rows = moe.dispatch(tokens, routing)
-    with jax.named_scope(phases.MOE_EXPERT):
-        # the grouped matmuls do the whole window's work, whatever is live
-        rows = moe.expert_ffn(rows, gate, up, down, routing.group_sizes,
-                              expected_rows=rows.shape[0])
-    with jax.named_scope(phases.MOE_COMBINE):
-        return moe.combine(rows, routing)
 
 
 def moe_block(lp: Dict, x, config: TrinityConfig, bias):
@@ -251,17 +232,17 @@ def moe_block(lp: Dict, x, config: TrinityConfig, bias):
             renormalize=c.route_norm, scoring="sigmoid", bias=bias,
             renorm_eps=1e-20, scaling=c.route_scale, held=c.held)
     out = moe.over_windows(
-        _window_of, routing, tokens,
+        WHOLE_WINDOW, routing, tokens,
         *(lp[n].astype(x.dtype) for n in ("gate", "up", "down")))
     with jax.named_scope(phases.MOE_SHARED):
         out = out + dense_ffn(lp["shared"], tokens)
     return out.reshape(b, s, d), routing
 
 
-@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6),
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5),
                    policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 def _layer(lp: Dict, x, bias, kind: str, config: TrinityConfig,
-           attn_fn: Callable, grouped: bool):
+           attn_fn: Callable):
     """One layer, both branches between their two norms, recomputed in the
     backward pass: the stream out and, of an expert layer, its counts over
     all experts and over the held ones and the windows of rows it ran (None
@@ -273,7 +254,7 @@ def _layer(lp: Dict, x, bias, kind: str, config: TrinityConfig,
 
     with jax.named_scope(phases.ATTN):
         a = attention_block(lp["attn"], norm("input_norm", x), config, kind,
-                            attn_fn, grouped)
+                            attn_fn)
     x = x + norm("post_attn_norm", a)
     h = norm("pre_mlp_norm", x)
     if "ffn" in lp:
@@ -286,7 +267,7 @@ def _layer(lp: Dict, x, bias, kind: str, config: TrinityConfig,
 
 
 def apply(params: Dict, tokens, config: TrinityConfig, expert_bias=None,
-          attn_fn: Callable = None, grouped: bool = False):
+          attn_fn: Callable = None):
     """``tokens`` [B, S] int32 -> (final hidden states [B, S, D] before the
     final norm, each expert layer's pairs per expert over all of them
     [expert layers, router_width], over the held ones [expert layers,
@@ -303,7 +284,7 @@ def apply(params: Dict, tokens, config: TrinityConfig, expert_bias=None,
         if expert and expert_bias is not None:
             bias = expert_bias[len(counts)]
         x, *of_experts = _layer(params[f"layer{i}"], x, bias, kind, c,
-                                attn_fn, grouped)
+                                attn_fn)
         if expert:
             for seen, one in zip((counts, held, windows), of_experts):
                 seen.append(one)
@@ -320,7 +301,7 @@ def make_loss_fn(config: TrinityConfig, attn: str = "full", **attn_kw):
     """``loss_fn(params, batch, expert_bias) -> (loss, aux)`` for
     pre-shifted ``batch = {"inputs": [B, S], "targets": [B, S]}``, for
     ``KVStore.make_step(loss_fn, has_aux=True)``. ``attn`` is 'full' or
-    'flash' (``models/lm.py::make_attn_fn``). ``aux``: ``ce``;
+    'flash' (``models/blocks.py::make_attn_fn``). ``aux``: ``ce``;
     ``expert_tokens`` [expert layers, router_width], the step's pairs per
     expert over all of them; ``held_tokens`` [expert layers, num_experts],
     those computed here; ``expert_windows`` [expert layers], the windows of
@@ -330,8 +311,7 @@ def make_loss_fn(config: TrinityConfig, attn: str = "full", **attn_kw):
 
     def loss_fn(params, batch, expert_bias):
         hidden, counts, held, windows = apply(
-            params, batch["inputs"], config, expert_bias, attn_fn,
-            grouped=attn == "flash")
+            params, batch["inputs"], config, expert_bias, attn_fn)
         with jax.named_scope(phases.HEAD):
             ce = token_ce(logits_of(params, hidden, config),
                           batch["targets"])

@@ -10,8 +10,9 @@ strength)::
     o_t = S^T q_t
 
 i.e. ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t
-v_t^T``. The plain references (``tests/kimi_reference.py``) run exactly that,
-token by token; 8,192 dependent steps of rank-one updates leave the MXU idle.
+v_t^T``. The plain reference (``benchmark/families/kimi_reference.py``) runs
+exactly that, token by token; 8,192 dependent steps of rank-one updates leave
+the MXU idle.
 This module is the **chunked form**, the normal path: with ``G`` the decays
 cumulated inside a chunk of ``C`` tokens (64) and ``S0`` the state entering
 it,
@@ -71,16 +72,16 @@ does its two, and a checkpoint whose policy is
 that is 49 KB a token a layer more between forward and backward (``o`` in
 bf16 8 KB, the states in f32 32 KB, the inverses 8 KB: 403 MB a layer at
 8,192 tokens) and one forward call of 11.4 ms a layer less. Under no
-checkpoint, or one that does not list them (``kda``'s own), the names are
-identity. The plain form has no ``custom_vjp`` and no names: a caller's
-policy recomputes it whole.
+checkpoint, or one that does not list them, the names are identity. The
+plain form has no ``custom_vjp`` and no names: a caller's policy recomputes
+it whole.
 
 **The plain form is differentiable by autodiff**: forty lines of
 ``jax.numpy`` that equal the token-by-token reference are differentiated
 right by construction (``tests/test_kimi_linear.py`` holds all five
-gradients of both realisations to the recurrence's). The whole op is under
-``jax.checkpoint`` (its own, ``checkpoint=True``, or the caller's wider
-one): under a policy-less one only ``q, k, v, g, beta`` live on between
+gradients of both realisations to the recurrence's). The whole op belongs
+under the caller's wider ``jax.checkpoint`` (``kda`` has none of its own):
+under a policy-less one only ``q, k, v, g, beta`` live on between
 forward and backward (40 KB a token a layer where the chunk's internals
 would be 130), under one that lists ``KEPT`` those and the kernels' three
 (89 KB), and the plain form's batched part runs in groups of ``GROUP``
@@ -346,7 +347,7 @@ def _under_mesh(run, batch: int, heads: int):
                      out_specs=wide, check_vma=False)
 
 
-def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True,
+def kda(q, k, v, g, beta, *, chunk: int = 64,
         interpret: Optional[bool] = None):
     """``q``, ``k`` [B, T, H, K], ``v`` [B, T, H, V], ``g`` [B, T, H, K] the
     log-decays (<= 0, f32), ``beta`` [B, T, H] -> ``o`` [B, T, H, V] in
@@ -354,10 +355,9 @@ def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True,
     each sequence of the batch on its own. ``T`` must be a multiple of
     ``chunk`` and ``chunk`` of ``SUB``: a sequence is not padded here (a pad
     of ``beta`` 0, ``g`` 0 tokens at the end changes no output before it and
-    is the caller's to add and cut). ``checkpoint=False`` leaves the
-    recomputation to a caller that has a wider ``jax.checkpoint`` of its own
-    around the call (``models/kimi_linear.py``): two nested ones would run
-    the forward pass three times. That caller's policy decides what of the
+    is the caller's to add and cut). The recomputation is the caller's, who
+    has a wider ``jax.checkpoint`` of its own around the call
+    (``models/kimi_linear.py``). That caller's policy decides what of the
     kernels lives from forward to backward: ``save_only_these_names(*KEPT)``
     keeps the output, states and inverses (49 KB a token a layer at 32 heads
     of 128) and the forward call runs once; no policy keeps nothing and it
@@ -378,4 +378,4 @@ def kda(q, k, v, g, beta, *, chunk: int = 64, checkpoint: bool = True,
             q.shape[0], q.shape[2])
     else:
         run = functools.partial(_kda, chunk=chunk)
-    return (jax.checkpoint(run) if checkpoint else run)(q, k, v, g, beta)
+    return run(q, k, v, g, beta)

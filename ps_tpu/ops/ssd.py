@@ -8,10 +8,10 @@ token does (``dt`` the step, > 0; ``A`` the head's decay rate, < 0; ``B`` and
     S  = exp(dt_t * A) * S + dt_t * outer(x_t, B_t)
     y_t = S @ C_t
 
-The plain references (``tests/nemotron_h_reference.py``) run exactly that,
-token by token. This module is the **chunked form**, the normal path: with
-``a = dt * A`` the log-decays (<= 0, one scalar a head and token, where
-``ops/kda.py``'s rule has one a channel and a delta correction with an
+The plain reference (``benchmark/families/nemotron_h_reference.py``) runs
+exactly that, token by token. This module is the **chunked form**, the normal
+path: with ``a = dt * A`` the log-decays (<= 0, one scalar a head and token,
+where ``ops/kda.py``'s rule has one a channel and a delta correction with an
 inverse), ``G`` their sum cumulated inside a chunk of ``Q`` tokens (128) and
 ``S0`` the state entering it,
 

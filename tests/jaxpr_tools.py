@@ -49,7 +49,7 @@ def layers_keep_the_flash_residuals_alone(monkeypatch, model, loss_args,
 
     text, calls, ((loss, _), grads) = trace_and_run()
     monkeypatch.setattr(model, "_layer", jax.checkpoint(
-        model._layer.__wrapped__, static_argnums=(3, 4, 5, 6)))
+        model._layer.__wrapped__, static_argnums=(3, 4, 5)))
     plain_text, plain_calls, ((plain_loss, _), plain_grads) = \
         trace_and_run()
     flash = attn == "flash"

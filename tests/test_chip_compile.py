@@ -106,9 +106,9 @@ def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
 def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     """``ops/kda.py`` at [1, 8192, 32, 128], forward and backward: the shape
     takes the Mosaic kernels (``kda.path``), two calls in the gradient (the
-    forward that keeps each chunk's entering state and inverse, recomputed
-    under the op's ``jax.checkpoint``, and the backward; the loss here needs
-    no output of the first forward, which XLA drops) and no loop of XLA's:
+    forward that keeps each chunk's entering state and inverse, and the
+    backward: the op has no ``jax.checkpoint`` of its own, so nothing runs
+    twice) and no loop of XLA's:
     the state is carried in VMEM along the grid. Between the two calls live
     the states (268 MB) and the inverses, where the plain form's four
     ``while`` loops kept 600 MB of the chunks' internals."""

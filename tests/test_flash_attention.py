@@ -2,7 +2,7 @@
 
 The kernels, forward and backward, run in interpret mode on CPU — the same
 online-softmax loop, block structure, and masking logic as on the chip —
-and must match the models' `_full_attention` (ps_tpu/models/lm.py) in both
+and must match the models' `_full_attention` (ps_tpu/models/blocks.py) in both
 the forward output and every input gradient, causal and padded, including
 the numerically delicate cases (fully-masked rows, block-boundary
 diagonals).
@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from jaxpr_tools import primitives
-from ps_tpu.models.lm import _full_attention
+from ps_tpu.models.blocks import _full_attention
 from ps_tpu.ops import flash_attention
 from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_key,
                                         _first_live, _last_live, _last_query,

@@ -1,10 +1,10 @@
 """LFM2-MoE (``ps_tpu/models/lfm2.py``, ``ps_tpu/ops/gated_conv.py``, the
 held experts, sigmoid routing and selection bias of ``ps_tpu/ops/moe.py``,
 grouped-query heads in ``ps_tpu/ops/flash_attention.py``) against its plain
-reference (``tests/lfm2_reference.py``: a masked loop over the held experts,
-whole rows of attention against repeated K/V, three shifted products for the
-convolution), at small sizes on the CPU with seeded weights; the benchmark's
-own copy of that reference held equal to it; then the family's pieces.
+reference (``benchmark/families/lfm2_reference.py``: a masked loop over the
+held experts, whole rows of attention against repeated K/V, three shifted
+products for the convolution), at small sizes on the CPU with seeded weights;
+then the family's pieces.
 
 Tolerances. Both sides compute in f32 here and differ only in the order of
 their sums: losses agree to a few f32 roundoffs, gradients to 1e-5 of their
@@ -22,12 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import lfm2_reference as reference
 import ps_tpu as ps
-from benchmark.families import lfm2_reference as benchmark_copy
+from benchmark.families import lfm2_reference as reference
 from benchmark.families import lfm2_step
 from ps_tpu.models import lfm2
-from ps_tpu.models.lm import _full_attention
+from ps_tpu.models.blocks import _full_attention
 from ps_tpu.ops import flash_attention, moe
 from ps_tpu.ops.gated_conv import gated_short_conv
 
@@ -69,10 +68,10 @@ def _system(cfg, params, batch, bias, attn="full"):
                 params, batch, bias)
 
 
-def _plain(sizes, params, batch, bias, module=reference):
+def _plain(sizes, params, batch, bias):
     with jax.default_matmul_precision("highest"):
         return jax.jit(jax.value_and_grad(
-            lambda p: module.loss_fn(p, batch, bias, sizes), has_aux=True))(
+            lambda p: reference.loss_fn(p, batch, bias, sizes), has_aux=True))(
                 params)
 
 
@@ -449,26 +448,6 @@ def test_route_with_softmax_and_every_expert_is_bitwise_todays(renormalize,
     b = jax.make_jaxpr(lambda x, r: _route_before_pr32(x, r, 4, renormalize))(
         x[0], lp["router"]["kernel"])
     assert str(a) == str(b)
-
-
-def test_the_two_copies_of_the_reference_are_equal():
-    """``tests/lfm2_reference.py`` and the benchmark's own
-    ``benchmark/families/lfm2_reference.py``: the same text, and loaded as
-    two modules the same values to the last bit."""
-    with open(reference.__file__) as f, open(benchmark_copy.__file__) as g:
-        text = f.read()
-        assert text == g.read()
-    assert "ps_tpu" not in text.split('"""')[2]     # no import of the program
-    assert reference is not benchmark_copy
-    sizes, _, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
-    (loss, aux), grads = _plain(sizes, params, batch, bias, benchmark_copy)
-    assert float(loss) == float(ref_loss)
-    for name in ref_aux:
-        np.testing.assert_array_equal(np.asarray(aux[name]),
-                                      np.asarray(ref_aux[name]))
-    for g, r in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 def test_reference_attends_in_query_blocks_as_in_one(monkeypatch):

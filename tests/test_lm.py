@@ -14,6 +14,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import ps_tpu as ps
 from ps_tpu.models import lm
+from ps_tpu.models.blocks import make_attn_fn
 
 VOCAB, D, HEADS, LAYERS, T, B = 64, 32, 4, 2, 32, 8
 
@@ -29,7 +30,7 @@ def _train(mesh_shape, attn, steps=6, rules=None):
     store = ps.KVStore(optimizer="adam", learning_rate=3e-3,
                        placement="sharded", partition_rules=rules)
     store.init(_params())
-    attn_fn = lm.make_attn_fn(attn, mesh=ctx.mesh)
+    attn_fn = make_attn_fn(attn, mesh=ctx.mesh)
     run = store.make_step(lm.make_loss_fn(n_heads=HEADS, attn_fn=attn_fn))
     sp = mesh_shape.get("seq", 1)
     sh = NamedSharding(ctx.mesh, P("data", "seq" if sp > 1 else None))

@@ -2,11 +2,10 @@
 ``ps_tpu/ops/ssd.py``; the four-tap filter with its bias in
 ``ps_tpu/ops/gated_conv.py``; the ungated experts, the latent rows and the
 shorter row buffers of ``ps_tpu/ops/moe.py``) against its plain reference
-(``tests/nemotron_h_reference.py``: the scan token by token, whole rows of
-attention, a masked loop over the held experts), at small sizes on the CPU
-with seeded weights; the shares of every layer added up to the uncut layer;
-the benchmark's own copy of that reference held equal to it; then the family's
-pieces.
+(``benchmark/families/nemotron_h_reference.py``: the scan token by token,
+whole rows of attention, a masked loop over the held experts), at small sizes
+on the CPU with seeded weights; the shares of every layer added up to the
+uncut layer; then the family's pieces.
 
 Tolerances. Both sides compute in f32 here and differ only in the order of
 their sums: losses agree to a few f32 roundoffs, gradients to 1e-5 of their
@@ -27,12 +26,11 @@ import numpy as np
 import pytest
 
 from jaxpr_tools import layers_keep_the_flash_residuals_alone
-import nemotron_h_reference as reference
-from benchmark.families import nemotron_h_reference as benchmark_copy
+from benchmark.families import nemotron_h_reference as reference
 from benchmark.families import nemotron_h_step
 from benchmark.layer_metrics import nemo as nemo_metrics
 from ps_tpu.models import nemotron_h
-from ps_tpu.models.lm import make_attn_fn
+from ps_tpu.models.blocks import make_attn_fn
 from ps_tpu.ops import moe
 from ps_tpu.ops.gated_conv import conv_silu
 from ps_tpu.ops.ssd import ssd
@@ -87,10 +85,10 @@ def _system(cfg, params, batch, bias, attn="full"):
                 params, batch, bias)
 
 
-def _plain(sizes, params, batch, bias, module=reference):
+def _plain(sizes, params, batch, bias):
     with jax.default_matmul_precision("highest"):
         return jax.jit(jax.value_and_grad(
-            lambda p: module.loss_fn(p, batch, bias, sizes), has_aux=True))(
+            lambda p: reference.loss_fn(p, batch, bias, sizes), has_aux=True))(
                 params)
 
 
@@ -133,8 +131,7 @@ def test_system_matches_reference(attn):
     _assert_grads_close(grads, ref_grads)
     with jax.default_matmul_precision("highest"):
         hidden, *_ = nemotron_h.apply(params, batch["inputs"], cfg, bias,
-                                       make_attn_fn(attn),
-                                       grouped=attn == "flash")
+                                       make_attn_fn(attn))
         logits = nemotron_h.logits_of(params, hidden, cfg)
         want = reference.logits_fn(params, batch["inputs"], bias, sizes)
     assert logits.shape == (2, 128, 256)
@@ -391,7 +388,7 @@ def test_the_eight_head_shares_of_the_attention_layer_add_up(attn):
              "attention_head_start": start})
         with jax.default_matmul_precision("highest"):
             total = total + nemotron_h.attention_block(
-                mine, x, cfg, make_attn_fn(attn), attn == "flash")[0]
+                mine, x, cfg, make_attn_fn(attn))[0]
     assert float(jnp.max(jnp.abs(want))) > 0.1
     np.testing.assert_allclose(total, want, atol=5e-5)
 
@@ -651,27 +648,6 @@ def test_ungated_expert_ffn_is_a_loop_over_the_groups():
 
 
 # -- (f) the reference's own pieces -------------------------------------------
-
-def test_the_two_copies_of_the_reference_are_equal():
-    """``tests/nemotron_h_reference.py`` and the benchmark's own
-    ``benchmark/families/nemotron_h_reference.py``: the same text, and loaded
-    as two modules the same values to the last bit."""
-    with open(reference.__file__) as f, open(benchmark_copy.__file__) as g:
-        text = f.read()
-        assert text == g.read()
-    assert "ps_tpu" not in text.split('"""')[2]     # no import of the program
-    assert "cumsum" not in text.split('"""')[2]     # no cumulated sum
-    assert reference is not benchmark_copy
-    sizes, _, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
-    (loss, aux), grads = _plain(sizes, params, batch, bias, benchmark_copy)
-    assert float(loss) == float(ref_loss)
-    for name in ref_aux:
-        np.testing.assert_array_equal(np.asarray(aux[name]),
-                                      np.asarray(ref_aux[name]))
-    for g, r in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
 
 def test_reference_in_blocks_as_in_one(monkeypatch):
     """The reference's attention in blocks of query rows and its recurrence

@@ -1,9 +1,9 @@
 """OLMoE (``ps_tpu/models/olmoe.py``, ``ps_tpu/ops/moe.py``) against its
-plain reference (``tests/olmoe_reference.py``: every expert on every token
-under a 0/1 mask, full attention, no sort, no ``ragged_dot``), at small sizes
-on the CPU with seeded weights; the benchmark's own copy of that reference
-held equal to it; then the family's pieces: the checks that the benchmark's
-``correct`` holds after step 0, and the stream that never repeats a batch.
+plain reference (``benchmark/families/olmoe_reference.py``: every expert on
+every token under a 0/1 mask, full attention, no sort, no ``ragged_dot``), at
+small sizes on the CPU with seeded weights; then the family's pieces: the
+checks that the benchmark's ``correct`` holds after step 0, and the stream
+that never repeats a batch.
 
 Tolerances. Both sides compute in f32 here and differ only in the order of
 their sums (sorted groups against a masked loop over experts, a blockwise
@@ -25,9 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import olmoe_reference as reference
 import ps_tpu as ps
-from benchmark.families import moe_step, olmoe_reference as benchmark_copy
+from benchmark.families import moe_step, olmoe_reference as reference
 from ps_tpu.models import olmoe
 from ps_tpu.ops import moe
 
@@ -378,26 +377,6 @@ def test_step0_checks_name_the_fault(fault):
         assert STEP0_FAULTS[fault] in failed
     else:
         assert failed == {STEP0_FAULTS[fault]}
-
-
-def test_the_two_copies_of_the_reference_are_equal():
-    """``tests/olmoe_reference.py`` and the benchmark's own
-    ``benchmark/families/olmoe_reference.py``: the same text, and loaded as
-    two modules the same values to the last bit."""
-    with open(reference.__file__) as f, open(benchmark_copy.__file__) as g:
-        assert f.read() == g.read()
-    assert reference is not benchmark_copy
-    sizes, _, params, batch, _, ((ref_loss, ref_aux), ref_grads) = _base()
-    (loss, aux), grads = jax.jit(jax.value_and_grad(
-        lambda p, b: benchmark_copy.loss_fn(p, b, sizes), has_aux=True))(
-            params, batch)
-    assert float(loss) == float(ref_loss)
-    for name in ref_aux:
-        np.testing.assert_array_equal(np.asarray(aux[name]),
-                                      np.asarray(ref_aux[name]))
-    for g, r in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 def test_cell_traffic_is_what_issue_28_named():

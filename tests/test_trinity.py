@@ -1,9 +1,10 @@
 """Trinity (``ps_tpu/models/trinity.py``: attention of two kinds, a window
 with rotary positions or every earlier key without any, behind a sigmoid gate
 and between two norms) against its plain reference
-(``tests/trinity_reference.py``), at small sizes on the CPU, and the pieces of
-its benchmark family (``benchmark/families/trinity_step.py``): the limits of
-the step-0 checks, the operations from shapes, the configuration and the cell.
+(``benchmark/families/trinity_reference.py``), at small sizes on the CPU, and
+the pieces of its benchmark family (``benchmark/families/trinity_step.py``):
+the limits of the step-0 checks, the operations from shapes, the
+configuration and the cell.
 """
 
 import dataclasses
@@ -19,12 +20,11 @@ import numpy as np
 import pytest
 
 from jaxpr_tools import layers_keep_the_flash_residuals_alone
-import trinity_reference as reference
-from benchmark.families import trinity_reference as benchmark_copy
+from benchmark.families import trinity_reference as reference
 from benchmark.families import trinity_step
 from benchmark.layer_metrics import trinity as trinity_metrics
 from ps_tpu.models import trinity
-from ps_tpu.models.lm import make_attn_fn
+from ps_tpu.models.blocks import make_attn_fn
 from ps_tpu.ops import moe
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -71,10 +71,10 @@ def _system(cfg, params, batch, bias, attn="full"):
                 params, batch, bias)
 
 
-def _plain(sizes, params, batch, bias, module=reference):
+def _plain(sizes, params, batch, bias):
     with jax.default_matmul_precision("highest"):
         return jax.jit(jax.value_and_grad(
-            lambda p: module.loss_fn(p, batch, bias, sizes), has_aux=True))(
+            lambda p: reference.loss_fn(p, batch, bias, sizes), has_aux=True))(
                 params)
 
 
@@ -119,8 +119,7 @@ def test_system_matches_reference(attn):
     _assert_grads_close(grads, ref_grads)
     with jax.default_matmul_precision("highest"):
         hidden, *_ = trinity.apply(params, batch["inputs"], cfg, bias,
-                                   make_attn_fn(attn),
-                                   grouped=attn == "flash")
+                                   make_attn_fn(attn))
         logits = trinity.logits_of(params, hidden, cfg)
         want = reference.logits_fn(params, batch["inputs"], bias, sizes)
     assert logits.shape == (2, 128, 256)
@@ -192,8 +191,8 @@ def _attention_inputs(seed=5, seq=128):
 
 def _attend(cfg, lp, x, kind, attn="full"):
     with jax.default_matmul_precision("highest"):
-        return trinity.attention_block(lp, x, cfg, kind, make_attn_fn(attn),
-                                       attn == "flash")[0]
+        return trinity.attention_block(lp, x, cfg, kind,
+                                       make_attn_fn(attn))[0]
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
@@ -337,27 +336,6 @@ def test_routing_is_sigmoid_top_k_renormalised_over_all_picks_and_scaled():
 
 
 # -- the reference itself -----------------------------------------------------
-
-def test_the_two_copies_of_the_reference_are_equal():
-    """``tests/trinity_reference.py`` and the benchmark's own
-    ``benchmark/families/trinity_reference.py``: the same text, and loaded as
-    two modules the same values to the last bit."""
-    with open(reference.__file__) as f, open(benchmark_copy.__file__) as g:
-        text = f.read()
-        assert text == g.read()
-    assert "ps_tpu" not in text.split('"""')[2]     # no import of the program
-    assert "pallas" not in text.split('"""')[2]     # and no kernel
-    assert reference is not benchmark_copy
-    sizes, _, params, batch, bias, ((ref_loss, ref_aux), ref_grads) = _base()
-    (loss, aux), grads = _plain(sizes, params, batch, bias, benchmark_copy)
-    assert float(loss) == float(ref_loss)
-    for name in ref_aux:
-        np.testing.assert_array_equal(np.asarray(aux[name]),
-                                      np.asarray(ref_aux[name]))
-    for g, r in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-
 
 def test_reference_in_blocks_as_in_one(monkeypatch):
     """The reference's attention in blocks of query rows and its logits in
