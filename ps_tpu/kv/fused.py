@@ -23,7 +23,7 @@ import jax
 from ps_tpu import obs
 from ps_tpu.kv import keys as keymod
 from ps_tpu.obs import phases
-from ps_tpu.parallel.sharding import gathered_sharding
+from ps_tpu.parallel.sharding import gathered_sharding, placed_by_rule
 
 
 def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
@@ -66,9 +66,14 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
     stored = gathered = out_shardings = None
     # num_workers is the size of the mesh's data axis
     if dense_store.placement == "sharded" and engine.num_workers > 1:
+        held = engine.get_tree_and_state()
         stored, state_shardings = jax.tree_util.tree_map(
-            lambda x: x.sharding, engine.get_tree_and_state())
-        gathered = jax.tree_util.tree_map(gathered_sharding, stored)
+            lambda x: x.sharding, held)
+        gathered = {
+            k: stored[k] if placed_by_rule(
+                engine.mesh, leaf, k, engine.partition_rules)
+            else gathered_sharding(stored[k])
+            for k, leaf in held[0].items()}
         # tables, their state, loss, aux, row counts: left to the compiler
         out_shardings = (stored, state_shardings) + (None,) * (
             2 + 3 * len(names))

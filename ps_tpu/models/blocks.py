@@ -21,14 +21,21 @@ def rms_norm(x, scale, eps):
     return (scale * xf).astype(x.dtype)
 
 
-def rope(x, theta):
+def rope(x, theta, *, inv_freq=None, scale=None):
     """Rotary positions on ``x`` [B, S, h, d], halves rotated against each
-    other (``rotate_half``), angles in f32."""
+    other (``rotate_half``), angles in f32. The frequencies are
+    ``theta ** (-2i / d)`` unless a table ``inv_freq`` [d / 2] is given (a
+    layer type's own: blended and divided as YaRN's); ``scale`` multiplies
+    cos and sin (YaRN's ``attention_factor``: a rotated q . k carries its
+    square). Without either the trace is the one-table call's."""
     seq, dim = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32)
     x1, x2 = jnp.split(xf, 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)

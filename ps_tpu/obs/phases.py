@@ -4,8 +4,8 @@ the spans of a process's set-up.
 Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
-``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``)
-and land in the
+``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
+``models/mellum.py``, ``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
@@ -98,6 +98,16 @@ ATTN_GATE = "ps.attn/gate"        # sigmoid of the gate projection times the cor
 
 TRINITY_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
                                ATTN_GATE)
+
+# -- scopes of Mellum (models/mellum.py), beside the six and Trinity's two cores --
+# Read by ``benchmark/layer_metrics/mellum.py``, which keeps its own copy.
+# MOE_EXCHANGE is opened in ``ops/moe.py`` around each collective of the
+# token exchange and nests under MOE_DISPATCH (rows to their experts' owners)
+# and MOE_COMBINE (results back): their times hold it, forward, recomputation
+# and backward alike. ATTN_FULL here is rotated by a scaled table of its own.
+MOE_EXCHANGE = "ps.moe/exchange"  # the all_to_all of rows and group sizes between the chips that share a layer
+
+MELLUM_SCOPES = MOE_SCOPES + (ATTN_WINDOW, ATTN_FULL, MOE_EXCHANGE)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

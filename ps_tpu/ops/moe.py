@@ -73,6 +73,40 @@ backward (PR 39, fourteen seeds: the step's time against the held pairs).
 ``R`` the window's length (Nemotron) the grouped matmuls do the whole
 window's work whatever is live.
 
+**A layer whose experts lie on the chips of a group, with its exchange**
+(``over_trips``, under ``shard_map`` over the mesh axis that holds the expert
+stacks split: chip ``c`` of ``n`` owns experts ``c * E / n`` onwards). Each
+chip routes its own ``T`` tokens over all ``E`` experts; its ``T * k`` sorted
+pairs are then sorted by owner too. What is sent: to each owner a buffer of
+``C = exchange_rows(T, k, n)`` rows, the next ``C`` of that owner's segment of
+the sorted pairs, zeros behind the live ones (``send``: the row side of a
+window, as a share's), and the ``E / n`` group sizes that go with them; one
+``all_to_all`` each way (``to_owners``, ``from_owners``), the chip's own
+buffer among them. An owner runs the grouped matmuls once a source over the
+``[E / n, D, F]`` stacks it holds, on rows that arrive sorted by its experts,
+and sends each source its results back in the rows they came in, where
+``receive`` sums them by token with the router's weights (the token side of a
+window). The sizes that arrived are what the grouped matmuls are handed, and
+``over_trips`` returns their sum over the trips beside the output: the rows an
+owner computed, counted from what the exchange carried and not from the
+sender's routing (``sent_rows`` is the sender's figure; a model that reports
+dropped pairs holds one against the other). How the buffers are sized: ``C`` is ``EXCHANGE_ROWS_OVER_EVEN`` times
+the rows an even load sends one owner (``T * k / n``), in whole tiles of the
+grouped matmul, a constant of the shapes; the constant's measurement stands
+beside it. What passes ``C`` is taken by further trips of the same path, each
+of a small buffer (``further_rows``: what passes the first is little): the
+first trip always runs, the others in a ``while_loop`` whose count is the
+group's largest (``trips_of``: a ``pmax``, every chip runs every collective as
+often as the others), dropless still, no capacity factor. What the backward
+sends: the transpose of an exchange is the exchange the other way, so the
+rows' cotangents travel ``to_owners`` where the results came ``from_owners``
+and back where the rows went, by ``all_to_all``'s own transpose; the gathers
+on either side are the share's ``custom_vjp`` rules, and ``over_trips``, as
+``over_windows``, computes a trip again for its gradient: between two layers
+only the tokens and the routing live on. The expert stacks' gradients are
+whole where they are made (every row of an expert arrives at its owner) and
+take no reduction over the axis.
+
 The rows need not be as wide as the router's input: ``route`` reads the
 tokens the router was trained on, ``dispatch`` and ``combine`` move whatever
 rows they are given (a model whose experts work in a latent hands them the
@@ -87,6 +121,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from ps_tpu.obs import phases
 
 
 class Routing(NamedTuple):
@@ -474,9 +510,8 @@ def over_windows(layer, routing: Routing, *operands):
     return _over_windows(layer, routing, operands)
 
 
-def _while_live(routing, first, more):
-    """``first`` joined by ``more(i)`` for the live windows 1, 2, ..."""
-    live = live_windows(routing)
+def _while_below(live, first, more):
+    """``first`` joined by ``more(i)`` for ``i`` = 1, 2, ... below ``live``."""
     return jax.lax.while_loop(
         lambda s: s[0] < live,
         lambda s: (s[0] + 1, jax.tree.map(jnp.add, s[1], more(s[0]))),
@@ -488,7 +523,7 @@ def _over_windows(layer, routing, operands):
     def run(i):
         return layer(_window(routing, i), *operands)
 
-    return _while_live(routing, run(0), run)
+    return _while_below(live_windows(routing), run(0), run)
 
 
 def _over_windows_fwd(layer, routing, operands):
@@ -507,12 +542,213 @@ def _over_windows_bwd(layer, res, g):
 
         return jax.vjp(run, routing.weights, operands)[1](g)
 
-    d_weights, d_operands = _while_live(routing, pull(0), pull)
+    d_weights, d_operands = _while_below(live_windows(routing), pull(0),
+                                         pull)
     nothing = jax.tree.map(lambda _: None, routing)
     return nothing._replace(weights=d_weights), d_operands
 
 
 _over_windows.defvjp(_over_windows_fwd, _over_windows_bwd)
+
+
+# -- the exchange: the experts of a layer on the chips of a group ------------
+
+#: A chip sends each owner this many times the rows an even load sends it
+#: (tokens x picks / chips) in the first trip of the exchange, and the grouped
+#: matmuls there do that buffer's work whatever is live. Measured on four
+#: v5e chips at Mellum2's published widths, 8,192 Zipf(1.0) tokens a chip,
+#: sixteen seeds of four layers (PR 46, ``tools/mellum_grad_check.py
+#: --load-seeds``): under those ids nearly every token of a layer picks the
+#: same eight experts (the fullest expert 5.4 to 8.0 times the mean, of 8
+#: possible), so an owner's share is half an even one for each of them it
+#: holds. The fullest pair of source and owner read 1.14 to 2.91 times even
+#: (2.91, 2.55, 2.31, 2.23, 2.17, 2.11, 2.05 the largest of 64); at 2, seven
+#: seeds of sixteen ran a second trip in some layer, at 3 none. Six of the
+#: eight on one chip (3.0) is one layer in 120 by the count of placements.
+EXCHANGE_ROWS_OVER_EVEN = 3
+#: and this many times in each trip after the first: what passed the first
+#: buffer is little (a layer at 3.1 times even is 1,600 rows over), so a
+#: further trip is a small buffer's exchange and work, about a hundredth of
+#: a step, and not a second pass of the first
+FURTHER_ROWS_OVER_EVEN = 0.25
+
+
+def exchange_rows(tokens: int, top_k: int, chips: int,
+                  over_even: float = None) -> int:
+    """``C``, the rows a chip sends each of the ``chips`` owners in the first
+    trip: ``EXCHANGE_ROWS_OVER_EVEN`` times the even load (or ``over_even``
+    times) in whole tiles of the grouped matmul, and never more than the
+    ``tokens x top_k`` pairs there are."""
+    if over_even is None:
+        over_even = EXCHANGE_ROWS_OVER_EVEN
+    even = tokens * top_k / chips
+    tiles = math.ceil(over_even * even / GROUPED_MATMUL_ROWS)
+    return min(tokens * top_k, GROUPED_MATMUL_ROWS * tiles)
+
+
+def further_rows(tokens: int, top_k: int, chips: int) -> int:
+    """The rows a chip sends each owner in a trip after the first."""
+    return exchange_rows(tokens, top_k, chips, FURTHER_ROWS_OVER_EVEN)
+
+
+class Trip(NamedTuple):
+    """One trip of the exchange, on the chip that routed the ``T`` tokens:
+    ``n`` owners, ``C`` rows each."""
+
+    index: _WindowIndex  # of the [n * C] rows of the send buffer, by owner
+    sizes: jax.Array     # [n, E / n] int32 this trip's rows by owner and expert
+    weights: jax.Array   # [T, k] the router's weights
+
+
+def _to_owner(routing: Routing, chips: int):
+    """Pairs per owner and expert [n, E / n], and where each owner's segment
+    of the sorted pairs begins [n]."""
+    counts = routing.group_sizes.reshape(chips, -1)
+    to_owner = jnp.sum(counts, axis=-1)
+    return counts, jnp.cumsum(to_owner) - to_owner
+
+
+def _reach(routing: Routing, trips, chips: int):
+    """How far into an owner's segment ``trips`` trips reach (``trips`` at
+    least 1)."""
+    t, k = routing.experts.shape
+    return (exchange_rows(t, k, chips)
+            + (trips - 1) * further_rows(t, k, chips))
+
+
+def trips_of(routing: Routing, axis_name):
+    """The trips ``over_trips`` runs for this routing, int32: those that the
+    fullest pair of source and owner in the group needs, the first one
+    always, the same number on every chip."""
+    chips = jax.lax.axis_size(axis_name)
+    t, k = routing.experts.shape
+    rows, more = exchange_rows(t, k, chips), further_rows(t, k, chips)
+    most = jnp.max(jnp.sum(routing.group_sizes.reshape(chips, -1), axis=-1))
+    mine = 1 + (jnp.maximum(most - rows, 0) + more - 1) // more
+    return jax.lax.pmax(mine, axis_name)
+
+
+def sent_rows(routing: Routing, trips, chips: int):
+    """The rows ``trips`` trips carry to each owner by expert [n, E / n], by
+    the sender's reckoning from its routing alone. What the owners were in
+    fact handed is ``over_trips``' second output, counted where it arrived."""
+    counts, _ = _to_owner(routing, chips)
+    ends = jnp.cumsum(counts, axis=-1)
+    reach = _reach(routing, trips, chips)
+    return jnp.minimum(ends, reach) - jnp.minimum(ends - counts, reach)
+
+
+def _trip(routing: Routing, i, chips: int) -> Trip:
+    """The ``i``-th trip of ``routing``'s sorted pairs to their owners: row
+    ``j`` of owner ``d``'s buffer is place ``first + j`` of ``d``'s segment,
+    ``first`` where the trips before it stopped. ``i`` the Python integer 0
+    is the first trip, of ``exchange_rows`` rows an owner; anything else a
+    further one (``i`` >= 1, traced), of ``further_rows``."""
+    t, k = routing.experts.shape
+    pairs = t * k
+    if isinstance(i, int) and i == 0:
+        first, rows = 0, exchange_rows(t, k, chips)
+    else:
+        first, rows = _reach(routing, i, chips), further_rows(t, k, chips)
+    counts, begins = _to_owner(routing, chips)
+    place = first + jnp.arange(rows, dtype=jnp.int32)
+    live = (place[None] < jnp.sum(counts, axis=-1)[:, None]).reshape(-1)
+    at = jnp.minimum(begins[:, None] + place[None], pairs - 1).reshape(-1)
+    pair = jnp.take(routing.order, at).astype(jnp.int32)
+    in_order, by_pair = jax.lax.sort(
+        (jnp.where(live, pair, pairs),
+         jnp.arange(chips * rows, dtype=jnp.int32)), num_keys=1)
+    owner = routing.experts // counts.shape[-1]
+    rank = routing.inverse.reshape(t, k) - jnp.take(begins, owner)
+    here = (rank >= first) & (rank < first + rows)
+    count = jnp.sum(here, axis=-1, dtype=jnp.int32)
+    ends = jnp.cumsum(counts, axis=-1)
+    sizes = (jnp.clip(ends, first, first + rows)
+             - jnp.clip(ends - counts, first, first + rows))
+    index = _WindowIndex(pair, live, by_pair, in_order,
+                         jnp.cumsum(count) - count, here)
+    return Trip(index, sizes.astype(jnp.int32), routing.weights)
+
+
+def send(x, trip: Trip):
+    """Rows of ``x`` [T, D] for the owners, [n, C, D]: each owner's next
+    ``C`` pairs in expert order, zeros past the live ones."""
+    chips = trip.sizes.shape[0]
+    rows = _dispatch_window(x, trip.index)
+    return rows.reshape(chips, -1, rows.shape[-1])
+
+
+def to_owners(rows, sizes, axis_name):
+    """``rows`` [n, C, D] and their group ``sizes`` [n, E / n], one buffer an
+    owner, exchanged: what the ``n`` sources sent this chip, by source."""
+    with jax.named_scope(phases.MOE_EXCHANGE):
+        return (jax.lax.all_to_all(rows, axis_name, 0, 0),
+                jax.lax.all_to_all(sizes, axis_name, 0, 0))
+
+
+def from_owners(rows, axis_name):
+    """The owners' results [n, C, D] back to the chips whose rows they are."""
+    with jax.named_scope(phases.MOE_EXCHANGE):
+        return jax.lax.all_to_all(rows, axis_name, 0, 0)
+
+
+def receive(rows, trip: Trip):
+    """The results ``rows`` [n, C, D] of the rows ``send`` made, summed by
+    token with the router's weights: [T, D], the part this trip gives."""
+    return _combine_window(rows.reshape(-1, rows.shape[-1]), trip.weights,
+                           trip.index)
+
+
+def over_trips(layer, routing: Routing, axis_name, *operands):
+    """``layer(trip, *operands)`` -> ([T, D], the group sizes that arrived
+    with the rows and that the experts were handed, int32 [n, E / n])
+    (``send``, ``to_owners``, the experts, ``from_owners``, ``receive``) over
+    every trip of the exchange that ``routing`` needs, both summed: the first
+    always, the others in a loop (module docstring). The second output is the
+    owner's own count of what the exchange carried to it, by source and
+    expert, and has no gradient. Under ``shard_map`` over ``axis_name``,
+    ``routing`` over all experts. ``layer`` reads nothing that needs a
+    gradient but ``trip.weights`` and ``operands``."""
+    if routing.live is not None:
+        raise ValueError("over_trips: the routing is over all experts, and "
+                         "the owners hold them between them")
+    return _over_trips(layer, axis_name, routing, operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _over_trips(layer, axis_name, routing, operands):
+    chips = jax.lax.axis_size(axis_name)
+
+    def run(i):
+        return layer(_trip(routing, i, chips), *operands)
+
+    return _while_below(trips_of(routing, axis_name), run(0), run)
+
+
+def _over_trips_fwd(layer, axis_name, routing, operands):
+    return (_over_trips(layer, axis_name, routing, operands),
+            (routing, operands))
+
+
+def _over_trips_bwd(layer, axis_name, res, g):
+    # as _over_windows_bwd: a trip is computed again for its gradient
+    routing, operands = res
+    chips = jax.lax.axis_size(axis_name)
+
+    def pull(i):
+        def run(weights, operands):
+            trip = _trip(routing._replace(weights=weights), i, chips)
+            return layer(trip, *operands)[0]
+
+        return jax.vjp(run, routing.weights, operands)[1](g[0])
+
+    d_weights, d_operands = _while_below(trips_of(routing, axis_name),
+                                         pull(0), pull)
+    nothing = jax.tree.map(lambda _: None, routing)
+    return nothing._replace(weights=d_weights), d_operands
+
+
+_over_trips.defvjp(_over_trips_fwd, _over_trips_bwd)
 
 
 def load_balance_loss(routing: Routing):

@@ -140,6 +140,17 @@ def param_sharding(mesh: Mesh, leaf: Any, placement: str,
     return NamedSharding(mesh, P(*spec))
 
 
+def placed_by_rule(mesh: Mesh, leaf: Any, key: str,
+                   rules: Optional[PartitionRules]) -> bool:
+    """Whether a ``partition_rules`` entry places ``leaf`` under ``key``. A
+    split that such an entry states is the model's own whatever its axis, as
+    a ``MODEL_AXIS`` entry already is: ``KVStore.make_step`` reads the leaf,
+    and writes its gradient, as it is stored (expert stacks split by expert
+    over ``DATA_AXIS``, ``models/mellum.py``); ZeRO's gather
+    (:func:`gathered_sharding`) is for the split the placement chose."""
+    return bool(rules) and _rule_sharding(mesh, leaf, key, rules) is not None
+
+
 def gathered_sharding(stored: NamedSharding) -> NamedSharding:
     """The sharding the loss reads a stored parameter under: the pull.
 
@@ -147,7 +158,8 @@ def gathered_sharding(stored: NamedSharding) -> NamedSharding:
     shards are all-gathered), every other entry stays (a tensor-parallel
     split over ``MODEL_AXIS`` is the model's own and is not undone). On a
     data-only mesh the result is replicated. ``KVStore.make_step`` states it
-    on every parameter before the loss reads it.
+    on every parameter before the loss reads it, but on one a rule placed
+    (:func:`placed_by_rule`).
     """
     # param_sharding and its rules give a dim one axis or none
     return NamedSharding(stored.mesh, P(*(

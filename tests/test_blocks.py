@@ -19,8 +19,11 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: Nemotron-H's scan is token by token (no cumulated sum), Trinity's
 #: attention an explicit band (no kernel)
 REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
-              "trinity": ("pallas",)}
-MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity")
+              "trinity": ("pallas",),
+              # Mellum's has no kernel, no mesh and no exchange
+              "mellum": ("pallas", "shard_map", "all_to_all", "ragged_dot")}
+MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
+          "mellum")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))

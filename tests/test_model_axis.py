@@ -220,3 +220,170 @@ def test_gathered_sharding_table(mesh_shape, placement, shape, rules, stored,
     out = gathered_sharding(sharding)
     assert out.mesh is sharding.mesh
     assert out.spec == gathered, (sharding.spec, out.spec)
+
+
+# -- a split over 'data' that a rule states: stored split, read split ----------
+
+@pytest.mark.parametrize("axes,key,shape,rules,read", [
+    # the placement's own split: ZeRO's, gathered to be read
+    ({"data": 4}, "moe/dense/kernel", (D, 4 * D), None, P(None, None)),
+    ({"data": 4}, "moe/dense/kernel", (D, 4 * D), "experts", P(None, None)),
+    # a rule's split over 'data' is the model's own, as one over 'model' is
+    ({"data": 4}, "moe/experts/w", (4, D, D), "experts",
+     P("data", None, None)),
+    ({"data": 4, "model": 2}, "moe/experts/w", (4, D, D), "both",
+     P("data", "model", None)),
+])
+def test_a_ruled_split_is_read_as_it_is_stored(axes, key, shape, rules, read):
+    """What ``kv/fused.py`` reads a leaf under: as stored where a rule placed
+    it, ZeRO's gather elsewhere."""
+    from ps_tpu.parallel.mesh import make_mesh
+    from ps_tpu.parallel.sharding import (gathered_sharding, param_sharding,
+                                          placed_by_rule)
+
+    mesh = make_mesh(axes)
+    rules = {None: None,
+             "experts": [(r"experts/w$", ("data", None, None))],
+             "both": [(r"experts/w$", ("data", "model", None))]}[rules]
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32)
+    stored = param_sharding(mesh, leaf, "sharded", key=key, rules=rules)
+    assert "data" in stored.spec
+    out = (stored if placed_by_rule(mesh, leaf, key, rules)
+           else gathered_sharding(stored))
+    assert out.spec == read
+
+
+def test_placed_by_rule_says_whether_a_rule_places_the_leaf():
+    from ps_tpu.parallel.mesh import make_mesh
+    from ps_tpu.parallel.sharding import placed_by_rule as ruled
+
+    mesh = make_mesh({"data": 4})
+    rules = [(r"experts/w$", ("data", None, None))]
+    stack = jax.ShapeDtypeStruct((4, D, D), jnp.float32)
+    assert ruled(mesh, stack, "moe/experts/w", rules)
+    assert not ruled(mesh, stack, "moe/dense/kernel", rules)
+    # a rule of another rank is skipped, as for the moments' scalars
+    assert not ruled(mesh, jax.ShapeDtypeStruct((D,), jnp.float32),
+                     "moe/experts/w", rules)
+    assert not ruled(mesh, stack, "moe/experts/w", None)
+
+
+def _split_loss(params, batch):
+    """Four 'experts' of [D, D] mixed by a gate every row computes: a leaf
+    that a rule may split over 'data' beside dense ones."""
+    x, y = batch
+    h = jnp.tanh(x @ params["dense"]["kernel"])
+    gate = jax.nn.softmax(x @ params["gate"]["kernel"], -1)       # [B, 4]
+    out = jnp.einsum("be,edf,bd->bf", gate, params["experts"]["w"], h)
+    return jnp.mean((out - y) ** 2)
+
+
+@pytest.mark.parametrize("aggregate", ["mean", "sum"])
+def test_a_leaf_split_by_rule_over_data_updates_as_on_one_device(aggregate):
+    """``KVStore(placement="sharded", partition_rules=...)`` with a rule over
+    the data axis: the leaf is stored split, the step reads it split (the
+    sharding constraint it states is the stored one, where a ZeRO leaf's is
+    the gathered one), its moments sit beside it, and after one AdamW step
+    behind a clip that bites every parameter equals optax's on one device.
+    The clip's global norm runs over leaves split by the rule and by ZeRO
+    alike: the clipped gradient, read from the first moments, is as long as
+    numpy says; ``aggregate="sum"`` scales both kinds alike."""
+    import optax
+
+    rng = np.random.default_rng(3)
+
+    def t(*shape):
+        return jnp.asarray(rng.normal(0, 0.3, shape).astype(np.float32))
+
+    params = {"dense": {"kernel": t(D, D)}, "gate": {"kernel": t(D, 4)},
+              "experts": {"w": t(4, D, D)}}
+    batch = _batches(1, seed=5)[0]
+    rule = dict(learning_rate=1e-2, b1=0.9, b2=0.95, eps=1e-8,
+                weight_decay=0.1)
+    clip = 0.05
+    scale = 4.0 if aggregate == "sum" else 1.0
+    grads = jax.tree.map(lambda g: scale * g,
+                         jax.grad(_split_loss)(params, batch))
+    norm = float(np.sqrt(sum(float(np.sum(np.square(np.asarray(g, np.float64))))
+                             for g in jax.tree.leaves(grads))))
+    assert norm > clip                      # the clip bites
+    opt = optax.chain(optax.clip_by_global_norm(clip), optax.adamw(**rule))
+    updates, _ = opt.update(grads, opt.init(params), params)
+    want = optax.apply_updates(params, updates)
+
+    ps.init(backend="tpu", mesh_shape={"data": 4})
+    try:
+        store = ps.KVStore(
+            optimizer="adamw", placement="sharded", aggregate=aggregate,
+            clip_by_global_norm=clip,
+            partition_rules=[(r"experts/w$", ("data", None, None))], **rule)
+        store.init(params)
+        assert store.pull("experts/w").sharding.spec == P("data", None, None)
+        assert store.pull("dense/kernel").sharding.spec == P("data", None)
+        step = store.make_step(_split_loss)
+        placed = store.shard_batch(batch)
+        stated = step.lower(placed).as_text()
+        # what the step states before the loss reads each leaf
+        assert stated.count("sharding_constraint") >= 6
+        step(placed)
+        for key in ("experts/w", "dense/kernel", "gate/kernel"):
+            leaf = want
+            for part in key.split("/"):
+                leaf = leaf[part]
+            # AdamW's first step is lr * g / (|g| + eps): where g is tiny a
+            # rounding of it shows; a leaf not reduced or not scaled is 1e-2 off
+            np.testing.assert_allclose(store.pull(key), leaf, atol=5e-5,
+                                       err_msg=key)
+            mu = optax.tree_utils.tree_get(store.optimizer_state(key), "mu")
+            assert mu.sharding.spec == store.pull(key).sharding.spec, key
+        clipped = np.sqrt(sum(
+            float(np.sum(np.square(np.asarray(
+                optax.tree_utils.tree_get(store.optimizer_state(k), "mu"),
+                np.float64)))) for k in store.keys())) / (1 - rule["b1"])
+        np.testing.assert_allclose(clipped, clip, rtol=1e-5)
+    finally:
+        ps.shutdown()
+
+
+def test_the_step_gathers_a_zero_leaf_and_not_a_ruled_one():
+    """The compiled step of the store above: the dense leaf is all-gathered
+    to be read and its gradient comes back as its owner's shard; no
+    all-gather has the ruled stack's shape."""
+    rng = np.random.default_rng(4)
+    params = {"dense": {"kernel": jnp.asarray(rng.normal(size=(D, D)),
+                                              jnp.float32)},
+              "gate": {"kernel": jnp.asarray(rng.normal(size=(D, 4)),
+                                             jnp.float32)},
+              "experts": {"w": jnp.asarray(rng.normal(size=(4, D, 3 * D)),
+                                           jnp.float32)}}
+
+    def loss(params, batch):
+        # the stack under shard_map over 'data', as an expert layer reads it
+        from jax import shard_map
+
+        x, y = batch
+        h = jnp.tanh(x @ params["dense"]["kernel"])
+
+        def local(h, w):        # [B / 4, D], [1, D, 3D]: this chip's expert
+            return (h @ w[0])[:, :D]
+
+        out = shard_map(local, mesh=ps.api.current_context().mesh,
+                        in_specs=(P("data"), P("data")),
+                        out_specs=P("data"))(h, params["experts"]["w"])
+        return jnp.mean((out - y) ** 2) + 0 * jnp.sum(
+            params["gate"]["kernel"])
+
+    ps.init(backend="tpu", mesh_shape={"data": 4})
+    try:
+        store = ps.KVStore(
+            optimizer="sgd", learning_rate=0.1, placement="sharded",
+            partition_rules=[(r"experts/w$", ("data", None, None))])
+        store.init(params)
+        step = store.make_step(loss)
+        hlo = step.compiled_text(store.shard_batch(_batches(1)[0]))
+        gathers = [line for line in hlo.splitlines()
+                   if "all-gather" in line and " = " in line]
+        assert any(f"[{D},{D}]" in g for g in gathers), gathers
+        assert not [g for g in gathers if f"{D},{3 * D}]" in g], gathers
+    finally:
+        ps.shutdown()

@@ -1428,3 +1428,164 @@ def test_benchmark_command_rehearses_the_set_up_metrics():
     metrics, as every cell's does: they name no ``workloads``."""
     line = _rehearse("widedeep-criteo.b4096.zipf")
     assert set(SETUP_METRICS) <= set(line["rehearsed"])
+
+
+# -- Mellum: the exchange's scope, on a mesh of four ---------------------------
+
+def _mellum_step():
+    """``(run, batch)`` of ``make_step(has_aux=True)`` on a tiny Mellum over
+    four devices that share each layer: a windowed layer and a full one,
+    eight experts, two a chip."""
+    from ps_tpu.models import mellum
+
+    rope = {"sliding_attention": {"rope_type": "default", "rope_theta": 1e4},
+            "full_attention": {"rope_type": "yarn", "rope_theta": 1e4,
+                               "factor": 4,
+                               "original_max_position_embeddings": 32,
+                               "beta_fast": 32, "beta_slow": 1,
+                               "attention_factor": 1.2}}
+    cfg = mellum.MellumConfig.from_dict(dict(
+        vocab_size=64, hidden_size=32, moe_intermediate_size=16,
+        num_hidden_layers=2,
+        layer_types=["sliding_attention", "full_attention"],
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        sliding_window=16, num_experts=8, num_experts_per_tok=2,
+        rope_parameters=rope, dtype="float32"))
+    ctx = ps.init(backend="tpu", mesh_shape={"data": 4})
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0,
+                       placement="sharded",
+                       partition_rules=mellum.mellum_partition_rules())
+    store.init(jax.jit(lambda k: mellum.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(4 * 65, dtype=np.int32).reshape(4, 65) * 7) % 64
+    step = store.make_step(mellum.make_loss_fn(cfg, mesh=ctx.mesh),
+                           has_aux=True)
+    return step, store.shard_batch({"inputs": ids[:, :-1],
+                                    "targets": ids[:, 1:]})
+
+
+def test_mellum_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Mellum adds to the scopes (``ps.moe/exchange``, opened in
+    ``ops/moe.py`` around the exchange's collectives inside ``ps.moe/dispatch``
+    and ``ps.moe/combine``) beside the six it shares with OLMoE and Trinity's
+    two cores: each in the lowered step's ``op_name``s under ``ps.grad``,
+    forward and backward, under a ``jax.checkpoint``, a ``shard_map`` and a
+    ``custom_vjp``; the exchange's ops are collectives and sit under both of
+    its parents; the reader's copy is equal."""
+    from benchmark.harness import tracered
+    from benchmark.layer_metrics import mellum as mellum_metrics
+
+    assert phases.MELLUM_SCOPES == mellum_metrics.MELLUM_SCOPES
+    assert phases.MELLUM_SCOPES[:6] == phases.MOE_SCOPES
+    for name in ("MOE_EXCHANGE", "ATTN_WINDOW", "ATTN_FULL", "MOE_ROUTE",
+                 "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE", "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(mellum_metrics, name)
+    assert not set(phases.MELLUM_SCOPES) & set(phases.DEVICE_PHASES)
+    assert set(mellum_metrics.SCOPE_METRICS) <= set(phases.MELLUM_SCOPES)
+    assert set(mellum_metrics._INNERMOST_FIRST) == set(phases.MELLUM_SCOPES)
+    monkeypatch.setitem(BUILDERS, "mellum", _mellum_step)
+    names = scope.op_names_of(_step_hlo("mellum"))
+    for s in phases.MELLUM_SCOPES:
+        # the instructions of a reducer or a comparator under ``shard_map``
+        # bear the scope alone ("ps.moe/route/jit(argsort)/sort"); the ops
+        # that call them, which a trace shows, the whole stack
+        under = [n for n in names.values() if s in n and n.startswith("jit(")]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {mellum_metrics.scope_of(own, n) for own, n in names.items()}
+    assert found == set(phases.MELLUM_SCOPES) | {None}
+    exchanged = [(own, n) for own, n in names.items()
+                 if phases.MOE_EXCHANGE in n]
+    assert any("all-to-all" in own for own, _ in exchanged)
+    for parent in (phases.MOE_DISPATCH, phases.MOE_COMBINE):
+        assert any(parent in n for _, n in exchanged), parent
+    assert tracered.is_collective("%all-to-all.7 = f32[4] all-to-all(%x)")
+
+
+def test_mellum_reader_on_a_hand_made_result(monkeypatch):
+    from benchmark.layer_metrics import mellum as mellum_metrics
+
+    call = 'custom_call_target="tpu_custom_call"'
+    rows, sizes = "bf16[4,96,8]", "s32[4,2]"
+    ops = {_ev("%qkv"): 0.004, _ev("%pack"): 0.001,
+           _ev("%band", "custom-call") + call: 0.008,
+           _ev("%triangle", "custom-call") + call: 0.010,
+           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
+           _ev("%all-to-all.1", "all-to-all", rows): 0.006,
+           _ev("%all-to-all.2", "all-to-all", rows): 0.0035,
+           # the group sizes' exchange and a further trip's small buffer
+           _ev("%all-to-all.3", "all-to-all", sizes): 0.00025,
+           _ev("%all-to-all.4", "all-to-all", "bf16[4,8,8]"): 0.00025,
+           _ev("%copy.9"): 0.002,
+           _ev("%all-gather.1", "all-gather"): 0.003,
+           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
+           _ev("%ce"): 0.005, _ev("%embed"): 0.001, _ev("%adam"): 0.007}
+    cp = "jit(f)/ps.grad/jvp()/checkpoint/"
+    exchange = cp + "ps.moe/combine/ps.moe/exchange/all_to_all"
+    names = {"%qkv": "jit(f)/ps.grad/transpose(jvp())/ps.attn/dot_general",
+             "%pack": cp + "ps.attn/ps.attn/window/transpose",
+             "%band": cp + "ps.attn/ps.attn/window/pallas_call",
+             "%triangle": "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
+                          "ps.attn/ps.attn/full/pallas_call",
+             "%route": cp + "ps.moe/route/dot",
+             "%rows": cp + "ps.moe/dispatch/gather",
+             "%back": "jit(f)/ps.grad/transpose(jvp())/ps.moe/combine/gather",
+             "%all-to-all.1": cp + "ps.moe/dispatch/ps.moe/exchange/"
+                                   "all_to_all",
+             "%all-to-all.2": exchange, "%all-to-all.3": exchange,
+             "%all-to-all.4": exchange, "%copy.9": exchange,
+             "%all-gather.1": "jit(f)/ps.apply/sharding_constraint",
+             "%ragged-dot-none.1": "ragged-dot-none",
+             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
+             "%embed": "jit(f)/ps.grad/jvp()/gather",
+             "%adam": "jit(f)/ps.apply/mul"}
+    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
+         "counters": {"mellum_exchange_rows_per_step": 1e6,
+                      "mellum_dropped_tokens": 0.0},
+         "facts": {"kernel_targets": ["tpu_custom_call"],
+                   "mellum_step_flops": 5e9,
+                   "mellum_exchange_bytes_per_row": 150.0,
+                   "mellum_exchange_buffer_rows": 96,
+                   "mellum_layers": 1,
+                   "mellum_window_flash_flops": 1e9,
+                   "mellum_window_flash_bytes": 1.0,
+                   "mellum_full_flash_flops": 1.0,
+                   "mellum_full_flash_bytes": 2e9},
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12,
+                   "ici_bits_per_s": 8e11},
+         "steps": 10, "window_s": 1.0}
+    assert [mellum_metrics.is_row_exchange(n, 96) for n in (
+        _ev("%a", "all-to-all", rows), _ev("%a", "all-to-all-start", rows),
+        _ev("%a", "all-to-all-done", rows), _ev("%a", "all-to-all", sizes),
+        _ev("%a", "fusion", rows), _ev("%a", "all-to-all", "bf16[4,960,8]"))
+    ] == [True, True, False, False, False, False]
+    out = mellum_metrics.scope_times(r, names)
+    assert out["mellum.dispatch_ms"] == pytest.approx(2.0)  # less the exchange
+    assert out["mellum.exchange_ms"] == pytest.approx(6.0)  # with its copy
+    assert out["mellum.exchange_exposed_ms"] == pytest.approx(5.0)
+    assert out["mellum.store_collective_ms"] == pytest.approx(1.5)
+    assert out["mellum.expert_ms"] == pytest.approx(4.0)
+    # two exchanges of rows in the one layer, counted in the trace:
+    # 1e6 rows x 150 B x 2 over 1e11 B/s is 3 ms of the 6
+    assert out["mellum.exchange_ici_share"] == pytest.approx(50.0)
+    assert out["mellum.window_flash_roofline"] == pytest.approx(25.0)
+    assert out["mellum.full_flash_roofline"] == pytest.approx(40.0)
+    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
+    whole = mellum_metrics.read(r)
+    assert whole["mellum.mfu"] == pytest.approx(5.0)  # 5e9 x 10 / s of 1e12
+    listed = {m["name"] for m in json.load(open(os.path.join(
+        _REPO, "BENCHMARK.json")))["per_layer"]
+        if m["name"].startswith("mellum.")}
+    # every metric the reader computes is listed, and none besides
+    assert len(listed) == 10 and listed == set(whole)
+    # a program without the rows' exchange (or whose buffers changed shape)
+    # reads no share of the interconnect rather than a wrong one
+    r["facts"]["mellum_exchange_buffer_rows"] = 97
+    assert "mellum.exchange_ici_share" not in mellum_metrics.scope_times(
+        r, names)
+    # a program without the scopes or the counters
+    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
+    assert mellum_metrics.scope_times(r, {}) == {}
+    assert mellum_metrics.read({"counters": {}, "facts": {}}) == {}
