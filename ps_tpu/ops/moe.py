@@ -3,18 +3,23 @@
 One token picks ``top_k`` of ``E`` experts; a step therefore holds
 ``T * top_k`` token-expert pairs. The pairs are sorted by expert, the token
 rows permuted into that order, and each expert's SwiGLU runs as one group of
-three grouped matmuls (``jax.lax.ragged_dot``) over its contiguous rows. The
+three grouped matmuls (``grouped_matmul.gmm``) over its contiguous rows. The
 group sizes are data, the shapes are not: every pair is computed whatever the
 imbalance, an expert may get no row at all, and there is no capacity factor
 and no dropped token on this path.
 
-On the TPU v5e, XLA lowers ``ragged_dot`` and both of its gradients to a
-Mosaic grouped matmul of its own (``ragged-dot-none`` custom calls, tiles of
-512 x 512 x 512; read from the optimized HLO compiled for a described v5e
-and seen in the chip's traces), at the FLOPs of the pairs and not of ``E``
-dense matmuls. XLA names those custom calls itself and drops the JAX
-``op_name``, so a trace reader finds them by the instruction name
-``%ragged-dot`` and not by a scope.
+The grouped matmuls are ``ops/grouped_matmul.py``'s: ``gmm`` and, from its
+``custom_vjp``, the same kernel against the stacks read transposed (the rows'
+gradient) and ``tgmm`` (the stacks' gradient, exact zeros for an expert with
+no row). Mosaic calls of ours, at the FLOPs of the pairs and not of ``E``
+dense matmuls, tiled from the shapes they are handed (``tiles(..)``: an
+expert's whole matrix stays in VMEM across its row tiles of 256). They keep
+the JAX ``op_name`` of where they are called, so a trace reader finds them
+under ``ps.moe/expert`` like everything else there. Until PR 47 these were
+``jax.lax.ragged_dot``, which XLA:TPU lowers to ``ragged-dot-none`` custom
+calls of its own (tiles of 512 x 512 x 512, no scope): 20-29% of the MXU at
+Mellum's shape, where ``gmm`` reads 76-83% (the table in
+``grouped_matmul.py``).
 
 Permutations move rows with gathers in both directions: the backward pass of
 a gather by a permutation is a gather by its inverse, which two
@@ -64,9 +69,12 @@ the v5e at the three cells' shapes (PR 40): 1.55 / 0.32 / 0.26 ms against
 3.06 / 1.50 / 0.47 for a sorted scatter-add of the same rows and 3.74 / 3.28
 / 1.53 for the gather of all ``T * k`` with its masked weighted sum.
 
-**The grouped matmuls' time follows the live rows**, not the buffer: on the
-v5e 0.5-0.6 us a row over ``relu2`` experts of 1,024 x 2,688, forward and
-backward (PR 39, fourteen seeds: the step's time against the held pairs).
+**The grouped matmuls' time follows the live rows**, not the buffer (a row
+tile past the last group is never visited): on the v5e 0.25 us a row over
+``relu2`` experts of 1,024 x 2,688, forward and backward, 0.34 with the
+forward computed again (PR 47's table: 2.2 ms the six calls over 8,704
+rows; 0.5-0.6 us through ``ragged_dot``, PR 39, fourteen seeds: the step's
+time against the held pairs).
 ``expert_ffn(..., expected_rows=R)`` makes it the same for every load up to
 ``R``: the last expert's group takes the zero rows after the live ones up to
 ``R`` (zeros in, zeros out, a zero gradient: no value changes by a bit). With
@@ -123,6 +131,7 @@ import jax
 import jax.numpy as jnp
 
 from ps_tpu.obs import phases
+from ps_tpu.ops.grouped_matmul import gmm
 
 
 class Routing(NamedTuple):
@@ -153,7 +162,8 @@ class Routing(NamedTuple):
 #: fourteen seeds of the Nemotron cell, whose layers held 0.34 to 3.4 times an
 #: even share of the pairs.
 HELD_ROWS_OVER_EVEN = 3
-#: the rows of one tile of XLA:TPU's grouped matmul
+#: what a window and an exchange buffer are whole multiples of: two row
+#: tiles of the grouped matmul (one of XLA:TPU's, which the sizes date from)
 GROUPED_MATMUL_ROWS = 512
 
 
@@ -420,12 +430,12 @@ def expert_ffn(rows, gate, up, down, group_sizes, activation="swiglu",
     if expected_rows is not None:
         spare = min(expected_rows, rows.shape[0]) - jnp.sum(group_sizes)
         group_sizes = group_sizes.at[-1].add(jnp.maximum(spare, 0))
-    g = jax.lax.ragged_dot(rows, gate, group_sizes)
+    g = gmm(rows, gate, group_sizes)
     if up is None:
         hidden = jnp.square(jax.nn.relu(g))
     else:
-        hidden = jax.nn.silu(g) * jax.lax.ragged_dot(rows, up, group_sizes)
-    return jax.lax.ragged_dot(hidden, down, group_sizes)
+        hidden = jax.nn.silu(g) * gmm(rows, up, group_sizes)
+    return gmm(hidden, down, group_sizes)
 
 
 def combine(rows, routing: Routing):

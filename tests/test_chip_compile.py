@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ps_tpu.ops import flash_attention, moe
+from ps_tpu.ops import flash_attention, grouped_matmul, moe
 from ps_tpu.ops.gated_conv import gated_short_conv
 from ps_tpu.ops.kda import kda, path
 from ps_tpu.ops.ssd import ssd
@@ -44,6 +44,14 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic_grouped_matmul(monkeypatch):
+    """``ops/grouped_matmul.py`` asks ``jax.devices()`` whether to interpret
+    its kernels, and here that is the CPU: the test steers it, the program
+    has no option for it."""
+    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
 
 
 #: [B, S, query heads, K/V heads, head dim (, the values' own)], causal,
@@ -213,9 +221,45 @@ EXPERT_BLOCKS = {
         ((8192, 1024, 2688, 8, 512, 22, "relu2"), 8704, 994259456)}
 
 
+#: rows, a row's width, an expert's width, groups: the grouped matmuls of
+#: the six expert cells (Mellum's a source's buffer, 16 experts a chip)
+GROUPED_MATMULS = {
+    "mellum2-12b-a2.5b.s8192.b1.zipf.x4": (49152, 2304, 896, 16),
+    "olmoe-1b-7b.s4096.zipf": (65536, 2048, 1024, 64),
+    "lfm2-24b-a2b.s8192.zipf": (24576, 2048, 1536, 8),
+    "trinity-mini.s16384.b1.zipf": (49152, 2048, 1024, 16),
+    "nemotron-3-super-120b-a12b.s8192.b1.zipf": (8704, 1024, 2688, 8),
+    "kimi-linear-48b-a3b.s8192.b1.zipf": (6144, 2304, 1024, 8)}
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED_MATMULS))
+def test_grouped_matmul_and_its_gradients_compile_at_the_cells_shapes(
+        cell, one_chip, no_compile_cache, mosaic_grouped_matmul):
+    """``gmm`` into an expert and out of it again, forward, the rows'
+    gradient and the stacks' gradient of each, at the tiles ``tiles(..)``
+    chooses: a choice that overflows VMEM fails here, not on the chip."""
+    m, d, f, e = GROUPED_MATMULS[cell]
+
+    def run(rows, up, down, sizes, g):
+        out, pull = jax.vjp(
+            lambda rows, up, down: grouped_matmul.gmm(
+                grouped_matmul.gmm(rows, up, sizes), down, sizes),
+            rows, up, down)
+        return out, pull(g)
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(run).lower(
+        arg(m, d), arg(e, d, f), arg(e, f, d), arg(e, dtype=jnp.int32),
+        arg(m, d)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert "ragged-dot" not in text and " while(" not in text
+
+
 @pytest.mark.parametrize("cell", sorted(EXPERT_BLOCKS))
-def test_a_shares_expert_block_moves_a_window_of_rows(cell, one_chip,
-                                                      no_compile_cache):
+def test_a_shares_expert_block_moves_a_window_of_rows(
+        cell, one_chip, no_compile_cache, mosaic_grouped_matmul):
     """Route, dispatch, the held experts and combine of ``ops/moe.py`` at
     the three share cells' shapes, forward and backward: nothing 65,536 rows
     long and as wide as a row is left (no gather, no ``where``, no buffer of
