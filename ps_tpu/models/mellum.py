@@ -36,7 +36,11 @@ held to. What differs here is how they are computed:
   matmuls run once a source and do a whole buffer's work whatever is live
   (``expected_rows``), so a step's time does not follow how the seed spread
   the popular experts over the chips (the fullest chip computes 1.1 to 2.6
-  times an even share of a layer's pairs over sixteen seeds, PR 46).
+  times an even share of a layer's pairs over sixteen seeds, PR 46). The
+  chip that routed the tokens touches those buffers by contiguous copies
+  alone: its random access is one gather of its own ``T x k`` pairs each
+  way (``ops/moe.py::send`` / ``receive``), whatever the seed made of the
+  split.
 - every layer is recomputed in the backward pass (one ``jax.checkpoint`` a
   layer) but for the flash call's output and logsumexp
   (``ops/flash_attention.py::KEPT``). What the exchange received is not kept:

@@ -87,33 +87,68 @@ stacks split: chip ``c`` of ``n`` owns experts ``c * E / n`` onwards). Each
 chip routes its own ``T`` tokens over all ``E`` experts; its ``T * k`` sorted
 pairs are then sorted by owner too. What is sent: to each owner a buffer of
 ``C = exchange_rows(T, k, n)`` rows, the next ``C`` of that owner's segment of
-the sorted pairs, zeros behind the live ones (``send``: the row side of a
-window, as a share's), and the ``E / n`` group sizes that go with them; one
-``all_to_all`` each way (``to_owners``, ``from_owners``), the chip's own
-buffer among them. An owner runs the grouped matmuls once a source over the
-``[E / n, D, F]`` stacks it holds, on rows that arrive sorted by its experts,
-and sends each source its results back in the rows they came in, where
-``receive`` sums them by token with the router's weights (the token side of a
-window). The sizes that arrived are what the grouped matmuls are handed, and
+the sorted pairs, zeros behind the live ones (``send``), and the ``E / n``
+group sizes that go with them; one ``all_to_all`` each way (``to_owners``,
+``from_owners``), the chip's own buffer among them. An owner runs the grouped
+matmuls once a source over the ``[E / n, D, F]`` stacks it holds, on rows that
+arrive sorted by its experts, and sends each source its results back in the
+rows they came in, where ``receive`` sums them by token with the router's
+weights. The sizes that arrived are what the grouped matmuls are handed, and
 ``over_trips`` returns their sum over the trips beside the output: the rows an
 owner computed, counted from what the exchange carried and not from the
 sender's routing (``sent_rows`` is the sender's figure; a model that reports
-dropped pairs holds one against the other). How the buffers are sized: ``C`` is ``EXCHANGE_ROWS_OVER_EVEN`` times
-the rows an even load sends one owner (``T * k / n``), in whole tiles of the
-grouped matmul, a constant of the shapes; the constant's measurement stands
-beside it. What passes ``C`` is taken by further trips of the same path, each
-of a small buffer (``further_rows``: what passes the first is little): the
-first trip always runs, the others in a ``while_loop`` whose count is the
-group's largest (``trips_of``: a ``pmax``, every chip runs every collective as
-often as the others), dropless still, no capacity factor. What the backward
-sends: the transpose of an exchange is the exchange the other way, so the
-rows' cotangents travel ``to_owners`` where the results came ``from_owners``
-and back where the rows went, by ``all_to_all``'s own transpose; the gathers
-on either side are the share's ``custom_vjp`` rules, and ``over_trips``, as
-``over_windows``, computes a trip again for its gradient: between two layers
-only the tokens and the routing live on. The expert stacks' gradients are
-whole where they are made (every row of an expert arrives at its owner) and
-take no reduction over the axis.
+dropped pairs holds one against the other). How the buffers are sized: ``C``
+is ``EXCHANGE_ROWS_OVER_EVEN`` times the rows an even load sends one owner
+(``T * k / n``), in whole tiles of the grouped matmul, a constant of the
+shapes; the constant's measurement stands beside it. What passes ``C`` is
+taken by further trips of the same path, each of a small buffer
+(``further_rows``: what passes the first is little): the first trip always
+runs, the others in a ``while_loop`` whose count is the group's largest
+(``trips_of``: a ``pmax``, every chip runs every collective as often as the
+others), dropless still, no capacity factor. What the backward sends: the
+transpose of an exchange is the exchange the other way, so the rows'
+cotangents travel ``to_owners`` where the results came ``from_owners`` and
+back where the rows went, by ``all_to_all``'s own transpose; ``send`` and
+``receive`` have ``custom_vjp`` rules that are each other's shape, and
+``over_trips``, as ``over_windows``, computes a trip again for its gradient:
+between two layers only the tokens and the routing live on. The expert stacks'
+gradients are whole where they are made (every row of an expert arrives at its
+owner) and take no reduction over the axis.
+
+**The source side of a trip moves the pairs a chip has, not the slots of its
+buffers.** The ``n * C`` slots (196,608 in the Mellum cell) are three times
+the ``T * k`` pairs (65,536), and how the pairs split over the owners follows
+the seed, but a chip sends each of its pairs once whatever the split: so the
+random access is over the pairs and the buffers are reached by contiguous
+copies alone. A share's window (``_WindowIndex``) is the other case, its live
+count follows the seed and the window is the bound, and shares nothing with
+this path. ``send`` (``_fill``): the token rows in expert order, one gather of
+``T * k`` rows as the one-chip ``dispatch`` makes; owner ``d``'s buffer is the
+run of ``C`` rows from ``begins[d] + first`` of that array, where the trips
+before stopped, with ``where(rank < to_owner[d], row, 0)`` behind the live
+ones: a ``dynamic_slice`` an owner and a select (``_owner_runs``). ``receive``
+(``_drain``): each pair's result is read from the slot that holds it, ``owner
+* C + rank - first`` (``Trip.slot``): one gather of ``T * k`` rows out of the
+buffers by token and pick, and the weighted sum over a token's ``k``
+neighbours; a pair that travels in another trip reads zero (``Trip.here``), a
+dead slot is never read, so the trips add up and what a grouped matmul left
+past its last group stays out. The cotangents are the same two: ``send``'s is
+``_drain`` without weights, ``receive``'s is ``_fill`` of the output's
+cotangent times the pairs' weights, the weights' own from the rows' dot
+products read back by slot. No key is sorted and no scatter is made: the
+routing's ``order`` and ``inverse`` are all the index there is. Timed alone on
+a v5e at the cell's shapes, with the trip's index each time (PR 48,
+``tools/exchange_passes.py``): ``send`` 6.3 ms, ``receive`` 4.2, their
+cotangents 3.7 and 9.0, where the sort of ``n * C`` keys with the gathers of
+``n * C`` rows by token and into pair order read 6.0, 22.6, 21.0 and 10.0; the
+gather of the pairs alone is 1.1 ms, the rest of ``send`` is XLA:TPU writing
+the four runs out and reading them again to stack them (it fuses no slice at a
+row offset it cannot see into what reads it: twice the bytes of one pass; the
+runs written one by one into zeros read 5.6 ms alone and 10 ms a step more in
+the cell, with 1.0e9 B more at the peak); ``receive`` by contiguous writes
+back into sorted order and the one-chip ``combine`` read 13.5 ms, with
+``_sum_rows`` 17.0. A further trip gathers the ``T * k`` rows again for its
+``further_rows`` an owner (it is the same path; a trip in a hundred layers).
 
 The rows need not be as wide as the router's input: ``route`` reads the
 tokens the router was trained on, ``dispatch`` and ``combine`` move whatever
@@ -603,11 +638,16 @@ def further_rows(tokens: int, top_k: int, chips: int) -> int:
 
 class Trip(NamedTuple):
     """One trip of the exchange, on the chip that routed the ``T`` tokens:
-    ``n`` owners, ``C`` rows each."""
+    ``n`` owners, ``C`` rows each. Row ``j`` of owner ``d``'s buffer is the
+    sorted pair at ``start[d] + j``."""
 
-    index: _WindowIndex  # of the [n * C] rows of the send buffer, by owner
     sizes: jax.Array     # [n, E / n] int32 this trip's rows by owner and expert
     weights: jax.Array   # [T, k] the router's weights
+    order: jax.Array     # [T * k] the routing's: pairs by owner and expert
+    start: jax.Array     # [n] int32 where in that order an owner's run begins
+    live: jax.Array      # [n, C] bool, the row is a pair of the owner's
+    slot: jax.Array      # [T, k] int32 the pair's row of the [n * C]
+    here: jax.Array      # [T, k] bool, the pair travels in this trip
 
 
 def _to_owner(routing: Routing, chips: int):
@@ -655,37 +695,79 @@ def _trip(routing: Routing, i, chips: int) -> Trip:
     is the first trip, of ``exchange_rows`` rows an owner; anything else a
     further one (``i`` >= 1, traced), of ``further_rows``."""
     t, k = routing.experts.shape
-    pairs = t * k
     if isinstance(i, int) and i == 0:
         first, rows = 0, exchange_rows(t, k, chips)
     else:
         first, rows = _reach(routing, i, chips), further_rows(t, k, chips)
     counts, begins = _to_owner(routing, chips)
-    place = first + jnp.arange(rows, dtype=jnp.int32)
-    live = (place[None] < jnp.sum(counts, axis=-1)[:, None]).reshape(-1)
-    at = jnp.minimum(begins[:, None] + place[None], pairs - 1).reshape(-1)
-    pair = jnp.take(routing.order, at).astype(jnp.int32)
-    in_order, by_pair = jax.lax.sort(
-        (jnp.where(live, pair, pairs),
-         jnp.arange(chips * rows, dtype=jnp.int32)), num_keys=1)
     owner = routing.experts // counts.shape[-1]
     rank = routing.inverse.reshape(t, k) - jnp.take(begins, owner)
     here = (rank >= first) & (rank < first + rows)
-    count = jnp.sum(here, axis=-1, dtype=jnp.int32)
     ends = jnp.cumsum(counts, axis=-1)
     sizes = (jnp.clip(ends, first, first + rows)
              - jnp.clip(ends - counts, first, first + rows))
-    index = _WindowIndex(pair, live, by_pair, in_order,
-                         jnp.cumsum(count) - count, here)
-    return Trip(index, sizes.astype(jnp.int32), routing.weights)
+    place = first + jnp.arange(rows, dtype=jnp.int32)
+    return Trip(sizes.astype(jnp.int32), routing.weights, routing.order,
+                jnp.minimum(begins + first, t * k),
+                place[None] < jnp.sum(counts, axis=-1)[:, None],
+                jnp.where(here, owner * rows + rank - first, 0), here)
 
 
+def _owner_runs(in_order, trip: Trip):
+    """``in_order`` [T * k, ..], one entry a sorted pair -> [n, C, ..]: each
+    owner's run of ``C`` from where its trip starts, zeros past its live
+    entries. Slices and a select: nothing is gathered."""
+    chips, rows = trip.live.shape
+    rest = in_order.shape[1:]
+    # a run may reach past the pairs, in its dead entries only
+    padded = jnp.pad(in_order, ((0, rows),) + ((0, 0),) * len(rest))
+    runs = jnp.stack([jax.lax.dynamic_slice_in_dim(padded, trip.start[d], rows)
+                      for d in range(chips)])
+    live = trip.live.reshape(trip.live.shape + (1,) * len(rest))
+    return jnp.where(live, runs, 0)
+
+
+def _fill(x, trip: Trip):
+    """``x`` [T, D] -> [n, C, D]: every pair's token row in expert order
+    (``_dispatch``'s gather of ``T * k`` rows), then each owner's run of
+    them."""
+    k = trip.here.shape[-1]
+    in_order = jnp.take(x, trip.order // k, axis=0, mode="clip")
+    return _owner_runs(in_order, trip)
+
+
+def _drain(rows, trip: Trip, weights=None):
+    """``rows`` [n, C, D] -> [T, D]: the sum of each token's rows that
+    travelled in this trip, each times its pair's weight if ``weights``
+    [T, k] are given (at the rows' precision, the products accumulated in
+    f32). ``T * k`` rows are gathered, by pair from where they lie in the
+    buffers; a dead row is never read, a pair of another trip reads zero."""
+    t, k = trip.here.shape
+    back = jnp.take(rows.reshape(-1, rows.shape[-1]), trip.slot.reshape(-1),
+                    axis=0, mode="clip").reshape(t, k, -1)
+    back = jnp.where(trip.here[..., None], back, 0)
+    if weights is None:
+        return jnp.sum(back, axis=1, dtype=jnp.float32).astype(rows.dtype)
+    return jnp.einsum("tkd,tk->td", back, weights.astype(rows.dtype),
+                      preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+@jax.custom_vjp
 def send(x, trip: Trip):
     """Rows of ``x`` [T, D] for the owners, [n, C, D]: each owner's next
     ``C`` pairs in expert order, zeros past the live ones."""
-    chips = trip.sizes.shape[0]
-    rows = _dispatch_window(x, trip.index)
-    return rows.reshape(chips, -1, rows.shape[-1])
+    return _fill(x, trip)
+
+
+def _send_fwd(x, trip):
+    return _fill(x, trip), trip
+
+
+def _send_bwd(trip, g):
+    return _drain(g, trip), None
+
+
+send.defvjp(_send_fwd, _send_bwd)
 
 
 def to_owners(rows, sizes, axis_name):
@@ -702,11 +784,34 @@ def from_owners(rows, axis_name):
         return jax.lax.all_to_all(rows, axis_name, 0, 0)
 
 
+@jax.custom_vjp
 def receive(rows, trip: Trip):
     """The results ``rows`` [n, C, D] of the rows ``send`` made, summed by
     token with the router's weights: [T, D], the part this trip gives."""
-    return _combine_window(rows.reshape(-1, rows.shape[-1]), trip.weights,
-                           trip.index)
+    return _drain(rows, trip, trip.weights)
+
+
+def _receive_fwd(rows, trip):
+    return receive(rows, trip), (rows, trip)
+
+
+def _receive_bwd(res, g):
+    rows, trip = res
+    weights = trip.weights
+    by_token = _fill(g, trip).astype(jnp.float32)
+    # the weights entered the sum at the rows' precision
+    rounded = weights.astype(rows.dtype).astype(jnp.float32)
+    scale = _owner_runs(jnp.take(rounded.reshape(-1), trip.order), trip)
+    # what the grouped matmuls left in the dead rows stays out: 0 x NaN
+    dots = jnp.sum(by_token * jnp.where(trip.live[..., None], rows, 0),
+                   axis=-1)
+    d_weights = jnp.where(trip.here, jnp.take(dots.reshape(-1), trip.slot), 0)
+    nothing = jax.tree.map(lambda _: None, trip)
+    return ((by_token * scale[..., None]).astype(rows.dtype),
+            nothing._replace(weights=d_weights.astype(weights.dtype)))
+
+
+receive.defvjp(_receive_fwd, _receive_bwd)
 
 
 def over_trips(layer, routing: Routing, axis_name, *operands):

@@ -47,6 +47,11 @@ SIZES = dict(
     use_sliding_window=True, qk_norm=True, router_aux_loss_coef=0.001,
     dtype="float32")
 CHIPS = 4
+#: XLA:CPU's scheduler for concurrency lets a layer's recomputed exchange
+#: start beside another layer's trips, and its in-process collectives then
+#: meet under one key (a rendezvous of five, or none). A chip runs its
+#: collectives in one order; the CPU is told to keep to the program's.
+IN_PROGRAM_ORDER = {"xla_cpu_enable_concurrency_optimized_scheduler": False}
 
 
 def _json(path):
@@ -137,9 +142,10 @@ def test_exchanged_layers_equal_the_one_device_layers_and_the_reference(
     with jax.default_matmul_precision("highest"):
         (one, one_aux), one_grads = jax.jit(jax.value_and_grad(
             mellum.make_loss_fn(cfg), has_aux=True))(params, batch)
-        (got, aux), grads = jax.jit(jax.value_and_grad(
-            mellum.make_loss_fn(cfg, mesh=mesh), has_aux=True))(
-                *_on_mesh(mesh, params, batch))
+        (got, aux), grads = jax.jit(
+            jax.value_and_grad(mellum.make_loss_fn(cfg, mesh=mesh),
+                               has_aux=True),
+            compiler_options=IN_PROGRAM_ORDER)(*_on_mesh(mesh, params, batch))
     for loss, a, g in ((one, one_aux, one_grads), (got, aux, grads)):
         assert abs(float(loss) - float(want)) <= F32_TOL * float(want)
         for term in ("ce", "load_balance"):
@@ -209,61 +215,208 @@ def test_received_rows_are_counted_where_they_arrive(lost, monkeypatch):
     assert checks["checks"]["expert_counts_match_reference"]
 
 
+def _tagged(t, width=8):
+    """Token rows that say whose they are: row ``t`` holds ``t + 1`` in its
+    first place (no live row is all zero), noise behind it."""
+    x = np.random.default_rng(t).normal(size=(t, width)).astype(np.float32)
+    x[:, 0] = 1 + np.arange(t)
+    return jnp.asarray(x)
+
+
+def _pairs_in(buffers, sizes, k, experts):
+    """The pairs ``t * k + j`` that ``send``'s ``buffers`` [n, C, D] of
+    ``_tagged`` rows hold, by owner, read from the rows alone: a row's token
+    from its first place, its expert from where it lies among the owner's
+    group ``sizes`` [n, E / n]. The rows behind an owner's live ones have to
+    be zero."""
+    buffers, sizes = np.asarray(buffers), np.asarray(sizes)
+    held = sizes.shape[-1]
+    found = []
+    for d, (rows, mine) in enumerate(zip(buffers, sizes)):
+        live = mine.sum()
+        assert not rows[live:].any()
+        tokens = rows[:live, 0].astype(np.int64) - 1
+        of = d * held + np.repeat(np.arange(held), mine)
+        picks = np.argmax(experts[tokens] == of[:, None], axis=-1)
+        assert (experts[tokens, picks] == of).all()   # the token picked it
+        found.append(tokens * k + picks)
+    return found
+
+
 def test_a_trip_sends_each_owner_its_next_rows_in_expert_order():
-    """``ops/moe.py::_trip`` by numpy: the buffer of owner ``d`` holds the
-    next ``C`` pairs of ``d``'s experts in expert order, the sizes are the
-    experts' counts clipped to the trip, and the trips' rows add up to every
-    pair once."""
+    """``ops/moe.py::send`` by numpy, through the buffers it fills: the
+    buffer of owner ``d`` holds the next ``C`` pairs of ``d``'s experts in
+    expert order (and in the routing's order inside an expert), the sizes are
+    the experts' counts clipped to the trip, zeros lie behind the live rows,
+    and the trip's rows add up to every pair once."""
     rng = np.random.default_rng(7)
     t, k, e, rows = 32, 4, 16, moe.exchange_rows(32, 4, CHIPS)
-    x = jnp.asarray(rng.normal(size=(t, 8)), jnp.float32)
+    x = _tagged(t)
     routing = moe.route(x, jnp.asarray(rng.normal(size=(8, e)), jnp.float32),
                         k, renormalize=True)
     assert rows == t * k
-    experts = np.asarray(routing.experts).reshape(-1)
+    experts = np.asarray(routing.experts)
     trip = moe._trip(routing, 0, CHIPS)
-    pair, live = (np.asarray(a).reshape(CHIPS, rows)
-                  for a in (trip.index.pair, trip.index.live))
+    buffers = moe.send(x, trip)
+    assert buffers.shape == (CHIPS, rows, 8)
+    order = np.asarray(routing.order)
     seen = []
-    for d in range(CHIPS):
-        mine = experts[pair[d][live[d]]]
+    for d, pairs in enumerate(_pairs_in(buffers, trip.sizes, k, experts)):
+        mine = experts.reshape(-1)[pairs]
         assert ((mine // 4) == d).all() and (np.diff(mine) >= 0).all()
         np.testing.assert_array_equal(
             np.asarray(trip.sizes)[d], np.bincount(mine - 4 * d, minlength=4))
-        seen.extend(pair[d][live[d]].tolist())
+        np.testing.assert_array_equal(pairs, order[len(seen):][:len(pairs)])
+        seen.extend(pairs.tolist())
     assert sorted(seen) == list(range(t * k))
     np.testing.assert_array_equal(np.asarray(moe.sent_rows(routing, 1, CHIPS)),
                                   np.asarray(trip.sizes))
 
 
-def test_further_trips_take_up_where_the_first_stopped(monkeypatch):
-    """The first trip's buffer and the further, smaller ones tile each
-    owner's segment without a gap or a row twice, whatever the load."""
+def _skewed_trips(monkeypatch):
+    """A routing of 32 tokens, four picks of 16, that chip 1's experts draw,
+    behind first buffers of one even share and further ones of a quarter:
+    the routing, the tokens, and each trip with its rows an owner."""
     monkeypatch.setattr(moe, "GROUPED_MATMUL_ROWS", 4)
     monkeypatch.setattr(moe, "EXCHANGE_ROWS_OVER_EVEN", 1)
     rng = np.random.default_rng(9)
     t, k, e = 32, 4, 16
     first, more = moe.exchange_rows(t, k, CHIPS), moe.further_rows(t, k, CHIPS)
     assert (first, more) == (32, 8)
-    x = jnp.asarray(rng.normal(size=(t, 8)), jnp.float32)
+    x = _tagged(t)
     router = jnp.asarray(rng.normal(size=(8, e)), jnp.float32)
     routing = moe.route(x, router.at[:, 4:8].multiply(6.0), k)
     to_owner = np.asarray(routing.group_sizes).reshape(CHIPS, -1).sum(-1)
-    trips = 1 + -(-max(to_owner.max() - first, 0) // more)
-    assert trips >= 3
-    seen, sizes = [], 0
-    for i in range(trips):
-        trip = moe._trip(routing, i if i == 0 else jnp.int32(i), CHIPS)
-        rows = first if i == 0 else more
-        assert trip.index.pair.shape == (CHIPS * rows,)
-        seen.extend(np.asarray(trip.index.pair)[
-            np.asarray(trip.index.live)].tolist())
+    count = 1 + -(-max(to_owner.max() - first, 0) // more)
+    assert count >= 3
+    trips = [(moe._trip(routing, i if i == 0 else jnp.int32(i), CHIPS),
+              first if i == 0 else more) for i in range(count)]
+    return routing, x, trips
+
+
+def test_further_trips_take_up_where_the_first_stopped(monkeypatch):
+    """The first trip's buffer and the further, smaller ones tile each
+    owner's segment without a gap or a row twice, whatever the load: read
+    from the rows ``send`` puts into them and from ``trip.sizes``."""
+    routing, x, trips = _skewed_trips(monkeypatch)
+    t, k = routing.experts.shape
+    experts = np.asarray(routing.experts)
+    order = np.asarray(routing.order)
+    to_owner = np.asarray(routing.group_sizes).reshape(CHIPS, -1).sum(-1)
+    begins = np.cumsum(to_owner) - to_owner
+    seen, sizes, reached = [], 0, np.zeros(CHIPS, np.int64)
+    for trip, rows in trips:
+        buffers = moe.send(x, trip)
+        assert buffers.shape == (CHIPS, rows, 8)
+        for d, pairs in enumerate(_pairs_in(buffers, trip.sizes, k, experts)):
+            # where the trips before stopped, in the owner's segment
+            np.testing.assert_array_equal(
+                pairs, order[begins[d] + reached[d]:][:len(pairs)])
+            reached[d] += len(pairs)
+            seen.extend(pairs.tolist())
         sizes = sizes + np.asarray(trip.sizes)
     assert sorted(seen) == list(range(t * k))
     np.testing.assert_array_equal(
         sizes, np.asarray(routing.group_sizes).reshape(CHIPS, -1))
     np.testing.assert_array_equal(
-        np.asarray(moe.sent_rows(routing, trips, CHIPS)), sizes)
+        np.asarray(moe.sent_rows(routing, len(trips), CHIPS)), sizes)
+
+
+def test_the_buffers_are_runs_of_the_sorted_pairs_bit_for_bit(monkeypatch):
+    """``send``'s buffers against a numpy construction from ``routing.order``
+    at a skewed routing, the first trip and the further ones: row ``j`` of
+    owner ``d`` is the token row of the pair at place ``first + j`` of ``d``'s
+    segment, zero past the segment's end, to the bit. And ``receive`` of the
+    very buffers gives every token its own row times the sum of its weights
+    once the trips are added: a pair outside a trip reads zero there."""
+    routing, x, trips = _skewed_trips(monkeypatch)
+    k = routing.experts.shape[-1]
+    order, tokens = np.asarray(routing.order), np.asarray(x)
+    to_owner = np.asarray(routing.group_sizes).reshape(CHIPS, -1).sum(-1)
+    begins = np.cumsum(to_owner) - to_owner
+    first, back = 0, 0
+    for trip, rows in trips:
+        want = np.zeros((CHIPS, rows, tokens.shape[-1]), tokens.dtype)
+        for d in range(CHIPS):
+            live = int(np.clip(to_owner[d] - first, 0, rows))
+            pairs = order[begins[d] + first:][:live]
+            want[d, :live] = tokens[pairs // k]
+        buffers = moe.send(x, trip)
+        np.testing.assert_array_equal(np.asarray(buffers), want)
+        back = back + moe.receive(buffers, trip)
+        first += rows
+    assert first >= to_owner.max()
+    np.testing.assert_allclose(
+        np.asarray(back),
+        tokens * np.asarray(routing.weights).sum(-1, keepdims=True),
+        rtol=1e-6)
+
+
+def _instructions(hlo):
+    """(name, result shape, opcode, operand names, op_name) of every
+    instruction of a compiled module's text."""
+    import re
+
+    pattern = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?\w+\[([\d,]*)\]"
+                         r"[^ ]* ([\w\-]+)\(([^)]*)\)")
+    for line in hlo.splitlines():
+        found = pattern.match(line)
+        if found:
+            name, dims, opcode, operands = found.groups()
+            scope = re.search(r'op_name="([^"]*)"', line)
+            yield (name, tuple(int(n) for n in dims.split(",") if n), opcode,
+                   re.findall(r"%([\w.\-]+)", operands),
+                   scope.group(1) if scope else "")
+
+
+def test_the_source_side_gathers_pairs_and_copies_buffers(monkeypatch):
+    """The expert layer on four devices, value and gradient, compiled on the
+    CPU at 72 tokens a chip, four picks, buffers of 216 rows an owner and 24
+    in a further trip: no ``sort`` has the ``n x C`` slots of the buffers for
+    keys anywhere, and under ``ps.moe/dispatch`` and ``ps.moe/combine`` there
+    is no ``sort`` and no ``scatter`` at all and no ``gather`` brings out
+    ``n x C`` rows: a gather there moves the ``T x k`` pairs (by token, or by
+    the slot that holds a pair's row) or fewer, and the buffers are made by
+    ``dynamic-slice`` and ``select``."""
+    from ps_tpu.obs import phases
+
+    monkeypatch.setattr(moe, "GROUPED_MATMUL_ROWS", 8)
+    tokens, k = 72, SIZES["num_experts_per_tok"]
+    pairs = tokens * k
+    first, more = (moe.exchange_rows(tokens, k, CHIPS),
+                   moe.further_rows(tokens, k, CHIPS))
+    slots = {CHIPS * first, CHIPS * more}
+    assert (first, more) == (216, 24) and not slots & {tokens, pairs}
+    sizes, cfg, params, _ = _setup(seed=5, rms_norm_eps=5e-6)
+    mesh = _mesh()
+    lp = params["layers"]["0"]
+    x = jnp.asarray(np.random.default_rng(5).normal(
+        size=(CHIPS, tokens, cfg.hidden_size)), jnp.float32)
+
+    def loss(lp, x):
+        return jnp.sum(mellum.moe_block(lp, x, cfg, mesh)[0] ** 2)
+
+    placed = _on_mesh(mesh, {"layers": {"0": lp}}, {"x": x})
+    hlo = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        placed[0]["layers"]["0"], placed[1]["x"]).compile().as_text()
+    program = list(_instructions(hlo))
+    shape_of = {name: shape for name, shape, *_ in program}
+    under, sliced = [], 0
+    for name, shape, opcode, operands, scope in program:
+        rows = [shape[:1]] + [shape_of.get(o, ())[:1] for o in operands]
+        if opcode == "sort":
+            assert not any(r and r[0] in slots for r in rows), (name, rows)
+        if not (phases.MOE_DISPATCH in scope or phases.MOE_COMBINE in scope):
+            continue
+        under.append(opcode)
+        assert opcode not in ("sort", "scatter"), (name, scope)
+        if opcode == "gather":
+            assert shape[0] <= pairs and shape[0] not in slots, (name, shape)
+        sliced += opcode == "dynamic-slice" and shape[:1] in ((first,),
+                                                                (more,))
+    assert "gather" in under and "select" in under
+    # forward and the trip computed again, the first trip and the loop's
+    assert sliced >= 2 * 2 * CHIPS
 
 
 def test_over_trips_refuses_a_share():
