@@ -68,7 +68,7 @@ def check_recorded():
 
 
 def check_kernel():
-    from benchmark.families.dense_step import flash_forward_cost
+    from benchmark.families import flash
     from benchmark.layer_metrics import kernel
 
     trace = tracered.load(os.path.join(HERE, "bert_1step.marked.trace.json"))
@@ -93,10 +93,14 @@ def check_kernel():
            and not n.startswith("%attention.")]
     assert own and not any(tracered.is_custom_call_to(n, targets)
                            for n in own)
-    # the reader, on the cell's shapes: batch 32, 12 heads, 512 x 64, 12 layers
-    flops, nbytes = flash_forward_cost(32, 12, 512, 64, 12)
+    # the reader, on the cell's shapes: batch 32, 12 heads, 512 x 64, 12
+    # layers; the step recorded is PR 24's, whose backward was no kernel
+    flops, nbytes = flash.cost(32, 12, 12, 512, 64, 64, 12,
+                               flash.seen_pairs(512, causal=False), None)
+    assert (flops, nbytes) == (12 * 4.0 * 32 * 12 * 512 * 512 * 64,
+                               12 * 32 * 12 * 512 * (4.0 * 64 * 2 + 4))
     got = kernel.read({
-        "facts": {"kernel_flops": flops, "kernel_bytes": nbytes,
+        "facts": {"flash_flops": flops, "flash_bytes": nbytes,
                   "kernel_targets": targets},
         "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
         "trace": red, "traced_steps": 1})["kernel.flash_roofline"]

@@ -14,6 +14,7 @@ import itertools
 
 import numpy as np
 
+from benchmark.families import flash
 from benchmark.harness.loop import Cell, seed_key
 
 #: bf16 unit roundoff
@@ -65,18 +66,6 @@ def mlm_pool(batch, seq_len, vocab_size, seed, count, mask_rate=0.15,
             "attention_mask": np.ones_like(ids),
         })
     return pool
-
-
-def flash_forward_cost(batch, heads, seq, head_dim, layers, itemsize=2):
-    """Operations and HBM bytes the Pallas kernel's calls of one step need.
-    Only the forward pass is a kernel (``ops/flash_attention.py``: the
-    backward is a blockwise scan in plain XLA), once per layer: QK^T and PV
-    are 2*S*S*d multiply-adds each per head; q, k, v are read and o written
-    once, and the f32 logsumexp written."""
-    flops = layers * 4.0 * batch * heads * seq * seq * head_dim
-    nbytes = layers * (4.0 * batch * heads * seq * head_dim * itemsize
-                       + 4.0 * batch * heads * seq)
-    return flops, nbytes
 
 
 def param_bytes(tree) -> int:
@@ -157,6 +146,7 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
     elif config["model"] == "bert":
         from ps_tpu.models.bert import (BertConfig, BertMLM,
                                         make_mlm_loss_fn, mlm_loss)
+        from ps_tpu.ops.flash_attention import backward_tiles
 
         seq = int(traffic["seq_len"])
         shape = dict(vocab_size=config["vocab_size"],
@@ -195,10 +185,19 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
             facts["flops_per_step"] = (per_seq["per_sample"] * batch
                                        + per_seq["per_step_const"])
         if traffic["attn"] == "flash":
+            # a padding mask, no mask over positions: every pair. Each head
+            # has K and V of its own, so the backward is the one call where
+            # the op's own rule gives it a tile that spans the sequence (512
+            # does), and the two calls past that
             heads = config["num_attention_heads"]
-            facts["kernel_flops"], facts["kernel_bytes"] = flash_forward_cost(
-                per_chip, heads, seq, config["hidden_size"] // heads,
-                config["num_hidden_layers"], dtype.itemsize)
+            dim = config["hidden_size"] // heads
+            spans = backward_tiles(seq, dim, dtype.itemsize, False) \
+                == (seq, seq)
+            facts["flash_flops"], facts["flash_bytes"] = flash.cost(
+                per_chip, heads, heads, seq, dim, dim,
+                config["num_hidden_layers"],
+                flash.seen_pairs(seq, causal=False),
+                "one call" if spans else "two calls", dtype.itemsize)
             facts["kernel_targets"] = config["kernel_targets"]
     else:
         raise ValueError(f"dense_step knows no model {config['model']!r}")

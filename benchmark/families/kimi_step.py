@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from benchmark.families import flash
 from benchmark.families import kimi_reference as reference
 from benchmark.families.lfm2_step import bias_by_sign_rule, learning_rate
 from benchmark.families.moe_step import (adamw_first_step, cosine,
@@ -149,22 +150,13 @@ def kda_core_cost(batch, seq, heads, k_dim, v_dim, chunk, layers,
 
 
 def flash_cost(batch, heads, seq, qk_dim, v_dim, layers, itemsize=2):
-    """Operations and HBM bytes of the causal flash kernel's three calls of
-    one step, **forward and backward**, keys ``qk_dim`` wide and values
-    ``v_dim``: a matmul over the [S, S] scores of one head is 2 S^2 d,
-    halved for the causal mask. Forward: QK^T and PV. dk / dv: the scores,
-    dP, dv and dk. dq: the scores, dP and dq. Bytes: the forward reads q, k,
-    v and writes the output and the f32 logsumexp; each backward call reads
-    q, dO, k, v and the two f32 rows and writes its gradients."""
-    s2 = float(seq) * seq
-    flops = layers * batch * heads * s2 * (
-        (qk_dim + v_dim) + (2 * qk_dim + 2 * v_dim) + (2 * qk_dim + v_dim))
-    q_k, v_o = 2 * qk_dim * itemsize, 2 * v_dim * itemsize   # q + k, v + o
-    rows = 2 * 4
-    per_token_head = ((q_k + v_o + 4)
-                      + (q_k + v_o + rows + (qk_dim + v_dim) * itemsize)
-                      + (q_k + v_o + rows + qk_dim * itemsize))
-    return flops, float(layers * batch * heads * seq * per_token_head)
+    """``flash.cost`` of the causal kernel's three calls, **forward and
+    backward**, where every query head has K and V of its own (the latent
+    attention; Nemotron-H's one K/V head is read as four here, as since PR
+    39), keys ``qk_dim`` wide and values ``v_dim``, the causal mask counted
+    as half the square, without the diagonal's half."""
+    return flash.cost(batch, heads, heads, seq, qk_dim, v_dim, layers,
+                      seq * seq / 2, itemsize=itemsize)
 
 
 def dense_flops(config, tokens, seq_len):
@@ -384,31 +376,31 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
               f"{float(jnp.min(state['expert_bias'])):+.4f} .. "
               f"{float(jnp.max(state['expert_bias'])):+.4f}",
               file=sys.stderr)
-        return {"kimi_dropped_tokens": float(routed - counts.sum()),
-                "kimi_load_max_over_mean":
+        return {"dropped_tokens": float(routed - counts.sum()),
+                "load_max_over_mean":
                 float(np.mean(counts.max(axis=-1) / counts.mean(axis=-1))),
-                "kimi_held_pair_share": float(held.sum() / counts.sum()),
+                "held_pair_share": float(held.sum() / counts.sum()),
                 # all expert layers of one chip, a step
-                "kimi_live_pairs_per_step":
+                "live_pairs_per_step":
                 float(held.sum() / len(held) / chips)}
 
     linear = config["linear_attn_config"]
     itemsize = np.dtype(cfg.dtype).itemsize
     facts = {
-        "kimi_dense_flops_per_step": dense_flops(config, tokens, seq),
-        "kimi_flops_per_pair": pair_flops(config),
+        "dense_flops_per_step": dense_flops(config, tokens, seq),
+        "flops_per_pair": pair_flops(config),
         "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
                                              traffic["ids"]["s"]),
         # where set-up's build phase goes, seconds
         "build_s": {"init_and_weights": t_weights - t_start,
                     "store_init": t_store - t_weights},
     }
-    facts["kimi_kda_core_flops"], facts["kimi_kda_core_bytes"] = \
+    facts["kda_core_flops"], facts["kda_core_bytes"] = \
         kda_core_cost(per_chip, seq, linear["num_heads"], linear["head_dim"],
                       linear["head_dim"], KDA_CHUNK,
                       len(linear["kda_layers"]), itemsize)
     if traffic["attn"] == "flash":
-        facts["kimi_flash_flops"], facts["kimi_flash_bytes"] = flash_cost(
+        facts["flash_flops"], facts["flash_bytes"] = flash_cost(
             per_chip, cfg.num_attention_heads, seq,
             cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim,
             len(linear["full_attn_layers"]), itemsize)

@@ -13,7 +13,7 @@ the plain reference, the benchmark's own copy
 ``tests/lfm2_reference.py``); the limits of the step-0 checks with their
 measured reasons; and the functions that give operations and bytes from
 shapes (``dense_flops``, ``pair_flops``, ``step_flops``,
-``conv_gate_bytes``, ``flash_forward_cost``).
+``conv_gate_bytes``; the flash kernel's are ``families/flash.py``'s).
 """
 
 from __future__ import annotations
@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from benchmark.families import flash
 from benchmark.families import lfm2_reference as reference
 from benchmark.families.moe_step import (adamw_first_step, cosine,
                                          fresh_batches, zipf_entropy)
@@ -165,17 +166,12 @@ def conv_gate_bytes(config, tokens, itemsize=2):
 
 def flash_forward_cost(batch, heads, kv_heads, seq, head_dim, layers,
                        itemsize=2):
-    """Operations and HBM bytes of the causal grouped-query forward kernel's
-    calls of one step (the backward is a scan in plain XLA): QK^T and PV are
-    2 * S * S * d multiply-adds each a query head, halved for the causal
-    mask; q is read and o written at ``heads``, k and v are read at
-    ``kv_heads`` (the kernel's index map reads head h // group: no repeated
-    copy is made or counted), and the f32 logsumexp is written."""
-    flops = layers * 4.0 * batch * heads * seq * seq * head_dim / 2
-    nbytes = layers * (
-        2.0 * batch * (heads + kv_heads) * seq * head_dim * itemsize
-        + 4.0 * batch * heads * seq)
-    return flops, nbytes
+    """``flash.cost`` of the causal forward calls alone, half the square:
+    what the cell's roofline counted while the backward was a scan in plain
+    XLA (before PR 33). No fact is made of it any more; tests/test_lfm2.py
+    still holds its numbers, and it goes with that case."""
+    return flash.cost(batch, heads, kv_heads, seq, head_dim, head_dim,
+                      layers, seq * seq / 2, None, itemsize)
 
 
 def learning_rate(optimizer, warmup_steps):
@@ -366,21 +362,21 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
               f"{float(jnp.min(state['expert_bias'])):+.4f} .. "
               f"{float(jnp.max(state['expert_bias'])):+.4f}",
               file=sys.stderr)
-        return {"lfm2_dropped_tokens": float(routed - counts.sum()),
-                "lfm2_load_max_over_mean":
+        return {"dropped_tokens": float(routed - counts.sum()),
+                "load_max_over_mean":
                 float(np.mean(counts.max(axis=-1) / counts.mean(axis=-1))),
-                "lfm2_held_pair_share": float(held.sum() / counts.sum()),
+                "held_pair_share": float(held.sum() / counts.sum()),
                 # all expert layers of one chip, a step
-                "lfm2_live_pairs_per_step":
+                "live_pairs_per_step":
                 float(held.sum() / len(held) / chips)}
 
     heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
     facts = {
-        "lfm2_dense_flops_per_step": dense_flops(config, tokens, seq),
-        "lfm2_flops_per_pair": pair_flops(config),
-        "lfm2_conv_gate_bytes_per_step": conv_gate_bytes(
+        "dense_flops_per_step": dense_flops(config, tokens, seq),
+        "flops_per_pair": pair_flops(config),
+        "conv_gate_bytes_per_step": conv_gate_bytes(
             config, tokens, np.dtype(cfg.dtype).itemsize),
-        "lfm2_expected_flops_per_step": step_flops(
+        "expected_flops_per_step": step_flops(
             config, tokens, seq, pairs * cfg.num_expert_layers
             * cfg.num_experts / cfg.router_width),
         "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
@@ -390,11 +386,10 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
                     "store_init": t_store - t_weights},
     }
     if traffic["attn"] == "flash":
-        facts["lfm2_flash_flops"], facts["lfm2_flash_bytes"] = \
-            flash_forward_cost(
-                per_chip, heads, kv_heads, seq, cfg.head_dim,
-                sum(k == "full_attention" for k in cfg.layer_types),
-                np.dtype(cfg.dtype).itemsize)
+        facts["flash_flops"], facts["flash_bytes"] = flash.cost(
+            per_chip, heads, kv_heads, seq, cfg.head_dim, cfg.head_dim,
+            sum(k == "full_attention" for k in cfg.layer_types),
+            flash.seen_pairs(seq), itemsize=np.dtype(cfg.dtype).itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

@@ -420,23 +420,27 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
               f"trips beyond it {int(trips.max())}", file=sys.stderr)
         # pairs routed that the senders did not send, and pairs sent that
         # the owners' grouped matmuls were not handed: both 0, dropless
-        return {"mellum_dropped_tokens":
+        return {"dropped_tokens":
                 float(abs(routed - counts.sum()) + abs(routed - sent.sum())
                       + abs(routed - received.sum())),
                 # all layers of one chip, a step, mean over the chips
-                "mellum_exchange_rows_per_step":
+                "exchange_rows_per_step":
                 float(moved.sum() / steps / chips)}
 
     itemsize = np.dtype(cfg.dtype).itemsize
     facts = {
-        "mellum_step_flops": step_flops(config, tokens, seq),
+        # a chip's: step_flops in its two parts. Dropless, so every pair
+        # of the chip's tokens is computed on some chip: the count is a fact
+        "dense_flops_per_step": dense_flops(config, tokens, seq),
+        "flops_per_pair": pair_flops(config),
+        "live_pairs_per_step": float(pairs * layers),
         # one row, one exchange; the reader counts the exchanges a layer
-        "mellum_exchange_bytes_per_row": exchange_bytes(config, 1, 1,
+        "exchange_bytes_per_row": exchange_bytes(config, 1, 1,
                                                         itemsize),
-        "mellum_layers": layers,
-        "mellum_exchange_buffer_rows": exchange_rows(
+        "layers": layers,
+        "exchange_buffer_rows": exchange_rows(
             tokens, cfg.num_experts_per_tok, chips),
-        "mellum_parameters": param_count(config),
+        "parameters": param_count(config),
         "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
                                              traffic["ids"]["s"]),
         # where set-up's build phase goes, seconds
@@ -446,13 +450,13 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
     if traffic["attn"] == "flash":
         shape = (per_chip, cfg.num_attention_heads, cfg.num_key_value_heads,
                  seq, cfg.head_dim)
-        for name, kind, window in (("window", WINDOWED, cfg.sliding_window),
-                                   ("full", FULL, None)):
+        for name, kind, window in (
+                ("window_flash", WINDOWED, cfg.sliding_window),
+                ("flash", FULL, None)):
             count = cfg.layer_types.count(kind)
             if count:
-                (facts[f"mellum_{name}_flash_flops"],
-                 facts[f"mellum_{name}_flash_bytes"]) = flash_cost(
-                     *shape, count, window, itemsize)
+                facts[f"{name}_flops"], facts[f"{name}_bytes"] = flash_cost(
+                    *shape, count, window, itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

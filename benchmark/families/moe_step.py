@@ -25,7 +25,7 @@ import numpy as np
 import optax
 
 from benchmark.families import olmoe_reference as reference
-from benchmark.families.dense_step import flash_forward_cost
+from benchmark.families import flash
 from benchmark.harness import stats
 from benchmark.harness.loop import Cell, seed_key
 
@@ -168,13 +168,6 @@ def expert_flops(config, tokens):
     pairs = tokens * config["num_experts_per_tok"]
     return float(config["num_hidden_layers"] * 3 * 3 * 2 * pairs
                  * config["hidden_size"] * config["intermediate_size"])
-
-
-def causal_flash_forward_cost(*shape):
-    """``dense_step.flash_forward_cost`` with the causal mask's half of the
-    operations and all of the bytes."""
-    flops, nbytes = flash_forward_cost(*shape)
-    return flops / 2, nbytes
 
 
 def cosine(a, b):
@@ -348,15 +341,19 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
             {n: stats.loss_at_n(values, n) for n in LOSS_STEPS
              if n < len(values)}), file=sys.stderr)
         counts = np.asarray(jax.device_get(expert_tokens), np.float64)
-        return {"moe_dropped_tokens":
+        return {"dropped_tokens":
                 float(pairs * chips * len(counts) - counts.sum()),
-                "moe_load_max_over_mean":
+                "load_max_over_mean":
                 float(np.mean(counts.max(axis=1) / counts.mean(axis=1)))}
 
     heads = cfg.num_attention_heads
     facts = {
-        "moe_flops_per_step": step_flops(config, tokens, seq),
-        "moe_expert_flops_per_step": expert_flops(config, tokens),
+        "dense_flops_per_step": (step_flops(config, tokens, seq)
+                                 - expert_flops(config, tokens)),
+        # no share is held and none dropped by capacity: every routed pair
+        # of every layer is computed here, so the count is a fact
+        "flops_per_pair": expert_flops(config, tokens) / pairs,
+        "live_pairs_per_step": float(pairs),
         "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
                                              traffic["ids"]["s"]),
         # where set-up's build phase goes, seconds
@@ -364,10 +361,10 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
                     "store_init": t_store - t_weights},
     }
     if traffic["attn"] == "flash":
-        facts["moe_flash_flops"], facts["moe_flash_bytes"] = \
-            causal_flash_forward_cost(
-                per_chip, heads, seq, cfg.hidden_size // heads,
-                cfg.num_hidden_layers, np.dtype(cfg.dtype).itemsize)
+        dim = cfg.hidden_size // heads
+        facts["flash_flops"], facts["flash_bytes"] = flash.cost(
+            per_chip, heads, heads, seq, dim, dim, cfg.num_hidden_layers,
+            flash.seen_pairs(seq), itemsize=np.dtype(cfg.dtype).itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

@@ -356,30 +356,30 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
               f"{float(jnp.min(state['expert_bias'])):+.4f} .. "
               f"{float(jnp.max(state['expert_bias'])):+.4f}",
               file=sys.stderr)
-        return {"nemo_dropped_tokens": float(routed - counts.sum()),
-                "nemo_load_max_over_mean": fullest,
-                "nemo_held_pair_share": float(held.sum() / counts.sum()),
+        return {"dropped_tokens": float(routed - counts.sum()),
+                "load_max_over_mean": fullest,
+                "held_pair_share": float(held.sum() / counts.sum()),
                 # all expert layers of one chip, a step
-                "nemo_live_pairs_per_step":
+                "live_pairs_per_step":
                 float(held.sum() / len(held) / chips)}
 
     itemsize = np.dtype(cfg.dtype).itemsize
     pattern = cfg.hybrid_override_pattern
     facts = {
-        "nemo_dense_flops_per_step": dense_flops(config, tokens, seq),
-        "nemo_flops_per_pair": pair_flops(config),
+        "dense_flops_per_step": dense_flops(config, tokens, seq),
+        "flops_per_pair": pair_flops(config),
         "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
                                              traffic["ids"]["s"]),
         # where set-up's build phase goes, seconds
         "build_s": {"init_and_weights": t_weights - t_start,
                     "store_init": t_store - t_weights},
     }
-    facts["nemo_ssd_flops"], facts["nemo_ssd_bytes"] = ssd_cost(
+    facts["ssd_flops"], facts["ssd_bytes"] = ssd_cost(
         per_chip, seq, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
         cfg.ssm_state_size, min(cfg.chunk_size, seq), pattern.count("M"),
         itemsize)
     if traffic["attn"] == "flash":
-        facts["nemo_flash_flops"], facts["nemo_flash_bytes"] = flash_cost(
+        facts["flash_flops"], facts["flash_bytes"] = flash_cost(
             per_chip, cfg.num_attention_heads, seq, cfg.head_dim,
             cfg.head_dim, pattern.count("*"), itemsize)
         facts["kernel_targets"] = config["kernel_targets"]

@@ -29,7 +29,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from benchmark.families import flash
 from benchmark.families import trinity_reference as reference
+from benchmark.families.flash import seen_pairs
 from benchmark.families.lfm2_step import bias_by_sign_rule, learning_rate
 from benchmark.families.moe_step import (adamw_first_step, cosine,
                                          fresh_batches, zipf_entropy)
@@ -142,32 +144,13 @@ def pair_flops(config):
     return 3 * 6.0 * config["hidden_size"] * config["moe_intermediate_size"]
 
 
-def seen_pairs(seq, window=None):
-    """Query-key pairs one head of one sequence attends over: the triangle
-    with its diagonal, or the band of ``window`` keys a row (itself and the
-    ``window - 1`` before it), whose first rows see fewer."""
-    if window is None or window >= seq:
-        return seq * (seq + 1) // 2
-    return window * (window + 1) // 2 + (seq - window) * window
-
-
 def flash_cost(batch, heads, kv_heads, seq, dim, layers, window=None,
                itemsize=2):
-    """Operations and HBM bytes of the flash kernel's three calls in one
-    step, **forward and backward**, over what the layers see, a band
-    (``window``) or the triangle: a matmul over the scores of one head is
-    2 x pairs x d. Forward: QK^T and PV. dk / dv: the scores, dP, dv and dk.
-    dq: the scores, dP and dq: nine in all. Bytes: the forward reads q, k, v
-    and writes the output and the f32 logsumexp; each backward call reads q,
-    dO, k, v and the two f32 rows and writes its gradients; K and V at their
-    own head count."""
-    flops = layers * batch * heads * 2.0 * seen_pairs(seq, window) * 9 * dim
-    q_side, k_side = dim * itemsize * heads, dim * itemsize * kv_heads
-    rows = 2 * 4 * heads
-    per_token = ((2 * q_side + 2 * k_side + 4 * heads)
-                 + (2 * q_side + 4 * k_side + rows)
-                 + (3 * q_side + 2 * k_side + rows))
-    return flops, float(layers * batch * seq * per_token)
+    """``flash.cost`` of the kernel's three calls, **forward and backward**,
+    over what the layers see: a band (``window``) or the triangle, keys and
+    values ``dim`` wide: nine matmuls over the pairs in all."""
+    return flash.cost(batch, heads, kv_heads, seq, dim, dim, layers,
+                      seen_pairs(seq, window), itemsize=itemsize)
 
 
 def live_step_share(seq, window, tiles):
@@ -389,17 +372,17 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
               f"range {float(jnp.min(state['expert_bias'])):+.4f} .. "
               f"{float(jnp.max(state['expert_bias'])):+.4f}",
               file=sys.stderr)
-        return {"trinity_dropped_tokens": float(routed - counts.sum()),
-                "trinity_load_max_over_mean": fullest,
-                "trinity_held_pair_share": float(held.sum() / counts.sum()),
+        return {"dropped_tokens": float(routed - counts.sum()),
+                "load_max_over_mean": fullest,
+                "held_pair_share": float(held.sum() / counts.sum()),
                 # all expert layers of one chip, a step
-                "trinity_live_pairs_per_step":
+                "live_pairs_per_step":
                 float(held.sum() / len(held) / chips)}
 
     itemsize = np.dtype(cfg.dtype).itemsize
     facts = {
-        "trinity_dense_flops_per_step": dense_flops(config, tokens, seq),
-        "trinity_flops_per_pair": pair_flops(config),
+        "dense_flops_per_step": dense_flops(config, tokens, seq),
+        "flops_per_pair": pair_flops(config),
         "unigram_entropy_nats": zipf_entropy(cfg.vocab_size,
                                              traffic["ids"]["s"]),
         # where set-up's build phase goes, seconds
@@ -409,16 +392,16 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
     if traffic["attn"] == "flash":
         shape = (per_chip, cfg.num_attention_heads, cfg.num_key_value_heads,
                  seq, cfg.head_dim)
-        for name, kind, window in (("window", WINDOWED, cfg.sliding_window),
-                                   ("full", FULL, None)):
+        for name, kind, window in (
+                ("window_flash", WINDOWED, cfg.sliding_window),
+                ("flash", FULL, None)):
             layers = cfg.layer_types.count(kind)
             if layers:
-                (facts[f"trinity_{name}_flash_flops"],
-                 facts[f"trinity_{name}_flash_bytes"]) = flash_cost(
-                     *shape, layers, window, itemsize)
+                facts[f"{name}_flops"], facts[f"{name}_bytes"] = flash_cost(
+                    *shape, layers, window, itemsize)
         if WINDOWED in cfg.layer_types and cfg.sliding_window < seq:
             # the tiles are the kernel's own choice from the shapes
-            facts["trinity_window_live_step_share"] = live_step_share(
+            facts["window_live_step_share"] = live_step_share(
                 seq, cfg.sliding_window,
                 forward_tiles(seq, cfg.head_dim, itemsize, True))
         facts["kernel_targets"] = config["kernel_targets"]
