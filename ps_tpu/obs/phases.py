@@ -5,7 +5,7 @@ Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
 ``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
-``models/mellum.py``, ``ops/moe.py``) and land in the
+``models/mellum.py``, ``models/sdar.py``, ``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
@@ -108,6 +108,17 @@ TRINITY_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
 MOE_EXCHANGE = "ps.moe/exchange"  # the all_to_all of rows and group sizes between the chips that share a layer
 
 MELLUM_SCOPES = MOE_SCOPES + (ATTN_WINDOW, ATTN_FULL, MOE_EXCHANGE)
+
+# -- scopes of SDAR (models/sdar.py), beside the six and ATTN_FULL -----------------
+# The six and ATTN_FULL are read by ``benchmark/layer_metrics/decoder.py``,
+# which keeps its own copy; ATTN_INBLOCK has no metric of its own yet and is
+# read inside ``decoder.attn_ms`` (it nests under ATTN, so ATTN's time holds
+# it). ATTN_FULL here is around the two kernel calls over the clean keys: the
+# clean queries under the edge a block wide, the noised ones under the strict
+# edge.
+ATTN_INBLOCK = "ps.attn/inblock"  # a noised query's own block of noised keys, and the merge by the logsumexps
+
+SDAR_SCOPES = MOE_SCOPES + (ATTN_FULL, ATTN_INBLOCK)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

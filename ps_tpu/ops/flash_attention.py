@@ -108,6 +108,40 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   names below (``tests/test_flash_attention.py`` pins it); a window that
   spans the sequence is the causal call.
 
+- AN EDGE A BLOCK WIDE (``edge_block=``, causal calls without a window:
+  block-diffusion training, ``models/sdar.py``, blocks of 4 in 8,192).
+  Query ``i`` sees every key before the end of its own block of
+  ``edge_block`` positions, ``j < (i // B + 1) * B``, or, with
+  ``strict_edge``, before its start, ``j < (i // B) * B``. ``B`` is a power
+  of two that divides 128 and so every tile: a query block's last row sees
+  to the tile's own end at most, so ``_last_live`` / ``_first_live``, the
+  index maps' clamps and the tiles stand as the causal call has them, and
+  only the diagonal tiles' predicate changes (``_visible``: the first
+  position of the query's block is ``i & -B``), in the forward, the dk / dv
+  and the dq kernel alike; the backward's unmasked fast path takes, under the
+  strict edge, the tiles that end before the query block starts. Under the
+  strict edge a tile the bounds call live may hold no visible pair (the
+  bounds are the causal call's, one block generous): it computes zeros.
+  **Rows that see no key** (the strict edge's first block) come out as the
+  padding mask's fully masked rows do: the gate keeps their probabilities at
+  0, the output is 0, the logsumexp ``_NEG_INF`` itself, finite, and their
+  gradients exactly zero. **The logsumexp as an output** (``return_lse=``):
+  the second output of the ``custom_vjp``, with a cotangent of its own:
+  ``d lse_i / d s_ij = p_ij``, so the backward runs on ``delta - dlse`` where
+  ``delta`` stands and nothing else changes (two calls always: the one-call
+  form computes delta inside). A caller merges two key sets by their
+  logsumexps outside the kernel (``models/sdar.py::own_block``: the clean
+  keys before a noised query's block through the strict call, its own block
+  of ``B`` noised keys as a dense product). Without ``edge_block`` and
+  ``return_lse`` the program is the one it was, to the jaxpr
+  (``tests/test_flash_attention.py`` pins it at five cells' shapes).
+  Inside the fused step (my chip runs, PR 50, [32, 8192, 128] on [4, 8192,
+  128], blocks of 4, tiles (1024, 1024) / (1024, 512)): a layer's six calls
+  take 36.6 ms (the two forwards about 5.0 each, the strict one with its
+  logsumexp as an output; dk / dv 7.44 and 7.23; dq about 6 each), 68.6% of
+  the roofline of ``L ** 2`` pairs a head over the two calls, where the
+  causal call over the same keys reads 67% in the Mellum cell.
+
 - THE RESIDUALS' NAMES (``KEPT``). The custom VJP keeps ``(q, k, v, mask,
   out, lse)``. Under a ``jax.checkpoint`` around the call none of the six
   lives from forward to backward, and the backward pass runs the forward
@@ -211,7 +245,7 @@ ROADMAP.md D4 holds the comparison at seq 512.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -229,6 +263,11 @@ _NEG_INF = -1e30
 # output and logsumexp, for the policy of a ``jax.checkpoint`` around the call
 # (the docstring's THE RESIDUALS' NAMES).
 KEPT = ("flash_out", "flash_lse")
+
+# A causal call's edge at a block's granularity (the docstring's AN EDGE A
+# BLOCK WIDE): the block's length, a power of two that divides 128, and
+# whether the query's own block is left out (strict).
+Edge = Tuple[int, bool]
 
 # What one grid step may hold in VMEM by forward_vmem_bytes' count, of the
 # 16 MiB Mosaic scopes to a kernel on a v5e by default. The count leaves out
@@ -318,22 +357,31 @@ def _last_query(j, block_q: int, block_k: int, window: int, num_q: int):
                        num_q - 1)
 
 
-def _visible(qi, j, shape, q_axis: int, window: Optional[int] = None):
+def _visible(qi, j, shape, q_axis: int, window: Optional[int] = None,
+             edge: Optional[Edge] = None):
     """Causal visibility of a score tile of query block ``qi`` and key
     block ``j``: the query position reaches the key position and, under a
-    window, lies fewer than ``window`` past it. The queries run along
-    ``q_axis`` of ``shape``, the keys along the other."""
+    window, lies fewer than ``window`` past it; under an ``edge`` a block
+    wide, the key lies before the end of the query's block, or (strict)
+    before its start. The queries run along ``q_axis`` of ``shape``, the
+    keys along the other."""
     qpos = qi * shape[q_axis] + jax.lax.broadcasted_iota(
         jnp.int32, shape, q_axis)
     kpos = j * shape[1 - q_axis] + jax.lax.broadcasted_iota(
         jnp.int32, shape, 1 - q_axis)
+    if edge is not None:
+        block, strict = edge
+        # the block is a power of two: ``& -block`` is its first position
+        first = jnp.bitwise_and(qpos, -block)
+        return kpos < (first if strict else first + block)
     if window is None:
         return qpos >= kpos
     return jnp.logical_and(qpos >= kpos, qpos - kpos < window)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
-                scale: float, causal: bool, window: Optional[int]):
+                scale: float, causal: bool, window: Optional[int],
+                edge: Optional[Edge] = None):
     """One (batch·head, q-block, kv-block) grid step. The kv dimension is
     the INNERMOST grid axis, so the (m, l, acc) VMEM scratch persists
     across a q-block's kv steps while Mosaic pipelines the next kv
@@ -353,7 +401,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k] f32
         if causal:
-            s = jnp.where(_visible(qi, j, s.shape, 0, window), s, _NEG_INF)
+            s = jnp.where(_visible(qi, j, s.shape, 0, window, edge), s,
+                          _NEG_INF)
         # padding mask: this block's key validity as a [1, block_k] row,
         # broadcast over the query rows
         s = jnp.where(mask_ref[:] > 0, s, _NEG_INF)
@@ -469,7 +518,7 @@ def _qkv_specs(q, k, v, mask, **tiles):
 
 
 def _flash_fwd(q, k, v, mask, *, scale, causal, window, block_q, block_k,
-               interpret):
+               interpret, edge=None):
     """q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v];
     mask: [B, S] routed per program. Returns out [BH, S, d_v] and the
     logsumexp [BH, 1, S]."""
@@ -481,7 +530,7 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, window, block_q, block_k,
         window=window)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          window=window),
+                          window=window, edge=edge),
         grid=(bh, seq // block_q, num_k),
         in_specs=[q_spec, k_spec, v_spec, mask_spec],
         out_specs=[o_spec, row_spec],
@@ -552,7 +601,8 @@ def _probabilities(s, lse, keep):
 
 
 def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
-                  block_k: int, window: Optional[int] = None):
+                  block_k: int, window: Optional[int] = None,
+                  edge: Optional[Edge] = None):
     """Calls ``step(when, diagonal)`` for the tile of query block ``qi``
     and key block ``j``: once where nothing is causal; else once for the
     live tiles the diagonal or the window's lower edge crosses, which need
@@ -563,6 +613,10 @@ def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
         step(True, False)
         return
     under = (j + 1) * block_k - 1 <= qi * block_q
+    if edge is not None and edge[1]:
+        # a strict edge hides the first row's own block: the tile's last
+        # key lies before that row
+        under = (j + 1) * block_k <= qi * block_q
     if window is not None:
         # the tile's last row still sees its first key
         under = jnp.logical_and(
@@ -573,7 +627,7 @@ def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
 
 def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
                 scale: float, causal: bool, window: Optional[int],
-                num_q: int, fused: bool):
+                num_q: int, fused: bool, edge: Optional[Edge] = None):
     """One (batch x K/V head, key block, query head of the group x query
     block) grid step of dk and dv. The tile is the transposed one, keys
     down the sublanes and queries along the lanes, so that the logsumexp
@@ -599,8 +653,8 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
         ) * scale  # [block_k, block_q] f32
         keep = mask_ref[:] > 0  # [block_k, 1]: this block's key validity
         if diagonal:
-            keep = jnp.logical_and(keep,
-                                   _visible(qi, j, st.shape, 1, window))
+            keep = jnp.logical_and(
+                keep, _visible(qi, j, st.shape, 1, window, edge))
         pt = _probabilities(st, lse_ref[:], keep)
         dpt = jax.lax.dot_general(
             v_ref[:], do_ref[:], (((1,), (1,)), ((), ())),
@@ -658,7 +712,7 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
         # and query blocks wholly past the window, by the other bound
         live = jnp.logical_and(
             live, qi <= _last_query(j, block_q, block_k, window, num_q))
-    _causal_steps(step, causal, live, qi, j, block_q, block_k, window)
+    _causal_steps(step, causal, live, qi, j, block_q, block_k, window, edge)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finalize():
@@ -667,7 +721,8 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
 
 def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
                dq_ref, dq_scr, lse_scr, delta_scr, *, scale: float,
-               causal: bool, window: Optional[int]):
+               causal: bool, window: Optional[int],
+               edge: Optional[Edge] = None):
     """One (batch x head, query block, key block) grid step of dq, the
     forward's grid and index maps. The tile has the queries down the
     sublanes, so the logsumexp and delta rows are turned into columns,
@@ -692,8 +747,8 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
         ) * scale  # [block_q, block_k] f32
         keep = mask_ref[:] > 0  # [1, block_k]
         if diagonal:
-            keep = jnp.logical_and(keep,
-                                   _visible(qi, j, s.shape, 0, window))
+            keep = jnp.logical_and(
+                keep, _visible(qi, j, s.shape, 0, window, edge))
         p = _probabilities(s, lse_scr[:, :1], keep)
         dp = jax.lax.dot_general(
             do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
@@ -714,7 +769,7 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
     if window is not None:
         live = jnp.logical_and(
             live, j >= _first_key(qi, block_q, block_k, window))
-    _causal_steps(step, causal, live, qi, j, block_q, block_k, window)
+    _causal_steps(step, causal, live, qi, j, block_q, block_k, window, edge)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -722,7 +777,7 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
 
 
 def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
-               block_q, block_k, interpret):
+               block_q, block_k, interpret, edge=None):
     """dk, dv: grid (B * h_kv, S / block_k, group * S / block_q). The
     innermost axis walks the query heads a K/V head serves and, within
     each, the query blocks from the first live one. With ``delta`` None
@@ -766,7 +821,8 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
                      lambda g, j, t: (q_head(g, t), 0, q_block(j, t)))
     dk, dv, *dq = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          window=window, num_q=num_q, fused=fused),
+                          window=window, num_q=num_q, fused=fused,
+                          edge=edge),
         grid=(bh_kv, seq // block_k, steps),
         in_specs=[
             q_spec, do_spec, row_spec, k_spec, v_spec,
@@ -790,7 +846,7 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
 
 
 def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, window,
-              block_q, block_k, interpret):
+              block_q, block_k, interpret, edge=None):
     """dq: the forward's grid, K/V head ``bh // group`` and the clamps at
     ``_last_live`` and, under a window, ``_first_key``."""
     bh, seq, d = q.shape
@@ -799,7 +855,7 @@ def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, window,
         window=window)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          window=window),
+                          window=window, edge=edge),
         grid=(bh, seq // block_q, seq // block_k),
         in_specs=[q_spec, do_spec, row_spec, row_spec, k_spec, v_spec,
                   mask_spec],
@@ -816,55 +872,64 @@ def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, window,
     )(q, do, lse, delta, k, v, mask[:, None, :])
 
 
-def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, window,
-               interpret):
+def _flash_bwd(q, k, v, mask, out, lse, do, dlse=None, *, scale, causal,
+               window, interpret, edge=None):
     """dq, dk, dv of ``_flash_fwd`` by two Mosaic calls, or by one where
     one tile spans the sequence and each K/V head serves one query head.
     q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v]; out,
-    do: [BH, S, d_v]; mask: [B, S]; lse: [BH, 1, S]. Exact probabilities
-    are recomputed per tile from the logsumexp; no [S, S] tensor reaches
-    HBM."""
+    do: [BH, S, d_v]; mask: [B, S]; lse: [BH, 1, S]; ``dlse``: the
+    logsumexp's own cotangent where it was an output, [BH, 1, S]. Exact
+    probabilities are recomputed per tile from the logsumexp; no [S, S]
+    tensor reaches HBM."""
     seq, d = q.shape[1:]
     block_q, block_k = backward_tiles(seq, d, q.dtype.itemsize, causal,
                                       v.shape[-1])
     delta = None
-    if not (q.shape == k.shape and block_q == block_k == seq):
+    if dlse is not None or not (q.shape == k.shape
+                                and block_q == block_k == seq):
         # D_i = sum_d dO_i * O_i, the softmax jacobian's row term, as a row
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)[:, None, :]
+    if dlse is not None:
+        # d lse_i / d s_ij = p_ij: dS = p (dP - delta + dlse)
+        delta = delta - dlse
     args = (q, do, lse, delta, k, v, mask.astype(jnp.int32))
     kwargs = dict(scale=scale, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, interpret=interpret)
+                  block_q=block_q, block_k=block_k, interpret=interpret,
+                  edge=edge)
     dk, dv, dq = _flash_dkv(*args, **kwargs)
     if dq is None:
         dq = _flash_dq(*args, **kwargs)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, mask, scale, causal, window, block_q, block_k,
-           interpret):
-    out, _ = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
-                        window=window, block_q=block_q, block_k=block_k,
-                        interpret=interpret)
-    return out
-
-
-def _flash_vjp_fwd(q, k, v, mask, scale, causal, window, block_q, block_k,
-                   interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 12)))
+def _flash(q, k, v, mask, scale, causal, window, edge, with_lse, block_q,
+           block_k, interpret):
+    """The output [BH, S, d_v] and, ``with_lse``, the logsumexp [BH, 1, S]
+    beside it as a second output with a cotangent of its own."""
     out, lse = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
-                          interpret=interpret)
+                          interpret=interpret, edge=edge)
+    return (out, lse) if with_lse else out
+
+
+def _flash_vjp_fwd(q, k, v, mask, scale, causal, window, edge, with_lse,
+                   block_q, block_k, interpret):
+    out, lse = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
+                          window=window, block_q=block_q, block_k=block_k,
+                          interpret=interpret, edge=edge)
     # named on the variables the backward reads: a name on the call's
     # result, outside the custom_vjp, leaves these two unnamed
     out, lse = map(checkpoint_name, (out, lse), KEPT)
-    return out, (q, k, v, mask, out, lse)
+    return ((out, lse) if with_lse else out), (q, k, v, mask, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, window, block_q, block_k, interpret, res,
-                   do):
-    dq, dk, dv = _flash_bwd(*res, do, scale=scale, causal=causal,
-                            window=window, interpret=interpret)
+def _flash_vjp_bwd(scale, causal, window, edge, with_lse, block_q, block_k,
+                   interpret, res, g):
+    do, dlse = g if with_lse else (g, None)
+    dq, dk, dv = _flash_bwd(*res, do, dlse, scale=scale, causal=causal,
+                            window=window, interpret=interpret, edge=edge)
     return dq, dk, dv, None
 
 
@@ -873,10 +938,12 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                     causal: bool = False, window: Optional[int] = None,
+                    edge_block: Optional[int] = None,
+                    strict_edge: bool = False, return_lse: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    mesh: Optional[Mesh] = None) -> jax.Array:
+                    mesh: Optional[Mesh] = None):
     """Fused flash attention. ``q``: [B, S, h, d] (the model-side layout
     of ps_tpu/models/{bert,lm}.py); ``k``: [B, S, h_kv, d] and ``v``:
     [B, S, h_kv, d_v] with ``h_kv`` dividing ``h`` (each K/V head serves
@@ -889,7 +956,14 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     ``0 <= i - j < window``, itself and the ``window - 1`` before it; the
     kernels skip, and do not fetch, the blocks wholly outside the band, so
     the call's work follows ``S * window`` and not ``S ** 2 / 2``. One that
-    spans the sequence is the causal call. Returns [B, S, h, d_v].
+    spans the sequence is the causal call. ``edge_block`` (causal calls
+    without a window): the edge at the granularity of blocks of that many
+    positions, a power of two that divides 128: query ``i`` sees every key
+    before the end of its own block, or with ``strict_edge`` before its
+    start; the first block's rows then see no key, and return zeros.
+    Returns [B, S, h, d_v]; with ``return_lse`` the pair of it and the
+    logsumexp of each row's visible scores [B, S, h] in f32 (about -1e30
+    for a row that sees no key), an output with a gradient of its own.
 
     ``block_q`` / ``block_k`` tile the forward kernel; left at None they
     are ``forward_tiles``' choice from the operands' shapes. ``interpret``
@@ -917,6 +991,15 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                              f"least the query's own position")
         if window >= seq:
             window = None   # every earlier key is inside it
+    edge = None
+    if edge_block is not None:
+        if not causal or window is not None or 128 % edge_block:
+            raise ValueError(
+                f"edge_block={edge_block} needs causal=True, no window and "
+                f"a block length that divides 128")
+        edge = (int(edge_block), bool(strict_edge))
+    elif strict_edge:
+        raise ValueError("strict_edge says which blocks an edge_block sees")
     if block_q is None or block_k is None:
         chosen = forward_tiles(seq, d, q.dtype.itemsize, causal, d_v)
         block_q, block_k = block_q or chosen[0], block_k or chosen[1]
@@ -940,8 +1023,12 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                 lb * x.shape[2], seq, x.shape[3])
 
         out = _flash(pack(q), pack(k), pack(v), mask, scale, causal,
-                     window, block_q, block_k, interpret)
-        return jnp.transpose(out.reshape(lb, lh, seq, d_v), (0, 2, 1, 3))
+                     window, edge, return_lse, block_q, block_k, interpret)
+        if return_lse:
+            out, lse = out
+            lse = jnp.transpose(lse.reshape(lb, lh, seq), (0, 2, 1))
+        out = jnp.transpose(out.reshape(lb, lh, seq, d_v), (0, 2, 1, 3))
+        return (out, lse) if return_lse else out
 
     if mesh is None:
         from ps_tpu import api
@@ -959,6 +1046,7 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     # check_vma off: jax 0.9.0 types the kernel's VMEM scratch as unvarying
     # and refuses to mix it with the varying loads inside the kernel body
     # ("Primitive mul requires varying manual axes to match")
+    out_specs = (spec, P(*spec[:3])) if return_lse else spec
     return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec,
                                                  P(spec[0], None)),
-                     out_specs=spec, check_vma=False)(q, k, v, mask)
+                     out_specs=out_specs, check_vma=False)(q, k, v, mask)

@@ -21,9 +21,11 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
               "trinity": ("pallas",),
               # Mellum's has no kernel, no mesh and no exchange
-              "mellum": ("pallas", "shard_map", "all_to_all", "ragged_dot")}
+              "mellum": ("pallas", "shard_map", "all_to_all", "ragged_dot"),
+              # SDAR's is one explicit mask: no kernel, no logsumexp merged
+              "sdar": ("pallas", "logaddexp")}
 MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
-          "mellum")
+          "mellum", "sdar")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))

@@ -97,6 +97,35 @@ def test_flash_forward_and_backward_compile_at_the_cells_shapes(
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("strict", [False, True],
+                         ids=["clean-queries", "noised-queries"])
+def test_the_edged_flash_calls_compile_at_sdars_shape(strict, one_chip,
+                                                      no_compile_cache):
+    """``sdar-30b-a3b.s8192.b1.zipf.bd4``'s two calls a layer at [1, 8192,
+    32 on 4, 128] (a step's batch of two): the edge a block of 4 wide, and,
+    for the noised queries, the strict edge with the logsumexp as a second
+    output whose cotangent the backward takes: three Mosaic calls each, no
+    loop of XLA's."""
+    def arg(heads):
+        return jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, edge_block=4,
+                              strict_edge=strict, return_lse=strict,
+                              interpret=False)
+        if not strict:
+            return jnp.sum(out.astype(jnp.float32))
+        out, lse = out
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(
+            jnp.logaddexp(lse, 0.0))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(32), arg(4), arg(4)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " while(" not in text
+
+
 def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     bcx = jax.ShapeDtypeStruct((2, 8192, 3 * 2048), jnp.bfloat16,
                                sharding=one_chip)

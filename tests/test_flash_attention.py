@@ -708,3 +708,224 @@ def test_without_a_window_the_program_is_the_one_it_was(monkeypatch, cell):
     assert tuple(names) == fa.KEPT
     assert text(stripped) == text(plain.jaxpr)
     assert text(named.jaxpr) != text(plain.jaxpr)
+
+
+# -- an edge a block wide, rows that see no key, the logsumexp as an output ----
+
+def _ref_edge(q, k, v, block, strict):
+    """Dense attention under an edge at a block's granularity: query ``i``
+    sees the keys before the end of its block, or (strict) before its
+    start. K/V repeated for the query heads they serve. Returns the output
+    and the logsumexp [B, S, h]; a row that sees no key gives zeros and
+    -1e30."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    of = jnp.arange(q.shape[1]) // block
+    seen = of[None, :] < of[:, None] if strict else of[None, :] <= of[:, None]
+    some = jnp.any(seen, axis=-1)[None, None, :, None]
+    m = jnp.max(jnp.where(seen, s, -jnp.inf), axis=-1, keepdims=True)
+    m = jnp.where(some, m, 0.0)
+    p = jnp.where(seen, jnp.exp(s - m), 0.0)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p / jnp.where(some, total, 1.0), v)
+    lse = jnp.where(some, m + jnp.log(jnp.where(some, total, 1.0)), -1e30)
+    return out, jnp.transpose(lse[..., 0], (0, 2, 1))
+
+
+# (seq, heads, K/V heads, head_dim, edge block, strict, forward tiles,
+# backward tiles); None = the rule's choice
+EDGE_CASES = [
+    pytest.param(512, 2, 2, 64, 4, False, (128, 128), (128, 128), id="b4-inclusive-q128-k128"),
+    pytest.param(512, 2, 2, 64, 4, True, (128, 128), (128, 128), id="b4-strict-q128-k128"),
+    pytest.param(512, 4, 1, 64, 4, False, (256, 128), (256, 128), id="b4-inclusive-4to1-q256-k128"),
+    pytest.param(512, 4, 1, 64, 4, True, (256, 128), (256, 128), id="b4-strict-4to1-q256-k128"),
+    pytest.param(512, 2, 2, 64, 32, True, (128, 128), (256, 128), id="b32-strict-q128-k128"),
+    pytest.param(512, 2, 2, 64, 128, False, (128, 128), (128, 128), id="b128-inclusive-a-tile"),
+    pytest.param(512, 2, 2, 64, 128, True, (128, 128), (128, 128), id="b128-strict-a-tile"),
+    pytest.param(512, 2, 2, 64, 1, False, (128, 128), (128, 128), id="b1-is-causal"),
+    pytest.param(512, 2, 2, 64, 16, True, (512, 512), (512, 512), id="b16-strict-one-block"),
+    pytest.param(1024, 8, 1, 128, 4, True, None, None, id="b4-strict-8to1-d128-chosen"),
+]
+
+
+@pytest.mark.parametrize("seq,h,h_kv,d,block,strict,forward,backward",
+                         EDGE_CASES)
+def test_block_edge_matches_dense_forward_and_gradients(
+        monkeypatch, seq, h, h_kv, d, block, strict, forward, backward):
+    """The edge a block wide, inclusive and strict, in the forward, the
+    dk / dv and the dq kernel: output, logsumexp and the gradients of q, k
+    and v through both outputs are the dense form's, at forced and chosen
+    tiles. The first block's rows under the strict edge see no key."""
+    if backward is not None:
+        monkeypatch.setattr(fa, "backward_tiles", lambda *shape: backward)
+    block_q, block_k = forward or (None, None)
+    q, _, _ = _qkv(41, s=seq, b=1, h=h, d=d)
+    _, k, v = _qkv(42, s=seq, b=1, h=h_kv, d=d)
+    rng = np.random.default_rng(43)
+    w_out = jnp.asarray(rng.normal(0, 1, (1, seq, h, d)).astype(np.float32))
+    w_lse = jnp.asarray(rng.normal(0, 1, (1, seq, h)).astype(np.float32))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, edge_block=block,
+                               strict_edge=strict, return_lse=True,
+                               block_q=block_q, block_k=block_k)
+
+    def ref(q, k, v):
+        return _ref_edge(q, k, v, block, strict)
+
+    def loss(attn):
+        def of(q, k, v):
+            out, lse = attn(q, k, v)
+            # a row that sees no key has no logsumexp to weigh
+            return jnp.sum(out * w_out) + jnp.sum(
+                jnp.where(lse > -1e29, lse * w_lse, 0.0))
+        return of
+
+    for got, want, name in zip(flash(q, k, v), ref(q, k, v),
+                               ("out", "lse")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
+    g_got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for got, want, name in zip(g_got, g_want, "qkv"):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=5e-4, atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+def test_rows_that_see_no_key_give_zeros_and_no_nan(return_lse):
+    """Under the strict edge the first block's queries see nothing: output
+    0, a logsumexp of -1e30's size, and gradients that are finite
+    everywhere and exactly zero for those queries."""
+    block = 8
+    q, k, v = _qkv(44, s=256, b=1, h=2, d=64)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, edge_block=block,
+                               strict_edge=True, return_lse=return_lse)
+
+    got = flash(q, k, v)
+    out, lse = got if return_lse else (got, None)
+    np.testing.assert_array_equal(np.asarray(out)[:, :block], 0.0)
+    assert np.isfinite(np.asarray(out)).all()
+    if return_lse:
+        assert (np.asarray(lse)[:, :block] < -1e29).all()
+        assert np.isfinite(np.asarray(lse)).all()
+        assert (np.abs(np.asarray(lse)[:, block:]) < 100).all()
+
+    def loss(q, k, v):
+        got = flash(q, k, v)
+        if not return_lse:
+            return jnp.sum(got ** 2)
+        # the merge that reads the logsumexp: weights of two key sets
+        out, lse = got
+        return jnp.sum(out ** 2) + jnp.sum(jnp.logaddexp(lse, 0.0))
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+    np.testing.assert_array_equal(np.asarray(grads[0])[:, :block], 0.0)
+    # the last block's keys are seen by no query at all
+    for g in grads[1:]:
+        np.testing.assert_array_equal(np.asarray(g)[:, -block:], 0.0)
+
+
+def test_the_logsumexp_carries_a_cotangent_of_its_own():
+    """A loss of the logsumexp alone: dS = p * dlse, through both backward
+    kernels, on a plain causal call too."""
+    q, k, v = _qkv(45, s=256, b=2, h=4, d=64)
+    for kw, (block, strict) in (({}, (1, False)),
+                                ({"edge_block": 4}, (4, False))):
+        def flash(q, k, v):
+            return jnp.sum(jnp.sin(flash_attention(
+                q, k, v, causal=True, return_lse=True, **kw)[1]))
+
+        def ref(q, k, v):
+            return jnp.sum(jnp.sin(_ref_edge(q, k, v, block, strict)[1]))
+
+        for got, want, name in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
+                                   jax.grad(ref, (0, 1, 2))(q, k, v), "qkv"):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=5e-4, atol=5e-4, err_msg=name)
+        np.testing.assert_array_equal(
+            np.asarray(jax.grad(flash, 2)(q, k, v)), 0.0)
+
+
+def test_an_edge_is_refused_where_it_has_no_meaning():
+    q, k, v = _qkv(46)
+    with pytest.raises(ValueError, match="edge_block"):
+        flash_attention(q, k, v, edge_block=4)
+    with pytest.raises(ValueError, match="edge_block"):
+        flash_attention(q, k, v, causal=True, window=64, edge_block=4)
+    with pytest.raises(ValueError, match="edge_block"):
+        flash_attention(q, k, v, causal=True, edge_block=48)
+    with pytest.raises(ValueError, match="strict_edge"):
+        flash_attention(q, k, v, causal=True, strict_edge=True)
+
+
+def test_under_a_mesh_the_edge_and_the_logsumexp_run_sharded():
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    q, _, _ = _qkv(47, s=256, b=2, h=4, d=64)
+    _, k, v = _qkv(48, s=256, b=2, h=2, d=64)
+    got = flash_attention(q, k, v, causal=True, edge_block=4,
+                          strict_edge=True, return_lse=True, mesh=mesh)
+    for g, w in zip(got, _ref_edge(q, k, v, 4, True)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-5, atol=2e-5)
+
+
+# sha256 (first 16 hex digits) of the text of value_and_grad's jaxpr, names
+# and all, addresses struck out, as the tree before the edge gave it (commit
+# fdcedf2, jax 0.9.0): [B, S, h, d] bf16, K/V heads, v's width, causal, window
+PROGRAMS_BEFORE_THE_EDGE = {
+    "bert": ((2, 512, 12, 64), 12, 64, False, None, "6b89a5fd508abea2"),
+    "olmoe": ((1, 4096, 16, 128), 16, 128, True, None, "b41ce700ff96f1b1"),
+    "trinity-full": ((1, 16384, 32, 128), 4, 128, True, None,
+                     "76f8390badbc8d4d"),
+    "trinity-window": ((1, 16384, 32, 128), 4, 128, True, 2048,
+                       "19ecf40257f2d1b9"),
+    "mellum-window": ((1, 8192, 32, 128), 4, 128, True, 1024,
+                      "dc32c360eb3d5b9f"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PROGRAMS_BEFORE_THE_EDGE))
+def test_without_an_edge_the_program_is_the_one_it_was(cell):
+    """No ``edge_block`` and no ``return_lse``: forward and backward trace
+    to the jaxpr the kernels gave before they knew either, kernel bodies
+    included, at the shapes of the cells that share this file."""
+    import hashlib
+    import re
+
+    shape, h_kv, d_v, causal, window, want = PROGRAMS_BEFORE_THE_EDGE[cell]
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct(shape[:2] + (h_kv, shape[3]), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:2] + (h_kv, d_v), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, window=window,
+            interpret=False).astype(jnp.float32)), argnums=(0, 1, 2)))(
+                q, k, v)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
+@pytest.mark.parametrize("seq,head_dim,causal,forward,backward", [
+    (512, 64, False, (512, 512), (512, 512)),        # bert-base.s512.flash
+    (4096, 128, True, (1024, 1024), (1024, 512)),    # olmoe-1b-7b.s4096.zipf
+    (16384, 128, True, (1024, 1024), (1024, 512)),   # trinity-mini.s16384
+    (8192, 128, True, (1024, 1024), (1024, 512)),    # mellum2 and sdar, 8,192
+])
+def test_the_cells_tiles_are_the_ones_they_were(seq, head_dim, causal,
+                                                forward, backward):
+    """The tiles take no edge: a call with one runs at the causal call's,
+    and the old calls at the ones they had."""
+    assert forward_tiles(seq, head_dim, 2, causal) == forward
+    assert backward_tiles(seq, head_dim, 2, causal) == backward
+    assert all(128 % b == 0 and t % b == 0
+               for b in (1, 2, 4, 8, 16, 32, 64, 128)
+               for t in forward + backward)

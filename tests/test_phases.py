@@ -1589,3 +1589,62 @@ def test_mellum_reader_on_a_hand_made_result(monkeypatch):
     r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
     assert mellum_metrics.scope_times(r, {}) == {}
     assert mellum_metrics.read({"counters": {}, "facts": {}}) == {}
+
+
+def _sdar_step():
+    """SDAR in small through the store: two layers on a clean and a noised
+    copy of eight sequences of 64 positions, blocks of 4, two of eight
+    experts held, three picks."""
+    from ps_tpu.models import sdar
+
+    cfg = sdar.SdarConfig(
+        vocab_size=64, hidden_size=32, moe_intermediate_size=16,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, router_width=8, num_experts=2, expert_start=2,
+        num_experts_per_tok=3, block_length=4, mask_token_id=63,
+        dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: sdar.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 64, dtype=np.int32).reshape(8, 64) * 7) % 63
+    masked = (np.arange(8 * 64).reshape(8, 64) // 4) % 2 == 0
+    step = store.make_step(sdar.make_loss_fn(cfg), has_aux=True)
+    return step, store.shard_batch({
+        "ids": ids, "noised_ids": np.where(masked, 63, ids).astype(np.int32),
+        "weights": np.where(masked, 2.0, 0.0).astype(np.float32)})
+
+
+def test_sdar_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What SDAR opens (``SDAR_SCOPES``): the six it shares with OLMoE,
+    Trinity's ``ps.attn/full`` around its two attention calls, and
+    ``ps.attn/inblock`` around the own-block term and the merge: each in the
+    lowered step's ``op_name``s under ``ps.grad``, forward and backward,
+    though every layer is under a ``jax.checkpoint``. The shared names are
+    the one decoder reader's copy, letter for letter; ``ps.attn/inblock`` is
+    the program's alone until a ``benchmark`` PR copies it, and the reader
+    counts its ops inside ``ps.attn``."""
+    from benchmark.layer_metrics import decoder
+
+    assert phases.SDAR_SCOPES[:6] == phases.MOE_SCOPES
+    assert phases.SDAR_SCOPES == phases.MOE_SCOPES + (phases.ATTN_FULL,
+                                                      phases.ATTN_INBLOCK)
+    for name in ("ATTN_FULL", "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT",
+                 "MOE_COMBINE", "ATTN", "HEAD"):
+        assert getattr(phases, name) == getattr(decoder, name)
+        assert getattr(phases, name) in phases.SDAR_SCOPES
+    assert phases.ATTN_INBLOCK not in decoder.SCOPES
+    assert not hasattr(decoder, "ATTN_INBLOCK")
+    assert not set(phases.SDAR_SCOPES) & set(phases.DEVICE_PHASES)
+    monkeypatch.setitem(BUILDERS, "sdar", _sdar_step)
+    names = scope.op_names_of(_step_hlo("sdar"))
+    for s in phases.SDAR_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == (set(phases.SDAR_SCOPES) - {phases.ATTN_INBLOCK}) | {None}
+    own_blocks = [n for n in names.values() if phases.ATTN_INBLOCK in n]
+    assert {decoder.scope_of("%x", n) for n in own_blocks} == {decoder.ATTN}
