@@ -48,15 +48,37 @@ which this module is held to. How they are computed here:
   shared expert** at the model's width, whole on every chip.
 - a final RMSNorm and an untied head.
 
-Every layer runs under one ``jax.checkpoint``: between layers the residual
-stream lives on (8 KB a token a layer in bf16) and, of the attention layer's
-flash call, its output and logsumexp (``ops/flash_attention.py::KEPT``, the
-two residuals that only the forward kernel can produce: 8.4 MB in the cell),
-so the recomputation does not run that kernel a second time; a layer's
-working set (an expert layer's 22 picks a token, a window's row buffers at
-2,688 wide, the shared expert's 5,376) exists once, while that layer's
-gradient is computed. The policy on ``_layer`` is the list of what a layer
-keeps; with ``attn='full'`` nothing bears a name and only the stream is kept.
+Every layer runs under one ``jax.checkpoint`` whose policy lists, by name,
+what the layer keeps from its forward pass for its backward pass beside the
+residual stream (8 KB a token a layer in bf16): whatever costs a matrix
+product, a ``top_k``, a sort or a kernel call to make again.
+
+- ``ops/flash_attention.py::KEPT``: the attention layer's flash output and
+  logsumexp, the two residuals that only the forward kernel can produce (8.4
+  MB in the cell);
+- ``ops/moe.py::ROUTE_KEPT``: an expert layer's router logits (the product
+  at ``Precision.HIGHEST``, six bf16 passes), its picks and the two
+  permutations of the pairs (18 MB), so that the backward pass
+  differentiates the routing the forward pass ran: on the chip a recomputed
+  layer's bf16 input is not the forward's to the bit, and a token near a tie
+  could pick another expert the second time;
+- ``PRODUCTS_KEPT``, beside ``_layer``: the outputs of a layer's first
+  matrix products (a mixer's in projection 38 MB, q / k / v 13 MB, the
+  tokens in the latent 17 MB, the shared expert's ``x W1`` 88 MB) and the
+  held experts' output in the latent (17 MB: without it the recomputation
+  runs the experts' ``combine`` again only to hand ``latent_up`` its input).
+
+0.90 GB over the cell's eleven layers, where the compiled step's peak moved
+by 0.06e9 B (it lies in a layer's backward, which holds the same working set
+either way). Everything else a layer makes is an elementwise pass, a filter
+or a scan away from these and exists once, while that layer's gradient is
+computed: an expert layer's ``[T, 22, 512]`` pick mask, a window's row
+buffers at 2,688 wide, the shared expert's activation at 5,376. A name is
+the identity where no policy lists it; with ``attn='full'`` no flash call
+runs and ``KEPT`` names nothing. What fits is a property of this model's
+compiled memory in its cell, which nothing in a layer's input shows: the
+list is this file's constant, and a model with less room (Mellum, SDAR)
+lists less.
 
 What the model does not compute, ``NemotronHConfig.from_dict`` refuses.
 
@@ -82,6 +104,7 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
 from ps_tpu.models.blocks import (WHOLE_WINDOW, make_attn_fn, rms_norm,
@@ -258,7 +281,8 @@ def mamba_block(lp: Dict, x, config: NemotronHConfig):
     c = config
     b, s, _ = x.shape
     heads, groups, inner = c.mamba_num_heads, c.n_groups, c.mamba_inner
-    projected = x @ lp["in_proj"]["kernel"].astype(x.dtype)
+    projected = checkpoint_name(
+        x @ lp["in_proj"]["kernel"].astype(x.dtype), "mamba_in")
     z, xbc, dt = jnp.split(projected, [inner, inner + c.conv_dim], axis=-1)
     with jax.named_scope(phases.MAMBA_CONV):
         xbc = conv_silu(xbc, lp["conv"]["kernel"], lp["conv"]["bias"])
@@ -289,7 +313,8 @@ def attention_block(lp: Dict, x, config: NemotronHConfig, attn_fn: Callable):
     heads, kv_heads = c.num_attention_heads, c.num_key_value_heads
 
     def proj(name, n):
-        return (x @ lp[name]["kernel"].astype(x.dtype)).reshape(b, s, n, -1)
+        projected = x @ lp[name]["kernel"].astype(x.dtype)
+        return checkpoint_name(projected, f"attn_{name}").reshape(b, s, n, -1)
 
     q, k, v = proj("q", heads), proj("k", kv_heads), proj("v", kv_heads)
     a = attn_fn(q, k, v, causal=True)
@@ -297,9 +322,9 @@ def attention_block(lp: Dict, x, config: NemotronHConfig, attn_fn: Callable):
 
 
 def relu2_ffn(lp: Dict, x):
-    """``relu(x W1) ** 2 W2``."""
-    hidden = jnp.square(jax.nn.relu(x @ lp["w1"]["kernel"].astype(x.dtype)))
-    return hidden @ lp["w2"]["kernel"].astype(x.dtype)
+    """``relu(x W1) ** 2 W2``; ``x W1`` bears the name 'shared_in'."""
+    pre = checkpoint_name(x @ lp["w1"]["kernel"].astype(x.dtype), "shared_in")
+    return jnp.square(jax.nn.relu(pre)) @ lp["w2"]["kernel"].astype(x.dtype)
 
 
 def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
@@ -316,10 +341,12 @@ def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
             renormalize=c.norm_topk_prob, scoring="sigmoid", bias=bias,
             renorm_eps=1e-20, scaling=c.routed_scaling_factor, held=c.held)
     with jax.named_scope(phases.MOE_LATENT):
-        latent = tokens @ lp["latent_down"]["kernel"].astype(x.dtype)
-    latent = moe.over_windows(WHOLE_WINDOW, routing, latent,
-                              lp["w1"].astype(x.dtype), None,
-                              lp["w2"].astype(x.dtype))
+        latent = checkpoint_name(
+            tokens @ lp["latent_down"]["kernel"].astype(x.dtype), "latent_in")
+    latent = checkpoint_name(
+        moe.over_windows(WHOLE_WINDOW, routing, latent,
+                         lp["w1"].astype(x.dtype), None,
+                         lp["w2"].astype(x.dtype)), "latent_out")
     with jax.named_scope(phases.MOE_LATENT):
         out = latent @ lp["latent_up"]["kernel"].astype(x.dtype)
     with jax.named_scope(phases.MOE_SHARED):
@@ -327,8 +354,18 @@ def moe_block(lp: Dict, x, config: NemotronHConfig, bias):
     return out.reshape(b, s, d), routing
 
 
+#: what a layer keeps beside the flash call's residuals and the routing
+#: (module docstring), by the names the values bear where they are made: a
+#: Mamba mixer's in projection, the attention's q, k and v, the tokens in
+#: the experts' latent and the held experts' output there, the shared
+#: expert's ``x W1``
+PRODUCTS_KEPT = ("mamba_in", "attn_q", "attn_k", "attn_v", "latent_in",
+                 "latent_out", "shared_in")
+
+
 @functools.partial(jax.checkpoint, static_argnums=(3, 4, 5),
-                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+                   policy=jax.checkpoint_policies.save_only_these_names(
+                       *KEPT, *moe.ROUTE_KEPT, *PRODUCTS_KEPT))
 def _layer(lp: Dict, x, bias, kind: str, config: NemotronHConfig,
            attn_fn: Callable):
     """One layer, ``x + f(rms_norm(x))``, recomputed in the backward pass:

@@ -38,12 +38,29 @@ held to. What differs here is how they are computed:
   whatever is live (``expected_rows``), plus the shared expert, whole on every
   chip of the group.
 - every layer is recomputed in the backward pass (one ``jax.checkpoint`` a
-  layer): between two layers the stream lives on and, of a flash call, the
-  two residuals that only its forward kernel can produce, its output and
-  logsumexp (``ops/flash_attention.py::KEPT``, 136 MB a layer in the cell),
-  so the recomputation does not run that kernel a second time. The policy on
-  ``_layer`` is the list of what a layer keeps; with ``attn='full'`` nothing
-  in a layer bears a name and nothing but the stream is kept.
+  layer) but for what its policy lists by name, which lives on between the
+  two passes beside the stream: whatever costs a matrix product, a
+  ``top_k``, a sort or a kernel call to make again. ``flash_attention.KEPT``:
+  a flash call's output and logsumexp (136 MB a layer in the cell), so the
+  recomputation does not run that kernel a second time.
+  ``ops/moe.py::ROUTE_KEPT``: the router's logits, picks and the pairs' two
+  permutations (10 MB), so the backward pass differentiates the routing the
+  forward pass ran. ``PRODUCTS_KEPT``, beside ``_layer``: the outputs of the
+  q, k, v and gate projections, q, k and v a head at a time and before the
+  norm (302 MB); q and k again as the attention call reads them, normed and
+  rotated, the backward kernels' operands (151 MB: the norm's backward
+  reads its input, the kernels' its output, and a recomputed norm and
+  rotation cost 3 ms a layer); the out projection's output and the
+  feed-forward branch's, which ``post_attn_norm`` and ``post_mlp_norm`` read
+  (67 MB each: without the second the recomputation runs the shared
+  expert's last product and the experts' ``combine`` only for it). 2.9 GB
+  over the cell's five layers, the compiled step's peak at 15.43e9 of
+  17.18e9 B where it was 13.93e9 (``PERF.md`` section 6, PR 51, has each
+  name's ms and bytes). The gated attention output is not kept: it took more
+  in the forward pass than its recomputation costs. A name is the identity
+  where no policy lists it. What fits is a property of this model's compiled
+  memory in its cell, which nothing in a layer's input shows: the list is
+  this file's constant.
 - a final RMSNorm and an untied head.
 
 Departures from the published model are the reference's (its docstring lists
@@ -71,6 +88,7 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
 from ps_tpu.models.blocks import (WHOLE_WINDOW, dense_ffn, make_attn_fn,
@@ -197,25 +215,39 @@ def attention_block(lp: Dict, x, config: TrinityConfig, kind: str,
     b, s, _ = x.shape
     heads, kv_heads = c.num_attention_heads, c.num_key_value_heads
 
-    def proj(name, n):
-        return (x @ lp[name]["kernel"].astype(x.dtype)).reshape(b, s, n, -1)
+    def proj(name):
+        return x @ lp[name]["kernel"].astype(x.dtype)
 
-    q = rms_norm(proj("q", heads), lp["q_norm"]["scale"], c.rms_norm_eps)
-    k = rms_norm(proj("k", kv_heads), lp["k_norm"]["scale"], c.rms_norm_eps)
-    v = proj("v", kv_heads)
-    gate = x @ lp["gate"]["kernel"].astype(x.dtype)
+    def per_head(name, n):
+        # named before the norm, whose own backward reads its input, and
+        # after the reshape: a kept [B, S, 4096] takes the stream's layout,
+        # tokens minor, and the norm over a head then costs a transposed f32
+        # copy in the forward pass and another in the recomputation (PERF.md
+        # section 6, PR 51); a kept [B, S, 32, 128] lies as the norm and the
+        # kernel read it
+        return checkpoint_name(proj(name).reshape(b, s, n, -1),
+                               f"attn_{name}")
+
+    q = rms_norm(per_head("q", heads), lp["q_norm"]["scale"], c.rms_norm_eps)
+    k = rms_norm(per_head("k", kv_heads), lp["k_norm"]["scale"],
+                 c.rms_norm_eps)
+    v = per_head("v", kv_heads)
+    gate = checkpoint_name(proj("gate"), "attn_gate")
     window = None
     if kind == WINDOWED:
         # positions and the window go together: a layer that sees every
         # earlier key rotates nothing
         q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
         window = c.sliding_window
+    # as the attention call reads them: its backward kernels' operands
+    q, k = checkpoint_name(q, "attn_q_read"), checkpoint_name(k, "attn_k_read")
     with jax.named_scope(phases.ATTN_WINDOW if window else phases.ATTN_FULL):
         a = attn_fn(q, k, v, causal=True, window=window)
     with jax.named_scope(phases.ATTN_GATE):
         a = (a.reshape(b, s, -1).astype(jnp.float32)
              * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
-    return a @ lp["out"]["kernel"].astype(x.dtype)
+    return checkpoint_name(a @ lp["out"]["kernel"].astype(x.dtype),
+                           "attn_out")
 
 
 def moe_block(lp: Dict, x, config: TrinityConfig, bias):
@@ -239,8 +271,17 @@ def moe_block(lp: Dict, x, config: TrinityConfig, bias):
     return out.reshape(b, s, d), routing
 
 
+#: what a layer keeps beside the flash call's residuals and the routing
+#: (module docstring), by the names the values bear where they are made: the
+#: outputs of the attention's four projections, q and k again as the
+#: attention call reads them, and what each branch hands its second norm
+PRODUCTS_KEPT = ("attn_q", "attn_k", "attn_v", "attn_gate", "attn_q_read",
+                 "attn_k_read", "attn_out", "ffn_out")
+
+
 @functools.partial(jax.checkpoint, static_argnums=(3, 4, 5),
-                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+                   policy=jax.checkpoint_policies.save_only_these_names(
+                       *KEPT, *moe.ROUTE_KEPT, *PRODUCTS_KEPT))
 def _layer(lp: Dict, x, bias, kind: str, config: TrinityConfig,
            attn_fn: Callable):
     """One layer, both branches between their two norms, recomputed in the
@@ -259,9 +300,10 @@ def _layer(lp: Dict, x, bias, kind: str, config: TrinityConfig,
     h = norm("pre_mlp_norm", x)
     if "ffn" in lp:
         with jax.named_scope(phases.FFN):
-            out = dense_ffn(lp["ffn"], h)
+            out = checkpoint_name(dense_ffn(lp["ffn"], h), "ffn_out")
         return x + norm("post_mlp_norm", out), None, None, None
     out, routing = moe_block(lp["moe"], h, config, bias)
+    out = checkpoint_name(out, "ffn_out")
     return (x + norm("post_mlp_norm", out), routing.counts,
             routing.group_sizes, moe.live_windows(routing))
 

@@ -164,6 +164,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ps_tpu.obs import phases
 from ps_tpu.ops.grouped_matmul import gmm
@@ -231,6 +232,23 @@ def _permute_bwd(res, g):
 permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+#: the names of what ``route`` makes that costs a product or a sort to make
+#: again: the logits, the picks of ``top_k`` and the two permutations of the
+#: pairs. A caller's ``jax.checkpoint`` keeps them with
+#: ``save_only_these_names(*ROUTE_KEPT)``; under no checkpoint, or one whose
+#: policy does not list them, the names are the identity
+ROUTE_KEPT = ("route_logits", "route_experts", "route_order",
+              "route_inverse")
+_LOGITS, _EXPERTS, _ORDER, _INVERSE = ROUTE_KEPT
+
+
+def _sorted_pairs(keys):
+    """``order`` and ``inverse`` of the pairs sorted by ``keys``, stable,
+    under their names of ``ROUTE_KEPT``."""
+    order = checkpoint_name(jnp.argsort(keys, stable=True), _ORDER)
+    return order, checkpoint_name(jnp.argsort(order), _INVERSE)
+
+
 def route(x, router, top_k: int, renormalize: bool = False, *,
           scoring: str = "softmax", bias=None, renorm_eps: float = 0.0,
           scaling: float = 1.0, held=None) -> Routing:
@@ -248,9 +266,17 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     of ``count`` picks a token, every held one among them. A share's routing
     carries the window of rows its layer moves at a time (``window_rows``).
     The matmul runs at the highest precision: 2 * T * D * E operations, and
-    which expert a token goes to should not hang on a bf16 pass."""
-    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
+    which expert a token goes to should not hang on a bf16 pass. The logits,
+    the picks and the pairs' two permutations bear the names ``ROUTE_KEPT``:
+    a layer's checkpoint that lists them runs the product (six bf16 passes),
+    the ``top_k`` and the two sorts once a step and not again for the
+    backward pass, which then differentiates the routing the forward pass
+    ran; on the chip a recomputed layer's bf16 input is not the forward's to
+    the bit, and a token near a tie may pick another expert the second
+    time."""
+    logits = checkpoint_name(
+        jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), _LOGITS)
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
@@ -261,7 +287,7 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     select = jax.lax.stop_gradient(probs)
     if bias is not None:
         select = select + jax.lax.stop_gradient(bias).astype(select.dtype)
-    _, experts = jax.lax.top_k(select, top_k)
+    experts = checkpoint_name(jax.lax.top_k(select, top_k)[1], _EXPERTS)
     # the picks' probabilities through a 0/1 mask, so that their cotangent
     # is a dense product and not a scatter into [T, E]
     picked = jax.nn.one_hot(experts, num_experts, dtype=probs.dtype)
@@ -273,8 +299,7 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
         weights = weights * scaling
     flat = experts.reshape(-1)
     if held is None or tuple(held) == (0, num_experts):
-        order = jnp.argsort(flat, stable=True)
-        inverse = jnp.argsort(order)
+        order, inverse = _sorted_pairs(flat)
         group_sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
         return Routing(logits, probs, weights, experts.astype(jnp.int32),
                        group_sizes, order, inverse, group_sizes)
@@ -293,8 +318,7 @@ def route(x, router, top_k: int, renormalize: bool = False, *,
     # held pairs first, in expert order; the absent ones after them
     local = flat - start
     is_held = (local >= 0) & (local < count)
-    order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
-    inverse = jnp.argsort(order)
+    order, inverse = _sorted_pairs(jnp.where(is_held, local, count))
     rows = window_rows(x.shape[0], top_k, count, num_experts)
     return Routing(logits, probs, weights, experts.astype(jnp.int32),
                    counts[start:start + count], order, inverse, counts,

@@ -1,0 +1,111 @@
+"""What a layer's ``jax.checkpoint`` keeps by name, read off the traced
+gradient: ``ops/moe.py::ROUTE_KEPT`` is the identity wherever no policy lists
+it (the five models that share ``route`` and do not) and takes the router's
+product, its ``top_k`` and its two sorts out of the recomputation where one
+does. The two models whose policies list it have their own cases in
+``test_nemotron_h.py`` and ``test_trinity.py``."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from jaxpr_tools import (checkpoint_names, equations, highest_products,
+                         primitives, traced_and_run)
+from ps_tpu.models import blocks
+from ps_tpu.ops import moe
+
+#: the models that call ``route`` and keep none of its names, each with the
+#: ``name`` equations its gradient holds: four a call of ``route``, counted
+#: once where it runs under no checkpoint (OLMoE's layers in small: two;
+#: LFM2's and Kimi's expert layers: four each) and twice, forward and
+#: recomputation, where a layer's policy lists the flash call's residuals
+#: alone (Mellum's four layers, on a mesh of four; SDAR's three)
+SHARE_ROUTE = {"olmoe": 4 * 2, "lfm2": 4 * 4, "kimi_linear": 4 * 4,
+               "mellum": 2 * 4 * 4, "sdar": 2 * 4 * 3}
+
+
+def _traced_gradient(name):
+    tests = importlib.import_module(f"test_{name}")
+    model = importlib.import_module(f"ps_tpu.models.{name}")
+    _, cfg, *args = tests._setup()
+    kw = {"mesh": tests._mesh()} if name == "mellum" else {}
+    fn = jax.value_and_grad(model.make_loss_fn(cfg, **kw), has_aux=True)
+    return jax.make_jaxpr(fn)(*args).jaxpr
+
+
+@pytest.fixture
+def without_names(monkeypatch):
+    """Call it to take ``route``'s names out. jax keeps a checkpointed
+    layer's trace by the layer's identity, so the traces made before are
+    dropped, and those made without names when the test is over."""
+    def patch():
+        monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
+        jax.clear_caches()
+
+    yield patch
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("name", sorted(SHARE_ROUTE))
+def test_routes_names_are_the_identity_where_no_policy_lists_them(
+        name, without_names):
+    """The loss's gradient with ``route``'s names is, equation for equation,
+    the one without them (``checkpoint_name`` patched out, which is the
+    parent's ``route``) but for the ``name`` equations themselves: as many
+    ``dot_general``s, ``top_k``s and sorts, in the same order."""
+    named = _traced_gradient(name)
+    assert checkpoint_names(named) == set(moe.ROUTE_KEPT)
+    without_names()
+    plain = primitives(_traced_gradient(name))
+    assert "name" not in plain and plain.count("top_k") > 0
+    listed = primitives(named)
+    assert listed.count("name") == SHARE_ROUTE[name]
+    assert [p for p in listed if p != "name"] == plain
+
+
+@pytest.mark.parametrize("held", [None, (4, 2)], ids=["all", "share"])
+def test_a_policy_that_lists_routes_names_routes_once(held):
+    """An expert layer under a ``jax.checkpoint`` whose policy is
+    ``ROUTE_KEPT``, against the same under one without a policy: the
+    gradient holds one ``top_k`` where the other holds two, one router
+    product at ``HIGHEST`` fewer (the forward's once, and the two of its
+    backward), two sorts fewer (``order`` and ``inverse``), and the same
+    bits."""
+    tokens, width, experts, top_k, hidden = 64, 32, 16, 6, 24
+    rng = np.random.default_rng(0)
+    x, router, w1, w2 = (
+        jnp.asarray(rng.normal(size=shape), jnp.float32)
+        for shape in ((tokens, width), (width, experts),
+                      (held[1] if held else experts, width, hidden),
+                      (held[1] if held else experts, hidden, width)))
+
+    def layer(x, router, w1, w2):
+        routing = moe.route(x, router, top_k, renormalize=True,
+                            scoring="sigmoid", held=held)
+        out = moe.over_windows(blocks.LIVE_ROWS, routing, x, w1, None, w2)
+        return x + out
+
+    def gradient(**policy):
+        fn = jax.grad(lambda *a: jnp.sum(jax.checkpoint(layer, **policy)(*a)),
+                      argnums=(0, 1, 2, 3))
+        return traced_and_run(fn, x, router, w1, w2)
+
+    kept, grads = gradient(
+        policy=jax.checkpoint_policies.save_only_these_names(*moe.ROUTE_KEPT))
+    plain, plain_grads = gradient()
+    assert primitives(kept).count("top_k") == 1
+    assert primitives(plain).count("top_k") == 2
+    assert highest_products(plain) - highest_products(kept) == 1
+    router_products = [e for e in equations(kept)
+                       if e.primitive.name == "dot_general"
+                       and {v.aval.shape for v in e.invars} & {router.shape}]
+    assert len(router_products) == 2      # the forward's and x's cotangent
+    assert primitives(plain).count("sort") - primitives(kept).count("sort") \
+        == 2
+    for g, w in zip(grads, plain_grads):
+        assert float(jnp.max(jnp.abs(g))) > 0
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

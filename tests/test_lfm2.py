@@ -433,15 +433,23 @@ def _route_before_pr32(x, router, top_k, renormalize=False):
 
 @pytest.mark.parametrize("renormalize", [False, True])
 @pytest.mark.parametrize("held", [None, (0, 16)])
-def test_route_with_softmax_and_every_expert_is_bitwise_todays(renormalize,
-                                                               held):
+def test_route_with_softmax_and_every_expert_is_bitwise_todays(
+        renormalize, held, monkeypatch):
     _, lp, x, _ = _layer()
     got = moe.route(x[0], lp["router"]["kernel"], 4, renormalize, held=held)
     want = _route_before_pr32(x[0], lp["router"]["kernel"], 4, renormalize)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     assert got.live is None and got.counts is got.group_sizes
-    # and the traced program is the same, op for op
+    # and the traced program is the same, op for op, but for the four names
+    # ``route`` gives since PR 51 (``ROUTE_KEPT``: the identity under no
+    # checkpoint)
+    named = jax.make_jaxpr(lambda x, r: moe.route(x, r, 4, renormalize,
+                                                  held=held)[:7])(
+        x[0], lp["router"]["kernel"])
+    assert [e.params["name"] for e in named.jaxpr.eqns
+            if e.primitive.name == "name"] == list(moe.ROUTE_KEPT)
+    monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
     a = jax.make_jaxpr(lambda x, r: moe.route(x, r, 4, renormalize,
                                               held=held)[:7])(
         x[0], lp["router"]["kernel"])

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_tools import layers_keep_the_flash_residuals_alone
+from jaxpr_tools import layers_keep_what_their_policy_lists
 from benchmark.families import nemotron_h_reference as reference
 from benchmark.families import nemotron_h_step
 from benchmark.layer_metrics import nemo as nemo_metrics
@@ -139,16 +139,23 @@ def test_system_matches_reference(attn):
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
-def test_a_layers_checkpoint_keeps_the_flash_residuals_and_nothing_else(
-        monkeypatch, attn):
-    """With 'flash' the loss's gradient holds three kernel calls an
-    attention layer, forward, dk / dv and dq, where a ``jax.checkpoint``
-    without a policy holds four, the forward run again for its output and
-    logsumexp; loss and every gradient are the same bits. With 'full'
-    nothing in a layer bears a name and the trace is the policy-less one."""
+def test_a_layers_checkpoint_keeps_what_its_policy_lists(monkeypatch, attn):
+    """Against a ``jax.checkpoint`` without a policy the loss and every
+    gradient are the same bits; with 'flash' the loss's gradient holds three
+    kernel calls an attention layer, forward, dk / dv and dq, where the
+    policy-less one holds four; and it holds 33 matrix products fewer: those
+    whose outputs bear a name the policy lists, a Mamba layer's in projection
+    (five layers), an expert layer's router, ``latent_down`` and the shared
+    expert's first, the attention layer's q, k and v; and, with the held
+    experts' output kept, the two run sums of an expert layer's ``combine``
+    (``ops/moe.py::_sum_rows``), which the recomputation ran only to hand
+    ``latent_up`` its input."""
     _, *loss_args = _setup()
-    layers_keep_the_flash_residuals_alone(monkeypatch, nemotron_h, loss_args,
-                                          attn, attention_layers=1)
+    kept = {"M": 1, "E": 3 + 2, "*": 3}
+    layers_keep_what_their_policy_lists(
+        monkeypatch, nemotron_h, loss_args, attn, attention_layers=1,
+        fewer_products=sum(kept[kind] for kind in
+                           SIZES["hybrid_override_pattern"]))
 
 
 def test_fused_step_matches_reference():

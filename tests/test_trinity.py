@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_tools import layers_keep_the_flash_residuals_alone
+from jaxpr_tools import layers_keep_what_their_policy_lists
 from benchmark.families import trinity_reference as reference
 from benchmark.families import trinity_step
 from benchmark.layer_metrics import trinity as trinity_metrics
@@ -127,16 +127,21 @@ def test_system_matches_reference(attn):
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
-def test_a_layers_checkpoint_keeps_the_flash_residuals_and_nothing_else(
-        monkeypatch, attn):
-    """With 'flash' the loss's gradient holds three kernel calls an
-    attention layer, forward, dk / dv and dq, where a ``jax.checkpoint``
-    without a policy holds four, the forward run again for its output and
-    logsumexp; loss and every gradient are the same bits. With 'full'
-    nothing in a layer bears a name and the trace is the policy-less one."""
+def test_a_layers_checkpoint_keeps_what_its_policy_lists(monkeypatch, attn):
+    """Against a ``jax.checkpoint`` without a policy the loss and every
+    gradient are the same bits; with 'flash' the loss's gradient holds three
+    kernel calls an attention layer, forward, dk / dv and dq, where the
+    policy-less one holds four; and it holds 42 matrix products fewer. Those
+    whose outputs bear a name the policy lists: the q, k, v, gate and out
+    projections of each of the five layers and the router of each of the
+    four expert layers. And those that ran only to hand ``post_mlp_norm`` its
+    input, which is kept: the dense layer's ``w2`` product, and of an expert
+    layer the shared expert's ``w2`` product and the two run sums of
+    ``combine`` (``ops/moe.py::_sum_rows``)."""
     _, *loss_args = _setup()
-    layers_keep_the_flash_residuals_alone(monkeypatch, trinity, loss_args,
-                                          attn, attention_layers=5)
+    layers_keep_what_their_policy_lists(
+        monkeypatch, trinity, loss_args, attn, attention_layers=5,
+        fewer_products=5 * (4 + 1) + 1 + 4 * (1 + 1 + 2))
 
 
 def test_fused_step_matches_reference():
