@@ -44,8 +44,8 @@ DEVICE_PHASES = (GRAD, APPLY, LOOKUP, ROW_APPLY, ROW_EXCHANGE, ROW_DEDUPE,
 
 # -- scopes inside the loss of the expert model (models/olmoe.py) --------------
 # They nest under GRAD, so the phases above keep adding up; they are read by
-# ``benchmark/layer_metrics/moe.py``, which keeps its own copy. Never part of
-# DEVICE_PHASES: that tuple is what ``layer_metrics/scope.py`` knows.
+# ``benchmark/layer_metrics/decoder.py``, which keeps its own copy. Never part
+# of DEVICE_PHASES: that tuple is what ``layer_metrics/scope.py`` knows.
 MOE_ROUTE = "ps.moe/route"        # router matmul, softmax, top-k, sort, group sizes
 MOE_DISPATCH = "ps.moe/dispatch"  # token rows permuted into expert order
 MOE_EXPERT = "ps.moe/expert"      # the three grouped matmuls and SwiGLU
@@ -56,7 +56,7 @@ HEAD = "ps.head"                  # final norm, head matmul, cross entropy
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERT, MOE_COMBINE, ATTN, HEAD)
 
 # -- scopes of the hybrid decoder (models/lfm2.py), beside the six above -------
-# Read by ``benchmark/layer_metrics/lfm2.py``, which keeps its own copy.
+# Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy.
 # CONV_GATE nests under CONV, so CONV's time holds it.
 CONV = "ps.conv"                  # the conv mixer: in projection, gates and taps, out projection
 CONV_GATE = "ps.conv/gate"        # ops/gated_conv.py alone: the two gates and the causal taps
@@ -65,7 +65,7 @@ FFN = "ps.ffn"                    # the dense SwiGLU of the leading layers
 LFM2_SCOPES = MOE_SCOPES + (CONV, CONV_GATE, FFN)
 
 # -- scopes of Kimi-Linear (models/kimi_linear.py), beside the six and FFN ------
-# Read by ``benchmark/layer_metrics/kimi.py``, which keeps its own copy. MLA
+# Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy. MLA
 # is under ATTN, the dense SwiGLU under FFN. KDA_CONV and KDA_CORE nest under
 # KDA, so KDA's time holds them.
 KDA = "ps.kda"                    # the KDA mixer: projections, taps, gates, the rule, norm, out projection
@@ -76,7 +76,7 @@ MOE_SHARED = "ps.moe/shared"      # the shared expert, a SwiGLU every token pass
 KIMI_SCOPES = MOE_SCOPES + (FFN, KDA, KDA_CONV, KDA_CORE, MOE_SHARED)
 
 # -- scopes of Nemotron-H (models/nemotron_h.py), beside the six ----------------
-# Read by ``benchmark/layer_metrics/nemo.py``, which keeps its own copy. The
+# Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy. The
 # attention layer is under ATTN, the shared expert under MOE_SHARED.
 # MAMBA_CONV and MAMBA_SSD nest under MAMBA, so MAMBA's time holds them.
 MAMBA = "ps.mamba"                # the Mamba-2 mixer: in projection, filter, scan, gated norm, out projection
@@ -88,7 +88,7 @@ NEMOTRON_SCOPES = MOE_SCOPES + (MAMBA, MAMBA_CONV, MAMBA_SSD, MOE_LATENT,
                                 MOE_SHARED)
 
 # -- scopes of Trinity (models/trinity.py), beside the six, FFN and MOE_SHARED ---
-# Read by ``benchmark/layer_metrics/trinity.py``, which keeps its own copy.
+# Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy.
 # The three nest under ATTN, so ATTN's time holds them: the attention call of
 # each kind of layer (with 'flash' the Mosaic kernels and the packing around
 # them) and the sigmoid gate on its output.
@@ -100,7 +100,7 @@ TRINITY_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
                                ATTN_GATE)
 
 # -- scopes of Mellum (models/mellum.py), beside the six and Trinity's two cores --
-# Read by ``benchmark/layer_metrics/mellum.py``, which keeps its own copy.
+# Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy.
 # MOE_EXCHANGE is opened in ``ops/moe.py`` around each collective of the
 # token exchange and nests under MOE_DISPATCH (rows to their experts' owners)
 # and MOE_COMBINE (results back): their times hold it, forward, recomputation

@@ -558,6 +558,16 @@ def _straggler_events():
             if e["kind"] == "straggler_suspect"]
 
 
+def _traffic_until(w, grads, done, deadline_s=60.0):
+    """``push_pull`` in a loop until ``done()`` holds; fails at the
+    deadline. In place of "for 2 s": what a window holds after 2 s depends
+    on what else the host runs."""
+    end = time.monotonic() + deadline_s
+    while not done():
+        assert time.monotonic() < end, "deadline"
+        w.push_pull(grads)
+
+
 def test_straggler_drill_localizes_slowed_member(tpu_async):
     """ISSUE acceptance: 3-member fleet, one member's apply artificially
     slowed → straggler_suspect flight event + counter + coordinator hint
@@ -572,15 +582,31 @@ def test_straggler_drill_localizes_slowed_member(tpu_async):
     try:
         w.pull_all()
         grads = {k: jnp.full_like(v, 0.01) for k, v in params.items()}
+        # warm-up: each member's first applies compile, and beside other
+        # processes one member's compile can stand out by itself. Traffic
+        # until those samples have left the window and nobody is suspected
+        warm = time.monotonic() + 1.25 * coord.tsdb.window_s
+        _traffic_until(w, grads, lambda: time.monotonic() > warm
+                       and not coord.straggler.suspects())
         events0 = len(_straggler_events())
         evals0 = coord.straggler.evaluations
 
-        # control: equal members — no false positive over M windows
-        t0 = time.monotonic()
-        while time.monotonic() - t0 < 2.0:
-            w.push_pull(grads)
-        time.sleep(0.3)
-        assert coord.straggler.evaluations - evals0 >= 2  # windows ran
+        # control: equal members — no false positive over M windows. The
+        # windows are counted, not timed: traffic until every member's
+        # window holds what the detector scores, then through two more
+        # evaluations (a busy host runs fewer of them in 2 s than an idle
+        # one)
+        scorer, uris = coord.straggler, [f"127.0.0.1:{s.port}" for s in svcs]
+
+        def scored():
+            means = [coord.tsdb.member_mean(u, scorer.metrics[0])
+                     for u in uris]
+            return all(m and m[1] >= scorer.min_count for m in means)
+
+        _traffic_until(w, grads, scored)
+        seen = scorer.evaluations
+        _traffic_until(w, grads, lambda: scorer.evaluations - seen >= 2)
+        assert scorer.evaluations - evals0 >= 2  # windows ran
         assert len(_straggler_events()) == events0
         assert coord.straggler.suspects() == []
 
@@ -593,10 +619,10 @@ def test_straggler_drill_localizes_slowed_member(tpu_async):
             return orig(*a, **kw)
 
         slow._engine.push_tree = crawling
-        t0 = time.monotonic()
-        while time.monotonic() - t0 < 2.5:
-            w.push_pull(grads)
-        time.sleep(0.3)
+        # until the onset's flight event: the evaluation that flags it sets
+        # the suspect, moves the counter and records the event, in that order
+        _traffic_until(w, grads,
+                       lambda: len(_straggler_events()) > events0)
 
         suspects = coord.straggler.suspects()
         assert len(suspects) == 1, suspects

@@ -25,9 +25,9 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
+from benchmark.families import flash
 from benchmark.families import kimi_reference as reference
 from benchmark.families import kimi_step
-from benchmark.layer_metrics import kimi as kimi_metrics
 from jaxpr_tools import checkpoint_names, primitives
 from ps_tpu.models import kimi_linear
 from ps_tpu.models.blocks import _full_attention, make_attn_fn
@@ -777,7 +777,7 @@ def _json(path):
         return json.load(f)
 
 
-def test_cells_are_what_issue_34_named():
+def test_cells_are_what_issue_34_named(listed_for):
     traffic = _json("benchmark/traffic/s8192.b1.zipf.json")
     assert "pool" not in traffic.pop("rehearse")
     assert traffic.pop("loss_step") in kimi_step.LOSS_STEPS == (32, 48, 64)
@@ -799,15 +799,8 @@ def test_cells_are_what_issue_34_named():
     assert set(entry["reduced"]) == {
         "num_hidden_layers", "linear_attn_config", "kda_layers",
         "full_attn_layers", "num_experts", "vocab_size"}
-    listed = [m for m in manifest["per_layer"]
-              if m["name"].startswith("kimi.")]
-    assert len(listed) == 17
-    assert all(m["workloads"] == [cell["name"]] for m in listed)
-    assert {m["name"] for m in listed} \
-        == set(kimi_metrics.SCOPE_METRICS.values()) | {
-            "kimi.kda_core_roofline", "kimi.flash_roofline",
-            "kimi.expert_mxu_share", "kimi.mfu", "kimi.held_pair_share",
-            "kimi.load_max_over_mean", "kimi.dropped_tokens"}
+    assert {"throughput", "loss_at_n"} <= {
+        m["moves"] for m in listed_for(CELL)}
     four = [w for w in manifest["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(manifest["workloads"]) // 4)
     # the control: BERT at 512 with attn left at its default
@@ -878,9 +871,13 @@ def test_configuration_holds_the_published_widths():
     assert rule_flops == 3 * per_chunk_head * 4 * 32 * 128
     assert rule_bytes == 4 * 8192 * 32 * (3 * (3 * 128 * 2 + 4 * 128 + 4)
                                           + 2 * 128 * 2)
-    kernel_flops, kernel_bytes = kimi_step.flash_cost(1, 32, 8192, 192, 128,
-                                                      1)
-    assert kernel_flops == 32 * 8192 * 8192 * (5 * 192 + 4 * 128)
+    # the latent attention's kernel: every query head with K and V of its
+    # own, keys 192 wide and values 128, over the triangle with its diagonal
+    pairs = flash.seen_pairs(8192)
+    kernel_flops, kernel_bytes = flash.cost(1, 32, 32, 8192, 192, 128, 1,
+                                            pairs)
+    assert kernel_flops == 32 * 2 * pairs * (5 * 192 + 4 * 128)
+    assert 2 * pairs == 8192 * 8193
     assert kernel_bytes == 32 * 8192 * (
         (2 * 192 * 2 + 2 * 128 * 2 + 4)
         + (2 * 192 * 2 + 2 * 128 * 2 + 8 + (192 + 128) * 2)

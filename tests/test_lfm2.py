@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
+from benchmark.families import flash
 from benchmark.families import lfm2_reference as reference
 from benchmark.families import lfm2_step
 from ps_tpu.models import lfm2
@@ -549,7 +550,7 @@ def _json(path):
         return json.load(f)
 
 
-def test_cell_is_what_issue_32_named():
+def test_cell_is_what_issue_32_named(listed_for):
     traffic = _json("benchmark/traffic/s8192.zipf.json")
     assert "pool" not in traffic.pop("rehearse")
     assert traffic.pop("loss_step") in lfm2_step.LOSS_STEPS
@@ -570,10 +571,8 @@ def test_cell_is_what_issue_32_named():
     assert set(entry["reduced"]) == {"num_hidden_layers", "layer_types",
                                      "num_dense_layers", "num_experts",
                                      "vocab_size"}
-    listed = [m for m in manifest["per_layer"]
-              if m["name"].startswith("lfm2.")]
-    assert len(listed) == 15
-    assert all(m["workloads"] == [cell["name"]] for m in listed)
+    assert {"throughput", "loss_at_n"} <= {
+        m["moves"] for m in listed_for(cell["name"])}
     four = [w for w in manifest["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(manifest["workloads"]) // 4)
 
@@ -613,10 +612,17 @@ def test_configuration_holds_the_published_widths():
     flops = lfm2_step.step_flops(config, tokens, 8192, 4 * tokens * 4 / 8)
     assert flops == pytest.approx(20.0e12, rel=0.01)
     assert lfm2_step.conv_gate_bytes(config, tokens) == 4 * 11 * tokens * 4096
-    kernel_flops, kernel_bytes = lfm2_step.flash_forward_cost(
-        2, 32, 8, 8192, 64, 1)
-    assert kernel_flops == 2 * 2 * 32 * 8192 * 8192 * 64
+    # the attention layer's kernel: two sequences, 32 heads on 8, 64 wide,
+    # over the triangle; the forward's two products and its bytes, then the
+    # nine of the three calls (what the cell's roofline counts since PR 33)
+    pairs = flash.seen_pairs(8192)
+    assert pairs == 8192 * 8193 // 2
+    kernel_flops, kernel_bytes = flash.cost(2, 32, 8, 8192, 64, 64, 1, pairs,
+                                            backward=None)
+    assert kernel_flops == 2 * 2 * 32 * 2 * pairs * 64
     assert kernel_bytes == 2 * 2 * 40 * 8192 * 64 * 2 + 4 * 2 * 32 * 8192
+    assert flash.cost(2, 32, 8, 8192, 64, 64, 1, pairs)[0] \
+        == 9 * kernel_flops / 2
 
 
 def test_config_refuses_what_the_model_does_not_compute():

@@ -379,7 +379,7 @@ def test_step0_checks_name_the_fault(fault):
         assert failed == {STEP0_FAULTS[fault]}
 
 
-def test_cell_traffic_is_what_issue_28_named():
+def test_cell_traffic_is_what_issue_28_named(listed_for):
     import json
     import os
 
@@ -396,6 +396,8 @@ def test_cell_traffic_is_what_issue_28_named():
         "ids": {"kind": "zipf", "s": 1.0}, "input": "direct",
         "pool": "fresh", "block_steps": 4, "warmup_steps": 4,
         "trace_blocks": 2}
+    assert {"throughput", "loss_at_n"} <= {
+        m["moves"] for m in listed_for("olmoe-1b-7b.s4096.zipf")}
 
 
 @pytest.mark.parametrize("seed", [7, 2**31 + 99])

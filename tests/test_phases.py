@@ -1,6 +1,8 @@
 """The phases inside the fused steps and the spans on the host path into
 them: ``ps_tpu/obs/phases.py``, ``Tracer.program_span``, and the benchmark's
-readers ``benchmark/layer_metrics/scope.py`` and ``host.py``.
+readers ``benchmark/layer_metrics/scope.py``, ``host.py``, ``setup.py`` and,
+of the scopes a decoder's loss opens, ``decoder.py``; then the benchmark's
+command rehearsing a cell and what its manifest lists for one.
 
 A tiny ``make_step`` and a tiny ``make_composite_step`` on the virtual mesh
 stand for the real ones: the scopes and spans are written in the step
@@ -9,6 +11,7 @@ builders, not in the models.
 
 import contextlib
 import functools
+import importlib
 import json
 import os
 import re
@@ -23,11 +26,9 @@ import numpy as np
 import pytest
 
 import ps_tpu as ps
-from benchmark.layer_metrics import (host, kimi as kimi_metrics,
-                                     lfm2 as lfm2_metrics, moe,
-                                     nemo as nemo_metrics, scope)
+from benchmark.harness import tracered
+from benchmark.layer_metrics import decoder, host, scope, step
 from benchmark.layer_metrics import setup as setup_metrics
-from benchmark.layer_metrics import trinity as trinity_metrics
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
 from ps_tpu.kv.sparse import SparseEmbedding
@@ -167,6 +168,20 @@ def test_program_and_benchmark_share_their_names():
     for name in ("STEP_RUN", "STEP_LAUNCH", "INPUT_PLACE",
                  "INPUT_SOURCE_WAIT", "INPUT_PRODUCE"):
         assert getattr(phases, name) == getattr(host, name)
+    # the decoders' scopes: the one reader's one copy, every name of it the
+    # program's, and a metric for every scope a model opens (the seven
+    # ``*_SCOPES``) but, until it is given one, ps.attn/inblock, which counts
+    # inside decoder.attn_ms
+    copied = [name for name in vars(decoder)
+              if name.isupper() and hasattr(phases, name)]
+    assert len(copied) == len(decoder.SCOPES) >= 21
+    for name in copied:
+        assert getattr(phases, name) == getattr(decoder, name), name
+    families = [name for name in vars(phases) if name.endswith("_SCOPES")]
+    opened = set().union(*(getattr(phases, name) for name in families))
+    assert len(families) == 7
+    assert opened - {phases.ATTN_INBLOCK} <= set(decoder.METRICS) <= opened
+    assert not opened & set(phases.DEVICE_PHASES)
     # every span the program records has a metric that reads it
     ring = [_span(name, 10.0, f"s{i}", "s0" if name == host.STEP_LAUNCH
                   else None, nbytes=1)
@@ -196,14 +211,9 @@ def _expert_step():
 def test_expert_scopes_reach_the_step_hlo_forward_and_backward(
         no_compile_cache, monkeypatch):
     """The scopes the model opens inside its loss nest under ``ps.grad``,
-    each with forward and backward ops, and the benchmark's reader keeps
-    the same names and finds them."""
-    assert phases.MOE_SCOPES == moe.MOE_SCOPES
-    for name in ("MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE",
-                 "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(moe, name)
-    assert not set(phases.MOE_SCOPES) & set(phases.DEVICE_PHASES)
-    assert set(moe.SCOPE_METRICS) == set(moe.MOE_SCOPES)
+    each with forward and backward ops, and the benchmark's reader finds
+    them (its names are the program's:
+    ``test_program_and_benchmark_share_their_names``)."""
     monkeypatch.setitem(BUILDERS, "expert", _expert_step)
     names = scope.op_names_of(_step_hlo("expert"))
     for s in phases.MOE_SCOPES:
@@ -214,12 +224,9 @@ def test_expert_scopes_reach_the_step_hlo_forward_and_backward(
         # under ps.grad: scope.py counts them as forward or backward
         assert {scope.phase_of(n)[0] for n in under} == {"forward",
                                                          "backward"}, s
-    found = {moe.scope_of(own, n) for own, n in names.items()}
-    assert found == set(moe.MOE_SCOPES) | {None}
-    # XLA:TPU's grouped matmuls carry no op_name: taken by their own name
-    assert moe.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
-        == moe.MOE_EXPERT
-    assert moe.scope_of("%fusion.7", "jit(f)/ps.apply/mul") is None
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == set(phases.MOE_SCOPES) | {None}
+    assert decoder.scope_of("%fusion.7", "jit(f)/ps.apply/mul") is None
 
 
 def _hybrid_step():
@@ -251,14 +258,8 @@ def test_hybrid_scopes_reach_the_step_hlo_forward_and_backward(
     """What LFM2 adds to the scopes (``ps.conv``, ``ps.conv/gate``,
     ``ps.ffn``) and the six it shares with OLMoE: each in the lowered step's
     ``op_name``s under ``ps.grad``, forward and backward, the expert
-    layer's also inside its recomputation; the reader's copy is equal."""
-    assert phases.LFM2_SCOPES == lfm2_metrics.LFM2_SCOPES
+    layer's also inside its recomputation; the reader finds each."""
     assert phases.LFM2_SCOPES[:6] == phases.MOE_SCOPES
-    for name in ("CONV", "CONV_GATE", "FFN", "MOE_ROUTE", "MOE_DISPATCH",
-                 "MOE_EXPERT", "MOE_COMBINE", "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(lfm2_metrics, name)
-    assert not set(phases.LFM2_SCOPES) & set(phases.DEVICE_PHASES)
-    assert set(lfm2_metrics.SCOPE_METRICS) == set(phases.LFM2_SCOPES)
     monkeypatch.setitem(BUILDERS, "hybrid", _hybrid_step)
     names = scope.op_names_of(_step_hlo("hybrid"))
     for s in phases.LFM2_SCOPES:
@@ -266,69 +267,13 @@ def test_hybrid_scopes_reach_the_step_hlo_forward_and_backward(
         assert under and all(phases.GRAD in n for n in under), s
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
-    found = {lfm2_metrics.scope_of(own, n) for own, n in names.items()}
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
     assert found == set(phases.LFM2_SCOPES) | {None}
     # the gate is the innermost scope of its ops, and the mixer's holds it
-    assert lfm2_metrics.scope_of(
+    assert decoder.scope_of(
         "%fusion.1", "jit(f)/ps.grad/jvp(ps.conv)/ps.conv/gate/mul") \
-        == lfm2_metrics.CONV_GATE
-    assert lfm2_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
-        == lfm2_metrics.MOE_EXPERT
-
-
-def test_lfm2_reader_on_a_hand_made_result(monkeypatch):
-    call = 'custom_call_target="tpu_custom_call"'
-    ops = {_ev("%in"): 0.004, _ev("%gate"): 0.002, _ev("%swiglu"): 0.006,
-           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
-           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
-           _ev("%flash", "custom-call") + call: 0.010,
-           _ev("%qkv"): 0.004, _ev("%ce"): 0.005, _ev("%embed"): 0.001,
-           _ev("%adam"): 0.007}
-    names = {"%in": "jit(f)/ps.grad/jvp(ps.conv)/dot_general",
-             "%gate": "jit(f)/ps.grad/transpose(jvp(ps.conv))/ps.conv/gate/m",
-             "%swiglu": "jit(f)/ps.grad/jvp(ps.ffn)/dot_general",
-             "%route": "jit(f)/ps.grad/jvp(ps.moe/route)/dot_general",
-             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
-             "%back": "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather",
-             "%ragged-dot-none.1": "ragged-dot-none",
-             "%flash": "jit(f)/ps.grad/jvp(ps.attn)/pallas_call",
-             "%qkv": "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general",
-             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
-             "%embed": "jit(f)/ps.grad/jvp()/gather",
-             "%adam": "jit(f)/ps.apply/mul"}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
-         "counters": {"lfm2_live_pairs_per_step": 1000.0,
-                      "lfm2_held_pair_share": 0.125,
-                      "lfm2_load_max_over_mean": 3.0,
-                      "lfm2_dropped_tokens": 0.0},
-         "facts": {"kernel_targets": ["tpu_custom_call"],
-                   "lfm2_flops_per_pair": 1e6,
-                   "lfm2_dense_flops_per_step": 4e9,
-                   "lfm2_conv_gate_bytes_per_step": 0.5e9,
-                   "lfm2_flash_flops": 1e9, "lfm2_flash_bytes": 1.0},
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
-         "steps": 10, "window_s": 1.0}
-    out = lfm2_metrics.scope_times(r, names)
-    assert out["lfm2.conv_ms"] == pytest.approx(3.0)        # with its gate
-    assert out["lfm2.conv_gate_ms"] == pytest.approx(1.0)
-    assert out["lfm2.dense_ffn_ms"] == pytest.approx(3.0)
-    assert out["lfm2.route_ms"] == pytest.approx(0.5)
-    assert out["lfm2.dispatch_ms"] == pytest.approx(2.0)    # with combine
-    assert out["lfm2.expert_ms"] == pytest.approx(4.0)
-    assert out["lfm2.attn_ms"] == pytest.approx(7.0)
-    assert out["lfm2.head_ms"] == pytest.approx(2.5)
-    assert out["lfm2.conv_gate_hbm_share"] == pytest.approx(50.0)
-    assert out["lfm2.expert_mxu_share"] == pytest.approx(25.0)  # 1 of 4 ms
-    assert out["lfm2.flash_roofline"] == pytest.approx(20.0)    # 1 of 5 ms
-    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
-    whole = lfm2_metrics.read(r)
-    assert whole["lfm2.mfu"] == pytest.approx(5.0)   # 5e9 x 10 / s of 1e12
-    assert whole["lfm2.held_pair_share"] == 0.125
-    assert len([k for k in whole if k.startswith("lfm2.")]) == 15
-    # a program without the scopes, the counters or the grouped matmuls
-    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
-    assert lfm2_metrics.scope_times(r, {}) == {}
-    assert lfm2_metrics.read({"counters": {}, "facts": {}}) == {}
+        == phases.CONV_GATE
+    assert decoder.outer_of(phases.CONV_GATE) == phases.CONV
 
 
 def _kimi_step():
@@ -362,15 +307,8 @@ def test_kimi_scopes_reach_the_step_hlo_forward_and_backward(
     """What Kimi-Linear adds to the scopes (``ps.kda``, ``ps.kda/conv``,
     ``ps.kda/core``, ``ps.moe/shared``) beside the six it shares with OLMoE
     and ``ps.ffn``: each in the lowered step's ``op_name``s under
-    ``ps.grad``, forward and backward; the reader's copy is equal."""
-    assert phases.KIMI_SCOPES == kimi_metrics.KIMI_SCOPES
+    ``ps.grad``, forward and backward; the reader finds each."""
     assert phases.KIMI_SCOPES[:6] == phases.MOE_SCOPES
-    for name in ("KDA", "KDA_CONV", "KDA_CORE", "MOE_SHARED", "FFN",
-                 "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE",
-                 "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(kimi_metrics, name)
-    assert not set(phases.KIMI_SCOPES) & set(phases.DEVICE_PHASES)
-    assert set(kimi_metrics.SCOPE_METRICS) == set(phases.KIMI_SCOPES)
     monkeypatch.setitem(BUILDERS, "kimi", _kimi_step)
     names = scope.op_names_of(_step_hlo("kimi"))
     for s in phases.KIMI_SCOPES:
@@ -378,80 +316,18 @@ def test_kimi_scopes_reach_the_step_hlo_forward_and_backward(
         assert under and all(phases.GRAD in n for n in under), s
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
-    found = {kimi_metrics.scope_of(own, n) for own, n in names.items()}
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
     assert found == set(phases.KIMI_SCOPES) | {None}
     # the rule and the taps are the innermost scopes of their ops, and the
     # mixer's holds them; the shared expert is not the routed ones'
-    for inner in (kimi_metrics.KDA_CORE, kimi_metrics.KDA_CONV):
-        assert kimi_metrics.scope_of(
+    for inner in (phases.KDA_CORE, phases.KDA_CONV):
+        assert decoder.scope_of(
             "%fusion.1", f"jit(f)/ps.grad/jvp(ps.kda)/checkpoint/{inner}/mul"
         ) == inner
-    assert kimi_metrics.scope_of(
+        assert decoder.outer_of(inner) == phases.KDA
+    assert decoder.scope_of(
         "%fusion.2", "jit(f)/ps.grad/jvp(ps.moe/shared)/dot_general") \
-        == kimi_metrics.MOE_SHARED
-    assert kimi_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
-        == kimi_metrics.MOE_EXPERT
-
-
-def test_kimi_reader_on_a_hand_made_result(monkeypatch):
-    call = 'custom_call_target="tpu_custom_call"'
-    ops = {_ev("%qkv"): 0.004, _ev("%taps"): 0.002, _ev("%scan"): 0.010,
-           _ev("%swiglu"): 0.006, _ev("%shared"): 0.002,
-           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
-           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
-           _ev("%flash", "custom-call") + call: 0.010,
-           _ev("%latent"): 0.004, _ev("%ce"): 0.005, _ev("%embed"): 0.001,
-           _ev("%adam"): 0.007}
-    names = {"%qkv": "jit(f)/ps.grad/jvp(ps.kda)/dot_general",
-             "%taps": "jit(f)/ps.grad/jvp(ps.kda)/checkpoint/ps.kda/conv/mul",
-             "%scan": "jit(f)/ps.grad/transpose(jvp(ps.kda))/checkpoint/"
-                      "ps.kda/core/while/body/dot_general",
-             "%swiglu": "jit(f)/ps.grad/jvp(ps.ffn)/dot_general",
-             "%shared": "jit(f)/ps.grad/jvp(ps.moe/shared)/dot_general",
-             "%route": "jit(f)/ps.grad/jvp(ps.moe/route)/dot_general",
-             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
-             "%back": "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather",
-             "%ragged-dot-none.1": "ragged-dot-none",
-             "%flash": "jit(f)/ps.grad/jvp(ps.attn)/pallas_call",
-             "%latent": "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general",
-             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
-             "%embed": "jit(f)/ps.grad/jvp()/gather",
-             "%adam": "jit(f)/ps.apply/mul"}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
-         "counters": {"kimi_live_pairs_per_step": 1000.0,
-                      "kimi_held_pair_share": 0.03125,
-                      "kimi_load_max_over_mean": 3.0,
-                      "kimi_dropped_tokens": 0.0},
-         "facts": {"kernel_targets": ["tpu_custom_call"],
-                   "kimi_flops_per_pair": 1e6,
-                   "kimi_dense_flops_per_step": 4e9,
-                   "kimi_kda_core_flops": 1.0, "kimi_kda_core_bytes": 1e9,
-                   "kimi_flash_flops": 2e9, "kimi_flash_bytes": 1.0},
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
-         "steps": 10, "window_s": 1.0}
-    out = kimi_metrics.scope_times(r, names)
-    assert out["kimi.kda_ms"] == pytest.approx(8.0)     # with taps and rule
-    assert out["kimi.kda_conv_ms"] == pytest.approx(1.0)
-    assert out["kimi.kda_core_ms"] == pytest.approx(5.0)
-    assert out["kimi.dense_ffn_ms"] == pytest.approx(3.0)
-    assert out["kimi.shared_ffn_ms"] == pytest.approx(1.0)
-    assert out["kimi.route_ms"] == pytest.approx(0.5)
-    assert out["kimi.dispatch_ms"] == pytest.approx(2.0)    # with combine
-    assert out["kimi.expert_ms"] == pytest.approx(4.0)
-    assert out["kimi.mla_ms"] == pytest.approx(7.0)
-    assert out["kimi.head_ms"] == pytest.approx(2.5)
-    assert out["kimi.kda_core_roofline"] == pytest.approx(20.0)  # 1 of 5 ms
-    assert out["kimi.expert_mxu_share"] == pytest.approx(25.0)   # 1 of 4 ms
-    assert out["kimi.flash_roofline"] == pytest.approx(40.0)     # 2 of 5 ms
-    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
-    whole = kimi_metrics.read(r)
-    assert whole["kimi.mfu"] == pytest.approx(5.0)   # 5e9 x 10 / s of 1e12
-    assert whole["kimi.held_pair_share"] == 0.03125
-    assert len([k for k in whole if k.startswith("kimi.")]) == 17
-    # a program without the scopes, the counters or the grouped matmuls
-    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
-    assert kimi_metrics.scope_times(r, {}) == {}
-    assert kimi_metrics.read({"counters": {}, "facts": {}}) == {}
+        == phases.MOE_SHARED
 
 
 def _nemotron_step():
@@ -486,15 +362,8 @@ def test_nemotron_scopes_reach_the_step_hlo_forward_and_backward(
     ``ps.mamba/ssd``, ``ps.moe/latent``) beside the six it shares with OLMoE
     and Kimi-Linear's ``ps.moe/shared``: each in the lowered step's
     ``op_name``s under ``ps.grad``, forward and backward, though every layer
-    is under a ``jax.checkpoint``; the reader's copy is equal."""
-    assert phases.NEMOTRON_SCOPES == nemo_metrics.NEMOTRON_SCOPES
+    is under a ``jax.checkpoint``; the reader finds each."""
     assert phases.NEMOTRON_SCOPES[:6] == phases.MOE_SCOPES
-    for name in ("MAMBA", "MAMBA_CONV", "MAMBA_SSD", "MOE_LATENT",
-                 "MOE_SHARED", "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT",
-                 "MOE_COMBINE", "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(nemo_metrics, name)
-    assert not set(phases.NEMOTRON_SCOPES) & set(phases.DEVICE_PHASES)
-    assert set(nemo_metrics.SCOPE_METRICS) == set(phases.NEMOTRON_SCOPES)
     monkeypatch.setitem(BUILDERS, "nemotron", _nemotron_step)
     names = scope.op_names_of(_step_hlo("nemotron"))
     for s in phases.NEMOTRON_SCOPES:
@@ -502,81 +371,18 @@ def test_nemotron_scopes_reach_the_step_hlo_forward_and_backward(
         assert under and all(phases.GRAD in n for n in under), s
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
-    found = {nemo_metrics.scope_of(own, n) for own, n in names.items()}
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
     assert found == set(phases.NEMOTRON_SCOPES) | {None}
     # the scan and the filter are the innermost scopes of their ops, and the
     # mixer's holds them; the latent projections are not the routed experts'
-    for inner in (nemo_metrics.MAMBA_SSD, nemo_metrics.MAMBA_CONV):
-        assert nemo_metrics.scope_of(
+    for inner in (phases.MAMBA_SSD, phases.MAMBA_CONV):
+        assert decoder.scope_of(
             "%fusion.1",
             f"jit(f)/ps.grad/jvp()/checkpoint/ps.mamba/{inner}/mul") == inner
-    assert nemo_metrics.scope_of(
+        assert decoder.outer_of(inner) == phases.MAMBA
+    assert decoder.scope_of(
         "%fusion.2", "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/latent/dot") \
-        == nemo_metrics.MOE_LATENT
-    assert nemo_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
-        == nemo_metrics.MOE_EXPERT
-
-
-def test_nemo_reader_on_a_hand_made_result(monkeypatch):
-    call = 'custom_call_target="tpu_custom_call"'
-    ops = {_ev("%in_proj"): 0.004, _ev("%taps"): 0.002, _ev("%scan"): 0.010,
-           _ev("%down"): 0.003, _ev("%shared"): 0.002,
-           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
-           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
-           _ev("%flash", "custom-call") + call: 0.010,
-           _ev("%qkv"): 0.004, _ev("%ce"): 0.005, _ev("%embed"): 0.001,
-           _ev("%adam"): 0.007}
-    names = {"%in_proj": "jit(f)/ps.grad/jvp()/checkpoint/ps.mamba/dot",
-             "%taps": "jit(f)/ps.grad/jvp()/checkpoint/ps.mamba/"
-                      "ps.mamba/conv/mul",
-             "%scan": "jit(f)/ps.grad/transpose(jvp())/checkpoint/ps.mamba/"
-                      "ps.mamba/ssd/while/body/dot_general",
-             "%down": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/latent/dot",
-             "%shared": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/shared/dot",
-             "%route": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/route/dot",
-             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
-             "%back": "jit(f)/ps.grad/transpose(jvp())/ps.moe/combine/gather",
-             "%ragged-dot-none.1": "ragged-dot-none",
-             "%flash": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/pallas_call",
-             "%qkv": "jit(f)/ps.grad/transpose(jvp())/ps.attn/dot_general",
-             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
-             "%embed": "jit(f)/ps.grad/jvp()/gather",
-             "%adam": "jit(f)/ps.apply/mul"}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
-         "counters": {"nemo_live_pairs_per_step": 1000.0,
-                      "nemo_held_pair_share": 0.015625,
-                      "nemo_load_max_over_mean": 3.0,
-                      "nemo_dropped_tokens": 0.0},
-         "facts": {"kernel_targets": ["tpu_custom_call"],
-                   "nemo_flops_per_pair": 1e6,
-                   "nemo_dense_flops_per_step": 4e9,
-                   "nemo_ssd_flops": 1.0, "nemo_ssd_bytes": 1e9,
-                   "nemo_flash_flops": 2e9, "nemo_flash_bytes": 1.0},
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
-         "steps": 10, "window_s": 1.0}
-    out = nemo_metrics.scope_times(r, names)
-    assert out["nemo.mamba_ms"] == pytest.approx(8.0)   # with filter and scan
-    assert out["nemo.mamba_conv_ms"] == pytest.approx(1.0)
-    assert out["nemo.ssd_ms"] == pytest.approx(5.0)
-    assert out["nemo.latent_ms"] == pytest.approx(1.5)
-    assert out["nemo.shared_ffn_ms"] == pytest.approx(1.0)
-    assert out["nemo.route_ms"] == pytest.approx(0.5)
-    assert out["nemo.dispatch_ms"] == pytest.approx(2.0)    # with combine
-    assert out["nemo.expert_ms"] == pytest.approx(4.0)
-    assert out["nemo.attn_ms"] == pytest.approx(7.0)
-    assert out["nemo.head_ms"] == pytest.approx(2.5)
-    assert out["nemo.ssd_roofline"] == pytest.approx(20.0)       # 1 of 5 ms
-    assert out["nemo.expert_mxu_share"] == pytest.approx(25.0)   # 1 of 4 ms
-    assert out["nemo.flash_roofline"] == pytest.approx(40.0)     # 2 of 5 ms
-    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
-    whole = nemo_metrics.read(r)
-    assert whole["nemo.mfu"] == pytest.approx(5.0)   # 5e9 x 10 / s of 1e12
-    assert whole["nemo.held_pair_share"] == 0.015625
-    assert len([k for k in whole if k.startswith("nemo.")]) == 17
-    # a program without the scopes, the counters or the grouped matmuls
-    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
-    assert nemo_metrics.scope_times(r, {}) == {}
-    assert nemo_metrics.read({"counters": {}, "facts": {}}) == {}
+        == phases.MOE_LATENT
 
 
 def _trinity_step():
@@ -609,16 +415,9 @@ def test_trinity_scopes_reach_the_step_hlo_forward_and_backward(
     ``ps.attn/gate``, all inside ``ps.attn``) beside the six it shares with
     OLMoE, LFM2's ``ps.ffn`` and Kimi-Linear's ``ps.moe/shared``: each in the
     lowered step's ``op_name``s under ``ps.grad``, forward and backward,
-    though every layer is under a ``jax.checkpoint``; the reader's copy is
-    equal."""
-    assert phases.TRINITY_SCOPES == trinity_metrics.TRINITY_SCOPES
+    though every layer is under a ``jax.checkpoint``; the reader finds
+    each."""
     assert phases.TRINITY_SCOPES[:6] == phases.MOE_SCOPES
-    for name in ("ATTN_WINDOW", "ATTN_FULL", "ATTN_GATE", "FFN", "MOE_SHARED",
-                 "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE",
-                 "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(trinity_metrics, name)
-    assert not set(phases.TRINITY_SCOPES) & set(phases.DEVICE_PHASES)
-    assert set(trinity_metrics.SCOPE_METRICS) == set(phases.TRINITY_SCOPES)
     monkeypatch.setitem(BUILDERS, "trinity", _trinity_step)
     names = scope.op_names_of(_step_hlo("trinity"))
     for s in phases.TRINITY_SCOPES:
@@ -626,127 +425,18 @@ def test_trinity_scopes_reach_the_step_hlo_forward_and_backward(
         assert under and all(phases.GRAD in n for n in under), s
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
-    found = {trinity_metrics.scope_of(own, n) for own, n in names.items()}
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
     assert found == set(phases.TRINITY_SCOPES) | {None}
     # the cores and the gate are the innermost scopes of their ops, and the
     # attention's holds them
-    for inner in (trinity_metrics.ATTN_WINDOW, trinity_metrics.ATTN_FULL,
-                  trinity_metrics.ATTN_GATE):
-        assert trinity_metrics.scope_of(
+    for inner in (phases.ATTN_WINDOW, phases.ATTN_FULL, phases.ATTN_GATE):
+        assert decoder.scope_of(
             "%fusion.1",
             f"jit(f)/ps.grad/jvp()/checkpoint/ps.attn/{inner}/mul") == inner
-    assert trinity_metrics.scope_of(
+        assert decoder.outer_of(inner) == phases.ATTN
+    assert decoder.scope_of(
         "%fusion.2", "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/dot") \
-        == trinity_metrics.ATTN
-    assert trinity_metrics.scope_of("%ragged-dot-none.3", "ragged-dot-none") \
-        == trinity_metrics.MOE_EXPERT
-
-
-def test_trinity_reader_on_a_hand_made_result(monkeypatch):
-    call = 'custom_call_target="tpu_custom_call"'
-    ops = {_ev("%qkv"): 0.004, _ev("%gate"): 0.002, _ev("%pack"): 0.001,
-           _ev("%band", "custom-call") + call: 0.008,
-           _ev("%triangle", "custom-call") + call: 0.010,
-           _ev("%dense"): 0.003, _ev("%shared"): 0.002,
-           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
-           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
-           _ev("%ce"): 0.005, _ev("%embed"): 0.001, _ev("%adam"): 0.007}
-    names = {"%qkv": "jit(f)/ps.grad/transpose(jvp())/ps.attn/dot_general",
-             "%gate": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/"
-                      "ps.attn/gate/mul",
-             "%pack": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/"
-                      "ps.attn/window/transpose",
-             "%band": "jit(f)/ps.grad/jvp()/checkpoint/ps.attn/"
-                      "ps.attn/window/pallas_call",
-             "%triangle": "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
-                          "ps.attn/ps.attn/full/pallas_call",
-             "%dense": "jit(f)/ps.grad/jvp()/checkpoint/ps.ffn/dot",
-             "%shared": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/shared/dot",
-             "%route": "jit(f)/ps.grad/jvp()/checkpoint/ps.moe/route/dot",
-             "%rows": "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather",
-             "%back": "jit(f)/ps.grad/transpose(jvp())/ps.moe/combine/gather",
-             "%ragged-dot-none.1": "ragged-dot-none",
-             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
-             "%embed": "jit(f)/ps.grad/jvp()/gather",
-             "%adam": "jit(f)/ps.apply/mul"}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
-         "counters": {"trinity_live_pairs_per_step": 1000.0,
-                      "trinity_held_pair_share": 0.125,
-                      "trinity_load_max_over_mean": 3.0,
-                      "trinity_dropped_tokens": 0.0},
-         "facts": {"kernel_targets": ["tpu_custom_call"],
-                   "trinity_flops_per_pair": 1e6,
-                   "trinity_dense_flops_per_step": 4e9,
-                   "trinity_window_flash_flops": 1e9,
-                   "trinity_window_flash_bytes": 1.0,
-                   "trinity_full_flash_flops": 1.0,
-                   "trinity_full_flash_bytes": 2e9,
-                   "trinity_window_live_step_share": 0.284},
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
-         "steps": 10, "window_s": 1.0}
-    out = trinity_metrics.scope_times(r, names)
-    assert out["trinity.attn_ms"] == pytest.approx(12.5)  # cores and gate in
-    assert out["trinity.window_core_ms"] == pytest.approx(4.5)
-    assert out["trinity.full_core_ms"] == pytest.approx(5.0)
-    assert out["trinity.attn_gate_ms"] == pytest.approx(1.0)
-    assert out["trinity.dense_ffn_ms"] == pytest.approx(1.5)
-    assert out["trinity.shared_ffn_ms"] == pytest.approx(1.0)
-    assert out["trinity.route_ms"] == pytest.approx(0.5)
-    assert out["trinity.dispatch_ms"] == pytest.approx(2.0)   # with combine
-    assert out["trinity.expert_ms"] == pytest.approx(4.0)
-    assert out["trinity.head_ms"] == pytest.approx(2.5)
-    # the kernels alone in the denominators, each kind over its own calls
-    assert out["trinity.window_flash_roofline"] == pytest.approx(25.0)
-    assert out["trinity.full_flash_roofline"] == pytest.approx(40.0)
-    assert out["trinity.expert_mxu_share"] == pytest.approx(25.0)
-    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
-    whole = trinity_metrics.read(r)
-    assert whole["trinity.mfu"] == pytest.approx(5.0)  # 5e9 x 10 / s of 1e12
-    assert whole["trinity.window_live_step_share"] == 0.284
-    assert len([k for k in whole if k.startswith("trinity.")]) == 18
-    assert set(whole) == {m["name"] for m in json.load(open(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "BENCHMARK.json")))["per_layer"] if m["name"].startswith("trinity.")}
-    # a program without the scopes, the counters or the grouped matmuls
-    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
-    assert trinity_metrics.scope_times(r, {}) == {}
-    assert trinity_metrics.read({"counters": {}, "facts": {}}) == {}
-
-
-def test_moe_reader_on_a_hand_made_result():
-    ops = {_ev("%route"): 0.002, _ev("%sorted"): 0.001,
-           _ev("%back"): 0.003,
-           _ev("%ragged-dot-none.1", "custom-call") + (
-               'custom_call_target="tpu_custom_call"'): 0.020,
-           _ev("%flash", "custom-call") + (
-               'custom_call_target="tpu_custom_call"'): 0.010,
-           _ev("%qkv"): 0.004, _ev("%ce"): 0.006, _ev("%embed"): 0.0005,
-           _ev("%adam"): 0.007}
-    names = {"%route": "jit(f)/ps.grad/jvp(ps.moe/route)/dot_general",
-             "%sorted": "jit(f)/ps.grad/jvp(ps.moe/dispatch)/gather",
-             "%back": "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather",
-             "%ragged-dot-none.1": "ragged-dot-none",
-             "%flash": "jit(f)/ps.grad/jvp(ps.attn)/pallas_call",
-             "%qkv": "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general",
-             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
-             "%embed": "jit(f)/ps.grad/jvp()/gather",
-             "%adam": "jit(f)/ps.apply/mul"}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
-         "facts": {"kernel_targets": ["tpu_custom_call"],
-                   "moe_expert_flops_per_step": 0.5e9,
-                   "moe_flash_flops": 1e9, "moe_flash_bytes": 1.0},
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12}}
-    out = moe.scope_times(r, names)
-    assert out["moe.route_ms"] == pytest.approx(1.0)
-    assert out["moe.dispatch_ms"] == pytest.approx(2.0)   # with combine
-    assert out["moe.expert_ms"] == pytest.approx(10.0)
-    assert out["moe.attn_ms"] == pytest.approx(7.0)
-    assert out["moe.head_ms"] == pytest.approx(3.0)
-    assert out["moe.expert_mxu_share"] == pytest.approx(5.0)   # 0.5 of 10 ms
-    assert out["moe.flash_roofline"] == pytest.approx(20.0)    # 1 of 5 ms
-    # a program without the scopes and without the grouped matmuls
-    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
-    assert moe.scope_times(r, {}) == {}
+        == phases.ATTN
 
 
 # -- scope.py on a hand-made result ------------------------------------------
@@ -879,6 +569,342 @@ def test_scope_two_marked_executables_that_disagree(capsys):
     assert out["scope.unattributed_share"] == pytest.approx(25.0)
     assert out["scope.apply_ms"] == pytest.approx(6.0)
     assert f"[{scope.CLASH}]" in capsys.readouterr().err
+
+
+# -- decoder.py on a decoder's hand-made result ------------------------------
+
+_CALL = 'custom_call_target="tpu_custom_call"'
+_JVP = "jit(f)/ps.grad/jvp({})/"
+_CP = "jit(f)/ps.grad/jvp()/checkpoint/"
+_BACK = "jit(f)/ps.grad/transpose(jvp())/"
+_RULE = "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
+_TARGETS = {"kernel_targets": ["tpu_custom_call"]}
+#: what every decoder's step has beside its scopes: the head, the embedding
+#: (under ps.grad, in no scope) and the apply
+_REST = [("%ce", "fusion", 0.005, "jit(f)/ps.grad/jvp(ps.head)/reduce"),
+         ("%embed", "fusion", 0.001, "jit(f)/ps.grad/jvp()/gather"),
+         ("%adam", "fusion", 0.007, "jit(f)/ps.apply/mul")]
+#: the expert layer of the five that hold a share: 0.5 ms of routing, 2 of
+#: dispatch and combine, 4 in the grouped matmul, a Mosaic call under its
+#: scope as every chip run has carried it since PR 47
+_EXPERTS = [("%route", "fusion", 0.001, _CP + "ps.moe/route/dot"),
+            ("%rows", "fusion", 0.003,
+             "jit(f)/ps.grad/checkpoint/ps.moe/dispatch/gather"),
+            ("%back", "fusion", 0.001, _BACK + "ps.moe/combine/gather"),
+            ("%gmm", "custom-call", 0.008,
+             _CP + "ps.moe/expert/pallas_call")]
+_HELD = {"live_pairs_per_step": 1000.0, "load_max_over_mean": 3.0,
+         "dropped_tokens": 0.0}
+#: 1000 live pairs of 1e6 FLOPs in expert_ms 4: a quarter of the MXU's peak
+_EXPERTS_WANT = {"decoder.route_ms": 0.5, "decoder.dispatch_ms": 2.0,
+                 "decoder.expert_ms": 4.0, "decoder.expert_mxu_share": 25.0,
+                 "decoder.head_ms": 2.5, "decoder.load_max_over_mean": 3.0,
+                 "decoder.dropped_tokens": 0.0}
+_ROWS, _SIZES = "bf16[4,96,8]", "s32[4,2]"
+_EXCHANGE = _CP + "ps.moe/combine/ps.moe/exchange/all_to_all"
+
+#: a decoder's hand-made result under the one set of keys (PERF.md section 4,
+#: "How a drawn decoder joins"): its events (name, opcode, seconds in two
+#: traced steps, op_name and, for a collective, its shape) on each of
+#: ``chips`` (one unless said; ``on_chip`` has what a chip reads otherwise),
+#: its facts and counters, and what ``decoder.read`` and ``step.read`` make
+#: of them: every number the cell's own reader was held to before PR 49
+#: merged the six. The next decoder is one more case.
+READER_CASES = {
+    "olmoe": {   # no share held: the pairs a step are a fact
+        "events": [
+            ("%route", "fusion", 0.002,
+             _JVP.format("ps.moe/route") + "dot_general"),
+            ("%sorted", "fusion", 0.001,
+             _JVP.format("ps.moe/dispatch") + "gather"),
+            ("%back", "fusion", 0.003,
+             "jit(f)/ps.grad/transpose(jvp(ps.moe/combine))/gather"),
+            ("%gmm", "custom-call", 0.020,
+             _JVP.format("ps.moe/expert") + "pallas_call"),
+            ("%flash", "custom-call", 0.010,
+             _JVP.format("ps.attn") + "pallas_call"),
+            ("%qkv", "fusion", 0.004,
+             "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general"),
+            ("%ce", "fusion", 0.006, "jit(f)/ps.grad/jvp(ps.head)/reduce"),
+            ("%embed", "fusion", 0.0005, "jit(f)/ps.grad/jvp()/gather"),
+            ("%adam", "fusion", 0.007, "jit(f)/ps.apply/mul")],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4.5e9,
+                  "flops_per_pair": 0.5e6, "live_pairs_per_step": 1000.0,
+                  "flash_flops": 1e9, "flash_bytes": 1.0},
+        "counters": {"load_max_over_mean": 7.25, "dropped_tokens": 0.0},
+        "want": {"decoder.route_ms": 1.0,
+                 "decoder.dispatch_ms": 2.0,           # with combine
+                 "decoder.expert_ms": 10.0, "decoder.attn_ms": 7.0,
+                 "decoder.head_ms": 3.0,
+                 "decoder.expert_mxu_share": 5.0,      # 0.5 of 10 ms
+                 "kernel.flash_roofline": 20.0,        # 1 of 5 ms
+                 "decoder.load_max_over_mean": 7.25,
+                 "decoder.dropped_tokens": 0.0},
+        "mfu": 5.0, "device_ms": 26.75},
+    "lfm2": {
+        "events": [
+            ("%in", "fusion", 0.004, _JVP.format("ps.conv") + "dot_general"),
+            ("%gate", "fusion", 0.002,
+             "jit(f)/ps.grad/transpose(jvp(ps.conv))/ps.conv/gate/m"),
+            ("%swiglu", "fusion", 0.006,
+             _JVP.format("ps.ffn") + "dot_general"),
+            ("%flash", "custom-call", 0.010,
+             _JVP.format("ps.attn") + "pallas_call"),
+            ("%qkv", "fusion", 0.004,
+             "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general"),
+            *_EXPERTS, *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "conv_gate_bytes_per_step": 0.5e9,
+                  "flash_flops": 1e9, "flash_bytes": 1.0},
+        "counters": {**_HELD, "held_pair_share": 0.125},
+        "want": {**_EXPERTS_WANT,
+                 "decoder.conv_ms": 3.0,               # with its gate
+                 "decoder.conv_gate_ms": 1.0, "decoder.dense_ffn_ms": 3.0,
+                 "decoder.attn_ms": 7.0,
+                 "decoder.conv_gate_hbm_share": 50.0,
+                 "kernel.flash_roofline": 20.0,        # 1 of 5 ms
+                 "decoder.held_pair_share": 0.125},
+        "mfu": 5.0, "device_ms": 26.0},
+    "kimi": {
+        "events": [
+            ("%qkv", "fusion", 0.004, _JVP.format("ps.kda") + "dot_general"),
+            ("%taps", "fusion", 0.002,
+             _JVP.format("ps.kda") + "checkpoint/ps.kda/conv/mul"),
+            ("%scan", "fusion", 0.010,
+             "jit(f)/ps.grad/transpose(jvp(ps.kda))/checkpoint/"
+             "ps.kda/core/while/body/dot_general"),
+            ("%swiglu", "fusion", 0.006,
+             _JVP.format("ps.ffn") + "dot_general"),
+            ("%shared", "fusion", 0.002,
+             _JVP.format("ps.moe/shared") + "dot_general"),
+            ("%flash", "custom-call", 0.010,
+             _JVP.format("ps.attn") + "pallas_call"),
+            ("%latent", "fusion", 0.004,
+             "jit(f)/ps.grad/transpose(jvp(ps.attn))/dot_general"),
+            *_EXPERTS, *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "kda_core_flops": 1.0,
+                  "kda_core_bytes": 1e9, "flash_flops": 2e9,
+                  "flash_bytes": 1.0},
+        "counters": {**_HELD, "held_pair_share": 0.03125},
+        "want": {**_EXPERTS_WANT,
+                 "decoder.kda_ms": 8.0,                # with taps and rule
+                 "decoder.kda_conv_ms": 1.0, "decoder.kda_core_ms": 5.0,
+                 "decoder.dense_ffn_ms": 3.0, "decoder.shared_ffn_ms": 1.0,
+                 "decoder.attn_ms": 7.0,               # the latent attention
+                 "kernel.kda_core_roofline": 20.0,     # 1 of 5 ms
+                 "kernel.flash_roofline": 40.0,        # 2 of 5 ms
+                 "decoder.held_pair_share": 0.03125},
+        "mfu": 5.0, "device_ms": 32.0},
+    "nemotron": {
+        "events": [
+            ("%in_proj", "fusion", 0.004, _CP + "ps.mamba/dot"),
+            ("%taps", "fusion", 0.002, _CP + "ps.mamba/ps.mamba/conv/mul"),
+            ("%scan", "fusion", 0.010,
+             "jit(f)/ps.grad/transpose(jvp())/checkpoint/ps.mamba/"
+             "ps.mamba/ssd/while/body/dot_general"),
+            ("%down", "fusion", 0.003, _CP + "ps.moe/latent/dot"),
+            ("%shared", "fusion", 0.002, _CP + "ps.moe/shared/dot"),
+            ("%flash", "custom-call", 0.010, _CP + "ps.attn/pallas_call"),
+            ("%qkv", "fusion", 0.004, _BACK + "ps.attn/dot_general"),
+            *_EXPERTS, *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "ssd_flops": 1.0, "ssd_bytes": 1e9,
+                  "flash_flops": 2e9, "flash_bytes": 1.0},
+        "counters": {**_HELD, "held_pair_share": 0.015625},
+        "want": {**_EXPERTS_WANT,
+                 "decoder.mamba_ms": 8.0,              # with filter and scan
+                 "decoder.mamba_conv_ms": 1.0, "decoder.ssd_ms": 5.0,
+                 "decoder.latent_ms": 1.5, "decoder.shared_ffn_ms": 1.0,
+                 "decoder.attn_ms": 7.0,
+                 "kernel.ssd_roofline": 20.0,          # 1 of 5 ms
+                 "kernel.flash_roofline": 40.0,        # 2 of 5 ms
+                 "decoder.held_pair_share": 0.015625},
+        "mfu": 5.0, "device_ms": 30.5},
+    "trinity": {
+        "events": [
+            ("%qkv", "fusion", 0.004, _BACK + "ps.attn/dot_general"),
+            ("%gate", "fusion", 0.002, _CP + "ps.attn/ps.attn/gate/mul"),
+            ("%pack", "fusion", 0.001,
+             _CP + "ps.attn/ps.attn/window/transpose"),
+            ("%band", "custom-call", 0.008,
+             _CP + "ps.attn/ps.attn/window/pallas_call"),
+            ("%triangle", "custom-call", 0.010,
+             _RULE + "ps.attn/ps.attn/full/pallas_call"),
+            ("%dense", "fusion", 0.003, _CP + "ps.ffn/dot"),
+            ("%shared", "fusion", 0.002, _CP + "ps.moe/shared/dot"),
+            *_EXPERTS, *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "window_flash_flops": 1e9,
+                  "window_flash_bytes": 1.0, "flash_flops": 1.0,
+                  "flash_bytes": 2e9, "window_live_step_share": 0.284},
+        "counters": {**_HELD, "held_pair_share": 0.125},
+        "want": {**_EXPERTS_WANT,
+                 "decoder.attn_ms": 12.5,              # cores and gate in
+                 "decoder.window_core_ms": 4.5, "decoder.full_core_ms": 5.0,
+                 "decoder.attn_gate_ms": 1.0, "decoder.dense_ffn_ms": 1.5,
+                 "decoder.shared_ffn_ms": 1.0,
+                 # the kernels alone in the denominators, each kind over
+                 # its own calls
+                 "kernel.window_flash_roofline": 25.0,
+                 "kernel.flash_roofline": 40.0,
+                 "decoder.held_pair_share": 0.125,
+                 "decoder.window_live_step_share": 0.284},
+        "mfu": 5.0, "device_ms": 28.0},
+    "mellum": {   # four chips share each layer; no share held, none dropped
+        "chips": 4,
+        "events": [
+            ("%qkv", "fusion", 0.004, _BACK + "ps.attn/dot_general"),
+            ("%pack", "fusion", 0.001,
+             _CP + "ps.attn/ps.attn/window/transpose"),
+            ("%band", "custom-call", 0.008,
+             _CP + "ps.attn/ps.attn/window/pallas_call"),
+            ("%triangle", "custom-call", 0.010,
+             _RULE + "ps.attn/ps.attn/full/pallas_call"),
+            ("%all-to-all.1", "all-to-all", 0.004,
+             _CP + "ps.moe/dispatch/ps.moe/exchange/all_to_all", _ROWS),
+            ("%all-to-all.2", "all-to-all", 0.0035, _EXCHANGE, _ROWS),
+            # the group sizes' exchange and a further trip's small buffer
+            ("%all-to-all.3", "all-to-all", 0.00025, _EXCHANGE, _SIZES),
+            ("%all-to-all.4", "all-to-all", 0.00025, _EXCHANGE,
+             "bf16[4,8,8]"),
+            ("%copy.9", "fusion", 0.002, _EXCHANGE),
+            ("%all-gather.1", "all-gather", 0.002,
+             "jit(f)/ps.apply/sharding_constraint"),
+            *_EXPERTS, *_REST],
+        # every chip waits for the slowest: the exchange's and the store's
+        # collectives are the worst chip's, which need not be the same one
+        "on_chip": {2: {"%all-to-all.1": 0.006}, 1: {"%all-gather.1": 0.003}},
+        "facts": {**_TARGETS, "dense_flops_per_step": 5e9,
+                  "exchange_bytes_per_row": 150.0,
+                  "exchange_buffer_rows": 96, "layers": 1,
+                  "window_flash_flops": 1e9, "window_flash_bytes": 1.0,
+                  "flash_flops": 1.0, "flash_bytes": 2e9},
+        "counters": {"exchange_rows_per_step": 1e6, "dropped_tokens": 0.0},
+        "want": {"decoder.route_ms": 0.5,
+                 "decoder.dispatch_ms": 2.0,           # less the exchange
+                 "decoder.expert_ms": 4.0, "decoder.head_ms": 2.5,
+                 "decoder.attn_ms": 11.5, "decoder.window_core_ms": 4.5,
+                 "decoder.full_core_ms": 5.0,
+                 "decoder.exchange_ms": 6.0,           # with its copy
+                 "decoder.exchange_exposed_ms": 5.0,
+                 "decoder.store_collective_ms": 1.5,
+                 # two exchanges of rows in the one layer, counted in the
+                 # trace: 1e6 rows x 150 B x 2 over 1e11 B/s is 3 ms of the 6
+                 "decoder.exchange_ici_share": 50.0,
+                 "kernel.window_flash_roofline": 25.0,
+                 "kernel.flash_roofline": 40.0,
+                 "decoder.dropped_tokens": 0.0},
+        "mfu": 5.0, "device_ms": 30.5},
+    "sdar": {
+        "events": [
+            ("%qkv", "fusion", 0.004, _CP + "ps.attn/dot_general"),
+            ("%clean", "custom-call", 0.006,
+             _CP + "ps.attn/ps.attn/full/pallas_call"),
+            ("%strict", "custom-call", 0.004,
+             _CP + "ps.attn/ps.attn/full/pallas_call"),
+            ("%dkv", "custom-call", 0.010,
+             _RULE + "ps.attn/ps.attn/full/pallas_call"),
+            ("%pack", "fusion", 0.002,
+             _CP + "ps.attn/ps.attn/full/transpose"),
+            # the program's own scope, no metric of its own: inside attn_ms
+            ("%own", "fusion", 0.003, _CP + "ps.attn/ps.attn/inblock/reduce"),
+            ("%merge", "fusion", 0.001,
+             _RULE + "ps.attn/ps.attn/inblock/exp"),
+            ("%route", "fusion", 0.002, _CP + "ps.moe/route/dot"),
+            ("%rows", "fusion", 0.003, _CP + "ps.moe/dispatch/gather"),
+            ("%back", "fusion", 0.001, _CP + "ps.moe/combine/gather"),
+            ("%gmm", "custom-call", 0.008, _CP + "ps.moe/expert/pallas_call"),
+            ("%ce", "fusion", 0.004, "jit(f)/ps.grad/jvp(ps.head)/reduce"),
+            ("%embed", "fusion", 0.001, "jit(f)/ps.grad/jvp()/gather"),
+            ("%adam", "fusion", 0.005, "jit(f)/ps.apply/mul")],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "flash_flops": 2e9,
+                  "flash_bytes": 1.0},
+        "counters": {"dropped_tokens": 0.0, "live_pairs_per_step": 1000.0,
+                     "held_pair_share": 0.125, "load_max_over_mean": 6.5,
+                     "masked_share": 0.5},
+        "want": {"decoder.attn_ms": 15.0,     # the cores and the own blocks in
+                 "decoder.full_core_ms": 11.0,  # the kernels and the packing
+                 "decoder.route_ms": 1.0, "decoder.dispatch_ms": 2.0,
+                 "decoder.expert_ms": 4.0, "decoder.head_ms": 2.0,
+                 "decoder.expert_mxu_share": 25.0,      # 1 of 4 ms
+                 # 2 ms of MXU over the three Mosaic calls' 10 ms
+                 "kernel.flash_roofline": 20.0,
+                 "decoder.held_pair_share": 0.125,
+                 "decoder.load_max_over_mean": 6.5,
+                 "decoder.dropped_tokens": 0.0},
+        # what the reader may come to say of this result without this file
+        # changing (PERF.md section 7, row 0): the own blocks' scope under
+        # a name of its own, the counter as it stands
+        "may": {"decoder.inblock_ms": 2.0, "decoder.masked_share": 0.5},
+        "mfu": 5.0, "device_ms": 27.0}}
+
+
+@pytest.mark.parametrize("case", sorted(READER_CASES))
+def test_the_one_reader_reads_a_decoders_hand_made_result(monkeypatch, case):
+    """Through ``layer_metrics/decoder.py::read`` and ``scope_times`` with
+    the one set of keys: each scope's metric by the innermost scope, a scope
+    opened inside another in both, dispatch and combine as one and less the
+    exchange; a kernel's roofline over the Mosaic calls under the
+    attention's scope, each kind over its own (the grouped matmul's under
+    ``ps.moe/expert`` in none), or over the rule's or the scan's scope; the
+    shares; the counters as they stand; ``step.mfu`` from the dense part
+    and the pairs; a scope the reader has no name for (``ps.attn/inblock``)
+    in its outer one."""
+    made = READER_CASES[case]
+    chips = made.get("chips", 1)
+    devices = {}
+    for chip in range(chips):
+        here = made.get("on_chip", {}).get(chip, {})
+        devices[f"d{chip}"] = {"ops": {
+            _ev(own, opcode, *shape) + (
+                _CALL if opcode == "custom-call" else ""): here.get(own, sec)
+            for own, opcode, sec, _, *shape in made["events"]}}
+    names = {own: op_name for own, _, _, op_name, *_ in made["events"]}
+    busy_s = sum(sec for _, _, sec, *_ in made["events"])
+    assert 1e3 * busy_s / 2 == pytest.approx(made["device_ms"])
+    facts = made["facts"]
+    r = {"trace": {"devices": devices, "busy_s": busy_s},
+         "traced_steps": 2, "chips": chips, "counters": made["counters"],
+         "facts": facts,
+         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12,
+                   "ici_bits_per_s": 8e11},
+         "steps": 10, "window_s": 1.0}
+    times = decoder.scope_times(r, names)
+    want, may = made["want"], made.get("may", {})
+    assert {k for k in want if k not in decoder.COUNTS.values()} \
+        <= set(times) <= set(want) | set(may)
+    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
+    whole = decoder.read(r)
+    assert set(want) <= set(whole) <= set(want) | set(may), sorted(
+        set(whole) ^ set(want))
+    for k, v in {**want, **may}.items():
+        assert whole.get(k, v) == pytest.approx(v, rel=1e-9), k
+    got = step.read(r)
+    assert got["step.mfu"] == pytest.approx(made["mfu"], rel=1e-9)
+    assert got["step.device_ms"] == pytest.approx(made["device_ms"], rel=1e-9)
+    if "exchange_buffer_rows" in facts:
+        # which events are an exchange of the first trip's rows
+        assert [decoder.is_row_exchange(n, 96) for n in (
+            _ev("%a", "all-to-all", _ROWS),
+            _ev("%a", "all-to-all-start", _ROWS),
+            _ev("%a", "all-to-all-done", _ROWS),
+            _ev("%a", "all-to-all", _SIZES), _ev("%a", "fusion", _ROWS),
+            _ev("%a", "all-to-all", "bf16[4,960,8]"))
+        ] == [True, True, False, False, False, False]
+        # a program without the rows' exchange (or whose buffers changed
+        # shape), or without the counter of rows, reads no share of the
+        # interconnect rather than a wrong one
+        for other in ({**r, "facts": {**facts, "exchange_buffer_rows": 97}},
+                      {**r, "counters": {"dropped_tokens": 0.0}}):
+            assert set(times) - set(decoder.scope_times(other, names)) == {
+                "decoder.exchange_ici_share"}
+    # a program without the scopes or the counters: nothing to read, nothing
+    # at 0
+    r["trace"]["devices"] = {"d0": {"ops": {_ev("%qkv"): 0.004}}}
+    assert decoder.scope_times(r, {}) == {}
+    assert decoder.read({"counters": {}, "facts": {}}) == {}
 
 
 # -- the program's spans -------------------------------------------------------
@@ -1016,51 +1042,6 @@ def _rehearse(cell):
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] and line["metrics"] == {}
     return line
-
-
-def test_benchmark_command_rehearses_the_lfm2_cell():
-    """PR 32's cell on the CPU, as OLMoE's: the command's own control flow
-    at the tiny sizes, ``correct`` with every step-0 check, and all fifteen
-    ``lfm2.*`` metrics listed."""
-    line = _rehearse("lfm2-24b-a2b.s8192.zipf")
-    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        listed = {m["name"] for m in json.load(f)["per_layer"]
-                  if m["name"].startswith("lfm2.")}
-    assert len(listed) == 15 and listed <= set(line["rehearsed"])
-    assert not {n for n in line["rehearsed"] if n.startswith("moe.")}
-
-
-@pytest.mark.parametrize("cell", ["olmoe-1b-7b.s4096.zipf",
-                                  "bert-base.s128.full"])
-def test_benchmark_command_rehearses_the_new_cells(cell):
-    """PR 28's cells: the OLMoE cell lists every ``moe.*`` metric, the BERT
-    cell without the kernel lists none of another cell's."""
-    line = _rehearse(cell)
-    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    expert = {m["name"] for m in manifest["per_layer"]
-              if m["name"].startswith("moe.")}
-    assert len(expert) == 10
-    assert all(m.get("workloads") == ["olmoe-1b-7b.s4096.zipf"]
-               for m in manifest["per_layer"] if m["name"] in expert)
-    if cell.startswith("olmoe"):
-        assert expert <= set(line["rehearsed"])
-    else:
-        assert not expert & set(line["rehearsed"])
-        assert {"entry.compile_s", "loop.dispatch_ms"} <= set(
-            line["rehearsed"])
-
-
-def test_benchmark_command_rehearses_the_host_metrics():
-    """A traced rehearsal of the Wide&Deep cell lists the ``host.*`` metrics
-    the cell is to report."""
-    line = _rehearse("widedeep-criteo.b4096.zipf")
-    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        listed = {m["name"] for m in json.load(f)["per_layer"]
-                  if m["name"].startswith("host.")
-                  and "widedeep-criteo.b4096.zipf" in m.get(
-                      "workloads", ["widedeep-criteo.b4096.zipf"])}
-    assert listed and listed <= set(line["rehearsed"])
 
 
 # -- set-up from inside the program -------------------------------------------
@@ -1423,13 +1404,6 @@ def test_set_up_reader_on_the_process_ring(monkeypatch, capsys):
     assert "it turned over: 2 spans dropped" in capsys.readouterr().err
 
 
-def test_benchmark_command_rehearses_the_set_up_metrics():
-    """A traced rehearsal of the Wide&Deep cell lists the nine ``setup.*``
-    metrics, as every cell's does: they name no ``workloads``."""
-    line = _rehearse("widedeep-criteo.b4096.zipf")
-    assert set(SETUP_METRICS) <= set(line["rehearsed"])
-
-
 # -- Mellum: the exchange's scope, on a mesh of four ---------------------------
 
 def _mellum_step():
@@ -1472,18 +1446,10 @@ def test_mellum_scopes_reach_the_step_hlo_forward_and_backward(
     two cores: each in the lowered step's ``op_name``s under ``ps.grad``,
     forward and backward, under a ``jax.checkpoint``, a ``shard_map`` and a
     ``custom_vjp``; the exchange's ops are collectives and sit under both of
-    its parents; the reader's copy is equal."""
-    from benchmark.harness import tracered
-    from benchmark.layer_metrics import mellum as mellum_metrics
-
-    assert phases.MELLUM_SCOPES == mellum_metrics.MELLUM_SCOPES
+    its parents; the reader finds each, and the exchange is no part of the
+    scopes it is opened inside."""
     assert phases.MELLUM_SCOPES[:6] == phases.MOE_SCOPES
-    for name in ("MOE_EXCHANGE", "ATTN_WINDOW", "ATTN_FULL", "MOE_ROUTE",
-                 "MOE_DISPATCH", "MOE_EXPERT", "MOE_COMBINE", "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(mellum_metrics, name)
-    assert not set(phases.MELLUM_SCOPES) & set(phases.DEVICE_PHASES)
-    assert set(mellum_metrics.SCOPE_METRICS) <= set(phases.MELLUM_SCOPES)
-    assert set(mellum_metrics._INNERMOST_FIRST) == set(phases.MELLUM_SCOPES)
+    assert decoder.outer_of(phases.MOE_EXCHANGE) is None
     monkeypatch.setitem(BUILDERS, "mellum", _mellum_step)
     names = scope.op_names_of(_step_hlo("mellum"))
     for s in phases.MELLUM_SCOPES:
@@ -1494,7 +1460,7 @@ def test_mellum_scopes_reach_the_step_hlo_forward_and_backward(
         assert under and all(phases.GRAD in n for n in under), s
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
-    found = {mellum_metrics.scope_of(own, n) for own, n in names.items()}
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
     assert found == set(phases.MELLUM_SCOPES) | {None}
     exchanged = [(own, n) for own, n in names.items()
                  if phases.MOE_EXCHANGE in n]
@@ -1502,93 +1468,6 @@ def test_mellum_scopes_reach_the_step_hlo_forward_and_backward(
     for parent in (phases.MOE_DISPATCH, phases.MOE_COMBINE):
         assert any(parent in n for _, n in exchanged), parent
     assert tracered.is_collective("%all-to-all.7 = f32[4] all-to-all(%x)")
-
-
-def test_mellum_reader_on_a_hand_made_result(monkeypatch):
-    from benchmark.layer_metrics import mellum as mellum_metrics
-
-    call = 'custom_call_target="tpu_custom_call"'
-    rows, sizes = "bf16[4,96,8]", "s32[4,2]"
-    ops = {_ev("%qkv"): 0.004, _ev("%pack"): 0.001,
-           _ev("%band", "custom-call") + call: 0.008,
-           _ev("%triangle", "custom-call") + call: 0.010,
-           _ev("%route"): 0.001, _ev("%rows"): 0.003, _ev("%back"): 0.001,
-           _ev("%all-to-all.1", "all-to-all", rows): 0.006,
-           _ev("%all-to-all.2", "all-to-all", rows): 0.0035,
-           # the group sizes' exchange and a further trip's small buffer
-           _ev("%all-to-all.3", "all-to-all", sizes): 0.00025,
-           _ev("%all-to-all.4", "all-to-all", "bf16[4,8,8]"): 0.00025,
-           _ev("%copy.9"): 0.002,
-           _ev("%all-gather.1", "all-gather"): 0.003,
-           _ev("%ragged-dot-none.1", "custom-call") + call: 0.008,
-           _ev("%ce"): 0.005, _ev("%embed"): 0.001, _ev("%adam"): 0.007}
-    cp = "jit(f)/ps.grad/jvp()/checkpoint/"
-    exchange = cp + "ps.moe/combine/ps.moe/exchange/all_to_all"
-    names = {"%qkv": "jit(f)/ps.grad/transpose(jvp())/ps.attn/dot_general",
-             "%pack": cp + "ps.attn/ps.attn/window/transpose",
-             "%band": cp + "ps.attn/ps.attn/window/pallas_call",
-             "%triangle": "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
-                          "ps.attn/ps.attn/full/pallas_call",
-             "%route": cp + "ps.moe/route/dot",
-             "%rows": cp + "ps.moe/dispatch/gather",
-             "%back": "jit(f)/ps.grad/transpose(jvp())/ps.moe/combine/gather",
-             "%all-to-all.1": cp + "ps.moe/dispatch/ps.moe/exchange/"
-                                   "all_to_all",
-             "%all-to-all.2": exchange, "%all-to-all.3": exchange,
-             "%all-to-all.4": exchange, "%copy.9": exchange,
-             "%all-gather.1": "jit(f)/ps.apply/sharding_constraint",
-             "%ragged-dot-none.1": "ragged-dot-none",
-             "%ce": "jit(f)/ps.grad/jvp(ps.head)/reduce",
-             "%embed": "jit(f)/ps.grad/jvp()/gather",
-             "%adam": "jit(f)/ps.apply/mul"}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}}, "traced_steps": 2,
-         "counters": {"mellum_exchange_rows_per_step": 1e6,
-                      "mellum_dropped_tokens": 0.0},
-         "facts": {"kernel_targets": ["tpu_custom_call"],
-                   "mellum_step_flops": 5e9,
-                   "mellum_exchange_bytes_per_row": 150.0,
-                   "mellum_exchange_buffer_rows": 96,
-                   "mellum_layers": 1,
-                   "mellum_window_flash_flops": 1e9,
-                   "mellum_window_flash_bytes": 1.0,
-                   "mellum_full_flash_flops": 1.0,
-                   "mellum_full_flash_bytes": 2e9},
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12,
-                   "ici_bits_per_s": 8e11},
-         "steps": 10, "window_s": 1.0}
-    assert [mellum_metrics.is_row_exchange(n, 96) for n in (
-        _ev("%a", "all-to-all", rows), _ev("%a", "all-to-all-start", rows),
-        _ev("%a", "all-to-all-done", rows), _ev("%a", "all-to-all", sizes),
-        _ev("%a", "fusion", rows), _ev("%a", "all-to-all", "bf16[4,960,8]"))
-    ] == [True, True, False, False, False, False]
-    out = mellum_metrics.scope_times(r, names)
-    assert out["mellum.dispatch_ms"] == pytest.approx(2.0)  # less the exchange
-    assert out["mellum.exchange_ms"] == pytest.approx(6.0)  # with its copy
-    assert out["mellum.exchange_exposed_ms"] == pytest.approx(5.0)
-    assert out["mellum.store_collective_ms"] == pytest.approx(1.5)
-    assert out["mellum.expert_ms"] == pytest.approx(4.0)
-    # two exchanges of rows in the one layer, counted in the trace:
-    # 1e6 rows x 150 B x 2 over 1e11 B/s is 3 ms of the 6
-    assert out["mellum.exchange_ici_share"] == pytest.approx(50.0)
-    assert out["mellum.window_flash_roofline"] == pytest.approx(25.0)
-    assert out["mellum.full_flash_roofline"] == pytest.approx(40.0)
-    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
-    whole = mellum_metrics.read(r)
-    assert whole["mellum.mfu"] == pytest.approx(5.0)  # 5e9 x 10 / s of 1e12
-    listed = {m["name"] for m in json.load(open(os.path.join(
-        _REPO, "BENCHMARK.json")))["per_layer"]
-        if m["name"].startswith("mellum.")}
-    # every metric the reader computes is listed, and none besides
-    assert len(listed) == 10 and listed == set(whole)
-    # a program without the rows' exchange (or whose buffers changed shape)
-    # reads no share of the interconnect rather than a wrong one
-    r["facts"]["mellum_exchange_buffer_rows"] = 97
-    assert "mellum.exchange_ici_share" not in mellum_metrics.scope_times(
-        r, names)
-    # a program without the scopes or the counters
-    r["trace"]["devices"]["d0"]["ops"] = {_ev("%qkv"): 0.004}
-    assert mellum_metrics.scope_times(r, {}) == {}
-    assert mellum_metrics.read({"counters": {}, "facts": {}}) == {}
 
 
 def _sdar_step():
@@ -1621,22 +1500,11 @@ def test_sdar_scopes_reach_the_step_hlo_forward_and_backward(
     Trinity's ``ps.attn/full`` around its two attention calls, and
     ``ps.attn/inblock`` around the own-block term and the merge: each in the
     lowered step's ``op_name``s under ``ps.grad``, forward and backward,
-    though every layer is under a ``jax.checkpoint``. The shared names are
-    the one decoder reader's copy, letter for letter; ``ps.attn/inblock`` is
+    though every layer is under a ``jax.checkpoint``. ``ps.attn/inblock`` is
     the program's alone until a ``benchmark`` PR copies it, and the reader
     counts its ops inside ``ps.attn``."""
-    from benchmark.layer_metrics import decoder
-
-    assert phases.SDAR_SCOPES[:6] == phases.MOE_SCOPES
     assert phases.SDAR_SCOPES == phases.MOE_SCOPES + (phases.ATTN_FULL,
                                                       phases.ATTN_INBLOCK)
-    for name in ("ATTN_FULL", "MOE_ROUTE", "MOE_DISPATCH", "MOE_EXPERT",
-                 "MOE_COMBINE", "ATTN", "HEAD"):
-        assert getattr(phases, name) == getattr(decoder, name)
-        assert getattr(phases, name) in phases.SDAR_SCOPES
-    assert phases.ATTN_INBLOCK not in decoder.SCOPES
-    assert not hasattr(decoder, "ATTN_INBLOCK")
-    assert not set(phases.SDAR_SCOPES) & set(phases.DEVICE_PHASES)
     monkeypatch.setitem(BUILDERS, "sdar", _sdar_step)
     names = scope.op_names_of(_step_hlo("sdar"))
     for s in phases.SDAR_SCOPES:
@@ -1645,6 +1513,96 @@ def test_sdar_scopes_reach_the_step_hlo_forward_and_backward(
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
     found = {decoder.scope_of(own, n) for own, n in names.items()}
-    assert found == (set(phases.SDAR_SCOPES) - {phases.ATTN_INBLOCK}) | {None}
-    own_blocks = [n for n in names.values() if phases.ATTN_INBLOCK in n]
-    assert {decoder.scope_of("%x", n) for n in own_blocks} == {decoder.ATTN}
+    assert found == (set(phases.SDAR_SCOPES) & set(decoder.METRICS)) | {None}
+    # the own blocks' ops count in decoder.attn_ms, as the attention's own
+    # while the reader has no name for their scope
+    own_blocks = {decoder.scope_of("%x", n) for n in names.values()
+                  if phases.ATTN_INBLOCK in n}
+    assert own_blocks and all(
+        phases.ATTN in (s, decoder.outer_of(s)) for s in own_blocks)
+
+
+# -- the benchmark's own command on the CPU, and its manifest ------------------
+
+with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
+    _MANIFEST = json.load(_f)
+
+
+@functools.lru_cache(maxsize=None)
+def _rehearse(cell):
+    """The result line of a traced rehearsal of ``cell``: the command's own
+    control flow at the tiny sizes, in a process of its own."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
+                        "JAX_COMPILATION_CACHE_DIR")}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "benchmark", "run.py"),
+         "--workload", cell, "--rehearse", "--trace", "1", "--seconds", "1"],
+        env=env, cwd=_REPO, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+_HOST_METRICS = ("host.step_launch_ms", "host.step_wrap_ms",
+                 "host.input_place_ms", "host.input_mb_per_step")
+#: cell -> what its rehearsal lists whatever the manifest says (metrics of
+#: readers of their own, by name). One cell of each decoder, the sparse cell,
+#: and a dense cell that lists nothing of its own; not all fourteen: each is
+#: a process of 15-40 s
+REHEARSED = {
+    "widedeep-criteo.b4096.zipf": _HOST_METRICS + SETUP_METRICS,
+    "bert-base.s128.full": ("entry.compile_s", "loop.dispatch_ms"),
+    "olmoe-1b-7b.s4096.zipf": (),
+    "lfm2-24b-a2b.s8192.zipf": (),
+    "kimi-linear-48b-a3b.s8192.b1.zipf": (),
+    "nemotron-3-super-120b-a12b.s8192.b1.zipf": (),
+    "trinity-mini.s16384.b1.zipf": (),
+    "mellum2-12b-a2.5b.s8192.b1.zipf.x4": (),
+    "sdar-30b-a3b.s8192.b1.zipf.bd4": (
+        "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
+        "setup.import_s", "device.peak_hbm_gib")}
+
+
+@pytest.mark.parametrize("cell", sorted(REHEARSED))
+def test_benchmark_command_rehearses_a_cell(cell, listed_for):
+    """``correct`` with every step-0 check, no value under a device metric's
+    name, as many devices as the cell asks for, and of the per-layer names
+    what the manifest lists for the cell: every name whose ``workloads``
+    names it (a decoder's reader lists its names from the marks of the
+    loaded step) and none the manifest does not give it (no other
+    configuration's)."""
+    line = _rehearse(cell)
+    assert line["correct"] and line["metrics"] == {}
+    asked = next(w for w in _MANIFEST["workloads"] if w["name"] == cell)
+    assert line["device"]["count"] == asked["chips"]
+    rehearsed, listed = set(line["rehearsed"]), listed_for(cell)
+    assert rehearsed <= {m["name"] for m in listed}
+    by_name = {m["name"] for m in listed if "workloads" in m
+               # scope.py and sparse.py read a device's trace and nothing
+               # else: they list nothing without one
+               and (m["source"] != "device_trace"
+                    or m["name"].split(".")[0] not in ("scope", "sparse"))}
+    assert by_name <= rehearsed, sorted(by_name - rehearsed)
+    assert set(REHEARSED[cell]) <= rehearsed
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in _MANIFEST["workloads"]])
+def test_every_listed_metric_has_a_reader(cell, listed_for):
+    """What ``benchmark/run.py`` needs of the manifest to report a cell: each
+    per-layer name listed for it resolves to a module of ``layer_metrics/``
+    with a ``read``, as ``run.py`` resolves it; and of the manifest as a
+    whole: no name twice, every ``workloads`` entry a cell, every ``moves``
+    an end-to-end metric, at most a quarter of the cells on four chips."""
+    for m in listed_for(cell):
+        group = m["name"].split(".", 1)[0]
+        module = importlib.import_module(f"benchmark.layer_metrics.{group}")
+        assert callable(module.read), m["name"]
+    cells = [w["name"] for w in _MANIFEST["workloads"]]
+    names = [m["name"] for m in _MANIFEST["per_layer"]]
+    assert len(set(cells)) == len(cells) and len(set(names)) == len(names)
+    end_to_end = {m["name"] for m in _MANIFEST["end_to_end"]}
+    for m in _MANIFEST["per_layer"]:
+        assert set(m.get("workloads", ())) <= set(cells), m["name"]
+        assert m["moves"] in end_to_end, m["name"]
+    four = [w for w in _MANIFEST["workloads"] if w["chips"] == 4]
+    assert len(four) <= len(cells) // 4

@@ -15,6 +15,8 @@ Drills:
   four-surface sync pin (field / env / README / docstrings).
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -51,6 +53,19 @@ def _push(port, payload):
         return bytes(ch.request(bytes(payload)))
     finally:
         ch.close()
+
+
+def _counted(svc, counter, want, deadline_s=10.0):
+    """``admit_stats()[counter]`` once it has reached ``want``, or as it
+    stands when ``deadline_s`` ran out. The native loop counts an ack or a
+    refusal after it has written the reply (``van.cpp::nl_admit``), so the
+    client can hold the bytes before the counter has moved: wait on the
+    counter, do not read it once."""
+    end = time.monotonic() + deadline_s
+    while (got := svc._nloop.admit_stats()[counter]) < want \
+            and time.monotonic() < end:
+        time.sleep(0.005)
+    return got
 
 
 def _sparse_emb():
@@ -97,7 +112,7 @@ def test_dense_replay_ack_byte_parity(request, monkeypatch):
         kind, _, _, extra = tv.decode(raw_native)
         assert kind == tv.OK and extra["dedup"] is True
         # served natively, and never re-applied on either side
-        assert native._nloop.admit_stats()["acks"] == base + 1
+        assert _counted(native, "acks", base + 1) == base + 1
         assert pump._engine.version == vpump
         assert native._engine.version == vnat
         # a strictly-fresh seq still applies exactly once through Python
@@ -140,7 +155,7 @@ def test_sparse_replay_ack_byte_parity(request, monkeypatch):
         assert raw_pump == raw_native
         kind, _, _, extra = tv.decode(raw_native)
         assert kind == tv.OK and extra["dedup"] is True
-        assert native._nloop.admit_stats()["acks"] == base + 1
+        assert _counted(native, "acks", base + 1) == base + 1
         assert dict(native.versions) == vers  # exactly once
     finally:
         pump.stop()
@@ -170,7 +185,7 @@ def test_backup_refusal_byte_parity(request, monkeypatch):
         kind, _, _, extra = tv.decode(raw_native)
         assert kind == tv.ERR and extra["backup"] is True
         assert "retry after promotion" in extra["error"]
-        assert native._nloop.admit_stats()["refusals"] == base + 1
+        assert _counted(native, "refusals", base + 1) == base + 1
         assert native._engine.version == 0  # refused, not applied
     finally:
         pump.stop()
@@ -206,7 +221,7 @@ def test_failover_reseeds_mirror_and_acks_natively(request, monkeypatch):
         kind, _, _, extra = tv.decode(raw)
         assert kind == tv.OK and extra["dedup"] is True
         assert extra["version"] == 1
-        assert back._nloop.admit_stats()["acks"] == base + 1
+        assert _counted(back, "acks", base + 1) == base + 1
         assert back._engine.version == 1  # exactly once across failover
     finally:
         back.stop()

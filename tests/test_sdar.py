@@ -4,15 +4,14 @@ under an attention edge a block wide) against its plain reference
 (``benchmark/families/sdar_reference.py``, the doubled sequence under one
 explicit ``[2 L, 2 L]`` mask), at small sizes on the CPU, and the pieces of
 its benchmark family (``benchmark/families/sdar_step.py``): the noising, the
-limits of the step-0 checks, the operations from shapes, the configuration,
-the cell, and the one decoder reader on a hand-made result of its scopes.
+limits of the step-0 checks, the operations from shapes, the configuration
+and the cell (its rehearsal and the one decoder reader on a hand-made result
+of its scopes are cases of ``tests/test_phases.py``).
 """
 
 import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +22,6 @@ from jaxpr_tools import flash_calls
 from benchmark.families import flash
 from benchmark.families import sdar_reference as reference
 from benchmark.families import sdar_step
-from benchmark.layer_metrics import decoder, scope, step
 from ps_tpu.models import sdar
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -668,9 +666,6 @@ def test_configuration_holds_the_published_widths():
             traffic["block_length"], traffic["pool"]) == (8192, 1, 4, "fresh")
     assert traffic["loss_step"] == int(cell["traffic"].rpartition(".n")[2])
     assert traffic["loss_step"] in sdar_step.LOSS_STEPS
-    # no per-layer entry names the cell: the manifest holds 128 of 128
-    assert not [m["name"] for m in manifest["per_layer"]
-                if CELL in m.get("workloads", ())]
 
 
 @pytest.mark.parametrize("change", [
@@ -691,119 +686,3 @@ def test_family_refuses_a_pool_it_would_have_to_cycle_and_another_block():
         sdar_step.build(config, {**traffic, "pool": 16}, 1, 0)
     with pytest.raises(ValueError, match="blocks of 8"):
         sdar_step.build(config, {**traffic, "block_length": 8}, 1, 0)
-
-
-def test_benchmark_command_rehearses_the_cell():
-    """The benchmark's own command on the CPU: the cell's control flow at
-    the tiny sizes, ``correct`` with every step-0 check, the list-less
-    metrics listed and no configuration's own."""
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
-                        "JAX_COMPILATION_CACHE_DIR")}
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "benchmark", "run.py"),
-         "--workload", CELL, "--rehearse", "--trace", "1", "--seconds", "1"],
-        env=env, cwd=_REPO, capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["correct"] and line["metrics"] == {}
-    for check in ("no_dropped_tokens", "loss_and_its_terms_match_reference",
-                  "expert_counts_match_reference",
-                  "gradient_matches_reference",
-                  "gradient_clipped_to_global_norm",
-                  "adamw_apply_matches_rule"):
-        assert f"'{check}': True" in proc.stderr, check
-    assert {"entry.compile_s", "input.wait_share", "loop.dispatch_ms",
-            "setup.import_s", "device.peak_hbm_gib"} <= set(line["rehearsed"])
-    assert not {n for n in line["rehearsed"] if n.split(".")[0] in (
-        "kimi", "lfm2", "moe", "nemo", "trinity", "mellum")}
-
-
-# -- the one reader, on a decoder's hand-made result ---------------------------
-
-def _ev(own, opcode="fusion", tail=""):
-    return (f"{own} = f32[8] {opcode}(%p0), kind=kLoop, "
-            "calls=%fused_computation, " + "backend_config={} " * 8 + tail)
-
-
-_CALL = 'custom_call_target="tpu_custom_call"'
-_CP = "jit(f)/ps.grad/jvp()/checkpoint/"
-_BACK = "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
-
-#: a decoder's hand-made one-chip result under the one set of keys: its
-#: events (name, seconds in two traced steps, op_name), its facts and
-#: counters, and what the one reader makes of them. The next decoder is one
-#: more case.
-READER_CASES = {
-    "sdar": {
-        "events": [
-            ("%qkv", "fusion", 0.004, _CP + "ps.attn/dot_general"),
-            ("%clean", "custom-call", 0.006,
-             _CP + "ps.attn/ps.attn/full/pallas_call"),
-            ("%strict", "custom-call", 0.004,
-             _CP + "ps.attn/ps.attn/full/pallas_call"),
-            ("%dkv", "custom-call", 0.010,
-             _BACK + "ps.attn/ps.attn/full/pallas_call"),
-            ("%pack", "fusion", 0.002,
-             _CP + "ps.attn/ps.attn/full/transpose"),
-            # the program's own scope, no metric of its own: inside attn_ms
-            ("%own", "fusion", 0.003, _CP + "ps.attn/ps.attn/inblock/reduce"),
-            ("%merge", "fusion", 0.001,
-             _BACK + "ps.attn/ps.attn/inblock/exp"),
-            ("%route", "fusion", 0.002, _CP + "ps.moe/route/dot"),
-            ("%rows", "fusion", 0.003, _CP + "ps.moe/dispatch/gather"),
-            ("%back", "fusion", 0.001, _CP + "ps.moe/combine/gather"),
-            ("%gmm", "custom-call", 0.008, _CP + "ps.moe/expert/pallas_call"),
-            ("%ce", "fusion", 0.004, "jit(f)/ps.grad/jvp(ps.head)/reduce"),
-            ("%embed", "fusion", 0.001, "jit(f)/ps.grad/jvp()/gather"),
-            ("%adam", "fusion", 0.005, "jit(f)/ps.apply/mul")],
-        "facts": {"kernel_targets": ["tpu_custom_call"],
-                  "dense_flops_per_step": 4e9, "flops_per_pair": 1e6,
-                  "flash_flops": 2e9, "flash_bytes": 1.0},
-        "counters": {"dropped_tokens": 0.0, "live_pairs_per_step": 1000.0,
-                     "held_pair_share": 0.125, "load_max_over_mean": 6.5,
-                     "masked_share": 0.5},
-        "want": {"decoder.attn_ms": 15.0,     # the cores and the own blocks in
-                 "decoder.full_core_ms": 11.0,  # the kernels and the packing
-                 "decoder.route_ms": 1.0, "decoder.dispatch_ms": 2.0,
-                 "decoder.expert_ms": 4.0, "decoder.head_ms": 2.0,
-                 "decoder.expert_mxu_share": 25.0,      # 1 of 4 ms
-                 # 2 ms of MXU over the three Mosaic calls' 10 ms
-                 "kernel.flash_roofline": 20.0,
-                 "decoder.held_pair_share": 0.125,
-                 "decoder.load_max_over_mean": 6.5,
-                 "decoder.dropped_tokens": 0.0},
-        "mfu": 5.0, "device_ms": 27.0}}
-
-
-@pytest.mark.parametrize("case", sorted(READER_CASES))
-def test_the_one_reader_reads_a_decoders_hand_made_result(monkeypatch, case):
-    """Through ``layer_metrics/decoder.py::read`` and ``scope_times`` with
-    the one set of keys: each scope's metric by the innermost scope, the
-    kernel's roofline over the Mosaic calls under the attention's scope (the
-    grouped matmul's under ``ps.moe/expert`` in none), the counters as they
-    stand, ``step.mfu`` from the dense part and the pairs; a scope the
-    reader has no name for (``ps.attn/inblock``) counts in its outer one."""
-    made = READER_CASES[case]
-    ops = {_ev(own, opcode, _CALL if opcode == "custom-call" else ""): sec
-           for own, opcode, sec, _ in made["events"]}
-    names = {own: op_name for own, _, _, op_name in made["events"]}
-    r = {"trace": {"devices": {"d0": {"ops": ops}}, "busy_s": 0.054},
-         "traced_steps": 2, "chips": 1, "counters": made["counters"],
-         "facts": made["facts"],
-         "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e12},
-         "steps": 10, "window_s": 1.0}
-    times = decoder.scope_times(r, names)
-    want = made["want"]
-    assert set(times) == {k for k in want
-                          if k not in decoder.COUNTS.values()}
-    monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
-    whole = decoder.read(r)
-    assert set(whole) == set(want), sorted(set(whole) ^ set(want))
-    for k, v in want.items():
-        assert whole[k] == pytest.approx(v, rel=1e-9), k
-    got = step.read(r)
-    assert got["step.mfu"] == pytest.approx(made["mfu"], rel=1e-9)
-    assert got["step.device_ms"] == pytest.approx(made["device_ms"], rel=1e-9)
-    # a program without the scopes: nothing to read, nothing at 0
-    assert decoder.scope_times(r, {}) == {}

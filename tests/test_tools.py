@@ -122,10 +122,12 @@ def test_ps_top_fleet_and_ps_doctor_smoke():
 
     import ps_tpu as ps
     from ps_tpu.backends.remote_async import AsyncPSService, connect_async
-    from ps_tpu.elastic import Coordinator
+    from ps_tpu.elastic import Coordinator, fetch_telemetry
 
     ps.init(backend="tpu", mode="async", num_workers=1, dc_lambda=0.0)
-    coord = Coordinator(port=0, report_ms=100, telemetry_window_s=5.0)
+    # a window that still holds the samples when the last tool asks, however
+    # long four interpreters take to start beside other processes
+    coord = Coordinator(port=0, report_ms=100, telemetry_window_s=600.0)
     caddr = f"127.0.0.1:{coord.port}"
     params = {f"p{i}/w": jnp.asarray(np.full((32, 4), 0.5, np.float32))
               for i in range(4)}
@@ -143,10 +145,19 @@ def test_ps_top_fleet_and_ps_doctor_smoke():
             w.pull_all()
             grads = {k: jnp.full_like(v, 0.01)
                      for k, v in params.items()}
-            t0 = time.monotonic()
-            while time.monotonic() - t0 < 1.5:
-                w.push_pull(grads)
-            time.sleep(0.3)
+            # traffic until the coordinator holds what the tools read: both
+            # members' samples and the worker's breakdown (not "for 1.5 s",
+            # which on a busy host can end before the first report)
+            members = {f"127.0.0.1:{svc.port}" for svc in svcs}
+            tel, end = {}, time.monotonic() + 60.0
+            while not (members <= set(tel.get("members", ()))
+                       and tel.get("fleet")
+                       and tel["breakdown"].get("total", {}).get("count", 0)
+                       > 0):
+                assert time.monotonic() < end, tel
+                for _ in range(20):
+                    w.push_pull(grads)
+                tel = fetch_telemetry(caddr)
 
             env = {k: v for k, v in os.environ.items()
                    if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
