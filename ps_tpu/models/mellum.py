@@ -29,7 +29,9 @@ held to. What differs here is how they are computed:
   ``sliding_attention`` layer and YaRN's blend in a ``full_attention`` one,
   whose cos and sin carry ``attention_factor``; ``window=sliding_window`` to
   the attention of a sliding layer. With ``attn='flash'`` K and V enter the
-  kernel at their own head count.
+  kernel at their own head count, and a sliding layer's calls are the
+  kernel's band step (a block against the window's keys before it and its
+  own, and no other).
 - ``moe_block``: softmax over all experts in f32, the top
   ``num_experts_per_tok``, their probabilities renormalised
   (``norm_topk_prob``), dropless grouped SwiGLU. Across chips the grouped

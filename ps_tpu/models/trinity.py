@@ -28,8 +28,9 @@ held to. What differs here is how they are computed:
   q and k head's own width; in a ``sliding_attention`` layer RoPE on q and k
   and ``window=sliding_window`` to the attention, in a ``full_attention``
   layer neither; the output times ``sigmoid(gate)`` in f32; out projection.
-  With ``attn='flash'`` K and V enter the kernel at their own head count and
-  the kernel skips, and does not fetch, what lies outside the band.
+  With ``attn='flash'`` K and V enter the kernel at their own head count, and
+  a windowed layer's calls are the kernel's band step: a query block against
+  the ``sliding_window`` keys before it and its own, and no other.
 - ``moe_block``: sigmoid scores in f32, the top ``num_experts_per_tok`` of
   ``score + expert_bias[layer]`` (the bias selects only), weights ``score /
   (sum of the picks' scores + 1e-20)`` times ``route_scale``, dropless grouped

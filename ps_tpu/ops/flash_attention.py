@@ -90,23 +90,65 @@ Design (TPU-first, per /opt/skills/guides/pallas_guide.md):
   index map (a second score matmul a tile) was not built: ROADMAP R3.
 
 - A WINDOW (``window=``, causal calls: Trinity's sliding layers see 2,048
-  keys of 16,384): query ``i`` sees the keys ``j`` with ``0 <= i - j <
-  window``. Each causal bound gets its mirror: in the forward and dq kernels
-  ``_first_key`` beside ``_last_live`` (the first key block a query block's
-  first row can see), in dk / dv ``_last_query`` beside ``_first_live``
-  (the last query block that sees a key block's last key), each both the
-  ``pl.when`` skip and the index maps' clamp, so a step outside the band
-  computes nothing and copies nothing; the band's lower edge is masked
-  inside its tiles as the diagonal is (``_visible``), and the backward's
-  unmasked fast path runs on the tiles wholly between the two edges. The
-  call's work follows ``S * window``, not ``S ** 2 / 2``. The TILES are the
-  causal call's, from the shapes alone (``forward_tiles`` and
-  ``backward_tiles`` take no window): a narrower block puts more of what it
-  computes inside the band but adds grid steps, and the wide one won at
-  every window measured, 256 to 2,048 (the table below).
-  ``window=None`` is the program it was, to the jaxpr but for the two
-  names below (``tests/test_flash_attention.py`` pins it); a window that
-  spans the sequence is the causal call.
+  keys of 16,384, Mellum's 1,024 of 8,192): query ``i`` sees the keys ``j``
+  with ``0 <= i - j < window``. A windowed call does not run on the tiled
+  kernels above: its grid step is shaped like the band (THE BAND STEP,
+  ``band``, the ``_band_*`` kernels and calls). *Forward and dq: grid
+  (B*h, S/block), no key axis.* A step holds one query block and the slab of
+  the ``ceil(window / block) + 1`` key and value blocks it can see, K, V and
+  the mask passed to the call once a block of the slab as plain
+  ``BlockSpec``s at block indices ``i - n + 1 .. i`` (a block before the
+  sequence's start names block 0 and is dead by position); Mosaic pipelines
+  them as it does any operand. Inside the step a static loop cuts the query
+  block into sub-blocks of ``r`` rows; sub-block ``t`` takes its scores
+  against **its own** slice of the slab, the ``window + r`` keys from the
+  first key its first row sees to its last row's own, and, since every key a
+  row sees is in the slice, softmaxes its rows **whole**: no running
+  maximum, no ``alpha``, no rescaled accumulator, no scratch, nothing
+  carried from one grid step to the next. Scores computed a row: ``window +
+  r`` (2,304 at ``r`` 256) where the tiled kernel's three key blocks of
+  1,024 computed 3,072. *Masks are added, and the position mask only where
+  an edge is.* Relative to a sub-block the band's two edges are the same two
+  triangles in every sub-block of every step (the lower edge in the slice's
+  first ``r`` columns, the diagonal in its last ``r``): two f32 bias tiles
+  of 0 / ``_NEG_INF`` made once a step, added to the pieces of the slice
+  they cross; the columns between see no position mask. The padding mask is
+  a [1, keys] bias row added to every piece. A row that sees no key (every
+  score under ``_NEG_INF / 2``) gives zeros and a logsumexp of ``_NEG_INF``
+  by a test a row, not a score; the backward reads such a row's logsumexp as
+  ``-_NEG_INF``, so that its probabilities are 0. *dk / dv: the mirror*,
+  grid (B*h_kv, S/block, group): a key block against the slab of query
+  blocks that see it (q, dO and the logsumexp and delta rows of ``j .. j + n
+  - 1``, a block past the sequence's end naming the last and dead by its
+  logsumexp), ``r`` keys at a time against their own ``window + r``
+  queries, the transposed tile, dk and dv summed over the slice in
+  registers and over the group's query heads in the f32 scratch, the only
+  carry left. A padded key's probabilities reach its own rows of dk and dv
+  and nothing else, so the padding mask is not in this tile at all: those
+  rows are written as zeros. The same work as the tiled kernels did: bf16
+  operands, f32 accumulation and softmax, exact probabilities from the
+  saved logsumexp, every pair of the band and no other; a whole-row softmax
+  in place of the online one changes roundoff, not mathematics (on the chip
+  the band's three results lie within 1 to 2 bf16 roundoffs of the tiled
+  kernels', as far as those lie from each other at other tiles). Three
+  Mosaic calls a layer as before, ``KEPT``, ``return_lse`` and the padding
+  mask composing as before.
+  ``block`` AND ``r`` are ``forward_band``'s and ``backward_band``'s, from
+  (seq, window, head_dim, itemsize) alone (``_band_tiles``): ``r`` the
+  tallest of 512, 256, 128 that is an eighth of the window at most (of a
+  sub-block's ``window + r`` scores a row, ``r`` lie outside the band: a
+  ninth at most), then the widest block whose step fits ``_VMEM_BUDGET`` by
+  ``forward_band_vmem_bytes`` / ``backward_band_vmem_bytes`` (a wider block
+  reads less of K and V twice and computes nothing more). Trinity's calls
+  run at (1024, 256) forward and backward, Mellum's at (2048, 128) and
+  (1024, 128). Under a window ``block_q=`` / ``block_k=`` force the
+  forward's ``block`` and ``r``. A window so wide that no band fits VMEM
+  (8,192 keys at head 128 in bf16: the slab alone is 8.4 MiB
+  double-buffered) is refused by the rule; no tiled form is kept for it. A
+  window that spans the sequence is the causal call; ``window=None`` is the
+  program it was, to the jaxpr (``tests/test_flash_attention.py`` pins it).
+  The table "Under a window" below has what the band replaced and what it
+  reads.
 
 - AN EDGE A BLOCK WIDE (``edge_block=``, causal calls without a window:
   block-diffusion training, ``models/sdar.py``, blocks of 4 in 8,192).
@@ -228,6 +270,48 @@ windowed layer's calls take 29.0 ms (the forward 7.31, dk / dv 12.39, dq
 9.33) and the full layer's 67.9 (19.19, 27.12, 21.58); a layer's
 ``jax.checkpoint`` that does not keep ``KEPT`` runs each forward twice.
 
+**The band step (my chip runs, PR 53: alone, median of 5 chains of 8 calls,
+seed 0, ``tools/window_table.py``, ``chiprun_out/pr53/window_table_1.json``;
+the tiled calls above in the same call, at their (1024, 1024) / (1024, 512),
+on the first and last rows).** The table above is history: PR 41's tiles are
+the program a windowed call was until PR 53. Roofline: the band's pairs, 2.62
+and 9.16 ms of MXU at Trinity's shape, 0.654 and 2.29 at Mellum's:
+
+| shape, window | block x r | forward ms | dq ms | dk / dv ms | forward, backward of its roofline |
+|---|---|---|---|---|---|
+| [32, 16384, 128] on [4, 16384, 128], 2,048 | the tiled calls | 8.454 | 11.236 | 13.221 | 31%, 37% |
+| | **1024 x 256**, the rules' | **4.056** | **5.112** | **6.639** | **65%, 78%** |
+| | 1024 x 128 | 4.101 | 5.076 | 6.458 | 64%, 79% |
+| | 1024 x 512 | 4.462 | 5.612 | 7.370 | 59%, 71% |
+| | 2048 x 256 | refused (19.9 MiB) | 4.961 | 6.541 | -, 80% |
+| | 2048 x 128 | refused (16.8 MiB) | 4.857 | 6.264 | -, 82% |
+| | 512 x 256 | 4.435 | 5.630 | 6.921 | 59%, 73% |
+| | 512 x 128 | 4.482 | 5.669 | 6.848 | 58%, 73% |
+| | 256 x 256 | 5.950 | 7.300 | 8.469 | 44%, 58% |
+| [32, 8192, 128] on [4, 8192, 128], 1,024 | the tiled calls | 2.766 | 3.713 | 4.439 | 24%, 28% |
+| | **2048 x 128**, the forward rule's | **1.354** | 1.416 | 1.790 | 48%, 71% |
+| | **1024 x 128**, the backward rule's | 1.351 | **1.506** | **1.875** | 48%, 68% |
+| | 1024 x 256 | 1.274 | 1.528 | 1.960 | 51%, 66% |
+| | 2048 x 256 | 1.220 | 1.470 | 1.927 | 54%, 67% |
+| | 1024 x 512 | 1.455 | 1.772 | 2.334 | 45%, 56% |
+| | 512 x 256 | 1.410 | 1.699 | 2.067 | 46%, 61% |
+| | 256 x 256 | 1.821 | 2.198 | 2.340 | 36%, 50% |
+
+Half the time at every band wider than 256: the rows whole (no rescale, no
+scratch round trip), the masks added and on the edges' pieces only, 0.75 of
+the scores. ``r`` hardly matters between 128 and 256 (a sixteenth of the
+scores against the fixed cost of a sub-block) and 512 loses 10%; a block of
+512 loses 9% to 1,024 (its slab is five blocks for a row's three), and 2,048
+gains 2 to 5% in the backward where it fits. The rules take none of the
+2,048s that Mosaic compiles over the backward's count. The band's results
+lie within 0.002 (forward), 0.001 (dq) and 0.008 (dk / dv, entries of 4 to
+5) of the tiled calls': one or two bf16 roundoffs, and as far as the bands
+lie from each other. Inside the fused step (the Trinity cell's traces, my
+chip runs, PR 53, seed 5300000101) a windowed layer's three calls take
+15.21 ms where 29.01 (the forward 3.85 where 7.31, dk / dv 6.48 where
+12.39, dq 4.88 where 9.31), the twelve 60.84 where 116.04, 77.4% of their
+roofline where 40.6% and where the full layer's, unmoved, read 74.7%.
+
 All three gradients agree with an f32 einsum attention on the same bf16
 inputs within 1.0-1.3 roundoffs of their largest entry at every tile
 tried, as the scan's did (0.8-1.2). Skipping the position mask under the
@@ -245,7 +329,7 @@ ROADMAP.md D4 holds the comparison at seq 512.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -327,9 +411,7 @@ def forward_tiles(seq: int, head_dim: int, itemsize: int, causal: bool,
     alone: ``_widest_tiles`` under ``forward_vmem_bytes``. A causal call
     keeps block_k <= block_q, so that a query block's diagonal tile, the
     one that computes masked scores, is no wider than the block itself. A
-    windowed call runs at the causal call's tiles, whatever its window: a
-    narrower block puts more of what it computes inside the band and still
-    loses to the grid steps it adds (the module docstring's table)."""
+    windowed call does not run on these tiles: ``forward_band``."""
     return _widest_tiles(forward_vmem_bytes, "forward", seq, head_dim,
                          itemsize, causal, v_head_dim)
 
@@ -340,31 +422,12 @@ def _last_live(qi, block_q: int, block_k: int):
     return ((qi + 1) * block_q - 1) // block_k
 
 
-def _first_key(qi, block_q: int, block_k: int, window: int):
-    """The first key block a windowed query block ``qi`` can see: the one
-    that holds the key ``window - 1`` positions before the block's first
-    row. Beside ``_last_live`` the other bound of the forward and dq
-    kernels' compute skip and of their K/V index maps' clamp."""
-    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
-
-
-def _last_query(j, block_q: int, block_k: int, window: int, num_q: int):
-    """The last query block that sees windowed key block ``j``: the one
-    that holds the row ``window - 1`` positions after the block's last
-    key. Beside ``_first_live`` the other bound of the dk / dv kernel's
-    skip and clamp."""
-    return jnp.minimum(((j + 1) * block_k + window - 2) // block_q,
-                       num_q - 1)
-
-
-def _visible(qi, j, shape, q_axis: int, window: Optional[int] = None,
-             edge: Optional[Edge] = None):
+def _visible(qi, j, shape, q_axis: int, edge: Optional[Edge] = None):
     """Causal visibility of a score tile of query block ``qi`` and key
-    block ``j``: the query position reaches the key position and, under a
-    window, lies fewer than ``window`` past it; under an ``edge`` a block
-    wide, the key lies before the end of the query's block, or (strict)
-    before its start. The queries run along ``q_axis`` of ``shape``, the
-    keys along the other."""
+    block ``j``: the query position reaches the key position; under an
+    ``edge`` a block wide, the key lies before the end of the query's
+    block, or (strict) before its start. The queries run along ``q_axis``
+    of ``shape``, the keys along the other."""
     qpos = qi * shape[q_axis] + jax.lax.broadcasted_iota(
         jnp.int32, shape, q_axis)
     kpos = j * shape[1 - q_axis] + jax.lax.broadcasted_iota(
@@ -374,14 +437,11 @@ def _visible(qi, j, shape, q_axis: int, window: Optional[int] = None,
         # the block is a power of two: ``& -block`` is its first position
         first = jnp.bitwise_and(qpos, -block)
         return kpos < (first if strict else first + block)
-    if window is None:
-        return qpos >= kpos
-    return jnp.logical_and(qpos >= kpos, qpos - kpos < window)
+    return qpos >= kpos
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
-                scale: float, causal: bool, window: Optional[int],
-                edge: Optional[Edge] = None):
+                scale: float, causal: bool, edge: Optional[Edge] = None):
     """One (batch·head, q-block, kv-block) grid step. The kv dimension is
     the INNERMOST grid axis, so the (m, l, acc) VMEM scratch persists
     across a q-block's kv steps while Mosaic pipelines the next kv
@@ -401,8 +461,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k] f32
         if causal:
-            s = jnp.where(_visible(qi, j, s.shape, 0, window, edge), s,
-                          _NEG_INF)
+            s = jnp.where(_visible(qi, j, s.shape, 0, edge), s, _NEG_INF)
         # padding mask: this block's key validity as a [1, block_k] row,
         # broadcast over the query rows
         s = jnp.where(mask_ref[:] > 0, s, _NEG_INF)
@@ -446,10 +505,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *scratch,
     # nothing. Their compute is skipped here and their DMA in _flash_fwd,
     # whose index maps stop at the same _last_live block.
     live = (j <= _last_live(qi, block_q, block_k)) if causal else True
-    if window is not None:
-        # and key blocks wholly before the window, by the other bound
-        live = jnp.logical_and(
-            live, j >= _first_key(qi, block_q, block_k, window))
 
     @pl.when(live)
     def _step():
@@ -468,7 +523,7 @@ def _vmem(shape, index_map):
 
 
 def _query_major_specs(bh: int, b: int, group: int, d: int, *, block_q: int,
-                       block_k: int, causal: bool, window: Optional[int]):
+                       block_k: int, causal: bool):
     """Block specs of the grid (B*h, S/block_q, S/block_k), keys innermost,
     that the forward and the dq call run on: a query-side [block_q, d]
     block, a [1, block_q] row of a [BH, 1, S] array (block (1, block_q)
@@ -488,12 +543,7 @@ def _query_major_specs(bh: int, b: int, group: int, d: int, *, block_q: int,
             return j
         # a step past the diagonal names the block the step before it held,
         # and the pipeline copies nothing for an index that stays
-        last = jnp.minimum(j, _last_live(i, block_q, block_k))
-        if window is None:
-            return last
-        # and a step before the window names the first block inside it,
-        # which the step that reaches it names again: one copy
-        return jnp.maximum(last, _first_key(i, block_q, block_k, window))
+        return jnp.minimum(j, _last_live(i, block_q, block_k))
 
     return (
         _vmem((None, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
@@ -517,7 +567,7 @@ def _qkv_specs(q, k, v, mask, **tiles):
     return q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec
 
 
-def _flash_fwd(q, k, v, mask, *, scale, causal, window, block_q, block_k,
+def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k,
                interpret, edge=None):
     """q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v];
     mask: [B, S] routed per program. Returns out [BH, S, d_v] and the
@@ -526,11 +576,10 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, window, block_q, block_k,
     d_v = v.shape[-1]
     num_k = seq // block_k
     q_spec, row_spec, k_spec, mask_spec, o_spec, v_spec = _qkv_specs(
-        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal,
-        window=window)
+        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          window=window, edge=edge),
+                          edge=edge),
         grid=(bh, seq // block_q, num_k),
         in_specs=[q_spec, k_spec, v_spec, mask_spec],
         out_specs=[o_spec, row_spec],
@@ -584,6 +633,111 @@ def backward_tiles(seq: int, head_dim: int, itemsize: int, causal: bool,
                          itemsize, causal, v_head_dim)
 
 
+def _band_blocks(block: int, window: int) -> int:
+    """Blocks of the slab one band step holds: those the window, rounded up
+    to the lanes, reaches into, and the step's own."""
+    return -(-_lanes(window) // block) + 1
+
+
+def _edge_bias_bytes(r: int, window: int) -> int:
+    """The two edges' f32 bias tiles: ``r`` columns each, and the lanes the
+    window was rounded up by beside the lower one."""
+    return r * (2 * r + (128 if window % 128 else 0)) * 4
+
+
+def forward_band_vmem_bytes(block: int, r: int, window: int, head_dim: int,
+                            itemsize: int,
+                            v_head_dim: Optional[int] = None) -> int:
+    """VMEM one grid step of the windowed forward keeps live
+    (``_band_fwd_kernel``): the q, out and logsumexp blocks and the slab's K,
+    V and mask blocks, double-buffered by the pipeline; a sub-block's f32
+    scores against its whole slice, the f32 probabilities of one piece, the
+    output accumulator and the two edges' bias tiles; and the probabilities
+    rounded to the operands' type of every sub-block of the step, which
+    Mosaic does not overlay. Within 5% of what Mosaic allocates at the nine
+    bands read (window 2,048, head 128, bf16: (1024, 256) 11.59 MiB,
+    (1024, 512) 14.95, (2048, 128) 16.79 and (2048, 256) 19.91, both
+    refused; window 1,024: (1024, 256) 6.18, (2048, 256) 13.09)."""
+    qk, vo = _lanes(head_dim), _lanes(v_head_dim or head_dim)
+    slice_ = _lanes(window) + r
+    a_block = 2 * block * (qk + vo) * itemsize + 2 * 8 * block * 4
+    tiles = (r * slice_ * 4 + r * min(block, slice_) * 4
+             + block * slice_ * itemsize + r * vo * 4)
+    return ((1 + _band_blocks(block, window)) * a_block + tiles
+            + _edge_bias_bytes(r, window))
+
+
+def backward_band_vmem_bytes(block: int, r: int, window: int, head_dim: int,
+                             itemsize: int,
+                             v_head_dim: Optional[int] = None) -> int:
+    """VMEM one grid step of the windowed backward keeps live, the larger
+    of its two calls: every block in or out double-buffered, the f32 scratch
+    and columns, the f32 s / p / dP / dS tiles of the widest piece (a block's
+    width at most: nothing of a row outlives its piece) and the bias tiles.
+    An over-count: at window 2,048, head 128, bf16, (1024, 256) counts
+    12.1 MiB where Mosaic allocates 8.28 for dk / dv and 6.61 for dq."""
+    qk, vo = _lanes(head_dim), _lanes(v_head_dim or head_dim)
+    blocks = _band_blocks(block, window)
+    a_block = 2 * block * itemsize            # a lane of a [block, .] block
+    a_row = 2 * 8 * block * 4                 # a [1, block] f32 or int32 row
+    # dk / dv: the slab's q, dO and two rows; k, v in, dk, dv out; the
+    # [block, 1] mask column on 128 lanes; the dk and dv accumulators
+    dkv = (blocks * (a_block * (qk + vo) + 2 * a_row)
+           + 2 * a_block * (qk + vo) + 2 * block * 128 * 4
+           + block * (qk + vo) * 4)
+    # dq: q, dO in, dq out and the two rows; the slab's k, v and mask; the
+    # two columns made of the rows
+    dq = (a_block * (2 * qk + vo) + 2 * a_row
+          + blocks * (a_block * (qk + vo) + a_row) + 2 * block * 128 * 4)
+    tiles = (4 * r * min(block, _lanes(window) + r) * 4
+             + r * (qk + vo) * 4)
+    return max(dkv, dq) + tiles + _edge_bias_bytes(r, window)
+
+
+def _band_tiles(vmem_bytes, what: str, seq: int, head_dim: int,
+                itemsize: int, window: int,
+                v_head_dim: Optional[int]) -> tuple[int, int]:
+    """(block, r) of a band step. The sub-block first: the tallest of 512,
+    256 and 128 rows that is an eighth of the window at most, 128 under a
+    window of 2,048: a sub-block's slice is ``r`` keys longer than its rows'
+    window, so at most a ninth of the scores it computes lie outside the
+    band. Then the widest block, among the divisors of ``seq`` that are
+    multiples of ``r``, whose step keeps ``vmem_bytes`` within
+    ``_VMEM_BUDGET``: a wider block reads less of K and V twice and adds no
+    scores."""
+    if seq % 128:
+        raise ValueError(
+            f"seq len {seq} must be divisible by 128 (pad the sequence)")
+    for r in (512, 256, 128):
+        if r > 128 and 8 * r > window:
+            continue
+        for block in range(seq, 0, -r):
+            if seq % block == 0 and vmem_bytes(
+                    block, r, window, head_dim, itemsize,
+                    v_head_dim) <= _VMEM_BUDGET:
+                return block, r
+    raise ValueError(
+        f"no {what} band step fits {_VMEM_BUDGET} B of VMEM at window "
+        f"{window}, head_dim {head_dim} (v {v_head_dim or head_dim}), "
+        f"itemsize {itemsize}")
+
+
+def forward_band(seq: int, head_dim: int, itemsize: int, window: int,
+                 v_head_dim: Optional[int] = None) -> tuple[int, int]:
+    """(block, r) of the windowed forward, from the operands' shapes and
+    the window alone: ``_band_tiles`` under ``forward_band_vmem_bytes``."""
+    return _band_tiles(forward_band_vmem_bytes, "forward", seq, head_dim,
+                       itemsize, window, v_head_dim)
+
+
+def backward_band(seq: int, head_dim: int, itemsize: int, window: int,
+                  v_head_dim: Optional[int] = None) -> tuple[int, int]:
+    """(block, r) of the two windowed backward calls, by the forward's
+    rule under ``backward_band_vmem_bytes``."""
+    return _band_tiles(backward_band_vmem_bytes, "backward", seq, head_dim,
+                       itemsize, window, v_head_dim)
+
+
 def _first_live(j, block_q: int, block_k: int):
     """The first query block that sees causal key block ``j``: the one
     that holds the row of the block's first key. The mirror of
@@ -601,14 +755,12 @@ def _probabilities(s, lse, keep):
 
 
 def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
-                  block_k: int, window: Optional[int] = None,
-                  edge: Optional[Edge] = None):
+                  block_k: int, edge: Optional[Edge] = None):
     """Calls ``step(when, diagonal)`` for the tile of query block ``qi``
     and key block ``j``: once where nothing is causal; else once for the
-    live tiles the diagonal or the window's lower edge crosses, which need
-    the position mask, and once for those wholly under the one and inside
-    the other, which do not (8% of the dk / dv call at LFM2's shape, 1% of
-    the dq call). Dead tiles run neither."""
+    live tiles the diagonal crosses, which need the position mask, and once
+    for those wholly under it, which do not (8% of the dk / dv call at
+    LFM2's shape, 1% of the dq call). Dead tiles run neither."""
     if not causal:
         step(True, False)
         return
@@ -617,17 +769,13 @@ def _causal_steps(step, causal: bool, live, qi, j, block_q: int,
         # a strict edge hides the first row's own block: the tile's last
         # key lies before that row
         under = (j + 1) * block_k <= qi * block_q
-    if window is not None:
-        # the tile's last row still sees its first key
-        under = jnp.logical_and(
-            under, (qi + 1) * block_q - 1 - j * block_k < window)
     step(jnp.logical_and(live, jnp.logical_not(under)), True)
     step(under, False)
 
 
 def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
-                scale: float, causal: bool, window: Optional[int],
-                num_q: int, fused: bool, edge: Optional[Edge] = None):
+                scale: float, causal: bool, num_q: int, fused: bool,
+                edge: Optional[Edge] = None):
     """One (batch x K/V head, key block, query head of the group x query
     block) grid step of dk and dv. The tile is the transposed one, keys
     down the sublanes and queries along the lanes, so that the logsumexp
@@ -653,8 +801,7 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
         ) * scale  # [block_k, block_q] f32
         keep = mask_ref[:] > 0  # [block_k, 1]: this block's key validity
         if diagonal:
-            keep = jnp.logical_and(
-                keep, _visible(qi, j, st.shape, 1, window, edge))
+            keep = jnp.logical_and(keep, _visible(qi, j, st.shape, 1, edge))
         pt = _probabilities(st, lse_ref[:], keep)
         dpt = jax.lax.dot_general(
             v_ref[:], do_ref[:], (((1,), (1,)), ((), ())),
@@ -708,11 +855,7 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
     # Their compute is skipped here and their DMA in _flash_dkv, whose
     # index maps start at the same _first_live block.
     live = qi >= _first_live(j, block_q, block_k)
-    if window is not None:
-        # and query blocks wholly past the window, by the other bound
-        live = jnp.logical_and(
-            live, qi <= _last_query(j, block_q, block_k, window, num_q))
-    _causal_steps(step, causal, live, qi, j, block_q, block_k, window, edge)
+    _causal_steps(step, causal, live, qi, j, block_q, block_k, edge)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finalize():
@@ -721,8 +864,7 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, k_ref, v_ref, mask_ref, *rest,
 
 def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
                dq_ref, dq_scr, lse_scr, delta_scr, *, scale: float,
-               causal: bool, window: Optional[int],
-               edge: Optional[Edge] = None):
+               causal: bool, edge: Optional[Edge] = None):
     """One (batch x head, query block, key block) grid step of dq, the
     forward's grid and index maps. The tile has the queries down the
     sublanes, so the logsumexp and delta rows are turned into columns,
@@ -747,8 +889,7 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
         ) * scale  # [block_q, block_k] f32
         keep = mask_ref[:] > 0  # [1, block_k]
         if diagonal:
-            keep = jnp.logical_and(
-                keep, _visible(qi, j, s.shape, 0, window, edge))
+            keep = jnp.logical_and(keep, _visible(qi, j, s.shape, 0, edge))
         p = _probabilities(s, lse_scr[:, :1], keep)
         dp = jax.lax.dot_general(
             do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
@@ -766,18 +907,15 @@ def _dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, mask_ref,
             dq_scr[:] += tile(diagonal)
 
     live = j <= _last_live(qi, block_q, block_k)
-    if window is not None:
-        live = jnp.logical_and(
-            live, j >= _first_key(qi, block_q, block_k, window))
-    _causal_steps(step, causal, live, qi, j, block_q, block_k, window, edge)
+    _causal_steps(step, causal, live, qi, j, block_q, block_k, edge)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[:] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
-               block_q, block_k, interpret, edge=None):
+def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
+               block_k, interpret, edge=None):
     """dk, dv: grid (B * h_kv, S / block_k, group * S / block_q). The
     innermost axis walks the query heads a K/V head serves and, within
     each, the query blocks from the first live one. With ``delta`` None
@@ -800,12 +938,7 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
             return i
         # a step before the diagonal names the first live block, which the
         # step that reaches it names again: one copy
-        first = jnp.maximum(i, _first_live(j, block_q, block_k))
-        if window is None:
-            return first
-        # and a step past the window names the last block inside it
-        return jnp.minimum(first,
-                           _last_query(j, block_q, block_k, window, num_q))
+        return jnp.maximum(i, _first_live(j, block_q, block_k))
 
     def q_side(width):
         return _vmem((None, block_q, width),
@@ -821,8 +954,7 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
                      lambda g, j, t: (q_head(g, t), 0, q_block(j, t)))
     dk, dv, *dq = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          window=window, num_q=num_q, fused=fused,
-                          edge=edge),
+                          num_q=num_q, fused=fused, edge=edge),
         grid=(bh_kv, seq // block_k, steps),
         in_specs=[
             q_spec, do_spec, row_spec, k_spec, v_spec,
@@ -845,17 +977,16 @@ def _flash_dkv(q, do, lse, delta, k, v, mask, *, scale, causal, window,
     return dk, dv, dq[0] if fused else None
 
 
-def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, window,
-              block_q, block_k, interpret, edge=None):
-    """dq: the forward's grid, K/V head ``bh // group`` and the clamps at
-    ``_last_live`` and, under a window, ``_first_key``."""
+def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, block_q,
+              block_k, interpret, edge=None):
+    """dq: the forward's grid, K/V head ``bh // group`` and the clamp at
+    ``_last_live``."""
     bh, seq, d = q.shape
     q_spec, row_spec, k_spec, mask_spec, do_spec, v_spec = _qkv_specs(
-        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal,
-        window=window)
+        q, k, v, mask, block_q=block_q, block_k=block_k, causal=causal)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          window=window, edge=edge),
+                          edge=edge),
         grid=(bh, seq // block_q, seq // block_k),
         in_specs=[q_spec, do_spec, row_spec, row_spec, k_spec, v_spec,
                   mask_spec],
@@ -872,21 +1003,368 @@ def _flash_dq(q, do, lse, delta, k, v, mask, *, scale, causal, window,
     )(q, do, lse, delta, k, v, mask[:, None, :])
 
 
+# -- the band step: a windowed call --------------------------------------------
+
+class Band(NamedTuple):
+    """What one grid step of a windowed call meets, from ``block``, ``r`` and
+    the window alone (``band``): the step holds one block of its own side and
+    ``blocks`` blocks of the other side end to end, the slab; sub-block ``t``
+    of its own ``r`` rows meets the ``lanes(window) + r`` slab columns from
+    ``first[t]`` on, its slice. Row ``a`` of a sub-block sees the slice's
+    columns ``c`` with ``low <= c - a <= high``, whatever the sub-block and
+    the step. ``pieces[t]`` cuts the slice where the slab changes block and
+    where the two edges' chunks end: ``(block of the slab, its first row, its
+    last row + 1, the slice column of the first, whether an edge crosses the
+    piece)``. A piece no edge crosses holds only pairs the window keeps."""
+    blocks: int
+    low: int
+    high: int
+    first: tuple
+    pieces: tuple
+
+
+def band(block: int, r: int, window: int, mirror: bool = False) -> Band:
+    """The geometry of the band step. Query side (the forward and dq): the
+    slab is the key blocks ``i - blocks + 1 .. i`` of query block ``i``, so
+    slab column ``p`` is key ``(i - blocks + 1) * block + p``, and the slice
+    of sub-block ``t`` ends with that sub-block's own keys. ``mirror`` (dk /
+    dv): the slab is the query blocks ``j .. j + blocks - 1`` of key block
+    ``j``, and the slice starts with the sub-block's own queries. The window
+    is rounded up to the 128 lanes for the slice, and the lanes it gains are
+    masked with the lower edge."""
+    wide = _lanes(window)
+    blocks = _band_blocks(block, window)
+    low, high = (0, window - 1) if mirror else (wide - window + 1, wide)
+    # the chunks the edges cross: columns under low + r - 1 (some row's
+    # lower bound lies past them) and columns past high
+    cuts = {_lanes(low + r - 1), high + 1 - (high + 1) % 128}
+    firsts, pieces = [], []
+    for t in range(block // r):
+        first = t * r if mirror else (blocks - 1) * block + t * r - wide
+        at = sorted({0, wide + r}
+                    | {c for c in cuts if 0 < c < wide + r}
+                    | {c for c in range(block - first % block, wide + r,
+                                        block)})
+        firsts.append(first)
+        pieces.append(tuple(
+            ((first + c0) // block, (first + c0) % block,
+             (first + c0) % block + c1 - c0, c0,
+             c0 < low + r - 1 or c1 - 1 > high)
+            for c0, c1 in zip(at, at[1:])))
+    return Band(blocks, low, high, tuple(firsts), tuple(pieces))
+
+
+def _edge_biases(geometry: Band, r: int):
+    """``bias(c0, c1)``: the [r, c1 - c0] f32 tile that is 0 on the pairs of
+    the slice's columns ``c0 .. c1 - 1`` the window keeps and ``_NEG_INF`` on
+    the others, made once a kernel body: the band's two edges are the same
+    two triangles in every sub-block of every step."""
+    made = {}
+
+    def bias(c0, c1):
+        if (c0, c1) not in made:
+            shape = (r, c1 - c0)
+            ahead = (c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                     - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+            made[c0, c1] = jnp.where(
+                jnp.logical_and(ahead >= geometry.low,
+                                ahead <= geometry.high), 0.0, _NEG_INF)
+        return made[c0, c1]
+
+    return bias
+
+
+def _nt(a, b):
+    """a b^T, f32 out: both contract their minor dimension."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _key_bias(mask_refs, qi, blocks: int):
+    """``padding(m, lo, hi)``: the padding mask of keys ``lo .. hi - 1`` of
+    the slab's block ``m`` as a [1, hi - lo] f32 row to add to the scores, 0
+    or ``_NEG_INF``; a block before the sequence's start (its index map
+    names block 0) is padding throughout. Read from the ref a piece at a
+    time: Mosaic does not broadcast a lane slice of a row held as a value."""
+    def padding(m, lo, hi):
+        return jnp.where(
+            jnp.logical_and(mask_refs[m][:, lo:hi] > 0,
+                            qi + m >= blocks - 1), 0.0, _NEG_INF)
+
+    return padding
+
+
+def _column(row):
+    """A [1, n] row as an [n, 1] column."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
+
+
+def _band_fwd_kernel(q_ref, *refs, scale: float, geometry: Band, r: int):
+    """One (batch x head, query block) grid step of a windowed forward:
+    the query block against the slab of key blocks it can see, ``r`` rows at
+    a time against their own slice. Every key a row sees is in its slice, so
+    the rows are softmaxed whole: no running maximum, no rescaling and no
+    scratch. All masks are added: the padding mask as a row, the two edges
+    as tiles on the pieces they cross and nowhere else. A row that sees no
+    key (every score under ``_NEG_INF / 2``) gives zeros and a logsumexp of
+    ``_NEG_INF``, as the tiled kernel's gate has it."""
+    n = geometry.blocks
+    k_refs, v_refs, mask_refs = refs[:n], refs[n:2 * n], refs[2 * n:3 * n]
+    o_ref, lse_ref = refs[3 * n:]
+    padding = _key_bias(mask_refs, pl.program_id(1), n)
+    edge = _edge_biases(geometry, r)
+    for t, pieces in enumerate(geometry.pieces):
+        rows = slice(t * r, (t + 1) * r)
+        q = q_ref[rows, :]
+        scores = []
+        for m, lo, hi, c0, crossed in pieces:
+            s = _nt(q, k_refs[m][lo:hi, :]) * scale + padding(m, lo, hi)
+            if crossed:
+                s = s + edge(c0, c0 + hi - lo)
+            scores.append(s)
+        top = functools.reduce(jnp.maximum, [
+            jnp.max(s, axis=-1, keepdims=True) for s in scores])
+        total, acc = 0.0, 0.0
+        for s, (m, lo, hi, _, _) in zip(scores, pieces):
+            p = jnp.exp(s - top)
+            total += jnp.sum(p, axis=-1, keepdims=True)
+            acc += _nn(p.astype(v_refs[m].dtype), v_refs[m][lo:hi, :])
+        seen = top > _NEG_INF / 2
+        o_ref[rows, :] = jnp.where(seen, acc / total, 0.0).astype(o_ref.dtype)
+        lse = jnp.where(seen, top + jnp.log(total), _NEG_INF)
+        lse_ref[:, rows] = jnp.broadcast_to(lse, (r, 128)).T[:1]
+
+
+def _exponent_rows(lse, live=True):
+    """The logsumexp as the backward subtracts it: a row that saw no key
+    (``_NEG_INF``), or one of a block that is not ``live``, reads
+    ``-_NEG_INF``, so that its probabilities are 0 whatever its scores."""
+    return jnp.where(jnp.logical_and(lse > _NEG_INF / 2, live), lse,
+                     -_NEG_INF)
+
+
+def _band_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, *refs, scale: float,
+                    geometry: Band, r: int):
+    """One (batch x head, query block) grid step of a windowed dq: the
+    forward's slab and slices, exact probabilities from the saved logsumexp,
+    dq of ``r`` rows summed over their slice's pieces in registers."""
+    n = geometry.blocks
+    k_refs, v_refs, mask_refs = refs[:n], refs[n:2 * n], refs[2 * n:3 * n]
+    dq_ref = refs[3 * n]
+    padding = _key_bias(mask_refs, pl.program_id(1), n)
+    edge = _edge_biases(geometry, r)
+    lse, delta = _column(_exponent_rows(lse_ref[:])), _column(delta_ref[:])
+    for t, pieces in enumerate(geometry.pieces):
+        rows = slice(t * r, (t + 1) * r)
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        dq = 0.0
+        for m, lo, hi, c0, crossed in pieces:
+            k = k_refs[m][lo:hi, :]
+            s = _nt(q, k) * scale + padding(m, lo, hi)
+            if crossed:
+                s = s + edge(c0, c0 + hi - lo)
+            p = jnp.exp(s - lse[rows])
+            ds = p * (_nt(do, v_refs[m][lo:hi, :]) - delta[rows])
+            dq += _nn(ds.astype(k.dtype), k)
+        dq_ref[rows, :] = (dq * scale).astype(dq_ref.dtype)
+
+
+def _band_dkv_kernel(*refs, scale: float, geometry: Band, r: int,
+                     num_q: int):
+    """One (batch x K/V head, key block, query head of the group) grid step
+    of a windowed dk and dv, the mirror: the key block against the slab of
+    query blocks that can see it (q, dO, and the logsumexp and delta rows),
+    ``r`` keys at a time against their own slice, the transposed tile (keys
+    down the sublanes). The only carry is over the group's query heads, in
+    the f32 scratch. A query block past the sequence's end (its index map
+    names the last) has no probability: its logsumexp reads ``-_NEG_INF``. A
+    padded key's column of probabilities is its own rows of dk and dv and
+    nothing else, so the padding mask is not in the tile: its rows are
+    written as zeros."""
+    n = geometry.blocks
+    q_refs, do_refs, lse_refs, delta_refs = (
+        refs[m * n:(m + 1) * n] for m in range(4))
+    k_ref, v_ref, mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs[4 * n:]
+    j, head = pl.program_id(1), pl.program_id(2)
+    edge = _edge_biases(geometry, r)
+
+    @pl.when(head == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    for t, pieces in enumerate(geometry.pieces):
+        rows = slice(t * r, (t + 1) * r)
+        k, v = k_ref[rows, :], v_ref[rows, :]
+        dk, dv = 0.0, 0.0
+        for m, lo, hi, c0, crossed in pieces:
+            q, do = q_refs[m][lo:hi, :], do_refs[m][lo:hi, :]
+            st = _nt(k, q) * scale
+            if crossed:
+                st = st + edge(c0, c0 + hi - lo)
+            # a query block past the sequence's end has no probability
+            pt = jnp.exp(st - _exponent_rows(lse_refs[m][:, lo:hi],
+                                             j + m < num_q))
+            dst = pt * (_nt(v, do) - delta_refs[m][:, lo:hi])
+            dv += _nn(pt.astype(do.dtype), do)
+            dk += _nn(dst.astype(q.dtype), q)
+        dk_scr[rows, :] += dk
+        dv_scr[rows, :] += dv
+
+    @pl.when(head == pl.num_programs(2) - 1)
+    def _finalize():
+        valid = mask_ref[:] > 0  # [block, 1]
+        dk_ref[:] = jnp.where(valid, dk_scr[:] * scale,
+                              0.0).astype(dk_ref.dtype)
+        dv_ref[:] = jnp.where(valid, dv_scr[:], 0.0).astype(dv_ref.dtype)
+
+
+def _band_query_specs(bh: int, b: int, group: int, block: int, blocks: int):
+    """Block specs of the grid (B*h, S/block) of a windowed forward and dq:
+    ``q_side(width)`` a [block, width] block of the step's own queries,
+    ``row`` their [1, block] row of a [BH, 1, S] array, ``k_side(width)``
+    the slab's ``blocks`` [block, width] key blocks at head ``bh // group``,
+    the last the queries' own and those before the sequence's start named
+    block 0, and ``mask`` the same blocks' [1, block] rows of the [B, 1, S]
+    mask."""
+    heads = bh // b
+
+    def slab(m):
+        return lambda i: jnp.maximum(i + m - (blocks - 1), 0)
+
+    def q_side(width):
+        return _vmem((None, block, width), lambda g, i: (g, i, 0))
+
+    def k_side(width):
+        return [_vmem((None, block, width),
+                      lambda g, i, at=slab(m): (g // group, at(i), 0))
+                for m in range(blocks)]
+
+    row = _vmem((None, 1, block), lambda g, i: (g, 0, i))
+    mask = [_vmem((None, 1, block),
+                  lambda g, i, at=slab(m): (g // heads, 0, at(i)))
+            for m in range(blocks)]
+    return q_side, row, k_side, mask
+
+
+_BAND_GRID = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
+
+
+def _band_fwd(q, k, v, mask, *, scale, window, block, r, interpret):
+    """The windowed forward: out [BH, S, d_v] and the logsumexp [BH, 1, S]
+    on the grid (B*h, S/block), K, V and the mask passed once a block of
+    the slab."""
+    bh, seq, d = q.shape
+    d_v = v.shape[-1]
+    geometry = band(block, r, window)
+    n = geometry.blocks
+    q_side, row, k_side, mask_specs = _band_query_specs(
+        bh, mask.shape[0], bh // k.shape[0], block, n)
+    return pl.pallas_call(
+        functools.partial(_band_fwd_kernel, scale=scale, geometry=geometry,
+                          r=r),
+        grid=(bh, seq // block),
+        in_specs=[q_side(d)] + k_side(d) + k_side(d_v) + mask_specs,
+        out_specs=[q_side(d_v), row],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
+        ],
+        compiler_params=_BAND_GRID,
+        interpret=interpret,
+    )(q, *[k] * n, *[v] * n, *[mask.astype(jnp.int32)[:, None, :]] * n)
+
+
+def _band_dq(q, do, lse, delta, k, v, mask, *, scale, window, block, r,
+             interpret):
+    bh, seq, d = q.shape
+    d_v = v.shape[-1]
+    geometry = band(block, r, window)
+    n = geometry.blocks
+    q_side, row, k_side, mask_specs = _band_query_specs(
+        bh, mask.shape[0], bh // k.shape[0], block, n)
+    return pl.pallas_call(
+        functools.partial(_band_dq_kernel, scale=scale, geometry=geometry,
+                          r=r),
+        grid=(bh, seq // block),
+        in_specs=[q_side(d), q_side(d_v), row, row] + k_side(d)
+        + k_side(d_v) + mask_specs,
+        out_specs=q_side(d),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_BAND_GRID,
+        interpret=interpret,
+    )(q, do, lse, delta, *[k] * n, *[v] * n, *[mask[:, None, :]] * n)
+
+
+def _band_dkv(q, do, lse, delta, k, v, mask, *, scale, window, block, r,
+              interpret):
+    """The windowed dk, dv: grid (B * h_kv, S / block, group), the last
+    axis the query heads a K/V head serves; q, dO and the two rows passed
+    once a block of the slab, the blocks past the sequence's end named the
+    last."""
+    bh, seq, d = q.shape
+    d_v = v.shape[-1]
+    bh_kv = k.shape[0]
+    group = bh // bh_kv
+    kv_heads = bh_kv // mask.shape[0]
+    num_q = seq // block
+    geometry = band(block, r, window, mirror=True)
+    n = geometry.blocks
+
+    def slab(m):
+        return lambda j: jnp.minimum(j + m, num_q - 1)
+
+    def q_side(width):
+        return [_vmem((None, block, width),
+                      lambda g, j, t, at=slab(m): (g * group + t, at(j), 0))
+                for m in range(n)]
+
+    def k_side(width):
+        return _vmem((None, block, width), lambda g, j, t: (g, j, 0))
+
+    rows = [_vmem((None, 1, block),
+                  lambda g, j, t, at=slab(m): (g * group + t, 0, at(j)))
+            for m in range(n)]
+    return pl.pallas_call(
+        functools.partial(_band_dkv_kernel, scale=scale, geometry=geometry,
+                          r=r, num_q=num_q),
+        grid=(bh_kv, num_q, group),
+        in_specs=q_side(d) + q_side(d_v) + rows + rows + [
+            k_side(d), k_side(d_v),
+            _vmem((None, block, 1), lambda g, j, t: (g // kv_heads, j, 0))],
+        out_specs=[k_side(d), k_side(d_v)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                        pltpu.VMEM((block, d_v), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*[q] * n, *[do] * n, *[lse] * n, *[delta] * n, k, v, mask[:, :, None])
+
+
 def _flash_bwd(q, k, v, mask, out, lse, do, dlse=None, *, scale, causal,
                window, interpret, edge=None):
-    """dq, dk, dv of ``_flash_fwd`` by two Mosaic calls, or by one where
-    one tile spans the sequence and each K/V head serves one query head.
+    """dq, dk, dv of ``_forward`` by two Mosaic calls (under a window the
+    band step's), or by one where one tile spans the sequence and each K/V
+    head serves one query head.
     q: [BH, S, d]; k: [BH / group, S, d]; v: [BH / group, S, d_v]; out,
     do: [BH, S, d_v]; mask: [B, S]; lse: [BH, 1, S]; ``dlse``: the
     logsumexp's own cotangent where it was an output, [BH, 1, S]. Exact
     probabilities are recomputed per tile from the logsumexp; no [S, S]
     tensor reaches HBM."""
     seq, d = q.shape[1:]
-    block_q, block_k = backward_tiles(seq, d, q.dtype.itemsize, causal,
-                                      v.shape[-1])
+    shapes = (seq, d, q.dtype.itemsize)
+    tiles = (backward_tiles(*shapes, causal, v.shape[-1]) if window is None
+             else backward_band(*shapes, window, v.shape[-1]))
     delta = None
-    if dlse is not None or not (q.shape == k.shape
-                                and block_q == block_k == seq):
+    if window is not None or dlse is not None or not (
+            q.shape == k.shape and tiles == (seq, seq)):
         # D_i = sum_d dO_i * O_i, the softmax jacobian's row term, as a row
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)[:, None, :]
@@ -894,13 +1372,29 @@ def _flash_bwd(q, k, v, mask, out, lse, do, dlse=None, *, scale, causal,
         # d lse_i / d s_ij = p_ij: dS = p (dP - delta + dlse)
         delta = delta - dlse
     args = (q, do, lse, delta, k, v, mask.astype(jnp.int32))
-    kwargs = dict(scale=scale, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, interpret=interpret,
-                  edge=edge)
+    if window is not None:
+        kwargs = dict(scale=scale, window=window, block=tiles[0], r=tiles[1],
+                      interpret=interpret)
+        dk, dv = _band_dkv(*args, **kwargs)
+        return _band_dq(*args, **kwargs), dk, dv
+    kwargs = dict(scale=scale, causal=causal, block_q=tiles[0],
+                  block_k=tiles[1], interpret=interpret, edge=edge)
     dk, dv, dq = _flash_dkv(*args, **kwargs)
     if dq is None:
         dq = _flash_dq(*args, **kwargs)
     return dq, dk, dv
+
+
+def _forward(q, k, v, mask, *, scale, causal, window, block_q, block_k,
+             interpret, edge):
+    """The forward call: under a window the band step, ``block_q`` its
+    block and ``block_k`` its sub-block."""
+    if window is not None:
+        return _band_fwd(q, k, v, mask, scale=scale, window=window,
+                         block=block_q, r=block_k, interpret=interpret)
+    return _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
+                      block_q=block_q, block_k=block_k, interpret=interpret,
+                      edge=edge)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 12)))
@@ -908,17 +1402,17 @@ def _flash(q, k, v, mask, scale, causal, window, edge, with_lse, block_q,
            block_k, interpret):
     """The output [BH, S, d_v] and, ``with_lse``, the logsumexp [BH, 1, S]
     beside it as a second output with a cotangent of its own."""
-    out, lse = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
-                          window=window, block_q=block_q, block_k=block_k,
-                          interpret=interpret, edge=edge)
+    out, lse = _forward(q, k, v, mask, scale=scale, causal=causal,
+                        window=window, block_q=block_q, block_k=block_k,
+                        interpret=interpret, edge=edge)
     return (out, lse) if with_lse else out
 
 
 def _flash_vjp_fwd(q, k, v, mask, scale, causal, window, edge, with_lse,
                    block_q, block_k, interpret):
-    out, lse = _flash_fwd(q, k, v, mask, scale=scale, causal=causal,
-                          window=window, block_q=block_q, block_k=block_k,
-                          interpret=interpret, edge=edge)
+    out, lse = _forward(q, k, v, mask, scale=scale, causal=causal,
+                        window=window, block_q=block_q, block_k=block_k,
+                        interpret=interpret, edge=edge)
     # named on the variables the backward reads: a name on the call's
     # result, outside the custom_vjp, leaves these two unnamed
     out, lse = map(checkpoint_name, (out, lse), KEPT)
@@ -954,9 +1448,10 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     (BERT padding convention); ``causal`` composes with it. ``window``
     (causal calls only): query ``i`` sees the keys ``j`` with
     ``0 <= i - j < window``, itself and the ``window - 1`` before it; the
-    kernels skip, and do not fetch, the blocks wholly outside the band, so
-    the call's work follows ``S * window`` and not ``S ** 2 / 2``. One that
-    spans the sequence is the causal call. ``edge_block`` (causal calls
+    call runs the band step (the module docstring's A WINDOW), a block
+    against the keys its window reaches and no other, so its work follows
+    ``S * window`` and not ``S ** 2 / 2``. One that spans the sequence is the
+    causal call. ``edge_block`` (causal calls
     without a window): the edge at the granularity of blocks of that many
     positions, a power of two that divides 128: query ``i`` sees every key
     before the end of its own block, or with ``strict_edge`` before its
@@ -966,7 +1461,9 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     for a row that sees no key), an output with a gradient of its own.
 
     ``block_q`` / ``block_k`` tile the forward kernel; left at None they
-    are ``forward_tiles``' choice from the operands' shapes. ``interpret``
+    are ``forward_tiles``' choice from the operands' shapes. Under a window
+    they are the band step's block and sub-block (``block_k`` a multiple of
+    128 that divides ``block_q``), ``forward_band``'s choice. ``interpret``
     defaults to True off-TPU so tests exercise the same kernel logic on
     CPU. Sequence length must be divisible by 128, the backward's key
     block, and by the forward's blocks (pad to 128 — XLA-side attention
@@ -1001,13 +1498,19 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     elif strict_edge:
         raise ValueError("strict_edge says which blocks an edge_block sees")
     if block_q is None or block_k is None:
-        chosen = forward_tiles(seq, d, q.dtype.itemsize, causal, d_v)
+        shapes = (seq, d, q.dtype.itemsize)
+        chosen = (forward_tiles(*shapes, causal, d_v) if window is None
+                  else forward_band(*shapes, window, d_v))
         block_q, block_k = block_q or chosen[0], block_k or chosen[1]
     if seq % block_q or seq % block_k or seq % 128:
         raise ValueError(
             f"seq len {seq} must be divisible by block_q={block_q}, "
             f"block_k={block_k} and 128 (pad the sequence)"
         )
+    if window is not None and (block_q % block_k or block_k % 128):
+        raise ValueError(
+            f"under a window block_k={block_k} is the sub-block of "
+            f"block_q={block_q}: a multiple of 128 that divides it")
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     if mask is None:

@@ -71,9 +71,16 @@ CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True, 3),
          "trinity-mini.s16384.b1.zipf, full":
              ((1, 16384, 32, 4, 128), True, 3),
          "trinity-mini.s16384.b1.zipf, windowed":
-             ((1, 16384, 32, 4, 128), True, 3)}
-#: the window of a cell's call, where it has one
-WINDOWS = {"trinity-mini.s16384.b1.zipf, windowed": 2048}
+             ((1, 16384, 32, 4, 128), True, 3),
+         # Mellum's three layers of four that see 1,024 keys of 8,192
+         "mellum2-12b-a2.5b.s8192.b1.zipf.x4, windowed":
+             ((1, 8192, 32, 4, 128), True, 3)}
+#: the window of a cell's call, where it has one: the band step
+#: (``ops/flash_attention.py``), a query block against the slab of key
+#: blocks it sees passed as so many operands, at the block and sub-block the
+#: rules choose
+WINDOWS = {"trinity-mini.s16384.b1.zipf, windowed": 2048,
+           "mellum2-12b-a2.5b.s8192.b1.zipf.x4, windowed": 1024}
 
 
 @pytest.mark.parametrize("cell", sorted(CALLS))

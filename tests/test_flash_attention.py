@@ -19,10 +19,11 @@ import pytest
 from jaxpr_tools import primitives
 from ps_tpu.models.blocks import _full_attention
 from ps_tpu.ops import flash_attention
-from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_key,
-                                        _first_live, _last_live, _last_query,
+from ps_tpu.ops.flash_attention import (_VMEM_BUDGET, _first_live,
+                                        _last_live, backward_band,
                                         backward_tiles, backward_vmem_bytes,
-                                        forward_tiles, forward_vmem_bytes)
+                                        band, forward_band, forward_tiles,
+                                        forward_vmem_bytes)
 
 # the module itself: ``ps_tpu.ops.flash_attention`` names the function
 fa = importlib.import_module("ps_tpu.ops.flash_attention")
@@ -106,7 +107,8 @@ def test_gradients_match_reference(causal):
 # (seq, batch, heads, head_dim, causal, block_q, block_k); None = the
 # chooser's tile. Forced tiles with block_q != block_k put the causal
 # diagonal mid-way through a block, in both orders, with key blocks past
-# the diagonal whose fetch is clamped.
+# the diagonal whose fetch is clamped. Under a window the call is the band
+# step, block_q its block and block_k its sub-block ``r``.
 TILE_CASES = [
     pytest.param(256, 2, 4, 64, False, None, None, None, id="chosen-s256"),
     pytest.param(512, 2, 2, 64, False, None, None, None, id="chosen-s512-one-block"),
@@ -118,13 +120,13 @@ TILE_CASES = [
     pytest.param(512, 1, 2, 64, True, 128, 512, None, id="causal-q128-k512"),
     pytest.param(512, 2, 2, 64, False, 128, 256, None, id="padded-q128-k256"),
     pytest.param(256, 2, 4, 64, True, 128, 128, None, id="causal-q128-k128"),
-    # a window: smaller than a block, a block, wider and off the blocks'
-    # edges, the chooser's tiles under it, and wider than the sequence
-    # (the causal call)
+    # a window: smaller than a sub-block, a sub-block, wider and off the
+    # lanes, one sub-block a block and several, the rule's band under it,
+    # and wider than the sequence (the causal call)
     pytest.param(512, 2, 2, 64, True, 128, 128, 64, id="window64-q128-k128"),
     pytest.param(512, 2, 2, 64, True, 128, 128, 128, id="window128-q128-k128"),
     pytest.param(512, 1, 2, 64, True, 256, 128, 200, id="window200-q256-k128"),
-    pytest.param(512, 1, 2, 64, True, 128, 256, 200, id="window200-q128-k256"),
+    pytest.param(512, 1, 2, 64, True, 512, 256, 200, id="window200-q512-k256"),
     pytest.param(512, 1, 2, 64, True, 512, 512, 100, id="window100-one-block"),
     pytest.param(1024, 1, 2, 128, True, None, None, 512, id="window512-chosen-s1024-d128"),
     pytest.param(512, 1, 2, 64, True, 128, 128, 1024, id="window1024-past-the-sequence"),
@@ -246,13 +248,13 @@ BACKWARD_CASES = [
     pytest.param(1024, 1, 2, 2, 128, True, 512, 256, None, id="d128-s1024-q512-k256"),
     pytest.param(512, 2, 2, 2, 64, False, 256, 128, None, id="padded-q256-k128"),
     pytest.param(512, 2, 2, 2, 64, False, 128, 512, None, id="padded-q128-k512"),
-    # a window, the backward's tiles forced: the dk / dv call's second
-    # bound (_last_query) and the dq call's (_first_key), grouped 8 to 1 as
-    # the 32 query heads on 4 K/V heads that bring it
+    # a window, the backward's band forced (block, r): the dq call's slab
+    # of keys and the dk / dv call's of queries, grouped 8 to 1 as the 32
+    # query heads on 4 K/V heads that bring it
     pytest.param(512, 1, 2, 2, 64, True, 128, 128, 64, id="window64-q128-k128"),
     pytest.param(512, 1, 2, 2, 64, True, 128, 128, 128, id="window128-q128-k128"),
     pytest.param(512, 1, 2, 2, 64, True, 256, 128, 200, id="window200-q256-k128"),
-    pytest.param(512, 1, 2, 2, 64, True, 128, 256, 200, id="window200-q128-k256"),
+    pytest.param(512, 1, 2, 2, 64, True, 512, 256, 200, id="window200-q512-k256"),
     pytest.param(512, 1, 8, 1, 64, True, 128, 128, 200, id="window200-grouped-8to1"),
     pytest.param(512, 1, 32, 4, 128, True, None, None, 256, id="window256-32on4-d128-chosen"),
     pytest.param(512, 1, 2, 2, 64, True, 512, 512, 100, id="window100-one-call"),
@@ -265,12 +267,13 @@ BACKWARD_CASES = [
 def test_backward_tiles_match_reference_gradients(monkeypatch, seq, b, h,
                                                   h_kv, d, causal, block_q,
                                                   block_k, window):
-    """Whatever tiles the two backward kernels run at, chosen or forced:
-    dq, dk and dv are the einsum attention's, dk and dv summed over the
-    query heads a K/V head serves."""
+    """Whatever tiles the two backward kernels run at, chosen or forced
+    (under a window the band step's block and sub-block): dq, dk and dv are
+    the einsum attention's, dk and dv summed over the query heads a K/V head
+    serves."""
     if block_q is not None:
-        monkeypatch.setattr(fa, "backward_tiles",
-                            lambda *shape: (block_q, block_k))
+        for rule in ("backward_tiles", "backward_band"):
+            monkeypatch.setattr(fa, rule, lambda *shape: (block_q, block_k))
     q, _, _ = _qkv(21, s=seq, b=b, h=h, d=d)
     _, k, v = _qkv(22, s=seq, b=b, h=h_kv, d=d)
     mask = jnp.asarray(_padding(23, b, seq))
@@ -427,6 +430,8 @@ def test_a_checkpoint_that_keeps_the_named_residuals_drops_the_forward_call(
 
     plain, keeps = grad(), grad(
         policy=jax.checkpoint_policies.save_only_these_names(*fa.KEPT))
+    if window is not None:
+        calls = 3  # the band step's backward is two calls at any length
     for fn, want in ((plain, calls + 1), (keeps, calls)):
         names = primitives(jax.make_jaxpr(fn)(q, k, v).jaxpr)
         assert names.count("pallas_call") == want
@@ -546,58 +551,184 @@ def test_bert_flash_matches_full():
 
 # -- attention that sees a window ---------------------------------------------
 
-@pytest.mark.parametrize("block_q,block_k", [
-    (128, 128), (256, 128), (128, 256), (512, 128), (128, 512), (512, 512),
+def _slices(seq, block, r, window, mirror):
+    """For every step and sub-block of a band call: the positions of its own
+    ``r`` rows, the positions its slice's columns hold, the pairs the kernel
+    keeps (the two edges where a piece is crossed, every pair where it is
+    not; nothing of a block outside the sequence) and those the pieces call
+    uncrossed."""
+    g = band(block, r, window, mirror=mirror)
+    width = -(-window // 128) * 128 + r
+    ahead = np.arange(width)[None, :] - np.arange(r)[:, None]
+    edges = (ahead >= g.low) & (ahead <= g.high)
+    for step in range(seq // block):
+        base = step * block if mirror else (step - g.blocks + 1) * block
+        for t, pieces in enumerate(g.pieces):
+            own = step * block + t * r + np.arange(r)
+            held = base + g.first[t] + np.arange(width)
+            free = np.zeros(width, bool)
+            at = 0
+            for m, lo, hi, c0, crossed in pieces:
+                # the pieces tile the slice, each inside one block of the
+                # slab, on the lanes
+                assert c0 == at and 0 <= lo < hi <= block
+                assert lo % 128 == 0 and hi % 128 == 0
+                assert m * block + lo == g.first[t] + c0
+                assert 0 <= m < g.blocks
+                free[c0:c0 + hi - lo] = not crossed
+                at += hi - lo
+            assert at == width
+            inside = (held >= 0) & (held < seq)
+            kept = np.where(free[None, :], True, edges) & inside[None, :]
+            yield own, held, kept, edges, free
+
+
+@pytest.mark.parametrize("block,r", [
+    (128, 128), (256, 128), (256, 256), (512, 128), (512, 512), (1024, 256),
     (1024, 512)])
 @pytest.mark.parametrize("window", [1, 64, 128, 200, 512, 2047])
-def test_window_bounds_are_the_skips_and_the_clamps(block_q, block_k, window):
-    """_first_key beside _last_live (forward, dq) and _last_query beside
-    _first_live (dk / dv): a tile is live exactly when some row of it sees
-    some key of it, from either side, and the clamped index of a dead step
-    names a live block."""
+def test_a_sub_blocks_slice_holds_exactly_the_pairs_its_rows_see(block, r,
+                                                                 window):
+    """The band step's geometry, from both sides: the slice of a sub-block
+    of queries (forward, dq) holds every key its rows see, the first at the
+    sequence's start included and the last their own, and keeps exactly
+    those; the slice of a sub-block of keys (dk / dv) every query that sees
+    them, up to the sequence's end. A piece the edges do not cross holds
+    only pairs inside the band, so it needs no mask."""
     seq = 2048
-    num_q, num_k = seq // block_q, seq // block_k
-    live = 0
-    for i in range(num_q):
-        first, last = (int(_first_key(i, block_q, block_k, window)),
-                       _last_live(i, block_q, block_k))
-        assert 0 <= first <= last < num_k
-        for j in range(num_k):
-            # the band crosses the tile: its first row's window reaches the
-            # tile's last key, and its last row reaches the tile's first
-            seen = (i * block_q - (window - 1) <= (j + 1) * block_k - 1
-                    and (i + 1) * block_q - 1 >= j * block_k)
-            assert (first <= j <= last) == seen
-            lo = _first_live(j, block_q, block_k)
-            hi = int(_last_query(j, block_q, block_k, window, num_q))
-            assert lo <= hi < num_q
-            assert (lo <= i <= hi) == seen
-            assert (min(max(j, first), last) == j) == seen
-            assert (min(max(i, lo), hi) == i) == seen
-            live += seen
-    assert _live_steps(seq, block_q, block_k, window) == live
+    for mirror in (False, True):
+        for own, held, kept, edges, free in _slices(seq, block, r, window,
+                                                    mirror):
+            ahead = (held[None, :] - own[:, None]) * (1 if mirror else -1)
+            seen = ((ahead >= 0) & (ahead < window)
+                    & (held >= 0)[None, :] & (held < seq)[None, :])
+            np.testing.assert_array_equal(kept, seen)
+            # none missing: the slice reaches the first and the last
+            # position any of its rows' windows holds
+            if mirror:
+                assert held[0] <= own[0]
+                assert held[-1] >= min(own[-1] + window - 1, seq - 1)
+            else:
+                assert held[0] <= max(own[0] - window + 1, 0)
+                assert held[-1] >= own[-1]
+            # and an uncrossed piece is unmasked by right, not by luck
+            assert edges[:, free].all()
+            assert seen.sum(axis=1).min() >= 1
 
 
-@pytest.mark.parametrize("seq,window,live,causal_live", [
-    # trinity-mini.s16384.b1.zipf's windowed layers at the forward's
-    # (1024, 1024): three key blocks a query block
-    (16384, 2048, 45, 136),
-    (16384, 512, 31, 136),
-    (4096, 2048, 9, 10),
-])
-def test_a_windowed_calls_live_steps_are_pinned(seq, window, live,
-                                                causal_live):
-    """The tiles come from the shapes alone, the causal call's (the wide
-    block won at every window measured on the chip), and the share of the
-    causal grid's live steps that the second bound leaves is a pure function
-    of them and the window."""
-    tiles = forward_tiles(seq, 128, 2, True)
-    assert tiles == (1024, 1024)
-    assert _live_steps(seq, *tiles, window) == live
-    assert _live_steps(seq, *tiles) == causal_live
-    # the benchmark's own count of the same share
-    from benchmark.families.trinity_step import live_step_share
-    assert live_step_share(seq, window, tiles) == live / causal_live
+# [B, S, h, d] of a cell's windowed call, its K/V heads and window, and the
+# band the rules give it: (block, r) forward and backward, blocks a slab
+BANDS = {
+    "trinity-mini.s16384.b1.zipf":
+        ((1, 16384, 32, 128), 4, 2048, (1024, 256), (1024, 256), 3, 3),
+    "mellum2-12b-a2.5b.s8192.b1.zipf.x4":
+        ((1, 8192, 32, 128), 4, 1024, (2048, 128), (1024, 128), 2, 2),
+    "a-window-of-512-in-16384":
+        ((1, 16384, 32, 128), 4, 512, (2048, 128), (1024, 128), 2, 2),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BANDS))
+def test_the_cells_windowed_calls_take_the_band_step(cell):
+    """From the shapes alone: a windowed call is three ``pallas_call``s with
+    no key axis in the forward's and dq's grids and the group for dk / dv's
+    last, at the block and sub-block the rules choose under their VMEM
+    counts, K and V passed once a block of the slab."""
+    shape, h_kv, window, forward, backward, fwd_blocks, bwd_blocks = (
+        BANDS[cell])
+    b, seq, h, d = shape
+    assert forward_band(seq, d, 2, window) == forward
+    assert backward_band(seq, d, 2, window) == backward
+    assert fa.forward_band_vmem_bytes(*forward, window, d, 2) <= _VMEM_BUDGET
+    assert fa.backward_band_vmem_bytes(*backward, window, d,
+                                       2) <= _VMEM_BUDGET
+    for tiles in (forward, backward):
+        assert tiles[0] % tiles[1] == 0 and seq % tiles[0] == 0
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, seq, h_kv, d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, window=window,
+        interpret=False).astype(jnp.float32)), argnums=(0, 1, 2)))(q, kv, kv)
+    calls = dict(_pallas_calls(jaxpr.jaxpr))
+    assert sorted(calls) == ["_band_dkv_kernel", "_band_dq_kernel",
+                             "_band_fwd_kernel"]
+    group = h // h_kv
+    want = {"_band_fwd_kernel": ((b * h, seq // forward[0]),
+                                 1 + 3 * fwd_blocks),
+            "_band_dq_kernel": ((b * h, seq // backward[0]),
+                                4 + 3 * bwd_blocks),
+            "_band_dkv_kernel": ((b * h_kv, seq // backward[0], group),
+                                 4 * bwd_blocks + 3)}
+    for name, (grid, operands) in want.items():
+        assert calls[name].params["grid_mapping"].grid == grid
+        assert len(calls[name].invars) == operands
+
+
+def _pallas_calls(jaxpr):
+    """(kernel name, equation) of every ``pallas_call`` under ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["jaxpr"].debug_info.func_name, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_a_window_no_band_fits_is_refused_and_r_divides_the_block():
+    """The slab of a window of 8,192 keys at head 128 does not fit VMEM
+    beside a sub-block's scores: the rule says so, and does not fall back
+    to another program. A forced band whose sub-block does not divide its
+    block is refused."""
+    with pytest.raises(ValueError, match="band step fits"):
+        forward_band(16384, 128, 2, 8192)
+    assert forward_band(16384, 128, 2, 4096) == (256, 256)
+    q, k, v = _qkv(35, s=512, b=1, h=2)
+    with pytest.raises(ValueError, match="sub-block"):
+        flash_attention(q, k, v, causal=True, window=64, block_q=128,
+                        block_k=256)
+
+
+def test_windowed_rows_that_lose_every_key_give_zeros_fwd_and_bwd():
+    """Under a window a row can lose all it sees to the padding mask (here
+    the keys 100..299 are padding and the window is 64: rows 163..299 see
+    nothing): exactly zero output and zero dq there, finite everywhere, the
+    reference's values on the rows that see a key, and zero dk / dv for the
+    padded keys."""
+    seq, window = 512, 64
+    q, k, v = _qkv(36, s=seq, b=1, h=2)
+    mask = np.ones((1, seq), np.int32)
+    mask[:, 100:300] = 0
+    dead = np.arange(seq)
+    dead = (dead >= 100 + window - 1) & (dead < 300)
+    mask = jnp.asarray(mask)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask=mask, causal=True,
+                               window=window, block_q=256, block_k=128)
+
+    def ref(q, k, v):
+        return _ref(q, k, v, mask=mask, causal=True, window=window)
+
+    out, want = np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v))
+    np.testing.assert_array_equal(out[:, dead], 0.0)
+    np.testing.assert_allclose(out[:, ~dead], want[:, ~dead], rtol=2e-5,
+                               atol=2e-5)
+
+    def loss(attn):
+        # the reference softmaxes a dead row to uniform garbage: keep it
+        # out of both losses
+        return lambda q, k, v: jnp.sum(
+            jnp.where(jnp.asarray(dead)[None, :, None, None], 0.0,
+                      attn(q, k, v)) ** 2)
+
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    wanted = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, wanted, "qkv"):
+        assert np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=5e-4,
+                                   atol=5e-4, err_msg=name)
+    np.testing.assert_array_equal(np.asarray(got[0])[:, dead], 0.0)
+    for g in got[1:]:
+        np.testing.assert_array_equal(np.asarray(g)[:, 100:300], 0.0)
 
 
 def test_window_with_values_of_their_own_width():
@@ -886,10 +1017,12 @@ PROGRAMS_BEFORE_THE_EDGE = {
     "olmoe": ((1, 4096, 16, 128), 16, 128, True, None, "b41ce700ff96f1b1"),
     "trinity-full": ((1, 16384, 32, 128), 4, 128, True, None,
                      "76f8390badbc8d4d"),
-    "trinity-window": ((1, 16384, 32, 128), 4, 128, True, 2048,
-                       "19ecf40257f2d1b9"),
-    "mellum-window": ((1, 8192, 32, 128), 4, 128, True, 1024,
-                      "dc32c360eb3d5b9f"),
+    # the windowed calls of these two cells were pinned here until they
+    # became the band step (PR 53); their full layers and Nemotron-H's share
+    # (commit 0fa2f40) stand in
+    "mellum-full": ((1, 8192, 32, 128), 4, 128, True, None,
+                    "e275f450b9524c6e"),
+    "nemotron": ((1, 8192, 4, 128), 1, 128, True, None, "909fe9c3ab518763"),
 }
 
 
@@ -910,6 +1043,36 @@ def test_without_an_edge_the_program_is_the_one_it_was(cell):
             q, k, v, causal=causal, window=window,
             interpret=False).astype(jnp.float32)), argnums=(0, 1, 2)))(
                 q, k, v)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
+@pytest.mark.parametrize("strict,want", [(False, "628f70edb28031ce"),
+                                         (True, "db12945bdc71633f")],
+                         ids=["clean-queries", "noised-queries"])
+def test_the_edged_calls_are_the_program_they_were(strict, want):
+    """SDAR's two calls a layer ([1, 8192, 32 on 4, 128], blocks of 4; the
+    strict one with its logsumexp as an output) trace to the jaxpr they gave
+    before a windowed call became the band step (commit 0fa2f40): the tiled
+    kernels lost their window arms and nothing else."""
+    import hashlib
+    import re
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, edge_block=4,
+                              strict_edge=strict, return_lse=strict,
+                              interpret=False)
+        if not strict:
+            return jnp.sum(out.astype(jnp.float32))
+        out, lse = out
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(
+            jnp.logaddexp(lse, 0.0))
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, kv, kv)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 

@@ -299,3 +299,14 @@ def test_nemotron_grad_check_rehearses():
     for fault in ("picks_not_scaled", "picks_not_renormalised"):
         assert out[f"reference_with_{fault}"]["lengths_apart"] > 2 * limit
 
+
+
+def test_window_table_rehearses():
+    """tools/window_table.py at tiny shapes on the CPU: the band step's
+    three calls run under the tool's own wrappers at both cells' names and
+    print no time."""
+    out = _run("window_table.py", "--rehearse")
+    assert out["device"] == "cpu"
+    assert sorted(out["shapes"]) == ["mellum", "trinity"]
+    for rows in out["shapes"].values():
+        assert rows["ms"] == {} and rows["window"] < rows["q"][1]

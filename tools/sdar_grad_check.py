@@ -119,11 +119,11 @@ def main(argv=None) -> int:
     fa = importlib.import_module("ps_tpu.ops.flash_attention")
     visible = fa._visible
 
-    def edge_one_position_off(qi, j, shape, q_axis, window=None, edge=None):
+    def edge_one_position_off(qi, j, shape, q_axis, edge=None):
         """Every row sees one key more: the first position past its edge
         (where that key's tile is one the kernels run at all)."""
         if edge is None:
-            return visible(qi, j, shape, q_axis, window, edge)
+            return visible(qi, j, shape, q_axis, edge)
         block, strict = edge
         iota = jax.lax.broadcasted_iota
         qpos = qi * shape[q_axis] + iota(jnp.int32, shape, q_axis)
