@@ -21,17 +21,32 @@ def rms_norm(x, scale, eps):
     return (scale * xf).astype(x.dtype)
 
 
-def rope(x, theta, *, inv_freq=None, scale=None):
+def rope(x, theta, *, inv_freq=None, scale=None, interleaved=False):
     """Rotary positions on ``x`` [B, S, h, d], halves rotated against each
     other (``rotate_half``), angles in f32. The frequencies are
     ``theta ** (-2i / d)`` unless a table ``inv_freq`` [d / 2] is given (a
     layer type's own: blended and divided as YaRN's); ``scale`` multiplies
     cos and sin (YaRN's ``attention_factor``: a rotated q . k carries its
-    square). Without either the trace is the one-table call's."""
+    square). ``interleaved``: channels ``2j`` and ``2j + 1`` are rotated
+    against each other instead (``rope_interleave``), each pair by the
+    ``j``-th angle, and come out where they went in: the partner is a lane's
+    neighbour, fetched by two rolls and a select on the lane's parity, no
+    strided slice. Without any of the three the trace is the one-table
+    call's."""
     seq, dim = x.shape[1], x.shape[-1]
     if inv_freq is None:
         inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
+    if interleaved:
+        cos, sin = (jnp.repeat(f(angles), 2, axis=-1)[None, :, None, :]
+                    for f in (jnp.cos, jnp.sin))
+        if scale is not None:
+            cos, sin = cos * scale, sin * scale
+        xf = x.astype(jnp.float32)
+        even = jnp.arange(dim) % 2 == 0
+        rotated = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                            jnp.roll(xf, 1, axis=-1))
+        return (xf * cos + rotated * sin).astype(x.dtype)
     cos = jnp.concatenate([jnp.cos(angles)] * 2, -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, -1)[None, :, None, :]
     if scale is not None:
@@ -86,6 +101,58 @@ def make_attn_fn(attn: str = "full", mesh=None, **kw) -> Callable:
         return op(q, k, v, mesh, causal=causal, **kw)
 
     return fn
+
+
+def mla_block(lp: Dict, x, config, attn_fn: Callable):
+    """Multi-head latent attention of the normed activations ``x``
+    [B, S, D]: K and V expanded from the normalised ``kv_lora_rank``-wide
+    latent, the ``qk_rope_head_dim`` channels every head shares broadcast to
+    the heads and concatenated behind each head's own ``qk_nope_head_dim``
+    (one operand as wide as q: the kernel reads q and k at 192 and v at 128,
+    ``ops/flash_attention.py``), scale ``(nope + rope) ** -0.5``. Two things
+    are read from ``config`` and absent where it has no such field: a
+    ``q_lora_rank``, and q goes through a latent of that width and its norm
+    (``q_a``, ``q_norm``, ``q_b`` in ``lp``) where it was one matrix
+    (``q``); a ``rope_theta``, and the shared channels and the matching last
+    channels of every query head are rotated by their position, by
+    interleaved pairs where ``rope_interleave``. With neither (Kimi-Linear:
+    a plain q, no positions) the trace is the block's as that model had it.
+    The shared channels' gradient is the sum over the heads."""
+    c = config
+    b, s, _ = x.shape
+    heads, nope, pe = (c.num_attention_heads, c.qk_nope_head_dim,
+                       c.qk_rope_head_dim)
+    q_rank = getattr(c, "q_lora_rank", None)
+    theta = getattr(c, "rope_theta", None)
+
+    def proj(name, h):
+        return h @ lp[name]["kernel"].astype(h.dtype)
+
+    with jax.named_scope(phases.ATTN_LATENT):
+        if q_rank is None:
+            q = proj("q", x)
+        else:
+            q = proj("q_b", rms_norm(proj("q_a", x), lp["q_norm"]["scale"],
+                                     c.rms_norm_eps))
+        q = q.reshape(b, s, heads, nope + pe)
+        latent = proj("kv_a", x)
+        compressed, k_pe = jnp.split(latent, [c.kv_lora_rank], axis=-1)
+        kv = proj("kv_b", rms_norm(compressed, lp["kv_norm"]["scale"],
+                                   c.rms_norm_eps)).reshape(b, s, heads, -1)
+    with jax.named_scope(phases.ATTN_ROPE):
+        if theta is not None:
+            pairs = getattr(c, "rope_interleave", False)
+            k_pe = rope(k_pe[:, :, None, :], theta, interleaved=pairs)[:, :, 0]
+            q = jnp.concatenate(
+                [q[..., :nope], rope(q[..., nope:], theta,
+                                     interleaved=pairs)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe[:, :, None, :], (b, s, heads, pe))],
+            axis=-1)
+    with jax.named_scope(phases.ATTN_FULL):
+        a = attn_fn(q, k, kv[..., nope:], causal=True)
+    return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
 
 
 def dense_ffn(lp: Dict, x):

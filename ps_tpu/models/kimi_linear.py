@@ -12,7 +12,8 @@ routed one. As LFM2's, the expert layer holds a share of the experts:
 expert-parallel group without its exchange (``ops/moe.py``).
 
 Pure functions over a parameter dict, as ``models/lfm2.py``; ``rms_norm``,
-``dense_ffn`` and the checkpointed ``experts_of`` are ``models/blocks.py``'s.
+``dense_ffn``, ``mla_block`` and the checkpointed ``experts_of`` are
+``models/blocks.py``'s.
 A block is::
 
     x += mixer(rms_norm(x));  x += ffn(rms_norm(x))
@@ -34,7 +35,8 @@ held to. What differs here is how they are computed:
   output, states and inverses, 49 KB, where q, k, v behind the taps and the
   f32 decays would be 41 more and the chunks' internals 130; the plain form
   of the rule bears no names and is recomputed whole).
-- ``mla_block``: K and V expanded from the normalised 512-wide latent, the 64
+- ``mla_block`` (``models/blocks.py``'s, with neither of its two options): K
+  and V expanded from the normalised 512-wide latent, the 64
   position-free channels every head shares broadcast to the 32 heads and
   concatenated behind the head's own 128 (one 192-wide operand: the kernel
   reads q and k at 192 and v at 128, ``ops/flash_attention.py``), scale
@@ -77,7 +79,7 @@ import jax.numpy as jnp
 
 from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
 from ps_tpu.models.blocks import (dense_ffn, experts_of, make_attn_fn,
-                                  rms_norm, token_ce)
+                                  mla_block, rms_norm, token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.gated_conv import conv_silu
@@ -273,29 +275,6 @@ def kda_block(lp: Dict, x, config: KimiLinearConfig):
                                 "dt_bias", "A_log", "out_norm")}
     o = _kda_of(projected, inner, config.kda_num_heads, config.rms_norm_eps)
     return o @ lp["out"]["kernel"].astype(x.dtype)
-
-
-def mla_block(lp: Dict, x, config: KimiLinearConfig, attn_fn: Callable):
-    """Multi-head latent attention without positions of the normed
-    activations ``x`` [B, S, D]."""
-    c = config
-    b, s, _ = x.shape
-    heads, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
-                         c.qk_rope_head_dim)
-
-    def proj(name, h):
-        return h @ lp[name]["kernel"].astype(h.dtype)
-
-    q = proj("q", x).reshape(b, s, heads, nope + rope)
-    latent = proj("kv_a", x)
-    compressed, k_pe = jnp.split(latent, [c.kv_lora_rank], axis=-1)
-    kv = proj("kv_b", rms_norm(compressed, lp["kv_norm"]["scale"],
-                               c.rms_norm_eps)).reshape(b, s, heads, -1)
-    k = jnp.concatenate(
-        [kv[..., :nope],
-         jnp.broadcast_to(k_pe[:, :, None, :], (b, s, heads, rope))], axis=-1)
-    a = attn_fn(q, k, kv[..., nope:], causal=True)
-    return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
 
 
 def moe_block(lp: Dict, x, config: KimiLinearConfig, bias):

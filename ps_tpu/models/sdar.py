@@ -70,7 +70,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any, Callable, Dict, Tuple
 
 import jax
@@ -294,10 +293,8 @@ def window_rows(config: SdarConfig, tokens: int) -> int:
     whole tiles of the grouped matmul, never more than the pairs there
     are."""
     c = config
-    even = tokens * c.num_experts_per_tok * c.num_experts / c.router_width
-    tiles = math.ceil(HELD_ROWS_OVER_EVEN * even / moe.GROUPED_MATMUL_ROWS)
-    return min(tokens * min(c.num_experts_per_tok, c.num_experts),
-               moe.GROUPED_MATMUL_ROWS * tiles)
+    return moe.window_rows(tokens, c.num_experts_per_tok, c.num_experts,
+                           c.router_width, HELD_ROWS_OVER_EVEN)
 
 
 def moe_block(lp: Dict, x, config: SdarConfig):

@@ -5,7 +5,8 @@ Constants only. The device phases are opened with ``jax.named_scope`` where
 the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
 ``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
-``models/mellum.py``, ``models/sdar.py``, ``ops/moe.py``) and land in the
+``models/mellum.py``, ``models/sdar.py``, ``models/joyai.py``,
+``models/blocks.py``, ``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
@@ -73,7 +74,17 @@ KDA_CONV = "ps.kda/conv"          # the three depthwise causal convolutions and 
 KDA_CORE = "ps.kda/core"          # ops/kda.py alone: the chunked gated delta rule
 MOE_SHARED = "ps.moe/shared"      # the shared expert, a SwiGLU every token passes
 
-KIMI_SCOPES = MOE_SCOPES + (FFN, KDA, KDA_CONV, KDA_CORE, MOE_SHARED)
+# ``models/blocks.py::mla_block``, the latent layer of this model and of
+# JoyAI-LLM-Flash, opens three scopes under ATTN (so ATTN's time holds them):
+# ATTN_FULL around the kernel call (Trinity's, Mellum's and SDAR's layers that
+# see every earlier key open it too), and two that have no metric of their own
+# yet and are read inside ``decoder.attn_ms``.
+ATTN_FULL = "ps.attn/full"        # the core of a layer that sees every earlier key
+ATTN_LATENT = "ps.attn/latent"    # latent attention's projections (q or q_a and q_b, kv_a, kv_b) and the latent norms
+ATTN_ROPE = "ps.attn/rope"        # the rotations, where the model has positions, and the making of the 192-wide q and k
+
+KIMI_SCOPES = MOE_SCOPES + (FFN, KDA, KDA_CONV, KDA_CORE, MOE_SHARED,
+                            ATTN_FULL, ATTN_LATENT, ATTN_ROPE)
 
 # -- scopes of Nemotron-H (models/nemotron_h.py), beside the six ----------------
 # Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy. The
@@ -93,7 +104,7 @@ NEMOTRON_SCOPES = MOE_SCOPES + (MAMBA, MAMBA_CONV, MAMBA_SSD, MOE_LATENT,
 # each kind of layer (with 'flash' the Mosaic kernels and the packing around
 # them) and the sigmoid gate on its output.
 ATTN_WINDOW = "ps.attn/window"    # the core of a layer that sees a window of keys, rotated
-ATTN_FULL = "ps.attn/full"        # the core of a layer that sees every earlier key, not rotated
+# ATTN_FULL (above): here the core of a layer that sees every earlier key, not rotated
 ATTN_GATE = "ps.attn/gate"        # sigmoid of the gate projection times the core's output
 
 TRINITY_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_WINDOW, ATTN_FULL,
@@ -119,6 +130,21 @@ MELLUM_SCOPES = MOE_SCOPES + (ATTN_WINDOW, ATTN_FULL, MOE_EXCHANGE)
 ATTN_INBLOCK = "ps.attn/inblock"  # a noised query's own block of noised keys, and the merge by the logsumexps
 
 SDAR_SCOPES = MOE_SCOPES + (ATTN_FULL, ATTN_INBLOCK)
+
+# -- scopes of JoyAI-LLM-Flash (models/joyai.py), beside the six, FFN, MOE_SHARED --
+# -- and the latent layer's three (ATTN_FULL, ATTN_LATENT, ATTN_ROPE, above) ---------
+# The six, FFN, MOE_SHARED and ATTN_FULL are read by
+# ``benchmark/layer_metrics/decoder.py``, which keeps its own copy; ATTN_LATENT,
+# ATTN_ROPE and the two below have no metric of their own yet. MTP is around
+# the whole prediction module: its ``ps.attn``, ``ps.moe/*`` and ``ps.head``
+# open inside it under their own names and are read as those; MTP_JOIN nests
+# under MTP and, having no scope of the reader's around it, is read with the
+# gradient's rest (the embedding, norms and residuals outside the scopes).
+MTP = "ps.mtp"                    # the prediction module for the token after next: join, one layer, norm, the shared head
+MTP_JOIN = "ps.mtp/join"          # the two norms, the second embedding lookup, eh_proj
+
+JOYAI_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_FULL, ATTN_LATENT,
+                             ATTN_ROPE, MTP, MTP_JOIN)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

@@ -203,13 +203,14 @@ HELD_ROWS_OVER_EVEN = 3
 GROUPED_MATMUL_ROWS = 512
 
 
-def window_rows(tokens: int, top_k: int, held: int, num_experts: int) -> int:
+def window_rows(tokens: int, top_k: int, held: int, num_experts: int,
+                over_even: float = HELD_ROWS_OVER_EVEN) -> int:
     """``R``, the rows a layer that holds ``held`` of ``num_experts`` experts
-    moves at a time: ``HELD_ROWS_OVER_EVEN`` times the even load in whole
-    tiles of the grouped matmul, and never more than the ``tokens x min(top_k,
-    held)`` pairs there are."""
+    moves at a time: ``over_even`` (``HELD_ROWS_OVER_EVEN`` unless a model
+    gives its own) times the even load in whole tiles of the grouped matmul,
+    and never more than the ``tokens x min(top_k, held)`` pairs there are."""
     even = tokens * top_k * held / num_experts
-    tiles = math.ceil(HELD_ROWS_OVER_EVEN * even / GROUPED_MATMUL_ROWS)
+    tiles = math.ceil(over_even * even / GROUPED_MATMUL_ROWS)
     return min(tokens * min(top_k, held), GROUPED_MATMUL_ROWS * tiles)
 
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ps_tpu.models import blocks
 from ps_tpu.models.blocks import make_attn_fn
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -23,9 +24,12 @@ REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
               # Mellum's has no kernel, no mesh and no exchange
               "mellum": ("pallas", "shard_map", "all_to_all", "ragged_dot"),
               # SDAR's is one explicit mask: no kernel, no logsumexp merged
-              "sdar": ("pallas", "logaddexp")}
+              "sdar": ("pallas", "logaddexp"),
+              # JoyAI's rotation is a product of pairs: no kernel, no roll
+              # of lanes, no rotation of halves
+              "joyai": ("pallas", "roll(", "rotate_half")}
 MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
-          "mellum", "sdar")
+          "mellum", "sdar", "joyai")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))
@@ -98,3 +102,89 @@ def test_a_sequence_parallel_closure_refuses_grouped_kv_by_name(attn):
     k = v = jnp.zeros((1, 8, 2, 8))
     with pytest.raises(ValueError, match=f"make_attn_fn\\('{attn}'\\)"):
         make_attn_fn(attn)(q, k, v)
+
+
+# -- the latent attention two models share --------------------------------------
+
+def _mla_as_kimi_had_it(lp, x, config, attn_fn):
+    """``models/kimi_linear.py::mla_block`` as it stood before the block
+    moved to ``models/blocks.py`` (commit 7b3f956), letter for letter."""
+    from ps_tpu.models.blocks import rms_norm
+
+    c = config
+    b, s, _ = x.shape
+    heads, nope, rope = (c.num_attention_heads, c.qk_nope_head_dim,
+                         c.qk_rope_head_dim)
+
+    def proj(name, h):
+        return h @ lp[name]["kernel"].astype(h.dtype)
+
+    q = proj("q", x).reshape(b, s, heads, nope + rope)
+    latent = proj("kv_a", x)
+    compressed, k_pe = jnp.split(latent, [c.kv_lora_rank], axis=-1)
+    kv = proj("kv_b", rms_norm(compressed, lp["kv_norm"]["scale"],
+                               c.rms_norm_eps)).reshape(b, s, heads, -1)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_pe[:, :, None, :], (b, s, heads, rope))], axis=-1)
+    a = attn_fn(q, k, kv[..., nope:], causal=True)
+    return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
+
+
+@pytest.mark.parametrize("attn", ["full", "flash"])
+def test_the_shared_latent_block_is_kimis_to_the_bit_with_neither_option(attn):
+    """``blocks.mla_block`` on a configuration with no ``q_lora_rank`` and no
+    ``rope_theta`` (Kimi-Linear's) gives the outputs and every gradient of the
+    block that model had, bit for bit, in the cell's bf16 over f32 leaves; the
+    model's module holds no block of its own any more."""
+    from ps_tpu.models import kimi_linear
+
+    assert kimi_linear.mla_block is blocks.mla_block
+    cfg = kimi_linear.KimiLinearConfig(
+        hidden_size=64, num_attention_heads=4, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
+    assert not hasattr(cfg, "q_lora_rank") and not hasattr(cfg, "rope_theta")
+    rng = np.random.default_rng(0)
+
+    def w(*shape):
+        return {"kernel": jnp.asarray(0.1 * rng.normal(size=shape),
+                                      jnp.float32)}
+
+    lp = {"q": w(64, 4 * 24), "kv_a": w(64, 40), "kv_b": w(32, 4 * 32),
+          "kv_norm": {"scale": jnp.asarray(1 + 0.1 * rng.normal(size=(32,)),
+                                           jnp.float32)},
+          "out": w(64, 64)}
+    x = jnp.asarray(rng.normal(size=(2, 128, 64)), jnp.bfloat16)
+    attn_fn = make_attn_fn(attn)
+
+    def run(block):
+        def loss(lp, x):
+            out = block(lp, x, cfg, attn_fn)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(lp, x)
+
+    (_, out), grads = run(blocks.mla_block)
+    (_, want), want_grads = run(_mla_as_kimi_had_it)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.max(jnp.abs(r.astype(jnp.float32)))) > 0
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(r, np.float32))
+
+
+def test_the_interleaved_rotation_leaves_the_other_callers_trace_alone():
+    """``rope`` without ``interleaved`` traces to what it traced to before
+    the option: Mellum's, Trinity's, OLMoE's and SDAR's calls; with it, two
+    rolls of the lanes and a select, and no strided slice."""
+    x = jax.ShapeDtypeStruct((1, 256, 4, 64), jnp.bfloat16)
+    halves = str(jax.make_jaxpr(lambda x: blocks.rope(x, 1e6))(x))
+    assert "roll" not in halves and "select_n" not in halves
+    assert halves == str(jax.make_jaxpr(
+        lambda x: blocks.rope(x, 1e6, interleaved=False))(x))
+    pairs = str(jax.make_jaxpr(
+        lambda x: blocks.rope(x, 1e6, interleaved=True))(x))
+    assert pairs.count("_roll_static") == 2 and "select_n" in pairs
+    assert "strides=(1, 1, 1, 2)" not in pairs

@@ -379,3 +379,52 @@ def test_labelled_mlm_head_compiles_at_the_cells_shapes(batch, seq, one_chip,
     too_tall = [d for d in wide if np.prod(d) // vocab > head_rows]
     assert not too_tall, too_tall
     assert text.count(" while(") == 2 and " conditional(" not in text
+
+
+def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache,
+                                                 mosaic_grouped_matmul):
+    """``joyai-llm-flash.s8192.b1.zipf``: value and gradient of the loss
+    ``KVStore.make_step`` differentiates, at the configuration's published
+    widths and [1, 8192] tokens. Eighteen Mosaic flash calls, three for each
+    of the six latent layers (the module's the sixth; each layer's checkpoint
+    keeps the forward call's output and logsumexp, so none runs twice), each
+    written out: the only loops are the expert layers' windows behind the
+    first, forward and backward, which a step with no overflow never enters.
+    Parameters and their gradients are 5.4e9 B of the program; the rest, the
+    temporaries, stays under 3.5e9 B: two [8192, 16160] f32 logit arrays are
+    not live at once (each head pass is recomputed under its own
+    checkpoint)."""
+    import json
+    import os
+
+    from ps_tpu.models import joyai
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "joyai-llm-flash.json")) as f:
+        cfg = joyai.JoyaiConfig.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda k: joyai.init_params(k, cfg),
+                                    jax.random.key(0)))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    bias = on_chip(jax.eval_shape(lambda: joyai.init_expert_bias(cfg)))
+    loss = joyai.make_loss_fn(cfg, attn="flash", interpret=False)
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, {"inputs": ids, "targets": ids}, bias).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in calls if "ps.attn/full" in line]
+    assert len(flash) == 18
+    assert all("ps.attn/full" in line or "ps.moe/expert" in line
+               for line in calls)
+    assert not [line for line in flash if "while" in line]
+    text = compiled.as_text()
+    assert text.count(" while(") == 2 * 5 and " conditional(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3.5e9
+    assert (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes) < 0.6 * 17.18e9

@@ -2,8 +2,8 @@
 gradient: ``ops/moe.py::ROUTE_KEPT`` is the identity wherever no policy lists
 it (the five models that share ``route`` and do not) and takes the router's
 product, its ``top_k`` and its two sorts out of the recomputation where one
-does. The two models whose policies list it have their own cases in
-``test_nemotron_h.py`` and ``test_trinity.py``."""
+does. The three models whose policies list it have their own cases in
+``test_nemotron_h.py``, ``test_trinity.py`` and ``test_joyai.py``."""
 
 import importlib
 

@@ -903,3 +903,27 @@ def test_family_refuses_a_pool_it_would_have_to_cycle():
     traffic = _json("benchmark/traffic/s8192.b1.zipf.json")
     with pytest.raises(ValueError, match="re-uses no batch"):
         kimi_step.build(config, {**traffic, "pool": 16}, 1, 0)
+
+
+def test_the_cells_program_is_the_one_before_the_latent_block_moved():
+    """The loss ``KVStore.make_step`` differentiates, value and gradient, at
+    the cell's shapes (the configuration's file, [1, 8192] tokens, the flash
+    kernel as the chip compiles it) traces to the jaxpr it gave at commit
+    7b3f956, when ``mla_block`` stood in ``models/kimi_linear.py``: the block's
+    two options and the three scopes around its parts leave Kimi-Linear's
+    program as it was (a scope's name is in no equation)."""
+    import hashlib
+    import re
+
+    config = _json("benchmark/configs/kimi-linear-48b-a3b.json")
+    cfg = kimi_linear.KimiLinearConfig.from_dict(config)
+    params = jax.eval_shape(lambda k: kimi_linear.init_params(k, cfg),
+                            jax.random.key(0))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+    bias = jax.eval_shape(lambda: kimi_linear.init_expert_bias(cfg))
+    loss = kimi_linear.make_loss_fn(cfg, attn="flash", interpret=False)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(
+        params, {"inputs": ids, "targets": ids}, bias)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "c949d15bfbd55590"

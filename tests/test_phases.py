@@ -158,6 +158,12 @@ def test_marks_change_no_instruction(kind, monkeypatch, no_compile_cache):
     assert _without_metadata(marked) == _without_metadata(bare)
 
 
+#: the scopes the program opens that ``layer_metrics/decoder.py`` has no name
+#: for yet (PERF.md section 7, row 0)
+PROGRAMS_OWN = {phases.ATTN_INBLOCK, phases.ATTN_LATENT, phases.ATTN_ROPE,
+                phases.MTP, phases.MTP_JOIN}
+
+
 def test_program_and_benchmark_share_their_names():
     assert set(phases.DEVICE_PHASES) == set(scope.DEVICE_PHASES)
     assert phases.BACKWARD_MARK == scope.BACKWARD_MARK
@@ -169,9 +175,11 @@ def test_program_and_benchmark_share_their_names():
                  "INPUT_SOURCE_WAIT", "INPUT_PRODUCE"):
         assert getattr(phases, name) == getattr(host, name)
     # the decoders' scopes: the one reader's one copy, every name of it the
-    # program's, and a metric for every scope a model opens (the seven
-    # ``*_SCOPES``) but, until it is given one, ps.attn/inblock, which counts
-    # inside decoder.attn_ms
+    # program's, and a metric for every scope a model opens (the eight
+    # ``*_SCOPES``) but, until each is given one, the program's own five:
+    # ps.attn/inblock, ps.attn/latent and ps.attn/rope, which count inside
+    # decoder.attn_ms, ps.mtp, whose inner scopes are read under their own
+    # names, and ps.mtp/join, read with the gradient's rest
     copied = [name for name in vars(decoder)
               if name.isupper() and hasattr(phases, name)]
     assert len(copied) == len(decoder.SCOPES) >= 21
@@ -179,8 +187,9 @@ def test_program_and_benchmark_share_their_names():
         assert getattr(phases, name) == getattr(decoder, name), name
     families = [name for name in vars(phases) if name.endswith("_SCOPES")]
     opened = set().union(*(getattr(phases, name) for name in families))
-    assert len(families) == 7
-    assert opened - {phases.ATTN_INBLOCK} <= set(decoder.METRICS) <= opened
+    assert len(families) == 8
+    assert opened - PROGRAMS_OWN <= set(decoder.METRICS) <= opened
+    assert PROGRAMS_OWN <= opened and not PROGRAMS_OWN & set(decoder.METRICS)
     assert not opened & set(phases.DEVICE_PHASES)
     # every span the program records has a metric that reads it
     ring = [_span(name, 10.0, f"s{i}", "s0" if name == host.STEP_LAUNCH
@@ -306,8 +315,11 @@ def test_kimi_scopes_reach_the_step_hlo_forward_and_backward(
         no_compile_cache, monkeypatch):
     """What Kimi-Linear adds to the scopes (``ps.kda``, ``ps.kda/conv``,
     ``ps.kda/core``, ``ps.moe/shared``) beside the six it shares with OLMoE
-    and ``ps.ffn``: each in the lowered step's ``op_name``s under
-    ``ps.grad``, forward and backward; the reader finds each."""
+    and ``ps.ffn``, and the three of its latent layer
+    (``models/blocks.py::mla_block``: ``ps.attn/full``, ``ps.attn/latent``,
+    ``ps.attn/rope``): each in the lowered step's ``op_name``s under
+    ``ps.grad``, forward and backward; the reader finds each it has a name
+    for."""
     assert phases.KIMI_SCOPES[:6] == phases.MOE_SCOPES
     monkeypatch.setitem(BUILDERS, "kimi", _kimi_step)
     names = scope.op_names_of(_step_hlo("kimi"))
@@ -317,7 +329,12 @@ def test_kimi_scopes_reach_the_step_hlo_forward_and_backward(
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
     found = {decoder.scope_of(own, n) for own, n in names.items()}
-    assert found == set(phases.KIMI_SCOPES) | {None}
+    assert found == (set(phases.KIMI_SCOPES) - PROGRAMS_OWN) | {None}
+    # the latent layer's projections and the making of the 192-wide q and k
+    # count in decoder.attn_ms, as the attention's own
+    for s in (phases.ATTN_LATENT, phases.ATTN_ROPE):
+        inner = {decoder.scope_of("%x", n) for n in names.values() if s in n}
+        assert inner == {phases.ATTN}, s
     # the rule and the taps are the innermost scopes of their ops, and the
     # mixer's holds them; the shared expert is not the routed ones'
     for inner in (phases.KDA_CORE, phases.KDA_CONV):
@@ -838,7 +855,54 @@ READER_CASES = {
         # changing (PERF.md section 7, row 0): the own blocks' scope under
         # a name of its own, the counter as it stands
         "may": {"decoder.inblock_ms": 2.0, "decoder.masked_share": 0.5},
-        "mfu": 5.0, "device_ms": 27.0}}
+        "mfu": 5.0, "device_ms": 27.0},
+    "joyai": {   # six latent layers, one of them in the prediction module
+        "events": [
+            ("%latent", "fusion", 0.004,
+             _CP + "ps.attn/ps.attn/latent/dot_general"),
+            ("%roll", "fusion", 0.002,
+             _CP + "ps.attn/ps.attn/rope/jit(_roll_static)/slice"),
+            ("%fwd", "custom-call", 0.006,
+             _CP + "ps.attn/ps.attn/full/pallas_call"),
+            ("%dkv", "custom-call", 0.008,
+             _RULE + "ps.attn/ps.attn/full/pallas_call"),
+            ("%out", "fusion", 0.002, _BACK + "ps.attn/dot_general"),
+            ("%dense", "fusion", 0.003, _CP + "ps.ffn/dot"),
+            ("%shared", "fusion", 0.002, _CP + "ps.moe/shared/dot"),
+            # the module: its own scope around everything, the inner scopes
+            # under their own names, the join under none the reader knows
+            ("%join", "fusion", 0.002,
+             _JVP.format("ps.mtp") + "ps.mtp/join/dot_general"),
+            ("%mtp_latent", "fusion", 0.002,
+             "jit(f)/ps.grad/transpose(jvp(ps.mtp))/jvp(ps.mtp)/checkpoint/"
+             "rematted_computation/ps.attn/ps.attn/latent/dot_general"),
+            ("%mtp_fwd", "custom-call", 0.006,
+             _JVP.format("ps.mtp") + "ps.attn/ps.attn/full/pallas_call"),
+            ("%mtp_head", "fusion", 0.003,
+             "jit(f)/ps.grad/transpose(jvp(ps.mtp))/ps.head/jvp(ps.mtp)/"
+             "ps.head/checkpoint/rematted_computation/dot_general"),
+            *_EXPERTS, *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "flash_flops": 2e9,
+                  "flash_bytes": 1.0},
+        "counters": {**_HELD, "held_pair_share": 0.0625, "ce": 9.5,
+                     "mtp_ce": 9.75, "mtp_positions": 8191.0},
+        "want": {**_EXPERTS_WANT,
+                 "decoder.attn_ms": 15.0,     # latent, rope and the cores in
+                 "decoder.full_core_ms": 10.0,
+                 "decoder.head_ms": 4.0,      # both passes
+                 "decoder.dense_ffn_ms": 1.5, "decoder.shared_ffn_ms": 1.0,
+                 # 2 ms of MXU over the three Mosaic calls' 10 ms
+                 "kernel.flash_roofline": 20.0,
+                 "decoder.held_pair_share": 0.0625},
+        # what the reader may come to say of this result without this file
+        # changing (PERF.md section 7, row 0): the four scopes the program
+        # opens and the reader has no name for, each one line of METRICS
+        "may": {"decoder.mtp_ms": 6.5, "decoder.mtp_join_ms": 1.0,
+                "decoder.attn_latent_ms": 3.0, "decoder.attn_rope_ms": 1.0,
+                "decoder.ce": 9.5, "decoder.mtp_ce": 9.75,
+                "decoder.mtp_positions": 8191.0},
+        "mfu": 5.0, "device_ms": 33.0}}
 
 
 @pytest.mark.parametrize("case", sorted(READER_CASES))
@@ -1522,6 +1586,70 @@ def test_sdar_scopes_reach_the_step_hlo_forward_and_backward(
         phases.ATTN in (s, decoder.outer_of(s)) for s in own_blocks)
 
 
+def _joyai_step():
+    """JoyAI-LLM-Flash in small through the store: the dense layer, one
+    expert layer and the prediction module on eight sequences of 64
+    positions, two of eight experts held, three picks."""
+    from ps_tpu.models import joyai
+
+    cfg = joyai.JoyaiConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        num_attention_heads=2, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        router_width=8, n_routed_experts=2, expert_start=2,
+        num_experts_per_tok=3, dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: joyai.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    step = store.make_step(joyai.make_loss_fn(cfg), has_aux=True)
+    bias = joyai.init_expert_bias(cfg)
+    return (lambda batch: step(batch, bias),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_joyai_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What JoyAI-LLM-Flash opens (``JOYAI_SCOPES``): the six it shares with
+    OLMoE, ``ps.ffn``, ``ps.moe/shared``, the latent layer's three
+    (``ps.attn/full``, ``ps.attn/latent``, ``ps.attn/rope``) and the
+    prediction module's two (``ps.mtp``, ``ps.mtp/join``): each in the
+    lowered step's ``op_name``s under ``ps.grad``, forward and backward,
+    though every layer and each head pass is under a ``jax.checkpoint``. The
+    reader has no name for four of them today: the latent projections and the
+    rotation count inside ``decoder.attn_ms``, the module's attention, experts
+    and head pass under their own scopes' metrics, and the join in none."""
+    assert phases.JOYAI_SCOPES == phases.MOE_SCOPES + (
+        phases.FFN, phases.MOE_SHARED, phases.ATTN_FULL, phases.ATTN_LATENT,
+        phases.ATTN_ROPE, phases.MTP, phases.MTP_JOIN)
+    monkeypatch.setitem(BUILDERS, "joyai", _joyai_step)
+    names = scope.op_names_of(_step_hlo("joyai"))
+    for s in phases.JOYAI_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == (set(phases.JOYAI_SCOPES) - PROGRAMS_OWN) | {None}
+    for s in (phases.ATTN_LATENT, phases.ATTN_ROPE):
+        inner = {decoder.scope_of("%x", n) for n in names.values() if s in n}
+        assert inner == {phases.ATTN}, s
+    # under the module's scope: its attention, its experts and its head pass
+    # are read as those, the join as nothing the reader knows
+    module = {decoder.scope_of("%x", n) for n in names.values()
+              if phases.MTP in n}
+    assert {phases.ATTN, phases.ATTN_FULL, phases.MOE_EXPERT,
+            phases.MOE_SHARED, phases.HEAD, None} <= module
+    assert {decoder.scope_of("%x", n) for n in names.values()
+            if phases.MTP_JOIN in n} == {None}
+    # the main head's pass and the module's: ps.head under two name stacks
+    heads = [n for n in names.values() if phases.HEAD in n]
+    assert any(phases.MTP in n for n in heads)
+    assert any(phases.MTP not in n for n in heads)
+
+
 # -- the benchmark's own command on the CPU, and its manifest ------------------
 
 with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
@@ -1559,6 +1687,9 @@ REHEARSED = {
     "trinity-mini.s16384.b1.zipf": (),
     "mellum2-12b-a2.5b.s8192.b1.zipf.x4": (),
     "sdar-30b-a3b.s8192.b1.zipf.bd4": (
+        "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
+        "setup.import_s", "device.peak_hbm_gib"),
+    "joyai-llm-flash.s8192.b1.zipf": (
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib")}
 
