@@ -10,6 +10,19 @@ step with them (the reference's Wide-&-Deep worker, SURVEY.md §4c).
 Gradients w.r.t. embeddings are taken against the *gathered rows* (shape
 [N, D]), never the full table: that IS the sparse push payload, and it keeps
 the backward pass free of dense [V, D] gradient materialization.
+
+The step pulls distinct rows. Where a table lives on one chip and applies
+through the fused tier (``SparseEmbedding.pulls_distinct``: read from the
+store, no knob), the dedupe the push needs anyway runs in front of the loss
+and serves three things: the pull gathers each distinct row of the batch
+once, the loss reads the rows through an expansion out of that batch-sized
+buffer, and the push applies to the rows the pull already holds without
+reading the table again — the reference's worker, which pulls a sorted list
+of distinct keys and pushes the same list. Across chips, where an owner's
+distinct rows are known only after the row exchange, and on the 'off' tier,
+the step looks every (id, slot) pair up and the push dedupes for itself, as
+before. The rows the loss reads, the sums and every written bit are the
+same either way (tests/test_sparse_apply.py).
 """
 
 from __future__ import annotations
@@ -91,7 +104,23 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
                 pulled = jax.lax.with_sharding_constraint(
                     params_kv, gathered)
         ids = ids_fn(batch)
-        rows = {n: emb_stores[n].lookup(tables[n], ids[n]) for n in names}
+        # the sparse pull. A table on one chip is pulled as the reference's
+        # worker pulls: the ids deduped first, each distinct row gathered
+        # once and held for the push. Tables handed the same id array share
+        # its plan, by the array's identity: one sort in the program
+        # whatever the compiler's CSE finds
+        rows, held, plans = {}, {}, {}
+        for n in names:
+            store = emb_stores[n]
+            if store.pulls_distinct:
+                key = (id(ids[n]), store.rows_per_shard)
+                if key not in plans:
+                    plans[key] = store.plan_pull(ids[n])
+                rows[n], rows_held = store.lookup_distinct(
+                    tables[n], ids[n], plans[key])
+                held[n] = (plans[key], rows_held)
+            else:
+                rows[n] = store.lookup(tables[n], ids[n])
         with jax.named_scope(phases.GRAD):
             out, (grads, grows) = jax.value_and_grad(
                 kv_loss, argnums=(0, 1), has_aux=has_aux
@@ -110,9 +139,14 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
         new_tables, new_estates, row_counts = [], [], []
         for n in names:
             store = emb_stores[n]
-            table, estate, counts = store.apply(
-                tables[n], estates[n], ids[n].reshape(-1),
-                grows[n].reshape(-1, store.dim))
+            if n in held:
+                table, estate, counts = store.apply_held(
+                    tables[n], estates[n], *held[n],
+                    grows[n].reshape(-1, store.dim))
+            else:
+                table, estate, counts = store.apply(
+                    tables[n], estates[n], ids[n].reshape(-1),
+                    grows[n].reshape(-1, store.dim))
             new_tables.append(table)
             new_estates.append(estate)
             row_counts.append(counts)
@@ -150,9 +184,11 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
             for n, table, estate, counts in zip(
                     names, rest[:k], rest[k:2 * k], rest[2 * k + 2:]):
                 store = emb_stores[n]
-                store.count_pull(n_ids[n])  # the program's own lookup
+                distinct = store.pulls_distinct
+                # the program's own lookup
+                store.count_pull(n_ids[n], held=distinct)
                 store.adopt_push(table, estate, counts, n_ids[n],
-                                 store.rows_nbytes(n_ids[n]))
+                                 store.rows_nbytes(n_ids[n]), held=distinct)
             params = keymod.unflatten(treedef, params_kv, key_order)
         if has_aux:
             return loss, params, aux

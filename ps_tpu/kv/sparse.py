@@ -13,7 +13,11 @@ TPU-native translation (north star: "sparse embedding row push/pull maps to
   (``NamedSharding(P('data', None))``) — the literal key→server range
   partition, as mesh shards.
 - **pull / lookup** = ``jnp.take`` on the sharded table; under GSPMD, XLA
-  partitions the gather and moves only the needed rows over ICI.
+  partitions the gather and moves only the needed rows over ICI. On one
+  chip the fused step pulls as the reference's worker does, the distinct
+  rows of a batch only and each once (``plan_pull`` / ``lookup_distinct``),
+  and its push takes those rows back (``apply_held``) in place of a second
+  gather.
 - **push / apply** = a ``shard_map`` program: worker-local (ids, row_grads)
   are exchanged to owner shards, duplicate rows are scatter-summed
   (segment-sum via ``.at[].add``), then a lazy row-wise optimizer
@@ -39,7 +43,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +54,28 @@ from jax import shard_map
 from ps_tpu import obs
 from ps_tpu.api import current_context
 from ps_tpu.obs import phases
-from ps_tpu.ops.sparse_apply import fused_sparse_apply, resolve_tier
+from ps_tpu.ops.sparse_apply import (
+    RowPlan,
+    apply_plan,
+    fused_sparse_apply,
+    pair_segments,
+    pull_plan,
+    resolve_tier,
+    row_plan,
+    rows_of_pairs,
+    segment_sums,
+)
 from ps_tpu.optim.rowwise import make_rowwise
 from ps_tpu.parallel.mesh import DATA_AXIS
+
+
+class PullPlan(NamedTuple):
+    """What a step knows of one id list before its loss
+    (:meth:`SparseEmbedding.plan_pull`): tables handed the same list share
+    it."""
+    rows: RowPlan         # the list deduped, ids the table lacks as filler
+    pair_seg: jax.Array   # [N]: pair j's slot among the distinct rows
+    ok: jax.Array         # [N] bool: pair j names a row of the table
 
 
 class SparseEmbedding:
@@ -128,32 +151,43 @@ class SparseEmbedding:
         # boundaries)
         self._counts_base = np.zeros((2,), np.int64)
         self._counts_pending: list = []
+        # the same of the pushes whose distinct rows the step's pull held
+        # (:meth:`apply_held`): their ``applied`` is also :attr:`rows_pulled`
+        self._held_pending: list = []
+        self._rows_pulled = 0
 
-    def record_counts(self, counts) -> None:
+    def record_counts(self, counts, held: bool = False) -> None:
         """Accumulate one push's (possibly device-resident) ``[dropped,
         applied]`` counts without forcing a host sync on the hot path.
         Pending counts fold into one device value periodically so a long
         run that never reads :attr:`dropped_rows` or :attr:`rows_applied`
-        holds O(1) buffers, not one per step: one fold for both counts."""
-        self._counts_pending.append(counts)
-        if len(self._counts_pending) >= 32:
-            oldest = self._counts_pending[0]
-            if getattr(oldest, "is_ready", lambda: True)():
+        holds O(1) buffers, not one per step: one fold for both counts.
+        ``held``: the push applied to the rows its step's pull gathered,
+        so the count of the one is the count of the other."""
+        pending = self._held_pending if held else self._counts_pending
+        pending.append(counts)
+        if len(pending) >= 32:
+            if getattr(pending[0], "is_ready", lambda: True)():
                 # 31 pushes old and computed: its copy to the host waits
                 # for nothing, and the int32 fold on the device never
                 # holds more than 32 pushes' rows
-                self._counts_base += np.asarray(oldest, np.int64)
-                del self._counts_pending[0]
-            total = self._counts_pending[0]
-            for x in self._counts_pending[1:]:
+                self._settle(pending.pop(0), held)
+            total = pending[0]
+            for x in pending[1:]:
                 total = total + x  # device-side adds: still no host sync
-            self._counts_pending = [total]
+            pending[:] = [total]
+
+    def _settle(self, counts, held: bool) -> None:
+        counts = np.asarray(counts, np.int64)
+        self._counts_base += counts
+        if held:
+            self._rows_pulled += int(counts[1])
 
     def _read_counts(self) -> np.ndarray:
-        if self._counts_pending:
-            pending, self._counts_pending = self._counts_pending, []
-            for x in pending:
-                self._counts_base += np.asarray(x, np.int64)
+        for pending, held in ((self._counts_pending, False),
+                              (self._held_pending, True)):
+            while pending:
+                self._settle(pending.pop(0), held)
         return self._counts_base
 
     @property
@@ -176,6 +210,19 @@ class SparseEmbedding:
         pairs as :attr:`rows_pushed` does. Reading this syncs any pending
         device counts."""
         return int(self._read_counts()[1])
+
+    @property
+    def rows_pulled(self) -> int:
+        """Total rows the store's pulls gathered out of the table: every
+        (id, slot) pair of a :meth:`pull` or :meth:`lookup`, and the
+        DISTINCT rows of a step that pulls over a plan
+        (:meth:`lookup_distinct`), read from the count its push reports.
+        ``rows_pulled / rows_pushed`` is the share of a step's slots its
+        one gather serves; :attr:`bytes_pulled` goes on counting the rows
+        the worker receives. Reading this syncs any pending device
+        counts."""
+        self._read_counts()
+        return self._rows_pulled
 
     @property
     def dropped_fraction(self) -> float:
@@ -238,8 +285,16 @@ class SparseEmbedding:
     def lookup(self, table: jax.Array, ids: jax.Array) -> jax.Array:
         """rows = table[ids] — GSPMD partitions the gather over row shards.
 
-        Out-of-range ids are clipped by jnp.take's default mode; valid ids
-        are the caller's contract (synthetic data guarantees it)."""
+        One gather of every (id, slot) pair, duplicates and all: the eager
+        :meth:`pull`, and the fused step's pull where it cannot know the
+        distinct rows before the loss (see :attr:`pulls_distinct`; there
+        the step calls :meth:`lookup_distinct`, which returns these rows
+        bit for bit).
+
+        Valid ids are the caller's contract (synthetic data guarantees
+        it). For an id the table does not have, ``jnp.take``'s default
+        mode answers: a negative id counts from the table's end, anything
+        else reads NaN."""
         with jax.named_scope(phases.LOOKUP):
             return jnp.take(table, ids, axis=0)
 
@@ -266,6 +321,12 @@ class SparseEmbedding:
         dense-rows rule, scatter back, over the distinct rows of the
         batch and no further — so apply cost is O(distinct ids), not
         O(rows_per_shard). Same math by the parity contract.
+
+        The eager :meth:`push`, and the fused step's push wherever its
+        pull looked every pair up. Where the step pulls distinct rows
+        (:attr:`pulls_distinct`) it calls :meth:`apply_held`, which is
+        this on one shard with the dedupe made before the loss and the
+        table's rows taken from the pull.
         """
         rps, dim, axis, k = self.rows_per_shard, self.dim, self.axis, self.k
         opt, tier = self._opt, self.fused_tier
@@ -315,6 +376,69 @@ class SparseEmbedding:
         with jax.named_scope(phases.ROW_APPLY):
             return fn(table, state, ids, row_grads)
 
+    # -- the same two over the distinct rows (the fused step on one chip) -----
+
+    @property
+    def pulls_distinct(self) -> bool:
+        """Whether a fused step (ps_tpu/kv/fused.py) pulls each distinct row
+        of a batch once — :meth:`plan_pull`, :meth:`lookup_distinct`,
+        :meth:`apply_held` — in place of :meth:`lookup` + :meth:`apply`. Read
+        from what the store is: on one shard the owner's distinct rows are
+        the batch's, known from the ids alone; across shards they are known
+        only after the row exchange, and the 'off' tier promises the legacy
+        program."""
+        return self.k == 1 and self.fused_tier != "off"
+
+    def plan_pull(self, ids: jax.Array) -> PullPlan:
+        """The dedupe of a step's ``ids`` (any shape), made before the loss:
+        the reference's worker sorts its keys, pulls the distinct ones and
+        pushes the same list. An id outside the table is filler to the plan,
+        as it is to :meth:`apply`'s owner mask."""
+        ids = ids.reshape(-1)
+        with jax.named_scope(phases.ROW_DEDUPE):
+            ok = (ids >= 0) & (ids < self.rows_per_shard)
+            masked = jnp.where(ok, ids, -1)
+        rows = row_plan(masked, self.rows_per_shard)
+        return PullPlan(rows, pair_segments(rows)[:ids.shape[0]], ok)
+
+    def lookup_distinct(self, table: jax.Array, ids: jax.Array,
+                        plan: PullPlan) -> Tuple[jax.Array, jax.Array]:
+        """:meth:`lookup`'s rows, each distinct row gathered from the table
+        once: ``(rows, held)``, ``held`` being the distinct rows in the
+        plan's order (:func:`~ps_tpu.ops.sparse_apply.pull_plan`), which
+        :meth:`apply_held` takes back, and ``rows`` their expansion to the
+        pairs, a gather out of that batch-sized buffer. ``plan`` is
+        :meth:`plan_pull` of the same ``ids``."""
+        held = pull_plan(table, plan.rows)
+        with jax.named_scope(phases.LOOKUP):
+            rows = rows_of_pairs(held, plan.pair_seg).reshape(
+                ids.shape + (self.dim,))
+            # an id the table lacks has no row among the distinct ones: the
+            # loss reads for it what lookup reads, at lookup's price, and
+            # only in a step that meets one
+            rows = jax.lax.cond(
+                jnp.all(plan.ok), lambda rows: rows,
+                lambda rows: jnp.where(
+                    plan.ok.reshape(ids.shape)[..., None], rows,
+                    self.lookup(table, ids)), rows)
+        return rows, held
+
+    def apply_held(self, table: jax.Array, state: Any, plan: PullPlan,
+                   held: jax.Array, row_grads: jax.Array
+                   ) -> Tuple[jax.Array, Any, jax.Array]:
+        """:meth:`apply` of the planned ids' ``row_grads`` [N, D] to the rows
+        :meth:`lookup_distinct` holds: the same sums in the same order, the
+        same rule and the same scatters, and no second gather of the table,
+        which nothing has written in between. Returns what :meth:`apply`
+        returns, bit for bit."""
+        with jax.named_scope(phases.ROW_APPLY):
+            g = jnp.where(plan.ok[:, None], row_grads, 0).astype(jnp.float32)
+            table, state, applied = apply_plan(
+                table, state, plan.rows, segment_sums(plan.rows, g),
+                self._opt, held)
+            # one shard and no exchange: nothing to drop, nothing to reduce
+            return table, state, jnp.stack([jnp.int32(0), applied])
+
     # -- eager PS API (the reference's worker-side protocol surface) ---------
 
     @property
@@ -337,10 +461,14 @@ class SparseEmbedding:
         returns, and what the gradient with respect to it weighs."""
         return n_ids * self.dim * np.dtype(self.dtype).itemsize
 
-    def count_pull(self, n_ids: int) -> None:
+    def count_pull(self, n_ids: int, held: bool = False) -> None:
         """Count a lookup of ``n_ids`` rows: :meth:`pull`'s, or that of a
-        program that gathers the rows itself (ps_tpu/kv/fused.py)."""
+        program that gathers the rows itself (ps_tpu/kv/fused.py).
+        ``held``: a :meth:`lookup_distinct`, whose gathered rows are counted
+        when its push reports them (:meth:`adopt_push`)."""
         self.bytes_pulled += self.rows_nbytes(n_ids)
+        if not held:
+            self._rows_pulled += n_ids
 
     def push(self, ids, row_grads) -> None:
         """Send (ids, row_grads); server scatter-applies immediately."""
@@ -382,14 +510,15 @@ class SparseEmbedding:
         self.row_version[touched] = self.push_count
 
     def adopt_push(self, table: jax.Array, state: Any, counts,
-                   n_ids: int, nbytes: int) -> None:
-        """Take over what one :meth:`apply` of ``n_ids`` row gradients
-        weighing ``nbytes`` returned, and count it: the tail of
-        :meth:`push` and of the fused step (ps_tpu/kv/fused.py).
-        ``counts`` may stay on the device. ``row_version`` is not stamped
-        here: only :meth:`push` has the ids on the host."""
+                   n_ids: int, nbytes: int, held: bool = False) -> None:
+        """Take over what one :meth:`apply` (``held``: :meth:`apply_held`)
+        of ``n_ids`` row gradients weighing ``nbytes`` returned, and count
+        it: the tail of :meth:`push` and of the fused step
+        (ps_tpu/kv/fused.py). ``counts`` may stay on the device.
+        ``row_version`` is not stamped here: only :meth:`push` has the ids
+        on the host."""
         self._table, self._state = table, state
-        self.record_counts(counts)  # sync-free; read at log time
+        self.record_counts(counts, held)  # sync-free; read at log time
         self.bytes_pushed += nbytes
         self.push_count += 1
         # arithmetic only — each routed row is (id:int32 + dim f32 grads)
@@ -465,6 +594,7 @@ class SparseEmbedding:
             "rows_pushed": self.rows_pushed,
             "dropped_rows": self.dropped_rows,
             "rows_applied": self.rows_applied,
+            "rows_pulled": self.rows_pulled,
         }
         ckpt.save(path, arrays, meta)
 
@@ -511,7 +641,8 @@ class SparseEmbedding:
         self._counts_base = np.array(
             [meta.get("dropped_rows", 0), meta.get("rows_applied", 0)],
             np.int64)
-        self._counts_pending = []
+        self._counts_pending, self._held_pending = [], []
+        self._rows_pulled = int(meta.get("rows_pulled", 0))
         return self._table
 
 

@@ -428,3 +428,74 @@ def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache,
     assert memory.temp_size_in_bytes < 3.5e9
     assert (memory.argument_size_in_bytes + memory.output_size_in_bytes
             + memory.temp_size_in_bytes) < 0.6 * 17.18e9
+
+
+@pytest.mark.parametrize("dim,rule", [(32, "adagrad"), (1, "sgd")],
+                         ids=["deep", "wide"])
+def test_a_tables_distinct_pull_and_held_push_compile_at_the_cells_shape(
+        dim, rule, one_chip, no_compile_cache, capsys):
+    """``widedeep-criteo.b4096.zipf``: one table's ``plan_pull`` ->
+    ``lookup_distinct`` -> a loss -> ``apply_held`` as the composite step
+    runs them on one chip, at ``f32[33800000, dim]`` and 106,496 pairs. The
+    donated table is written where it came in and in the layout it came in
+    with, rows minor; no ``copy`` and no ``transpose`` makes a table-sized
+    array (the dim-1 table's re-layout to a vector and back is the
+    ``reduce`` and the loop PERF.md §5 counts, 1.9 ms a step, before this
+    pull as after); and the gather of all N pairs stands in a
+    ``conditional``'s branch alone. Prints the layout the compiler gave the
+    batch-sized buffer of held rows (PERF.md §7 row 13)."""
+    import ps_tpu as ps
+    from ps_tpu.kv.sparse import SparseEmbedding
+
+    rows, batch, features = 33_800_000, 4096, 26
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ps.init(backend="tpu", mesh_shape={"data": 1})
+    try:
+        emb = SparseEmbedding(rows, dim, optimizer=rule, learning_rate=0.05)
+        assert emb.pulls_distinct
+        table = on_chip((rows, dim))
+        state = jax.tree_util.tree_map(
+            lambda x: on_chip(x.shape, x.dtype),
+            jax.eval_shape(emb._opt.init, table))
+
+        def pull_and_push(table, state, ids, weight):
+            plan = emb.plan_pull(ids)
+            pulled, held = emb.lookup_distinct(table, ids, plan)
+            loss, grads = jax.value_and_grad(lambda r: jnp.sum(
+                jnp.tanh(r.astype(jnp.bfloat16).astype(jnp.float32))
+                * weight))(pulled)
+            return emb.apply_held(table, state, plan, held,
+                                  grads.reshape(-1, dim)) + (loss,)
+
+        compiled = jax.jit(pull_and_push, donate_argnums=(0, 1)).lower(
+            table, state, on_chip((batch, features), jnp.int32),
+            on_chip((batch, features, dim))).compile()
+    finally:
+        ps.shutdown()
+    text = compiled.as_text()
+    head = text.splitlines()[0]
+    tiles = "T(8,128)" if dim > 1 else "T(1,128)"
+    stored = f"f32[{rows},{dim}]{{0,1:{tiles}}}"
+    assert f"entry_computation_layout={{({stored}," in head
+    assert f")->({stored}," in head
+    assert "{0}: (0, {}, may-alias)" in head
+    table_sized = [ln for ln in text.splitlines() if re.search(
+        rf"= \w+\[(1,1,)?{rows}[\],]\S* (copy|copy-start|transpose)\(", ln)]
+    assert not table_sized, table_sized
+    # every pair's row out of the table: only where an id lacks one
+    n = batch * features
+    whole = [ln for ln in text.splitlines()
+             if " gather(" in ln and f"[{batch},{features}" in ln]
+    assert whole and all("cond/branch" in ln for ln in whole), whole
+    pull = next(ln for ln in text.splitlines()
+                if " while(" in ln and 'ps.lookup/while"' in ln)
+    held = re.findall(rf"f32\[{n},{dim}\]\{{[^}}]*\}}" if dim > 1
+                      else rf"f32\[{n}\]\{{[^}}]*\}}",
+                      pull[:pull.index(" while(")])
+    assert len(held) == 1, pull
+    with capsys.disabled():
+        print(f"\nheld rows of the dim-{dim} table, as the v5e compiler "
+              f"lays them out in the pull's loop: {held[0]}")

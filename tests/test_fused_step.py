@@ -220,6 +220,165 @@ def test_sharded_step_hands_back_the_shardings_it_took(kind):
         jax.monitoring.unregister_event_duration_listener(heard)
 
 
+# -- the pull of the distinct rows (ISSUE 55) -----------------------------------
+
+#: the Wide&Deep cell's rehearsal size (benchmark/configs, "rehearse")
+WD_BATCH, WD_FEATURES, WD_VOCAB, WD_DIM = 64, 26, 1000, 8
+WD_ROWS, WD_PAIRS = WD_FEATURES * WD_VOCAB, WD_BATCH * WD_FEATURES
+
+
+def _wide_deep(devices, tier, names=("deep", "wide"), ids_fn=None):
+    """The lowered text of the composite step of ``names``' tables at the
+    rehearsal size: ``ids_fn`` defaults to the model's, which hands every
+    table the same array."""
+    from ps_tpu.models.wide_deep import (WideDeep, WideDeepConfig,
+                                         make_ids_fn, make_wide_deep_loss_fn)
+
+    ps.init(backend="tpu", mesh_shape={"data": devices})
+    cfg = WideDeepConfig(num_dense=13, num_sparse=WD_FEATURES,
+                         per_feature_vocab=WD_VOCAB, embed_dim=WD_DIM,
+                         mlp=(32, 16))
+    model = WideDeep(cfg)
+    shape = (2, cfg.num_sparse, cfg.embed_dim)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, cfg.num_dense)),
+                        jnp.zeros(shape), jnp.zeros(shape[:2] + (1,))
+                        )["params"]
+    dense = ps.KVStore(optimizer="adam", learning_rate=0.001,
+                       placement="sharded")
+    dense.init(params)
+    tables = {}
+    for i, (name, dim, rule) in enumerate((("deep", WD_DIM, "adagrad"),
+                                           ("wide", 1, "sgd"))):
+        if name in names:
+            tables[name] = SparseEmbedding(cfg.total_rows, dim,
+                                           optimizer=rule, fused_apply=tier)
+            tables[name].init(jax.random.key(i + 1))
+    loss_fn = make_wide_deep_loss_fn(model)
+    if "wide" not in names:
+        def loss_fn(p, rows, b, whole=loss_fn):
+            return whole(p, {**rows, "wide": rows["deep"][..., :1]}, b)
+    run = ps.make_composite_step(dense, tables, loss_fn,
+                                 ids_fn or make_ids_fn(cfg))
+    rng = np.random.default_rng(0)
+    batch = {"dense": rng.normal(size=(WD_BATCH, 13)).astype(np.float32),
+             "sparse": rng.integers(0, WD_VOCAB, (WD_BATCH, WD_FEATURES)
+                                    ).astype(np.int32),
+             "label": rng.integers(0, 2, WD_BATCH).astype(np.float32)}
+    text = run.lower(dense.shard_batch(batch)).as_text()
+    ps.shutdown()
+    return text.splitlines(), cfg
+
+
+def _indent(line):
+    return len(line) - len(line.lstrip())
+
+
+def _enclosing(lines, i):
+    """The op (or function) whose region line ``i`` stands in: the nearest
+    line above that is indented less and is no ``cond {`` / ``} do {`` /
+    ``}, {`` between an op's regions."""
+    for j in range(i - 1, -1, -1):
+        if _indent(lines[j]) < _indent(lines[i]) and (
+                " = " in lines[j] or "func.func" in lines[j]):
+            return lines[j]
+    return ""
+
+
+def _gathers_of(lines, operand):
+    """``(gather line, the op enclosing it or its function's call)`` of
+    every ``stablehlo.gather`` that reads an ``operand``-typed tensor;
+    ``jnp.take`` lowers to a private function around its gather."""
+    found = []
+    for i, ln in enumerate(lines):
+        if "stablehlo.gather" in ln and f": (tensor<{operand}>" in ln:
+            func = _enclosing(lines, i)
+            if "func.func private" in func:
+                name = re.search(r"@(\w+)\(", func).group(1)
+                calls = [j for j, c in enumerate(lines)
+                         if f"call @{name}(" in c]
+                found += [(ln, _enclosing(lines, j)) for j in calls]
+            else:
+                found.append((ln, func))
+    return found
+
+
+def test_composite_step_on_one_chip_gathers_a_tables_distinct_rows_once():
+    """At the rehearsal size, one chip: the deep table is gathered by one
+    ``gather`` of ``chunk_len(N)`` slots, ascending and stated so, in a
+    ``while`` whose trip count comes from the data; the push has
+    no gather of it. The only other one stands in the branch of a ``case``
+    for a batch with an id the table lacks, and reads all N pairs as
+    ``lookup`` does."""
+    from ps_tpu.ops.sparse_apply import chunk_len
+
+    lines, _ = _wide_deep(1, "auto")
+    c = chunk_len(WD_PAIRS)
+    assert WD_PAIRS > c  # a loop, not the one-chunk path
+    gathers = _gathers_of(lines, f"{WD_ROWS}x{WD_DIM}xf32")
+    in_case = [g for g, op in gathers if "stablehlo.case" in op]
+    walked = [(g, op) for g, op in gathers if "stablehlo.case" not in op]
+    assert len(in_case) == 1 and len(walked) == 1, gathers
+    assert f"tensor<{WD_BATCH}x{WD_FEATURES}x{WD_DIM}xf32>" in in_case[0]
+    gather, loop = walked[0]
+    assert f"-> tensor<{c}x{WD_DIM}xf32>" in gather
+    # (StableHLO's gather has no attribute for ``unique_indices``: the
+    # hint is in the jaxpr and in the transpose's scatter only)
+    assert "indices_are_sorted = true" in gather
+    assert "stablehlo.while" in loop
+    # the bound the counter is compared with enters the loop as a value
+    # computed from the ids, not as a constant
+    at = lines.index(loop)
+    compare = next(ln for ln in lines[at:] if "stablehlo.compare" in ln)
+    bound = re.search(r"LT, %\w+, (%\w+),", compare).group(1)
+    init = re.search(rf"{re.escape(bound)} = (%\w+)", loop).group(1)
+    assert not init.startswith("%c"), loop
+
+
+def test_tables_handed_one_id_array_share_one_plan():
+    """``ids_fn`` hands ``deep`` and ``wide`` the same array: the step
+    sorts for one table (the ids, the distinct ids to the front, the
+    pairs' slots) however many tables read it; handed two arrays it sorts
+    for each."""
+    def sorts(lines):
+        # ``jnp.sort`` is a call of a private function around its op
+        return sum("call @sort" in ln or (
+            "stablehlo.sort" in ln
+            and "func.func private" not in _enclosing(lines, i))
+            for i, ln in enumerate(lines))
+
+    one, cfg = _wide_deep(1, "auto", names=("deep",))
+    both, _ = _wide_deep(1, "auto")
+    apart, _ = _wide_deep(1, "auto", ids_fn=lambda b: {
+        "deep": cfg.global_ids(b["sparse"]),
+        "wide": cfg.global_ids(b["sparse"])})
+    assert sorts(one) == 3
+    assert sorts(both) == sorts(one)
+    assert sorts(apart) == 2 * sorts(one)
+
+
+@pytest.mark.parametrize("devices,tier", [(8, "auto"), (1, "off"),
+                                          (8, "off")])
+def test_composite_step_across_chips_or_off_looks_every_pair_up(devices, tier):
+    """Where the owner's distinct rows are known only after the exchange,
+    and on the tier that promises the legacy program, the step is
+    ``lookup`` + ``apply`` as before: one gather of all N pairs a table,
+    at the top of the program, no plan before the loss and no ``case``.
+    (Against the parent commit's text, by hand, PR 55: the 'off' tier's
+    byte for byte on one device and on eight; the fused tier's on eight
+    but for the order in which ``fused_sparse_apply`` states its sort and
+    its sums.)"""
+    lines, _ = _wide_deep(devices, tier)
+    assert not [ln for ln in lines if "stablehlo.case" in ln]
+    pulls = [(g, op) for g, op in _gathers_of(
+        lines, f"{WD_ROWS}x{WD_DIM}xf32")
+        if f"tensor<{WD_BATCH}x{WD_FEATURES}x{WD_DIM}xf32>" in g]
+    assert len(pulls) == 1
+    assert "func.func public @main" in pulls[0][1]
+    first_sort = next((i for i, ln in enumerate(lines)
+                       if "stablehlo.sort" in ln), len(lines))
+    assert first_sort > lines.index(pulls[0][1])
+
+
 # -- the seam -----------------------------------------------------------------
 
 def _private_attributes_of_others(tree):

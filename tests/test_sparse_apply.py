@@ -23,11 +23,13 @@ import ps_tpu as ps
 from ps_tpu.config import Config
 from ps_tpu.kv.sparse import SparseEmbedding, _dedupe_rows
 from ps_tpu.ops.sparse_apply import (
-    batch_segment_sum,
     chunk_len,
     fused_sparse_apply,
     hbm_bytes_model,
+    pair_segments,
     resolve_tier,
+    row_plan,
+    segment_sums,
 )
 from ps_tpu.optim.rowwise import make_rowwise
 
@@ -104,16 +106,29 @@ def test_fused_parity_sharded_a2a():
     np.testing.assert_array_equal(got_t, base_t)
 
 
+def _plan_and_sums(ids, grads, num_rows=V):
+    """``(uids, gsum, cnt, n_unique, idx, pair_seg)`` of a push's two
+    halves, the plan of the ids and the sums of the gradients, as numpy."""
+    plan = row_plan(ids, num_rows)
+    return tuple(map(np.asarray, (
+        plan.uids, segment_sums(plan, grads), plan.cnt, plan.n_unique,
+        plan.idx, pair_segments(plan))))
+
+
 def test_batch_segment_sum_orders_and_counts():
     """The compact contract: the U distinct real ids in front, ascending,
-    each with its duplicates' sum and count; filler behind."""
+    each with its duplicates' sum and count; filler behind, the list
+    padded with it to whole chunks (6 pairs: one chunk of 8)."""
     ids = jnp.asarray([5, -1, 2, 5, 5, 2], jnp.int32)
     grads = jnp.asarray(np.arange(6 * D, dtype=np.float32).reshape(6, D))
-    uids, gsum, cnt, n_unique = map(np.asarray,
-                                    batch_segment_sum(ids, grads))
+    uids, gsum, cnt, n_unique, idx, pair_seg = _plan_and_sums(ids, grads)
     assert n_unique == 2 and n_unique.dtype == np.int32
-    assert uids.tolist() == [2, 5, -1, -1, -1, -1]
-    assert cnt.tolist() == [2, 3, 0, 0, 0, 0]
+    assert uids.tolist() == [2, 5, -1, -1, -1, -1, -1, -1]
+    assert cnt.tolist() == [2, 3, 0, 0, 0, 0, 0, 0]
+    # what the loop gathers and scatters at: filler past the table's end
+    assert idx.tolist() == [2, 5] + [V + i for i in range(2, 8)]
+    # each pair's slot among the distinct rows; filler's is the slot after
+    assert pair_seg.tolist() == [1, 2, 0, 1, 1, 0, 2, 2]
     g = np.asarray(grads)
     # duplicates summed in arrival order (the stable sort keeps it)
     np.testing.assert_array_equal(gsum[0], g[2] + g[5])
@@ -122,9 +137,9 @@ def test_batch_segment_sum_orders_and_counts():
     np.testing.assert_array_equal(gsum[2], g[1])
     assert np.all(gsum[3:] == 0)
     # no real id at all: nothing in front
-    none = batch_segment_sum(jnp.full((4,), -1, jnp.int32),
-                             jnp.zeros((4, D), jnp.float32))
-    assert int(none[3]) == 0 and np.asarray(none[0]).tolist() == [-1] * 4
+    none = _plan_and_sums(jnp.full((4,), -1, jnp.int32),
+                          jnp.zeros((4, D), jnp.float32))
+    assert int(none[3]) == 0 and none[0].tolist() == [-1] * 8
 
 
 # -- the loop over the distinct rows (ISSUE 31) ------------------------------
@@ -341,6 +356,115 @@ def test_rows_applied_through_composite_step(tier):
         assert len(emb._counts_pending) == 5  # nothing read on the way
         assert (emb.rows_applied, emb.rows_pushed) == (want, 5 * 16 * 3)
         assert emb.dropped_rows == 0
+    ps.shutdown()
+
+
+# -- the step that pulls each distinct row once (ISSUE 55) --------------------
+
+def _step_ids(case, rng):
+    """The id arrays of the parity cases, 2-D as ``ids_fn`` hands them."""
+    if case == "ragged":  # N no multiple of the chunk: padded with filler
+        ids = (rng.zipf(1.2, size=(250, 10)) - 1) % V_LOOP
+        assert ids.size % chunk_len(ids.size)
+        return ids.astype(np.int32)
+    shape = (N_LOOP // 12, 12)
+    if case == "distinct":
+        return rng.permutation(V_LOOP)[:N_LOOP].reshape(shape).astype(np.int32)
+    if case == "same":
+        return np.full(shape, 7, np.int32)
+    ids = ((rng.zipf(1.2, size=shape) - 1) % V_LOOP).astype(np.int32)
+    ids[0, 0] = V_LOOP - 1  # the table's last row, which a clipped slot reads
+    if case == "filler":
+        ids[rng.random(shape) < 0.1] = -1
+    if case == "beyond":  # lookup reads NaN past the end, and wraps before 0
+        ids[rng.random(shape) < 0.01] = V_LOOP + 3
+        ids[rng.random(shape) < 0.01] = -5
+        ids[3, 3] = -V_LOOP - 2
+    return ids
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "adagrad", "adam"])
+@pytest.mark.parametrize("case", ["zipf", "distinct", "same", "filler",
+                                  "ragged", "beyond"])
+def test_distinct_pull_and_held_push_equal_lookup_and_apply(optimizer, case):
+    """``plan_pull`` + ``lookup_distinct`` + ``apply_held`` against
+    ``lookup`` + ``apply`` on the same ids, bit for bit: the rows the loss
+    reads (its value and its gradient with respect to them say so), the
+    table, the state and the counts. Values in eighths, so that no sum
+    depends on how a backend fuses it (see ``LR_EXACT``)."""
+    rng = np.random.default_rng(55)
+    ids = _step_ids(case, rng)
+    table0 = _eighths(rng, (V_LOOP, D))
+    weight = jnp.asarray(_eighths(rng, ids.shape + (D,)))
+    ps.init(backend="tpu", mesh_shape={"data": 1})
+    emb = SparseEmbedding(V_LOOP, D, optimizer=optimizer,
+                          learning_rate=LR_EXACT)
+    emb.init(table0)
+    assert emb.pulls_distinct
+
+    def loss_fn(rows):
+        return jnp.sum(weight * rows * rows)
+
+    def by_pairs(table, state, ids):
+        rows = emb.lookup(table, ids)
+        loss, grads = jax.value_and_grad(loss_fn)(rows)
+        return emb.apply(table, state, ids.reshape(-1),
+                         grads.reshape(-1, D)) + (loss, grads, rows)
+
+    def by_distinct_rows(table, state, ids):
+        plan = emb.plan_pull(ids)
+        rows, held = emb.lookup_distinct(table, ids, plan)
+        loss, grads = jax.value_and_grad(loss_fn)(rows)
+        return emb.apply_held(table, state, plan, held,
+                              grads.reshape(-1, D)) + (loss, grads, rows)
+
+    args = (emb.table, emb.state(), jnp.asarray(ids))
+    want = jax.device_get(jax.jit(by_pairs)(*args))
+    got = jax.device_get(jax.jit(by_distinct_rows)(*args))
+    jax.tree_util.tree_map(np.testing.assert_array_equal, got, want)
+    real = ids[(ids >= 0) & (ids < V_LOOP)]
+    assert got[2].tolist() == [0, np.unique(real).size]
+    assert np.isnan(got[3]) == (case == "beyond")
+    untouched = np.setdiff1d(np.arange(V_LOOP), real)
+    np.testing.assert_array_equal(got[0][untouched], table0[untouched])
+    if real.size:
+        assert not np.array_equal(got[0], table0)
+    ps.shutdown()
+
+
+@pytest.mark.parametrize("devices,tier,distinct", [
+    (1, "jax", True), (1, "off", False), (8, "jax", False)])
+def test_rows_pulled_after_three_steps(devices, tier, distinct):
+    """``rows_pulled`` counts what the store's pulls gathered out of the
+    table: a step on one chip the distinct rows of its batch, from the
+    count its push reports and with nothing read on the way; a step that
+    looks every pair up, and the eager ``pull``, the pairs."""
+    ps.init(backend="tpu", mesh_shape={"data": devices})
+    dense = ps.KVStore(optimizer="sgd", learning_rate=0.1)
+    dense.init({"w": jnp.ones((D, 1))})
+    emb = SparseEmbedding(V, D, optimizer="adagrad", fused_apply=tier)
+    emb.init(_table0())
+    assert emb.pulls_distinct == distinct
+    step = ps.make_composite_step(
+        dense, {"emb": emb},
+        lambda p, rows, b: jnp.mean((rows["emb"] @ p["w"]) ** 2),
+        lambda b: {"emb": b["ids"]})
+    rng = np.random.default_rng(9)
+    pulled = 0
+    for _ in range(3):
+        ids = ((rng.zipf(1.5, size=(16, 3)) - 1) % V).astype(np.int32)
+        step(dense.shard_batch({"ids": ids}))
+        pulled += np.unique(ids).size if distinct else ids.size
+    assert len(emb._held_pending) == (3 if distinct else 0)
+    assert len(emb._counts_pending) == (0 if distinct else 3)
+    assert emb.rows_pulled == pulled
+    assert (emb.rows_pulled == emb.rows_applied) == distinct
+    assert emb.rows_pushed == 3 * 48
+    # the worker received every pair's row, however few were gathered
+    assert emb.bytes_pulled == 3 * 48 * D * 4
+    emb.pull(np.arange(5))
+    assert emb.rows_pulled == pulled + 5
+    assert emb.rows_pulled == pulled + 5  # reading twice counts once
     ps.shutdown()
 
 
