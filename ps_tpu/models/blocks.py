@@ -9,9 +9,12 @@ from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
+from ps_tpu.ops.gated_conv import conv_silu
+from ps_tpu.ops.ssd import ssd
 
 
 def rms_norm(x, scale, eps):
@@ -153,6 +156,46 @@ def mla_block(lp: Dict, x, config, attn_fn: Callable):
     with jax.named_scope(phases.ATTN_FULL):
         a = attn_fn(q, k, kv[..., nope:], causal=True)
     return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
+
+
+def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
+                state: int, chunk: int, eps: float):
+    """The Mamba-2 mixer of the normed activations ``x`` [B, S, D], for
+    ``heads`` heads of ``head_dim`` on ``groups`` groups of B and C over a
+    state of ``state``: ``[z | xBC | dt] = x W_in`` (which bears the name
+    'mamba_in'); the x, B and C channels through the causal taps, the bias
+    and the SiLU of ``ops/gated_conv.py::conv_silu``; ``dt = softplus(dt +
+    dt_bias)`` in f32; the scan in its chunked form (``ops/ssd.py``, chunks
+    of ``chunk``), whose output bears the name 'mamba_ssd'; then, in f32, the
+    skip ``D x``, the gate ``silu(z)`` first and an RMSNorm over each group's
+    channels after it (a share that holds whole groups has the uncut mixer's
+    norm over them, exactly; at one group the norm is over all ``heads *
+    head_dim`` channels); the out projection.
+    Nemotron-H's (a share of the heads: what comes out is that share's part
+    of a sum) and Granite-4.0-H's (whole)."""
+    b, s, _ = x.shape
+    inner = heads * head_dim
+    conv_dim = inner + 2 * groups * state
+    projected = checkpoint_name(
+        x @ lp["in_proj"]["kernel"].astype(x.dtype), "mamba_in")
+    z, xbc, dt = jnp.split(projected, [inner, inner + conv_dim], axis=-1)
+    with jax.named_scope(phases.MAMBA_CONV):
+        xbc = conv_silu(xbc, lp["conv"]["kernel"], lp["conv"]["bias"])
+    xs, b_in, c_in = jnp.split(xbc, [inner, inner + groups * state], axis=-1)
+    xs = xs.reshape(b, s, heads, head_dim)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    with jax.named_scope(phases.MAMBA_SSD):
+        y = checkpoint_name(ssd(
+            xs, dt, -jnp.exp(lp["A_log"]), b_in.reshape(b, s, groups, -1),
+            c_in.reshape(b, s, groups, -1), chunk=min(chunk, s)), "mamba_ssd")
+    with jax.named_scope(phases.MAMBA_GATE):
+        y = y.astype(jnp.float32) + lp["D"][:, None] * xs.astype(jnp.float32)
+        # the gate first, then the norm over each group's channels
+        y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = rms_norm(y.reshape(b, s, groups, -1),
+                     lp["out_norm"]["scale"].reshape(groups, -1), eps)
+    return y.reshape(b, s, inner).astype(x.dtype) \
+        @ lp["out_proj"]["kernel"].astype(x.dtype)
 
 
 def dense_ffn(lp: Dict, x):

@@ -27,13 +27,13 @@ Pure functions over a parameter dict, as ``models/kimi_linear.py``;
 plain reference's docstring (``benchmark/families/nemotron_h_reference.py``),
 which this module is held to. How they are computed here:
 
-- ``mamba_block``: ``[z | xBC | dt] = u W_in``; the x, B and C channels through
-  the four causal taps, the bias and the SiLU of
-  ``ops/gated_conv.py::conv_silu``; ``dt = softplus(dt + dt_bias)`` in f32; the
-  scan in its chunked form (``ops/ssd.py``, chunks of ``chunk_size``) plus the
-  skip ``D x``; the gate first, then an RMSNorm over each group's channels
-  (so a share's norm is the uncut mixer's over that group, exactly); the out
-  projection.
+- ``mamba_block`` (``models/blocks.py``'s, which Granite-4.0-H calls too):
+  ``[z | xBC | dt] = u W_in``; the x, B and C channels through the four
+  causal taps, the bias and the SiLU of ``ops/gated_conv.py::conv_silu``;
+  ``dt = softplus(dt + dt_bias)`` in f32; the scan in its chunked form
+  (``ops/ssd.py``, chunks of ``chunk_size``) plus the skip ``D x``; the gate
+  first, then an RMSNorm over each group's channels (so a share's norm is
+  the uncut mixer's over that group, exactly); the out projection.
 - ``attention_block``: q on the held query heads, k and v on the held K/V
   heads, no position, no bias, scale ``head_dim ** -0.5``; with
   ``attn='flash'`` K and V enter the Pallas kernel at their own head count.
@@ -106,14 +106,13 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ps_tpu.models import blocks
 from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
 from ps_tpu.models.blocks import (WHOLE_WINDOW, make_attn_fn, rms_norm,
                                   token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.flash_attention import KEPT
-from ps_tpu.ops.gated_conv import conv_silu
-from ps_tpu.ops.ssd import ssd
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,32 +275,14 @@ def init_params(key, config: NemotronHConfig) -> Dict:
 
 
 def mamba_block(lp: Dict, x, config: NemotronHConfig):
-    """The Mamba-2 mixer on normed activations ``x`` [B, S, D]: the held
-    heads' part of the sum after the out projection."""
+    """The Mamba-2 mixer on normed activations ``x`` [B, S, D]
+    (``models/blocks.py::mamba_block`` at the held heads and groups): the
+    held heads' part of the sum after the out projection."""
     c = config
-    b, s, _ = x.shape
-    heads, groups, inner = c.mamba_num_heads, c.n_groups, c.mamba_inner
-    projected = checkpoint_name(
-        x @ lp["in_proj"]["kernel"].astype(x.dtype), "mamba_in")
-    z, xbc, dt = jnp.split(projected, [inner, inner + c.conv_dim], axis=-1)
-    with jax.named_scope(phases.MAMBA_CONV):
-        xbc = conv_silu(xbc, lp["conv"]["kernel"], lp["conv"]["bias"])
-    xs, b_in, c_in = jnp.split(
-        xbc, [inner, inner + groups * c.ssm_state_size], axis=-1)
-    xs = xs.reshape(b, s, heads, c.mamba_head_dim)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
-    with jax.named_scope(phases.MAMBA_SSD):
-        y = ssd(xs, dt, -jnp.exp(lp["A_log"]),
-                b_in.reshape(b, s, groups, -1), c_in.reshape(b, s, groups, -1),
-                chunk=min(c.chunk_size, s))
-    y = y.astype(jnp.float32) + lp["D"][:, None] * xs.astype(jnp.float32)
-    # the gate first, then the norm over each group's channels
-    y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(y.reshape(b, s, groups, -1),
-                 lp["out_norm"]["scale"].reshape(groups, -1),
-                 c.layer_norm_epsilon)
-    return y.reshape(b, s, inner).astype(x.dtype) \
-        @ lp["out_proj"]["kernel"].astype(x.dtype)
+    return blocks.mamba_block(
+        lp, x, heads=c.mamba_num_heads, head_dim=c.mamba_head_dim,
+        groups=c.n_groups, state=c.ssm_state_size, chunk=c.chunk_size,
+        eps=c.layer_norm_epsilon)
 
 
 def attention_block(lp: Dict, x, config: NemotronHConfig, attn_fn: Callable):

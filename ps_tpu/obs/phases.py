@@ -6,7 +6,7 @@ the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
 ``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
 ``models/mellum.py``, ``models/sdar.py``, ``models/joyai.py``,
-``models/blocks.py``, ``ops/moe.py``) and land in the
+``models/granite_h.py``, ``models/blocks.py``, ``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
@@ -89,14 +89,18 @@ KIMI_SCOPES = MOE_SCOPES + (FFN, KDA, KDA_CONV, KDA_CORE, MOE_SHARED,
 # -- scopes of Nemotron-H (models/nemotron_h.py), beside the six ----------------
 # Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy. The
 # attention layer is under ATTN, the shared expert under MOE_SHARED.
-# MAMBA_CONV and MAMBA_SSD nest under MAMBA, so MAMBA's time holds them.
+# MAMBA_CONV, MAMBA_SSD and MAMBA_GATE nest under MAMBA, so MAMBA's time holds
+# them; the three are opened by ``models/blocks.py::mamba_block``, the one
+# mixer of this model and of Granite-4.0-H. MAMBA_GATE has no metric of its
+# own yet and is read inside ``decoder.mamba_ms``.
 MAMBA = "ps.mamba"                # the Mamba-2 mixer: in projection, filter, scan, gated norm, out projection
 MAMBA_CONV = "ps.mamba/conv"      # the depthwise causal filter over x, B and C, its bias and SiLU
 MAMBA_SSD = "ps.mamba/ssd"        # ops/ssd.py alone: the chunked scalar-decay scan
+MAMBA_GATE = "ps.mamba/gate"      # the D skip, silu(z), their product and the gated norm: f32 elementwise over the mixer's channels
 MOE_LATENT = "ps.moe/latent"      # the two projections between the model's width and the experts' latent
 
-NEMOTRON_SCOPES = MOE_SCOPES + (MAMBA, MAMBA_CONV, MAMBA_SSD, MOE_LATENT,
-                                MOE_SHARED)
+NEMOTRON_SCOPES = MOE_SCOPES + (MAMBA, MAMBA_CONV, MAMBA_SSD, MAMBA_GATE,
+                                MOE_LATENT, MOE_SHARED)
 
 # -- scopes of Trinity (models/trinity.py), beside the six, FFN and MOE_SHARED ---
 # Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy.
@@ -145,6 +149,13 @@ MTP_JOIN = "ps.mtp/join"          # the two norms, the second embedding lookup, 
 
 JOYAI_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_FULL, ATTN_LATENT,
                              ATTN_ROPE, MTP, MTP_JOIN)
+
+# -- scopes of Granite-4.0-H (models/granite_h.py): no expert, so not the six -------
+# A dense decoder of two parts a layer: the mixer under MAMBA (with its three
+# inner scopes) or ATTN, the SwiGLU every layer has under FFN, the tied head
+# under HEAD. All but MAMBA_GATE are read by
+# ``benchmark/layer_metrics/decoder.py``, which keeps its own copy.
+GRANITE_SCOPES = (ATTN, HEAD, FFN, MAMBA, MAMBA_CONV, MAMBA_SSD, MAMBA_GATE)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

@@ -34,9 +34,20 @@ gradient. A scalar decay makes ``L`` a [Q, Q] matrix a head: no sub-blocks
 are needed, where KDA's per-channel decay forces them.
 
 **Differentiated by autodiff.** What it keeps for the backward pass is the
-caller's to bound: ``L`` is 64 KB a head a chunk in f32, so
-``models/nemotron_h.py`` runs each layer under one ``jax.checkpoint`` and
-between the layers only the residual stream lives on.
+caller's to bound: ``L`` is ``4 Q^2`` bytes a head a chunk in f32 (64 KB at
+chunks of 128, 256 KB at 256), so a model runs each layer under one
+``jax.checkpoint`` and between the layers only the residual stream lives on.
+Over all heads a copy of ``L`` is 67 MB at Nemotron-H's share (16 heads, 64
+chunks of 128) and 537 MB at Granite-4.0-H's whole mixer (64 heads on one
+group, 32 chunks of 256), and one copy is what the gradient holds: XLA fuses
+the masked exponential into the product that reads it, so the gradient at
+``[1, 8192, 64, 64]`` compiles to 0.49e9 B of temporaries
+(``tests/test_chip_compile.py``). A form over blocks of 16 heads, each under a
+checkpoint of its own in a ``lax.map``, held 0.04e9 B and ran the Granite cell
+0.8% faster; it was not kept for that (``PERF.md`` section 6, PR 56). A layer
+whose policy keeps the scan's output (``models/blocks.py`` names it
+'mamba_ssd') recomputes less of the form: Granite's cell runs 3.2% faster for
+67 MB a layer.
 
 Precision: the cumulated sums, the decays, ``dt x`` and the carried state in
 f32; the four products take their operands in ``x``'s dtype (the

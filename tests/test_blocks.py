@@ -27,9 +27,12 @@ REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
               "sdar": ("pallas", "logaddexp"),
               # JoyAI's rotation is a product of pairs: no kernel, no roll
               # of lanes, no rotation of halves
-              "joyai": ("pallas", "roll(", "rotate_half")}
+              "joyai": ("pallas", "roll(", "rotate_half"),
+              # Granite-4.0-H's scan is token by token, its attention whole
+              # rows under the model's own multiplier
+              "granite_h": ("cumsum", "pallas")}
 MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
-          "mellum", "sdar", "joyai")
+          "mellum", "sdar", "joyai", "granite_h")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))
@@ -166,6 +169,85 @@ def test_the_shared_latent_block_is_kimis_to_the_bit_with_neither_option(attn):
 
     (_, out), grads = run(blocks.mla_block)
     (_, want), want_grads = run(_mla_as_kimi_had_it)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))
+    for g, r in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.max(jnp.abs(r.astype(jnp.float32)))) > 0
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(r, np.float32))
+
+
+# -- the Mamba-2 mixer two models share ----------------------------------------
+
+def _mamba_as_nemotron_had_it(lp, x, config):
+    """``models/nemotron_h.py::mamba_block`` as it stood before the block
+    moved to ``models/blocks.py`` (commit cf89cf5), letter for letter."""
+    from ps_tpu.models.blocks import rms_norm
+    from ps_tpu.ops.gated_conv import conv_silu
+    from ps_tpu.ops.ssd import ssd
+
+    c = config
+    b, s, _ = x.shape
+    heads, groups, inner = c.mamba_num_heads, c.n_groups, c.mamba_inner
+    projected = x @ lp["in_proj"]["kernel"].astype(x.dtype)
+    z, xbc, dt = jnp.split(projected, [inner, inner + c.conv_dim], axis=-1)
+    xbc = conv_silu(xbc, lp["conv"]["kernel"], lp["conv"]["bias"])
+    xs, b_in, c_in = jnp.split(
+        xbc, [inner, inner + groups * c.ssm_state_size], axis=-1)
+    xs = xs.reshape(b, s, heads, c.mamba_head_dim)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    y = ssd(xs, dt, -jnp.exp(lp["A_log"]),
+            b_in.reshape(b, s, groups, -1), c_in.reshape(b, s, groups, -1),
+            chunk=min(c.chunk_size, s))
+    y = y.astype(jnp.float32) + lp["D"][:, None] * xs.astype(jnp.float32)
+    y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))
+    y = rms_norm(y.reshape(b, s, groups, -1),
+                 lp["out_norm"]["scale"].reshape(groups, -1),
+                 c.layer_norm_epsilon)
+    return y.reshape(b, s, inner).astype(x.dtype) \
+        @ lp["out_proj"]["kernel"].astype(x.dtype)
+
+
+def test_the_shared_mamba_block_is_nemotrons_to_the_bit():
+    """``blocks.mamba_block`` at Nemotron-H's sizes (a share of the heads on
+    two B/C groups) gives the outputs and every gradient of the block that
+    model had, bit for bit, in the cell's bf16 over f32 leaves; both models
+    call the one block and neither holds a mixer of its own."""
+    import inspect
+
+    from ps_tpu.models import granite_h, nemotron_h
+
+    assert granite_h.mamba_block is blocks.mamba_block
+    for model in (granite_h, nemotron_h):
+        source = inspect.getsource(model)
+        assert "mamba_block(" in source and "ssd(" not in source
+        assert "conv_silu(" not in source
+    cfg = nemotron_h.NemotronHConfig(
+        hidden_size=64, mamba_num_heads=4, mamba_head_dim=8, n_groups=2,
+        ssm_state_size=16, chunk_size=32)
+    rng = np.random.default_rng(0)
+
+    def w(*shape):
+        return jnp.asarray(0.1 * rng.normal(size=shape), jnp.float32)
+
+    lp = {"in_proj": {"kernel": w(64, 32 + cfg.conv_dim + 4)},
+          "conv": {"kernel": w(cfg.conv_dim, 4), "bias": w(cfg.conv_dim)},
+          "dt_bias": w(4), "A_log": jnp.log(jnp.asarray(
+              rng.uniform(1, 16, size=4), jnp.float32)),
+          "D": 1 + w(4), "out_norm": {"scale": 1 + w(32)},
+          "out_proj": {"kernel": w(32, 64)}}
+    x = jnp.asarray(rng.normal(size=(2, 128, 64)), jnp.bfloat16)
+
+    def run(block):
+        def loss(lp, x):
+            out = block(lp, x, cfg)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(lp, x)
+
+    (_, out), grads = run(nemotron_h.mamba_block)
+    (_, want), want_grads = run(_mamba_as_nemotron_had_it)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(want, np.float32))
     for g, r in zip(jax.tree_util.tree_leaves(grads),

@@ -244,6 +244,31 @@ def test_chunked_ssd_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def test_the_scan_compiles_at_granites_shape(one_chip, no_compile_cache):
+    """``ops/ssd.py`` at the Granite cell's whole mixer, [1, 8192, 64, 64] on
+    one B/C group of state 128 in chunks of 256, forward and backward: plain
+    XLA, the state carried by ``while`` loops over the 32 chunks, and of the
+    [256, 256] decay matrices of all 64 heads (537 MB a copy in f32) one copy:
+    0.49e9 B of temporaries, under 5 / 8 GiB, not ISSUE 56's 2-2.5e9 (the
+    masked exponential is fused into the product that reads it)."""
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg(1, 8192, 64, 64), arg(1, 8192, 64, dtype=jnp.float32),
+            arg(64, dtype=jnp.float32), arg(1, 8192, 1, 128),
+            arg(1, 8192, 1, 128))
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(ssd(x, dt, a, b, c, chunk=256).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert " while(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2 ** 27
+
+
 #: (tokens, width of the rows, width of an expert, held, router width,
 #: picks, activation), the window's rows, and the temp bytes the same block
 #: compiled to before PR 40 (its row buffers 65,536 long), read once from

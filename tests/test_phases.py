@@ -161,7 +161,7 @@ def test_marks_change_no_instruction(kind, monkeypatch, no_compile_cache):
 #: the scopes the program opens that ``layer_metrics/decoder.py`` has no name
 #: for yet (PERF.md section 7, row 0)
 PROGRAMS_OWN = {phases.ATTN_INBLOCK, phases.ATTN_LATENT, phases.ATTN_ROPE,
-                phases.MTP, phases.MTP_JOIN}
+                phases.MTP, phases.MTP_JOIN, phases.MAMBA_GATE}
 
 
 def test_program_and_benchmark_share_their_names():
@@ -175,11 +175,12 @@ def test_program_and_benchmark_share_their_names():
                  "INPUT_SOURCE_WAIT", "INPUT_PRODUCE"):
         assert getattr(phases, name) == getattr(host, name)
     # the decoders' scopes: the one reader's one copy, every name of it the
-    # program's, and a metric for every scope a model opens (the eight
-    # ``*_SCOPES``) but, until each is given one, the program's own five:
+    # program's, and a metric for every scope a model opens (the nine
+    # ``*_SCOPES``) but, until each is given one, the program's own six:
     # ps.attn/inblock, ps.attn/latent and ps.attn/rope, which count inside
-    # decoder.attn_ms, ps.mtp, whose inner scopes are read under their own
-    # names, and ps.mtp/join, read with the gradient's rest
+    # decoder.attn_ms, ps.mamba/gate, which counts inside decoder.mamba_ms,
+    # ps.mtp, whose inner scopes are read under their own names, and
+    # ps.mtp/join, read with the gradient's rest
     copied = [name for name in vars(decoder)
               if name.isupper() and hasattr(phases, name)]
     assert len(copied) == len(decoder.SCOPES) >= 21
@@ -187,7 +188,7 @@ def test_program_and_benchmark_share_their_names():
         assert getattr(phases, name) == getattr(decoder, name), name
     families = [name for name in vars(phases) if name.endswith("_SCOPES")]
     opened = set().union(*(getattr(phases, name) for name in families))
-    assert len(families) == 8
+    assert len(families) == 9
     assert opened - PROGRAMS_OWN <= set(decoder.METRICS) <= opened
     assert PROGRAMS_OWN <= opened and not PROGRAMS_OWN & set(decoder.METRICS)
     assert not opened & set(phases.DEVICE_PHASES)
@@ -376,10 +377,11 @@ def _nemotron_step():
 def test_nemotron_scopes_reach_the_step_hlo_forward_and_backward(
         no_compile_cache, monkeypatch):
     """What Nemotron-H adds to the scopes (``ps.mamba``, ``ps.mamba/conv``,
-    ``ps.mamba/ssd``, ``ps.moe/latent``) beside the six it shares with OLMoE
-    and Kimi-Linear's ``ps.moe/shared``: each in the lowered step's
-    ``op_name``s under ``ps.grad``, forward and backward, though every layer
-    is under a ``jax.checkpoint``; the reader finds each."""
+    ``ps.mamba/ssd``, ``ps.mamba/gate``, ``ps.moe/latent``) beside the six it
+    shares with OLMoE and Kimi-Linear's ``ps.moe/shared``: each in the
+    lowered step's ``op_name``s under ``ps.grad``, forward and backward,
+    though every layer is under a ``jax.checkpoint``; the reader finds each
+    but the gate's, which it reads as the mixer's."""
     assert phases.NEMOTRON_SCOPES[:6] == phases.MOE_SCOPES
     monkeypatch.setitem(BUILDERS, "nemotron", _nemotron_step)
     names = scope.op_names_of(_step_hlo("nemotron"))
@@ -389,7 +391,9 @@ def test_nemotron_scopes_reach_the_step_hlo_forward_and_backward(
         assert any(phases.BACKWARD_MARK in n for n in under), s
         assert any(phases.BACKWARD_MARK not in n for n in under), s
     found = {decoder.scope_of(own, n) for own, n in names.items()}
-    assert found == set(phases.NEMOTRON_SCOPES) | {None}
+    assert found == (set(phases.NEMOTRON_SCOPES) - PROGRAMS_OWN) | {None}
+    assert {decoder.scope_of("%x", n) for n in names.values()
+            if phases.MAMBA_GATE in n} == {phases.MAMBA}
     # the scan and the filter are the innermost scopes of their ops, and the
     # mixer's holds them; the latent projections are not the routed experts'
     for inner in (phases.MAMBA_SSD, phases.MAMBA_CONV):
@@ -902,7 +906,33 @@ READER_CASES = {
                 "decoder.attn_latent_ms": 3.0, "decoder.attn_rope_ms": 1.0,
                 "decoder.ce": 9.5, "decoder.mtp_ce": 9.75,
                 "decoder.mtp_positions": 8191.0},
-        "mfu": 5.0, "device_ms": 33.0}}
+        "mfu": 5.0, "device_ms": 33.0},
+    "granite": {   # dense: no expert, no counter; a mixer and a SwiGLU a layer
+        "events": [
+            ("%in", "fusion", 0.004, _CP + "ps.mamba/dot_general"),
+            ("%taps", "fusion", 0.002, _CP + "ps.mamba/ps.mamba/conv/mul"),
+            # a block of heads under its own checkpoint inside the map
+            ("%scan", "fusion", 0.010,
+             "jit(f)/ps.grad/transpose(jvp())/checkpoint/ps.mamba/"
+             "ps.mamba/ssd/while/body/checkpoint/rematted_computation/"
+             "dot_general"),
+            ("%gated", "fusion", 0.004, _CP + "ps.mamba/ps.mamba/gate/mul"),
+            ("%flash", "custom-call", 0.010, _CP + "ps.attn/pallas_call"),
+            ("%qkv", "fusion", 0.004, _BACK + "ps.attn/dot_general"),
+            ("%swiglu", "fusion", 0.012, _CP + "ps.ffn/dot_general"),
+            *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 5e9, "ssd_flops": 1.0,
+                  "ssd_bytes": 1e9, "flash_flops": 2e9, "flash_bytes": 1.0},
+        "counters": {},
+        "want": {"decoder.mamba_ms": 10.0,     # with filter, scan and gate
+                 "decoder.mamba_conv_ms": 1.0, "decoder.ssd_ms": 5.0,
+                 "decoder.attn_ms": 7.0, "decoder.dense_ffn_ms": 6.0,
+                 "decoder.head_ms": 2.5,
+                 "kernel.ssd_roofline": 20.0,          # 1 of 5 ms
+                 "kernel.flash_roofline": 40.0},       # 2 of 5 ms
+        # S11 row 0: the one scope of this model without a name of its own
+        "may": {"decoder.mamba_gate_ms": 2.0},
+        "mfu": 5.0, "device_ms": 29.5}}
 
 
 @pytest.mark.parametrize("case", sorted(READER_CASES))
@@ -1650,6 +1680,53 @@ def test_joyai_scopes_reach_the_step_hlo_forward_and_backward(
     assert any(phases.MTP not in n for n in heads)
 
 
+def _granite_step():
+    """``(run, batch)`` of ``make_step`` (no aux: the model has no state of
+    its own) on a tiny Granite-4.0-H: a Mamba-2 layer and an attention layer,
+    each with its SwiGLU."""
+    from ps_tpu.models import granite_h
+
+    cfg = granite_h.GraniteHConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=2,
+        layer_types=("mamba", "attention"), mamba_n_heads=4, mamba_d_head=16,
+        mamba_d_state=8, mamba_chunk_size=32, num_attention_heads=2,
+        num_key_value_heads=1, shared_intermediate_size=48,
+        dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: granite_h.init_params(k, cfg))(
+        jax.random.key(0)))
+    # 64 tokens: two chunks of the scan
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    return (store.make_step(granite_h.make_loss_fn(cfg)),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_granite_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Granite-4.0-H opens (``GRANITE_SCOPES``: no expert, so not the
+    six): ``ps.attn``, ``ps.head``, ``ps.ffn`` in every layer, and the shared
+    mixer's four, ``ps.mamba/gate`` the new one: each in the lowered step's
+    ``op_name``s under ``ps.grad``, forward and backward, though every layer
+    is under a ``jax.checkpoint``. The reader has no name for the gate's
+    today: it counts inside ``decoder.mamba_ms``."""
+    assert phases.GRANITE_SCOPES == (
+        phases.ATTN, phases.HEAD, phases.FFN, phases.MAMBA,
+        phases.MAMBA_CONV, phases.MAMBA_SSD, phases.MAMBA_GATE)
+    monkeypatch.setitem(BUILDERS, "granite", _granite_step)
+    names = scope.op_names_of(_step_hlo("granite"))
+    for s in phases.GRANITE_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == (set(phases.GRANITE_SCOPES) - PROGRAMS_OWN) | {None}
+    assert {decoder.scope_of("%x", n) for n in names.values()
+            if phases.MAMBA_GATE in n} == {phases.MAMBA}
+    assert not [n for n in names.values() if "ps.moe" in n]
+
+
 # -- the benchmark's own command on the CPU, and its manifest ------------------
 
 with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
@@ -1690,6 +1767,9 @@ REHEARSED = {
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib"),
     "joyai-llm-flash.s8192.b1.zipf": (
+        "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
+        "setup.import_s", "device.peak_hbm_gib"),
+    "granite-4.0-h-micro.s8192.b1.zipf": (
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib")}
 

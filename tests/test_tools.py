@@ -282,23 +282,51 @@ def test_kimi_grad_check_rehearses():
     assert found["loss_rel_diff"] > 5e-5
 
 
-def test_nemotron_grad_check_rehearses():
-    """tools/nemotron_grad_check.py at the configuration's tiny sizes: the
-    system's gradients are the reference's, and the reference on 8-bit
-    weights turns every witness's gradient."""
-    out = _run("nemotron_grad_check.py", "--rehearse", "--table")
+@pytest.mark.parametrize("model", ["nemotron_h", "granite_h"])
+def test_nemotron_grad_check_rehearses(model):
+    """tools/nemotron_grad_check.py at a configuration's tiny sizes, the
+    model as data (Nemotron-H's share; Granite-4.0-H, whose loss has no
+    selection bias and no counts): the system's gradients are the
+    reference's, the reference on 8-bit weights turns every witness's
+    gradient, and each fault planted in the reference fails a limit of the
+    family's step-0 checks (Granite's through ``step0_checks`` itself)."""
+    import importlib
+
+    family = importlib.import_module(f"benchmark.families.{model}_step")
+    out = _run("nemotron_grad_check.py", "--rehearse", "--table",
+               "--model", model)
     assert out["worst"]["cosine"] > 1 - 1e-9
     assert out["loss"]["rel_diff"] < 1e-5
-    assert out["pairs_on_another_expert"] == [0, 0, 0, 0, 0]
     found = out["reference_on_e4m3_weights"]
     cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
-    assert len(cosines) == 8 and max(cosines) < 0.999
-    assert sum(found["pairs_on_another_expert"]) > 0
-    # the fault the limit on the lengths is there for, and only it
-    from benchmark.families.nemotron_h_step import GRAD_NORM_TOLERANCE as limit
-    for fault in ("picks_not_scaled", "picks_not_renormalised"):
-        assert out[f"reference_with_{fault}"]["lengths_apart"] > 2 * limit
-
+    assert len(cosines) == len(family.GRAD_COSINE)
+    limit = family.GRAD_NORM_TOLERANCE
+    if model == "nemotron_h":
+        assert max(cosines) < 0.999
+        assert out["pairs_on_another_expert"] == [0, 0, 0, 0, 0]
+        assert sum(found["pairs_on_another_expert"]) > 0
+        # the fault the limit on the lengths is there for, and only it
+        for fault in ("picks_not_scaled", "picks_not_renormalised"):
+            assert out[f"reference_with_{fault}"]["lengths_apart"] > 2 * limit
+        return
+    # at the tiny sizes the tied embedding turns least (.99953; its gradient
+    # is a sum over every position of both uses), the eight others read
+    # .9842-.9973; the lengths part 0.086
+    assert max(cosines) < 0.9997 and sorted(cosines)[-2] < 0.998
+    assert found["lengths_apart"] > 1.5 * limit
+    assert out["pairs_on_another_expert"] is None
+    # through the family's own step0_checks and the loss's tolerance, as if
+    # each were the system: the system is correct, no other case is
+    assert out["system"] == {"correct": True, "failed": []}
+    assert not found["correct"] \
+        and "gradient_matches_reference" in found["failed"]
+    faults = {k: v for k, v in out.items() if k.startswith("reference_with")}
+    assert len(faults) == 5
+    for name, read in faults.items():
+        assert not read["correct"] and read["failed"], name
+        # the logits' divisor is common to every gradient: the loss holds it
+        assert read["lengths_apart"] > 2 * limit \
+            or read["loss_rel_diff"] > 2 * family.TOLERANCE[0], name
 
 
 def test_window_table_rehearses():

@@ -26,11 +26,31 @@ not scaled by ``routed_scaling_factor``, or not renormalised, against the
 whole reference. ``--rehearse`` runs the same
 at the configuration's tiny sizes on the CPU. Results go to stdout and to
 ``chiprun_out/nemotron_grad_check.json``.
+
+The model is data (``MODELS``): ``--model granite_h`` runs the same readings
+for ``granite-4.0-h-micro.s8192.b1.zipf`` (a loss without a selection bias or
+counts; the Pallas flash kernel at 32 query heads on 8 K/V heads, the scan
+at 64 heads a group in chunks of 256) with the five faults its limits are
+there for: a multiplier read as another model's (the residual's as 1, the
+embedding's as 1, the logits not divided, attention scaled by ``head_dim **
+-0.5``) and the gated norm before its gate; to
+``chiprun_out/granite_h_grad_check.json``.
+There the control on 8-bit weights and every fault also go through the
+harness's own comparison as if each were the system
+(``granite_h_step.step0_checks`` and the loss's ``TOLERANCE``, as
+``tools/mellum_grad_check.py`` does it): each has to come out ``correct:
+false``, with the checks it ``failed``; with ``--table`` the system itself
+goes through the same and has to come out ``correct: true``. Nemotron's checks
+read the step's own aux (the held counts, the bias after the sign rule), which
+a reference does not return: its cases are printed beside the limits only.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
+import importlib
 import json
 import os
 import sys
@@ -40,6 +60,44 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def _norm_before_gate(reference):
+    """The gated norm with the norm first: the fault, for
+    ``granite_h_reference.gated_norm``."""
+    import jax
+
+    def gated_norm(y, z, scale, groups, eps):
+        seq, inner = y.shape
+        y = reference.rms_norm(y.reshape(seq, groups, -1),
+                               scale.reshape(groups, -1), eps)
+        return y.reshape(seq, inner) * jax.nn.silu(z)
+
+    return {"gated_norm": gated_norm}
+
+
+#: a model's name in ``ps_tpu.models`` and ``benchmark.families`` -> its
+#: configuration class and files, whether its loss takes a selection bias and
+#: returns counts, and the faults planted in its reference: a change to the
+#: configuration's keys, or functions of the reference to swap
+MODELS = {
+    "nemotron_h": {
+        "config_class": "NemotronHConfig",
+        "config": "nemotron-3-super-120b-a12b", "traffic": "s8192.b1.zipf.n96",
+        "routed": True,
+        "faults": {"picks_not_scaled": {"routed_scaling_factor": 1.0},
+                   "picks_not_renormalised": {"norm_topk_prob": False}}},
+    "granite_h": {
+        "config_class": "GraniteHConfig",
+        "config": "granite-4.0-h-micro", "traffic": "s8192.b1.zipf",
+        "routed": False,
+        "faults": {"residual_multiplier_read_as_1":
+                   {"residual_multiplier": 1.0},
+                   "embedding_not_times_12": {"embedding_multiplier": 1.0},
+                   "logits_not_divided_by_8": {"logits_scaling": 1.0},
+                   "attention_scaled_by_an_eighth":
+                   {"attention_multiplier": 0.125},
+                   "norm_before_the_gate": _norm_before_gate}}}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seeds", default="39",
@@ -47,7 +105,11 @@ def main(argv=None) -> int:
     ap.add_argument("--table", action="store_true",
                     help="every tensor's gradient at the first seed")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--model", default="nemotron_h", choices=sorted(MODELS))
     args = ap.parse_args(argv)
+    spec = MODELS[args.model]
+    tool = "nemotron_grad_check" if args.model == "nemotron_h" \
+        else f"{args.model}_grad_check"
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
 
@@ -55,80 +117,128 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.families import nemotron_h_reference as reference
-    from benchmark.families import nemotron_h_step
+    from benchmark.families.lfm2_step import learning_rate
+    from benchmark.families.moe_step import cosine, fresh_batches
+    from benchmark.families.nemotron_h_step import lengths_apart
     from benchmark.harness.loop import seed_key
-    from ps_tpu.models import nemotron_h
 
+    reference = importlib.import_module(
+        f"benchmark.families.{args.model}_reference")
+    family = importlib.import_module(f"benchmark.families.{args.model}_step")
+    model = importlib.import_module(f"ps_tpu.models.{args.model}")
     with open(os.path.join(
-            ROOT, "benchmark/configs/nemotron-3-super-120b-a12b.json")) as f:
+            ROOT, f"benchmark/configs/{spec['config']}.json")) as f:
         config = json.load(f)
     with open(os.path.join(
-            ROOT, "benchmark/traffic/s8192.b1.zipf.n96.json")) as f:
+            ROOT, f"benchmark/traffic/{spec['traffic']}.json")) as f:
         traffic = json.load(f)
     if args.rehearse:
         config.update(config["rehearse"])
         traffic.update(traffic["rehearse"])
     elif jax.devices()[0].platform != "tpu":
-        print("nemotron_grad_check: no TPU found; --rehearse runs the tiny "
+        print(f"{tool}: no TPU found; --rehearse runs the tiny "
               "sizes on the CPU", file=sys.stderr)
         return 1
-    cfg = nemotron_h.NemotronHConfig.from_dict(config)
-    witnesses = tuple(nemotron_h_step.GRAD_COSINE)
-    bias = nemotron_h.init_expert_bias(cfg)
+    cfg = getattr(model, spec["config_class"]).from_dict(config)
+    witnesses = tuple(family.GRAD_COSINE)
+    routed = spec["routed"]
+    # what the loss takes beside the parameters and the batch
+    extra = (model.init_expert_bias(cfg),) if routed else ()
 
     def timed(name, fn):
         t0 = time.perf_counter()
         value = jax.device_get(fn())
-        print(f"nemotron_grad_check: {name} in {time.perf_counter() - t0:.1f} s",
+        print(f"{tool}: {name} in {time.perf_counter() - t0:.1f} s",
               file=sys.stderr)
         return value
 
+    def with_aux(out):
+        """``((loss, aux), grads)`` of a routed model's or of one whose loss
+        is a scalar alone."""
+        return out if routed else ((out[0], None), out[1])
+
     system = jax.jit(jax.value_and_grad(
-        nemotron_h.make_loss_fn(cfg, attn=traffic["attn"]), has_aux=True))
+        model.make_loss_fn(cfg, attn=traffic["attn"]), has_aux=routed))
     plain = jax.jit(jax.value_and_grad(
-        lambda p, b: reference.loss_fn(p, b, bias, config), has_aux=True))
+        lambda p, b: reference.loss_fn(p, b, *extra, config), has_aux=routed))
     on_witnesses = jax.jit(lambda p, b: reference.witness_grads(
-        p, b, bias, config, witnesses))
+        p, b, *extra, config, witnesses))
     fp8 = jnp.float8_e4m3fn   # the nearest precision below bfloat16
-    faulty = {name: jax.jit(lambda p, b, c={**config, **change}:
-                            reference.witness_grads(p, b, bias, c, witnesses))
-              for name, change in (
-                  ("picks_not_scaled", {"routed_scaling_factor": 1.0}),
-                  ("picks_not_renormalised", {"norm_topk_prob": False}))}
+    faulty = {}
+    for name, fault in spec["faults"].items():
+        swapped = fault(reference) if callable(fault) else {}
+        change = {} if callable(fault) else fault
+        faulty[name] = (swapped, jax.jit(
+            lambda p, b, c={**config, **change}:
+            reference.witness_grads(p, b, *extra, c, witnesses)))
+
+    @contextlib.contextmanager
+    def swap(functions):
+        """The reference with these functions of its own replaced, while a
+        faulty run is traced."""
+        kept = {name: getattr(reference, name) for name in functions}
+        for name, fn in functions.items():
+            setattr(reference, name, fn)
+        try:
+            yield
+        finally:
+            for name, fn in kept.items():
+                setattr(reference, name, fn)
 
     def rel(a, b):
         return abs(float(a) - float(b)) / abs(float(b))
 
     def moved(a, b):
+        if not routed:
+            return None
         a, b = (np.asarray(x["expert_tokens"], np.int64) for x in (a, b))
         return (np.abs(a - b).sum(axis=-1) // 2).tolist()
 
     def norm(x):
         return float(np.linalg.norm(np.asarray(x, np.float64)))
 
+    opt = dict(config["optimizer"])
+    _, rule = learning_rate(opt, opt.pop("warmup_steps", 0))
+
+    def verdict(loss, grads, ref_loss, whole):
+        """A case as if it were the system, against the whole reference: the
+        family's own ``step0_checks`` on its witnesses' gradients (as what
+        AdamW's first moment holds of an unclipped gradient; no apply to
+        read) and the loss under the family's tolerance, which is what the
+        loop's ``correct`` holds at step 0."""
+        if routed:
+            return {}
+        result = family.step0_checks(
+            {k: {"mu": (1 - rule["b1"]) * np.asarray(grads[k], np.float64),
+                 "reference_grad": np.asarray(whole[k])} for k in witnesses},
+            rule["clip_by_global_norm"], rule)
+        checks = {"step0_matches_reference":
+                  rel(loss, ref_loss) <= family.TOLERANCE[0],
+                  **result["checks"]}
+        return {"correct": all(checks.values()),
+                "failed": sorted(k for k, ok in checks.items() if not ok)}
+
     def lengths(grads, whole):
         ratios = {k: norm(grads[k]) / norm(whole[k]) for k in witnesses}
         return {**{f"grad_norm_ratio.{k}": v for k, v in ratios.items()},
-                "lengths_apart": nemotron_h_step.lengths_apart(
-                    list(ratios.values()))}
+                "lengths_apart": lengths_apart(list(ratios.values()))}
 
     out = {"device": jax.devices()[0].device_kind, "seeds": []}
     for seed in [int(x) for x in args.seeds.split(",")]:
-        batch = next(nemotron_h_step.fresh_batches(
+        batch = next(fresh_batches(
             int(traffic["per_chip_batch"]), int(traffic["seq_len"]),
             cfg.vocab_size, traffic["ids"]["s"], seed))
-        params = jax.jit(lambda k: nemotron_h.init_params(k, cfg))(
+        params = jax.jit(lambda k: model.init_params(k, cfg))(
             seed_key(seed))
         one = {"seed": seed}
         with jax.default_matmul_precision("highest"):
-            (ref_loss, ref_aux), whole = timed(
+            (ref_loss, ref_aux), whole = with_aux(timed(
                 "reference, the witnesses",
-                lambda: on_witnesses(params, batch))
+                lambda: on_witnesses(params, batch)))
         if args.table and not out["seeds"]:
             # every tensor, the system's against the reference's
-            (loss, aux), grads = timed("system gradients",
-                                       lambda: system(params, batch, bias))
+            (loss, aux), grads = with_aux(timed(
+                "system gradients", lambda: system(params, batch, *extra)))
             with jax.default_matmul_precision("highest"):
                 _, ref_grads = timed("reference gradients",
                                      lambda: plain(params, batch))
@@ -136,13 +246,17 @@ def main(argv=None) -> int:
                            "reference": float(ref_loss),
                            "rel_diff": rel(loss, ref_loss)}
             one["pairs_on_another_expert"] = moved(aux, ref_aux)
+            one["system"] = verdict(loss, {
+                k: functools.reduce(lambda t, part: t[part], k.split("/"),
+                                    grads) for k in witnesses},
+                ref_loss, whole)
             rows = []
             flat, _ = jax.tree_util.tree_flatten_with_path(grads)
             for (path, g), r in zip(flat,
                                     jax.tree_util.tree_leaves(ref_grads)):
                 g, r = (np.asarray(x, np.float64).ravel() for x in (g, r))
                 rows.append({"tensor": jax.tree_util.keystr(path),
-                             "cosine": nemotron_h_step.cosine(g, r),
+                             "cosine": cosine(g, r),
                              "norm_ratio": norm(g) / norm(r),
                              "rel_diff": norm(g - r) / norm(r)})
             one["gradients"] = rows
@@ -156,28 +270,33 @@ def main(argv=None) -> int:
         rounded = jax.tree_util.tree_map(
             lambda w: w.astype(fp8).astype(w.dtype), params)
         with jax.default_matmul_precision("highest"):
-            (value, v_aux), v_grads = timed(
+            (value, v_aux), v_grads = with_aux(timed(
                 "reference on e4m3 weights",
-                lambda: on_witnesses(rounded, batch))
+                lambda: on_witnesses(rounded, batch)))
         one["reference_on_e4m3_weights"] = {
             "loss_rel_diff": rel(value, ref_loss),
             "pairs_on_another_expert": moved(v_aux, ref_aux),
-            **{f"grad_cosine.{k}": nemotron_h_step.cosine(v_grads[k], whole[k])
+            **{f"grad_cosine.{k}": cosine(v_grads[k], whole[k])
                for k in witnesses},
-            **lengths(v_grads, whole)}
-        for name, run in faulty.items():
-            with jax.default_matmul_precision("highest"):
-                _, f_grads = timed(f"reference with {name}",
-                                   lambda: run(params, batch))
-            one[f"reference_with_{name}"] = lengths(f_grads, whole)
+            **lengths(v_grads, whole),
+            **verdict(value, v_grads, ref_loss, whole)}
+        for name, (swapped, run) in faulty.items():
+            with jax.default_matmul_precision("highest"), swap(swapped):
+                (f_loss, _), f_grads = with_aux(timed(
+                    f"reference with {name}", lambda: run(params, batch)))
+            one[f"reference_with_{name}"] = {
+                "loss_rel_diff": rel(f_loss, ref_loss),
+                "least_grad_cosine": min(cosine(f_grads[k], whole[k])
+                                         for k in witnesses),
+                **lengths(f_grads, whole),
+                **verdict(f_loss, f_grads, ref_loss, whole)}
         out["seeds"].append(one)
         # one line a seed; the last line of stdout is the last seed's
         print(json.dumps({k: v for k, v in one.items() if k != "gradients"}),
               flush=True)
 
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "nemotron_grad_check.json"),
-              "w") as f:
+    with open(os.path.join(ROOT, "chiprun_out", f"{tool}.json"), "w") as f:
         json.dump(out, f, indent=1)
     return 0
 
