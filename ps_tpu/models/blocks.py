@@ -5,7 +5,7 @@ a block two of them need stands here, a block one needs in that model's file.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -158,13 +158,26 @@ def mla_block(lp: Dict, x, config, attn_fn: Callable):
     return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
 
 
+def off_chip(interpret: Optional[bool] = None) -> bool:
+    """Whether the Mosaic calls a model makes itself run in interpret mode:
+    the caller's word, else whether the process's first device is no TPU.
+    Asked where a loss function is built, outside any trace, and handed down
+    as a static argument of whatever is cached on the way (a mixer's
+    ``jax.checkpoint``, the kernel's ``custom_vjp``)."""
+    if interpret is None:
+        return jax.devices()[0].platform != "tpu"
+    return interpret
+
+
 def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
                 state: int, chunk: int, eps: float):
     """The Mamba-2 mixer of the normed activations ``x`` [B, S, D], for
     ``heads`` heads of ``head_dim`` on ``groups`` groups of B and C over a
     state of ``state``: ``[z | xBC | dt] = x W_in`` (which bears the name
     'mamba_in'); the x, B and C channels through the causal taps, the bias
-    and the SiLU of ``ops/gated_conv.py::conv_silu``; ``dt = softplus(dt +
+    and the SiLU of ``ops/gated_conv.py::conv_silu`` in its XLA form (what
+    comes out feeds the scan's einsums in layouts XLA has to be free to
+    choose: a Mosaic call there cost the Granite cell 1%); ``dt = softplus(dt +
     dt_bias)`` in f32; the scan in its chunked form (``ops/ssd.py``, chunks
     of ``chunk``), whose output bears the name 'mamba_ssd'; then, in f32, the
     skip ``D x``, the gate ``silu(z)`` first and an RMSNorm over each group's

@@ -15,7 +15,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ps_tpu.ops import flash_attention, grouped_matmul, moe
-from ps_tpu.ops.gated_conv import gated_short_conv
+from ps_tpu.ops.gated_conv import conv_silu, gated_short_conv
+from ps_tpu.ops.gated_conv import path as taps_path
 from ps_tpu.ops.kda import kda, path
 from ps_tpu.ops.ssd import ssd
 
@@ -147,6 +148,78 @@ def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+#: channels and whether there is a bias: the three cells' calls on [1, 8192, C]
+TAPS = {"granite-4.0-h-micro.s8192.b1.zipf": (4352, True),
+        "kimi-linear-48b-a3b.s8192.b1.zipf": (4096, False),
+        "nemotron-3-super-120b-a12b.s8192.b1.zipf": (1280, True)}
+
+
+@pytest.mark.parametrize("cell", sorted(TAPS))
+def test_the_taps_shift_in_vmem_at_the_cells_shapes(cell, one_chip,
+                                                    no_compile_cache):
+    """``conv_silu`` at ``bf16[1, 8192, C]``, four taps: the shapes take the
+    kernels, the forward is one Mosaic call and so is the gradient (the
+    forward's output is not asked for, so XLA drops that call), no shifted
+    f32 copy of ``[S, C]`` stands in either entry computation (the plain
+    form's gradient held seven ``f32[1, 81xx, 4352]`` arrays and 571 MB of
+    temporaries; its forward three and 428 MB) and the temporaries are under
+    64 MB. ``interpret`` is this test's to say, and it says it to the
+    call."""
+    channels, bias = TAPS[cell]
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg(1, 8192, channels, dtype=jnp.bfloat16), arg(channels, 4),
+            arg(channels) if bias else None)
+    assert taps_path(*args[:2]) == "kernel"
+
+    def forward(x, w, b):
+        return conv_silu(x, w, b, interpret=False)
+
+    def loss(x, w, b):
+        return jnp.sum(forward(x, w, b).astype(jnp.float32))
+
+    wrt = (0, 1, 2) if bias else (0, 1)
+    for fn in (forward, jax.grad(loss, argnums=wrt)):
+        compiled = jax.jit(fn).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        assert " while(" not in text
+        assert not re.search(rf"f32\[1,81\d\d,{channels}\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("cell", sorted(c for c in TAPS if "kimi" not in c))
+def test_the_taps_xla_form_writes_no_shifted_copy(cell, one_chip,
+                                                  no_compile_cache):
+    """``conv_silu`` as ``mamba_block`` calls it (no word of the kernels: the
+    XLA form) at ``bf16[1, 8192, C]``: the pad in ``x``'s own dtype and the
+    slices cut from it fuse, so the forward keeps nothing beside ``x`` and
+    ``y`` (428 MB of shifted f32 copies before PR 57) and the gradient at
+    most ``dz`` in f32 (571 MB before), and neither holds a Mosaic call."""
+    channels, _ = TAPS[cell]
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg(1, 8192, channels, dtype=jnp.bfloat16), arg(channels, 4),
+            arg(channels))
+
+    def loss(x, w, b):
+        return jnp.sum(conv_silu(x, w, b).astype(jnp.float32))
+
+    whole = 8192 * channels
+    for fn, room in ((conv_silu, 0), (jax.grad(loss, (0, 1, 2)), 4 * whole)):
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" not in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= room + 2 ** 20
+        # x and y once (and dy, dx and dz's two passes), not a copy a tap
+        passes = compiled.cost_analysis()["bytes accessed"] / (2 * whole)
+        assert passes < (2.1 if room == 0 else 6.1)
+
+
 def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     """``ops/kda.py`` at [1, 8192, 32, 128], forward and backward: the shape
     takes the Mosaic kernels (``kda.path``), two calls in the gradient (the
@@ -182,14 +255,17 @@ def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
     """``models/kimi_linear.py::_kda_of`` at the cell's shape ([1, 8192,
     4096] projections, 32 heads), value and gradient: its checkpoint keeps
     the rule's output, states and inverses by name (``ops/kda.py::KEPT``),
-    so the program holds the forward call once and the backward call, where
-    a policy-less checkpoint holds a second forward (three calls and 3.43e9
-    B of temporaries; the value is asked for so that XLA cannot drop the
-    first). Temporaries 2.83e9 B: the three kept arrays are 0.40e9 of them,
-    the rest the recomputed taps, decays and gates in f32."""
+    so the program holds the rule's forward call once and its backward call,
+    where a policy-less checkpoint holds a second forward (the value is asked
+    for so that XLA cannot drop the first), and nine calls of the taps
+    (``ops/gated_conv.py``; q, k and v: forward, the forward again under the
+    checkpoint, backward). Temporaries 1.28e9 B, where the taps' plain form
+    stood at 2.83e9: the three kept arrays are 0.40e9 of them, the rest the
+    recomputed decays and gates in f32."""
     from ps_tpu.models import kimi_linear
 
-    # ``_kda_of`` leaves ``interpret`` to ``jax.devices()``, the CPU's here
+    # ``_kda_of`` leaves the rule's ``interpret`` to ``jax.devices()``, the
+    # CPU's here; the taps' is its own argument, false unless a caller says
     monkeypatch.setattr(kimi_linear, "kda",
                         functools.partial(kda, interpret=False))
     heads, width, rank, tokens = 32, 128, 128, (1, 8192)
@@ -215,9 +291,9 @@ def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         projected, weights).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 9
     assert " while(" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 29
 
 
 def test_chunked_ssd_compiles_at_the_cells_shape(one_chip, no_compile_cache):
