@@ -158,17 +158,6 @@ def mla_block(lp: Dict, x, config, attn_fn: Callable):
     return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
 
 
-def off_chip(interpret: Optional[bool] = None) -> bool:
-    """Whether the Mosaic calls a model makes itself run in interpret mode:
-    the caller's word, else whether the process's first device is no TPU.
-    Asked where a loss function is built, outside any trace, and handed down
-    as a static argument of whatever is cached on the way (a mixer's
-    ``jax.checkpoint``, the kernel's ``custom_vjp``)."""
-    if interpret is None:
-        return jax.devices()[0].platform != "tpu"
-    return interpret
-
-
 def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
                 state: int, chunk: int, eps: float):
     """The Mamba-2 mixer of the normed activations ``x`` [B, S, D], for

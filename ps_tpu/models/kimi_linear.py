@@ -79,10 +79,10 @@ import jax.numpy as jnp
 
 from ps_tpu.models.blocks import init_expert_bias  # noqa: F401 — re-export
 from ps_tpu.models.blocks import (dense_ffn, experts_of, make_attn_fn,
-                                  mla_block, off_chip, rms_norm, token_ce)
+                                  mla_block, rms_norm, token_ce)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
-from ps_tpu.ops.gated_conv import conv_silu
+from ps_tpu.ops.gated_conv import conv_silu_kernel
 from ps_tpu.ops.kda import KEPT, kda
 
 
@@ -229,21 +229,16 @@ def init_params(key, config: KimiLinearConfig) -> Dict:
     return params
 
 
-def _kda_of(projected, weights, heads: int, eps: float,
-            interpret: bool = False):
+@functools.partial(jax.checkpoint, static_argnums=(2, 3),
+                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+def _mixer(projected, weights, heads: int, eps: float):
     """Everything of the KDA mixer between its projections: ``projected``
     the activations behind q, k, v [B, S, H * K], the two low-rank gates'
     inner sides [B, S, r] and the write strength's logits [B, S, H];
     ``weights`` the f32 leaves used here. Recomputed in the backward pass,
     but the rule's forward kernel call, whose named residuals are kept.
-    ``interpret`` is the taps' (``blocks.off_chip``): a static argument of
-    the checkpoint, which takes every one by position."""
-    return _mixer(projected, weights, heads, eps, interpret)
-
-
-@functools.partial(jax.checkpoint, static_argnums=(2, 3, 4),
-                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
-def _mixer(projected, weights, heads: int, eps: float, interpret: bool):
+    Which device the kernels under it are traced for is in the checkpoint's
+    key with the shapes (``ops/mosaic.py``)."""
     q, k, v, f_inner, g_inner, b_logits = projected
     batch, seq = q.shape[:2]
 
@@ -254,8 +249,7 @@ def _mixer(projected, weights, heads: int, eps: float, interpret: bool):
         return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
     with jax.named_scope(phases.KDA_CONV):
-        q, k, v = (head_wise(conv_silu(x, weights[f"{n}_conv"],
-                                       interpret=interpret))
+        q, k, v = (head_wise(conv_silu_kernel(x, weights[f"{n}_conv"]))
                    for n, x in (("q", q), ("k", k), ("v", v)))
     dtype = v.dtype
     q, k = (unit(x.astype(jnp.float32)) for x in (q, k))
@@ -273,8 +267,7 @@ def _mixer(projected, weights, heads: int, eps: float, interpret: bool):
     return o.astype(dtype).reshape(batch, seq, -1)
 
 
-def kda_block(lp: Dict, x, config: KimiLinearConfig,
-              interpret: bool = False):
+def kda_block(lp: Dict, x, config: KimiLinearConfig):
     """The KDA mixer on normed activations ``x`` [B, S, D]."""
     def proj(name):
         return x @ lp[name]["kernel"].astype(x.dtype)
@@ -282,8 +275,7 @@ def kda_block(lp: Dict, x, config: KimiLinearConfig,
     projected = tuple(proj(n) for n in ("q", "k", "v", "f_a", "g_a", "b"))
     inner = {n: lp[n] for n in ("q_conv", "k_conv", "v_conv", "f_b", "g_b",
                                 "dt_bias", "A_log", "out_norm")}
-    o = _kda_of(projected, inner, config.kda_num_heads, config.rms_norm_eps,
-                interpret)
+    o = _mixer(projected, inner, config.kda_num_heads, config.rms_norm_eps)
     return o @ lp["out"]["kernel"].astype(x.dtype)
 
 
@@ -307,10 +299,9 @@ def moe_block(lp: Dict, x, config: KimiLinearConfig, bias):
 
 
 def apply(params: Dict, tokens, config: KimiLinearConfig, expert_bias=None,
-          attn_fn: Callable = None, interpret: bool = False):
+          attn_fn: Callable = None):
     """``tokens`` [B, S] int32 -> (final hidden states [B, S, D] before the
-    final norm, the list of each expert layer's ``Routing``). ``interpret``:
-    the KDA mixers' Mosaic taps in interpret mode."""
+    final norm, the list of each expert layer's ``Routing``)."""
     c = config
     attn_fn = attn_fn or make_attn_fn("full")
     x = jnp.take(params["embed"]["tokens"], tokens, axis=0).astype(c.dtype)
@@ -320,7 +311,7 @@ def apply(params: Dict, tokens, config: KimiLinearConfig, expert_bias=None,
         h = rms_norm(x, lp["mixer_norm"]["scale"], c.rms_norm_eps)
         if i + 1 in c.kda_layers:
             with jax.named_scope(phases.KDA):
-                x = x + kda_block(lp["kda"], h, c, interpret)
+                x = x + kda_block(lp["kda"], h, c)
         else:
             with jax.named_scope(phases.ATTN):
                 x = x + mla_block(lp["attn"], h, c, attn_fn)
@@ -351,14 +342,12 @@ def make_loss_fn(config: KimiLinearConfig, attn: str = "full", **attn_kw):
     expert over all of them; ``held_tokens`` [expert layers, num_experts],
     those computed here; ``expert_windows`` [expert layers], the windows of
     rows each layer ran (1 unless its held pairs overflowed the first);
-    ``expert_bias``, the bias for the next step. An ``interpret`` among
-    ``attn_kw`` is every kernel's, the taps' too (``blocks.off_chip``)."""
+    ``expert_bias``, the bias for the next step."""
     attn_fn = make_attn_fn(attn, **attn_kw)
-    interpret = off_chip(attn_kw.get("interpret"))
 
     def loss_fn(params, batch, expert_bias):
         hidden, routings = apply(params, batch["inputs"], config, expert_bias,
-                                 attn_fn, interpret)
+                                 attn_fn)
         with jax.named_scope(phases.HEAD):
             ce = token_ce(logits_of(params, hidden, config),
                           batch["targets"])

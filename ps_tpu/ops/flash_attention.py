@@ -339,6 +339,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ps_tpu.ops import mosaic
 from ps_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 _NEG_INF = -1e30
@@ -1436,7 +1437,6 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
                     strict_edge: bool = False, return_lse: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None,
                     mesh: Optional[Mesh] = None):
     """Fused flash attention. ``q``: [B, S, h, d] (the model-side layout
     of ps_tpu/models/{bert,lm}.py); ``k``: [B, S, h_kv, d] and ``v``:
@@ -1463,11 +1463,11 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
     ``block_q`` / ``block_k`` tile the forward kernel; left at None they
     are ``forward_tiles``' choice from the operands' shapes. Under a window
     they are the band step's block and sub-block (``block_k`` a multiple of
-    128 that divides ``block_q``), ``forward_band``'s choice. ``interpret``
-    defaults to True off-TPU so tests exercise the same kernel logic on
-    CPU. Sequence length must be divisible by 128, the backward's key
-    block, and by the forward's blocks (pad to 128 — XLA-side attention
-    pads the same way in practice).
+    128 that divides ``block_q``), ``forward_band``'s choice. Off the chip
+    the kernels run in interpret mode (``ops/mosaic.py::interpret``), so
+    tests exercise the same kernel logic on CPU. Sequence length must be
+    divisible by 128, the backward's key block, and by the forward's blocks
+    (pad to 128 — XLA-side attention pads the same way in practice).
 
     ``mesh`` defaults to the one ``ps_tpu.init`` built, if any. Under a
     mesh the kernel runs inside ``shard_map`` — batch over 'data', heads
@@ -1511,8 +1511,7 @@ def flash_attention(q, k, v, *, mask: Optional[jax.Array] = None,
         raise ValueError(
             f"under a window block_k={block_k} is the sub-block of "
             f"block_q={block_q}: a multiple of 128 that divides it")
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = mosaic.interpret()
     if mask is None:
         mask = jnp.ones((b, seq), jnp.int32)
     scale = d ** -0.5

@@ -29,8 +29,8 @@ Attention runs it on q, k and v (four taps, no bias, ``models/kimi_linear.py``)
 and the Mamba-2 mixer on its x, B and C channels (four taps and a bias,
 ``models/blocks.py::mamba_block``: Granite-4.0-H's and Nemotron-H's).
 
-**``conv_silu`` has two realisations of one rule, and its caller says which
-neighbours it has.** By its shapes a forward pass reads ``x`` and writes
+**``conv_silu`` has two realisations of one rule, a name each: its caller's
+neighbours say which.** By its shapes a forward pass reads ``x`` and writes
 ``y``, 2 passes of ``tokens x C x itemsize``; a backward pass reads ``x`` and
 ``dy`` and writes ``dx``, 3 passes. A layer's ``jax.checkpoint`` runs the
 forward twice: 7 passes a layer, 0.50 GB at Granite's [8192, 4352] in bf16.
@@ -44,7 +44,7 @@ with a bias (ISSUE 57): the forward kept three ``f32[1, 819x, 4352]`` slices
 the gradient seven such arrays (571 MB, 3.35 GB accessed where ``x``, ``dy``
 and ``dx`` are 0.214).
 
-*The XLA form* (``_conv_silu``; what ``models/blocks.py::mamba_block`` gets,
+*The XLA form* (``conv_silu``; what ``models/blocks.py::mamba_block`` calls,
 and every shape the kernels do not take). One copy of ``x`` padded by
 ``taps - 1`` rows **in its own dtype**, static slices of it, each cast after
 it is cut (``_moved_copies``): the pad is a producer the loop fusion takes
@@ -60,8 +60,8 @@ XLA writes each from a taps fusion of its own; a custom call pins one
 row-major result and the copies into the other layouts then cost what the
 kernel saved (the table's last column).
 
-*The Mosaic calls* (``forward`` / ``backward``; what
-``models/kimi_linear.py::_kda_of`` gets, whose q, k and v go on to the KDA
+*The Mosaic calls* (``conv_silu_kernel``: ``forward`` / ``backward``; what
+``models/kimi_linear.py::_mixer`` calls, whose q, k and v go on to the KDA
 kernels row-major, at shapes ``path`` accepts: whole 128-lane tiles of
 channels, whole tiles of rows, at least one block). A grid step holds a
 ``[rows, lanes]`` block of one sequence (``tiles``: the widest multiple of
@@ -82,9 +82,9 @@ registers carried in VMEM along the grid's row blocks and batch
 (``arbitrary``; the lane tiles ``parallel``) and folded to a row each at
 the last step, so they differ from the XLA form's in the last bits.
 Residuals are ``x``, ``w`` and ``b`` in both. ``interpret`` is a static
-argument of the ``custom_vjp`` and the caller's to decide
-(``models/blocks.py::off_chip``, asked where a loss function is built):
-nothing here asks ``jax.devices()``. Under a mesh of several devices the
+argument of ``forward`` / ``backward`` and of the ``custom_vjp``, which
+``conv_silu_kernel`` fills from ``ops/mosaic.py::interpret``. Under a mesh
+of several devices the
 calls are not wrapped in ``shard_map`` as ``ops/kda.py``'s are: no cell runs
 that mixer across chips yet.
 
@@ -114,12 +114,13 @@ slower still in Granite's (481.41).
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ps_tpu.ops import mosaic
 
 
 def shift(u, by: int):
@@ -242,14 +243,14 @@ def _sublanes(itemsize: int) -> int:
 
 
 def path(x, w) -> str:
-    """Which realisation ``conv_silu`` takes for ``x`` [B, S, C] and ``w``
-    [C, taps]: ``"kernel"`` (the Mosaic calls) where the channels fill whole
-    128-lane tiles, the sequence whole tiles of ``x``'s dtype, the taps and
-    the bias one tile of rows, and a sequence at least one grid step's block
-    (under that a call is one step with nothing to overlap, and the plain
-    form's temporary is a few MB), else ``"plain"`` (the XLA form,
-    ``_conv_silu``). Read from the shapes alone; whether a caller wants the
-    kernels at all is ``conv_silu``'s ``interpret``."""
+    """Which realisation ``conv_silu_kernel`` takes for ``x`` [B, S, C] and
+    ``w`` [C, taps]: ``"kernel"`` (the Mosaic calls) where the channels fill
+    whole 128-lane tiles, the sequence whole tiles of ``x``'s dtype, the taps
+    and the bias one tile of rows, and a sequence at least one grid step's
+    block (under that a call is one step with nothing to overlap, and the
+    plain form's temporary is a few MB), else ``"plain"`` (the XLA form,
+    ``conv_silu``). Read from the shapes alone; whether a caller wants the
+    kernels at all is which of the two functions it calls."""
     _, seq, channels = x.shape
     tiled = channels % _VREG == 0 and seq % _sublanes(x.dtype.itemsize) == 0
     whole = seq * channels >= _BLOCK and w.shape[-1] < _TILE
@@ -509,21 +510,26 @@ def _conv_silu_kernel_bwd(interpret, res, dy):
 _conv_silu_kernel.defvjp(_conv_silu_kernel_fwd, _conv_silu_kernel_bwd)
 
 
-def conv_silu(x, w, b=None, *, interpret: Optional[bool] = None):
+def conv_silu(x, w, b=None):
     """``silu`` of the depthwise causal convolution of ``x`` [B, S, C] with
     the filter ``w`` [C, taps] (zero left pad) plus the bias ``b`` [C] if
     there is one, in f32, the result in ``x``'s dtype, each sequence of the
     batch on its own. One rule in each direction as ``gated_short_conv``'s:
     only ``x``, ``w`` and ``b`` are kept, and the backward pass is the
     transposed taps, not autodiff's pads and slices of a concatenation.
-    A caller whose neighbours are Mosaic calls (``models/kimi_linear.py``:
-    q, k and v go on to the KDA kernels, row-major as a custom call writes
-    them) says how the kernels run, ``interpret`` true off the chip, and
-    gets the Mosaic calls where ``path`` says the shapes take them; one whose
-    neighbours are XLA's (``models/blocks.py::mamba_block``: the scan's
-    einsums take the result in three layouts that XLA writes from this
-    function's own fusions) says nothing and gets the XLA form (module
-    docstring: what each costs where)."""
-    if interpret is not None and path(x, w) == "kernel":
-        return _conv_silu_kernel(x, w, b, interpret)
+    The XLA form, for a caller whose neighbours are XLA's
+    (``models/blocks.py::mamba_block``: the scan's einsums take the result
+    in three layouts that XLA writes from this function's own fusions;
+    module docstring: what each form costs where)."""
+    return _conv_silu(x, w, b)
+
+
+def conv_silu_kernel(x, w, b=None):
+    """``conv_silu`` for a caller whose neighbours are Mosaic calls
+    (``models/kimi_linear.py``: q, k and v go on to the KDA kernels,
+    row-major as a custom call writes them): the Mosaic calls where ``path``
+    says the shapes take them (in interpret mode off the chip,
+    ``ops/mosaic.py::interpret``), the XLA form elsewhere."""
+    if path(x, w) == "kernel":
+        return _conv_silu_kernel(x, w, b, mosaic.interpret())
     return _conv_silu(x, w, b)

@@ -65,10 +65,12 @@ Design:
   autodiff of ``ragged_dot`` kept, and returns the cotangents in the
   operands' dtypes. Same arithmetic as before in other tiles: operands in
   their dtype on the MXU, f32 accumulation, one rounding.
-- Off the chip the calls run in interpret mode (``_interpret``), so the CPU
-  tests run the kernels' own code (``tests/test_grouped_matmul.py``, against
-  ``ragged_dot`` and its autodiff); ``tests/test_chip_compile.py`` compiles
-  all three for a described v5e at the six cells' shapes. ``_gmm`` and
+- Off the chip the calls run in interpret mode (``ops/mosaic.py::interpret``,
+  asked while ``_gmm`` and ``tgmm`` are traced: their ``jax.jit`` keys the
+  device it reads beside the shapes), so the CPU tests run the kernels' own
+  code (``tests/test_grouped_matmul.py``, against ``ragged_dot`` and its
+  autodiff); ``tests/test_chip_compile.py`` compiles all three for a
+  described v5e at the six cells' shapes. ``_gmm`` and
   ``tgmm`` are jitted so that a model's layers trace and lower each shape
   once (interpret mode's cost on the CPU is the trace); the call keeps the
   scope it is made under (``.../ps.moe/expert/jit(_gmm)/gmm/pallas_call``).
@@ -158,6 +160,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ps_tpu.ops import mosaic
+
 # What one grid step may hold in VMEM by ``vmem_bytes``' count. A call asks
 # Mosaic for its own count and a quarter more (``_vmem_limit``: Mosaic's
 # temporaries, the f32 product before it is rounded, the boundary tiles'
@@ -173,12 +177,6 @@ _VMEM_BUDGET = 25 * 2 ** 20
 #: the rows of a tile: no shorter (the MXU's width), no longer
 _MIN_ROWS = 128
 _MAX_ROWS = 256
-
-
-def _interpret() -> bool:
-    """Off the chip the calls run in interpret mode, as ``flash_attention``
-    decides it: the CPU tests run the kernels' own code."""
-    return jax.devices()[0].platform != "tpu"
 
 
 def _lanes(width: int) -> int:
@@ -380,7 +378,7 @@ def _gmm(lhs, rhs, group_sizes, *, transpose_rhs: bool, tiling=None):
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=dtype.itemsize * (
                 m * k * pl.cdiv(n, tn) + e * k * n + m * n)),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
         name="gmm_transposed" if transpose_rhs else "gmm",
     )(*scalars, lhs, rhs)
 
@@ -465,7 +463,7 @@ def tgmm(lhs, g, group_sizes, *, tiling=None):
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=dtype.itemsize * (
                 m * k * pl.cdiv(n, tn) + m * n * pl.cdiv(k, tk) + e * k * n)),
-        interpret=_interpret(),
+        interpret=mosaic.interpret(),
         name="tgmm",
     )(*scalars, lhs, g)
 

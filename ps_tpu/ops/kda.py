@@ -68,7 +68,7 @@ elementwise passes away, these three are the whole call away. So
 enter the residual tuple, as ``ops/flash_attention.py::_flash_vjp_fwd``
 does its two, and a checkpoint whose policy is
 ``save_only_these_names(*KEPT)`` keeps them and recomputes no forward call:
-``models/kimi_linear.py::_kda_of`` says so for the mixer. At 32 heads of 128
+``models/kimi_linear.py::_mixer`` says so for the mixer. At 32 heads of 128
 that is 49 KB a token a layer more between forward and backward (``o`` in
 bf16 8 KB, the states in f32 32 KB, the inverses 8 KB: 403 MB a layer at
 8,192 tokens) and one forward call of 11.4 ms a layer less. Under no
@@ -157,7 +157,6 @@ Mosaic's own do (0.94 against 1.11 us for the inverse).
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -165,7 +164,7 @@ from jax import shard_map
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ps_tpu.ops import kda_mosaic
+from ps_tpu.ops import kda_mosaic, mosaic
 from ps_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 #: tokens of a sub-block, inside which every pair gets its own decay (and
@@ -347,8 +346,7 @@ def _under_mesh(run, batch: int, heads: int):
                      out_specs=wide, check_vma=False)
 
 
-def kda(q, k, v, g, beta, *, chunk: int = 64,
-        interpret: Optional[bool] = None):
+def kda(q, k, v, g, beta, *, chunk: int = 64):
     """``q``, ``k`` [B, T, H, K], ``v`` [B, T, H, V], ``g`` [B, T, H, K] the
     log-decays (<= 0, f32), ``beta`` [B, T, H] -> ``o`` [B, T, H, V] in
     ``v``'s dtype: the recurrence of the module docstring from a zero state,
@@ -361,20 +359,17 @@ def kda(q, k, v, g, beta, *, chunk: int = 64,
     kernels lives from forward to backward: ``save_only_these_names(*KEPT)``
     keeps the output, states and inverses (49 KB a token a layer at 32 heads
     of 128) and the forward call runs once; no policy keeps nothing and it
-    runs twice. ``path`` says which realisation runs;
-    ``interpret`` is the kernels' (None: off the chip the same kernels run in
-    interpret mode), and under ``ps_tpu.init``'s mesh they run in
-    ``shard_map`` (``_under_mesh``)."""
+    runs twice. ``path`` says which realisation runs; off the chip the same
+    kernels run in interpret mode (``ops/mosaic.py::interpret``), and under
+    ``ps_tpu.init``'s mesh they run in ``shard_map`` (``_under_mesh``)."""
     t = q.shape[1]
     if chunk % SUB or t % chunk:
         raise ValueError(f"kda: {t} tokens in chunks of {chunk}, sub-blocks "
                          f"of {SUB}: each must divide the one before")
     if path(q, k, v, chunk) == "kernel":
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
         run = _under_mesh(
             functools.partial(_kda_kernel, chunk=chunk, mxu=_mxu_dtype(),
-                              interpret=interpret),
+                              interpret=mosaic.interpret()),
             q.shape[0], q.shape[2])
     else:
         run = functools.partial(_kda, chunk=chunk)

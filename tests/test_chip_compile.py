@@ -6,7 +6,6 @@ topology described inside a fixture: only one process may hold libtpu, and
 only the worker that is given this file loads it.
 """
 
-import functools
 import re
 
 import jax
@@ -15,14 +14,15 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ps_tpu.ops import flash_attention, grouped_matmul, moe
-from ps_tpu.ops.gated_conv import conv_silu, gated_short_conv
+from ps_tpu.ops.gated_conv import (conv_silu, conv_silu_kernel,
+                                   gated_short_conv)
 from ps_tpu.ops.gated_conv import path as taps_path
 from ps_tpu.ops.kda import kda, path
 from ps_tpu.ops.ssd import ssd
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def described_chip():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(
@@ -30,7 +30,19 @@ def one_chip():
             chips_per_host_bounds=(1, 1, 1))
     except Exception as e:
         pytest.skip(f"no v5e topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices[0]
+
+
+@pytest.fixture
+def one_chip(described_chip):
+    """The sharding of every argument, and for the length of the test the
+    described chip as ``jax.default_device``: what the kernels ask whether
+    they go through Mosaic (``ops/mosaic.py``), and what every cache of
+    traces keys, so a trace another test made for the CPU is not served.
+    Nothing can be made or run under it, only described and compiled: a
+    key, too, is made inside the ``jax.eval_shape`` that wants it."""
+    with jax.default_device(described_chip):
+        yield SingleDeviceSharding(described_chip)
 
 
 @pytest.fixture
@@ -45,14 +57,6 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
-
-
-@pytest.fixture
-def mosaic_grouped_matmul(monkeypatch):
-    """``ops/grouped_matmul.py`` asks ``jax.devices()`` whether to interpret
-    its kernels, and here that is the CPU: the test steers it, the program
-    has no option for it."""
-    monkeypatch.setattr(grouped_matmul, "_interpret", lambda: False)
 
 
 #: [B, S, query heads, K/V heads, head dim (, the values' own)], causal,
@@ -95,7 +99,7 @@ def test_flash_forward_and_backward_compile_at_the_cells_shapes(
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=causal,
-                              window=WINDOWS.get(cell), interpret=False)
+                              window=WINDOWS.get(cell))
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -120,8 +124,7 @@ def test_the_edged_flash_calls_compile_at_sdars_shape(strict, one_chip,
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, edge_block=4,
-                              strict_edge=strict, return_lse=strict,
-                              interpret=False)
+                              strict_edge=strict, return_lse=strict)
         if not strict:
             return jnp.sum(out.astype(jnp.float32))
         out, lse = out
@@ -163,8 +166,7 @@ def test_the_taps_shift_in_vmem_at_the_cells_shapes(cell, one_chip,
     f32 copy of ``[S, C]`` stands in either entry computation (the plain
     form's gradient held seven ``f32[1, 81xx, 4352]`` arrays and 571 MB of
     temporaries; its forward three and 428 MB) and the temporaries are under
-    64 MB. ``interpret`` is this test's to say, and it says it to the
-    call."""
+    64 MB."""
     channels, bias = TAPS[cell]
 
     def arg(*shape, dtype=jnp.float32):
@@ -175,7 +177,7 @@ def test_the_taps_shift_in_vmem_at_the_cells_shapes(cell, one_chip,
     assert taps_path(*args[:2]) == "kernel"
 
     def forward(x, w, b):
-        return conv_silu(x, w, b, interpret=False)
+        return conv_silu_kernel(x, w, b)
 
     def loss(x, w, b):
         return jnp.sum(forward(x, w, b).astype(jnp.float32))
@@ -239,8 +241,7 @@ def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     assert path(*args[:3], 64) == "kernel"
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(kda(q, k, v, g, beta,
-                           interpret=False).astype(jnp.float32))
+        return jnp.sum(kda(q, k, v, g, beta).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile()
@@ -251,8 +252,8 @@ def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
 
 
 def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
-        one_chip, no_compile_cache, monkeypatch):
-    """``models/kimi_linear.py::_kda_of`` at the cell's shape ([1, 8192,
+        one_chip, no_compile_cache):
+    """``models/kimi_linear.py::_mixer`` at the cell's shape ([1, 8192,
     4096] projections, 32 heads), value and gradient: its checkpoint keeps
     the rule's output, states and inverses by name (``ops/kda.py::KEPT``),
     so the program holds the rule's forward call once and its backward call,
@@ -264,10 +265,6 @@ def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
     recomputed decays and gates in f32."""
     from ps_tpu.models import kimi_linear
 
-    # ``_kda_of`` leaves the rule's ``interpret`` to ``jax.devices()``, the
-    # CPU's here; the taps' is its own argument, false unless a caller says
-    monkeypatch.setattr(kimi_linear, "kda",
-                        functools.partial(kda, interpret=False))
     heads, width, rank, tokens = 32, 128, 128, (1, 8192)
 
     def arg(*shape, dtype=jnp.float32):
@@ -285,7 +282,7 @@ def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
                "A_log": arg(heads), "out_norm": {"scale": arg(width)}}
 
     def loss(projected, weights):
-        return jnp.sum(kimi_linear._kda_of(projected, weights, heads, 1e-5)
+        return jnp.sum(kimi_linear._mixer(projected, weights, heads, 1e-5)
                        .astype(jnp.float32))
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
@@ -371,7 +368,7 @@ GROUPED_MATMULS = {
 
 @pytest.mark.parametrize("cell", sorted(GROUPED_MATMULS))
 def test_grouped_matmul_and_its_gradients_compile_at_the_cells_shapes(
-        cell, one_chip, no_compile_cache, mosaic_grouped_matmul):
+        cell, one_chip, no_compile_cache):
     """``gmm`` into an expert and out of it again, forward, the rows'
     gradient and the stacks' gradient of each, at the tiles ``tiles(..)``
     chooses: a choice that overflows VMEM fails here, not on the chip."""
@@ -396,7 +393,7 @@ def test_grouped_matmul_and_its_gradients_compile_at_the_cells_shapes(
 
 @pytest.mark.parametrize("cell", sorted(EXPERT_BLOCKS))
 def test_a_shares_expert_block_moves_a_window_of_rows(
-        cell, one_chip, no_compile_cache, mosaic_grouped_matmul):
+        cell, one_chip, no_compile_cache):
     """Route, dispatch, the held experts and combine of ``ops/moe.py`` at
     the three share cells' shapes, forward and backward: nothing 65,536 rows
     long and as wide as a row is left (no gather, no ``where``, no buffer of
@@ -462,9 +459,8 @@ def test_labelled_mlm_head_compiles_at_the_cells_shapes(batch, seq, one_chip,
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
     params = jax.tree_util.tree_map(described, jax.eval_shape(
-        lambda k: model.init(k, jnp.zeros((2, seq), jnp.int32),
-                             jnp.ones((2, seq), jnp.int32))["params"],
-        jax.random.key(0)))
+        lambda: model.init(jax.random.key(0), jnp.zeros((2, seq), jnp.int32),
+                           jnp.ones((2, seq), jnp.int32))["params"]))
     batch_shapes = {k: jax.ShapeDtypeStruct((batch, seq), jnp.int32,
                                             sharding=one_chip)
                     for k in ("input_ids", "labels", "attention_mask")}
@@ -482,8 +478,7 @@ def test_labelled_mlm_head_compiles_at_the_cells_shapes(batch, seq, one_chip,
     assert text.count(" while(") == 2 and " conditional(" not in text
 
 
-def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache,
-                                                 mosaic_grouped_matmul):
+def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     """``joyai-llm-flash.s8192.b1.zipf``: value and gradient of the loss
     ``KVStore.make_step`` differentiates, at the configuration's published
     widths and [1, 8192] tokens. Eighteen Mosaic flash calls, three for each
@@ -509,11 +504,11 @@ def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache,
         return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(lambda k: joyai.init_params(k, cfg),
-                                    jax.random.key(0)))
+    params = on_chip(jax.eval_shape(
+        lambda: joyai.init_params(jax.random.key(0), cfg)))
     ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
     bias = on_chip(jax.eval_shape(lambda: joyai.init_expert_bias(cfg)))
-    loss = joyai.make_loss_fn(cfg, attn="flash", interpret=False)
+    loss = joyai.make_loss_fn(cfg, attn="flash")
     compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
         params, {"inputs": ids, "targets": ids}, bias).compile()
     calls = [line for line in compiled.as_text().splitlines()

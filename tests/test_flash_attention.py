@@ -646,9 +646,12 @@ def test_the_cells_windowed_calls_take_the_band_step(cell):
         assert tiles[0] % tiles[1] == 0 and seq % tiles[0] == 0
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((b, seq, h_kv, d), jnp.bfloat16)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, window=window,
-        interpret=False).astype(jnp.float32)), argnums=(0, 1, 2)))(q, kv, kv)
+    with jax.default_device("tpu"):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=True,
+                window=window).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, kv, kv)
     calls = dict(_pallas_calls(jaxpr.jaxpr))
     assert sorted(calls) == ["_band_dkv_kernel", "_band_dq_kernel",
                              "_band_fwd_kernel"]
@@ -823,10 +826,11 @@ def test_without_a_window_the_program_is_the_one_it_was(monkeypatch, cell):
     v = jax.ShapeDtypeStruct(shape[:2] + (h_kv, d_v), jnp.bfloat16)
 
     def trace():
-        return jax.make_jaxpr(jax.value_and_grad(
-            lambda q, k, v: jnp.sum(flash_attention(
-                q, k, v, causal=causal, interpret=False).astype(jnp.float32)),
-            argnums=(0, 1, 2)))(q, k, v)
+        with jax.default_device("tpu"):
+            return jax.make_jaxpr(jax.value_and_grad(
+                lambda q, k, v: jnp.sum(flash_attention(
+                    q, k, v, causal=causal).astype(jnp.float32)),
+                argnums=(0, 1, 2)))(q, k, v)
 
     def text(jaxpr):
         return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
@@ -1038,11 +1042,12 @@ def test_without_an_edge_the_program_is_the_one_it_was(cell):
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     k = jax.ShapeDtypeStruct(shape[:2] + (h_kv, shape[3]), jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape[:2] + (h_kv, d_v), jnp.bfloat16)
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(
-        lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=causal, window=window,
-            interpret=False).astype(jnp.float32)), argnums=(0, 1, 2)))(
-                q, k, v)
+    with jax.default_device("tpu"):
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=causal,
+                window=window).astype(jnp.float32)), argnums=(0, 1, 2)))(
+                    q, k, v)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
@@ -1063,16 +1068,16 @@ def test_the_edged_calls_are_the_program_they_were(strict, want):
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, edge_block=4,
-                              strict_edge=strict, return_lse=strict,
-                              interpret=False)
+                              strict_edge=strict, return_lse=strict)
         if not strict:
             return jnp.sum(out.astype(jnp.float32))
         out, lse = out
         return jnp.sum(out.astype(jnp.float32)) + jnp.sum(
             jnp.logaddexp(lse, 0.0))
 
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
-        q, kv, kv)
+    with jax.default_device("tpu"):
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+            q, kv, kv)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from ps_tpu.ops import gated_conv
-from ps_tpu.ops.gated_conv import causal_taps, conv_silu, path, shift, tiles
+from ps_tpu.ops.gated_conv import (causal_taps, conv_silu, conv_silu_kernel,
+                                   path, shift, tiles)
 
 #: batch, sequence, channels, taps, bias, dtype, (block elements, lanes at
 #: most) -> the (rows, lanes) of a grid step they give
@@ -73,7 +74,7 @@ def test_the_kernels_are_the_plain_form(case, monkeypatch):
     assert path(x, w) == "kernel"
     assert tiles(seq, channels, x.dtype.itemsize) == want
 
-    y, vjp = jax.vjp(lambda *a: conv_silu(*a, interpret=True), x, w, b)
+    y, vjp = jax.vjp(conv_silu_kernel, x, w, b)
     ref, ref_vjp = jax.vjp(_plain, x, w, b)
     assert y.dtype == x.dtype and y.shape == x.shape
     tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
@@ -103,9 +104,9 @@ def test_a_sequences_first_rows_read_nothing_of_the_one_before(monkeypatch):
     rng = np.random.default_rng(7)
     x = jnp.asarray(rng.normal(size=(2, 48, 128)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(128, 4)), jnp.float32)
-    alone = conv_silu(x[1:], w, interpret=True)
+    alone = conv_silu_kernel(x[1:], w)
     for other in (x[0], 1e6 * jnp.ones_like(x[0])):
-        both = conv_silu(jnp.stack([other, x[1]]), w, interpret=True)
+        both = conv_silu_kernel(jnp.stack([other, x[1]]), w)
         np.testing.assert_array_equal(np.asarray(both[1]),
                                       np.asarray(alone[0]))
 
@@ -130,18 +131,17 @@ def test_the_shapes_alone_choose_the_realisation(shape, taps, dtype, want):
         assert lanes % 128 == 0 and rows * lanes <= gated_conv._BLOCK
 
 
-@pytest.mark.parametrize("shape, interpret", [
-    ((2, 24, 96), True),        # a toy: whatever ``interpret`` says
-    ((1, 4096, 128), None),     # a kernel's shape, and a caller that says
-], ids=["toy", "no-word"])      # nothing of the kernels
-def test_the_xla_form_is_what_the_others_take(shape, interpret):
+@pytest.mark.parametrize("shape, form, want", [
+    ((2, 24, 96), conv_silu_kernel, "plain"),   # a toy under the kernels' name
+    ((1, 4096, 128), conv_silu, "kernel"),      # a kernel's shape, and a
+], ids=["toy", "no-word"])                      # caller of the XLA form's
+def test_the_xla_form_is_what_the_others_take(shape, form, want):
     """No Mosaic call in the trace, value or gradient."""
     x = jax.ShapeDtypeStruct(shape, jnp.float32)
     w = jax.ShapeDtypeStruct((shape[-1], 4), jnp.float32)
-    assert path(x, w) == ("plain" if interpret else "kernel")
+    assert path(x, w) == want
     jaxpr = jax.make_jaxpr(jax.grad(
-        lambda x, w: jnp.sum(conv_silu(x, w, interpret=interpret)),
-        argnums=(0, 1)))(x, w)
+        lambda x, w: jnp.sum(form(x, w)), argnums=(0, 1)))(x, w)
     assert "pallas_call" not in str(jaxpr)
 
 

@@ -318,7 +318,7 @@ def test_a_checkpoint_that_keeps_the_named_residuals_drops_the_forward_call(
 
 
 def test_the_mixers_checkpoint_keeps_its_inputs_and_the_named_three(capsys):
-    """``models/kimi_linear.py::_kda_of`` at a kernel-path width: its
+    """``models/kimi_linear.py::_mixer`` at a kernel-path width: its
     gradient holds the forward call once and the backward call, and what
     lives from the forward pass to the backward pass is its inputs and the
     kernel's three named residuals (``KEPT``), nothing of the taps, the
@@ -341,7 +341,7 @@ def test_the_mixers_checkpoint_keeps_its_inputs_and_the_named_three(capsys):
                "out_norm": {"scale": 1 + 0.1 * normal(width)}}
 
     def loss(*args):  # linear in the mixer's output: it keeps nothing itself
-        return jnp.sum(kimi_linear._kda_of(*args, heads, 1e-5))
+        return jnp.sum(kimi_linear._mixer(*args, heads, 1e-5))
 
     args = (projected, weights)
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(*args).jaxpr
@@ -916,12 +916,18 @@ def test_the_cells_program_is_the_one_before_the_latent_block_moved():
     Re-pinned by PR 57 (the commit after c7e065a), on purpose: the twelve
     ``conv_silu`` bodies of the four KDA layers (q, k and v: forward, the
     forward again under the mixer's checkpoint, backward) are Mosaic calls
-    (``ops/gated_conv.py``), and ``interpret`` reaches them as a static
-    argument of that checkpoint. Shown first on that tree with the taps
+    (``ops/gated_conv.py``). Shown first on that tree with the taps
     alone put back (``gated_conv.path`` answering "plain" and
     ``gated_conv._conv_silu`` given c7e065a's two rules, letter for letter):
     the hash was 7b3f956's, ``c949d15bfbd55590``, so nothing else of the
-    program moved."""
+    program moved.
+
+    Re-pinned by PR 58 to the program the chip traces, under
+    ``jax.default_device("tpu")``: until then the hash (``7a8428adbec9c2e9``)
+    was of a program no machine runs, flash and the taps through Mosaic and
+    the grouped matmuls and the KDA rule interpreted, the CPU's answer.
+    Shown first that it is d251cd7's: on that tree with ``jax.devices``
+    answering a TPU the same trace gave this hash (``CHANGES.md``, PR 58)."""
     import hashlib
     import re
 
@@ -931,9 +937,11 @@ def test_the_cells_program_is_the_one_before_the_latent_block_moved():
                             jax.random.key(0))
     ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
     bias = jax.eval_shape(lambda: kimi_linear.init_expert_bias(cfg))
-    loss = kimi_linear.make_loss_fn(cfg, attn="flash", interpret=False)
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(
-        params, {"inputs": ids, "targets": ids}, bias)
+    loss = kimi_linear.make_loss_fn(cfg, attn="flash")
+    with jax.default_device("tpu"):
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(
+            params, {"inputs": ids, "targets": ids}, bias)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    assert "interpret=True" not in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == "7a8428adbec9c2e9"
+        == "ed78106ae5358e69"

@@ -35,7 +35,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from ps_tpu.ops import gated_conv  # noqa: E402
+from ps_tpu.ops import gated_conv, mosaic  # noqa: E402
 
 #: sequence, channels, bias
 CELLS = {"granite": (8192, 4352, True), "kimi": (8192, 4096, False),
@@ -104,7 +104,7 @@ def main() -> int:
     if not args.rehearse and device.platform != "tpu":
         print("no TPU found: a time comes from the chip", file=sys.stderr)
         return 1
-    interpret = device.platform != "tpu"
+    interpret = mosaic.interpret()
     chains, calls = (1, 1) if args.rehearse else (args.chains, args.per_chain)
     table = {"device": device.device_kind, "seed": args.seed, "cells": {}}
     for name in args.cells.split(","):

@@ -33,6 +33,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from ps_tpu.ops import mosaic  # noqa: E402
+
 fa = importlib.import_module("ps_tpu.ops.flash_attention")
 
 #: sequence, query heads, K/V heads, head width, window
@@ -122,7 +124,7 @@ def main() -> int:
     if not args.rehearse and device.platform != "tpu":
         print("no TPU found: a time comes from the chip", file=sys.stderr)
         return 1
-    interpret = device.platform != "tpu"
+    interpret = mosaic.interpret()
     bands = [tuple(int(x) for x in b.split("x"))
              for b in args.bands.split(",")]
     if args.rehearse:
