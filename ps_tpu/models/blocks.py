@@ -164,11 +164,14 @@ def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
     ``heads`` heads of ``head_dim`` on ``groups`` groups of B and C over a
     state of ``state``: ``[z | xBC | dt] = x W_in`` (which bears the name
     'mamba_in'); the x, B and C channels through the causal taps, the bias
-    and the SiLU of ``ops/gated_conv.py::conv_silu`` in its XLA form (what
-    comes out feeds the scan's einsums in layouts XLA has to be free to
-    choose: a Mosaic call there cost the Granite cell 1%); ``dt = softplus(dt +
-    dt_bias)`` in f32; the scan in its chunked form (``ops/ssd.py``, chunks
-    of ``chunk``), whose output bears the name 'mamba_ssd'; then, in f32, the
+    and the SiLU of ``ops/gated_conv.py::conv_silu`` in its XLA form (in
+    front of the XLA scan its Mosaic form cost the Granite cell 1%, PR 57;
+    in front of the scan's Mosaic calls it is measured and not merged,
+    ``PERF.md`` section 7); ``dt = softplus(dt + dt_bias)`` in f32; the scan
+    (``ops/ssd.py`` in chunks of ``chunk``: two Mosaic calls at the cells'
+    shapes, whose forward runs again in a layer's recomputation for the
+    states its backward reads, so the output bears no name for a policy to
+    keep; the XLA form at shapes the kernels do not take); then, in f32, the
     skip ``D x``, the gate ``silu(z)`` first and an RMSNorm over each group's
     channels after it (a share that holds whole groups has the uncut mixer's
     norm over them, exactly; at one group the norm is over all ``heads *
@@ -187,9 +190,8 @@ def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
     xs = xs.reshape(b, s, heads, head_dim)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
     with jax.named_scope(phases.MAMBA_SSD):
-        y = checkpoint_name(ssd(
-            xs, dt, -jnp.exp(lp["A_log"]), b_in.reshape(b, s, groups, -1),
-            c_in.reshape(b, s, groups, -1), chunk=min(chunk, s)), "mamba_ssd")
+        y = ssd(xs, dt, -jnp.exp(lp["A_log"]), b_in.reshape(b, s, groups, -1),
+                c_in.reshape(b, s, groups, -1), chunk=min(chunk, s))
     with jax.named_scope(phases.MAMBA_GATE):
         y = y.astype(jnp.float32) + lp["D"][:, None] * xs.astype(jnp.float32)
         # the gate first, then the norm over each group's channels

@@ -27,8 +27,10 @@ to. How they are computed here:
 
 - the mixer: ``blocks.mamba_block`` at all ``mamba_n_heads`` heads and
   ``mamba_n_groups`` groups, the scan in chunks of ``mamba_chunk_size``
-  (``ops/ssd.py``: all 64 heads in one batched form, whose gradient holds
-  one copy of their decays, 0.5e9 B inside one layer's backward);
+  (``ops/ssd.py``: at the cell's shape one Mosaic call forward and one
+  backward over all 64 heads, the decays and the states in VMEM; what lives
+  between the two is the f32 states that entered each chunk, 134 MB inside
+  one layer's backward);
 - attention: q, k, v without bias or position; the attention closure scales
   by ``head_dim ** -0.5``, so q is multiplied by ``attention_multiplier *
   head_dim ** 0.5`` first (1/64 x 8 = 0.125, a power of two: exact in
@@ -47,17 +49,19 @@ what the layer keeps from its forward pass for its backward pass beside the
 residual stream (4 KB a token a layer in bf16). ``ops/flash_attention.py::
 KEPT``: the attention layer's flash output and logsumexp, which only the
 forward kernel can produce (34 MB + 1 MB in the cell). ``PRODUCTS_KEPT``,
-beside ``_layer``: of the five values a layer names (a mixer's in projection
-'mamba_in' 139 MB and its scan's output 'mamba_ssd' 67 MB, the attention's q /
-k / v 50 MB, the SwiGLU's ``x W_in`` 'ffn_in' 268 MB a layer at 8,192 tokens
-in bf16) **what the compiled peak leaves room for**: 772M parameters take
-12.37e9 B of the chip's 17.18e9 with their moments and gradients, and all
-five kept take 15.7e9 B, 91%. Kept: q / k / v; the SwiGLU's ``x W_in``, the
-largest product a layer makes again (4.0 ms a layer); and the scan's output,
-with which the recomputed layer makes the scan's decays and states again but
-not its output (the step runs 3.2% faster for 0.6e9 B). 14.48e9 B compiled,
-84%. The mixer's in projection (2.1 ms a layer for 1.25e9 B more) is made
-again. The list is this file's
+beside ``_layer``: of the four values a layer names (a mixer's in projection
+'mamba_in' 139 MB, the attention's q / k / v 50 MB, the SwiGLU's ``x W_in``
+'ffn_in' 268 MB a layer at 8,192 tokens in bf16) **what the compiled peak
+leaves room for**: 772M parameters take 12.37e9 B of the chip's 17.18e9 with
+their moments and gradients. Kept: q / k / v and the SwiGLU's ``x W_in``, the
+largest product a layer makes again (4.0 ms a layer). The scan's output is
+not a fifth (it was until PR 59, 0.6e9 B): the recomputed layer runs the
+scan's forward call again for the states its backward call reads, which
+gives the output with them, and the step with the output kept and the
+states made by a pass of their own ran 1.4% slower (``PERF.md`` section 6,
+PR 59). 13.76e9 B at the run's peak, 80%. The mixer's in projection (2.1 ms a layer for 1.25e9 B more) is made
+again.
+The list is this file's
 constant, argued from the compiled step's memory in ``PERF.md`` (PR 56): what
 fits is a property of this model in its cell, which nothing in a layer's
 input shows. A name is the identity where no policy lists it.
@@ -256,10 +260,9 @@ def swiglu(lp: Dict, x, width: int):
 
 #: what a layer keeps beside the flash call's residuals (module docstring), by
 #: the names the values bear where they are made: the attention layer's q, k
-#: and v, the SwiGLU's ``x W_in`` and the scan's output. A mixer's in
-#: projection ('mamba_in') is made again: 1.25e9 B over the nine layers that
-#: would put the compiled peak at 91% of the chip
-PRODUCTS_KEPT = ("attn_q", "attn_k", "attn_v", "ffn_in", "mamba_ssd")
+#: and v and the SwiGLU's ``x W_in``. A mixer's in projection ('mamba_in') is
+#: made again: 1.25e9 B over the nine layers
+PRODUCTS_KEPT = ("attn_q", "attn_k", "attn_v", "ffn_in")
 
 
 def _add(x, part, multiplier: float):

@@ -55,10 +55,13 @@ one temporary (143 MB), at all three cells' shapes
 (``tests/test_chip_compile.py``). The gain is the backward's: alone, the
 forward call takes what it took (the fusion is bound by its sublane
 shuffles, not by its bytes). Its place is where XLA's own fusions read the
-result: Granite's scan (``ops/ssd.py``) takes ``xs`` in three layouts, and
-XLA writes each from a taps fusion of its own; a custom call pins one
-row-major result and the copies into the other layouts then cost what the
-kernel saved (the table's last column).
+result: until PR 59 Granite's scan (``ops/ssd.py``'s XLA form) took ``xs``
+in three layouts, and XLA wrote each from a taps fusion of its own; a custom
+call pins one row-major result and the copies into the other layouts then
+cost what the kernel saved (the table's last column). Since PR 59 the scan
+at the cells' shapes is a Mosaic call that reads ``xs`` row-major; what the
+Mosaic taps do in front of it is measured in ``PERF.md`` section 7 and not
+merged.
 
 *The Mosaic calls* (``conv_silu_kernel``: ``forward`` / ``backward``; what
 ``models/kimi_linear.py::_mixer`` calls, whose q, k and v go on to the KDA

@@ -33,30 +33,50 @@ nats over a chunk), so it is masked *before* the exponential, as
 gradient. A scalar decay makes ``L`` a [Q, Q] matrix a head: no sub-blocks
 are needed, where KDA's per-channel decay forces them.
 
-**Differentiated by autodiff.** What it keeps for the backward pass is the
-caller's to bound: ``L`` is ``4 Q^2`` bytes a head a chunk in f32 (64 KB at
-chunks of 128, 256 KB at 256), so a model runs each layer under one
-``jax.checkpoint`` and between the layers only the residual stream lives on.
-Over all heads a copy of ``L`` is 67 MB at Nemotron-H's share (16 heads, 64
-chunks of 128) and 537 MB at Granite-4.0-H's whole mixer (64 heads on one
-group, 32 chunks of 256), and one copy is what the gradient holds: XLA fuses
-the masked exponential into the product that reads it, so the gradient at
-``[1, 8192, 64, 64]`` compiles to 0.49e9 B of temporaries
-(``tests/test_chip_compile.py``). A form over blocks of 16 heads, each under a
-checkpoint of its own in a ``lax.map``, held 0.04e9 B and ran the Granite cell
-0.8% faster; it was not kept for that (``PERF.md`` section 6, PR 56). A layer
-whose policy keeps the scan's output (``models/blocks.py`` names it
-'mamba_ssd') recomputes less of the form: Granite's cell runs 3.2% faster for
-67 MB a layer.
+**Two realisations of the chunked form, chosen by the operands' shapes and
+dtype alone** (``ssd_mosaic.takes``; no argument, name or environment
+variable enters it).
 
-Precision: the cumulated sums, the decays, ``dt x`` and the carried state in
-f32; the four products take their operands in ``x``'s dtype (the
-configuration's compute dtype: one bf16 pass on the MXU) and accumulate in
-f32.
+*The Mosaic calls* (``ops/ssd_mosaic.py``; both cells' shapes: Granite-4.0-H's
+whole mixer, 64 heads of 64 on one B/C group of state 128 in chunks of 256,
+and Nemotron-H's share of 16 heads in chunks of 128, in bf16): one call
+forward and one backward behind a ``jax.custom_vjp``, over ``x`` as the mixer
+has it, ``[B, T, H P]`` row-major. The grid walks the sequence in chunks of
+128 tokens (sub-chunks of the configuration's: the recurrence is the same
+whatever the chunk), all heads' ``[P, N]`` states stay in a VMEM scratch, a
+head's ``L`` is built on the tile and dies in the product that reads it, and
+``dB`` / ``dC`` are summed over the group's heads inside the call. What lives
+from the forward call to the backward one is the f32 states that entered each
+chunk (134 MB at Granite's shape): under a layer's ``jax.checkpoint`` the
+forward call runs again for them and gives the output with them, so a layer's
+policy keeps nothing of the scan (``PERF.md`` section 6, PR 59: the three
+ways that were measured). Off the chip the same kernels run in interpret mode
+(``ops/mosaic.py::interpret``). Under a mesh of several devices the calls are
+not wrapped in ``shard_map`` as ``ops/kda.py``'s are: no cell runs this mixer
+across chips.
 
-Plain XLA. ``benchmark/families/nemotron_h_step.py::ssd_cost`` counts the
-operations and bytes of this form from the shapes; ``nemo.ssd_roofline`` is
-the yardstick of the kernel that may replace it.
+*The XLA form* (``_ssd_plain``; every other shape: more than one group, heads
+that do not fill 128-lane tiles, a chunk that is no multiple of 128, the
+``rehearse`` configurations). Differentiated by autodiff. ``L`` is ``4 Q^2``
+bytes a head a chunk in f32 and made over all heads and chunks at once (537
+MB a copy at Granite's shape, of which the gradient compiled to 0.49e9 B of
+temporaries), each chunk's contribution to the state is written, carried by
+a ``while`` over the chunks and read back, and the einsums read ``x`` in
+three layouts that XLA writes from fusions of its own: at the Granite cell it
+took 62.2 ms of a 443 ms step where the kernels take 31.7 (``PERF.md``
+section 6, PR 59).
+
+Precision, in both: the cumulated sums, the decays and the carried state in
+f32; the products take their operands in ``x``'s dtype (the configuration's
+compute dtype: one bf16 pass on the MXU; f32 operands at the highest
+precision in the kernels) and accumulate in f32. The XLA form rounds ``dt x``
+and ``L o C B^T`` each before their product; the kernels round ``L o C B^T o
+dt`` once and take ``x`` as it is.
+
+``benchmark/families/nemotron_h_step.py::ssd_cost`` counts the operations and
+bytes of the chunked form from the configuration's shapes, whatever
+implements it; ``nemo.ssd_roofline`` and ``kernel.ssd_roofline`` are its
+share of what the scan's scope takes.
 """
 
 from __future__ import annotations
@@ -66,7 +86,27 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ps_tpu.ops import mosaic, ssd_mosaic
 from ps_tpu.ops.kda import masked_exp
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ssd_kernel(x, dt, A, B, C, interpret):
+    return ssd_mosaic.forward(x, dt, A, B, C, interpret=interpret,
+                              keep=False)[0]
+
+
+def _ssd_kernel_fwd(x, dt, A, B, C, interpret):
+    y, (states,) = ssd_mosaic.forward(x, dt, A, B, C, interpret=interpret,
+                                      keep=True)
+    return y, (x, dt, A, B, C, states)
+
+
+def _ssd_kernel_bwd(interpret, res, dy):
+    return ssd_mosaic.backward(*res, dy, interpret=interpret)
+
+
+_ssd_kernel.defvjp(_ssd_kernel_fwd, _ssd_kernel_bwd)
 
 
 def ssd(x, dt, A, B, C, *, chunk: int = 128):
@@ -84,6 +124,14 @@ def ssd(x, dt, A, B, C, *, chunk: int = 128):
             f"ssd: {seq} tokens in chunks of {chunk}, {heads} heads on "
             f"B {B.shape} and C {C.shape}: the chunk must divide the "
             f"sequence and the groups the heads")
+    if ssd_mosaic.takes(x, B, chunk):
+        return _ssd_kernel(x, dt, A, B, C, mosaic.interpret())
+    return _ssd_plain(x, dt, A, B, C, chunk)
+
+
+def _ssd_plain(x, dt, A, B, C, chunk: int):
+    """The XLA form, for every shape the kernels do not take."""
+    seq, heads, groups = x.shape[1], x.shape[2], B.shape[2]
     batch, width, state_dim = x.shape[0], x.shape[3], B.shape[3]
     n, per_group = seq // chunk, heads // groups
     mxu = x.dtype

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ps_tpu.ops import flash_attention, grouped_matmul, moe
+from ps_tpu.ops import flash_attention, grouped_matmul, moe, ssd_mosaic
 from ps_tpu.ops.gated_conv import (conv_silu, conv_silu_kernel,
                                    gated_short_conv)
 from ps_tpu.ops.gated_conv import path as taps_path
@@ -293,53 +293,76 @@ def test_the_kda_mixer_keeps_the_kernels_residuals_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 29
 
 
-def test_chunked_ssd_compiles_at_the_cells_shape(one_chip, no_compile_cache):
-    """``ops/ssd.py`` at the Nemotron cell's share, [1, 8192, 16, 64] on one
-    B/C group of state 128 in chunks of 128, forward and backward: plain XLA
-    (no Mosaic call), the state carried by ``while`` loops over the 64
-    chunks, and the chunks' [128, 128] decay matrices of all heads (67 MB in
-    f32) a few times over, not a buffer a token pair."""
-    def arg(*shape, dtype=jnp.bfloat16):
+def _scan_gradient(one_chip, heads, width, state, chunk, dtype):
+    """``ops/ssd.py::ssd``'s gradient at 8,192 tokens of ``heads`` heads of
+    ``width`` on one B/C group, compiled: (its text, its temporaries'
+    bytes). ``x`` enters and its cotangent leaves as the mixer has them,
+    [1, 8192, heads * width]: a [.., heads, width] argument of a program has
+    a tiled layout of its own, which a copy would have to undo."""
+    def arg(*shape, dtype=dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    args = (arg(1, 8192, 16, 64), arg(1, 8192, 16, dtype=jnp.float32),
-            arg(16, dtype=jnp.float32), arg(1, 8192, 1, 128),
-            arg(1, 8192, 1, 128))
+    args = (arg(1, 8192, heads * width),
+            arg(1, 8192, heads, dtype=jnp.float32),
+            arg(heads, dtype=jnp.float32), arg(1, 8192, 1, state),
+            arg(1, 8192, 1, state))
 
     def loss(x, dt, a, b, c):
-        return jnp.sum(ssd(x, dt, a, b, c, chunk=128).astype(jnp.float32))
+        return jnp.sum(ssd(x.reshape(1, 8192, heads, width), dt, a, b, c,
+                           chunk=chunk).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *args).compile()
-    text = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in text
-    assert " while(" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def _entering_states(heads, width=64, state=128):
+    """Bytes of the f32 states that entered each of the kernels' chunks of
+    8,192 tokens: what the forward call keeps for the backward one."""
+    return 8192 // ssd_mosaic.CHUNK * heads * width * state * 4
+
+
+def test_chunked_ssd_compiles_at_the_cells_shape(one_chip, no_compile_cache):
+    """``ops/ssd.py`` at the Nemotron cell's share, 16 heads of 64 on one B/C
+    group of state 128 in chunks of 128, forward and backward: the two Mosaic
+    calls of ``ops/ssd_mosaic.py`` (a silent fall back to the XLA form fails
+    here), no ``while`` over the chunks and no [128, 128] decay matrix in
+    HBM. The temporaries are at most the f32 states that entered each chunk
+    (33.6 MB; the compile counts 0 B here, the states in a buffer it does not
+    count) and the steps re-laid, where the XLA form held 2**30 B."""
+    text, temp = _scan_gradient(one_chip, 16, 64, 128, 128, jnp.bfloat16)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" not in text
+    assert not re.search(r"f32\[[0-9,]*128,128\]", text)
+    assert temp <= _entering_states(16) + 2 ** 22
 
 
 def test_the_scan_compiles_at_granites_shape(one_chip, no_compile_cache):
-    """``ops/ssd.py`` at the Granite cell's whole mixer, [1, 8192, 64, 64] on
-    one B/C group of state 128 in chunks of 256, forward and backward: plain
-    XLA, the state carried by ``while`` loops over the 32 chunks, and of the
-    [256, 256] decay matrices of all 64 heads (537 MB a copy in f32) one copy:
-    0.49e9 B of temporaries, under 5 / 8 GiB, not ISSUE 56's 2-2.5e9 (the
-    masked exponential is fused into the product that reads it)."""
-    def arg(*shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    """``ops/ssd.py`` at the Granite cell's whole mixer, 64 heads of 64 on
+    one B/C group of state 128 in chunks of 256, forward and backward: the
+    same two Mosaic calls (eight blocks of eight heads a chunk), no
+    ``while``, no f32 [.., 256, 256] array over all heads and no copy of an
+    array as large as ``x`` into a second layout. The temporaries are the
+    entering states (134.2 MB) and the steps re-laid for the blocks of eight
+    heads with their cotangents (67.6 MB seen: 2 MB each by their shapes,
+    33.6 as the chip pads a last axis of eight to 128 lanes), where the XLA
+    form's gradient held 0.49e9 B."""
+    text, temp = _scan_gradient(one_chip, 64, 64, 128, 256, jnp.bfloat16)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" not in text
+    assert not re.search(r"f32\[[0-9,]*256,256\]", text)
+    assert not re.search(r"\[1,8192,(4096|64,64)\]\S* copy\(", text)
+    assert _entering_states(64) <= temp <= _entering_states(64) + 2 ** 27
 
-    args = (arg(1, 8192, 64, 64), arg(1, 8192, 64, dtype=jnp.float32),
-            arg(64, dtype=jnp.float32), arg(1, 8192, 1, 128),
-            arg(1, 8192, 1, 128))
 
-    def loss(x, dt, a, b, c):
-        return jnp.sum(ssd(x, dt, a, b, c, chunk=256).astype(jnp.float32))
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        *args).compile()
-    text = compiled.as_text()
+def test_a_rehearse_shape_takes_the_xla_form(one_chip, no_compile_cache):
+    """What the kernels do not take (``ops/ssd_mosaic.py::takes``: a
+    ``rehearse`` configuration's heads of 16 on a state of 16 in chunks of 32,
+    in f32) compiles as the XLA form, the state carried by ``while`` loops:
+    the case that shows the two tests above would see a fall back."""
+    text, _ = _scan_gradient(one_chip, 4, 16, 16, 32, jnp.float32)
     assert 'custom_call_target="tpu_custom_call"' not in text
     assert " while(" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 5 * 2 ** 27
 
 
 #: (tokens, width of the rows, width of an expert, held, router width,

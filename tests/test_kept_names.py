@@ -111,32 +111,28 @@ def test_a_policy_that_lists_routes_names_routes_once(held):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
-# -- Granite-4.0-H's policy: the flash call's residuals, five products of six --
+# -- Granite-4.0-H's policy: the flash call's residuals, four products of five --
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
 def test_granites_layers_keep_what_their_policy_lists(monkeypatch, attn):
-    """``models/granite_h.py::_layer`` names six values (a mixer's in
-    projection and its scan's output, the SwiGLU's ``x W_in``, the
-    attention's q, k and v) and its policy lists five of them beside the
-    flash call's residuals: against a ``jax.checkpoint`` without a policy the
-    loss is the same bits and every gradient the same to a few f32 roundoffs
-    (with the scan's output kept, the cotangents of B and C are summed in
-    another order); the gradient holds ten matrix
-    products fewer: the one attention layer's q, k and v, the four layers'
+    """``models/granite_h.py::_layer`` names five values (a mixer's in
+    projection, the SwiGLU's ``x W_in``, the attention's q, k and v) and its
+    policy lists four of them beside the flash call's residuals: against a
+    ``jax.checkpoint`` without a policy the loss is the same bits and every
+    gradient the same to a few f32 roundoffs; the gradient holds seven matrix
+    products fewer: the one attention layer's q, k and v and the four layers'
     ``x W_in`` ('mamba_in' is made again: the compiled peak has no room for
-    it) and, of each of the three Mamba-2 layers' scans, the one product that
-    its output alone needed, the read of the entering state (at these sizes
-    the heads are one block, whose other products the backward pass reads;
-    over several blocks, as in the cell, each block is recomputed under its
-    own checkpoint and the layer's recomputation holds no scan at all); with
-    'flash', three kernel calls where the policy-less one holds four."""
+    it; the scan's output bears no name since PR 59: in the cell the scan is
+    two Mosaic calls whose forward runs again for the states the backward
+    reads, and gives the output with them); with 'flash', three kernel calls
+    where the policy-less one holds four."""
     from ps_tpu.models import granite_h
     from ps_tpu.ops.flash_attention import KEPT
     import test_granite_h
 
     _, cfg, params, batch = test_granite_h._setup()
     assert granite_h.PRODUCTS_KEPT == ("attn_q", "attn_k", "attn_v",
-                                       "ffn_in", "mamba_ssd")
+                                       "ffn_in")
 
     def trace_and_run():
         jaxpr, out = traced_and_run(jax.value_and_grad(
@@ -155,7 +151,7 @@ def test_granites_layers_keep_what_their_policy_lists(monkeypatch, attn):
         trace_and_run()
     flash = attn == "flash"
     assert (calls, plain_calls) == (3 * flash, 4 * flash)
-    assert plain_products - products == 3 + 4 + 3
+    assert plain_products - products == 3 + 4
     assert float(loss) == float(plain_loss)
     for g, w in zip(jax.tree_util.tree_leaves(grads),
                     jax.tree_util.tree_leaves(plain_grads)):
