@@ -6,7 +6,8 @@ the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
 ``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
 ``models/mellum.py``, ``models/sdar.py``, ``models/joyai.py``,
-``models/granite_h.py``, ``models/blocks.py``, ``ops/moe.py``) and land in the
+``models/granite_h.py``, ``models/qwen3_next.py``, ``models/blocks.py``,
+``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
@@ -69,9 +70,9 @@ LFM2_SCOPES = MOE_SCOPES + (CONV, CONV_GATE, FFN)
 # Read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy. MLA
 # is under ATTN, the dense SwiGLU under FFN. KDA_CONV and KDA_CORE nest under
 # KDA, so KDA's time holds them.
-KDA = "ps.kda"                    # the KDA mixer: projections, taps, gates, the rule, norm, out projection
-KDA_CONV = "ps.kda/conv"          # the three depthwise causal convolutions and their SiLU
-KDA_CORE = "ps.kda/core"          # ops/kda.py alone: the chunked gated delta rule
+KDA = "ps.kda"                    # the delta-rule mixer (Kimi-Linear's KDA, Qwen3-Next's Gated DeltaNet): projections, taps, gates, the rule, norm, out projection
+KDA_CONV = "ps.kda/conv"          # the mixer's depthwise causal taps and their SiLU: Kimi's three filters, Qwen3-Next's one over q | k | v
+KDA_CORE = "ps.kda/core"          # ops/kda.py alone: the chunked gated delta rule, with the broadcasts in front of it where the decay is a head's
 MOE_SHARED = "ps.moe/shared"      # the shared expert, a SwiGLU every token passes
 
 # ``models/blocks.py::mla_block``, the latent layer of this model and of
@@ -156,6 +157,16 @@ JOYAI_SCOPES = MOE_SCOPES + (FFN, MOE_SHARED, ATTN_FULL, ATTN_LATENT,
 # under HEAD. All but MAMBA_GATE are read by
 # ``benchmark/layer_metrics/decoder.py``, which keeps its own copy.
 GRANITE_SCOPES = (ATTN, HEAD, FFN, MAMBA, MAMBA_CONV, MAMBA_SSD, MAMBA_GATE)
+
+# -- scopes of Qwen3-Next (models/qwen3_next.py), beside the six and MOE_SHARED -------
+# All read by ``benchmark/layer_metrics/decoder.py``, which keeps its own copy,
+# but ATTN_ROPE (inside ``decoder.attn_ms``). The delta-rule mixer is under
+# KDA with Kimi-Linear's two inner scopes; the attention layer under ATTN
+# with ATTN_ROPE around the partial rotation, ATTN_FULL around the kernel call
+# and ATTN_GATE around the sigmoid gate that the q projection carries; the
+# shared expert and its own sigmoid gate under MOE_SHARED.
+QWEN3_NEXT_SCOPES = MOE_SCOPES + (KDA, KDA_CONV, KDA_CORE, MOE_SHARED,
+                                  ATTN_FULL, ATTN_ROPE, ATTN_GATE)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

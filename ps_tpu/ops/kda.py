@@ -361,7 +361,28 @@ def kda(q, k, v, g, beta, *, chunk: int = 64):
     of 128) and the forward call runs once; no policy keeps nothing and it
     runs twice. ``path`` says which realisation runs; off the chip the same
     kernels run in interpret mode (``ops/mosaic.py::interpret``), and under
-    ``ps_tpu.init``'s mesh they run in ``shard_map`` (``_under_mesh``)."""
+    ``ps_tpu.init``'s mesh they run in ``shard_map`` (``_under_mesh``).
+
+    Two special cases of the rule are computed as the general one, exactly
+    (``models/qwen3_next.py``'s gated delta rule is both): ``g`` [B, T, H],
+    **one decay a head and a token**, is that decay in each of the head's
+    ``K`` channels; ``q``, ``k`` [B, T, H / r, K], **fewer key heads than
+    value heads**, are each read by the ``r`` value heads that follow one
+    another (value head ``h`` reads key head ``h // r``). Both are broadcast
+    in front of the call (4 K bytes of f32 and 2 x ``itemsize`` K (r - 1) / r
+    a token a value head that a kernel of the special case would not move),
+    and autodiff sums the cotangents back: ``dg`` over the channels, ``dq``
+    and ``dk`` over a key head's readers. ``path`` is asked of the broadcast
+    shapes."""
+    heads = v.shape[2]
+    if heads % q.shape[2] or q.shape[2] != k.shape[2]:
+        raise ValueError(f"kda: {q.shape[2]} query and {k.shape[2]} key "
+                         f"heads for {heads} value heads: equal, and a "
+                         f"divisor of the value heads")
+    if q.shape[2] != heads:
+        q, k = (jnp.repeat(x, heads // x.shape[2], axis=2) for x in (q, k))
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], (*g.shape, q.shape[-1]))
     t = q.shape[1]
     if chunk % SUB or t % chunk:
         raise ValueError(f"kda: {t} tokens in chunks of {chunk}, sub-blocks "

@@ -30,9 +30,13 @@ REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
               "joyai": ("pallas", "roll(", "rotate_half"),
               # Granite-4.0-H's scan is token by token, its attention whole
               # rows under the model's own multiplier
-              "granite_h": ("cumsum", "pallas")}
+              "granite_h": ("cumsum", "pallas"),
+              # Qwen3-Next's rule is token by token, its rotation explicit
+              # pairs, its experts a masked loop
+              "qwen3_next": ("cumsum", "pallas", "rotate_half",
+                             "ragged_dot")}
 MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
-          "mellum", "sdar", "joyai", "granite_h")
+          "mellum", "sdar", "joyai", "granite_h", "qwen3_next")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))

@@ -79,7 +79,11 @@ CALLS = {"lfm2-24b-a2b.s8192.zipf": ((2, 8192, 32, 8, 64), True, 3),
              ((1, 16384, 32, 4, 128), True, 3),
          # Mellum's three layers of four that see 1,024 keys of 8,192
          "mellum2-12b-a2.5b.s8192.b1.zipf.x4, windowed":
-             ((1, 8192, 32, 4, 128), True, 3)}
+             ((1, 8192, 32, 4, 128), True, 3),
+         # Qwen3-Next's gated attention: keys and values of 256 alike, the
+         # widest head and the widest group (eight query heads a K/V head)
+         "qwen3-next-80b-a3b.s8192.b1.zipf":
+             ((1, 8192, 16, 2, 256), True, 3)}
 #: the window of a cell's call, where it has one: the band step
 #: (``ops/flash_attention.py``), a query block against the slab of key
 #: blocks it sees passed as so many operands, at the block and sub-block the
@@ -248,6 +252,36 @@ def test_chunked_kda_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+
+
+def test_the_scalar_decay_rule_compiles_as_qwen3_next_calls_it(
+        one_chip, no_compile_cache):
+    """``ops/kda.py`` as ``models/qwen3_next.py::gdn_block`` calls it: q and
+    k [1, 8192, 16, 128] read by 32 value heads, ``g`` [1, 8192, 32] one
+    decay a head. The broadcast shapes take the Mosaic kernels (``path``),
+    the gradient holds their two calls and no loop of XLA's, and the five
+    gradients come back at the operands' own shapes (the sums over a key
+    head's two readers and over the decay's channels are autodiff's)."""
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    keys, values = (1, 8192, 16, 128), (1, 8192, 32, 128)
+    args = (arg(*keys), arg(*keys), arg(*values),
+            arg(*values[:3], dtype=jnp.float32),
+            arg(*values[:3], dtype=jnp.float32))
+    assert path(arg(*values), arg(*values), args[2], 64) == "kernel"
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda(q, k, v, g, beta).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " while(" not in text
+    grads = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
+    assert [x.shape for x in grads] == [a.shape for a in args]
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
 
 

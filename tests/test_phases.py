@@ -175,7 +175,7 @@ def test_program_and_benchmark_share_their_names():
                  "INPUT_SOURCE_WAIT", "INPUT_PRODUCE"):
         assert getattr(phases, name) == getattr(host, name)
     # the decoders' scopes: the one reader's one copy, every name of it the
-    # program's, and a metric for every scope a model opens (the nine
+    # program's, and a metric for every scope a model opens (the ten
     # ``*_SCOPES``) but, until each is given one, the program's own six:
     # ps.attn/inblock, ps.attn/latent and ps.attn/rope, which count inside
     # decoder.attn_ms, ps.mamba/gate, which counts inside decoder.mamba_ms,
@@ -188,7 +188,7 @@ def test_program_and_benchmark_share_their_names():
         assert getattr(phases, name) == getattr(decoder, name), name
     families = [name for name in vars(phases) if name.endswith("_SCOPES")]
     opened = set().union(*(getattr(phases, name) for name in families))
-    assert len(families) == 9
+    assert len(families) == 10
     assert opened - PROGRAMS_OWN <= set(decoder.METRICS) <= opened
     assert PROGRAMS_OWN <= opened and not PROGRAMS_OWN & set(decoder.METRICS)
     assert not opened & set(phases.DEVICE_PHASES)
@@ -907,6 +907,48 @@ READER_CASES = {
                 "decoder.ce": 9.5, "decoder.mtp_ce": 9.75,
                 "decoder.mtp_positions": 8191.0},
         "mfu": 5.0, "device_ms": 33.0},
+    "qwen3_next": {   # three delta-rule layers and one gated attention layer
+        "events": [
+            ("%qkvz", "fusion", 0.004, _CP + "ps.kda/dot_general"),
+            ("%taps", "custom-call", 0.002,
+             _CP + "ps.kda/ps.kda/conv/pallas_call"),
+            # the broadcast of the decay and the repeat of the key heads are
+            # under the rule's scope with its two Mosaic calls
+            ("%spread", "fusion", 0.002,
+             _CP + "ps.kda/ps.kda/core/broadcast_in_dim"),
+            ("%rule", "custom-call", 0.008,
+             _RULE + "ps.kda/ps.kda/core/pallas_call"),
+            ("%q", "fusion", 0.004, _BACK + "ps.attn/dot_general"),
+            ("%turn", "fusion", 0.001, _CP + "ps.attn/ps.attn/rope/mul"),
+            ("%fwd", "custom-call", 0.004,
+             _CP + "ps.attn/ps.attn/full/pallas_call"),
+            ("%dkv", "custom-call", 0.006,
+             _RULE + "ps.attn/ps.attn/full/pallas_call"),
+            ("%gate", "fusion", 0.002, _CP + "ps.attn/ps.attn/gate/mul"),
+            # the shared expert with its own sigmoid gate
+            ("%shared", "fusion", 0.002, _CP + "ps.moe/shared/logistic"),
+            *_EXPERTS, *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 4e9,
+                  "flops_per_pair": 1e6, "kda_core_flops": 1.0,
+                  "kda_core_bytes": 0.5e9, "flash_flops": 2e9,
+                  "flash_bytes": 1.0},
+        "counters": {**_HELD, "held_pair_share": 0.0625},
+        "want": {**_EXPERTS_WANT,
+                 "decoder.kda_ms": 8.0,        # with taps, spread and rule
+                 "decoder.kda_conv_ms": 1.0, "decoder.kda_core_ms": 5.0,
+                 "decoder.attn_ms": 8.5,       # rope, cores and gate in it
+                 "decoder.full_core_ms": 5.0, "decoder.attn_gate_ms": 1.0,
+                 "decoder.shared_ffn_ms": 1.0,
+                 # the scalar-decay rule's least bytes: 0.5 of 5 ms, the
+                 # broadcast's time in the 5
+                 "kernel.kda_core_roofline": 10.0,
+                 # 2 ms of MXU over the two Mosaic calls' 5 ms; the taps'
+                 # and the rule's calls lie under ps.kda and are not its
+                 "kernel.flash_roofline": 40.0,
+                 "decoder.held_pair_share": 0.0625},
+        # S11 row 0: the one scope of this model without a name of its own
+        "may": {"decoder.attn_rope_ms": 0.5},
+        "mfu": 5.0, "device_ms": 30.5},
     "granite": {   # dense: no expert, no counter; a mixer and a SwiGLU a layer
         "events": [
             ("%in", "fusion", 0.004, _CP + "ps.mamba/dot_general"),
@@ -1727,6 +1769,55 @@ def test_granite_scopes_reach_the_step_hlo_forward_and_backward(
     assert not [n for n in names.values() if "ps.moe" in n]
 
 
+def _qwen3_next_step():
+    """``(run, batch)`` of ``make_step`` on a tiny Qwen3-Next: a delta-rule
+    layer and a gated attention layer, each with its experts."""
+    from ps_tpu.models import qwen3_next
+
+    cfg = qwen3_next.Qwen3NextConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=2,
+        full_attention_interval=2, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, router_width=8, num_experts=2,
+        expert_start=2, num_experts_per_tok=3, dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: qwen3_next.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    return (store.make_step(qwen3_next.make_loss_fn(cfg), has_aux=True),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_qwen3_next_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Qwen3-Next opens (``QWEN3_NEXT_SCOPES``): the six it shares with
+    OLMoE, the delta-rule mixer's three (Kimi-Linear's names), ``ps.moe/shared``
+    around the shared expert and its gate, and under ``ps.attn`` the rotation,
+    the core and the gate: each in the lowered step's ``op_name``s under
+    ``ps.grad``, forward and backward, though every layer is under a
+    ``jax.checkpoint``. Held equal to the reader's copy: every one of them
+    but the rotation's has its metric (``decoder.METRICS``)."""
+    assert phases.QWEN3_NEXT_SCOPES == phases.MOE_SCOPES + (
+        phases.KDA, phases.KDA_CONV, phases.KDA_CORE, phases.MOE_SHARED,
+        phases.ATTN_FULL, phases.ATTN_ROPE, phases.ATTN_GATE)
+    assert set(phases.QWEN3_NEXT_SCOPES) - {phases.ATTN_ROPE} \
+        <= set(decoder.METRICS)
+    monkeypatch.setitem(BUILDERS, "qwen3_next", _qwen3_next_step)
+    names = scope.op_names_of(_step_hlo("qwen3_next"))
+    for s in phases.QWEN3_NEXT_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == (set(phases.QWEN3_NEXT_SCOPES) - PROGRAMS_OWN) | {None}
+    assert {decoder.scope_of("%x", n) for n in names.values()
+            if phases.ATTN_ROPE in n} == {phases.ATTN}
+
+
 # -- the benchmark's own command on the CPU, and its manifest ------------------
 
 with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
@@ -1770,6 +1861,9 @@ REHEARSED = {
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib"),
     "granite-4.0-h-micro.s8192.b1.zipf": (
+        "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
+        "setup.import_s", "device.peak_hbm_gib"),
+    "qwen3-next-80b-a3b.s8192.b1.zipf": (
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib")}
 

@@ -282,6 +282,29 @@ def test_kimi_grad_check_rehearses():
     assert found["loss_rel_diff"] > 5e-5
 
 
+def test_qwen3_next_grad_check_rehearses():
+    """tools/qwen3_next_grad_check.py at the configuration's tiny sizes: the
+    system's gradients are the reference's on all 70 tensors and the system
+    comes out ``correct`` through the family's own ``step0_checks``; the
+    reference on 8-bit weights and every planted fault but one do not. The
+    one: the gate in front of the head norm, which at a tiny width moves no
+    witness past its limit (on the chip neither: ``PERF.md`` section 6, PR
+    60)."""
+    out = _run("qwen3_next_grad_check.py", "--rehearse")
+    assert out["tensors_read"] == 70
+    assert out["worst"]["cosine"] > 1 - 1e-6
+    assert out["system"]["correct"] and out["system"]["failed"] == []
+    assert out["system"]["pairs_on_another_expert"] == [0, 0, 0, 0]
+    cases = {k: v for k, v in out.items() if k.startswith("reference_")}
+    assert len(cases) == 6
+    assert not cases["reference_on_e4m3_weights"]["correct"]
+    for name in ("a_norm_whose_scale_ignores_w",
+                 "key_heads_tiled_not_repeated", "every_channel_rotated",
+                 "the_picks_not_renormalised"):
+        assert "gradient_matches_reference" in \
+            cases[f"reference_with_{name}"]["failed"], name
+
+
 @pytest.mark.parametrize("model", ["nemotron_h", "granite_h"])
 def test_nemotron_grad_check_rehearses(model):
     """tools/nemotron_grad_check.py at a configuration's tiny sizes, the
