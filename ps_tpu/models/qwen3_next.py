@@ -36,11 +36,13 @@ this module is held to. What differs here is how they are computed:
   value head; q and k L2-normalised a head; the log-decay ``-exp(A_log) *
   softplus(a + dt_bias)`` and the write strength ``sigmoid(b)`` a value head;
   the rule in its chunked form (``ops/kda.py`` at ``g`` [B, S, H] and q, k of
-  16 heads: the general rule computing the special case, on the Mosaic
-  kernels at the published widths); the per-head RMSNorm **first** and the
-  gate ``silu(z)`` **after** it (``models/blocks.py::mamba_block`` gates
-  first); the out projection. Taps, normalisation, gates, decays, state and
-  norm in f32, the projections in ``dtype``.
+  16 heads: at the published widths the scalar-decay Mosaic kernels, which
+  read a key head once for its two value heads and one decay a head; the
+  general rule on broadcast operands at the tests' widths); the per-head
+  RMSNorm **first** and the gate ``silu(z)`` **after** it
+  (``models/blocks.py::mamba_block`` gates first); the out projection. Taps,
+  normalisation, gates, decays, state and norm in f32, the projections in
+  ``dtype``.
 - ``attention_block``: ``[q | gate] = x W_q`` a head at a time (256 | 256),
   q and k under their zero-centred norms, the first ``partial_rotary_factor``
   of the channels rotated (half-split pairs, ``blocks.rope`` over those

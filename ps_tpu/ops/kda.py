@@ -45,18 +45,27 @@ exponent too, so no gradient is 0 x inf.
 products of [C, C] matrices at the highest precision, batched over every chunk
 and head, in place of ``solve_triangular``'s forward substitution (the table).
 
-**Two realisations, chosen by shape** (``path``): where a head's keys and
-values fill whole 128-lane tiles and the chunk is 64 (the benchmark's cell:
-32 heads of 128), the rule runs as the Mosaic kernels of
-``ops/kda_mosaic.py`` behind a ``custom_vjp``: the state stays in VMEM
-across the chunks of a head, a chunk's internals live and die there, the
-operands are read as the projections write them ([B, T, H * K], no
-transposed f32 copy), and the forward rule keeps for the backward, beside
-its operands, only the state entering each chunk and the chunk's inverse.
-Everything else (the tests' models at widths 16 and 32) runs the plain XLA
-form below, which is also the kernels' second oracle beside the
-token-by-token recurrence. One algorithm, the same arithmetic classes
-("Precision"), no switch to set.
+**Three realisations, chosen by shape** (``path``): where a head's keys and
+values fill whole 128-lane tiles and the chunk is 64 (the benchmark's cells:
+32 heads of 128), the rule runs as Mosaic kernels of ``ops/kda_mosaic.py``
+behind a ``custom_vjp``: the state stays in VMEM across the chunks of a
+head, a chunk's internals live and die there, the operands are read as the
+projections write them ([B, T, H * K], no transposed f32 copy), and the
+forward rule keeps for the backward, beside its operands, only the state
+entering each chunk and the chunk's inverse. Which kernels is read from the
+decay's shape: ``g`` [B, T, H, K], **a decay a channel** (Kimi-Linear), takes
+the per-channel kernels (``_chunk``: the decays by halving levels); ``g``
+[B, T, H], **one decay a head and a token** (Qwen3-Next's gated delta rule),
+takes the scalar-decay kernels (``_scalar_chunk``) at the operands' own
+shapes: ``exp(G_t - G_s)`` is then one [C, C] matrix a head that scales the
+entries of ``K K^T`` and ``Q K^T``, which are one [2C, K] x [K, C] product a
+**key** head, read once by the ``r`` value heads that follow one another
+(value head ``h`` reads key head ``h // r``); no decay a channel, no repeated
+key head and none of autodiff's sums back exist in HBM. Everything else (the
+tests' models at widths 16 and 32) runs the plain XLA form below on
+operands broadcast to the general rule's shapes, which is also the kernels'
+second oracle beside the token-by-token recurrence. One algorithm, the same
+arithmetic classes ("Precision"), no switch to set.
 
 **The residuals' names (``KEPT``).** Under a ``jax.checkpoint`` around the
 call none of ``(q, k, v, g, beta, states, inverses)`` lives from forward to
@@ -152,6 +161,27 @@ and two independent chains take twice one: throughput, not latency, so more
 heads a step buy only the step's fixed cost (0.6 us). The six terms of a
 highest-precision product written by hand over three bf16 pieces cost what
 Mosaic's own do (0.94 against 1.11 us for the inverse).
+
+The scalar-decay kernels (my chip runs, PR 61, ``tools/gdn_table.py``: q and
+k ``bf16[1, 8192, 16, 128]`` read by 32 value heads, ``g`` and ``beta``
+``f32[1, 8192, 32]``; four calls chained in one program; the last column from
+the Qwen3-Next cell's traces, three layers, where a call reads 8.15 forward
+and 5.17 backward inside the step):
+
+| what | forward, ms | backward, ms | forward + backward, ms | ``decoder.kda_core_ms`` / ``step.device_ms`` |
+|---|---|---|---|---|
+| the per-channel kernels on operands broadcast for them (the decay to 128 channels, 16 key heads repeated to 32, autodiff's sums back: before PR 61) | 13.10 | - | 28.15 | 87.15 / 280.83 |
+| **the scalar body, two key heads with their four value heads a step** | **8.62** | **5.85** | **14.00** | **40.75 / 236.68** |
+| the same without the inverse (``I - A`` in its place) | 3.14 | 5.84 | - | not run |
+| without the exponents' run sum (the masked decays themselves in its place) | 8.23 | 4.72 | - | not run |
+| without the solve's products | 7.45 | 4.90 | - | not run |
+
+Of a forward call 5.5 ms are the inverse's ten [64, 64] products at the
+highest precision (64%; the backward loads it), 1.2 / 1.0 the solve, 0.4 /
+1.1 the exponents' three exact passes and their transposition, and 1.5 / 3.8
+everything else: the pair product a key head, the three products with the
+state, blocks in and out. ``gdn_core_cost``'s least time for a layer is
+0.66 ms.
 """
 
 from __future__ import annotations
@@ -282,14 +312,20 @@ def _kda(q, k, v, g, beta, chunk: int):
     return out.astype(v.dtype)
 
 
-def path(q, k, v, chunk: int) -> str:
-    """Which realisation of the rule operands of these shapes take:
-    ``"kernel"`` (``ops/kda_mosaic.py``) where a head's keys and values fill
-    whole 128-lane tiles and the chunk is the kernels' 64, else ``"plain"``
-    (this module's XLA form). Read from the shapes alone."""
+def path(q, k, v, chunk: int, g=None) -> str:
+    """Which realisation of the rule operands of these shapes take, read from
+    the shapes alone. Where a head's keys and values fill whole 128-lane
+    tiles and the chunk is the kernels' 64, the Mosaic kernels of
+    ``ops/kda_mosaic.py``: ``"scalar_kernel"`` for ``g`` [B, T, H], one decay
+    a head, at the operands' own shapes (``q`` and ``k`` may have fewer
+    heads than ``v``), ``"kernel"`` for a decay a channel (``g`` [B, T, H, K]
+    or none given), on broadcast operands. Else ``"plain"``, this module's
+    XLA form, on broadcast operands too."""
     lanes = q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
     whole = q.shape[1] % chunk == 0 and q.shape == k.shape
-    return "kernel" if lanes and whole and chunk == 64 else "plain"
+    if not (lanes and whole and chunk == 64):
+        return "plain"
+    return "scalar_kernel" if g is not None and g.ndim == 3 else "kernel"
 
 
 def _mxu_dtype():
@@ -301,34 +337,42 @@ def _mxu_dtype():
             else jnp.float32)
 
 
+def _calls(g):
+    """The kernels' forward and backward for a decay of ``g``'s shape."""
+    return ((kda_mosaic.scalar_forward, kda_mosaic.scalar_backward)
+            if g.ndim == 3 else (kda_mosaic.forward, kda_mosaic.backward))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _kda_kernel(q, k, v, g, beta, chunk, mxu, interpret):
-    return kda_mosaic.forward(q, k, v, g, beta, chunk=chunk, mxu=mxu,
-                              interpret=interpret, keep=False)[0]
+    return _calls(g)[0](q, k, v, g, beta, chunk=chunk, mxu=mxu,
+                        interpret=interpret, keep=False)[0]
 
 
 def _kda_kernel_fwd(q, k, v, g, beta, chunk, mxu, interpret):
-    out, kept = kda_mosaic.forward(q, k, v, g, beta, chunk=chunk, mxu=mxu,
-                                   interpret=interpret, keep=True)
+    out, kept = _calls(g)[0](q, k, v, g, beta, chunk=chunk, mxu=mxu,
+                             interpret=interpret, keep=True)
     # named on the variables the backward reads, as ``_flash_vjp_fwd`` does
     out, states, inverses = map(checkpoint_name, (out, *kept), KEPT)
     return out, (q, k, v, g, beta, (states, inverses))
 
 
 def _kda_kernel_bwd(chunk, mxu, interpret, res, do):
-    return kda_mosaic.backward(*res, do, chunk=chunk, mxu=mxu,
-                               interpret=interpret)
+    return _calls(res[3])[1](*res, do, chunk=chunk, mxu=mxu,
+                             interpret=interpret)
 
 
 _kda_kernel.defvjp(_kda_kernel_fwd, _kda_kernel_bwd)
 
 
-def _under_mesh(run, batch: int, heads: int):
+def _under_mesh(run, q, g):
     """``run`` inside ``shard_map`` over the mesh ``ps_tpu.init`` built, if
     any: batch over 'data' and heads over 'model' wherever the axis exists
     and divides (what it does not divide is computed replicated), as
     ``ops/flash_attention.py`` runs its kernels: GSPMD cannot partition a
-    Mosaic call, and the plain form it replaces at these shapes could be."""
+    Mosaic call, and the plain form it replaces at these shapes could be.
+    The heads are split by ``q``'s, so a key head stays with the value heads
+    that read it; ``g`` is split as it comes, [B, T, H] or [B, T, H, K]."""
     from ps_tpu import api
 
     if not api.is_initialized() or api.current_context().mesh is None:
@@ -339,11 +383,13 @@ def _under_mesh(run, batch: int, heads: int):
         size = mesh.shape.get(name, 1)
         return name if size > 1 and n % size == 0 else None
 
-    wide = P(axis(DATA_AXIS, batch), None, axis(MODEL_AXIS, heads), None)
+    wide = P(axis(DATA_AXIS, q.shape[0]), None, axis(MODEL_AXIS, q.shape[2]),
+             None)
+    specs = (wide,) * 3 + (P(*wide[:g.ndim]), P(*wide[:3]))
     # check_vma off for the reason flash_attention gives: jax 0.9.0 types
     # a kernel's VMEM scratch as unvarying
-    return shard_map(run, mesh=mesh, in_specs=(wide,) * 4 + (P(*wide[:3]),),
-                     out_specs=wide, check_vma=False)
+    return shard_map(run, mesh=mesh, in_specs=specs, out_specs=wide,
+                     check_vma=False)
 
 
 def kda(q, k, v, g, beta, *, chunk: int = 64):
@@ -363,35 +409,39 @@ def kda(q, k, v, g, beta, *, chunk: int = 64):
     kernels run in interpret mode (``ops/mosaic.py::interpret``), and under
     ``ps_tpu.init``'s mesh they run in ``shard_map`` (``_under_mesh``).
 
-    Two special cases of the rule are computed as the general one, exactly
-    (``models/qwen3_next.py``'s gated delta rule is both): ``g`` [B, T, H],
-    **one decay a head and a token**, is that decay in each of the head's
-    ``K`` channels; ``q``, ``k`` [B, T, H / r, K], **fewer key heads than
-    value heads**, are each read by the ``r`` value heads that follow one
-    another (value head ``h`` reads key head ``h // r``). Both are broadcast
-    in front of the call (4 K bytes of f32 and 2 x ``itemsize`` K (r - 1) / r
-    a token a value head that a kernel of the special case would not move),
-    and autodiff sums the cotangents back: ``dg`` over the channels, ``dq``
-    and ``dk`` over a key head's readers. ``path`` is asked of the broadcast
-    shapes."""
+    Two special cases of the rule (``models/qwen3_next.py``'s gated delta
+    rule is both): ``g`` [B, T, H], **one decay a head and a token**, is that
+    decay in each of the head's ``K`` channels; ``q``, ``k`` [B, T, H / r, K],
+    **fewer key heads than value heads**, are each read by the ``r`` value
+    heads that follow one another (value head ``h`` reads key head
+    ``h // r``). At the kernels' shapes a scalar decay takes kernels written
+    for it, which read the operands as they come and return ``dq``, ``dk``
+    [B, T, H / r, K] and ``dg`` [B, T, H] summed in VMEM
+    (``path``: ``"scalar_kernel"``). Everywhere else (a decay a channel on
+    fewer key heads, the plain form) both are broadcast in front of the call
+    and computed as the general rule, exactly, and autodiff sums the
+    cotangents back: ``dg`` over the channels, ``dq`` and ``dk`` over a key
+    head's readers."""
     heads = v.shape[2]
     if heads % q.shape[2] or q.shape[2] != k.shape[2]:
         raise ValueError(f"kda: {q.shape[2]} query and {k.shape[2]} key "
                          f"heads for {heads} value heads: equal, and a "
                          f"divisor of the value heads")
-    if q.shape[2] != heads:
-        q, k = (jnp.repeat(x, heads // x.shape[2], axis=2) for x in (q, k))
-    if g.ndim == 3:
-        g = jnp.broadcast_to(g[..., None], (*g.shape, q.shape[-1]))
     t = q.shape[1]
     if chunk % SUB or t % chunk:
         raise ValueError(f"kda: {t} tokens in chunks of {chunk}, sub-blocks "
                          f"of {SUB}: each must divide the one before")
-    if path(q, k, v, chunk) == "kernel":
+    route = path(q, k, v, chunk, g)
+    if route != "scalar_kernel":
+        if q.shape[2] != heads:
+            q, k = (jnp.repeat(x, heads // x.shape[2], axis=2)
+                    for x in (q, k))
+        if g.ndim == 3:
+            g = jnp.broadcast_to(g[..., None], (*g.shape, q.shape[-1]))
+    if route == "plain":
+        run = functools.partial(_kda, chunk=chunk)
+    else:
         run = _under_mesh(
             functools.partial(_kda_kernel, chunk=chunk, mxu=_mxu_dtype(),
-                              interpret=mosaic.interpret()),
-            q.shape[0], q.shape[2])
-    else:
-        run = functools.partial(_kda, chunk=chunk)
+                              interpret=mosaic.interpret()), q, g)
     return run(q, k, v, g, beta)

@@ -50,6 +50,29 @@ for it. Mosaic reads no such context (f32 operands and no ``precision`` give
 one pass), so the class is a static argument and each product names its own
 (``_product``): its backward rounds the cotangent as the default precision
 would, and keeps the result in f32.
+
+**One decay a head: a second chunk body, chosen by the decay's shape**
+(``_scalar_chunk`` and the kernels below it, for ``g`` [B, T, H];
+``ops/kda.py::path``). With one log-decay a token for all of a head's
+channels, ``exp(G_t - G_s)`` comes out of the sum over the channels: it is
+one [C, C] matrix ``D`` a value head, and ``A = beta * strict(K K^T * D)``,
+``B = lower(Q K^T * D)``. So ``K K^T`` and ``Q K^T`` are one
+[2C, K] x [K, C] product at the highest precision **a key head**, made once
+and scaled by each of the value heads that read the key head (a grid step
+holds whole key heads with all their readers, and the backward's ``dq`` and
+``dk`` are summed over the readers in VMEM); no halving levels, no
+sub-blocks and no [C, K] exponentials: every exponent of a causal pair is
+<= 0 as it stands. The exponents ``G_t - G_s`` are still run sums of their
+own and not differences of two cumulated decays: ``through @ (g * before)``,
+a 0 / 1 matrix times the head's decays masked to the tokens after ``s``
+(``_run_sums``: three exact passes), 0 on and above the diagonal, so the
+mask is in the exponent where it is made. ``g`` comes as ``beta`` does, a
+row of C a chunk and a head ([B, H, N, 1, C]), ``q exp(G)`` and
+``k exp(G_C - G)`` are row scalings by [C, 1] columns summed on the VPU, and
+``dg`` leaves as such a row. From ``A`` and ``B`` on the body is
+``_chunk``'s, piece for piece (``_inverse``, ``_solve``, the three products
+with the state), in the same arithmetic classes; what it keeps for the
+backward has the per-channel kernels' shapes.
 """
 
 from __future__ import annotations
@@ -388,3 +411,205 @@ def backward(q, k, v, g, beta, kept, do, *, chunk, mxu, interpret):
     dbeta = jnp.transpose(dbeta.reshape(b, h, t), (0, 2, 1))
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dg.reshape(g.shape), dbeta.astype(beta.dtype))
+
+
+# -- one decay a head, a key head read by several value heads -------------------
+
+def _scalar_chunk(q, k, vs, gs, betas, states_t, inverses=None, *, mxu):
+    """One chunk of one key head and the value heads that read it, the decay
+    one scalar a head and a token. ``q``, ``k`` [C, K] f32, read once; for
+    each reader ``v`` [C, V], ``g`` and ``beta`` [1, C], ``state_t`` [V, K]
+    and, where an earlier pass kept it, ``inverse`` [C, C] -> for each reader
+    (``o`` [C, V], the transposed state leaving, ``inverse``), as three
+    lists. The equations are ``_chunk``'s with ``exp(G_t - G_s)`` pulled out
+    of the sums over the channels."""
+    c = q.shape[0]
+    t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = jnp.where(t == r, 1.0, 0.0)
+    through = jnp.where(r <= t, 1.0, 0.0)          # [t, r]: g_r is in G_t
+    after = 1.0 - through                          # [t, r]: g_r is in G_C - G_t
+    before = through - eye                         # [t, r]: r in front of t
+    runs = through.astype(jnp.bfloat16)
+    # every pair of tokens of the key head, once for all its readers
+    qk, kk = _halves(_product("nt", _F32, jnp.concatenate([q, k], axis=0), k),
+                     0)
+    outs, states, kept = [], [], []
+    for j, (v, g, beta, state_t) in enumerate(zip(vs, gs, betas, states_t)):
+        column = jnp.sum(eye * g, axis=1, keepdims=True)      # [C, 1]
+        # [t, s] = g_{s+1} + .. + g_t, a run sum of its own (0 on and above
+        # the diagonal: the exponent is masked where it is made)
+        exponent, = _run_sums(runs, column * before)
+        pair = through * jnp.exp(exponent)                    # <= 1
+        decay = jnp.exp(jnp.sum(through * g, axis=1, keepdims=True))
+        to_end = jnp.exp(jnp.sum(after * g, axis=1, keepdims=True))
+        beta = jnp.sum(eye * beta, axis=1, keepdims=True)     # [C, 1]
+        a = beta * before * kk * pair
+        b = qk * pair
+        inverse = _inverse(jax.lax.stop_gradient(a), eye) \
+            if inverses is None else inverses[j]
+        w_v, w_k = _halves(_solve(a, jnp.concatenate(
+            [beta * v, beta * decay * k], axis=1), inverse), 1)
+        read_k, read_q = _halves(_product(
+            "nt", mxu, jnp.concatenate([w_k, q * decay], axis=0), state_t), 0)
+        u = w_v - read_k
+        outs.append(read_q + _product("nn", mxu, b, u))
+        states.append(
+            state_t * jnp.exp(jnp.sum(g, axis=1, keepdims=True))
+            + _product("tn", mxu, u, k * to_end))
+        kept.append(inverse)
+    return outs, states, kept
+
+
+def _key_head(j: int, keys: int, readers: int, q_ref, k_ref, v_ref, g_ref,
+              beta_ref):
+    """Key head ``j`` of a grid step's ``keys``: the value heads that read
+    it, and its operands as ``_scalar_chunk`` takes them (q, k, and a list a
+    reader of v, g and beta)."""
+    mine = range(j * readers, (j + 1) * readers)
+    return mine, (
+        *(ref[_head(ref, j, keys)].astype(_F32) for ref in (q_ref, k_ref)),
+        [v_ref[_head(v_ref, i, keys * readers)].astype(_F32) for i in mine],
+        [g_ref[i] for i in mine], [beta_ref[i] for i in mine])
+
+
+def _scalar_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                           keys, readers, mxu):
+    """A grid step: one chunk of ``keys`` key heads, each with its
+    ``readers`` value heads; ``rest`` as ``_forward_kernel``'s."""
+    *kept, carry = rest
+    values = keys * readers
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        carry[...] = jnp.zeros_like(carry)
+
+    for j in range(keys):
+        mine, operands = _key_head(j, keys, readers, q_ref, k_ref, v_ref,
+                                   g_ref, beta_ref)
+        if kept:
+            for i in mine:
+                kept[0][i] = carry[i]
+        outs, states, inverses = _scalar_chunk(
+            *operands, [carry[i] for i in mine], mxu=mxu)
+        for i, out, state, inverse in zip(mine, outs, states, inverses):
+            carry[i] = state
+            if kept:
+                kept[1][i] = inverse
+            o_ref[_head(o_ref, i, values)] = out.astype(o_ref.dtype)
+
+
+def _scalar_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
+                            inverses_ref, do_ref, dq_ref, dk_ref, dv_ref,
+                            dg_ref, dbeta_ref, carry, *, keys, readers, mxu):
+    """A grid step of the backward pass, as ``_backward_kernel``'s: a key
+    head's ``dq`` and ``dk`` are the sums over its readers (``jax.vjp`` of
+    the chunk adds them here, in VMEM), ``dg`` a row a value head."""
+    values = keys * readers
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        carry[...] = jnp.zeros_like(carry)
+
+    for j in range(keys):
+        mine, operands = _key_head(j, keys, readers, q_ref, k_ref, v_ref,
+                                   g_ref, beta_ref)
+        inverses = [inverses_ref[i] for i in mine]
+        _, transposed = jax.vjp(
+            lambda *a: _scalar_chunk(*a, inverses, mxu=mxu)[:2],
+            *operands, [states_ref[i] for i in mine])
+        dq, dk, dvs, dgs, dbetas, dstates = transposed(
+            ([do_ref[_head(do_ref, i, values)].astype(_F32) for i in mine],
+             [carry[i] for i in mine]))
+        dq_ref[_head(dq_ref, j, keys)] = dq.astype(dq_ref.dtype)
+        dk_ref[_head(dk_ref, j, keys)] = dk.astype(dk_ref.dtype)
+        for i, dv, dg, dbeta, dstate in zip(mine, dvs, dgs, dbetas, dstates):
+            dv_ref[_head(dv_ref, i, values)] = dv.astype(dv_ref.dtype)
+            dg_ref[i], dbeta_ref[i], carry[i] = dg, dbeta, dstate
+
+
+def keys_a_step(keys: int, readers: int) -> int:
+    """Key heads one grid step computes, each whole with its readers:
+    ``heads_a_step``'s four value heads where the readers allow."""
+    return next(n for n in (4, 2, 1)
+                if keys % n == 0 and (n * readers <= 4 or n == 1))
+
+
+def _scalar_specs(b, t, keys, readers, width, v_width, chunk, reverse: bool):
+    """``_specs`` for ``keys`` key heads of ``readers`` value heads each: the
+    grid (batch, groups of key heads, chunks), the key heads of a step, and
+    the block specs of q and k [B, T, Hk * width], of v [B, T, H * v_width]
+    and of the rows, the states and the inverses [B, H, N, ...], a step's
+    value heads those of its key heads."""
+    n = t // chunk
+    per = keys_a_step(keys, readers)
+
+    def at(i):
+        return n - 1 - i if reverse else i
+
+    def tokens(lanes):
+        return pl.BlockSpec((None, chunk, lanes),
+                            lambda b_, h_, i: (b_, at(i), h_),
+                            memory_space=pltpu.VMEM)
+
+    def a_head(*block):
+        return pl.BlockSpec((None, per * readers, None) + block,
+                            lambda b_, h_, i: (b_, h_, at(i), 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    return ((b, keys // per, n), per, tokens(per * width),
+            tokens(per * readers * v_width), a_head(1, chunk),
+            a_head(v_width, width), a_head(chunk, chunk))
+
+
+def scalar_forward(q, k, v, g, beta, *, chunk, mxu, interpret, keep: bool):
+    """``forward`` at the scalar-decay rule's own shapes: ``q``, ``k``
+    [B, T, Hk, K], ``v`` [B, T, H, V], ``g`` and ``beta`` [B, T, H], value
+    head ``h`` reading key head ``h // (H / Hk)``. Returns what ``forward``
+    does, in the same shapes."""
+    b, t, keys, width = q.shape
+    h, v_width = v.shape[2:]
+    n, readers = t // chunk, h // keys
+    grid, per, wide, v_wide, row, state, square = _scalar_specs(
+        b, t, keys, readers, width, v_width, chunk, reverse=False)
+    out = pl.pallas_call(
+        functools.partial(_scalar_forward_kernel, keys=per, readers=readers,
+                          mxu=mxu),
+        grid=grid,
+        in_specs=[wide, wide, v_wide, row, row],
+        out_specs=[v_wide] + [state, square] * keep,
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * v_width), v.dtype)]
+        + [jax.ShapeDtypeStruct((b, h, n, v_width, width), _F32),
+           jax.ShapeDtypeStruct((b, h, n, chunk, chunk), _F32)] * keep,
+        scratch_shapes=[pltpu.VMEM((per * readers, v_width, width), _F32)],
+        compiler_params=_SEMANTICS, interpret=interpret,
+    )(q.reshape(b, t, -1), k.reshape(b, t, -1), v.reshape(b, t, -1),
+      _rows(g.astype(_F32), chunk), _rows(beta.astype(_F32), chunk))
+    return out[0].reshape(b, t, h, v_width), tuple(out[1:])
+
+
+def scalar_backward(q, k, v, g, beta, kept, do, *, chunk, mxu, interpret):
+    """``backward`` at the scalar-decay rule's own shapes: ``dq`` and ``dk``
+    at the key heads, ``dg`` and ``dbeta`` [B, T, H]."""
+    b, t, keys, width = q.shape
+    h, v_width = v.shape[2:]
+    readers = h // keys
+    grid, per, wide, v_wide, row, state, square = _scalar_specs(
+        b, t, keys, readers, width, v_width, chunk, reverse=True)
+    flat = [x.reshape(b, t, -1) for x in (q, k, v)]
+    rows = [_rows(x.astype(_F32), chunk) for x in (g, beta)]
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
+        functools.partial(_scalar_backward_kernel, keys=per, readers=readers,
+                          mxu=mxu),
+        grid=grid,
+        in_specs=[wide, wide, v_wide, row, row, state, square, v_wide],
+        out_specs=[wide, wide, v_wide, row, row],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in flat + rows],
+        scratch_shapes=[pltpu.VMEM((per * readers, v_width, width), _F32)],
+        compiler_params=_SEMANTICS, interpret=interpret,
+    )(*flat, *rows, *kept, do.reshape(b, t, -1))
+    dg, dbeta = (jnp.transpose(x.reshape(b, h, t), (0, 2, 1)).astype(a.dtype)
+                 for x, a in ((dg, g), (dbeta, beta)))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dg, dbeta)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import ps_tpu as ps
 from benchmark.families import flash
 from benchmark.families import qwen3_next_reference as reference
 from benchmark.families import qwen3_next_step
@@ -232,6 +233,171 @@ def test_kda_refuses_key_heads_that_do_not_divide_the_value_heads(heads):
     with pytest.raises(ValueError, match="divisor of the value heads"):
         kda_ops.kda(q, k, v, jnp.zeros((1, 64, values)),
                     jnp.zeros((1, 64, values)))
+
+
+
+# -- the kernels of the special case (``ops/kda_mosaic.py::_scalar_chunk``) -----------
+
+def _reader_inputs(readers, seq, batch=2, seed=0):
+    """``_rule_inputs`` at the kernels' width with ``4 / readers`` key heads
+    for the four value heads."""
+    q, k, v, g, beta = _rule_inputs("kernel", seq, batch, seed)
+    rng = np.random.default_rng(seed + 1)
+    q, k = (rng.normal(size=(batch, seq, 4 // readers, WIDTH["kernel"]))
+            for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    return [jnp.asarray(q, jnp.float32), jnp.asarray(k, jnp.float32), v, g,
+            beta]
+
+
+def _value_and_grads(f, args, weights):
+    return f(*args), jax.grad(
+        lambda *a: jnp.sum(f(*a).astype(jnp.float32) * weights),
+        argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("readers", [1, 2, 4])
+def test_a_key_head_is_read_once_by_each_of_its_value_heads(readers):
+    """The scalar-decay kernels at one, two and four value heads a key head
+    (four, two and one key head a grid step) over two chunks: the
+    token-by-token recurrence, forward and all five gradients, ``dq`` and
+    ``dk`` summed over the readers inside the kernel."""
+    args = _reader_inputs(readers, 128)
+    assert kda_ops.path(*args[:3], 64, args[3]) == "scalar_kernel"
+    weights = jnp.asarray(np.random.default_rng(9).normal(
+        size=args[2].shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        (got, grads), (want, ref_grads) = (
+            _value_and_grads(f, args, weights)
+            for f in (kda_ops.kda, _recurrence))
+    assert _rel(got, want) <= F32_TOL
+    for name, a, g, r in zip("q k v g beta".split(), args, grads, ref_grads):
+        assert g.shape == a.shape, name
+        assert _rel(g, r) <= F32_TOL, (name, _rel(g, r))
+
+
+def _broadcast(q, k, v, g, beta):
+    """The operands as the per-channel kernels take them."""
+    r = v.shape[2] // q.shape[2]
+    return (jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v,
+            jnp.broadcast_to(g[..., None], v.shape[:3] + q.shape[3:]), beta)
+
+
+def test_the_scalar_body_and_the_per_channel_kernels_agree_at_bf16_operands():
+    """The cell's dtypes (q, k, v in bf16, decays and strengths in f32) and
+    its one-pass products (no ``highest``): the scalar body on the operands'
+    own shapes and the per-channel kernels on broadcast ones are both the
+    recurrence to within the rounding of a bf16 output, which the
+    per-channel kernels' own distance reads, and so is their distance to
+    each other; the gradients likewise (``tests/test_kimi_linear.py::
+    test_kernel_and_plain_form_agree_at_bf16_operands``'s pattern)."""
+    args = _rule_inputs("kernel", 192)
+    args = [x.astype(jnp.bfloat16) for x in args[:3]] + args[3:]
+    exact = [x.astype(jnp.float32) for x in args]
+    assert kda_ops.path(*args[:3], 64, args[3]) == "scalar_kernel"
+    assert kda_ops.path(*_broadcast(*args)[:3], 64,
+                        _broadcast(*args)[3]) == "kernel"
+    weights = jnp.asarray(np.random.default_rng(9).normal(
+        size=args[2].shape), jnp.float32)
+
+    def per_channel(*a):
+        return kda_ops.kda(*_broadcast(*a))
+
+    (want, want_grads), (ours, our_grads), (theirs, their_grads) = (
+        _value_and_grads(f, a, weights) for f, a in (
+            (_recurrence, exact), (kda_ops.kda, args), (per_channel, args)))
+    ours, theirs = (x.astype(jnp.float32) for x in (ours, theirs))
+    bound = 4 * _rel(theirs, want)
+    assert 1e-3 < bound < 4e-2
+    assert _rel(ours, want) <= bound and _rel(ours, theirs) <= bound
+    for name, g, p, r in zip("q k v g beta".split(), our_grads, their_grads,
+                             want_grads):
+        g, p = g.astype(jnp.float32), p.astype(jnp.float32)
+        assert _rel(g, r) <= max(4 * _rel(p, r), bound), name
+
+
+def _shapes(keys, values, width, g_width):
+    def array(*shape):
+        return jax.ShapeDtypeStruct((1, 128) + shape, jnp.float32)
+
+    return (array(keys, width), array(keys, width), array(values, width),
+            array(values, *g_width), array(values))
+
+
+@pytest.mark.parametrize("keys,width,g_width,chunk,want", [
+    (2, 128, (), 64, "scalar_kernel"),
+    (4, 128, (), 64, "scalar_kernel"),
+    (4, 128, (128,), 64, "kernel"),
+    (2, 128, (128,), 64, "kernel"),
+    (2, 32, (), 64, "plain"),
+    (4, 32, (32,), 64, "plain"),
+    (2, 128, (), 32, "plain"),
+], ids=["scalar", "scalar-equal-heads", "per-channel",
+        "per-channel-fewer-keys", "scalar-narrow", "per-channel-narrow",
+        "scalar-chunk-32"])
+def test_the_realisation_is_read_from_the_operands_shapes(keys, width,
+                                                          g_width, chunk,
+                                                          want):
+    """``kda.path`` of the operands as the caller hands them, and what
+    ``kda`` then traces: the scalar kernels take q and k at the key heads
+    and ``g`` a head (no repeat, no broadcast to the channels in front of
+    the call), the per-channel kernels and the plain form broadcast
+    operands; nothing but the shapes is asked."""
+    q, k, v, g, beta = _shapes(keys, 4, width, g_width)
+    assert kda_ops.path(q, k, v, chunk, g) == want
+    jaxpr = jax.make_jaxpr(functools.partial(kda_ops.kda, chunk=chunk))(
+        q, k, v, g, beta).jaxpr
+    calls = [e for e in equations(jaxpr) if e.primitive.name == "pallas_call"]
+    assert len(calls) == (0 if want == "plain" else 1)
+    for call in calls:
+        read = [tuple(x.aval.shape) for x in call.invars]
+        heads = keys if want == "scalar_kernel" else 4
+        assert read[:2] == [(1, 128, heads * width)] * 2
+        assert ((1, 128, 4 * width) in read[3:]) == (want == "kernel")
+
+
+def test_the_cells_rule_takes_the_scalar_kernels():
+    """At the benchmark cell's shapes (16 key heads of 128 read by 32 value
+    heads, one decay a head, chunk 64) the rule runs on the scalar-decay
+    kernels, two key heads with their four value heads a grid step."""
+    from ps_tpu.ops import kda_mosaic
+
+    keys = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16)
+    values = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32)
+    assert kda_ops.path(keys, keys, values, 64, g) == "scalar_kernel"
+    assert kda_mosaic.keys_a_step(16, 2) == 2
+    assert [kda_mosaic.keys_a_step(16, r) for r in (1, 4, 8)] == [4, 1, 1]
+
+
+@pytest.mark.parametrize("mesh_shape", [{"data": 2, "model": 2}, {"data": 8},
+                                        {"data": 2, "model": 4}],
+                         ids=["data2_model2", "data8", "data2_model4"])
+def test_under_a_mesh_the_scalar_kernels_run_sharded_and_agree(mesh_shape):
+    """Under ``ps.init``'s mesh the scalar kernels go through ``shard_map``
+    with the key heads split over 'model' where they divide (a key head
+    stays with its readers; four cannot divide two key heads, eight cannot
+    divide the batch: replicated there): the same values and gradients as
+    without a mesh (``tests/test_kimi_linear.py::
+    test_under_a_mesh_the_kernels_run_sharded_and_agree``'s pattern)."""
+    args = _rule_inputs("kernel", 128)     # B = 2, two key heads for four
+
+    def value_and_grads():
+        return jax.value_and_grad(lambda *a: jnp.sum(kda_ops.kda(*a) ** 2),
+                                  argnums=(0, 1, 2, 3, 4))(*args)
+
+    want = value_and_grads()
+    ps.init(backend="tpu", mesh_shape=mesh_shape)
+    try:
+        jaxpr = jax.make_jaxpr(value_and_grads)().jaxpr
+        got = jax.jit(value_and_grads)()
+    finally:
+        ps.shutdown()
+    assert "shard_map" in {e.primitive.name for e in jaxpr.eqns}
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5),
+        got, want)
 
 
 # -- the share ---------------------------------------------------------------------
