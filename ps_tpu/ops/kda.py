@@ -44,6 +44,9 @@ exponent too, so no gradient is 0 x inf.
 ``(I + A)^-1 = prod_i (I + (-A)^(2^i))``, i < log2 C: five squarings and five
 products of [C, C] matrices at the highest precision, batched over every chunk
 and head, in place of ``solve_triangular``'s forward substitution (the table).
+The kernels take the same product with its factors in the other order (they
+are polynomials in ``A`` and commute), which makes a level one product
+(``kda_mosaic._inverses``; the last table).
 
 **Three realisations, chosen by shape** (``path``): where a head's keys and
 values fill whole 128-lane tiles and the chunk is 64 (the benchmark's cells:
@@ -80,7 +83,8 @@ does its two, and a checkpoint whose policy is
 ``models/kimi_linear.py::_mixer`` says so for the mixer. At 32 heads of 128
 that is 49 KB a token a layer more between forward and backward (``o`` in
 bf16 8 KB, the states in f32 32 KB, the inverses 8 KB: 403 MB a layer at
-8,192 tokens) and one forward call of 11.4 ms a layer less. Under no
+8,192 tokens) and one forward call a layer less (11.4 ms when PR 44 read
+it, 7.7 since PR 62). Under no
 checkpoint, or one that does not list them, the names are identity. The
 plain form has no ``custom_vjp`` and no names: a caller's policy recomputes
 it whole.
@@ -148,6 +152,7 @@ its [8192, 4096] operands with it: the last two rows):
 | the same with the masks handed in as tables, not made of iotas | 12.33 | 26.64 | not run |
 | **as landed: 4 heads a step** (2: 12.11 / 26.16) | **11.91** | **25.92** | **144.00 / 566.70** |
 | the same kernels, the mixer's checkpoint keeping ``KEPT`` (PR 44): 8 calls a step where there were 12, each at its time inside the step | 11.39 | 24.58 | **99.30 / 430.36** (my chip run, PR 44; 143.99 / 495.80 on the parent beside it) |
+| **the inverses of a step's four heads taken together, six [64, 64] x [64, 128] products a chain (PR 62)**: alone 8.27 (11.92 on the parent beside it), the backward 14.62 (14.63) | **7.73** | 20.92 | **84.64 / 348.79** (my chip run, PR 62; the ledger's PR 61 line reads 99.30 / 363.33) |
 
 Where a call's time goes (ablations of the 2-head form, forward / forward +
 backward, ms): the inverse's ten [64, 64] products at the highest precision
@@ -160,28 +165,51 @@ product costs 104 cycles at the highest precision and 54 in one bf16 pass,
 and two independent chains take twice one: throughput, not latency, so more
 heads a step buy only the step's fixed cost (0.6 us). The six terms of a
 highest-precision product written by hand over three bf16 pieces cost what
-Mosaic's own do (0.94 against 1.11 us for the inverse).
+Mosaic's own do (0.94 against 1.11 us for the inverse). **What PR 62 found
+of that reading:** two chains took twice one because the scheduler ran them
+one after the other, not because a product fills the MXU. A product is its
+weights' pushes, six passes and their pops in a row, about 200 cycles before
+the next product of the chain can start, and the ten products of an inverse
+are six such steps deep (five squarings and the last update; an update runs
+beside the next squaring): 1,223 cycles an inverse, measured. Six products
+[64, 64] x [64, 128] in ``_inverse`` alone, the issue's form, are as deep
+and read 1,203 with a concatenation a level (a wide product 241 cycles by
+the difference, where two narrow ones are 244: the count bought nothing),
+1,250 carried as ``[X | P]``, 1,174 as ``[P | X]`` (no lane moves), 1,369
+with the rows stacked instead, ``[X; P] @ P``; in one bf16 pass the ten cost
+737 and the six 882, so the depth and not the passes. With the chains of a
+step's heads issued level by level the same six products cost 367 cycles an
+inverse (four chains, this kernel: 61 a product) and 398 (the scalar
+kernels'), 0.39 and 0.42 us.
 
 The scalar-decay kernels (my chip runs, PR 61, ``tools/gdn_table.py``: q and
 k ``bf16[1, 8192, 16, 128]`` read by 32 value heads, ``g`` and ``beta``
 ``f32[1, 8192, 32]``; four calls chained in one program; the last column from
 the Qwen3-Next cell's traces, three layers, where a call reads 8.15 forward
-and 5.17 backward inside the step):
+and 5.17 backward inside the step, 4.42 and 4.86 since PR 62):
 
 | what | forward, ms | backward, ms | forward + backward, ms | ``decoder.kda_core_ms`` / ``step.device_ms`` |
 |---|---|---|---|---|
 | the per-channel kernels on operands broadcast for them (the decay to 128 channels, 16 key heads repeated to 32, autodiff's sums back: before PR 61) | 13.10 | - | 28.15 | 87.15 / 280.83 |
-| **the scalar body, two key heads with their four value heads a step** | **8.62** | **5.85** | **14.00** | **40.75 / 236.68** |
+| the scalar body, two key heads with their four value heads a step (PR 61) | 8.62 | 5.85 | 14.00 | 40.75 / 236.68 |
 | the same without the inverse (``I - A`` in its place) | 3.14 | 5.84 | - | not run |
 | without the exponents' run sum (the masked decays themselves in its place) | 8.23 | 4.72 | - | not run |
 | without the solve's products | 7.45 | 4.90 | - | not run |
+| six wide products in ``_inverse`` alone, the chains still one after the other (PR 62; the parent 8.57 beside it): a concatenation a level / carried ``[X | P]`` / ``[P | X]`` | 8.50 / 8.71 / 8.34 | - | - | not run |
+| a key head's two readers' chains level by level, ten narrow / six ``[P | X]`` products | 6.55 / 5.63 | 5.44 | - | 30.99 / 226.91 |
+| **as landed (PR 62): the step's four chains level by level, six ``[P | X]`` products each** (the parent 8.58 / 5.87 beside it) | **4.85** | **5.43** | **9.91** | **28.60 / 224.41** |
+| the same without the inverse | 3.12 | 5.45 | - | not run |
+| without the exponents' run sum | 4.41 | 4.70 | - | not run |
+| without the solve's products | 3.57 | 4.18 | - | not run |
 
-Of a forward call 5.5 ms are the inverse's ten [64, 64] products at the
-highest precision (64%; the backward loads it), 1.2 / 1.0 the solve, 0.4 /
-1.1 the exponents' three exact passes and their transposition, and 1.5 / 3.8
-everything else: the pair product a key head, the three products with the
-state, blocks in and out. ``gdn_core_cost``'s least time for a layer is
-0.66 ms.
+Of PR 61's forward call 5.5 ms were the inverse's ten [64, 64] products at
+the highest precision (64%; the backward loads it), 1.2 / 1.0 the solve, 0.4
+/ 1.1 the exponents' three exact passes and their transposition, and 1.5 /
+3.8 everything else: the pair product a key head, the three products with
+the state, blocks in and out. Of PR 62's 4.85 the inverses are 1.7 (36%),
+the solve 1.3 / 1.3, the run sum 0.4 / 0.8; the backward's 0.4 came with the
+body's two loops (a key head's readers' products side by side, no inverse
+among them). ``gdn_core_cost``'s least time for a layer is 0.66 ms.
 """
 
 from __future__ import annotations

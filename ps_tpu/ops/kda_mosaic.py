@@ -4,9 +4,11 @@ everything a chunk computes on the way (cumulated decays, A, B, the inverse
 and its products) lives and dies in VMEM too.
 
 **One chunk is one pure function**, ``_chunk``: the chunk's q, k, v, g, beta
-and the state entering it -> the chunk's outputs and the state leaving it.
-The forward kernel calls it once a grid step; the backward kernel takes
-``jax.vjp`` of it on the blocks it has loaded, inside the kernel's body, so
+and the state entering it -> the chunk's outputs and the state leaving it,
+each a list with one entry a head. The forward kernel calls it once a grid
+step, on all the step's heads (their inverses are taken together, below);
+the backward kernel takes ``jax.vjp`` of it a head at a time on the blocks
+it has loaded, inside the kernel's body, so
 the backward is the forward's own transposition and no second derivation
 (every op of ``_chunk`` lowers in Mosaic in both directions: products,
 elementwise, masks from iotas, concatenations). Four pieces carry a
@@ -31,11 +33,38 @@ large cumulated decays is ever formed: the kernels sit at 4e-7 of the
 token-by-token recurrence where the plain form sits at 6e-6).
 
 **The inverse is computed once a pass and never transposed.** ``(I + A)^-1``
-by the plain form's squarings; the solve ``W = (I + A)^-1 R`` has the rule
+``= prod_i (I + P^(2^i))``, ``P = -A``, the plain form's product
+(``_inverses``); the solve ``W = (I + A)^-1 R`` has the rule
 ``dR = (I + A)^-T dW``, ``dA = -dR W^T`` (two products where the squarings'
 own transposition is twenty), and the forward that runs inside the backward
 pass keeps the inverse [C, C] beside the state entering the chunk, so the
 backward kernel loads both and computes neither.
+
+**A chain of products costs its depth, so the chains of a grid step are
+taken together and a level is one product.** The plain form's level is a
+squaring and an update, ``P <- P P``, ``X <- X + X P``: ten [64, 64]
+products a chunk, six of them one after another (the five squarings, then
+the last update). Every factor is a polynomial in ``A``, so the factors
+commute and the update may as well be ``X <- X + P X``: it then shares its
+left operand with the next squaring and both are ``P @ [P | X] =
+[P^2 | P X]``, one [64, 64] x [64, 128] product a level, six a chain, from
+``[-A | I]``. The power stands in the left lanes, where a left operand is
+read from, so no level moves a lane (with ``[X | P]`` every level rotates
+64 lanes twice, and a rotation waits in the chain). Mosaic's scheduler lays
+a product out as its weights' pushes, its six passes and their pops one
+after another (about 200 cycles at 940 MHz, as many for the wide product as
+for the narrow one: 1,223 cycles for the ten products and 1,174 for the six
+alone, my chip runs, PR 62), and does not reach across the thousands of
+operations between one head's chain and the next head's: a step's chains
+ran one after the other, each waiting for its own results. So ``_chunk``
+and ``_scalar_chunk`` make ``A`` for all their heads first and hand
+``_inverses`` the list: it walks the levels once and issues every chain's
+product at a level side by side, which the scheduler does overlap. With a
+step's four chains together an inverse costs 367 cycles (398 in the scalar
+kernels) where it cost 1,223, with two together 576 (``ops/kda.py``'s
+tables). The order of
+two commuting factors is all that changed of the arithmetic: the kept
+inverses are the parent's to 1.5e-8 of their largest entry on the chip.
 
 **Arithmetic classes** (``ops/kda.py``, "Precision"): state, decays,
 exponentials, A, B, the inverse in f32. The levels inside a sub-block of
@@ -70,7 +99,7 @@ mask is in the exponent where it is made. ``g`` comes as ``beta`` does, a
 row of C a chunk and a head ([B, H, N, 1, C]), ``q exp(G)`` and
 ``k exp(G_C - G)`` are row scalings by [C, 1] columns summed on the VPU, and
 ``dg`` leaves as such a row. From ``A`` and ``B`` on the body is
-``_chunk``'s, piece for piece (``_inverse``, ``_solve``, the three products
+``_chunk``'s, piece for piece (``_inverses``, ``_solve``, the three products
 with the state), in the same arithmetic classes; what it keeps for the
 backward has the per-channel kernels' shapes.
 """
@@ -180,15 +209,23 @@ def _run_sums_bwd(runs, cts):
 _run_sums.defvjp(_run_sums_fwd, _run_sums_bwd)
 
 
-def _inverse(a, eye):
-    """``(I + a)^-1 = prod_i (I + (-a)^(2^i))`` for strictly lower
-    triangular ``a`` [C, C] (``a^C = 0``), at the highest precision."""
-    power = -a
-    inverse = eye + power
-    for _ in range((a.shape[0] - 1).bit_length() - 1):
-        power = _dot("nn", _F32, power, power)
-        inverse = inverse + _dot("nn", _F32, inverse, power)
-    return inverse
+def _inverses(mats, eye):
+    """``(I + a)^-1 = prod_i (I + (-a)^(2^i))`` for each strictly lower
+    triangular ``a`` [C, C] of ``mats`` (``a^C = 0``), at the highest
+    precision. A level is one product 2 C columns wide, ``P @ [P | X] =
+    [P^2 | P X]``: the next power, and what the level adds to the inverse so
+    far (from ``[-a | I]``; the first level's ``P I`` and the last one's
+    square ride along). The chains of ``mats`` are taken level by level, one's
+    product in flight while another's operands are made (the module
+    docstring has why, and what each choice costs on the chip)."""
+    c = eye.shape[0]
+    left = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1) < c
+    boths = [jnp.concatenate([-a, eye], axis=1) for a in mats]
+    for _ in range((c - 1).bit_length()):
+        news = [_dot("nn", _F32, both[:, :c], both) for both in boths]
+        boths = [jnp.where(left, new, both + new)
+                 for new, both in zip(news, boths)]
+    return [both[:, c:] for both in boths]
 
 
 @jax.custom_vjp
@@ -213,13 +250,15 @@ def _solve_bwd(res, dw):
 _solve.defvjp(_solve_fwd, _solve_bwd)
 
 
-def _chunk(q, k, v, g, beta, state_t, inverse=None, *, mxu):
-    """One chunk of one head. ``q``, ``k``, ``g`` [C, K], ``v`` [C, V],
-    ``beta`` [1, C], all f32; ``state_t`` [V, K] the transposed state
-    entering the chunk; ``inverse`` [C, C] the chunk's ``(I + A)^-1`` where
-    an earlier pass kept it -> (``o`` [C, V], the transposed state leaving,
-    ``inverse``)."""
-    c = q.shape[0]
+def _chunk(qs, ks, vs, gs, betas, states_t, inverses=None, *, mxu):
+    """One chunk of some heads, a list an operand. For each head ``q``, ``k``,
+    ``g`` [C, K], ``v`` [C, V], ``beta`` [1, C], all f32; ``state_t`` [V, K]
+    the transposed state entering the chunk; ``inverse`` [C, C] the chunk's
+    ``(I + A)^-1`` where an earlier pass kept it -> for each head (``o``
+    [C, V], the transposed state leaving, ``inverse``), as three lists. What
+    comes before the inverses is computed for every head, then the inverses
+    together (``_inverses``), then the rest."""
+    c = qs[0].shape[0]
     levels = range(1, c.bit_length())
     t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
@@ -237,38 +276,47 @@ def _chunk(q, k, v, g, beta, state_t, inverse=None, *, mxu):
     runs = [((t >> (level - 1)) == (r >> (level - 1)))
             & (upper(t, level) == (r <= t)) for level in levels] \
         + [r <= t, r > t]
-    *sums, cum, to_end = _run_sums(jnp.concatenate(
+    runs = jnp.concatenate(
         [jnp.where(run, 1.0, 0.0).astype(jnp.bfloat16) for run in runs],
-        axis=0), g)
-    beta = jnp.sum(eye * beta, axis=1, keepdims=True)         # [C, 1]
-    qk = jnp.concatenate([q, k], axis=0)
-    products = jnp.zeros((2 * c, c), _F32)
-    for level, run in zip(levels, sums):
-        factor = jnp.exp(run)                                 # <= 1
-        above = jnp.where(upper(row, level), factor, 0.0)
-        pairs = _product(
-            "nt", _F32 if (1 << level) <= SUB else mxu,
-            qk * jnp.concatenate([above, above], axis=0),
-            k * (factor - above))
-        if (1 << level) < c:
-            pairs = jnp.where((stacked_t >> level) == (stacked_r >> level),
-                              pairs, 0.0)
-        products = products + pairs
-    b, a = _halves(products, 0)
-    b = b + eye * jnp.sum(q * k, axis=1, keepdims=True)
-    a = beta * a
-    if inverse is None:
-        inverse = _inverse(jax.lax.stop_gradient(a), eye)
-    decay = jnp.exp(cum)
-    w_v, w_k = _halves(_solve(a, jnp.concatenate(
-        [beta * v, beta * k * decay], axis=1), inverse), 1)
-    read_k, read_q = _halves(_product(
-        "nt", mxu, jnp.concatenate([w_k, q * decay], axis=0), state_t), 0)
-    u = w_v - read_k
-    out = read_q + _product("nn", mxu, b, u)
-    state_t = state_t * jnp.exp(jnp.sum(g, axis=0, keepdims=True)) \
-        + _product("tn", mxu, u, k * jnp.exp(to_end))
-    return out, state_t, inverse
+        axis=0)
+    pairs = []
+    for q, k, g, beta in zip(qs, ks, gs, betas):
+        *sums, cum, to_end = _run_sums(runs, g)
+        beta = jnp.sum(eye * beta, axis=1, keepdims=True)         # [C, 1]
+        qk = jnp.concatenate([q, k], axis=0)
+        products = jnp.zeros((2 * c, c), _F32)
+        for level, run in zip(levels, sums):
+            factor = jnp.exp(run)                                 # <= 1
+            above = jnp.where(upper(row, level), factor, 0.0)
+            level_pairs = _product(
+                "nt", _F32 if (1 << level) <= SUB else mxu,
+                qk * jnp.concatenate([above, above], axis=0),
+                k * (factor - above))
+            if (1 << level) < c:
+                level_pairs = jnp.where(
+                    (stacked_t >> level) == (stacked_r >> level),
+                    level_pairs, 0.0)
+            products = products + level_pairs
+        b, a = _halves(products, 0)
+        b = b + eye * jnp.sum(q * k, axis=1, keepdims=True)
+        pairs.append((beta, beta * a, b, cum, to_end))
+    if inverses is None:
+        inverses = _inverses(
+            [jax.lax.stop_gradient(a) for _, a, *_ in pairs], eye)
+    outs, states = [], []
+    for q, k, v, g, state_t, (beta, a, b, cum, to_end), inverse in zip(
+            qs, ks, vs, gs, states_t, pairs, inverses):
+        decay = jnp.exp(cum)
+        w_v, w_k = _halves(_solve(a, jnp.concatenate(
+            [beta * v, beta * k * decay], axis=1), inverse), 1)
+        read_k, read_q = _halves(_product(
+            "nt", mxu, jnp.concatenate([w_k, q * decay], axis=0), state_t), 0)
+        u = w_v - read_k
+        outs.append(read_q + _product("nn", mxu, b, u))
+        states.append(
+            state_t * jnp.exp(jnp.sum(g, axis=0, keepdims=True))
+            + _product("tn", mxu, u, k * jnp.exp(to_end)))
+    return outs, states, inverses
 
 
 def _head(ref, j: int, heads: int):
@@ -288,13 +336,16 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
     def _zero():
         carry[...] = jnp.zeros_like(carry)
 
-    for j in range(heads):
-        if kept:
+    every = range(heads)
+    if kept:
+        for j in every:
             kept[0][j] = carry[j]
-        out, carry[j], inverse = _chunk(
-            *(ref[_head(ref, j, heads)].astype(_F32)
-              for ref in (q_ref, k_ref, v_ref, g_ref)),
-            beta_ref[j], carry[j], mxu=mxu)
+    outs, states, inverses = _chunk(
+        *([ref[_head(ref, j, heads)].astype(_F32) for j in every]
+          for ref in (q_ref, k_ref, v_ref, g_ref)),
+        [beta_ref[j] for j in every], [carry[j] for j in every], mxu=mxu)
+    for j, out, state, inverse in zip(every, outs, states, inverses):
+        carry[j] = state
         if kept:
             kept[1][j] = inverse
         o_ref[_head(o_ref, j, heads)] = out.astype(o_ref.dtype)
@@ -303,9 +354,9 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
                      inverses_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
                      dbeta_ref, carry, *, heads, mxu):
-    """A grid step: one chunk of ``heads`` heads, the chunks walked from
-    the last to the first; ``carry`` the cotangent of the state leaving the
-    chunk."""
+    """A grid step: one chunk of ``heads`` heads, a head at a time, the
+    chunks walked from the last to the first; ``carry`` the cotangent of the
+    state leaving the chunk."""
 
     @pl.when(pl.program_id(2) == 0)
     def _zero():
@@ -314,19 +365,19 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
     for j in range(heads):
         inverse = inverses_ref[j]
         _, transposed = jax.vjp(
-            lambda *a: _chunk(*a, inverse, mxu=mxu)[:2],
-            *(ref[_head(ref, j, heads)].astype(_F32)
+            lambda *a: _chunk(*a, [inverse], mxu=mxu)[:2],
+            *([ref[_head(ref, j, heads)].astype(_F32)]
               for ref in (q_ref, k_ref, v_ref, g_ref)),
-            beta_ref[j], states_ref[j])
-        *cotangents, dbeta_ref[j], carry[j] = transposed(
-            (do_ref[_head(do_ref, j, heads)].astype(_F32), carry[j]))
-        for ref, ct in zip((dq_ref, dk_ref, dv_ref, dg_ref), cotangents):
+            [beta_ref[j]], [states_ref[j]])
+        *cotangents, (dbeta_ref[j],), (carry[j],) = transposed(
+            ([do_ref[_head(do_ref, j, heads)].astype(_F32)], [carry[j]]))
+        for ref, (ct,) in zip((dq_ref, dk_ref, dv_ref, dg_ref), cotangents):
             ref[_head(ref, j, heads)] = ct.astype(ref.dtype)
 
 
 def heads_a_step(heads: int) -> int:
-    """Heads one grid step computes: independent chains of small products
-    that the scheduler interleaves, and one step's fixed cost shared."""
+    """Heads one grid step computes: their inverses' chains are taken
+    together (``_inverses``), and one step's fixed cost is shared."""
     return next(n for n in (4, 2, 1) if heads % n == 0)
 
 
@@ -415,15 +466,19 @@ def backward(q, k, v, g, beta, kept, do, *, chunk, mxu, interpret):
 
 # -- one decay a head, a key head read by several value heads -------------------
 
-def _scalar_chunk(q, k, vs, gs, betas, states_t, inverses=None, *, mxu):
-    """One chunk of one key head and the value heads that read it, the decay
-    one scalar a head and a token. ``q``, ``k`` [C, K] f32, read once; for
-    each reader ``v`` [C, V], ``g`` and ``beta`` [1, C], ``state_t`` [V, K]
-    and, where an earlier pass kept it, ``inverse`` [C, C] -> for each reader
-    (``o`` [C, V], the transposed state leaving, ``inverse``), as three
-    lists. The equations are ``_chunk``'s with ``exp(G_t - G_s)`` pulled out
-    of the sums over the channels."""
-    c = q.shape[0]
+def _scalar_chunk(qs, ks, vs, gs, betas, states_t, inverses=None, *, mxu):
+    """One chunk of some key heads and the value heads that read them, the
+    decay one scalar a head and a token. For each key head ``q``, ``k``
+    [C, K] f32, read once; for each value head (value head ``i`` reads key
+    head ``i // readers``) ``v`` [C, V], ``g`` and ``beta`` [1, C],
+    ``state_t`` [V, K] and, where an earlier pass kept it, ``inverse``
+    [C, C] -> for each value head (``o`` [C, V], the transposed state
+    leaving, ``inverse``), as three lists. The equations are ``_chunk``'s
+    with ``exp(G_t - G_s)`` pulled out of the sums over the channels, and its
+    order: what comes before the inverses for every head, the inverses
+    together, the rest."""
+    c = qs[0].shape[0]
+    readers = len(vs) // len(qs)
     t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     eye = jnp.where(t == r, 1.0, 0.0)
@@ -431,23 +486,29 @@ def _scalar_chunk(q, k, vs, gs, betas, states_t, inverses=None, *, mxu):
     after = 1.0 - through                          # [t, r]: g_r is in G_C - G_t
     before = through - eye                         # [t, r]: r in front of t
     runs = through.astype(jnp.bfloat16)
-    # every pair of tokens of the key head, once for all its readers
-    qk, kk = _halves(_product("nt", _F32, jnp.concatenate([q, k], axis=0), k),
-                     0)
-    outs, states, kept = [], [], []
-    for j, (v, g, beta, state_t) in enumerate(zip(vs, gs, betas, states_t)):
-        column = jnp.sum(eye * g, axis=1, keepdims=True)      # [C, 1]
-        # [t, s] = g_{s+1} + .. + g_t, a run sum of its own (0 on and above
-        # the diagonal: the exponent is masked where it is made)
-        exponent, = _run_sums(runs, column * before)
-        pair = through * jnp.exp(exponent)                    # <= 1
+    pairs = []
+    for j, (q, k) in enumerate(zip(qs, ks)):
+        # every pair of tokens of the key head, once for all its readers
+        qk, kk = _halves(
+            _product("nt", _F32, jnp.concatenate([q, k], axis=0), k), 0)
+        mine = slice(j * readers, (j + 1) * readers)
+        for g, beta in zip(gs[mine], betas[mine]):
+            column = jnp.sum(eye * g, axis=1, keepdims=True)      # [C, 1]
+            # [t, s] = g_{s+1} + .. + g_t, a run sum of its own (0 on and
+            # above the diagonal: the exponent is masked where it is made)
+            exponent, = _run_sums(runs, column * before)
+            pair = through * jnp.exp(exponent)                    # <= 1
+            beta = jnp.sum(eye * beta, axis=1, keepdims=True)     # [C, 1]
+            pairs.append((beta, beta * before * kk * pair, qk * pair))
+    if inverses is None:
+        inverses = _inverses(
+            [jax.lax.stop_gradient(a) for _, a, _ in pairs], eye)
+    outs, states = [], []
+    for i, (v, g, state_t, (beta, a, b), inverse) in enumerate(zip(
+            vs, gs, states_t, pairs, inverses)):
+        q, k = qs[i // readers], ks[i // readers]
         decay = jnp.exp(jnp.sum(through * g, axis=1, keepdims=True))
         to_end = jnp.exp(jnp.sum(after * g, axis=1, keepdims=True))
-        beta = jnp.sum(eye * beta, axis=1, keepdims=True)     # [C, 1]
-        a = beta * before * kk * pair
-        b = qk * pair
-        inverse = _inverse(jax.lax.stop_gradient(a), eye) \
-            if inverses is None else inverses[j]
         w_v, w_k = _halves(_solve(a, jnp.concatenate(
             [beta * v, beta * decay * k], axis=1), inverse), 1)
         read_k, read_q = _halves(_product(
@@ -457,18 +518,18 @@ def _scalar_chunk(q, k, vs, gs, betas, states_t, inverses=None, *, mxu):
         states.append(
             state_t * jnp.exp(jnp.sum(g, axis=1, keepdims=True))
             + _product("tn", mxu, u, k * to_end))
-        kept.append(inverse)
-    return outs, states, kept
+    return outs, states, inverses
 
 
-def _key_head(j: int, keys: int, readers: int, q_ref, k_ref, v_ref, g_ref,
-              beta_ref):
-    """Key head ``j`` of a grid step's ``keys``: the value heads that read
-    it, and its operands as ``_scalar_chunk`` takes them (q, k, and a list a
-    reader of v, g and beta)."""
-    mine = range(j * readers, (j + 1) * readers)
+def _key_heads(some, keys: int, readers: int, q_ref, k_ref, v_ref, g_ref,
+               beta_ref):
+    """Key heads ``some`` of a grid step's ``keys``: the value heads that
+    read them, and their operands as ``_scalar_chunk`` takes them (a list a
+    key head of q and of k, a list a value head of v, g and beta)."""
+    mine = [i for j in some for i in range(j * readers, (j + 1) * readers)]
     return mine, (
-        *(ref[_head(ref, j, keys)].astype(_F32) for ref in (q_ref, k_ref)),
+        *([ref[_head(ref, j, keys)].astype(_F32) for j in some]
+          for ref in (q_ref, k_ref)),
         [v_ref[_head(v_ref, i, keys * readers)].astype(_F32) for i in mine],
         [g_ref[i] for i in mine], [beta_ref[i] for i in mine])
 
@@ -476,7 +537,8 @@ def _key_head(j: int, keys: int, readers: int, q_ref, k_ref, v_ref, g_ref,
 def _scalar_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
                            keys, readers, mxu):
     """A grid step: one chunk of ``keys`` key heads, each with its
-    ``readers`` value heads; ``rest`` as ``_forward_kernel``'s."""
+    ``readers`` value heads, all in one ``_scalar_chunk``; ``rest`` as
+    ``_forward_kernel``'s."""
     *kept, carry = rest
     values = keys * readers
 
@@ -484,27 +546,27 @@ def _scalar_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
     def _zero():
         carry[...] = jnp.zeros_like(carry)
 
-    for j in range(keys):
-        mine, operands = _key_head(j, keys, readers, q_ref, k_ref, v_ref,
-                                   g_ref, beta_ref)
+    mine, operands = _key_heads(range(keys), keys, readers, q_ref, k_ref,
+                                v_ref, g_ref, beta_ref)
+    if kept:
+        for i in mine:
+            kept[0][i] = carry[i]
+    outs, states, inverses = _scalar_chunk(
+        *operands, [carry[i] for i in mine], mxu=mxu)
+    for i, out, state, inverse in zip(mine, outs, states, inverses):
+        carry[i] = state
         if kept:
-            for i in mine:
-                kept[0][i] = carry[i]
-        outs, states, inverses = _scalar_chunk(
-            *operands, [carry[i] for i in mine], mxu=mxu)
-        for i, out, state, inverse in zip(mine, outs, states, inverses):
-            carry[i] = state
-            if kept:
-                kept[1][i] = inverse
-            o_ref[_head(o_ref, i, values)] = out.astype(o_ref.dtype)
+            kept[1][i] = inverse
+        o_ref[_head(o_ref, i, values)] = out.astype(o_ref.dtype)
 
 
 def _scalar_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
                             inverses_ref, do_ref, dq_ref, dk_ref, dv_ref,
                             dg_ref, dbeta_ref, carry, *, keys, readers, mxu):
-    """A grid step of the backward pass, as ``_backward_kernel``'s: a key
-    head's ``dq`` and ``dk`` are the sums over its readers (``jax.vjp`` of
-    the chunk adds them here, in VMEM), ``dg`` a row a value head."""
+    """A grid step of the backward pass, as ``_backward_kernel``'s, a key
+    head at a time: its ``dq`` and ``dk`` are the sums over its readers
+    (``jax.vjp`` of the chunk adds them here, in VMEM), ``dg`` a row a value
+    head."""
     values = keys * readers
 
     @pl.when(pl.program_id(2) == 0)
@@ -512,13 +574,13 @@ def _scalar_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref,
         carry[...] = jnp.zeros_like(carry)
 
     for j in range(keys):
-        mine, operands = _key_head(j, keys, readers, q_ref, k_ref, v_ref,
-                                   g_ref, beta_ref)
+        mine, operands = _key_heads([j], keys, readers, q_ref, k_ref, v_ref,
+                                    g_ref, beta_ref)
         inverses = [inverses_ref[i] for i in mine]
         _, transposed = jax.vjp(
             lambda *a: _scalar_chunk(*a, inverses, mxu=mxu)[:2],
             *operands, [states_ref[i] for i in mine])
-        dq, dk, dvs, dgs, dbetas, dstates = transposed(
+        (dq,), (dk,), dvs, dgs, dbetas, dstates = transposed(
             ([do_ref[_head(do_ref, i, values)].astype(_F32) for i in mine],
              [carry[i] for i in mine]))
         dq_ref[_head(dq_ref, j, keys)] = dq.astype(dq_ref.dtype)

@@ -31,7 +31,7 @@ from benchmark.families import kimi_step
 from jaxpr_tools import checkpoint_names, primitives
 from ps_tpu.models import kimi_linear
 from ps_tpu.models.blocks import _full_attention, make_attn_fn
-from ps_tpu.ops import flash_attention, kda as kda_ops, moe
+from ps_tpu.ops import flash_attention, kda as kda_ops, kda_mosaic, moe
 from ps_tpu.ops.flash_attention import (backward_tiles, backward_vmem_bytes,
                                         forward_tiles, forward_vmem_bytes)
 from ps_tpu.ops.gated_conv import causal_taps
@@ -413,6 +413,73 @@ def test_kernel_and_plain_form_agree_at_bf16_operands():
                              want_grads):
         g, p = g.astype(jnp.float32), p.astype(jnp.float32)
         assert _rel(g, r) <= max(4 * _rel(p, r), bound), name
+
+
+def _chunk_matrix(c, decay, seed):
+    """``A = beta * strict(K K^T * D)`` of one chunk of ``c`` tokens as the
+    scalar body makes it: unit keys, ``D[t, s] = exp(g_{s+1} + .. + g_t)``
+    for log-decays of ``decay`` nats a token, strengths up to 0.95."""
+    rng = np.random.default_rng(seed)
+    k = rng.normal(size=(c, 16))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    cum = np.cumsum(-decay * rng.uniform(0.3, 2.3, size=c))
+    beta = rng.uniform(0.05, 0.95, size=(c, 1))
+    pair = np.exp(np.tril(cum[:, None] - cum[None, :]))     # no exp of a gain
+    return np.tril(beta * (k @ k.T) * pair, -1)
+
+
+@pytest.mark.parametrize("decay", [1e-6, 0.1, 16.0],
+                         ids=["no_decay", "a_tenth", "sixteen"])
+@pytest.mark.parametrize("c", [16, 32, 64])
+def test_the_kernels_inverse_is_numpys(c, decay):
+    """``kda_mosaic._inverses`` (pure ``jnp``: no kernel around it here)
+    against ``numpy.linalg.inv(I + a)`` in float64, at the kernels' chunk
+    and at the two sizes below it, from a chunk that forgets nothing (the
+    inverse's entries largest) to one that forgets a key in a token."""
+    mats = [_chunk_matrix(c, decay, seed) for seed in (0, 1)]
+    got = kda_mosaic._inverses([jnp.asarray(a, jnp.float32) for a in mats],
+                               jnp.eye(c, dtype=jnp.float32))
+    for a, x in zip(mats, got):
+        want = np.linalg.inv(np.eye(c) + a)
+        assert x.shape == (c, c) and x.dtype == jnp.float32
+        assert np.abs(np.asarray(x, np.float64) - want).max() \
+            <= 2e-6 * np.abs(want).max()
+
+
+def test_the_inverses_of_a_step_are_taken_level_by_level():
+    """The shape of ``_inverses`` at the kernels' chunk: a chain is six
+    products (one a level, ``P @ [P | X]``, [64, 64] x [64, 128]), every one
+    of f32 operands at ``Precision.HIGHEST``, and two chains alternate
+    product by product (a chain's time on the chip is its depth: one's
+    product is in flight while the other's operands are made), each
+    computing what it computes alone, to the bit."""
+    highest = jax.lax.Precision.HIGHEST
+    mats = [jnp.asarray(_chunk_matrix(64, 0.1, seed), jnp.float32)
+            for seed in (0, 1)]
+    eye = jnp.eye(64, dtype=jnp.float32)
+    both = jax.make_jaxpr(kda_mosaic._inverses)(mats, eye).jaxpr
+    products = [e for e in both.eqns if e.primitive.name == "dot_general"]
+    assert len(products) == 12
+    assert primitives(
+        jax.make_jaxpr(kda_mosaic._inverses)(mats[:1], eye).jaxpr).count(
+            "dot_general") == 6
+    for eqn in products:
+        left, right = (v.aval for v in eqn.invars)
+        assert (left.shape, right.shape) == ((64, 64), (64, 128))
+        assert left.dtype == right.dtype == jnp.float32
+        assert eqn.outvars[0].aval.shape == (64, 128)
+        assert eqn.params["precision"] == (highest, highest)
+    # which of the two matrices each product descends from
+    chain = {v: {i} for i, v in enumerate(both.invars[:2])}
+    for eqn in both.eqns:
+        reads = set().union(*(chain[v] for v in eqn.invars
+                              if not hasattr(v, "val") and v in chain))
+        chain.update((v, reads) for v in eqn.outvars)
+    assert [chain[e.outvars[0]] for e in products] == [{0}, {1}] * 6
+    together = kda_mosaic._inverses(mats, eye)
+    for a, x in zip(mats, together):
+        np.testing.assert_array_equal(
+            np.asarray(x), np.asarray(kda_mosaic._inverses([a], eye)[0]))
 
 
 def test_causal_taps_are_the_convolution_of_both_mixers():
@@ -927,7 +994,16 @@ def test_the_cells_program_is_the_one_before_the_latent_block_moved():
     was of a program no machine runs, flash and the taps through Mosaic and
     the grouped matmuls and the KDA rule interpreted, the CPU's answer.
     Shown first that it is d251cd7's: on that tree with ``jax.devices``
-    answering a TPU the same trace gave this hash (``CHANGES.md``, PR 58)."""
+    answering a TPU the same trace gave this hash (``CHANGES.md``, PR 58).
+
+    Re-pinned by PR 62, on purpose: the four forward KDA kernels take their
+    step's four inverses together, six [64, 64] x [64, 128] products a chain
+    (``kda_mosaic._inverses``; 706 ``dot_general`` in the text where 722
+    were). Shown first on that tree with the forward kernel calling
+    ``_chunk`` a head at a time and ``_inverses`` given fc665e2's
+    ``_inverse`` body for each matrix: the hash was fc665e2's,
+    ``ed78106ae5358e69``, so nothing else of the program moved (the
+    backward kernels call ``_chunk`` a head at a time as they did)."""
     import hashlib
     import re
 
@@ -944,4 +1020,4 @@ def test_the_cells_program_is_the_one_before_the_latent_block_moved():
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     assert "interpret=True" not in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == "ed78106ae5358e69"
+        == "03ef5e42e82a1c48"

@@ -62,7 +62,7 @@ def _operands(seed: int, seq: int, keys: int, values: int):
 
 #: a piece of ``_scalar_chunk`` -> what stands in for it in an ablation
 ABLATIONS = {
-    "inverse": ("_inverse", lambda a, eye: eye - a),
+    "inverse": ("_inverses", lambda mats, eye: [eye - a for a in mats]),
     "run_sum": ("_run_sums", lambda runs, x: (x,)),
     "solve": ("_solve", lambda a, rhs, inverse: rhs + 0.0 * jnp.sum(a)),
 }
