@@ -1,5 +1,8 @@
 """The blocks more than one model computes alike. No model imports another:
 a block two of them need stands here, a block one needs in that model's file.
+The blocked readout (``blocked_head_nll``) came from ``models/mellum.py`` in
+PR 63: Mellum's ``blocked_head_ce`` is the mean of its block sums, Ouro's four
+readouts (``models/ouro.py``) take a position's loss from it and weight it.
 """
 
 from __future__ import annotations
@@ -258,3 +261,34 @@ def token_ce(logits, targets):
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), -1)
     tok = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
     return jnp.mean(lse - tok.astype(jnp.float32))
+
+
+def blocked_head_nll(hidden, head, targets, block, *, summed=False):
+    """The next-token negative log-likelihood of ``hidden @ head`` for normed
+    hidden states ``hidden`` [B, S, D], a head [D, V] and ``targets`` [B, S],
+    in ``token_ce``'s logsumexp form and f32, the logits formed ``block``
+    positions of every sequence at a time, each block under a
+    ``jax.checkpoint``: [B, block, V] logits and their cotangent live at
+    once, never [B, S, V]. A position's loss [B, S], for a caller that
+    weights it (Ouro's exit distribution); with ``summed`` each block's sum
+    [S / block] and no array a position, which is the program
+    ``mellum.blocked_head_ce`` has traced to since PR 46. The head's
+    gradient is the sum over the blocks, and over every call that reads the
+    same ``head``."""
+    b, s, d = hidden.shape
+    if s % block:
+        raise ValueError(f"blocked_head_ce: blocks of {block} do not tile {s}")
+
+    @jax.checkpoint
+    def block_nll(args):
+        h, t = args                                  # [B, block, D], [B, block]
+        z = h @ head.astype(h.dtype)
+        lse = jax.nn.logsumexp(z.astype(jnp.float32), -1)
+        tok = jnp.take_along_axis(z, t[..., None], -1)[..., 0]
+        nll = lse - tok.astype(jnp.float32)
+        return jnp.sum(nll) if summed else nll
+
+    blocks = (jnp.moveaxis(hidden.reshape(b, s // block, block, d), 1, 0),
+              jnp.moveaxis(targets.reshape(b, s // block, block), 1, 0))
+    out = jax.lax.map(block_nll, blocks)
+    return out if summed else jnp.moveaxis(out, 0, 1).reshape(b, s)

@@ -52,7 +52,7 @@ held to. What differs here is how they are computed:
   more: six exchanges a layer a step, two of them forward).
 - a final RMSNorm and an untied head, whose cross entropy runs over
   ``HEAD_BLOCK`` positions of every sequence at a time
-  (``blocked_head_ce``).
+  (``blocked_head_ce``, the mean of ``models/blocks.py::blocked_head_nll``).
 
 The loss is the cross entropy plus ``router_aux_loss_coef`` times the sum
 over layers of ``E * sum_e f_e P_e`` over the global batch
@@ -81,7 +81,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ps_tpu.models.blocks import make_attn_fn, rms_norm, rope
+from ps_tpu.models.blocks import (blocked_head_nll, make_attn_fn, rms_norm,
+                                  rope)
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.flash_attention import KEPT
@@ -375,26 +376,16 @@ def apply(params: Dict, tokens, config: MellumConfig,
 
 def blocked_head_ce(hidden, head, targets, block):
     """``blocks.token_ce`` of ``hidden @ head`` for normed hidden states
-    ``hidden`` [B, S, D] and a head [D, V], the logits formed ``block``
-    positions of every sequence at a time, each block under a
-    ``jax.checkpoint``: [B, block, V] logits and their cotangent live at
+    ``hidden`` [B, S, D] and a head [D, V]: the mean of
+    ``blocks.blocked_head_nll``'s block sums (the blocked readout lives there
+    since PR 63: Ouro's four weighted readouts call it too), the logits
+    formed ``block`` positions of every sequence at a time, each block under
+    a ``jax.checkpoint``: [B, block, V] logits and their cotangent live at
     once, not [B, S, V] (98,304 ids at 8,192 tokens a chip: 0.8e9 B for
     3.2e9)."""
-    b, s, d = hidden.shape
-    if s % block:
-        raise ValueError(f"blocked_head_ce: blocks of {block} do not tile {s}")
-
-    @jax.checkpoint
-    def block_nll(args):
-        h, t = args                                  # [B, block, D], [B, block]
-        z = h @ head.astype(h.dtype)
-        lse = jax.nn.logsumexp(z.astype(jnp.float32), -1)
-        tok = jnp.take_along_axis(z, t[..., None], -1)[..., 0]
-        return jnp.sum(lse - tok.astype(jnp.float32))
-
-    blocks = (jnp.moveaxis(hidden.reshape(b, s // block, block, d), 1, 0),
-              jnp.moveaxis(targets.reshape(b, s // block, block), 1, 0))
-    return jnp.sum(jax.lax.map(block_nll, blocks)) / (b * s)
+    b, s, _ = hidden.shape
+    return jnp.sum(blocked_head_nll(hidden, head, targets, block,
+                                    summed=True)) / (b * s)
 
 
 def make_loss_fn(config: MellumConfig, attn: str = "full", mesh=None,

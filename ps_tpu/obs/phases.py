@@ -6,7 +6,8 @@ the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``ops/sparse_apply.py``, ``models/olmoe.py``, ``models/lfm2.py``,
 ``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
 ``models/mellum.py``, ``models/sdar.py``, ``models/joyai.py``,
-``models/granite_h.py``, ``models/qwen3_next.py``, ``models/blocks.py``,
+``models/granite_h.py``, ``models/qwen3_next.py``, ``models/ouro.py``,
+``models/blocks.py``,
 ``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
@@ -167,6 +168,20 @@ GRANITE_SCOPES = (ATTN, HEAD, FFN, MAMBA, MAMBA_CONV, MAMBA_SSD, MAMBA_GATE)
 # shared expert and its own sigmoid gate under MOE_SHARED.
 QWEN3_NEXT_SCOPES = MOE_SCOPES + (KDA, KDA_CONV, KDA_CORE, MOE_SHARED,
                                   ATTN_FULL, ATTN_ROPE, ATTN_GATE)
+
+# -- scopes of Ouro (models/ouro.py): ATTN, FFN and HEAD as the dense decoders ----------
+# LOOP is around all that runs ``total_ut_steps`` times on the same weights:
+# the stack's layers, with their ATTN and FFN inside it, and the final norm
+# that closes a pass. HEAD, around the one blocked readout of all the passes
+# behind the loop, and EXIT, around the passes' gates, the exit distribution,
+# its entropy and the weighting of the passes' losses, are opened beside it,
+# not inside: LOOP, HEAD and EXIT are disjoint. ATTN, FFN and HEAD are read by
+# ``benchmark/layer_metrics/decoder.py``, LOOP and EXIT by
+# ``benchmark/layer_metrics/ouro.py``; each keeps its own copy.
+LOOP = "ps.loop"                  # the passes: every layer application and the final norm of each pass
+EXIT = "ps.exit"                  # the passes' exit gates, the exit distribution, its entropy, the weighted sum
+
+OURO_SCOPES = (ATTN, HEAD, FFN, LOOP, EXIT)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

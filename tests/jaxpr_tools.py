@@ -1,5 +1,8 @@
 """What the tests read off a traced program."""
 
+import hashlib
+import re
+
 import jax
 import numpy as np
 
@@ -84,3 +87,11 @@ def layers_keep_what_their_policy_lists(monkeypatch, model, loss_args, attn,
                             jax.tree_util.tree_leaves(plain_grads)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
                                       err_msg=jax.tree_util.keystr(path))
+
+
+def digest(fn, *args):
+    """sha256 of ``fn``'s jaxpr at ``args`` as text, the addresses that a
+    function's repr carries taken out: the same program gives the same
+    digest in every process."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()

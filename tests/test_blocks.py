@@ -34,9 +34,12 @@ REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
               # Qwen3-Next's rule is token by token, its rotation explicit
               # pairs, its experts a masked loop
               "qwen3_next": ("cumsum", "pallas", "rotate_half",
-                             "ragged_dot")}
+                             "ragged_dot"),
+              # Ouro's passes and layers are Python loops: no kernel, no
+              # scan
+              "ouro": ("pallas", "lax.scan")}
 MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
-          "mellum", "sdar", "joyai", "granite_h", "qwen3_next")
+          "mellum", "sdar", "joyai", "granite_h", "qwen3_next", "ouro")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))
@@ -274,3 +277,39 @@ def test_the_interleaved_rotation_leaves_the_other_callers_trace_alone():
         lambda x: blocks.rope(x, 1e6, interleaved=True))(x))
     assert pairs.count("_roll_static") == 2 and "select_n" in pairs
     assert "strides=(1, 1, 1, 2)" not in pairs
+
+
+@pytest.mark.parametrize("summed", [True, False])
+def test_the_blocked_head_gives_the_whole_heads_loss_a_position(summed):
+    """``blocks.blocked_head_nll`` (Mellum's blocked readout, moved here in
+    PR 63 for Ouro's four weighted readouts): a position's loss [B, S], or
+    with ``summed`` a block's sum, is the whole head's, value and gradient;
+    weighted a position, its gradient is the weighted whole's; no [B, S, V]
+    array stands in its trace."""
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.normal(size=(2, 16, 8)), jnp.float32)
+    head = jnp.asarray(rng.normal(size=(8, 32)), jnp.float32)
+    targets = jnp.asarray(rng.integers(0, 32, size=(2, 16)), jnp.int32)
+    weights = jnp.asarray(rng.uniform(size=(2, 16)), jnp.float32)
+
+    def whole(h, w):
+        z = h @ w
+        nll = jax.nn.logsumexp(z, -1) - jnp.take_along_axis(
+            z, targets[..., None], -1)[..., 0]
+        return jnp.sum(nll) if summed else jnp.sum(weights * nll)
+
+    def blocked(h, w):
+        out = blocks.blocked_head_nll(h, w, targets, 4, summed=summed)
+        assert out.shape == ((4,) if summed else (2, 16))
+        return jnp.sum(out) if summed else jnp.sum(weights * out)
+
+    want = jax.value_and_grad(whole, (0, 1))(h, head)
+    got = jax.value_and_grad(blocked, (0, 1))(h, head)
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert float(jnp.max(jnp.abs(g - r))) <= 1e-5 * float(
+            jnp.max(jnp.abs(r)))
+    text = str(jax.make_jaxpr(jax.grad(blocked, (0, 1)))(h, head))
+    assert "f32[2,4,32]" in text and "[2,16,32]" not in text
+    with pytest.raises(ValueError, match="do not tile"):
+        blocks.blocked_head_nll(h, head, targets, 5)

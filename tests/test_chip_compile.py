@@ -598,6 +598,54 @@ def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
             + memory.temp_size_in_bytes) < 0.6 * 17.18e9
 
 
+def test_ouros_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
+    """``ouro-2.6b.s8192.b1.zipf``: value and gradient of the loss
+    ``KVStore.make_step`` differentiates, at the configuration's published
+    widths and [1, 8192] tokens, the passes scanned. One copy of the stack in
+    the program: 24 Mosaic flash calls, three for each of the eight layers,
+    all of them inside the passes' two loops (forward and backward; each
+    application's checkpoint keeps the forward call's output and logsumexp,
+    so none runs twice), where the unrolled passes would write 96. The
+    program's arguments are the parameters once and its results their
+    gradients once (2.45e9 B each: one f32 gradient a weight, the four
+    cotangents summed in the backward loop's carry); the temporaries (the
+    kept activations of 32 applications, the loops' bf16 copies of the
+    weights, the gradients' accumulators) stay under 5.4e9 B (5.17e9 compiled; 5.60e9 while
+    each pass ran its own readout inside the loop), and no [8192, 49152]
+    array of logits is among them."""
+    import json
+    import os
+
+    from ps_tpu.models import ouro
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ouro-2.6b.json")) as f:
+        cfg = ouro.OuroConfig.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: ouro.init_params(jax.random.key(0), cfg)))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    loss = ouro.make_loss_fn(cfg, attn="flash")
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, {"inputs": ids, "targets": ids}).compile()
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(flash) == 3 * cfg.num_hidden_layers
+    assert all("ps.loop" in line and "ps.attn" in line and "while" in line
+               for line in flash)
+    assert "8192,49152]" not in text and "2048,49152]" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 2.46e9
+    assert memory.output_size_in_bytes < 2.46e9
+    assert memory.temp_size_in_bytes < 5.4e9
+
+
 @pytest.mark.parametrize("dim,rule", [(32, "adagrad"), (1, "sgd")],
                          ids=["deep", "wide"])
 def test_a_tables_distinct_pull_and_held_push_compile_at_the_cells_shape(

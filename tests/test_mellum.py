@@ -570,6 +570,32 @@ def test_rope_leaves_its_old_call_alone_and_the_blocked_head_is_the_whole():
     assert _worst(blocked, whole) <= 1e-6
     with pytest.raises(ValueError, match="do not tile"):
         mellum.blocked_head_ce(h, head, targets, 5)
+    # the blocked readout lives in blocks.py since PR 63 and Mellum's name
+    # is the mean of its block sums
+    summed = jax.value_and_grad(lambda h, w: jnp.sum(blocks.blocked_head_nll(
+        h, w, targets, 4, summed=True)) / targets.size, (0, 1))(h, head)
+    assert _worst(summed, blocked) == 0
+
+
+#: sha256 of the jaxpr of value and gradient of Mellum's loss, one full layer
+#: over [1, 4096] tokens (two blocks of the head), as the tree stood before
+#: PR 63 moved the blocked readout into blocks.py (parent 9583dc0)
+MELLUM_JAXPR = "588fbf82266be705c2c0ea813249ed9ab98ddcc4728d63000c85d9c8e4c74711"
+
+
+def test_mellums_loss_traces_to_the_program_it_had():
+    """Moving ``blocked_head_ce``'s body into ``blocks.blocked_head_nll`` and
+    giving it a form that returns a position's loss changed nothing Mellum
+    traces: the jaxpr of its loss's value and gradient, head in two blocks,
+    is the parent commit's, by a pinned digest."""
+    from jaxpr_tools import digest
+
+    _, cfg, params, batch = _setup(
+        seq=4096, batch=1, num_hidden_layers=1, layer_types=[FULL],
+        mlp_layer_types=["sparse"])
+    assert batch["inputs"].shape[1] % mellum.HEAD_BLOCK == 0
+    assert digest(jax.value_and_grad(mellum.make_loss_fn(cfg), has_aux=True),
+                  params, batch) == MELLUM_JAXPR
 
 
 # -- (iv) the parameters, counted from shapes -----------------------------------

@@ -28,6 +28,7 @@ import pytest
 import ps_tpu as ps
 from benchmark.harness import tracered
 from benchmark.layer_metrics import decoder, host, scope, step
+from benchmark.layer_metrics import ouro as ouro_metrics
 from benchmark.layer_metrics import setup as setup_metrics
 from ps_tpu import obs
 from ps_tpu.data.prefetch import device_prefetch, threaded_source
@@ -161,7 +162,10 @@ def test_marks_change_no_instruction(kind, monkeypatch, no_compile_cache):
 #: the scopes the program opens that ``layer_metrics/decoder.py`` has no name
 #: for yet (PERF.md section 7, row 0)
 PROGRAMS_OWN = {phases.ATTN_INBLOCK, phases.ATTN_LATENT, phases.ATTN_ROPE,
-                phases.MTP, phases.MTP_JOIN, phases.MAMBA_GATE}
+                phases.MTP, phases.MTP_JOIN, phases.MAMBA_GATE,
+                # the looped decoder's two, which ``layer_metrics/ouro.py``
+                # reads (they nest around and beside decoder.py's scopes)
+                phases.LOOP, phases.EXIT}
 
 
 def test_program_and_benchmark_share_their_names():
@@ -188,7 +192,10 @@ def test_program_and_benchmark_share_their_names():
         assert getattr(phases, name) == getattr(decoder, name), name
     families = [name for name in vars(phases) if name.endswith("_SCOPES")]
     opened = set().union(*(getattr(phases, name) for name in families))
-    assert len(families) == 10
+    assert len(families) == 11
+    assert (ouro_metrics.LOOP, ouro_metrics.EXIT) == (phases.LOOP,
+                                                      phases.EXIT)
+    assert set(ouro_metrics.MARKS) == {phases.LOOP, phases.EXIT}
     assert opened - PROGRAMS_OWN <= set(decoder.METRICS) <= opened
     assert PROGRAMS_OWN <= opened and not PROGRAMS_OWN & set(decoder.METRICS)
     assert not opened & set(phases.DEVICE_PHASES)
@@ -599,6 +606,7 @@ _JVP = "jit(f)/ps.grad/jvp({})/"
 _CP = "jit(f)/ps.grad/jvp()/checkpoint/"
 _BACK = "jit(f)/ps.grad/transpose(jvp())/"
 _RULE = "jit(f)/ps.grad/transpose(ps.grad)/checkpoint/"
+_LOOPED = "jit(f)/ps.grad/jvp()/while/body/{}/"
 _TARGETS = {"kernel_targets": ["tpu_custom_call"]}
 #: what every decoder's step has beside its scopes: the head, the embedding
 #: (under ps.grad, in no scope) and the apply
@@ -974,7 +982,45 @@ READER_CASES = {
                  "kernel.flash_roofline": 40.0},       # 2 of 5 ms
         # S11 row 0: the one scope of this model without a name of its own
         "may": {"decoder.mamba_gate_ms": 2.0},
-        "mfu": 5.0, "device_ms": 29.5}}
+        "mfu": 5.0, "device_ms": 29.5},
+    "ouro": {   # dense and looped: the passes a while loop, the head and the
+        # exit beside it; a layer application under its checkpoint
+        "events": [
+            ("%qkv", "fusion", 0.004,
+             _LOOPED.format("ps.loop/checkpoint/ps.attn") + "dot_general"),
+            ("%flash", "custom-call", 0.010,
+             "jit(f)/ps.grad/transpose(ps.grad)/while/body/ps.loop/"
+             "checkpoint/ps.attn/pallas_call"),
+            ("%swiglu", "fusion", 0.012,
+             _LOOPED.format("ps.loop/checkpoint/ps.ffn") + "dot_general"),
+            # norms and residuals of an application, and the final norm:
+            # in the loop, in no scope of decoder.py's
+            ("%post_norm", "fusion", 0.003,
+             _LOOPED.format("ps.loop/checkpoint") + "rsqrt"),
+            ("%final_norm", "fusion", 0.001,
+             _LOOPED.format("ps.loop/checkpoint") + "mul"),
+            ("%readout", "fusion", 0.006,
+             _LOOPED.format("ps.head") + "while/body/checkpoint/dot_general"),
+            ("%gate", "fusion", 0.001,
+             _LOOPED.format("ps.exit/checkpoint") + "logistic"),
+            ("%weighted", "fusion", 0.001, _JVP.format("ps.exit") + "mul"),
+            ("%embed", "fusion", 0.001, "jit(f)/ps.grad/jvp()/gather"),
+            ("%adam", "fusion", 0.007, "jit(f)/ps.apply/mul")],
+        "facts": {**_TARGETS, "dense_flops_per_step": 5e9, "passes": 4,
+                  "flash_flops": 2e9, "flash_bytes": 1.0},
+        "counters": {"expected_passes": 1.875, "exit_entropy": 1.2},
+        "want": {"decoder.attn_ms": 7.0, "decoder.dense_ffn_ms": 6.0,
+                 "decoder.head_ms": 3.0,
+                 "kernel.flash_roofline": 40.0},       # 2 of 5 ms
+        # what layer_metrics/ouro.py makes of the same result: decoder.py's
+        # three under its names, the loop (attention, SwiGLU, norms and
+        # residuals, the final norm) and the exit by their own marks, the
+        # two counters as they stand; loop + head + exit lie under ps.grad
+        "ouro": {"ouro.attn_ms": 7.0, "ouro.dense_ffn_ms": 6.0,
+                 "ouro.head_ms": 3.0, "ouro.loop_ms": 15.0,
+                 "ouro.exit_ms": 1.0, "ouro.expected_passes": 1.875,
+                 "ouro.exit_entropy": 1.2},
+        "mfu": 5.0, "device_ms": 23.0}}
 
 
 @pytest.mark.parametrize("case", sorted(READER_CASES))
@@ -1036,6 +1082,22 @@ def test_the_one_reader_reads_a_decoders_hand_made_result(monkeypatch, case):
                       {**r, "counters": {"dropped_tokens": 0.0}}):
             assert set(times) - set(decoder.scope_times(other, names)) == {
                 "decoder.exchange_ici_share"}
+    if "ouro" in made:
+        mine = ouro_metrics.read({**r, "decoder": whole})
+        assert mine == pytest.approx(made["ouro"], rel=1e-9)
+        assert (mine["ouro.loop_ms"] + mine["ouro.head_ms"]
+                + mine["ouro.exit_ms"]) <= 1e3 * busy_s / 2
+        # a rehearsal lists the names and no value; a program without the
+        # marks or the counters gives nothing
+        listed = ouro_metrics.read({**{k: v for k, v in r.items()
+                                       if k != "decoder"},
+                                    "peaks": {}, "trace": None})
+        assert set(listed) == set(made["ouro"]) and not any(
+            listed[k] for k in listed if k.endswith("_ms"))
+        monkeypatch.setattr(scope, "loaded_op_names", lambda: {})
+        assert ouro_metrics.read(
+            {**r, "counters": {}, "decoder": {}}) == {}
+        monkeypatch.setattr(scope, "loaded_op_names", lambda: names)
     # a program without the scopes or the counters: nothing to read, nothing
     # at 0
     r["trace"]["devices"] = {"d0": {"ops": {_ev("%qkv"): 0.004}}}
@@ -1818,6 +1880,64 @@ def test_qwen3_next_scopes_reach_the_step_hlo_forward_and_backward(
             if phases.ATTN_ROPE in n} == {phases.ATTN}
 
 
+def _ouro_step():
+    """``(run, batch)`` of ``make_step`` on a tiny Ouro: two layers run three
+    times, the passes scanned."""
+    from ps_tpu.models import ouro
+
+    cfg = ouro.OuroConfig.from_dict(dict(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        head_dim=16, total_ut_steps=3, dtype="float32"))
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: ouro.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    return (store.make_step(ouro.make_loss_fn(cfg), has_aux=True),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_ouro_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Ouro opens (``OURO_SCOPES``): ``ps.attn``, ``ps.ffn`` and
+    ``ps.head`` as the dense decoders, ``ps.loop`` around the passes and
+    ``ps.exit`` around the gates and the weighting: each in the lowered
+    step's ``op_name``s under ``ps.grad``, forward and backward, though the
+    passes are a scan and every layer application is under a
+    ``jax.checkpoint``. ``decoder.py`` reads the attention and the SwiGLU by
+    the innermost scope, inside the loop; the loop's own events (norms,
+    residuals) and the exit's in none of its; the head's and the exit's are
+    not the loop's."""
+    assert phases.OURO_SCOPES == (phases.ATTN, phases.HEAD, phases.FFN,
+                                  phases.LOOP, phases.EXIT)
+    monkeypatch.setitem(BUILDERS, "ouro", _ouro_step)
+    names = scope.op_names_of(_step_hlo("ouro"))
+    for s in phases.OURO_SCOPES:
+        under = [n for n in names.values() if s in n]
+        # what the passes' scan finds the same in every pass (the rotation's
+        # cos and sin, the mask, the targets' indices) JAX lifts out of the
+        # loop and names without the transform's scope: no product among
+        # them
+        lifted = [n for n in under if phases.GRAD not in n]
+        assert not [n for n in lifted
+                    if n.endswith(("dot_general", "pallas_call"))], s
+        under = [n for n in under if n not in lifted]
+        assert under and len(under) > len(lifted), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == {phases.ATTN, phases.HEAD, phases.FFN, None}
+    for inner in (phases.ATTN, phases.FFN):
+        assert all(phases.LOOP in n for n in names.values() if inner in n)
+    assert {decoder.scope_of("%x", n) for n in names.values()
+            if phases.EXIT in n} == {None}
+    for outside in (phases.HEAD, phases.EXIT):
+        assert not [n for n in names.values()
+                    if outside in n and phases.LOOP in n], outside
+    assert not [n for n in names.values() if "ps.moe" in n]
+
+
 # -- the benchmark's own command on the CPU, and its manifest ------------------
 
 with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
@@ -1865,7 +1985,11 @@ REHEARSED = {
         "setup.import_s", "device.peak_hbm_gib"),
     "qwen3-next-80b-a3b.s8192.b1.zipf": (
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
-        "setup.import_s", "device.peak_hbm_gib")}
+        "setup.import_s", "device.peak_hbm_gib"),
+    "ouro-2.6b.s8192.b1.zipf": (
+        "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
+        "setup.import_s", "device.peak_hbm_gib", "step.mfu",
+        "kernel.flash_roofline")}
 
 
 @pytest.mark.parametrize("cell", sorted(REHEARSED))

@@ -352,6 +352,52 @@ def test_nemotron_grad_check_rehearses(model):
             or read["loss_rel_diff"] > 2 * family.TOLERANCE[0], name
 
 
+def test_nemotron_grad_check_rehearses_ouro():
+    """``tools/nemotron_grad_check.py --model ouro``: a loss with an ``aux``
+    and no routing. The system's gradients are the reference's and it comes
+    out correct through ``ouro_step.step0_checks`` and the loss's tolerance;
+    the reference on 8-bit weights and each of the six faults the limits are
+    there for do not: four by the gradients, the objective and the exit
+    distribution at once, the last pass's gradient alone by the gradients
+    and nothing else (its loss is the whole one's), the entropy's sign by
+    the objective and the gate's gradient, turned round."""
+    from benchmark.families import ouro_step as family
+
+    out = _run("nemotron_grad_check.py", "--rehearse", "--table",
+               "--model", "ouro")
+    assert out["worst"]["cosine"] > 1 - 1e-9
+    assert out["loss"]["rel_diff"] < 1e-5
+    assert out["system"]["correct"] and out["system"]["failed"] == []
+    # what the aux's checks read stands beside the verdict
+    assert out["system"]["exit_apart"] < 1e-6 \
+        and out["system"]["objective_rel_diff"] < 1e-6
+    assert out["pairs_on_another_expert"] is None
+    found = out["reference_on_e4m3_weights"]
+    cosines = [v for k, v in found.items() if k.startswith("grad_cosine")]
+    assert len(cosines) == len(family.GRAD_COSINE) and max(cosines) < 0.999
+    assert not found["correct"] \
+        and "gradient_matches_reference" in found["failed"]
+    faults = {k[len("reference_with_"):]: v for k, v in out.items()
+              if k.startswith("reference_with")}
+    assert len(faults) == 6
+    for name, read in faults.items():
+        assert not read["correct"], name
+        assert "gradient_matches_reference" in read["failed"], name
+    # the forward pass is the whole one's: the gradients alone tell
+    assert faults["gradient_of_the_last_pass_alone"]["loss_rel_diff"] == 0
+    assert faults["gradient_of_the_last_pass_alone"]["failed"] == [
+        "gradient_matches_reference"]
+    for name in ("final_norm_once_after_the_last_pass",
+                 "post_norms_left_out",
+                 "last_pass_takes_its_own_gates_share"):
+        assert {"step0_matches_reference",
+                "exit_distribution_matches_reference"} \
+            <= set(faults[name]["failed"]), name
+    sign = faults["entropy_term_added"]
+    assert "objective_matches_reference" in sign["failed"]
+    assert sign["least_grad_cosine"] < -0.5
+
+
 def test_window_table_rehearses():
     """tools/window_table.py at tiny shapes on the CPU: the band step's
     three calls run under the tool's own wrappers at both cells' names and

@@ -43,6 +43,20 @@ false``, with the checks it ``failed``; with ``--table`` the system itself
 goes through the same and has to come out ``correct: true``. Nemotron's checks
 read the step's own aux (the held counts, the bias after the sign rule), which
 a reference does not return: its cases are printed beside the limits only.
+
+``--model ouro`` runs them for ``ouro-2.6b.s8192.b1.zipf`` (a loss with an
+``aux`` and no routing: the step's loss as the loop reads it is ``aux["ce"]``,
+and ``ouro_step.step0_checks`` also compares the objective, the passes' cross
+entropies and the exit distribution; the Pallas flash kernel at 16 heads on
+16, 32 calls each way) with the six faults its limits are there for: the
+gradient of the last pass alone (the earlier passes' uses of the stack held
+constant), the final norm applied once after the last pass, the two
+post-norms left out, the last pass given its own gate's share and not what is
+left (mass lost), the entropy term's sign, and the rotation over half the
+channels; to ``chiprun_out/ouro_grad_check.json``. ``--table`` is for its
+rehearsal: at the published widths the system's gradient tree and the
+reference's for every tensor do not fit the chip together, and the cell's own
+step-0 checks are where the system comes out ``correct: true``.
 """
 
 from __future__ import annotations
@@ -74,10 +88,93 @@ def _norm_before_gate(reference):
     return {"gated_norm": gated_norm}
 
 
+def _last_pass_alone(reference):
+    """``passes`` with every pass but the last reading the stack and the
+    final norm as constants: the weights' gradient is the last use's alone,
+    not the sum over the passes. For ``ouro_reference.passes``."""
+    import jax
+
+    def passes(params, ids, config):
+        last = config["total_ut_steps"] - 1
+        held = jax.lax.stop_gradient(params)
+        u = params["embed"]["tokens"][ids]
+        out = []
+        for t in range(last + 1):
+            p = params if t == last else held
+            u = reference.rms_norm(
+                reference.run_layers(p, u, config),
+                p["final_norm"]["scale"], config["rms_norm_eps"])
+            out.append(u)
+        return out
+
+    return {"passes": passes}
+
+
+def _final_norm_once(reference):
+    """``passes`` as a plain decoder would close them: the stream runs on
+    un-normed from pass to pass, the earlier readouts and gates read it as it
+    is, and the final norm is applied once, after the last pass."""
+    def passes(params, ids, config):
+        u = params["embed"]["tokens"][ids]
+        out = []
+        for _ in range(config["total_ut_steps"]):
+            u = reference.run_layers(params, u, config)
+            out.append(u)
+        out[-1] = reference.rms_norm(u, params["final_norm"]["scale"],
+                                     config["rms_norm_eps"])
+        return out
+
+    return {"passes": passes}
+
+
+def _no_post_norms(reference):
+    """``layer`` with a pre-norm alone in front of each part."""
+    def layer(lp, x, config):
+        eps = config["rms_norm_eps"]
+        a = x + reference.attention(lp["attn"], reference.rms_norm(
+            x, lp["attn_norm"]["scale"], eps), config)
+        return a + reference.swiglu(lp["ffn"], reference.rms_norm(
+            a, lp["ffn_norm"]["scale"], eps))
+
+    return {"layer": layer}
+
+
+def _last_gate_read(reference):
+    """``p_T = lambda_T S_{T-1}``: the last pass takes its own gate's share
+    and the rest of the mass is lost."""
+    import jax.numpy as jnp
+
+    def exit_distribution(lam):
+        p, left = [], jnp.ones_like(lam[0])
+        for t in range(lam.shape[0]):
+            p.append(lam[t] * left)
+            left = left * (1.0 - lam[t])
+        return jnp.stack(p)
+
+    return {"exit_distribution": exit_distribution}
+
+
+def _half_rotation(reference):
+    """``rope`` over the first half of every head's channels (a
+    ``partial_rotary_factor`` of 0.5), the rest passed through."""
+    import jax.numpy as jnp
+
+    whole = reference.rope
+
+    def rope(x, theta):
+        half = x.shape[-1] // 2
+        return jnp.concatenate([whole(x[..., :half], theta), x[..., half:]],
+                               -1)
+
+    return {"rope": rope}
+
+
 #: a model's name in ``ps_tpu.models`` and ``benchmark.families`` -> its
 #: configuration class and files, whether its loss takes a selection bias and
-#: returns counts, and the faults planted in its reference: a change to the
-#: configuration's keys, or functions of the reference to swap
+#: returns counts (``routed``) or returns an ``aux`` whose ``ce`` is the
+#: step's loss as the loop reads it (``aux``), and the faults planted in its
+#: reference: a change to the configuration's keys, or functions of the
+#: reference to swap
 MODELS = {
     "nemotron_h": {
         "config_class": "NemotronHConfig",
@@ -95,7 +192,17 @@ MODELS = {
                    "logits_not_divided_by_8": {"logits_scaling": 1.0},
                    "attention_scaled_by_an_eighth":
                    {"attention_multiplier": 0.125},
-                   "norm_before_the_gate": _norm_before_gate}}}
+                   "norm_before_the_gate": _norm_before_gate}},
+    "ouro": {
+        "config_class": "OuroConfig",
+        "config": "ouro-2.6b", "traffic": "s8192.b1.zipf.n96",
+        "routed": False, "aux": True,
+        "faults": {"gradient_of_the_last_pass_alone": _last_pass_alone,
+                   "final_norm_once_after_the_last_pass": _final_norm_once,
+                   "post_norms_left_out": _no_post_norms,
+                   "last_pass_takes_its_own_gates_share": _last_gate_read,
+                   "entropy_term_added": {"exit_entropy_beta": -0.05},
+                   "rotation_over_half_the_channels": _half_rotation}}}
 
 
 def main(argv=None) -> int:
@@ -142,6 +249,7 @@ def main(argv=None) -> int:
     cfg = getattr(model, spec["config_class"]).from_dict(config)
     witnesses = tuple(family.GRAD_COSINE)
     routed = spec["routed"]
+    has_aux = routed or spec.get("aux", False)
     # what the loss takes beside the parameters and the batch
     extra = (model.init_expert_bias(cfg),) if routed else ()
 
@@ -155,12 +263,13 @@ def main(argv=None) -> int:
     def with_aux(out):
         """``((loss, aux), grads)`` of a routed model's or of one whose loss
         is a scalar alone."""
-        return out if routed else ((out[0], None), out[1])
+        return out if has_aux else ((out[0], None), out[1])
 
     system = jax.jit(jax.value_and_grad(
-        model.make_loss_fn(cfg, attn=traffic["attn"]), has_aux=routed))
+        model.make_loss_fn(cfg, attn=traffic["attn"]), has_aux=has_aux))
     plain = jax.jit(jax.value_and_grad(
-        lambda p, b: reference.loss_fn(p, b, *extra, config), has_aux=routed))
+        lambda p, b: reference.loss_fn(p, b, *extra, config),
+        has_aux=has_aux))
     on_witnesses = jax.jit(lambda p, b: reference.witness_grads(
         p, b, *extra, config, witnesses))
     fp8 = jnp.float8_e4m3fn   # the nearest precision below bfloat16
@@ -200,23 +309,31 @@ def main(argv=None) -> int:
     opt = dict(config["optimizer"])
     _, rule = learning_rate(opt, opt.pop("warmup_steps", 0))
 
-    def verdict(loss, grads, ref_loss, whole):
+    def verdict(loss, grads, ref_loss, whole, aux=None, ref_aux=None):
         """A case as if it were the system, against the whole reference: the
         family's own ``step0_checks`` on its witnesses' gradients (as what
         AdamW's first moment holds of an unclipped gradient; no apply to
-        read) and the loss under the family's tolerance, which is what the
-        loop's ``correct`` holds at step 0."""
+        read; with an ``aux`` the objective and the aux too) and the loss as
+        the loop reads it (``aux["ce"]`` where the loss has one) under the
+        family's tolerance, which is what the loop's ``correct`` holds at
+        step 0."""
         if routed:
             return {}
+        read = (aux["ce"], ref_aux["ce"]) if aux else (loss, ref_loss)
         result = family.step0_checks(
             {k: {"mu": (1 - rule["b1"]) * np.asarray(grads[k], np.float64),
                  "reference_grad": np.asarray(whole[k])} for k in witnesses},
-            rule["clip_by_global_norm"], rule)
+            rule["clip_by_global_norm"], rule,
+            *([{"loss": loss, **aux}, {"loss": ref_loss, **ref_aux}]
+              if aux else []))
         checks = {"step0_matches_reference":
-                  rel(loss, ref_loss) <= family.TOLERANCE[0],
+                  rel(*read) <= family.TOLERANCE[0],
                   **result["checks"]}
         return {"correct": all(checks.values()),
-                "failed": sorted(k for k, ok in checks.items() if not ok)}
+                "failed": sorted(k for k, ok in checks.items() if not ok),
+                # what the aux's checks read, beside their limits
+                **{k: v for k, v in result["detail"].items()
+                   if k.endswith(("_rel_diff", "_apart")) and aux}}
 
     def lengths(grads, whole):
         ratios = {k: norm(grads[k]) / norm(whole[k]) for k in witnesses}
@@ -249,7 +366,7 @@ def main(argv=None) -> int:
             one["system"] = verdict(loss, {
                 k: functools.reduce(lambda t, part: t[part], k.split("/"),
                                     grads) for k in witnesses},
-                ref_loss, whole)
+                ref_loss, whole, aux, ref_aux)
             rows = []
             flat, _ = jax.tree_util.tree_flatten_with_path(grads)
             for (path, g), r in zip(flat,
@@ -279,17 +396,17 @@ def main(argv=None) -> int:
             **{f"grad_cosine.{k}": cosine(v_grads[k], whole[k])
                for k in witnesses},
             **lengths(v_grads, whole),
-            **verdict(value, v_grads, ref_loss, whole)}
+            **verdict(value, v_grads, ref_loss, whole, v_aux, ref_aux)}
         for name, (swapped, run) in faulty.items():
             with jax.default_matmul_precision("highest"), swap(swapped):
-                (f_loss, _), f_grads = with_aux(timed(
+                (f_loss, f_aux), f_grads = with_aux(timed(
                     f"reference with {name}", lambda: run(params, batch)))
             one[f"reference_with_{name}"] = {
                 "loss_rel_diff": rel(f_loss, ref_loss),
                 "least_grad_cosine": min(cosine(f_grads[k], whole[k])
                                          for k in witnesses),
                 **lengths(f_grads, whole),
-                **verdict(f_loss, f_grads, ref_loss, whole)}
+                **verdict(f_loss, f_grads, ref_loss, whole, f_aux, ref_aux)}
         out["seeds"].append(one)
         # one line a seed; the last line of stdout is the last seed's
         print(json.dumps({k: v for k, v in one.items() if k != "gradients"}),
