@@ -16,6 +16,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
+from ps_tpu.ops import rope as rotary
 from ps_tpu.ops.gated_conv import conv_silu
 from ps_tpu.ops.ssd import ssd
 
@@ -38,11 +39,38 @@ def rope(x, theta, *, inv_freq=None, scale=None, interleaved=False):
     ``j``-th angle, and come out where they went in: the partner is a lane's
     neighbour, fetched by two rolls and a select on the lane's parity, no
     strided slice. Without any of the three the trace is the one-table
-    call's."""
+    call's.
+
+    **Where the halves' rotation runs on the chip.** At heads of one 128-lane
+    tile on whole row blocks (``ops/rope.py::path``: Ouro, OLMoE, SDAR,
+    Trinity's windowed layers, Mellum with its table and factor) it is
+    ``ops/rope.py::rotate``, one Mosaic pass that reads ``x`` in its own
+    dtype and writes the attention kernel's operand, the same f32 arithmetic
+    in VMEM. The expression below, compiled for a v5e, makes XLA write
+    ``x.astype(f32)``, ``-x2`` and ``x1`` as arrays of their own (half of each
+    tile empty) before the fusion that sums them, and the same again for the
+    cotangent; bytes a tensor a pass at ``bf16[1, 8192, 16, 128]``, as tiled
+    (``ops/rope.py``'s table):
+
+    | | written | read | passes a layer application |
+    |---|---|---|---|
+    | the expression | 67 + 134 + 34 MB | 67 + 67 + 134 MB | q and k: forward, recomputation, transposed |
+    | ``rotate`` | 34 MB | 34 MB + 8 MB of tables | the same six |
+
+    The [B, S, h, d] contract stands: ``rotate`` takes the head-major view,
+    which is how the projection's product (or the head norm's output) lies on
+    the chip and what ``flash_attention`` asks for, so the two transpositions
+    here are bitcasts in the compiled step and no caller changes. Every other
+    shape (heads of 64, a rotated slice, ``interleaved``, the narrow widths
+    of tests and rehearsals) takes the expression, its trace untouched."""
     seq, dim = x.shape[1], x.shape[-1]
     if inv_freq is None:
         inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None]
+    if rotary.path(x, interleaved) == "kernel":
+        turned = rotary.rotate(jnp.transpose(x, (0, 2, 1, 3)),
+                               *rotary.tables(angles, scale))
+        return jnp.transpose(turned, (0, 2, 1, 3))
     if interleaved:
         cos, sin = (jnp.repeat(f(angles), 2, axis=-1)[None, :, None, :]
                     for f in (jnp.cos, jnp.sin))

@@ -33,7 +33,16 @@ they are computed here:
   ``PERF.md`` section 6 (PR 63) has both on the chip and why the default;
 - attention: q, k and v without bias or norm, all 128 channels of q and k
   rotated (``blocks.rope``, halves against each other), causal; with
-  ``attn='flash'`` the Pallas kernel at 16 heads on 16;
+  ``attn='flash'`` the Pallas kernel at 16 heads on 16. At the cell's shape
+  the rotation is ``ops/rope.py::rotate``, a Mosaic pass between the
+  projection's ``bf16[1,16,8192,128]`` and the flash call that reads q (and
+  k) once and writes it once, 34 MB each way, six times a layer application
+  (q and k: forward, the checkpoint's recomputation, and the same pass on dq
+  and dk). ``blocks.rope``'s ``jax.numpy`` expression made XLA write the
+  product as ``f32[1,8192,16,128]``, two half-width ``f32[1,8192,16,64]`` and
+  then the kernel's operand, 235 MB written and 268 MB read where 34 + 34
+  do, in each of those six passes: about 2.5 GB an application, 81 GB a step
+  of 32 (``ops/rope.py``'s table; ``PERF.md`` section 6, PR 64);
 - the SwiGLU: ``blocks.dense_ffn`` (``w1`` the gate, ``w3`` up, ``w2`` down);
 - the readouts: one call of ``blocks.blocked_head_nll`` over the ``T x B``
   sequences ``h(1..T)`` that the passes give, behind the loop, all ``T``

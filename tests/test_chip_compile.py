@@ -59,6 +59,30 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+def _written(text: str):
+    """The result types of a compiled program's instructions that write an
+    array: every instruction outside the computations a ``fusion`` calls
+    (what stands inside one lives in registers and VMEM)."""
+    fused = set(re.findall(r" fusion\([^\n]*?calls=(%[\w.\-]+)", text))
+    types, inside = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):  # a computation opens or closes
+            inside = line.split(" ")[0] in fused
+        elif not inside and " = " in line:
+            result = re.match(r"(.*?)\s[\w\-]+\(", line.split(" = ", 1)[1])
+            types.append(result.group(1) if result else "")
+    return types
+
+
+def _mosaic_calls(text: str):
+    """A compiled program's Mosaic calls: the rotation's (``ops/rope.py``
+    names its kernels ``rope`` and ``rope_transposed``) and the others."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    rotations = [line for line in calls if "rope" in line.split(" = ")[0]]
+    return rotations, [line for line in calls if line not in rotations]
+
+
 #: [B, S, query heads, K/V heads, head dim (, the values' own)], causal,
 #: Mosaic calls of the gradient: the five cells' calls. The forward, dk / dv
 #: and dq; at BERT's shape one backward tile spans the sequence and one call
@@ -605,7 +629,11 @@ def test_ouros_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     the program: 24 Mosaic flash calls, three for each of the eight layers,
     all of them inside the passes' two loops (forward and backward; each
     application's checkpoint keeps the forward call's output and logsumexp,
-    so none runs twice), where the unrolled passes would write 96. The
+    so none runs twice), where the unrolled passes would write 96; and the
+    rotation's 48 (``ops/rope.py``: q and k of a layer in the forward loop,
+    again in the backward loop's recomputation, and the same pass on dq and
+    dk), between which and the projection's ``bf16[1,16,8192,128]`` no f32
+    array of q's or k's size, whole or in halves, is written (ISSUE 64). The
     program's arguments are the parameters once and its results their
     gradients once (2.45e9 B each: one f32 gradient a weight, the four
     cotangents summed in the backward loop's carry); the temporaries (the
@@ -634,16 +662,85 @@ def test_ouros_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
     compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
         params, {"inputs": ids, "targets": ids}).compile()
     text = compiled.as_text()
-    flash = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    rotations, flash = _mosaic_calls(text)
     assert len(flash) == 3 * cfg.num_hidden_layers
+    # q and k: forward, recomputation, and the cotangent's (the same pass)
+    assert len(rotations) == 6 * cfg.num_hidden_layers
+    assert sum("rope_transposed" in line for line in rotations) \
+        == 2 * cfg.num_hidden_layers
     assert all("ps.loop" in line and "ps.attn" in line and "while" in line
-               for line in flash)
+               for line in rotations + flash)
+    # the rotation writes the flash call's operand and nothing else: no f32
+    # copy of a projection's product and no half-width f32 array
+    assert not [kind for kind in _written(text)
+                if "f32[1,8192,16,128]" in kind or "f32[1,8192,16,64]" in kind
+                or "f32[1,16,8192,128]" in kind]
     assert "8192,49152]" not in text and "2048,49152]" in text
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes < 2.46e9
     assert memory.output_size_in_bytes < 2.46e9
     assert memory.temp_size_in_bytes < 5.4e9
+
+
+#: [B, S, query heads, K/V heads] of 128 channels, the window or None: the
+#: cells whose q and k reach ``blocks.rope`` behind an RMSNorm over each head
+ROTATIONS = {"trinity-mini.s16384.b1.zipf, windowed": ((1, 16384, 32, 4), 2048),
+             "sdar-30b-a3b.s8192.b1.zipf.bd4": ((2, 8192, 32, 4), None),
+             "mellum2-12b-a2.5b.s8192.b1.zipf.x4, a chip's": (
+                 (1, 8192, 32, 4), 1024)}
+
+
+@pytest.mark.parametrize("cell", sorted(ROTATIONS))
+def test_the_rotation_compiles_behind_a_head_norm_at_the_cells_shapes(
+        cell, one_chip, no_compile_cache):
+    """Projection, ``rms_norm`` over a head, ``blocks.rope``, the flash call
+    and their gradient as Trinity's windowed layers, SDAR's and Mellum's
+    write them: the rotation is ``ops/rope.py``'s Mosaic call (``path``) at
+    tiles that fit the VMEM, twice forward and twice transposed beside the
+    flash kernels' three; the norm's bf16 output is the call's operand as it
+    stands (no ``copy`` in front of it, the transposition a bitcast), the
+    call's output the flash call's; and no f32 array of q's or k's size in
+    halves or head-major is written: what ``rope``'s ``jax.numpy`` form made
+    XLA write three times a tensor (ISSUE 64)."""
+    from ps_tpu.models import blocks
+    from ps_tpu.ops import rope
+
+    (b, s, h, h_kv), window = ROTATIONS[cell]
+    d = 2048
+
+    def loss(x, wq, wk, wv, sq, sk):
+        def proj(w, n):
+            return (x @ w).reshape(b, s, n, 128)
+
+        q = blocks.rope(blocks.rms_norm(proj(wq, h), sq, 1e-6), 1e4)
+        k = blocks.rope(blocks.rms_norm(proj(wk, h_kv), sk, 1e-6), 1e4)
+        assert rope.path(q) == rope.path(k) == "kernel"
+        return jnp.sum(flash_attention(q, k, proj(wv, h_kv), causal=True,
+                                       window=window).astype(jnp.float32))
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        arg(b, s, d), arg(d, h * 128), arg(d, h_kv * 128),
+        arg(d, h_kv * 128), arg(128, dtype=jnp.float32),
+        arg(128, dtype=jnp.float32)).compile().as_text()
+    rotations, flash = _mosaic_calls(text)
+    assert len(rotations) == 4 and len(flash) == 3
+    assert sum("rope_transposed" in line for line in rotations) == 2
+    made = {line.split(" = ")[0].strip(): line for line in text.splitlines()
+            if " = " in line}
+    for line in rotations:
+        operand = line.split("custom-call(")[1].split(",")[0]
+        assert " copy(" not in made[operand], made[operand][:200]
+    written = " ".join(_written(text))
+    for n in (h, h_kv):
+        assert f"f32[{b},{s},{n},64]" not in written
+        assert f"f32[{b},{n},{s},128]" not in written
+        # at a batch of two XLA hands the norm its projection in f32 (a
+        # ``convolution_convert_fusion`` and a re-laid copy of it, before
+        # this kernel as after): the norm's, not the rotation's
+        assert b > 1 or f"f32[{b},{s},{n},128]" not in written
 
 
 @pytest.mark.parametrize("dim,rule", [(32, "adagrad"), (1, "sgd")],
