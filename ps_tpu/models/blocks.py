@@ -3,6 +3,10 @@ a block two of them need stands here, a block one needs in that model's file.
 The blocked readout (``blocked_head_nll``) came from ``models/mellum.py`` in
 PR 63: Mellum's ``blocked_head_ce`` is the mean of its block sums, Ouro's four
 readouts (``models/ouro.py``) take a position's loss from it and weight it.
+``layer_norm``, ``diff_attention_block`` and ``gmu_block`` came with
+Phi-4-mini-flash (``models/phi4flash.py``, PR 65), which is their one caller
+so far: ISSUE 65 asked for them here, beside the closure and the norm they
+are built on, where the next differential or memory-gated model finds them.
 """
 
 from __future__ import annotations
@@ -26,6 +30,15 @@ def rms_norm(x, scale, eps):
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (scale * xf).astype(x.dtype)
+
+
+def layer_norm(x, scale, bias, eps):
+    """LayerNorm over the last axis: mean and variance in f32, the scale and
+    the bias applied there, result in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (scale * xf + bias).astype(x.dtype)
 
 
 def rope(x, theta, *, inv_freq=None, scale=None, interleaved=False):
@@ -187,6 +200,61 @@ def mla_block(lp: Dict, x, config, attn_fn: Callable):
     with jax.named_scope(phases.ATTN_FULL):
         a = attn_fn(q, k, kv[..., nope:], causal=True)
     return a.reshape(b, s, -1) @ lp["out"]["kernel"].astype(x.dtype)
+
+
+def diff_attention_block(lp: Dict, q, k, v, attn_fn: Callable, *,
+                         lambda_init: float, eps: float, core: str,
+                         window: Optional[int] = None):
+    """Differential attention (Ye et al., arXiv:2410.05258 section 2) of
+    ``q`` [B, S, 2 h, d], ``k`` and ``v`` [B, S, 2 h_kv, d]: consecutive
+    heads are a pair ``(q1, q2)``, ``(k1, k2)``, and a pair of value heads is
+    ONE value head ``2 d`` wide, which both maps read::
+
+        a_j = softmax(q_j k_j^T / sqrt(d)) v                  j = 1, 2
+        lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+        out = (1 - lambda_init) * rms_norm_{2d}(a_1 - lambda a_2) * w
+
+    with four learned vectors of ``d`` and one norm scale of ``2 d`` in
+    ``lp``, the norm a head. The two maps are ONE call of ``attn_fn``
+    (opened under the scope ``core``), ``2 h`` query heads on ``2 h_kv`` K/V
+    heads over the value heads twice: the first ``h`` query heads are the
+    ``q1`` and read ``k1``, the last ``h`` the ``q2`` on ``k2`` (each K/V
+    head serves ``h / h_kv`` consecutive query heads either way), keys ``d``
+    wide against values ``2 d``: the kernel's ``d_v``. Two calls of ``h`` on
+    ``h_kv`` heads are the same work in twice the grid launches and were not
+    faster (``PERF.md`` section 6, PR 65); v's copy is 42 MB at the cell's
+    shape and its two cotangents one sum. What follows the call runs in f32
+    under ``ps.attn/diff``. Returns [B, S, h * 2 d]."""
+    b, s, pairs, d = q.shape
+    heads, kv_heads = pairs // 2, k.shape[2] // 2
+
+    def firsts_then_seconds(t, n):
+        return jnp.moveaxis(t.reshape(b, s, n, 2, d), 3, 2).reshape(
+            b, s, 2 * n, d)
+
+    v = v.reshape(b, s, kv_heads, 2 * d)
+    with jax.named_scope(core):
+        a = attn_fn(firsts_then_seconds(q, heads),
+                    firsts_then_seconds(k, kv_heads),
+                    jnp.concatenate([v, v], axis=2), causal=True,
+                    window=window)
+    with jax.named_scope(phases.ATTN_DIFF):
+        a = a.astype(jnp.float32)
+        lam = jnp.exp(jnp.vdot(lp["lambda_q1"], lp["lambda_k1"])) \
+            - jnp.exp(jnp.vdot(lp["lambda_q2"], lp["lambda_k2"])) + lambda_init
+        a = rms_norm(a[:, :, :heads] - lam * a[:, :, heads:],
+                     lp["head_norm"]["scale"], eps) * (1.0 - lambda_init)
+    return a.reshape(b, s, -1).astype(q.dtype)
+
+
+def gmu_block(lp: Dict, x, memory):
+    """The Gated Memory Unit (SambaY, arXiv:2507.06607) of the normed
+    activations ``x`` [B, S, D] over ``memory`` [B, S, d_inner], another
+    layer's scan output: ``(memory * silu(x W_in)) W_out``; no scan, no
+    taps, no state of its own."""
+    gate = jax.nn.silu(x @ lp["in_proj"]["kernel"].astype(x.dtype))
+    return (memory.astype(x.dtype) * gate) \
+        @ lp["out_proj"]["kernel"].astype(x.dtype)
 
 
 def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,

@@ -7,7 +7,7 @@ the work is written (``kv/fused.py``, ``kv/sparse.py``,
 ``models/kimi_linear.py``, ``models/nemotron_h.py``, ``models/trinity.py``,
 ``models/mellum.py``, ``models/sdar.py``, ``models/joyai.py``,
 ``models/granite_h.py``, ``models/qwen3_next.py``, ``models/ouro.py``,
-``models/blocks.py``,
+``models/phi4flash.py``, ``models/blocks.py``,
 ``ops/moe.py``) and land in the
 ``op_name`` of every HLO instruction traced under them; the host spans are
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
@@ -182,6 +182,24 @@ LOOP = "ps.loop"                  # the passes: every layer application and the 
 EXIT = "ps.exit"                  # the passes' exit gates, the exit distribution, its entropy, the weighted sum
 
 OURO_SCOPES = (ATTN, HEAD, FFN, LOOP, EXIT)
+
+# -- scopes of Phi-4-mini-flash (models/phi4flash.py): no expert, so not the six ----------
+# A decoder whose second half reads its first half's memory. ATTN, HEAD, FFN,
+# MAMBA, MAMBA_CONV and the two cores ATTN_WINDOW and ATTN_FULL mean what they
+# mean above and are read by ``benchmark/layer_metrics/decoder.py``, which
+# keeps its own copy. The four below have no metric yet (``BENCHMARK.json``
+# holds its 128 ``per_layer`` entries; ``PERF.md`` section 7 names the
+# metrics that will read them) and are read from a traced run's stderr:
+# MAMBA_S6 nests under MAMBA and ATTN_CROSS and ATTN_DIFF under ATTN, so those
+# two metrics hold them; GMU is a mixer of its own and no scope of the
+# reader's is around it, so it is read with the gradient's rest.
+MAMBA_S6 = "ps.mamba/s6"          # ops/selective_scan.py alone: Mamba-1's scan, a decay a channel and a state
+GMU = "ps.gmu"                    # the gated memory unit: in projection, the gate by another layer's scan output, out projection
+ATTN_CROSS = "ps.attn/cross"      # the core of a layer that reads another layer's K and V
+ATTN_DIFF = "ps.attn/diff"        # differential attention's combine: lambda, the subtraction, the head norm, the scale (f32 elementwise)
+
+PHI4FLASH_SCOPES = (ATTN, HEAD, FFN, MAMBA, MAMBA_CONV, ATTN_WINDOW,
+                    ATTN_FULL, MAMBA_S6, GMU, ATTN_CROSS, ATTN_DIFF)
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n

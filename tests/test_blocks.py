@@ -37,9 +37,14 @@ REFERENCES = {"olmoe": (), "lfm2": (), "kimi": (), "nemotron_h": ("cumsum",),
                              "ragged_dot"),
               # Ouro's passes and layers are Python loops: no kernel, no
               # scan
-              "ouro": ("pallas", "lax.scan")}
+              "ouro": ("pallas", "lax.scan"),
+              # Phi-4-mini-flash's scan is token by token (no cumulated sum,
+              # no parallel scan), its attention an explicit mask
+              "phi4flash": ("pallas", "cumsum", "associative_scan",
+                            "cumprod")}
 MODELS = ("lm", "olmoe", "lfm2", "kimi_linear", "nemotron_h", "trinity",
-          "mellum", "sdar", "joyai", "granite_h", "qwen3_next", "ouro")
+          "mellum", "sdar", "joyai", "granite_h", "qwen3_next", "ouro",
+          "phi4flash")
 
 
 @pytest.mark.parametrize("name", sorted(REFERENCES))

@@ -812,3 +812,100 @@ def test_a_tables_distinct_pull_and_held_push_compile_at_the_cells_shape(
     with capsys.disabled():
         print(f"\nheld rows of the dim-{dim} table, as the v5e compiler "
               f"lays them out in the pull's loop: {held[0]}")
+
+
+# -- Phi-4-mini-flash: the selective scan and the step -------------------------
+
+def _no_state_a_token(text: str, seq: int, channels: int, state: int):
+    """No array a compiled program writes has a token axis of ``seq`` (whole
+    or as chunks x tokens) beside both the channels and the states: what
+    Mamba-1's scan would keep if it kept a state a token."""
+    full = seq * channels * state
+    for kind in _written(text):
+        for dims in re.findall(r"\[([0-9,]+)\]", kind):
+            size = 1
+            for n in dims.split(","):
+                size *= int(n)
+            assert size < full, kind
+
+
+def test_the_selective_scan_compiles_at_the_phi4flash_cells_shape(
+        one_chip, no_compile_cache):
+    """``ops/selective_scan.py`` at ``phi-4-mini-flash-reasoning.s16384.b1.
+    zipf``'s mixer, 5,120 channels on a state of 16 over 16,384 tokens,
+    forward and backward in plain XLA: two ``while`` loops over the chunks
+    (each with the unrolled tokens' loop inside), no Mosaic call yet, and no
+    array of [16384, 5120, 16] f32 entries (5.4e9 B) or anything near it: the
+    temporaries are the states that entered each chunk and one chunk's
+    products, under 0.6e9 B, where the arguments are 0.84e9."""
+    from ps_tpu.ops.selective_scan import CHUNK, selective_scan
+
+    seq, channels, state = 16384, 5120, 16
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (arg((1, seq, channels), jnp.bfloat16),
+            arg((1, seq, channels), jnp.float32),
+            arg((channels, state), jnp.float32),
+            arg((1, seq, state), jnp.bfloat16),
+            arg((1, seq, state), jnp.bfloat16), arg((channels,), jnp.float32),
+            arg((1, seq, channels), jnp.float32))
+
+    def gradient(x, dt, a, b, c, d, w):
+        return jax.grad(lambda *o: jnp.sum(w * selective_scan(*o)),
+                        argnums=range(6))(x, dt, a, b, c, d)
+
+    compiled = jax.jit(gradient).lower(*args).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert " while(" in text
+    _no_state_a_token(text, seq, channels, state)
+    entering = seq // CHUNK * state * channels * 4
+    assert entering <= compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_phi4flashs_step_compiles_at_the_cells_shape(one_chip,
+                                                     no_compile_cache):
+    """``phi-4-mini-flash-reasoning.s16384.b1.zipf``: value and gradient of
+    the loss ``KVStore.make_step`` differentiates, at the configuration's
+    published widths and [1, 16384] tokens. Nine Mosaic flash calls, three
+    for each differential layer (each layer's checkpoint keeps the forward
+    call's output and logsumexp, so none runs twice), the window layer's the
+    band's; no array with a state a token; no [16384, 25008] array of logits.
+    The program's arguments are the parameters once and its results their
+    gradients once (2.79e9 B each); the temporaries stay under 4.5e9 B, which
+    with the store's two moments (5.58e9) leaves the chip's 17.18e9 a margin
+    the run's peak confirms (``PERF.md`` section 5)."""
+    import json
+    import os
+
+    from ps_tpu.models import phi4flash
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        cfg = phi4flash.Phi4FlashConfig.from_dict(json.load(f))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: phi4flash.init_params(jax.random.key(0), cfg)))
+    ids = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    loss = phi4flash.make_loss_fn(cfg, attn="flash")
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        params, {"inputs": ids, "targets": ids}).compile()
+    text = compiled.as_text()
+    _, flash = _mosaic_calls(text)
+    assert len(flash) == 9
+    assert sum("ps.attn/window" in line for line in flash) == 3
+    assert sum("ps.attn/cross" in line for line in flash) == 3
+    assert sum("ps.attn/full" in line for line in flash) == 3
+    _no_state_a_token(text, 16384, 5120, 16)
+    assert "16384,25008]" not in text and "2048,25008]" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 2.80e9
+    assert memory.output_size_in_bytes < 2.80e9
+    assert memory.temp_size_in_bytes < 4.5e9

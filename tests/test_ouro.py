@@ -277,12 +277,13 @@ def test_the_cell_is_what_issue_63_named(listed_for):
     the cell is in the two that read any decoder's facts."""
     manifest = _json("BENCHMARK.json")
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
-    assert cell == manifest["workloads"][-1]
+    # the eighteenth cell on the fourteenth configuration; later PRs append
+    assert cell == manifest["workloads"][17]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "ouro-2.6b", "s8192.b1.zipf.n96", 1)
     assert [w["name"] for w in manifest["workloads"]
             if w["config"] == cell["config"]] == [CELL]
-    entry = manifest["configs"][-1]
+    entry = manifest["configs"][13]
     assert (entry["name"], entry["file"]) == ("ouro-2.6b", CONFIG)
     assert entry["source"] == ("https://huggingface.co/ByteDance/Ouro-2.6B/"
                                "blob/main/config.json")
@@ -292,9 +293,9 @@ def test_the_cell_is_what_issue_63_named(listed_for):
     assert {"throughput", "setup_s"} <= {m["moves"] for m in listed_for(CELL)}
     own = [m for m in listed_for(CELL) if "workloads" in m]
     assert {m["name"] for m in own} == {"step.mfu", "kernel.flash_roofline"}
-    assert all(m["workloads"][-1] == CELL for m in own)
+    assert all(CELL in m["workloads"] for m in own)
     assert len(manifest["per_layer"]) == 128
-    assert len(manifest["workloads"]) == 18 and len(manifest["configs"]) == 14
+    assert len(manifest["workloads"]) >= 18 and len(manifest["configs"]) >= 14
 
 
 def test_configuration_holds_the_published_widths():

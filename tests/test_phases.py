@@ -165,7 +165,12 @@ PROGRAMS_OWN = {phases.ATTN_INBLOCK, phases.ATTN_LATENT, phases.ATTN_ROPE,
                 phases.MTP, phases.MTP_JOIN, phases.MAMBA_GATE,
                 # the looped decoder's two, which ``layer_metrics/ouro.py``
                 # reads (they nest around and beside decoder.py's scopes)
-                phases.LOOP, phases.EXIT}
+                phases.LOOP, phases.EXIT,
+                # Phi-4-mini-flash's four (PR 65, the manifest at its 128):
+                # the scan inside decoder.mamba_ms, the cross core and the
+                # combine inside decoder.attn_ms, the gmu with the rest
+                phases.MAMBA_S6, phases.GMU, phases.ATTN_CROSS,
+                phases.ATTN_DIFF}
 
 
 def test_program_and_benchmark_share_their_names():
@@ -192,7 +197,7 @@ def test_program_and_benchmark_share_their_names():
         assert getattr(phases, name) == getattr(decoder, name), name
     families = [name for name in vars(phases) if name.endswith("_SCOPES")]
     opened = set().union(*(getattr(phases, name) for name in families))
-    assert len(families) == 11
+    assert len(families) == 12
     assert (ouro_metrics.LOOP, ouro_metrics.EXIT) == (phases.LOOP,
                                                       phases.EXIT)
     assert set(ouro_metrics.MARKS) == {phases.LOOP, phases.EXIT}
@@ -983,6 +988,39 @@ READER_CASES = {
         # S11 row 0: the one scope of this model without a name of its own
         "may": {"decoder.mamba_gate_ms": 2.0},
         "mfu": 5.0, "device_ms": 29.5},
+    "phi4flash": {   # dense; a second half that reads the first's memory
+        "events": [
+            ("%in", "fusion", 0.004, _CP + "ps.mamba/dot_general"),
+            ("%taps", "fusion", 0.002, _CP + "ps.mamba/ps.mamba/conv/mul"),
+            # the selective scan: no name of its own yet, inside ps.mamba
+            ("%s6", "fusion", 0.010,
+             _BACK + "checkpoint/ps.mamba/ps.mamba/s6/while/body/mul"),
+            ("%band", "custom-call", 0.002,
+             _CP + "ps.attn/ps.attn/window/pallas_call"),
+            ("%full", "custom-call", 0.008,
+             _CP + "ps.attn/ps.attn/full/pallas_call"),
+            # another layer's K and V: a flash call under ps.attn, not the
+            # window's, so with the full layer's in the roofline
+            ("%cross", "custom-call", 0.008,
+             _RULE + "ps.attn/ps.attn/cross/pallas_call"),
+            ("%diff", "fusion", 0.002, _CP + "ps.attn/ps.attn/diff/mul"),
+            ("%qkv", "fusion", 0.004, _BACK + "ps.attn/dot_general"),
+            # a mixer no scope of the reader's is around: the gradient's rest
+            ("%gmu", "fusion", 0.006, _CP + "ps.gmu/dot_general"),
+            ("%swiglu", "fusion", 0.012, _CP + "ps.ffn/dot_general"),
+            *_REST],
+        "facts": {**_TARGETS, "dense_flops_per_step": 5e9, "scan_flops": 1.0,
+                  "scan_bytes": 1e9, "flash_flops": 2e9, "flash_bytes": 1.0,
+                  "window_flash_flops": 0.25e9, "window_flash_bytes": 1.0},
+        "counters": {},
+        "want": {"decoder.mamba_ms": 8.0,      # with the taps and the scan
+                 "decoder.mamba_conv_ms": 1.0,
+                 "decoder.attn_ms": 12.0,      # three cores, combine, qkv
+                 "decoder.window_core_ms": 1.0, "decoder.full_core_ms": 4.0,
+                 "decoder.dense_ffn_ms": 6.0, "decoder.head_ms": 2.5,
+                 "kernel.flash_roofline": 25.0,          # 2 of 4 + 4 ms
+                 "kernel.window_flash_roofline": 25.0},  # 0.25 of 1 ms
+        "mfu": 5.0, "device_ms": 35.5},
     "ouro": {   # dense and looped: the passes a while loop, the head and the
         # exit beside it; a layer application under its checkpoint
         "events": [
@@ -1831,6 +1869,61 @@ def test_granite_scopes_reach_the_step_hlo_forward_and_backward(
     assert not [n for n in names.values() if "ps.moe" in n]
 
 
+def _phi4flash_step():
+    """``(run, batch)`` of ``make_step`` on a tiny Phi-4-mini-flash: layers
+    2 to 7 of a model of eight, one of every kind."""
+    from ps_tpu.models import phi4flash
+
+    cfg = phi4flash.Phi4FlashConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=6, first_layer=2,
+        model_layers=8, num_attention_heads=4, num_key_value_heads=2,
+        intermediate_size=48, sliding_window=16, mamba_d_state=4,
+        dtype=jnp.float32)
+    ps.init(backend="tpu")
+    store = ps.KVStore(optimizer="adamw", clip_by_global_norm=1.0)
+    store.init(jax.jit(lambda k: phi4flash.init_params(k, cfg))(
+        jax.random.key(0)))
+    ids = (np.arange(8 * 65, dtype=np.int32).reshape(8, 65) * 7) % 64
+    return (store.make_step(phi4flash.make_loss_fn(cfg)),
+            store.shard_batch({"inputs": ids[:, :-1], "targets": ids[:, 1:]}))
+
+
+def test_phi4flash_scopes_reach_the_step_hlo_forward_and_backward(
+        no_compile_cache, monkeypatch):
+    """What Phi-4-mini-flash opens (``PHI4FLASH_SCOPES``: no expert, so not
+    the six): ``ps.attn`` with the three cores and the combine, ``ps.head``,
+    ``ps.ffn`` in every layer, ``ps.mamba`` with the taps and the selective
+    scan, and ``ps.gmu``: each in the lowered step's ``op_name``s under
+    ``ps.grad``, forward and backward, though every layer is under a
+    ``jax.checkpoint`` that hands the memory and K, V on. The reader has no
+    name for four of them today: the scan counts inside ``decoder.mamba_ms``,
+    the cross core and the combine inside ``decoder.attn_ms``, the gmu with
+    the gradient's rest."""
+    assert phases.PHI4FLASH_SCOPES == (
+        phases.ATTN, phases.HEAD, phases.FFN, phases.MAMBA,
+        phases.MAMBA_CONV, phases.ATTN_WINDOW, phases.ATTN_FULL,
+        phases.MAMBA_S6, phases.GMU, phases.ATTN_CROSS, phases.ATTN_DIFF)
+    assert (phases.MAMBA_S6, phases.GMU, phases.ATTN_CROSS,
+            phases.ATTN_DIFF) == ("ps.mamba/s6", "ps.gmu", "ps.attn/cross",
+                                  "ps.attn/diff")
+    monkeypatch.setitem(BUILDERS, "phi4flash", _phi4flash_step)
+    names = scope.op_names_of(_step_hlo("phi4flash"))
+    for s in phases.PHI4FLASH_SCOPES:
+        under = [n for n in names.values() if s in n]
+        assert under and all(phases.GRAD in n for n in under), s
+        assert any(phases.BACKWARD_MARK in n for n in under), s
+        assert any(phases.BACKWARD_MARK not in n for n in under), s
+    found = {decoder.scope_of(own, n) for own, n in names.items()}
+    assert found == (set(phases.PHI4FLASH_SCOPES) - PROGRAMS_OWN) | {None}
+    for inner, outer in ((phases.MAMBA_S6, phases.MAMBA),
+                         (phases.ATTN_CROSS, phases.ATTN),
+                         (phases.ATTN_DIFF, phases.ATTN),
+                         (phases.GMU, None)):
+        assert {decoder.scope_of("%x", n) for n in names.values()
+                if inner in n} == {outer}, inner
+    assert not [n for n in names.values() if "ps.moe" in n]
+
+
 def _qwen3_next_step():
     """``(run, batch)`` of ``make_step`` on a tiny Qwen3-Next: a delta-rule
     layer and a gated attention layer, each with its experts."""
@@ -1987,6 +2080,10 @@ REHEARSED = {
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib"),
     "ouro-2.6b.s8192.b1.zipf": (
+        "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
+        "setup.import_s", "device.peak_hbm_gib", "step.mfu",
+        "kernel.flash_roofline"),
+    "phi-4-mini-flash-reasoning.s16384.b1.zipf": (
         "entry.compile_s", "input.wait_share", "loop.dispatch_ms",
         "setup.import_s", "device.peak_hbm_gib", "step.mfu",
         "kernel.flash_roofline")}

@@ -57,6 +57,21 @@ channels; to ``chiprun_out/ouro_grad_check.json``. ``--table`` is for its
 rehearsal: at the published widths the system's gradient tree and the
 reference's for every tensor do not fit the chip together, and the cell's own
 step-0 checks are where the system comes out ``correct: true``.
+
+``--model phi4flash`` runs them for
+``phi-4-mini-flash-reasoning.s16384.b1.zipf`` (a scalar loss; the Pallas flash
+kernel at 40 maps on 20 K/V heads, keys of 64 against values of 128, once
+under a window of 512; the selective scan in chunks) with the ten faults its
+limits are there for: ``lambda_init`` at the cut's own depth (1, 3, 5 for 15,
+17, 19), the head norm left out, ``1 - lambda_init`` left out, the memory
+taken after the gate, ``D x`` left out of the memory, the window read as
+full, the cross layer reading the window layer's K and V, an RMSNorm
+where a LayerNorm stands, and the lambda vectors' gradient dropped or of the
+wrong sign (the forward pass whole: ``phi4flash_step.LAMBDA_WITNESSES`` are
+there for these two); to ``chiprun_out/phi4flash_grad_check.json``.
+``--table`` is for its rehearsal, as Ouro's; there the last two may pass,
+the tiny sizes' scalars lying under ``LAMBDA_FLOOR``, which is what bf16
+reads off them at the published sizes.
 """
 
 from __future__ import annotations
@@ -169,6 +184,79 @@ def _half_rotation(reference):
     return {"rope": rope}
 
 
+def _cuts_own_depth(reference):
+    """``lambda_init`` at a layer's index in the cut (1, 3, 5), not in the
+    whole model (15, 17, 19). For ``phi4flash_reference.lambda_init``."""
+    whole = reference.lambda_init
+    return {"lambda_init": lambda depth: whole(depth - 14)}
+
+
+def _no_head_norm(reference):
+    """The two maps' difference goes on un-normed."""
+    return {"rms_norm": lambda x, scale, eps: x}
+
+
+def _no_one_minus_lambda(reference):
+    """``combine`` without its last factor."""
+    return {"combine": lambda a1, a2, lam, init, scale, eps:
+            reference.rms_norm(a1 - lam * a2, scale, eps)}
+
+
+def _lambda_gradient(times: float):
+    """``combine`` reading the same lambda with ``times`` its gradient: 0, the
+    four vectors' gradient dropped; -1, of the wrong sign. The forward pass
+    is the whole reference's."""
+    def fault(reference):
+        import jax
+
+        whole = reference.combine
+
+        def combine(a1, a2, lam, init, scale, eps):
+            kept = jax.lax.stop_gradient(lam)
+            return whole(a1, a2, kept + times * (lam - kept), init, scale,
+                         eps)
+
+        return {"combine": combine}
+
+    return fault
+
+
+def _memory(after_gate: bool):
+    """``mamba_mixer`` handing on the scan's output after the ``silu(z)``
+    gate, or before it and without the ``D x`` skip."""
+    def fault(reference):
+        import jax
+
+        whole = reference.mamba_mixer
+
+        def mamba_mixer(lp, u, config):
+            out, y = whole(lp, u, config)
+            inner = 2 * config["hidden_size"]
+            projected = u @ lp["in_proj"]["kernel"]
+            if after_gate:
+                return out, y * jax.nn.silu(projected[:, inner:])
+            x = reference.conv_silu(projected[:, :inner],
+                                    lp["conv"]["kernel"], lp["conv"]["bias"])
+            return out, y - lp["D"] * x
+
+        return {"mamba_mixer": mamba_mixer}
+
+    return fault
+
+
+def _rms_for_layer_norm(reference):
+    """Every LayerNorm without its mean: an RMSNorm with the same scale and
+    bias."""
+    import jax
+    import jax.numpy as jnp
+
+    def layer_norm(x, p, eps):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
+            * p["scale"] + p["bias"]
+
+    return {"layer_norm": layer_norm}
+
+
 #: a model's name in ``ps_tpu.models`` and ``benchmark.families`` -> its
 #: configuration class and files, whether its loss takes a selection bias and
 #: returns counts (``routed``) or returns an ``aux`` whose ``ce`` is the
@@ -202,7 +290,24 @@ MODELS = {
                    "post_norms_left_out": _no_post_norms,
                    "last_pass_takes_its_own_gates_share": _last_gate_read,
                    "entropy_term_added": {"exit_entropy_beta": -0.05},
-                   "rotation_over_half_the_channels": _half_rotation}}}
+                   "rotation_over_half_the_channels": _half_rotation}},
+    "phi4flash": {
+        "config_class": "Phi4FlashConfig",
+        "config": "phi-4-mini-flash-reasoning",
+        "traffic": "s16384.b1.zipf",
+        "routed": False,
+        "faults": {"lambda_init_at_the_cuts_own_depth": _cuts_own_depth,
+                   "head_norm_left_out": _no_head_norm,
+                   "one_minus_lambda_init_left_out": _no_one_minus_lambda,
+                   "memory_taken_after_the_gate": _memory(after_gate=True),
+                   "skip_left_out_of_the_memory": _memory(after_gate=False),
+                   "window_read_as_full": {"sliding_window": 1 << 30},
+                   "cross_reads_the_window_layers_kv": lambda reference: {
+                       "PRODUCERS": {**reference.PRODUCERS, "kv": "window"}},
+                   "rms_norm_for_layer_norm": _rms_for_layer_norm,
+                   "lambda_gradient_dropped": _lambda_gradient(0.0),
+                   "lambda_gradient_of_the_wrong_sign":
+                   _lambda_gradient(-1.0)}}}
 
 
 def main(argv=None) -> int:
@@ -247,7 +352,11 @@ def main(argv=None) -> int:
               "sizes on the CPU", file=sys.stderr)
         return 1
     cfg = getattr(model, spec["config_class"]).from_dict(config)
-    witnesses = tuple(family.GRAD_COSINE)
+    # the leaves whose cosines and lengths are read, and with them those a
+    # family hands to its checks for another reading (Phi-4-mini-flash's
+    # lambda vectors)
+    compared = tuple(family.GRAD_COSINE)
+    witnesses = tuple(getattr(family, "WITNESSES", compared))
     routed = spec["routed"]
     has_aux = routed or spec.get("aux", False)
     # what the loss takes beside the parameters and the batch
@@ -333,10 +442,12 @@ def main(argv=None) -> int:
                 "failed": sorted(k for k, ok in checks.items() if not ok),
                 # what the aux's checks read, beside their limits
                 **{k: v for k, v in result["detail"].items()
-                   if k.endswith(("_rel_diff", "_apart")) and aux}}
+                   if k.endswith(("_rel_diff", "_apart")) and aux},
+                **{k: v for k, v in result["detail"].items()
+                   if k.startswith("lambda_scalar.")}}
 
     def lengths(grads, whole):
-        ratios = {k: norm(grads[k]) / norm(whole[k]) for k in witnesses}
+        ratios = {k: norm(grads[k]) / norm(whole[k]) for k in compared}
         return {**{f"grad_norm_ratio.{k}": v for k, v in ratios.items()},
                 "lengths_apart": lengths_apart(list(ratios.values()))}
 
@@ -394,7 +505,7 @@ def main(argv=None) -> int:
             "loss_rel_diff": rel(value, ref_loss),
             "pairs_on_another_expert": moved(v_aux, ref_aux),
             **{f"grad_cosine.{k}": cosine(v_grads[k], whole[k])
-               for k in witnesses},
+               for k in compared},
             **lengths(v_grads, whole),
             **verdict(value, v_grads, ref_loss, whole, v_aux, ref_aux)}
         for name, (swapped, run) in faulty.items():
@@ -404,7 +515,7 @@ def main(argv=None) -> int:
             one[f"reference_with_{name}"] = {
                 "loss_rel_diff": rel(f_loss, ref_loss),
                 "least_grad_cosine": min(cosine(f_grads[k], whole[k])
-                                         for k in witnesses),
+                                         for k in compared),
                 **lengths(f_grads, whole),
                 **verdict(f_loss, f_grads, ref_loss, whole, f_aux, ref_aux)}
         out["seeds"].append(one)
