@@ -26,7 +26,10 @@ which this module is held to. How they are computed here:
   conv_silu``; ``[delta | B | C] = x W_x``; ``dt = softplus(delta W_dt +
   b_dt)`` in f32; the scan is ``ops/selective_scan.py`` (a decay for every
   channel and state: ``A`` is [5120, 16], which ``ops/ssd.py``'s scalar-decay
-  form cannot express), f32 inside, in chunks with one state kept a chunk.
+  form cannot express), f32 inside: at the published width two Mosaic calls,
+  forward and backward, with a block of channels' state in VMEM and one state
+  kept a tile of tokens (``selective_scan.path``), at the tests' and the
+  rehearsal's narrow ones XLA's loops over chunks.
   Its output with the ``D x`` skip, **before** the ``silu(z)`` gate, is what
   a ``mamba_memory`` layer hands on as ``M``, in the compute dtype;
 - differential attention (``blocks.diff_attention_block``): two softmax maps

@@ -829,18 +829,11 @@ def _no_state_a_token(text: str, seq: int, channels: int, state: int):
             assert size < full, kind
 
 
-def test_the_selective_scan_compiles_at_the_phi4flash_cells_shape(
-        one_chip, no_compile_cache):
-    """``ops/selective_scan.py`` at ``phi-4-mini-flash-reasoning.s16384.b1.
-    zipf``'s mixer, 5,120 channels on a state of 16 over 16,384 tokens,
-    forward and backward in plain XLA: two ``while`` loops over the chunks
-    (each with the unrolled tokens' loop inside), no Mosaic call yet, and no
-    array of [16384, 5120, 16] f32 entries (5.4e9 B) or anything near it: the
-    temporaries are the states that entered each chunk and one chunk's
-    products, under 0.6e9 B, where the arguments are 0.84e9."""
-    from ps_tpu.ops.selective_scan import CHUNK, selective_scan
-
-    seq, channels, state = 16384, 5120, 16
+def _selective_gradient(one_chip, channels, state, seq=16384):
+    """The gradient of ``ops/selective_scan.py::selective_scan`` in all six
+    operands, compiled for the described chip at ``x`` bf16[1, seq,
+    channels] on ``state`` states."""
+    from ps_tpu.ops.selective_scan import selective_scan
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -856,7 +849,55 @@ def test_the_selective_scan_compiles_at_the_phi4flash_cells_shape(
         return jax.grad(lambda *o: jnp.sum(w * selective_scan(*o)),
                         argnums=range(6))(x, dt, a, b, c, d)
 
-    compiled = jax.jit(gradient).lower(*args).compile()
+    return jax.jit(gradient).lower(*args).compile()
+
+
+def test_the_selective_scan_compiles_at_the_phi4flash_cells_shape(
+        one_chip, no_compile_cache):
+    """``ops/selective_scan.py`` at ``phi-4-mini-flash-reasoning.s16384.b1.
+    zipf``'s mixer, 5,120 channels on a state of 16 over 16,384 tokens,
+    forward and backward: the two Mosaic calls of ``ops/
+    selective_scan_mosaic.py`` (``path`` says ``"kernel"``), no ``while``
+    over tokens outside them, and no array of [16384, 5120, 16] f32 entries
+    (5.4e9 B) or anything near it: what lives between the calls is the state
+    that entered each tile, and the temporaries (that, ``B`` and ``C`` with
+    the token last, ``dA`` a tile before its sum) stay under 0.6e9 B, where
+    the arguments are 0.84e9."""
+    from ps_tpu.ops.selective_scan import path
+    from ps_tpu.ops.selective_scan_mosaic import TILE
+
+    seq, channels, state = 16384, 5120, 16
+    assert path(jax.ShapeDtypeStruct((1, seq, channels), jnp.bfloat16),
+                jax.ShapeDtypeStruct((channels, state), jnp.float32)) \
+        == "kernel"
+    compiled = _selective_gradient(one_chip, channels, state)
+    text = compiled.as_text()
+    _, calls = _mosaic_calls(text)
+    assert ["s6_forward" in line for line in calls] == [True, False]
+    assert "s6_backward" in calls[1]
+    assert " while(" not in text
+    _no_state_a_token(text, seq, channels, state)
+    # one state a tile between the calls (21 MB: the compiler may hold it in
+    # the chip's fast memory, ``S(1)``, where it is no temporary of the HBM's)
+    assert f"f32[{seq // TILE},1,{state},{channels}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_the_selective_scans_xla_form_compiles_where_the_kernels_do_not(
+        one_chip, no_compile_cache):
+    """The same shape on 12 states, which are no whole registers of eight
+    sublanes: ``path`` says ``"xla"`` and the compiled gradient is what PR
+    65's was,
+    two ``while`` loops over the chunks (each with the unrolled tokens' loop
+    inside), no Mosaic call, the states that entered each chunk and one
+    chunk's products kept and no state a token."""
+    from ps_tpu.ops.selective_scan import CHUNK, path
+
+    seq, channels, state = 16384, 5120, 12
+    assert path(jax.ShapeDtypeStruct((1, seq, channels), jnp.bfloat16),
+                jax.ShapeDtypeStruct((channels, state), jnp.float32)) \
+        == "xla"
+    compiled = _selective_gradient(one_chip, channels, state)
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' not in text
     assert " while(" in text
@@ -872,7 +913,10 @@ def test_phi4flashs_step_compiles_at_the_cells_shape(one_chip,
     published widths and [1, 16384] tokens. Nine Mosaic flash calls, three
     for each differential layer (each layer's checkpoint keeps the forward
     call's output and logsumexp, so none runs twice), the window layer's the
-    band's; no array with a state a token; no [16384, 25008] array of logits.
+    band's; six calls of the selective scan's kernels under ``ps.mamba/s6``,
+    three for each Mamba-1 layer (forward, forward again in the layer's
+    recomputation, backward); no array with a state a token; no
+    [16384, 25008] array of logits.
     The program's arguments are the parameters once and its results their
     gradients once (2.79e9 B each); the temporaries stay under 4.5e9 B, which
     with the store's two moments (5.58e9) leaves the chip's 17.18e9 a margin
@@ -898,7 +942,11 @@ def test_phi4flashs_step_compiles_at_the_cells_shape(one_chip,
     compiled = jax.jit(jax.value_and_grad(loss)).lower(
         params, {"inputs": ids, "targets": ids}).compile()
     text = compiled.as_text()
-    _, flash = _mosaic_calls(text)
+    _, calls = _mosaic_calls(text)
+    scan = [line for line in calls if "ps.mamba/s6" in line]
+    assert len(scan) == 6
+    assert sum("s6_backward" in line for line in scan) == 2
+    flash = [line for line in calls if line not in scan]
     assert len(flash) == 9
     assert sum("ps.attn/window" in line for line in flash) == 3
     assert sum("ps.attn/cross" in line for line in flash) == 3

@@ -1,8 +1,10 @@
-"""Mamba-1's selective scan (``ps_tpu/ops/selective_scan.py``) against the
-recurrence written out token by token, at small sizes on the CPU: values and
-all six gradients, at lengths the chunk divides and does not, at several
-chunk lengths and unrolls; and what the chunked form keeps for its backward
-pass.
+"""Mamba-1's selective scan (``ps_tpu/ops/selective_scan.py``), its XLA form,
+against the recurrence written out token by token, at small sizes on the CPU:
+values and all six gradients, at lengths the chunk divides and does not, at
+several chunk lengths and unrolls; and what the chunked form keeps for its
+backward pass. Every shape here (24 channels on 4 states) is one the Mosaic
+kernels refuse (``path``), so ``selective_scan`` is the XLA form;
+``tests/test_selective_scan_mosaic.py`` has the kernels.
 
 Tolerances. Both sides compute in f32 and differ in nothing but where a
 chunk's boundary puts a ``jax.checkpoint``: values agree to a few f32
@@ -16,7 +18,7 @@ import numpy as np
 import pytest
 
 from jaxpr_tools import primitives
-from ps_tpu.ops.selective_scan import CHUNK, UNROLL, selective_scan
+from ps_tpu.ops.selective_scan import CHUNK, UNROLL, path, selective_scan
 
 TOL = 2e-5
 
@@ -104,11 +106,12 @@ def test_no_decay_is_too_weak_or_too_strong_for_a_chunk(decay):
 
 
 def test_one_state_a_chunk_lives_between_the_passes():
-    """What the gradient's trace keeps from the forward pass beside the
-    operands: the state that entered each chunk, [chunks, B, N, C], and no
-    array with a token axis and a state axis at once."""
+    """What the XLA form's gradient's trace keeps from the forward pass
+    beside the operands: the state that entered each chunk, [chunks, B, N,
+    C], and no array with a token axis and a state axis at once."""
     seq, chunk = 64, 16
     *operands, weights = _inputs(seq)
+    assert path(operands[0], operands[2]) == "xla"
     batch, _, channels = operands[0].shape
     state = operands[2].shape[1]
     jaxpr = jax.make_jaxpr(jax.grad(

@@ -14,7 +14,11 @@ does, runs the loop with a traced segment and no window to speak of, and
 walks the trace once with ``benchmark/harness/tracered.py`` and
 ``benchmark/layer_metrics/scope.py::loaded_op_names`` (an event's
 instruction name in the optimized HLO of the loaded executables gives its
-``op_name``). The **innermost** listed scope takes an event's time
+``op_name``). A scope's Mosaic calls are listed by instruction with their
+time a step (``mosaic_calls``: six under ``ps.mamba/s6``, the two Mamba-1
+layers' forward, recomputed forward and backward; what the scope holds
+beyond them is XLA's around the calls). The **innermost** listed scope takes
+an event's time
 (``layer_metrics/decoder.py::scope_of`` over the listed names); an event
 under none of them is counted under ``(none listed)``. The tool goes with the
 ``benchmark`` PR that lists these scopes' metrics (``PERF.md`` section 7, row
@@ -96,15 +100,22 @@ def main(argv=None) -> int:
     else:
         devices, steps = r["trace"]["devices"], r["traced_steps"]
         per_ms = 1e3 / steps / len(devices)
-        seconds = {}
+        seconds, kernels = {}, {}
         for d in devices.values():
             for name, sec in d["ops"].items():
                 own = tracered.parts(name)["own"]
                 found = decoder.scope_of(own, op_names.get(own) or "", scopes)
                 key = found or "(none listed)"
                 seconds[key] = seconds.get(key, 0.0) + sec
+                if found and tracered.is_custom_call_to(
+                        name, ("tpu_custom_call",)):
+                    kernels.setdefault(found, {})[own] = per_ms * sec
         out["traced_steps"] = steps
         out["scopes"] = {s: per_ms * sec for s, sec in sorted(seconds.items())}
+        # a scope's Mosaic calls, each instruction of the step once: their
+        # count says a kernel engaged, the rest of the scope is XLA's around
+        out["mosaic_calls"] = {s: dict(sorted(calls.items()))
+                               for s, calls in sorted(kernels.items())}
         facts = r["facts"]
         under = out["scopes"].get("ps.mamba/s6")
         if under and "scan_bytes" in facts:
