@@ -14,8 +14,9 @@
    size). The kernel's seconds are the twelve Mosaic calls' and nothing
    else's; a matcher over the whole line reads 1.9x as much.
 3. A hand-made two-device trace with collectives alone, in a loop's body
-   and as a fusion, a nested ``while``, custom calls and host spans: every
-   number worked out by hand below.
+   and as a fusion, a nested ``while``, custom calls and host spans, the
+   program's nested inside the loop's: every number worked out by hand
+   below.
 """
 
 import os
@@ -151,7 +152,11 @@ def check_handmade():
         },
         "modules": {},
         # gaps of device 0: 15-20 ms and 43-50 ms
+        # the loop's spans and, nested in its dispatch, the program's pair
+        # as harness/loop.py hands them over: the wrapper and its launch
         "host": [("loop.dispatch", 14 * ms, 4 * ms),
+                 ("step.run", 14.5 * ms, 3.4 * ms),
+                 ("step.launch", 16 * ms, 1.8 * ms),
                  ("loop.block", 40 * ms, 20 * ms),
                  ("input.next", 44 * ms, 1 * ms)],
     }
@@ -169,10 +174,18 @@ def check_handmade():
     assert close(ops["%while.1 = (f32[8]) while((f32[8]) %t), body=%b"],
                  0.007)                                    # 20 - 6 - 1 - 6
     assert close(ops["%dot.1 = f32[8] dot(f32[8] %a, f32[8] %b)"], 0.012)
-    # gap 15-20 ms: its middle, 17.5 ms, lies in loop.dispatch; gap
-    # 43-50 ms: its middle, 46.5 ms, lies in loop.block only
-    assert close(red["idle_gaps"]["loop.dispatch"], 0.005)
+    # gap 15-20 ms: its middle, 17.5 ms, lies in loop.dispatch, in step.run
+    # inside it and in step.launch inside that: the innermost, the one that
+    # started last, takes it; gap 43-50 ms: its middle, 46.5 ms, lies in
+    # loop.block only
+    assert close(red["idle_gaps"]["step.launch"], 0.005)
     assert close(red["idle_gaps"]["loop.block"], 0.007)
+    assert set(red["idle_gaps"]) == {"step.launch", "loop.block"}
+    # without the launch the rest of the wrapper takes it; without the
+    # program's spans the loop's own, as before PR 67
+    for left, to in ((2, "step.run"), (1, "loop.dispatch")):
+        fewer = {**trace, "host": trace["host"][:left] + trace["host"][3:]}
+        assert close(tracered.reduce(fewer)["idle_gaps"][to], 0.005), to
     assert close(tracered.op_seconds(red, lambda n: "dot(" in n),
                  0.006)                                    # mean of chips
     assert close(tracered.op_seconds(red, lambda n: tracered.is_custom_call_to(

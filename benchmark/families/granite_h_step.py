@@ -11,8 +11,8 @@ ids (``moe_step.fresh_batches``); the plain reference
 (``families/granite_h_reference.py``); the limits of the step-0 checks with
 their measured reasons; and the functions that give operations and bytes from
 shapes, whatever implements them (``dense_flops`` here,
-``nemotron_h_step.ssd_cost`` for the scan, ``kimi_step.flash_cost`` for the
-kernel's three calls). The warm-up is LFM2's rule
+``nemotron_h_step.ssd_cost`` for the scan, ``families/flash.py::cost`` for
+the kernel's three calls). The warm-up is LFM2's rule
 (``lfm2_step.learning_rate``) at this configuration's length.
 """
 
@@ -25,8 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from benchmark.families import flash
 from benchmark.families import granite_h_reference as reference
-from benchmark.families.kimi_step import flash_cost
 from benchmark.families.lfm2_step import learning_rate
 from benchmark.families.moe_step import (adamw_first_step, cosine,
                                          fresh_batches, zipf_entropy)
@@ -274,9 +274,11 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
         cfg.mamba_n_groups, cfg.mamba_d_state,
         min(cfg.mamba_chunk_size, seq), mamba, itemsize)
     if traffic["attn"] == "flash":
-        facts["flash_flops"], facts["flash_bytes"] = flash_cost(
-            per_chip, cfg.num_attention_heads, seq, cfg.head_dim,
-            cfg.head_dim, cfg.layer_types.count("attention"), itemsize)
+        # K and V counted a query head each, as Nemotron-H's
+        facts["flash_flops"], facts["flash_bytes"] = flash.cost(
+            per_chip, cfg.num_attention_heads, cfg.num_attention_heads, seq,
+            cfg.head_dim, cfg.head_dim, cfg.layer_types.count("attention"),
+            flash.seen_pairs(seq), itemsize=itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

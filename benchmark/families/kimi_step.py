@@ -154,7 +154,10 @@ def flash_cost(batch, heads, seq, qk_dim, v_dim, layers, itemsize=2):
     backward**, where every query head has K and V of its own (the latent
     attention; Nemotron-H's one K/V head is read as four here, as since PR
     39), keys ``qk_dim`` wide and values ``v_dim``, the causal mask counted
-    as half the square, without the diagonal's half."""
+    as half the square, without the diagonal's half. No cell's facts are made
+    here since PR 67 (they count ``flash.seen_pairs``, the diagonal in);
+    ``tests/test_joyai.py`` holds JoyAI's count against this one, so it goes
+    with that line (PERF.md section 7)."""
     return flash.cost(batch, heads, heads, seq, qk_dim, v_dim, layers,
                       seq * seq / 2, itemsize=itemsize)
 
@@ -400,10 +403,11 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
                       linear["head_dim"], KDA_CHUNK,
                       len(linear["kda_layers"]), itemsize)
     if traffic["attn"] == "flash":
-        facts["flash_flops"], facts["flash_bytes"] = flash_cost(
-            per_chip, cfg.num_attention_heads, seq,
+        facts["flash_flops"], facts["flash_bytes"] = flash.cost(
+            per_chip, cfg.num_attention_heads, cfg.num_attention_heads, seq,
             cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim,
-            len(linear["full_attn_layers"]), itemsize)
+            len(linear["full_attn_layers"]), flash.seen_pairs(seq),
+            itemsize=itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

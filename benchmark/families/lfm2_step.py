@@ -164,16 +164,6 @@ def conv_gate_bytes(config, tokens, itemsize=2):
     return float(layers * 11 * tokens * config["hidden_size"] * itemsize)
 
 
-def flash_forward_cost(batch, heads, kv_heads, seq, head_dim, layers,
-                       itemsize=2):
-    """``flash.cost`` of the causal forward calls alone, half the square:
-    what the cell's roofline counted while the backward was a scan in plain
-    XLA (before PR 33). No fact is made of it any more; tests/test_lfm2.py
-    still holds its numbers, and it goes with that case."""
-    return flash.cost(batch, heads, kv_heads, seq, head_dim, head_dim,
-                      layers, seq * seq / 2, None, itemsize)
-
-
 def learning_rate(optimizer, warmup_steps):
     """The configuration's rate as the store takes it, and AdamW's rule as
     step 0 applies it: with ``warmup_steps`` the rate climbs linearly to

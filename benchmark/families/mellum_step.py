@@ -12,9 +12,9 @@ The yardstick's own pieces live here and beside this file: the stream of Zipf
 ids (``moe_step.fresh_batches``); the plain reference
 (``families/mellum_reference.py``: all 64 experts in one place, no exchange);
 the limits of the step-0 checks with their measured reasons; and the functions
-that give operations and bytes from shapes (``flash_cost`` and ``seen_pairs``
-are Trinity's; ``pair_flops``, ``dense_flops``, ``step_flops``,
-``exchange_bytes``). The warm-up is LFM2's (``lfm2_step.learning_rate``).
+that give operations and bytes from shapes (``pair_flops``, ``dense_flops``,
+``step_flops``, ``exchange_bytes``; the flash kernels' are
+``families/flash.py``'s). The warm-up is LFM2's (``lfm2_step.learning_rate``).
 """
 
 from __future__ import annotations
@@ -27,12 +27,13 @@ import jax
 import numpy as np
 import optax
 
+from benchmark.families import flash
 from benchmark.families import mellum_reference as reference
+from benchmark.families.flash import seen_pairs
 from benchmark.families.lfm2_step import learning_rate
 from benchmark.families.moe_step import (adamw_first_step, cosine,
                                          fresh_batches, zipf_entropy)
 from benchmark.families.nemotron_h_step import lengths_apart
-from benchmark.families.trinity_step import flash_cost, seen_pairs
 from benchmark.harness import stats
 from benchmark.harness.loop import Cell, seed_key
 
@@ -55,13 +56,18 @@ WINDOWED, FULL = "sliding_attention", "full_attention"
 # the first session's form of the tool, which compared the readings itself
 # and read the stack witness whole; since the review it hands each control to
 # step0_checks below as if it were the system and prints the verdicts, the
-# stack sliced as chip 0 and chip 2 hold it. That form has run on the CPU at
-# the rehearsal sizes, where both controls come out not correct, and not yet
-# on the chip at the cell's: PERF.md section 7).
+# stack sliced as chip 0 and chip 2 hold it. That form ran on the chip at
+# the cell's size in PR 67, three seeds: the rows "e4m3, PR 67" below and
+# "no factor" again (loss 1.7e-5 to 1.0e-4, full q .983-.990, lengths apart
+# 0.337-0.349): both controls "not correct" at every seed).
 #
 #               loss     ce       balance  flips a layer  window k  full q   router   stack 0  stack 2  embed    lengths apart
 #  seen, worst  8.52e-5  8.49e-5  5.92e-4  1,348 (0.51%)  .99988    .99990   .99919   .99988   .99966   .99998   0.0112
-#  LIMIT        1.5e-4   1.5e-4   3e-3     3,145 (1.2%)   .995      .995     .99      .995     .995     .997     0.05
+#  14th seed    1.539e-4 1.543e-4 2.96e-4  1,799         (seed 1807744670, which the driver's check of PR 60 drew: my chip runs, PR 60;
+#                                                        on PR 67's tree, behind PR 64's rotation: 1.565e-4, 1.569e-4, 3.15e-4, 1,819)
+#  e4m3, PR 67  2.78e-4  2.79e-4  1.36e-3  7,226-20,437  .98731    .97929   .98790   .98552   .98832   .99462   0.0172
+#               (the mildest of three seeds each, through step0_checks on the four chips at the cell's size: 1807744670, 6700000121 / 122)
+#  LIMIT        2.5e-4   2.5e-4   3e-3     3,145 (1.2%)   .995      .995     .99      .995     .995     .997     0.05
 #  e4m3         5.67e-4  5.50e-4  1.82e-2  8,039-14,298   .98449    .98497   .97226   .98642 (whole)    .99349   0.0791
 #  no factor    3.28e-5  3.26e-5  1.99e-4  1,945 (last)   .99909    .98900   .99802   .99958 (whole)    .99972   0.3587
 #
@@ -73,18 +79,35 @@ WINDOWED, FULL = "sliding_attention", "full_attention"
 # the vocabulary), moves 3.3e-5 and would pass. The router's gradient is
 # whole here (every chip's tokens, all 64 experts): .99919 at worst where
 # the share cells, whose router gets one share's part of a sum, read .955.
-TOLERANCE = (1.5e-4,
+# The loss's and the cross entropy's limits were 1.5e-4 until PR 67: 1.8x
+# the largest of the first thirteen seeds. The fourteenth (1807744670) read
+# 1.539e-4 and 1.543e-4 on the accepted program, twice and to the digit, every
+# other check true, so any PR whose check drew it was refused whatever it
+# changed. Lower reading 1.569e-4 (the largest of fourteen seeds, on PR 67's
+# tree). The control, run through step0_checks on the chip at the cell's size
+# for the first time in PR 67, reads 2.78e-4, 3.71e-4 and 7.64e-4 in the loss
+# at three seeds (PR 46's one reading was 5.67e-4): its mildest is 1.8x the
+# lower reading, not three times, so **the loss has no upper reading of its
+# own**, and 2.5e-4 (1.6x the lower reading, 0.9x the control's mildest)
+# refuses the control at all three seeds with little room. What tells e4m3
+# apart with room are the pairs on another expert (7,226 in its best layer of
+# its best seed against the system's worst 1,819: 4.0x, limit 3,145) and the
+# six cosines (.974-.994 against limits of .99-.997): "not correct" at every
+# seed by the counts, the gradient, the terms and the loss.
+TOLERANCE = (2.5e-4,
              "bf16 compute with top-8 flips against an f32 reference whose "
-             "attention is an explicit mask under a softmax: 1.8x the "
-             "largest of 13 seeds (8.52e-5; the next 6.9e-5); the reference "
-             "on e4m3 weights moves 5.67e-4. Blunt (a full layer without its "
+             "attention is an explicit mask under a softmax: 1.6x the "
+             "largest of 14 seeds (1.565e-4 at seed 1807744670; the next "
+             "8.52e-5); the reference on e4m3 weights moves 2.78e-4 to "
+             "7.64e-4 at three seeds. Blunt (a full layer without its "
              "YaRN factor moves it 3.3e-5), so after_step0 holds the two "
              "terms, the counts, the exchange's rows, the gradient, the clip "
              "and the apply")
 #: each loss term against the reference's, relative: the balance term is a sum
-#: over 64 experts of shares that a flipped pair moves whole. Seen: 8.49e-5
-#: and 5.92e-4 at most; e4m3 5.50e-4 and 1.82e-2
-TERM_TOLERANCE = {"ce": 1.5e-4, "load_balance": 3e-3}
+#: over 64 experts of shares that a flipped pair moves whole. Seen: 1.569e-4
+#: (the fourteenth seed; 8.49e-5 over the thirteen before it) and 5.92e-4 at
+#: most; e4m3 2.79e-4 to 7.69e-4 and 1.36e-3 to 1.36e-2 (three seeds, PR 67)
+TERM_TOLERANCE = {"ce": 2.5e-4, "load_balance": 3e-3}
 #: token-expert pairs, of the global batch's T * top_k a layer, that may sit on
 #: another expert than the reference's (top-8 flips between bf16 and f32
 #: activations): half the sum over the 64 experts of |count - reference
@@ -455,8 +478,9 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
                 ("flash", FULL, None)):
             count = cfg.layer_types.count(kind)
             if count:
-                facts[f"{name}_flops"], facts[f"{name}_bytes"] = flash_cost(
-                    *shape, count, window, itemsize)
+                facts[f"{name}_flops"], facts[f"{name}_bytes"] = flash.cost(
+                    *shape, cfg.head_dim, count, seen_pairs(seq, window),
+                    itemsize=itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

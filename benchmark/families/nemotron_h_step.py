@@ -12,10 +12,9 @@ ids (``moe_step.fresh_batches``); the plain reference, the benchmark's own copy
 ``tests/nemotron_h_reference.py``); the limits of the step-0 checks with their
 measured reasons; and the functions that give operations and bytes from shapes
 of the share that is computed, whatever implements it (``ssd_cost``,
-``flash_cost``, ``dense_flops``, ``pair_flops``, ``step_flops``). The warm-up
-and the sign rule are LFM2's (``lfm2_step.learning_rate``,
-``lfm2_step.bias_by_sign_rule``), the flash kernel's count Kimi-Linear's
-(``kimi_step.flash_cost``: forward and both backward calls).
+``dense_flops``, ``pair_flops``, ``step_flops``; the flash kernel's are
+``families/flash.py``'s). The warm-up and the sign rule are LFM2's
+(``lfm2_step.learning_rate``, ``lfm2_step.bias_by_sign_rule``).
 """
 
 from __future__ import annotations
@@ -29,8 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from benchmark.families import flash
 from benchmark.families import nemotron_h_reference as reference
-from benchmark.families.kimi_step import flash_cost
 from benchmark.families.lfm2_step import bias_by_sign_rule, learning_rate
 from benchmark.families.moe_step import (adamw_first_step, cosine,
                                          fresh_batches, zipf_entropy)
@@ -379,9 +378,11 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
         cfg.ssm_state_size, min(cfg.chunk_size, seq), pattern.count("M"),
         itemsize)
     if traffic["attn"] == "flash":
-        facts["flash_flops"], facts["flash_bytes"] = flash_cost(
-            per_chip, cfg.num_attention_heads, seq, cfg.head_dim,
-            cfg.head_dim, pattern.count("*"), itemsize)
+        # K and V counted a query head each, as since PR 39
+        facts["flash_flops"], facts["flash_bytes"] = flash.cost(
+            per_chip, cfg.num_attention_heads, cfg.num_attention_heads, seq,
+            cfg.head_dim, cfg.head_dim, pattern.count("*"),
+            flash.seen_pairs(seq), itemsize=itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

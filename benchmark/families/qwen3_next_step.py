@@ -39,9 +39,11 @@ from benchmark.harness.loop import Cell, seed_key
 
 # -- the limits of the step-0 checks, with what was measured ------------------
 # The fused step computes in bf16 as the configuration states, with the
-# chunked delta rule (its state, decays and inverse in f32; the scalar decay
-# broadcast to the head's channels and the 16 key heads repeated to their 32
-# readers in front of the Mosaic kernels), the Pallas flash kernel at keys and
+# chunked delta rule (its state, decays and inverse in f32; since PR 61 the
+# scalar-decay Mosaic kernels read one decay a head and q and k at their 16
+# key heads: at the readings below the decay was broadcast to the head's
+# channels and the key heads repeated to their 32 readers in front of the
+# general kernels), the Pallas flash kernel at keys and
 # values of 256 and the grouped matmuls over the held experts; the reference
 # in f32 at "highest" with the rule token by token and none of the kernels.
 # All readings: my chip runs, PR 60, TPU v5 lite, published widths, 8,192
@@ -173,8 +175,10 @@ def gdn_core_cost(batch, seq, key_heads, value_heads, k_dim, v_dim, chunk,
     """Operations and HBM bytes of the **scalar-decay** gated delta rule in
     one step, forward and backward, from its shapes: the least work of the
     chunked form at one decay a head and a token and ``key_heads`` q / k
-    heads read by ``value_heads`` value heads, whatever computes it (today
-    ``ops/kda.py``'s general rule on broadcast operands, which moves more).
+    heads read by ``value_heads`` value heads, whatever computes it (since PR
+    61 ``ops/kda_mosaic.py``'s scalar-decay kernels on the operands' own
+    shapes; before it the general rule on broadcast operands, which moved
+    more).
     A chunk of C tokens, forward: ``K K^T`` and ``Q K^T`` are the causal
     halves of two C x C x K products **a key head** (C^2 K each: a scalar
     decay scales their entries and is no part of the product); a value head

@@ -67,13 +67,32 @@ from ps_tpu.models import sdar
 #: plain mean spread 1.1 / 0.83 / 0.63 / 0.62 / 0.29% (my chip runs, PR 50,
 #: seeds 5000000401-406). The loss itself and its two terms are held to the
 #: reference at step 0 (``LOSS_TOLERANCE``, ``BALANCE_TOLERANCE``).
-TOLERANCE = (1.8e-4,
+#: This first limit was 1.8e-4 until PR 67: 2.1x the largest of nineteen
+#: seeds (8.7e-5). The twentieth (825088846, which the driver's check of PR 63
+#: drew) read 2.249e-4 on the accepted program, three times and to the digit,
+#: every other check true (2.183e-4 on PR 67's tree, behind PR 64's
+#: rotation): the same long tail as the weighted loss's below (2.03e-4 at
+#: that seed). That reading lies over e4m3's best seed (2.2e-4),
+#: so **this number has no upper reading and tells no precision apart**; it is
+#: ``LOSS_TOLERANCE``, 1.33x the largest of twenty, and holds what a gross
+#: fault moves. What refuses the reference on e4m3 weights, at each of its six
+#: seeds (tools/sdar_grad_check.py, seeds 5000000111-116), are the pairs on
+#: another expert than the reference's (12,541 to 20,549 in the worst layer
+#: against the system's worst 1,589 and ``FLIP_SHARE``'s 5,242) and the q, k,
+#: stack and row cosines below. Again in PR 67, three more seeds (825088846,
+#: 6700000131 / 132): the control's plain mean read 1.51e-3, **7.8e-6** and
+#: 3.63e-4 where the system's read 2.183e-4, 4.4e-5 and 3.9e-5, and it was
+#: refused at each by the counts (9,352 / 12,527 / 25,103) and four to six of
+#: the six cosines.
+TOLERANCE = (3e-4,
              "the masked positions' plain cross entropy, bf16 compute with "
              "top-8 flips against an f32 reference whose attention is an "
              "explicit [2L, 2L] mask under a softmax: 6e-7 to 8.7e-5 at "
-             "nineteen seeds, the limit 2.1x the largest; the reference on "
-             "e4m3 weights moves 2.2e-4 to 1.78e-3 at six, the edge one "
-             "position off 3.7e-4 (tools/sdar_grad_check.py, seeds "
+             "nineteen seeds and 2.249e-4 at the twentieth (825088846), the "
+             "limit 1.33x the largest; the reference on e4m3 weights moves "
+             "2.2e-4 to 1.78e-3 at six, so this number refuses it at some "
+             "seeds only and the counts and cosines at all six; the edge "
+             "one position off 3.7e-4 (tools/sdar_grad_check.py, seeds "
              "5000000111-116). Blunt (0.02-normal weights give every token "
              "nearly the entropy of the vocabulary), so after_step0 holds "
              "the loss and its two terms, the counts, the gradient, the "

@@ -11,8 +11,8 @@ ids (``moe_step.fresh_batches``); the plain reference, the benchmark's own copy
 (``families/trinity_reference.py``, letter for letter the tests'
 ``tests/trinity_reference.py``); the limits of the step-0 checks with their
 measured reasons; and the functions that give operations and bytes from shapes
-(``seen_pairs``, ``flash_cost`` for a band and for a triangle,
-``live_step_share``, ``dense_flops``, ``pair_flops``, ``step_flops``). The
+(``dense_flops``, ``pair_flops``, ``step_flops``; the flash kernels' are
+``families/flash.py``'s, for a band and for a triangle). The
 warm-up and the sign rule are LFM2's (``lfm2_step.learning_rate``,
 ``lfm2_step.bias_by_sign_rule``), the step-0 checks Nemotron-H's with this
 family's limits.
@@ -144,19 +144,13 @@ def pair_flops(config):
     return 3 * 6.0 * config["hidden_size"] * config["moe_intermediate_size"]
 
 
-def flash_cost(batch, heads, kv_heads, seq, dim, layers, window=None,
-               itemsize=2):
-    """``flash.cost`` of the kernel's three calls, **forward and backward**,
-    over what the layers see: a band (``window``) or the triangle, keys and
-    values ``dim`` wide: nine matmuls over the pairs in all."""
-    return flash.cost(batch, heads, kv_heads, seq, dim, dim, layers,
-                      seen_pairs(seq, window), itemsize=itemsize)
-
-
 def live_step_share(seq, window, tiles):
     """Grid steps a windowed forward call computes over those the causal
     call at the same tiles would, by the definition: a tile is live when
-    some row of it sees some key of it."""
+    some row of it sees some key of it. No fact is made of it since PR 67:
+    a windowed call has no causal grid since PR 53 (its steps are shaped like
+    the band), so the share described no kernel; ``tests/test_trinity.py``
+    still holds its numbers, and it goes with that case."""
     block_q, block_k = tiles
     blocks = [(i, j) for i in range(seq // block_q)
               for j in range(seq // block_k)]
@@ -263,7 +257,6 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
     from ps_tpu.data.prefetch import device_prefetch
     from ps_tpu.models.trinity import (TrinityConfig, init_expert_bias,
                                        init_params, make_loss_fn)
-    from ps_tpu.ops.flash_attention import forward_tiles
     from ps_tpu.parallel.sharding import replicated
 
     if config["model"] != "trinity":
@@ -397,13 +390,9 @@ def build(config: dict, traffic: dict, chips: int, seed: int) -> Cell:
                 ("flash", FULL, None)):
             layers = cfg.layer_types.count(kind)
             if layers:
-                facts[f"{name}_flops"], facts[f"{name}_bytes"] = flash_cost(
-                    *shape, layers, window, itemsize)
-        if WINDOWED in cfg.layer_types and cfg.sliding_window < seq:
-            # the tiles are the kernel's own choice from the shapes
-            facts["window_live_step_share"] = live_step_share(
-                seq, cfg.sliding_window,
-                forward_tiles(seq, cfg.head_dim, itemsize, True))
+                facts[f"{name}_flops"], facts[f"{name}_bytes"] = flash.cost(
+                    *shape, cfg.head_dim, layers, seen_pairs(seq, window),
+                    itemsize=itemsize)
         facts["kernel_targets"] = config["kernel_targets"]
     stream = device_prefetch(batches, place=store.shard_batch)
     return Cell(samples_per_step_per_chip=per_chip, stream=stream, step=step,

@@ -14,6 +14,7 @@ import dataclasses
 import math
 import shutil
 import tempfile
+import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -111,6 +112,23 @@ def _blocks(cell, stream, k: int, spans: Spans, losses: list, compiles,
         pending = loss
 
 
+def program_spans(since: float, until: float) -> List[Tuple[str, float, float]]:
+    """``(name, start, seconds)`` on ``perf_counter`` of the program's own
+    host spans (the ring of ``ps_tpu.obs.tracer()`` that
+    ``layer_metrics/host.py`` reads) that started in ``[since, until)`` on
+    this thread: what the loop's ``loop.dispatch`` and ``input.next`` were
+    doing inside. The producer thread's spans run beside the loop and say
+    nothing of where it stood. None on a program without the ring."""
+    try:
+        from ps_tpu import obs
+        ring = obs.tracer().spans()
+    except (ImportError, AttributeError):
+        return []
+    here = threading.get_ident()
+    return [(s.name, s.t0, 1e-6 * s.dur_us) for s in ring
+            if since <= s.t0 < until and getattr(s, "_tid", here) == here]
+
+
 def _live_executables() -> list:
     import jax
 
@@ -195,6 +213,7 @@ def run(cell, traffic: dict, seconds: float, trace: bool, compiles,
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             options.host_tracer_level = 0
+            t_traced = time.perf_counter()
             jax.profiler.start_trace(tdir, profiler_options=options)
             try:
                 blocks = int(traffic["trace_blocks"])
@@ -209,10 +228,14 @@ def run(cell, traffic: dict, seconds: float, trace: bool, compiles,
             offset = tracered.host_clock_offset(
                 loaded, [(t, i - before) for t, i in syncs])
             if offset is not None:
+                # the loop's three spans of the traced blocks and, inside
+                # them, the program's: a gap goes to the innermost
+                own = [(name, t0, dur) for name, spans_ in tspans.each.items()
+                       for t0, dur in spans_]
                 loaded["host"] = [
                     (name, (t0 - offset) * 1e9, dur * 1e9)
-                    for name, spans_ in tspans.each.items()
-                    for t0, dur in spans_]
+                    for name, t0, dur in own + program_spans(
+                        t_traced, syncs[-1][0])]
             reduced = tracered.reduce(loaded)
         finally:
             shutil.rmtree(tdir, ignore_errors=True)

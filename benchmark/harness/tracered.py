@@ -166,7 +166,9 @@ def reduce(trace: dict, window_ns: Tuple[float, float] = None) -> dict:
     runs on that device meanwhile), ``ops`` (self time by name), ``gaps`` (idle
     intervals); and over devices ``window_s``, ``busy_s`` (mean),
     ``idle_share`` (worst device), ``idle_gaps`` (idle seconds of the worst
-    device by the host span that covered each gap's middle)."""
+    device by the innermost host span over each gap's middle: of the spans
+    that cover it the one that started last, the loop's own or, inside
+    them, the program's)."""
     per_device = {}
     starts, ends = [], []
     for dev, events in trace["devices"].items():
@@ -205,9 +207,10 @@ def reduce(trace: dict, window_ns: Tuple[float, float] = None) -> dict:
     by_span: Dict[str, float] = {}
     for a, b in per_device[worst]["gaps"]:
         mid = (a + b) / 2
-        # the innermost host span over the gap's middle
-        cover = [(e - s, n) for s, e, n in spans if s <= mid < e]
-        name = min(cover)[1] if cover else "(no span)"
+        # the innermost host span over the gap's middle: the one that
+        # started last, the shorter of two that started together
+        cover = [(-s, e - s, n) for s, e, n in spans if s <= mid < e]
+        name = min(cover)[2] if cover else "(no span)"
         by_span[name] = by_span.get(name, 0.0) + (b - a) * 1e-9
     busy_mean = sum(d["busy_s"] for d in per_device.values()) / len(per_device)
     return {

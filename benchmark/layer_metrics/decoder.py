@@ -12,7 +12,13 @@ loaded executables gives its ``op_name``. The innermost scope takes an
 event's time; a scope opened inside another scope of this table
 (``ps.conv/gate`` in ``ps.conv``, the two cores and the gate in ``ps.attn``,
 the taps and the rule in ``ps.kda``, the filter and the scan in ``ps.mamba``)
-counts in its own metric and in the outer one's. ``ps.moe/exchange`` is
+counts in its own metric and in the outer one's. The finer marks of
+``MARKS`` (the own blocks, the latent projections and the rotation inside
+``ps.attn``, the gate inside ``ps.mamba``, the prediction module around its
+own attention, experts and head pass, and its join) are read where they
+stand, as ``layer_metrics/ouro.py`` reads its two: every event whose
+``op_name`` carries the mark, which stays in its outer scope's metric too.
+``ps.moe/exchange`` is
 opened inside dispatch and combine, which are no scope of the exchange's
 name: ``decoder.dispatch_ms`` is those two **less** the exchange. The scopes
 nest under ``ps.grad``, so the times are parts of ``scope.forward_ms`` +
@@ -23,9 +29,10 @@ collectives, which are the worst chip's: every chip waits for the slowest.
 The shares, none of which can pass 100%: a kernel's ``*_roofline`` is the
 least time its operations and bytes allow, forward and backward, from shapes
 (``families/flash.py``, ``kimi_step.kda_core_cost``,
-``nemotron_h_step.ssd_cost``), over the time of the Mosaic calls under the
-attention's scope (the calls under ``ps.attn/window`` apart), or of
-everything under the rule's or the scan's; ``decoder.expert_mxu_share`` the
+``nemotron_h_step.ssd_cost``), over the time of the flash calls (the Mosaic
+calls under the attention's scope but the rotation's, ``ops/rope.py``'s,
+which are no part of the count; those under ``ps.attn/window`` apart), or
+of everything under the rule's or the scan's; ``decoder.expert_mxu_share`` the
 FLOPs of the pairs the step computed here (its own counter where a share is
 held) over the MXU's peak over ``decoder.expert_ms``;
 ``decoder.conv_gate_hbm_share`` the gated convolution's bytes over the HBM's
@@ -89,6 +96,21 @@ METRICS = {
     MAMBA: "decoder.mamba_ms", MAMBA_CONV: "decoder.mamba_conv_ms",
     MAMBA_SSD: "decoder.ssd_ms"}
 SCOPES = tuple(METRICS)
+#: the finer marks, as the program writes them -> their metrics. Not among
+#: the scopes above: ``scope_of`` leaves their events with the scope around
+#: them (``tests/test_phases.py`` holds that, and that ``METRICS`` has none of
+#: them), so each is read by the mark itself, anywhere in the ``op_name``:
+#: ``ps.mtp`` lies around other scopes, the others inside one.
+#: ``benchmark/check/check_decoder.py`` holds them equal to the program's
+MARKS = {"ps.attn/inblock": "decoder.inblock_ms",
+         "ps.attn/latent": "decoder.attn_latent_ms",
+         "ps.attn/rope": "decoder.attn_rope_ms",
+         "ps.mamba/gate": "decoder.mamba_gate_ms",
+         "ps.mtp": "decoder.mtp_ms", "ps.mtp/join": "decoder.mtp_join_ms"}
+#: how the own instruction name of the rotation's Mosaic calls starts
+#: (``ops/rope.py`` names its kernels ``rope`` and ``rope_transposed``): under
+#: ``ps.attn`` and no flash call
+ROTATION = "%rope"
 #: the kernels whose cost a family states as ``<key>_flops`` and
 #: ``<key>_bytes``: roofline -> (key, the time it is held against: a scope's
 #: metric, or the Mosaic calls of that kind under the attention's scope)
@@ -101,6 +123,10 @@ ROOFLINES = {"kernel.flash_roofline": ("flash", None),
 COUNTS = {"load_max_over_mean": "decoder.load_max_over_mean",
           "held_pair_share": "decoder.held_pair_share",
           "dropped_tokens": "decoder.dropped_tokens",
+          "masked_share": "decoder.masked_share",
+          # no family states it since PR 67 (the causal grid it was counted
+          # from went with PR 53); tests/test_phases.py's hand-made result
+          # does, and holds the name
           "window_live_step_share": "decoder.window_live_step_share"}
 
 
@@ -119,6 +145,13 @@ def scope_of(own: str, op_name: str, scopes=SCOPES):
     at = {s: op_name.rfind(s) for s in scopes}
     found = max(scopes, key=lambda s: (at[s], len(s)))
     return found if at[found] >= 0 else None
+
+
+def mark_seconds(trace: dict, op_names: dict, mark: str) -> float:
+    """Seconds in a reduced trace, mean of the chips, of every event whose
+    ``op_name`` carries ``mark``, wherever in the name stack."""
+    return tracered.op_seconds(trace, lambda name: mark in (
+        op_names.get(tracered.parts(name)["own"]) or ""))
 
 
 def is_row_exchange(name: str, buffer_rows) -> bool:
@@ -166,7 +199,7 @@ def scope_times(r: dict, op_names: dict, scope_of=scope_of) -> dict:
     trace, steps = r["trace"], r["traced_steps"]
     devices = trace["devices"]
     per_ms = 1e3 / steps / len(devices)   # seconds over chips -> ms a step
-    by_scope = {}
+    by_scope, by_mark = {}, {}
     flash_s = {"flash": 0.0, "window_flash": 0.0}
     exchange_s, exposed_s, store_s, exchanges = [], [], [], []  # a chip each
     grad_s = 0.0
@@ -184,6 +217,9 @@ def scope_times(r: dict, op_names: dict, scope_of=scope_of) -> dict:
             collective = tracered.is_collective(name)
             if found is not None or scope.GRAD in op_name:
                 grad_s += sec
+            for mark in MARKS:
+                if mark in op_name:
+                    by_mark[mark] = by_mark.get(mark, 0.0) + sec
             if found == MOE_EXCHANGE:
                 exchange_s[-1] += sec
                 if collective:
@@ -200,8 +236,8 @@ def scope_times(r: dict, op_names: dict, scope_of=scope_of) -> dict:
             outer = outer_of(found)
             if outer:
                 by_scope[outer] = by_scope.get(outer, 0.0) + sec
-            if ATTN in (found, outer) and tracered.is_custom_call_to(
-                    name, targets):
+            if (ATTN in (found, outer) and not own.startswith(ROTATION)
+                    and tracered.is_custom_call_to(name, targets)):
                 flash_s["window_flash" if found == ATTN_WINDOW
                         else "flash"] += sec
     if not any(by_scope.values()):
@@ -209,6 +245,7 @@ def scope_times(r: dict, op_names: dict, scope_of=scope_of) -> dict:
     out = {}
     for s, sec in by_scope.items():
         out[METRICS[s]] = out.get(METRICS[s], 0.0) + per_ms * sec
+    out.update({MARKS[mark]: per_ms * sec for mark, sec in by_mark.items()})
     if MOE_EXCHANGE in by_scope:
         out["decoder.exchange_ms"] = 1e3 * max(exchange_s) / steps
         out["decoder.exchange_exposed_ms"] = 1e3 * max(exposed_s) / steps
@@ -275,6 +312,8 @@ def rehearsed(facts: dict, op_names: dict, scope_of=scope_of) -> dict:
     found = {scope_of(own, op_name) for own, op_name in op_names.items()}
     found.discard(None)
     out = {METRICS[s]: 0.0 for s in found}
+    out.update({metric: 0.0 for mark, metric in MARKS.items()
+                if any(mark in op_name for op_name in op_names.values())})
     if "decoder.expert_ms" in out and "flops_per_pair" in facts:
         out["decoder.expert_mxu_share"] = 0.0
     if "decoder.conv_gate_ms" in out and "conv_gate_bytes_per_step" in facts:

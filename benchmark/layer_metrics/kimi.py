@@ -17,9 +17,8 @@ LISTED = (
     "decoder.shared_ffn_ms", "decoder.attn_ms", "decoder.head_ms",
     "decoder.dense_ffn_ms", "decoder.kda_ms", "decoder.kda_conv_ms",
     "decoder.kda_core_ms", "kernel.kda_core_roofline",
-    "kernel.flash_roofline", "decoder.expert_mxu_share", "step.mfu",
-    "decoder.held_pair_share", "decoder.load_max_over_mean",
-    "decoder.dropped_tokens")
+    "decoder.expert_mxu_share", "decoder.held_pair_share",
+    "decoder.load_max_over_mean", "decoder.dropped_tokens")
 #: those it had under another name than its prefix gives
 RENAMED = {"decoder.attn_ms": "kimi.mla_ms"}
 SCOPE_METRICS, scope_of, scope_times, read = twin.make(

@@ -17,8 +17,8 @@ LISTED = (
     "decoder.attn_ms", "decoder.head_ms", "decoder.conv_ms",
     "decoder.conv_gate_ms", "decoder.dense_ffn_ms",
     "decoder.conv_gate_hbm_share", "decoder.expert_mxu_share",
-    "kernel.flash_roofline", "step.mfu", "decoder.held_pair_share",
-    "decoder.load_max_over_mean", "decoder.dropped_tokens")
+    "decoder.held_pair_share", "decoder.load_max_over_mean",
+    "decoder.dropped_tokens")
 #: those it had under another name than its prefix gives
 RENAMED = {}
 SCOPE_METRICS, scope_of, scope_times, read = twin.make(

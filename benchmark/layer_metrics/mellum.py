@@ -17,9 +17,9 @@ LISTED = (
     "decoder.dispatch_ms", "decoder.expert_ms", "decoder.exchange_ms",
     "decoder.exchange_exposed_ms", "decoder.exchange_ici_share",
     "decoder.store_collective_ms", "kernel.window_flash_roofline",
-    "kernel.flash_roofline", "step.mfu", "decoder.dropped_tokens")
+    "decoder.dropped_tokens")
 #: those it had under another name than its prefix gives
-RENAMED = {"kernel.flash_roofline": "mellum.full_flash_roofline"}
+RENAMED = {}
 SCOPE_METRICS, scope_of, scope_times, read = twin.make(
     "mellum", MELLUM_SCOPES, LISTED, RENAMED)
 _INNERMOST_FIRST = MELLUM_SCOPES   # tests/test_phases.py reads the set

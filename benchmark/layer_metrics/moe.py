@@ -13,8 +13,7 @@ MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERT, MOE_COMBINE, ATTN, HEAD)
 LISTED = (
     "decoder.route_ms", "decoder.dispatch_ms", "decoder.expert_ms",
     "decoder.attn_ms", "decoder.head_ms", "decoder.expert_mxu_share",
-    "kernel.flash_roofline", "step.mfu", "decoder.load_max_over_mean",
-    "decoder.dropped_tokens")
+    "decoder.load_max_over_mean", "decoder.dropped_tokens")
 #: those it had under another name than its prefix gives
 RENAMED = {}
 SCOPE_METRICS, scope_of, scope_times, read = twin.make(

@@ -17,9 +17,9 @@ LISTED = (
     "decoder.route_ms", "decoder.dispatch_ms", "decoder.expert_ms",
     "decoder.shared_ffn_ms", "decoder.latent_ms", "decoder.attn_ms",
     "decoder.head_ms", "decoder.mamba_ms", "decoder.mamba_conv_ms",
-    "decoder.ssd_ms", "kernel.ssd_roofline", "kernel.flash_roofline",
-    "decoder.expert_mxu_share", "step.mfu", "decoder.held_pair_share",
-    "decoder.load_max_over_mean", "decoder.dropped_tokens")
+    "decoder.ssd_ms", "kernel.ssd_roofline", "decoder.expert_mxu_share",
+    "decoder.held_pair_share", "decoder.load_max_over_mean",
+    "decoder.dropped_tokens")
 #: those it had under another name than its prefix gives
 RENAMED = {}
 SCOPE_METRICS, scope_of, scope_times, read = twin.make(

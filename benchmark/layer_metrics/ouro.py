@@ -28,7 +28,6 @@ to read, and the metrics are left out.
 
 from __future__ import annotations
 
-from benchmark.harness import tracered
 from benchmark.layer_metrics import decoder, scope
 
 LOOP = "ps.loop"
@@ -61,7 +60,6 @@ def read(r: dict) -> dict:
         if not r.get("peaks"):   # --rehearse: the name, no value
             out[metric] = 0.0
         elif trace and steps:
-            out[metric] = 1e3 / steps * tracered.op_seconds(
-                trace, lambda name: mark in (
-                    op_names.get(tracered.parts(name)["own"]) or ""))
+            out[metric] = 1e3 / steps * decoder.mark_seconds(
+                trace, op_names, mark)
     return out

@@ -16,13 +16,11 @@ TRINITY_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERT, MOE_COMBINE, ATTN,
 LISTED = (
     "decoder.route_ms", "decoder.dispatch_ms", "decoder.expert_ms",
     "decoder.shared_ffn_ms", "decoder.attn_ms", "decoder.head_ms",
-    "decoder.dense_ffn_ms", "decoder.window_core_ms",
-    "decoder.full_core_ms", "decoder.attn_gate_ms",
-    "kernel.window_flash_roofline", "kernel.flash_roofline",
-    "decoder.expert_mxu_share", "step.mfu", "decoder.held_pair_share",
-    "decoder.load_max_over_mean", "decoder.dropped_tokens",
-    "decoder.window_live_step_share")
+    "decoder.dense_ffn_ms", "decoder.window_core_ms", "decoder.full_core_ms",
+    "decoder.attn_gate_ms", "kernel.window_flash_roofline",
+    "decoder.expert_mxu_share", "decoder.held_pair_share",
+    "decoder.load_max_over_mean", "decoder.dropped_tokens")
 #: those it had under another name than its prefix gives
-RENAMED = {"kernel.flash_roofline": "trinity.full_flash_roofline"}
+RENAMED = {}
 SCOPE_METRICS, scope_of, scope_times, read = twin.make(
     "trinity", TRINITY_SCOPES, LISTED, RENAMED)

@@ -1,17 +1,24 @@
 """The names ``layer_metrics/decoder.py``'s metrics had while each model's
 cell brought a reader of its own: ``moe.*``, ``lfm2.*``, ``kimi.*``,
-``nemo.*``, ``trinity.*``, ``mellum.*``. ``BENCHMARK.json`` still lists
-those 87, and ``tests/`` (which a PR that changes the benchmark may not
-touch) hold each list, each module's names and each hand-made result with its
-numbers. So the six modules stay as views: their old keys read into the one
-set, the one reader's result given under their old names, nothing computed
-here. They go, with this file, when the manifest lists ``decoder.*`` in
-their place (PERF.md section 7).
+``nemo.*``, ``trinity.*``, ``mellum.*``. ``BENCHMARK.json`` still lists 74
+of the 87: in PR 67 the six ``<p>.mfu`` became ``step.mfu`` and the six
+``<p>.flash_roofline`` / ``<p>.full_flash_roofline`` ``kernel.flash_roofline``
+(names the manifest had for the newer cells already), and
+``trinity.window_live_step_share``, a constant of a grid the windowed call
+lost in PR 53, left. The six modules are views: their
+old keys read into the one set, the one reader's result given under their old
+names, nothing computed here. They go, with this file, when the manifest
+lists ``decoder.*`` in their place: since PR 52 no test holds the older names
+or imports a view, but ``tests/test_granite_h.py``, ``test_qwen3_next.py``,
+``test_ouro.py`` and ``test_phi4flash.py`` hold the manifest at 128 names
+and three cells' lists at two names, and ``tests/test_joyai.py`` its cell in
+none, so the rest of the swap (128 -> 85) waits for a PR that may edit them
+(PERF.md section 7, row 0).
 """
 
 from __future__ import annotations
 
-from benchmark.layer_metrics import decoder, step
+from benchmark.layer_metrics import decoder
 
 #: the custom calls XLA:TPU made of ``jax.lax.ragged_dot`` until PR 47, by
 #: the start of their own instruction name: they carried no scope. No cell's
@@ -63,11 +70,7 @@ def make(prefix: str, scopes: tuple, listed: tuple, renamed=()):
         return older(decoder.scope_times(current(r), op_names, scope_of))
 
     def read(r: dict) -> dict:
-        r = current(r)
-        out = decoder.read(r, scope_of)
-        if decoder.chip_flops(r["facts"], r["counters"]):
-            out.update(step.read({**r, "trace": None}))   # step.mfu alone
-        return older(out)
+        return older(decoder.read(current(r), scope_of))
 
     scope_metrics = {s: names[decoder.METRICS[s]] for s in scopes
                      if decoder.METRICS[s] in names}

@@ -13,7 +13,9 @@ The last line of standard output is one JSON object. On anything but a TPU
 named in ``harness/peaks.json``, or with fewer chips than the cell asks for,
 the command exits 1 and prints no result. ``--rehearse`` runs the cell's
 control flow on the CPU at the tiny sizes under ``"rehearse"`` in the
-configuration and traffic files, and prints no metric.
+configuration and traffic files, and prints no metric: its line lists the
+names the cell's readers would give, those the manifest lists for the cell
+(``rehearsed``) and those it does not (``unlisted``).
 """
 
 from __future__ import annotations
@@ -114,26 +116,40 @@ def main(argv=None) -> int:
     r.update(chips=chips, peaks=peaks, cell=cell)
 
     # -- the metrics of this run: end to end, or per layer from the readers
-    values = {}
+    values, unlisted = {}, []
     if args.trace:
         listed = [m for m in manifest["per_layer"]
                   if cell["name"] in m.get("workloads", [cell["name"]])]
         readers = {}
-        for m in listed:
+        # a rehearsal asks every group's reader, a listed name or not: what
+        # they give beyond the cell's lists is ``unlisted`` in its line
+        for m in manifest["per_layer"] if args.rehearse else listed:
             group = m["name"].split(".", 1)[0]
             if group not in readers:
                 module = importlib.import_module(
                     f"benchmark.layer_metrics.{group}")
                 readers[group] = module.read(r)
+        for m in listed:
+            group = m["name"].split(".", 1)[0]
             if m["name"] in readers[group]:
                 values[m["name"]] = {"value": readers[group][m["name"]],
                                      "unit": m["unit"]}
+        unlisted = sorted({name for got in readers.values() for name in got
+                           if name not in values})
     else:
         for m in manifest["end_to_end"]:
             values[m["name"]] = {"value": r[m["name"]], "unit": m["unit"]}
 
+    # which block was slow and what the host did longest: a stalled run's
+    # own account (the program's spans say more in a traced run's idle gaps)
+    slow = max(range(len(r["block_s"])), key=r["block_s"].__getitem__)
+    longest = {name: round(1e3 * max(durations), 3)
+               for name, durations in r["spans"].items() if durations}
     print(f"[{cell['name']}] seed {args.seed}: {r['steps']} steps in "
-          f"{r['window_s']:.3f} s, setup {r['setup_s']:.1f} s "
+          f"{r['window_s']:.3f} s (longest block {r['block_s'][slow]:.4f} s, "
+          f"number {slow} of {len(r['block_s'])}, the median "
+          f"{sorted(r['block_s'])[len(r['block_s']) // 2]:.4f}; longest host "
+          f"span, ms: {longest}), setup {r['setup_s']:.1f} s "
           f"{r['setup_phases_s']} "
           f"({r['setup_compile_s']:.1f} s compiling or loading, cache "
           f"{r['cache']}), checks {r['checks']}, reference {r['reference']}, "
@@ -142,7 +158,7 @@ def main(argv=None) -> int:
             "failed": r["failed"]}
     if args.rehearse:
         # a CPU run's numbers are never written under a device metric's name
-        line.update(metrics={}, rehearsed=sorted(values),
+        line.update(metrics={}, rehearsed=sorted(values), unlisted=unlisted,
                     device={"platform": dev.platform, "kind": dev.device_kind,
                             "count": len(devices)})
         print(json.dumps(line))
