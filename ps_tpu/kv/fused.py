@@ -35,7 +35,7 @@ import jax
 
 from ps_tpu import obs
 from ps_tpu.kv import keys as keymod
-from ps_tpu.obs import phases
+from ps_tpu.obs import pace, phases
 from ps_tpu.parallel.sharding import gathered_sharding, placed_by_rule
 
 
@@ -164,16 +164,19 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
     check_health = dense_store._check_health
     span = obs.tracer().program_span
     n_ids: Dict[str, int] = {}  # id-list sizes are static: probed once
+    # whether the chip was waiting for each launch: asked twice a step
+    account = pace.StepPace()
 
     def run(batch, *extra):
-        with span(phases.STEP_RUN, step=dense_store.step):
+        with span(phases.STEP_RUN, step=dense_store.step) as whole:
             check_health()  # dead peer -> typed error, not a hung psum
             if names and not n_ids:
                 n_ids.update(
                     (n, math.prod(ids.shape))
                     for n, ids in jax.eval_shape(ids_fn, batch).items())
             args = step_args(batch, extra)
-            with span(phases.STEP_LAUNCH, step=dense_store.step):
+            with span(phases.STEP_LAUNCH, step=dense_store.step,
+                      **account.launching()):
                 params_kv, state, *rest = fused_step(*args)
             k = len(names)
             loss, aux = rest[2 * k:2 * k + 2]
@@ -190,6 +193,7 @@ def make_fused_step(dense_store, emb_stores: Dict[str, "SparseEmbedding"],
                 store.adopt_push(table, estate, counts, n_ids[n],
                                  store.rows_nbytes(n_ids[n]), held=distinct)
             params = keymod.unflatten(treedef, params_kv, key_order)
+            account.ran(loss, whole.args["step"], whole.t0)
         if has_aux:
             return loss, params, aux
         return loss, params

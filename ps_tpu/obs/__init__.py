@@ -15,7 +15,9 @@ Three layers, each usable alone, wired through every transport hot path:
   :mod:`ps_tpu.obs.phases`, which also names the ``jax.named_scope`` phases
   inside the device program); jax's own trace / lower / compile events
   become their children, four ``ps_compile_*`` counters and the
-  ``recompile`` flight event (:mod:`ps_tpu.obs.compiles`).
+  ``recompile`` flight event (:mod:`ps_tpu.obs.compiles`); each
+  ``step.launch`` says how many of its wrapper's steps the chip still held
+  (:mod:`ps_tpu.obs.pace`: ``ps_step_*`` and the ``slow_step`` event).
 - **Metrics** (:mod:`ps_tpu.obs.metrics`): counters, gauges, and
   log2-bucket latency histograms (p50/p99/p999) that ``TransportStats``
   feeds; exported in the extended STATS frame, rendered live by

@@ -13,7 +13,8 @@ the work is written (``kv/fused.py``, ``kv/sparse.py``,
 recorded with ``ps_tpu.obs.tracer().program_span`` (``kv/fused.py``,
 ``data/prefetch.py``), the set-up spans too (``ps_tpu/__init__.py``,
 ``api.py``, ``kv/store.py``, ``kv/sparse.py``), and the compiler's spans by
-the one listener on ``jax.monitoring`` (``obs/compiles.py``).
+the one listener on ``jax.monitoring`` (``obs/compiles.py``); what a
+``step.launch`` says of the step's pace is ``obs/pace.py``'s.
 ``benchmark/layer_metrics/scope.py``, ``host.py`` and ``setup.py`` keep
 their own copy of the names they look up (the benchmark also runs on trees
 that lack this file); ``tests/test_phases.py`` holds them equal. Every span
@@ -203,13 +204,25 @@ PHI4FLASH_SCOPES = (ATTN, HEAD, FFN, MAMBA, MAMBA_CONV, ATTN_WINDOW,
 
 # -- host spans (Tracer.program_span) -----------------------------------------
 STEP_RUN = "step.run"                      # the whole of run(batch); step=n
-STEP_LAUNCH = "step.launch"                # the jitted call only; child of step.run
+STEP_LAUNCH = "step.launch"                # the jitted call only; child of step.run; step, in_flight[, drained_at_most_ms]
 INPUT_PLACE = "input.place"                # place(item) in device_prefetch; seq, nbytes
 INPUT_SOURCE_WAIT = "input.source_wait"    # the consumer's q.get(); seq
 INPUT_PRODUCE = "input.produce"            # next(batches) in the producer thread; seq
 
 HOST_SPANS = (STEP_RUN, STEP_LAUNCH, INPUT_PLACE, INPUT_SOURCE_WAIT,
               INPUT_PRODUCE)
+
+# -- the step's pace (obs/pace.py, called by kv/fused.py::run) ------------------
+# Whether the chip was waiting for a launch. Two arguments of ``step.launch``,
+# read by ``benchmark/layer_metrics/pace.py``, which keeps its own copy; a
+# gauge and two counters of ``obs.default_registry()`` and one flight event,
+# an operator's, when the ring has turned over.
+IN_FLIGHT = "in_flight"                    # steps of this wrapper launched and not yet seen finished, at the launch
+DRAINED_AT_MOST_MS = "drained_at_most_ms"  # on a drained launch (in_flight 0, not the first): ms since the launch before it began
+STEP_IN_FLIGHT = "ps_step_in_flight"       # gauge: in_flight at the last launch
+STEP_DRAINED_LAUNCHES = "ps_step_drained_launches_total"  # counter: launches with in_flight 0 but the first
+STEP_SLOW = "ps_step_slow_total"           # counter: slow_step events
+SLOW_STEP = "slow_step"                    # flight event; step, ms, median_ms, in_flight
 
 # -- set-up spans (Tracer.program_span; a dozen a process, none per step) ------
 # Read by ``benchmark/layer_metrics/setup.py``, which keeps its own copy.

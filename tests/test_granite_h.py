@@ -290,10 +290,11 @@ def _json(path):
 
 def test_the_cell_is_what_issue_56_named(listed_for):
     """One configuration, one cell on one chip under the Kimi cell's traffic
-    file as it stands, and no per-layer entry of its own: the manifest stands
-    at its 128, and of the lists that are there the cell is in the two that
+    file as it stands; of the manifest's lists the cell is in the two that
     read any decoder's facts (the step's share of the peak, the flash calls'
-    of their roofline)."""
+    of their roofline). What else lists it is the manifest's to say:
+    ``tests/test_phases.py`` holds every name listed for the cell to what
+    its rehearsal gives, and the manifest to its 128 names at most."""
     manifest = _json("BENCHMARK.json")
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -308,9 +309,8 @@ def test_the_cell_is_what_issue_56_named(listed_for):
     assert entry["reduced"] == ["num_hidden_layers", "layer_types",
                                 "vocab_size"]
     assert {"throughput", "setup_s"} <= {m["moves"] for m in listed_for(CELL)}
-    assert {m["name"] for m in listed_for(CELL) if "workloads" in m} == {
+    assert {m["name"] for m in listed_for(CELL) if "workloads" in m} >= {
         "step.mfu", "kernel.flash_roofline"}
-    assert len(manifest["per_layer"]) == 128
 
 
 def test_configuration_holds_the_published_widths():

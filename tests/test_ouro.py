@@ -271,10 +271,11 @@ def test_the_readouts_read_one_head_a_block_of_positions_at_a_time():
 def test_the_cell_is_what_issue_63_named(listed_for):
     """One configuration, one cell on one chip under the Nemotron cell's
     traffic file as it stands (``loss_step`` 96: at 48 six seeds spread 2.1%,
-    as ISSUE 63 foresaw), and no per-layer entry of its own: the manifest stands
-    at its 128 (the seven ``ouro.*`` names ISSUE 63 asks for have a reader,
-    ``layer_metrics/ouro.py``, and no room), and of the lists that are there
-    the cell is in the two that read any decoder's facts."""
+    as ISSUE 63 foresaw); of the manifest's lists the cell is in the two that
+    read any decoder's facts. What else lists it (the ``ouro.*`` names of
+    ``layer_metrics/ouro.py`` among them) is the manifest's to say:
+    ``tests/test_phases.py`` holds every name listed for the cell to what
+    its rehearsal gives, and the manifest to its 128 names at most."""
     manifest = _json("BENCHMARK.json")
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     # the eighteenth cell on the fourteenth configuration; later PRs append
@@ -292,9 +293,8 @@ def test_the_cell_is_what_issue_63_named(listed_for):
     assert entry["reduced"] == ["num_hidden_layers", "layer_types"]
     assert {"throughput", "setup_s"} <= {m["moves"] for m in listed_for(CELL)}
     own = [m for m in listed_for(CELL) if "workloads" in m]
-    assert {m["name"] for m in own} == {"step.mfu", "kernel.flash_roofline"}
+    assert {m["name"] for m in own} >= {"step.mfu", "kernel.flash_roofline"}
     assert all(CELL in m["workloads"] for m in own)
-    assert len(manifest["per_layer"]) == 128
     assert len(manifest["workloads"]) >= 18 and len(manifest["configs"]) >= 14
 
 

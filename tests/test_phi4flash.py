@@ -480,10 +480,11 @@ def _json(path):
 
 def test_the_cell_is_what_issue_65_named(listed_for):
     """One configuration, one cell on one chip under a traffic file of its
-    own, and no per-layer entry: the manifest stands at its 128, and of the
-    lists that are there the cell is in the two that read any decoder's
-    facts (the step's share of the peak, the flash calls' of their
-    roofline)."""
+    own; of the manifest's lists the cell is in the two that read any
+    decoder's facts (the step's share of the peak, the flash calls' of their
+    roofline). What else lists it is the manifest's to say:
+    ``tests/test_phases.py`` holds every name listed for the cell to what
+    its rehearsal gives, and the manifest to its 128 names at most."""
     manifest = _json("BENCHMARK.json")
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -502,12 +503,12 @@ def test_the_cell_is_what_issue_65_named(listed_for):
                                "config.json")
     assert entry["reduced"] == ["num_hidden_layers", "vocab_size"]
     assert {"throughput", "setup_s"} <= {m["moves"] for m in listed_for(CELL)}
-    own = [m for m in listed_for(CELL) if "workloads" in m]
-    assert {m["name"] for m in own} == {"step.mfu", "kernel.flash_roofline"}
-    # appended to both lists, behind the cell PR 63 appended
-    assert all(m["workloads"][-2:] == ["ouro-2.6b.s8192.b1.zipf", CELL]
-               for m in own)
-    assert len(manifest["per_layer"]) == 128
+    own = {m["name"]: m for m in listed_for(CELL) if "workloads" in m}
+    assert set(own) >= {"step.mfu", "kernel.flash_roofline"}
+    # in both lists behind the cell PR 63 appended
+    for name in ("step.mfu", "kernel.flash_roofline"):
+        cells = own[name]["workloads"]
+        assert cells.index("ouro-2.6b.s8192.b1.zipf") < cells.index(CELL)
     traffic = _json("benchmark/traffic/s16384.b1.zipf.json")
     assert (traffic["per_chip_batch"], traffic["seq_len"], traffic["attn"],
             traffic["input"], traffic["pool"]) == (1, 16384, "flash",

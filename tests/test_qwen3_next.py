@@ -669,7 +669,6 @@ def test_the_cell_is_what_issue_60_named(listed_for):
     assert traffic["loss_step"] in qwen3_next_step.LOSS_STEPS
     four = [w for w in manifest["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(manifest["workloads"]) // 4)
-    assert len(manifest["per_layer"]) == 128
 
 
 @pytest.mark.parametrize("change", [

@@ -577,11 +577,19 @@ def test_configuration_holds_the_published_widths():
                              flash.seen_pairs(seq))
     assert band == 31_458_304 * 32 * 2304
     assert triangle == 134_225_920 * 32 * 2304
-    # the band at the kernel's (1024, 1024): 45 of the triangle's 136 live
-    # steps, where the pairs are 23.4%
-    assert trinity_step.live_step_share(seq, 2048, (1024, 1024)) \
+
+
+def test_the_bands_share_of_a_causal_grids_live_steps():
+    """``trinity_step.live_step_share``: the band at the kernel's
+    (1024, 1024) is 45 of the triangle's 136 live steps, where the pairs are
+    23.4%. No cell's facts call it since PR 67 (the causal grid it counts
+    went with PR 53): the test goes with the function."""
+    if not hasattr(trinity_step, "live_step_share"):
+        pytest.skip("benchmark/families/trinity_step.py has no "
+                    "live_step_share any more")
+    assert trinity_step.live_step_share(16384, 2048, (1024, 1024)) \
         == pytest.approx(45 / 136)
-    assert trinity_step.live_step_share(seq, 2048, (512, 512)) \
+    assert trinity_step.live_step_share(16384, 2048, (512, 512)) \
         == pytest.approx(150 / 528)
 
 
