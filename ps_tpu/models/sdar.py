@@ -37,10 +37,16 @@ How they are computed here:
   noised queries under the strict one, which returns its logsumexp beside its
   output (``ops/flash_attention.py``'s ``edge_block=``, ``strict_edge=``,
   ``return_lse=``: the first block's noised queries see no clean key and get
-  zeros and -1e30); then ``own_block`` (``ps.attn/inblock``): a noised
-  query's scores over the ``B`` noised keys of its own block, in f32 on the
-  vector units ([L / B, B, B] a head: no matmul is worth a block of four),
-  merged with the kernel's part by the two logsumexps. The kernels see
+  zeros and -1e30); then ``own_block`` (``ps.attn/inblock``,
+  ``ops/own_block.py``): a noised query's scores over the ``B`` noised keys
+  of its own block, merged with the kernel's part by the two logsumexps,
+  all in f32. At heads of 128 on whole tiles of 128 positions (the cell) it
+  is one Mosaic pass forward and one backward: ``B`` divides 128, so a tile
+  holds whole blocks and the term is the tile's queries over the tile's keys
+  on the MXU under the block-diagonal mask, q, k, v and the kernel's part
+  read once and the merged rows written once; at every other shape
+  (``own_block.path``) the same equations as f32 products and sums over
+  ``[L / B, B, B]`` a head. The kernels see
   ``L (L + B) / 2 + L (L - B) / 2 = L ** 2`` pairs a head, not the
   ``2 L ** 2`` of a causal call over ``2 L``, and the clean-query x
   noised-key quarter is in no call.
@@ -80,6 +86,7 @@ from ps_tpu.models.blocks import WHOLE_WINDOW, rms_norm, rope
 from ps_tpu.obs import default_registry, phases
 from ps_tpu.ops import moe
 from ps_tpu.ops.flash_attention import KEPT
+from ps_tpu.ops.own_block import own_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,32 +219,6 @@ def make_edge_attn(attn: str = "full", **kw) -> Callable:
                                **kw)
 
     return flash_fn
-
-
-def own_block(q, k, v, earlier, lse, block: int):
-    """A noised query's attention over the noised keys of its own block,
-    both directions, merged with what the kernel gave it of the earlier
-    clean keys: ``q``, ``earlier`` [B, L, h, d], ``k``, ``v`` [B, L, h_kv,
-    d], ``lse`` [B, L, h] (the kernel's logsumexp, -1e30 where a row saw no
-    key). Scores, softmax and the merge in f32, as products and sums over
-    the ``block`` keys (no matmul of [block, d] x [d, block]); the result in
-    ``q``'s dtype."""
-    b, seq, h, d = q.shape
-    g = k.shape[2]
-    n = seq // block
-    f32 = jnp.float32
-    # [B, n, query in block, key in block, K/V head, query head of it, d]
-    qf = q.astype(f32).reshape(b, n, block, 1, g, h // g, d)
-    kf = k.astype(f32).reshape(b, n, 1, block, g, 1, d)
-    vf = v.astype(f32).reshape(b, n, 1, block, g, 1, d)
-    s = jnp.sum(qf * kf, axis=-1) * (d ** -0.5)
-    own_lse = jax.nn.logsumexp(s, axis=3, keepdims=True)
-    own = jnp.sum(jnp.exp(s - own_lse)[..., None] * vf, axis=3)
-    own, own_lse = own.reshape(b, seq, h, d), own_lse.reshape(b, seq, h)
-    total = jnp.logaddexp(lse, own_lse)
-    out = (jnp.exp(lse - total)[..., None] * earlier.astype(f32)
-           + jnp.exp(own_lse - total)[..., None] * own)
-    return out.astype(q.dtype)
 
 
 def attention_block(lp: Dict, x, config: SdarConfig, attn_fn: Callable):

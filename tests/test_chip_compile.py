@@ -6,6 +6,7 @@ topology described inside a fixture: only one process may hold libtpu, and
 only the worker that is given this file loads it.
 """
 
+import functools
 import re
 
 import jax
@@ -57,6 +58,12 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+def _described(sharding, tree):
+    """``tree``'s leaves as shapes on the described chip: nothing is made."""
+    return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
 
 
 def _written(text: str):
@@ -163,6 +170,52 @@ def test_the_edged_flash_calls_compile_at_sdars_shape(strict, one_chip,
         arg(32), arg(4), arg(4)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert " while(" not in text
+
+
+def test_the_own_block_kernels_engage_in_sdars_step(one_chip,
+                                                   no_compile_cache):
+    """``sdar-30b-a3b.s8192.b1.zipf.bd4``: value and gradient of the loss
+    ``KVStore.make_step`` differentiates at the configuration's published
+    widths and [1, 8192] tokens, one of its six layers (they are alike; the
+    step compiles in three quarters of a minute with one). ``ops/own_block.py::path`` is
+    static, so what says the kernels engage is the compiled program: under
+    ``ps.attn/inblock`` three Mosaic calls a layer (the forward, the forward
+    again in the layer's recomputation, the backward) and no f32 array of q's
+    size, ``[.., 8192, 32, 128]`` whole or cut in blocks of four, written by
+    a convert, a reduction or anything else; the flash calls stay six a
+    layer."""
+    import json
+    import os
+
+    from ps_tpu.models import sdar
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sdar-30b-a3b.json")) as f:
+        cfg = sdar.SdarConfig.from_dict(
+            {**json.load(f), "num_hidden_layers": 1})
+
+    on_chip = functools.partial(_described, one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: sdar.init_params(jax.random.key(0), cfg)))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((1, 8192), jnp.float32, sharding=one_chip)
+    loss = sdar.make_loss_fn(cfg, attn="flash")
+    text = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, {"ids": ids, "noised_ids": ids, "weights": weights}
+    ).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    own = [line for line in calls if "ps.attn/inblock" in line]
+    assert len(own) == 3 * cfg.num_hidden_layers
+    assert sum("own_block_backward" in line for line in own) \
+        == cfg.num_hidden_layers
+    assert sum("ps.attn/full" in line for line in calls) \
+        == 6 * cfg.num_hidden_layers
+    under = [line for line in text.splitlines() if "ps.attn/inblock" in line]
+    assert not [line for line in under if re.search(
+        r"f32\[(\d+,)*(8192,32,128|2048,4,4,8,128|8192,4,8,128)\]", line)]
 
 
 def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):
@@ -596,9 +649,7 @@ def test_joyais_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
                            "joyai-llm-flash.json")) as f:
         cfg = joyai.JoyaiConfig.from_dict(json.load(f))
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_described, one_chip)
 
     params = on_chip(jax.eval_shape(
         lambda: joyai.init_params(jax.random.key(0), cfg)))
@@ -651,9 +702,7 @@ def test_ouros_step_compiles_at_the_cells_shape(one_chip, no_compile_cache):
                            "ouro-2.6b.json")) as f:
         cfg = ouro.OuroConfig.from_dict(json.load(f))
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_described, one_chip)
 
     params = on_chip(jax.eval_shape(
         lambda: ouro.init_params(jax.random.key(0), cfg)))
@@ -931,9 +980,7 @@ def test_phi4flashs_step_compiles_at_the_cells_shape(one_chip,
                            "phi-4-mini-flash-reasoning.json")) as f:
         cfg = phi4flash.Phi4FlashConfig.from_dict(json.load(f))
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_described, one_chip)
 
     params = on_chip(jax.eval_shape(
         lambda: phi4flash.init_params(jax.random.key(0), cfg)))

@@ -23,6 +23,7 @@ from benchmark.families import flash
 from benchmark.families import sdar_reference as reference
 from benchmark.families import sdar_step
 from ps_tpu.models import sdar
+from ps_tpu.ops import own_block
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32_TOL = 1e-5
@@ -236,6 +237,8 @@ def test_two_calls_and_the_merge_match_dense_attention_under_the_mask(
     q, k, v = (jnp.asarray(rng.normal(0, 1, (2, 256, heads, 32)), jnp.float32)
                for heads in (4, 2, 2))
     w = jnp.asarray(rng.normal(0, 1, q.shape), jnp.float32)
+    # heads of 32 channels: the XLA form (tests/test_own_block.py, the kernels)
+    assert own_block.path(q[1:], k[1:], block) == "xla"
     got = _two_calls_and_the_merge(q, k, v, block, attn)
     want = _dense_under_the_mask(q, k, v, block)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -17,7 +17,11 @@ instruction name in the optimized HLO of the loaded executables gives its
 ``op_name``). A scope's Mosaic calls are listed by instruction with their
 time a step (``mosaic_calls``: six under ``ps.mamba/s6``, the two Mamba-1
 layers' forward, recomputed forward and backward; what the scope holds
-beyond them is XLA's around the calls). The **innermost** listed scope takes
+beyond them is XLA's around the calls). With ``--ops copy,transpose`` the
+events whose instruction name starts with one of these are listed too, a scope
+at a time, by name and result shape with their count and time a step
+(``ops``: the re-laid copies XLA puts around a Mosaic call show here, ``PERF.md``
+section 6, PR 69). The **innermost** listed scope takes
 an event's time
 (``layer_metrics/decoder.py::scope_of`` over the listed names); an event
 under none of them is counted under ``(none listed)``. The tool goes with the
@@ -36,6 +40,7 @@ import argparse
 import importlib
 import json
 import os
+import re
 import sys
 import time
 
@@ -50,10 +55,13 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default=CELL)
     ap.add_argument("--scopes", default=SCOPES, help="comma-separated")
+    ap.add_argument("--ops", default="", help="comma-separated starts of "
+                    "instruction names to list by shape under each scope")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     scopes = tuple(args.scopes.split(","))
+    listed = tuple("%" + start for start in args.ops.split(",") if start)
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
@@ -100,7 +108,7 @@ def main(argv=None) -> int:
     else:
         devices, steps = r["trace"]["devices"], r["traced_steps"]
         per_ms = 1e3 / steps / len(devices)
-        seconds, kernels = {}, {}
+        seconds, kernels, by_shape = {}, {}, {}
         for d in devices.values():
             for name, sec in d["ops"].items():
                 own = tracered.parts(name)["own"]
@@ -110,12 +118,24 @@ def main(argv=None) -> int:
                 if found and tracered.is_custom_call_to(
                         name, ("tpu_custom_call",)):
                     kernels.setdefault(found, {})[own] = per_ms * sec
+                if found and listed and own.startswith(listed):
+                    # "%copy.12 = bf16[1,32,8192,128]{..} copy(..)": the
+                    # instruction's name without its number, and its shape
+                    kind = re.sub(r"[.\d]+$", "", own[1:]) + " " + re.sub(
+                        r"\{[^}]*\}", "", name.partition(" = ")[2].split(" ")[0])
+                    seen = by_shape.setdefault(found, {}).setdefault(
+                        kind, [0, 0.0])
+                    seen[0] += 1 / len(devices)
+                    seen[1] += per_ms * sec
         out["traced_steps"] = steps
         out["scopes"] = {s: per_ms * sec for s, sec in sorted(seconds.items())}
         # a scope's Mosaic calls, each instruction of the step once: their
         # count says a kernel engaged, the rest of the scope is XLA's around
         out["mosaic_calls"] = {s: dict(sorted(calls.items()))
                                for s, calls in sorted(kernels.items())}
+        if listed:
+            out["ops"] = {s: dict(sorted(kinds.items()))
+                          for s, kinds in sorted(by_shape.items())}
         facts = r["facts"]
         under = out["scopes"].get("ps.mamba/s6")
         if under and "scan_bytes" in facts:
