@@ -58,8 +58,29 @@ How they are computed here:
   ``ops/moe.py`` fixes 3: ``HELD_ROWS_OVER_EVEN`` below says why), on the
   tokens of both streams together.
 - every layer is recomputed in the backward pass (one ``jax.checkpoint`` a
-  layer) but its two kernel calls' outputs and logsumexps
-  (``ops/flash_attention.py::KEPT``), as Trinity's.
+  layer) but for what its policy lists by name, as Trinity's: whatever costs
+  a matrix product, a ``top_k``, a sort or a kernel call to make again.
+  ``ops/flash_attention.py::KEPT``: the two flash calls' outputs and the
+  strict one's logsumexp. ``ops/moe.py::ROUTE_KEPT``: the router's logits,
+  the picks and the pairs' two permutations (10 MB a layer in the cell), so
+  the backward pass differentiates the routing the forward pass ran.
+  ``PRODUCTS_KEPT``, beside ``_layer``, under Trinity's names: the q, k and
+  v projections' outputs a head at a time and before the norm (168 MB), q
+  and k again as the calls and ``own_block`` read them, normed and rotated
+  (151 MB); and ``attn_stream``, the stream once the out projection's output
+  is added, which ``post_attn_norm`` reads (67 MB), so that the out
+  projection's product is not made again. The name is on the sum and not on
+  the product: XLA folds the sum into the product and rounds once, and a
+  name on the product made it round the product to bf16 first and the sum
+  again in every layer, a forward pass that one seed's router gradient did
+  not forgive. 2.4 GB over the cell's six layers, 41 ms of a 685 ms step
+  (``PERF.md`` section 6, PR 71, has each name's ms and bytes). The
+  feed-forward branch adds to the stream with no norm behind it, so its
+  output feeds nothing a backward pass reads and bears no name; the own
+  block's merged rows bear none either (kept, they cost the forward pass
+  more than their recomputation takes). A name is the identity where no
+  policy lists it; the list is this file's constant, chosen from the
+  measured table.
 - a final RMSNorm and an untied head **on the noised stream alone**: the
   clean stream's last layer feeds the noised one's keys and nothing else.
 
@@ -81,6 +102,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ps_tpu.models.blocks import WHOLE_WINDOW, rms_norm, rope
 from ps_tpu.obs import default_registry, phases
@@ -229,16 +251,23 @@ def attention_block(lp: Dict, x, config: SdarConfig, attn_fn: Callable):
     b2, s, _ = x.shape
     b = b2 // 2
 
-    def proj(name, n):
-        return (x @ lp[name]["kernel"].astype(x.dtype)).reshape(b2, s, n, -1)
+    def per_head(name, n):
+        # named a head at a time and before the norm, as Trinity's
+        # (``models/trinity.py::attention_block`` says why)
+        return checkpoint_name(
+            (x @ lp[name]["kernel"].astype(x.dtype)).reshape(b2, s, n, -1),
+            f"attn_{name}")
 
-    q = rms_norm(proj("q", c.num_attention_heads), lp["q_norm"]["scale"],
+    q = rms_norm(per_head("q", c.num_attention_heads), lp["q_norm"]["scale"],
                  c.rms_norm_eps)
-    k = rms_norm(proj("k", c.num_key_value_heads), lp["k_norm"]["scale"],
+    k = rms_norm(per_head("k", c.num_key_value_heads), lp["k_norm"]["scale"],
                  c.rms_norm_eps)
-    v = proj("v", c.num_key_value_heads)
+    v = per_head("v", c.num_key_value_heads)
     # each copy at positions 0 .. L-1: the same rotation for both
     q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+    # as the two calls and ``own_block`` read them: their backward kernels'
+    # operands
+    q, k = checkpoint_name(q, "attn_q_read"), checkpoint_name(k, "attn_k_read")
     with jax.named_scope(phases.ATTN_FULL):
         clean = attn_fn(q[:b], k[:b], v[:b], c.block_length, False, False)
         earlier, lse = attn_fn(q[b:], k[:b], v[:b], c.block_length, True,
@@ -297,8 +326,17 @@ def moe_block(lp: Dict, x, config: SdarConfig):
     return out.reshape(b2, s, d), routing
 
 
+#: what a layer keeps beside the flash calls' residuals and the routing
+#: (module docstring), by the names the values bear where they are made: the
+#: outputs of the attention's q, k and v projections, q and k again as the
+#: calls read them, and the stream once the out projection's output is added
+PRODUCTS_KEPT = ("attn_q", "attn_k", "attn_v", "attn_q_read", "attn_k_read",
+                 "attn_stream")
+
+
 @functools.partial(jax.checkpoint, static_argnums=(2, 3),
-                   policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+                   policy=jax.checkpoint_policies.save_only_these_names(
+                       *KEPT, *moe.ROUTE_KEPT, *PRODUCTS_KEPT))
 def _layer(lp: Dict, x, config: SdarConfig, attn_fn: Callable):
     """One layer on both streams, recomputed in the backward pass: the
     stream out, the layer's counts over all experts and over the held ones,
@@ -308,7 +346,10 @@ def _layer(lp: Dict, x, config: SdarConfig, attn_fn: Callable):
         a = attention_block(
             lp["attn"], rms_norm(x, lp["input_norm"]["scale"], eps), config,
             attn_fn)
-    x = x + a
+    # the stream behind the attention branch, which ``post_attn_norm`` reads:
+    # the sum bears the name, for a name on the out projection's output
+    # alone rounds it to bf16 before the sum is rounded (module docstring)
+    x = checkpoint_name(x + a, "attn_stream")
     out, routing = moe_block(
         lp["moe"], rms_norm(x, lp["post_attn_norm"]["scale"], eps), config)
     with jax.named_scope(phases.MOE_ROUTE):

@@ -45,6 +45,32 @@ def highest_products(jaxpr):
                for eqn in equations(jaxpr))
 
 
+def recomputed(jaxpr):
+    """The equations of every differentiated ``jax.checkpoint`` of
+    ``jaxpr``'s top level: a layer's recomputation and its backward pass, as
+    the gradient holds them."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "remat2" and eqn.params["differentiated"]:
+            yield from equations(eqn.params["jaxpr"])
+
+
+def weight_products(eqns):
+    """The shapes of the 2-D right operands of the ``dot_general``s among
+    ``eqns`` that contract an activation's last axis with a weight's first:
+    ``x @ W`` as a forward pass writes it. Neither cotangent of such a
+    product has that form (``dy @ W.T`` contracts the weight's second axis,
+    ``x.T @ dy`` the rows)."""
+    shapes = []
+    for eqn in eqns:
+        if eqn.primitive.name != "dot_general":
+            continue
+        (lhs, rhs), _ = eqn.params["dimension_numbers"]
+        x, w = (v.aval for v in eqn.invars)
+        if w.ndim == 2 and tuple(rhs) == (0,) and tuple(lhs) == (x.ndim - 1,):
+            shapes.append(w.shape)
+    return shapes
+
+
 def traced_and_run(fn, *args):
     """``fn``'s jaxpr at ``args`` and its result there, compiled without the
     backend's optimizations: XLA:CPU's fused kernels round a product and a

@@ -183,7 +183,14 @@ def test_the_own_block_kernels_engage_in_sdars_step(one_chip,
     again in the layer's recomputation, the backward) and no f32 array of q's
     size, ``[.., 8192, 32, 128]`` whole or cut in blocks of four, written by
     a convert, a reduction or anything else; the flash calls stay six a
-    layer."""
+    layer. And what says the layer's names (``sdar.PRODUCTS_KEPT``) engaged
+    under the chip's compiler: a product that gives ``bf16[2, 8192, 4096]``
+    is q's projection (``[.., 2048] x [2048, 4096]``) or the out
+    projection's cotangent for its input, and the step holds one of each a
+    layer, the first in the forward pass and none in the recomputation (the
+    policy that listed the flash calls' residuals alone held q's twice);
+    the rotation's Mosaic calls are two forward and two transposed, none
+    again."""
     import json
     import os
 
@@ -216,6 +223,13 @@ def test_the_own_block_kernels_engage_in_sdars_step(one_chip,
     under = [line for line in text.splitlines() if "ps.attn/inblock" in line]
     assert not [line for line in under if re.search(
         r"f32\[(\d+,)*(8192,32,128|2048,4,4,8,128|8192,4,8,128)\]", line)]
+    q_wide = [line for line in text.splitlines() if re.search(
+        r"= bf16\[2,8192,4096\]\S* convolution\(", line)]
+    assert len(q_wide) == 2 * cfg.num_hidden_layers
+    assert sum("transpose(" not in line for line in q_wide) \
+        == cfg.num_hidden_layers
+    assert not [line for line in q_wide + calls
+                if "rematted_computation" in line and "/inblock/" not in line]
 
 
 def test_gated_conv_compiles_at_the_cells_shape(one_chip, no_compile_cache):

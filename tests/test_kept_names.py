@@ -1,9 +1,11 @@
 """What a layer's ``jax.checkpoint`` keeps by name, read off the traced
 gradient: ``ops/moe.py::ROUTE_KEPT`` is the identity wherever no policy lists
-it (the five models that share ``route`` and do not) and takes the router's
-product, its ``top_k`` and its two sorts out of the recomputation where one
-does. The three models whose policies list it have their own cases in
-``test_nemotron_h.py``, ``test_trinity.py`` and ``test_joyai.py``."""
+it (the four models that share ``route`` and do not; SDAR's names under the
+policy it had before it listed them) and takes the router's product, its
+``top_k`` and its two sorts out of the recomputation where one does. The
+four models whose policies list it have their own cases in
+``test_nemotron_h.py``, ``test_trinity.py``, ``test_joyai.py`` and
+``test_sdar.py``."""
 
 import importlib
 
@@ -14,35 +16,42 @@ import pytest
 
 from jaxpr_tools import (checkpoint_names, equations, highest_products,
                          primitives, traced_and_run)
-from ps_tpu.models import blocks
+from ps_tpu.models import blocks, sdar
 from ps_tpu.ops import moe
+from ps_tpu.ops.flash_attention import KEPT
 
-#: the models that call ``route`` and keep none of its names, each with the
-#: ``name`` equations its gradient holds: four a call of ``route``, counted
-#: once where it runs under no checkpoint (OLMoE's layers in small: two;
-#: LFM2's and Kimi's expert layers: four each) and twice, forward and
+#: the models that call ``route`` under no policy that lists its names, each
+#: with the ``name`` equations its gradient holds: four a call of ``route``,
+#: counted once where it runs under no checkpoint (OLMoE's layers in small:
+#: two; LFM2's and Kimi's expert layers: four each) and twice, forward and
 #: recomputation, where a layer's policy lists the flash call's residuals
-#: alone (Mellum's four layers, on a mesh of four; SDAR's three)
+#: alone (Mellum's four layers, on a mesh of four). SDAR's three layers list
+#: them since PR 71: its case puts them back under the policy they had
+#: before, ``flash_attention.KEPT`` alone, where ``route``'s four names and
+#: the six of ``sdar.PRODUCTS_KEPT`` are each borne twice a layer
 SHARE_ROUTE = {"olmoe": 4 * 2, "lfm2": 4 * 4, "kimi_linear": 4 * 4,
-               "mellum": 2 * 4 * 4, "sdar": 2 * 4 * 3}
+               "mellum": 2 * 4 * 4, "sdar": 2 * (4 + 6) * 3}
 
 
-def _traced_gradient(name):
+def _traced_gradient(name, **kw):
     tests = importlib.import_module(f"test_{name}")
     model = importlib.import_module(f"ps_tpu.models.{name}")
     _, cfg, *args = tests._setup()
-    kw = {"mesh": tests._mesh()} if name == "mellum" else {}
+    if name == "mellum":
+        kw["mesh"] = tests._mesh()
     fn = jax.value_and_grad(model.make_loss_fn(cfg, **kw), has_aux=True)
     return jax.make_jaxpr(fn)(*args).jaxpr
 
 
 @pytest.fixture
 def without_names(monkeypatch):
-    """Call it to take ``route``'s names out. jax keeps a checkpointed
-    layer's trace by the layer's identity, so the traces made before are
-    dropped, and those made without names when the test is over."""
+    """Call it to take ``route``'s names out, and SDAR's own. jax keeps a
+    checkpointed layer's trace by the layer's identity, so the traces made
+    before are dropped, and those made without names when the test is
+    over."""
     def patch():
-        monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
+        for module in (moe, sdar):
+            monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
         jax.clear_caches()
 
     yield patch
@@ -52,19 +61,40 @@ def without_names(monkeypatch):
 
 @pytest.mark.parametrize("name", sorted(SHARE_ROUTE))
 def test_routes_names_are_the_identity_where_no_policy_lists_them(
-        name, without_names):
+        name, without_names, monkeypatch):
     """The loss's gradient with ``route``'s names is, equation for equation,
     the one without them (``checkpoint_name`` patched out, which is the
     parent's ``route``) but for the ``name`` equations themselves: as many
-    ``dot_general``s, ``top_k``s and sorts, in the same order."""
+    ``dot_general``s, ``top_k``s and sorts, in the same order. SDAR's layer
+    under a policy that lists the flash call's residuals alone says the same
+    of every name ``models/sdar.py`` gives."""
+    names = set(moe.ROUTE_KEPT)
+    if name == "sdar":
+        names |= set(sdar.PRODUCTS_KEPT)
+        monkeypatch.setattr(sdar, "_layer", jax.checkpoint(
+            sdar._layer.__wrapped__, static_argnums=(2, 3),
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT)))
     named = _traced_gradient(name)
-    assert checkpoint_names(named) == set(moe.ROUTE_KEPT)
+    assert checkpoint_names(named) == names
     without_names()
     plain = primitives(_traced_gradient(name))
     assert "name" not in plain and plain.count("top_k") > 0
     listed = primitives(named)
     assert listed.count("name") == SHARE_ROUTE[name]
     assert [p for p in listed if p != "name"] == plain
+
+
+@pytest.mark.parametrize("name", ["sdar"])
+def test_a_gradient_bears_the_names_its_layers_policy_lists_and_no_other(
+        name):
+    """With 'flash' the names in the loss's gradient are exactly those of the
+    layer's policy: the flash call's residuals, ``route``'s four and the
+    file's ``PRODUCTS_KEPT``. A name that a traced run showed to return
+    nothing does not stay in the model (``PERF.md`` section 6, PRs 51 and
+    71)."""
+    model = importlib.import_module(f"ps_tpu.models.{name}")
+    assert checkpoint_names(_traced_gradient(name, attn="flash")) \
+        == {*KEPT, *moe.ROUTE_KEPT, *model.PRODUCTS_KEPT}
 
 
 @pytest.mark.parametrize("held", [None, (4, 2)], ids=["all", "share"])
@@ -127,7 +157,6 @@ def test_granites_layers_keep_what_their_policy_lists(monkeypatch, attn):
     reads, and gives the output with them); with 'flash', three kernel calls
     where the policy-less one holds four."""
     from ps_tpu.models import granite_h
-    from ps_tpu.ops.flash_attention import KEPT
     import test_granite_h
 
     _, cfg, params, batch = test_granite_h._setup()

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_tools import flash_calls
+from jaxpr_tools import (flash_calls, recomputed, traced_and_run,
+                         weight_products)
 from benchmark.families import flash
 from benchmark.families import sdar_reference as reference
 from benchmark.families import sdar_step
@@ -170,28 +171,53 @@ def test_fused_step_matches_reference():
 @pytest.mark.parametrize("attn", ["full", "flash"])
 def test_a_layers_checkpoint_keeps_the_flash_residuals_and_nothing_else(
         monkeypatch, attn):
-    """With 'flash' the loss's gradient holds six kernel calls a layer, the
-    two forwards and each one's dk / dv and dq, where a ``jax.checkpoint``
-    without a policy holds eight, the forwards run again for their outputs
-    and logsumexps; loss and every gradient are the same bits."""
-    _, cfg, params, batch = _setup()
+    """What a layer's policy lists by name (``flash_attention.KEPT``,
+    ``ops/moe.py::ROUTE_KEPT``, ``sdar.PRODUCTS_KEPT``; the test keeps the
+    name it had when the first was the whole list) is not made again. With
+    'flash' the loss's gradient holds six kernel calls a layer, the two
+    forwards and each one's dk / dv and dq, where a ``jax.checkpoint``
+    without a policy holds eight. A layer's recomputation holds none of the
+    q, k, v and out projections' products nor the router's, no ``top_k`` and
+    neither ``argsort`` of the pairs, where the policy-less one holds each
+    once (the sort it keeps is ``ops/moe.py::_window_index``'s over a
+    window's rows, which bears no name, once for the dispatch and once for
+    ``combine``). Loss and every gradient are the same bits, as
+    ``tests/test_trinity.py``'s counterpart asks: in f32 on the CPU a kept
+    value is the value its recomputation makes, and ``traced_and_run``
+    compiles both programs without the fusions that would round them
+    apart. (On the chip a kept value is the forward pass's own bf16 array;
+    the benchmark's step-0 checks hold that.) Two layers: they are alike,
+    and a third buys seconds of compiling and nothing else."""
+    _, cfg, params, batch = _setup(num_hidden_layers=2)
+    lp = params["layer0"]
+    projections = [lp["attn"][n]["kernel"].shape for n in "qkv"] + [
+        lp["attn"]["out"]["kernel"].shape, lp["moe"]["router"]["kernel"].shape]
 
     def trace_and_run():
-        fn = jax.value_and_grad(sdar.make_loss_fn(cfg, attn=attn),
-                                has_aux=True)
-        return (flash_calls(jax.make_jaxpr(fn)(params, batch).jaxpr),
-                jax.jit(fn)(params, batch))
+        jaxpr, out = traced_and_run(jax.value_and_grad(
+            sdar.make_loss_fn(cfg, attn=attn), has_aux=True), params, batch)
+        again = list(recomputed(jaxpr))
+        return (flash_calls(jaxpr), weight_products(again),
+                [e.primitive.name for e in again], out)
 
-    calls, ((loss, _), grads) = trace_and_run()
+    calls, products, again, ((loss, _), grads) = trace_and_run()
     monkeypatch.setattr(sdar, "_layer", jax.checkpoint(
         sdar._layer.__wrapped__, static_argnums=(2, 3)))
-    plain_calls, ((plain_loss, _), plain_grads) = trace_and_run()
-    flash_on = attn == "flash"
-    assert calls == 3 * 6 * flash_on and plain_calls == 3 * 8 * flash_on
+    plain_calls, plain_products, plain_again, ((plain_loss, _), plain_grads) \
+        = trace_and_run()
+    layers, flash_on = cfg.num_hidden_layers, attn == "flash"
+    assert calls == layers * 6 * flash_on
+    assert plain_calls == layers * 8 * flash_on
+    assert products == []
+    assert sorted(plain_products) == sorted(layers * projections)
+    assert (again.count("top_k"), plain_again.count("top_k")) == (0, layers)
+    assert (again.count("sort"), plain_again.count("sort")) \
+        == (2 * layers, 4 * layers)
     assert float(loss) == float(plain_loss)
-    for g, w in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(plain_grads)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(plain_grads)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=jax.tree_util.keystr(path))
 
 
 # -- the attention form: two kernel calls and the own-block merge --------------
