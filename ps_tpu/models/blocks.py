@@ -21,7 +21,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ps_tpu.obs import phases
 from ps_tpu.ops import moe
 from ps_tpu.ops import rope as rotary
-from ps_tpu.ops.gated_conv import conv_silu
+from ps_tpu.ops.gated_conv import conv_silu_kernel
 from ps_tpu.ops.ssd import ssd
 
 
@@ -263,10 +263,8 @@ def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
     ``heads`` heads of ``head_dim`` on ``groups`` groups of B and C over a
     state of ``state``: ``[z | xBC | dt] = x W_in`` (which bears the name
     'mamba_in'); the x, B and C channels through the causal taps, the bias
-    and the SiLU of ``ops/gated_conv.py::conv_silu`` in its XLA form (in
-    front of the XLA scan its Mosaic form cost the Granite cell 1%, PR 57;
-    in front of the scan's Mosaic calls it is measured and not merged,
-    ``PERF.md`` section 7); ``dt = softplus(dt + dt_bias)`` in f32; the scan
+    and the SiLU of ``ops/gated_conv.py::conv_silu_kernel``; ``dt =
+    softplus(dt + dt_bias)`` in f32; the scan
     (``ops/ssd.py`` in chunks of ``chunk``: two Mosaic calls at the cells'
     shapes, whose forward runs again in a layer's recomputation for the
     states its backward reads, so the output bears no name for a policy to
@@ -276,7 +274,25 @@ def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
     norm over them, exactly; at one group the norm is over all ``heads *
     head_dim`` channels); the out projection.
     Nemotron-H's (a share of the heads: what comes out is that share's part
-    of a sum) and Granite-4.0-H's (whole)."""
+    of a sum) and Granite-4.0-H's (whole).
+
+    **The element-wise stages take the layout the scan's Mosaic calls state.**
+    The taps are ``conv_silu_kernel``: at the cells' shapes two Mosaic calls,
+    which write ``x``, ``B`` and ``C`` row-major as the scan's calls read them
+    (``ops/ssd_mosaic.py``), and at a shape ``gated_conv.path`` does not take
+    (the tests' small ones) the XLA form, as for Kimi-Linear's and
+    Qwen3-Next's mixers: the shapes choose, and no argument, field or
+    environment variable. (While the scan was XLA's at Granite's shape its
+    einsums read ``x`` in three layouts and the Mosaic taps cost that cell
+    471.91 ms a step where 443.03, in copies, PR 57; no cell's scan is XLA's
+    since PR 59.) Behind the
+    scan the skip is ``repeat(D, head_dim)`` times the taps' ``x`` as ``[B,
+    S, heads * head_dim]``, the layout the scan's call writes ``y`` in: as
+    4-D math over ``[.., heads, head_dim]`` XLA chose a layout of its own for
+    it and paid for the difference in four copies of ``[1, 8192, 4096]`` in
+    a layer's backward (``PERF.md`` section 6, PR 72). The same products
+    forward, to the bit; ``D``'s gradient sums the same terms in another
+    order."""
     b, s, _ = x.shape
     inner = heads * head_dim
     conv_dim = inner + 2 * groups * state
@@ -284,17 +300,19 @@ def mamba_block(lp: Dict, x, *, heads: int, head_dim: int, groups: int,
         x @ lp["in_proj"]["kernel"].astype(x.dtype), "mamba_in")
     z, xbc, dt = jnp.split(projected, [inner, inner + conv_dim], axis=-1)
     with jax.named_scope(phases.MAMBA_CONV):
-        xbc = conv_silu(xbc, lp["conv"]["kernel"], lp["conv"]["bias"])
+        xbc = conv_silu_kernel(xbc, lp["conv"]["kernel"], lp["conv"]["bias"])
     xs, b_in, c_in = jnp.split(xbc, [inner, inner + groups * state], axis=-1)
-    xs = xs.reshape(b, s, heads, head_dim)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
     with jax.named_scope(phases.MAMBA_SSD):
-        y = ssd(xs, dt, -jnp.exp(lp["A_log"]), b_in.reshape(b, s, groups, -1),
-                c_in.reshape(b, s, groups, -1), chunk=min(chunk, s))
+        y = ssd(xs.reshape(b, s, heads, head_dim), dt, -jnp.exp(lp["A_log"]),
+                b_in.reshape(b, s, groups, -1), c_in.reshape(b, s, groups, -1),
+                chunk=min(chunk, s))
     with jax.named_scope(phases.MAMBA_GATE):
-        y = y.astype(jnp.float32) + lp["D"][:, None] * xs.astype(jnp.float32)
+        # the skip over the flat channels, as the scan's calls lay them out
+        y = y.reshape(b, s, inner).astype(jnp.float32) \
+            + jnp.repeat(lp["D"], head_dim) * xs.astype(jnp.float32)
         # the gate first, then the norm over each group's channels
-        y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = y * jax.nn.silu(z.astype(jnp.float32))
         y = rms_norm(y.reshape(b, s, groups, -1),
                      lp["out_norm"]["scale"].reshape(groups, -1), eps)
     return y.reshape(b, s, inner).astype(x.dtype) \

@@ -30,7 +30,14 @@ to. How they are computed here:
   (``ops/ssd.py``: at the cell's shape one Mosaic call forward and one
   backward over all 64 heads, the decays and the states in VMEM; what lives
   between the two is the f32 states that entered each chunk, 134 MB inside
-  one layer's backward);
+  one layer's backward). The element-wise stages around it take its layout
+  (PR 72): ``mamba_block`` takes the 4,352 x, B and C channels through
+  ``ops/gated_conv.py::conv_silu_kernel``'s two Mosaic calls, which write
+  row-major what the scan's calls read row-major (the XLA form at a shape
+  those kernels do not take, the tests' sizes), and the skip ``D x`` behind
+  it is spelled over the flat 4,096 channels the scan writes. While this
+  shape's scan was XLA's (until PR 59) the XLA taps were the faster, because
+  that scan read ``x`` in three layouts (``ops/gated_conv.py``'s table);
 - attention: q, k, v without bias or position; the attention closure scales
   by ``head_dim ** -0.5``, so q is multiplied by ``attention_multiplier *
   head_dim ** 0.5`` first (1/64 x 8 = 0.125, a power of two: exact in

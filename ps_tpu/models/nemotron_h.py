@@ -29,9 +29,14 @@ which this module is held to. How they are computed here:
 
 - ``mamba_block`` (``models/blocks.py``'s, which Granite-4.0-H calls too):
   ``[z | xBC | dt] = u W_in``; the x, B and C channels through the four
-  causal taps, the bias and the SiLU of ``ops/gated_conv.py::conv_silu``;
-  ``dt = softplus(dt + dt_bias)`` in f32; the scan in its chunked form
-  (``ops/ssd.py``, chunks of ``chunk_size``) plus the skip ``D x``; the gate
+  causal taps, the bias and the SiLU of
+  ``ops/gated_conv.py::conv_silu_kernel`` (at the cell's share, 16 heads of
+  64 on one held group, the scan is two Mosaic calls over row-major operands
+  and the taps in front of it are two more, so that XLA re-lays nothing
+  between them, PR 72; the XLA form at a shape the taps' kernels do not
+  take, the tests' sizes); ``dt = softplus(dt + dt_bias)``
+  in f32; the scan in its chunked form (``ops/ssd.py``, chunks of
+  ``chunk_size``) plus the skip ``D x`` over the flat channels; the gate
   first, then an RMSNorm over each group's channels (so a share's norm is
   the uncut mixer's over that group, exactly); the out projection.
 - ``attention_block``: q on the held query heads, k and v on the held K/V

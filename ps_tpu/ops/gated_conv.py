@@ -44,8 +44,9 @@ with a bias (ISSUE 57): the forward kept three ``f32[1, 819x, 4352]`` slices
 the gradient seven such arrays (571 MB, 3.35 GB accessed where ``x``, ``dy``
 and ``dx`` are 0.214).
 
-*The XLA form* (``conv_silu``; what ``models/blocks.py::mamba_block`` calls,
-and every shape the kernels do not take). One copy of ``x`` padded by
+*The XLA form* (``conv_silu``; what Phi-4-mini-flash's Mamba-1 mixer calls,
+what ``models/blocks.py::mamba_block`` called until PR 72, and every shape
+the kernels do not take). One copy of ``x`` padded by
 ``taps - 1`` rows **in its own dtype**, static slices of it, each cast after
 it is cut (``_moved_copies``): the pad is a producer the loop fusion takes
 in and the slices are read in place. Same values to the bit
@@ -58,16 +59,19 @@ shuffles, not by its bytes). Its place is where XLA's own fusions read the
 result: until PR 59 Granite's scan (``ops/ssd.py``'s XLA form) took ``xs``
 in three layouts, and XLA wrote each from a taps fusion of its own; a custom
 call pins one row-major result and the copies into the other layouts then
-cost what the kernel saved (the table's last column). Since PR 59 the scan
-at the cells' shapes is a Mosaic call that reads ``xs`` row-major; what the
-Mosaic taps do in front of it is measured in ``PERF.md`` section 7 and not
-merged.
+cost what the kernel saved (the table's third row, in brackets). Since PR 59
+the scan at the cells' shapes is a Mosaic call that reads ``xs`` row-major,
+and since PR 72 ``mamba_block`` calls ``conv_silu_kernel``: no cell's scan
+is XLA's, and Nemotron's share was faster with the Mosaic taps in front of
+that one too (the table's last row).
 
 *The Mosaic calls* (``conv_silu_kernel``: ``forward`` / ``backward``; what
-``models/kimi_linear.py::_mixer`` calls, whose q, k and v go on to the KDA
-kernels row-major, at shapes ``path`` accepts: whole 128-lane tiles of
-channels, whole tiles of rows, at least one block). A grid step holds a
-``[rows, lanes]`` block of one sequence (``tiles``: the widest multiple of
+``models/kimi_linear.py::_mixer`` and ``models/qwen3_next.py`` call, whose q,
+k and v go on to the delta rule's kernels row-major, and since PR 72
+``mamba_block``, whose x, B and C go on to the scan's Mosaic calls in
+Granite's and Nemotron's cells; at shapes ``path`` accepts: whole 128-lane
+tiles of channels, whole tiles of rows, at least one block). A grid step holds
+a ``[rows, lanes]`` block of one sequence (``tiles``: the widest multiple of
 128 up to 512 lanes that divides ``C``, rows to 512 Ki elements; 2,048 x 256
 at 4,352 and 1,280 channels, 1,024 x 512 at 4,096) and, through a second
 ``BlockSpec`` on the same operand, the tile of rows before it (zeros at a
@@ -91,17 +95,17 @@ of several devices the
 calls are not wrapped in ``shard_map`` as ``ops/kda.py``'s are: no cell runs
 that mixer across chips yet.
 
-**Measured (TPU v5e, jax 0.9.0; my chip runs, PR 57): a call alone at
-``bf16[1, 8192, C]``, four taps, twenty calls chained in one program
-(``tools/taps_table.py``), forward /
-backward in ms; and the taps' scope inside its cell's step, a step's calls
-together, with the step beside it.**
+**Measured (TPU v5e, jax 0.9.0; my chip runs, PR 57, and PR 72 where said):
+a call alone at ``bf16[1, 8192, C]``, four taps, twenty calls chained in one
+program (``tools/taps_table.py``), forward / backward in ms; and the taps'
+scope inside its cell's step, a step's calls together, with the step beside
+it.**
 
 | form | 4,352 with a bias (Granite) | 4,096 (Kimi) | 1,280 with a bias (Nemotron) | in the cell: taps / step, ms |
 |---|---|---|---|---|
 | ``shift`` on the f32 cast (before PR 57) | 1.06 / 4.43 | 0.99 / 3.90 | 0.26 / 0.95 | Granite 50.06 / 467.18, Kimi 53.72 / 424.76, Nemotron 2.94 / 236.26 |
-| **the XLA form** | 1.06 / 2.17 | 0.99 / 1.88 | 0.26 / 0.34 | **Granite 20.40 / 443.03**, Kimi 14.66 / 374.64, **Nemotron 3.25 / 235.94** |
-| **the Mosaic calls** (467 / 403 GB/s of the bytes above at 4,352) | 0.31 / 0.53 | 0.29 / 0.52 | 0.12 / 0.18 | Granite 9.02 / 471.91 (the scan's scope 51.44 -> 70.65 and the gate's 30.02 -> 43.6: copies of ``f32[1,8192,4096]`` and ``f32[1,32,256,1,64,64]`` into the layouts the einsums read), **Kimi 11.62 / 363.37**, Nemotron 1.54 / 234.29 |
+| **the XLA form** | 1.06 / 2.17 | 0.99 / 1.88 | 0.26 / 0.34 | Granite 20.40 / 443.03 in front of the XLA scan (PR 57), 21.02 / 409.80 in front of the scan's Mosaic calls (PR 72's parent), Kimi 14.66 / 374.64, Nemotron 3.25 / 235.94 (PR 57), 2.64 / 228.73 (PR 72's parent) |
+| **the Mosaic calls** (467 / 403 GB/s of the bytes above at 4,352) | 0.31 / 0.53 | 0.29 / 0.52 | 0.12 / 0.18 | **Granite 9.01 / 392.36 and Nemotron 1.53 / 227.39 in front of the scan's Mosaic calls (my chip runs, PR 72: what ``mamba_block`` runs; 27 calls a Granite step, 0.263 forward, 0.473 backward; the ``pad`` that joins the three cotangents into the backward call's ``dy`` stands outside the scope, under ``ps.mamba``: +1.92 ms a Granite step and +0.74 a Nemotron step, so taps and pad together 10.93 and 2.27)**; in front of the XLA scan (PR 57) Granite 9.02 / 471.91 (the scan's scope 51.44 -> 70.65 and the gate's 30.02 -> 43.6: copies of ``f32[1,8192,4096]`` and ``f32[1,32,256,1,64,64]`` into the layouts the einsums read) and Nemotron 1.54 / 234.29; **Kimi 11.62 / 363.37** |
 
 The chip's plain elementwise pass over the forward's bytes (``silu`` alone)
 takes 0.17 ms at 4,352 channels: the kernel's forward stands at 0.31, bound
@@ -521,17 +525,18 @@ def conv_silu(x, w, b=None):
     only ``x``, ``w`` and ``b`` are kept, and the backward pass is the
     transposed taps, not autodiff's pads and slices of a concatenation.
     The XLA form, for a caller whose neighbours are XLA's
-    (``models/blocks.py::mamba_block``: the scan's einsums take the result
-    in three layouts that XLA writes from this function's own fusions;
-    module docstring: what each form costs where)."""
+    (``models/phi4flash.py``'s Mamba-1 mixer; until PR 59 Granite's scan,
+    whose einsums took the result in three layouts that XLA wrote from this
+    function's own fusions; module docstring: what each form costs where)."""
     return _conv_silu(x, w, b)
 
 
 def conv_silu_kernel(x, w, b=None):
     """``conv_silu`` for a caller whose neighbours are Mosaic calls
     (``models/kimi_linear.py``: q, k and v go on to the KDA kernels,
-    row-major as a custom call writes them): the Mosaic calls where ``path``
-    says the shapes take them (in interpret mode off the chip,
+    row-major as a custom call writes them; ``models/blocks.py::mamba_block``:
+    x, B and C go on to the scan's, ``ops/ssd_mosaic.py``): the Mosaic calls
+    where ``path`` says the shapes take them (in interpret mode off the chip,
     ``ops/mosaic.py::interpret``), the XLA form elsewhere."""
     if path(x, w) == "kernel":
         return _conv_silu_kernel(x, w, b, mosaic.interpret())

@@ -221,11 +221,47 @@ def _mamba_as_nemotron_had_it(lp, x, config):
         @ lp["out_proj"]["kernel"].astype(x.dtype)
 
 
+def _mixer_case(cfg, batch, seq, seed):
+    """Seeded f32 leaves of one mixer at ``cfg``'s sizes and bf16 inputs
+    ``[batch, seq, hidden_size]``: the cell's bf16 over f32 leaves."""
+    rng = np.random.default_rng(seed)
+    heads, inner, width = cfg.mamba_num_heads, cfg.mamba_inner, cfg.hidden_size
+
+    def w(*shape):
+        return jnp.asarray(0.1 * rng.normal(size=shape), jnp.float32)
+
+    lp = {"in_proj": {"kernel": w(width, inner + cfg.conv_dim + heads)},
+          "conv": {"kernel": w(cfg.conv_dim, 4), "bias": w(cfg.conv_dim)},
+          "dt_bias": w(heads), "A_log": jnp.log(jnp.asarray(
+              rng.uniform(1, 16, size=heads), jnp.float32)),
+          "D": 1 + w(heads), "out_norm": {"scale": 1 + w(inner)},
+          "out_proj": {"kernel": w(inner, width)}}
+    return lp, jnp.asarray(rng.normal(size=(batch, seq, width)), jnp.bfloat16)
+
+
+def _mixer_value_and_grads(block, cfg, lp, x):
+    """((loss, output), (the leaves' gradients, ``x``'s)) of ``block``."""
+    def loss(lp, x):
+        out = block(lp, x, cfg)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+    return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(lp, x)
+
+
 def test_the_shared_mamba_block_is_nemotrons_to_the_bit():
     """``blocks.mamba_block`` at Nemotron-H's sizes (a share of the heads on
     two B/C groups) gives the outputs and every gradient of the block that
     model had, bit for bit, in the cell's bf16 over f32 leaves; both models
-    call the one block and neither holds a mixer of its own."""
+    call the one block and neither holds a mixer of its own. Since PR 72
+    the skip is ``repeat(D, head_dim)`` times the flat channels: the same
+    products forward, so the output, ``x``'s gradient and every leaf that
+    is a product with them stay to the bit. ``D``'s gradient is the same
+    terms summed in another order (the tokens first, then a head's
+    channels: 7e-7 of its largest entry seen), held to 1e-5. XLA's CPU
+    fusions then sum two other leaves over the tokens in another order too
+    (the taps' filter 1.3e-8 of its largest entry, the norm's scale 7e-8):
+    those two are held to two f32 roundoffs of the largest entry, 2 ** -22,
+    under which no fault of a gradient hides."""
     import inspect
 
     from ps_tpu.models import granite_h, nemotron_h
@@ -238,35 +274,74 @@ def test_the_shared_mamba_block_is_nemotrons_to_the_bit():
     cfg = nemotron_h.NemotronHConfig(
         hidden_size=64, mamba_num_heads=4, mamba_head_dim=8, n_groups=2,
         ssm_state_size=16, chunk_size=32)
-    rng = np.random.default_rng(0)
-
-    def w(*shape):
-        return jnp.asarray(0.1 * rng.normal(size=shape), jnp.float32)
-
-    lp = {"in_proj": {"kernel": w(64, 32 + cfg.conv_dim + 4)},
-          "conv": {"kernel": w(cfg.conv_dim, 4), "bias": w(cfg.conv_dim)},
-          "dt_bias": w(4), "A_log": jnp.log(jnp.asarray(
-              rng.uniform(1, 16, size=4), jnp.float32)),
-          "D": 1 + w(4), "out_norm": {"scale": 1 + w(32)},
-          "out_proj": {"kernel": w(32, 64)}}
-    x = jnp.asarray(rng.normal(size=(2, 128, 64)), jnp.bfloat16)
-
-    def run(block):
-        def loss(lp, x):
-            out = block(lp, x, cfg)
-            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
-
-        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))(lp, x)
-
-    (_, out), grads = run(nemotron_h.mamba_block)
-    (_, want), want_grads = run(_mamba_as_nemotron_had_it)
+    lp, x = _mixer_case(cfg, 2, 128, seed=0)
+    (_, out), grads = _mixer_value_and_grads(nemotron_h.mamba_block, cfg,
+                                             lp, x)
+    (_, want), want_grads = _mixer_value_and_grads(
+        _mamba_as_nemotron_had_it, cfg, lp, x)
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(want, np.float32))
+    sums = [[tree.pop("D"), tree["conv"].pop("kernel"),
+             tree.pop("out_norm")["scale"]]
+            for tree in (grads[0], want_grads[0])]
+    for g, r, tol in zip(*sums, (1e-5, 2.0 ** -22, 2.0 ** -22)):
+        np.testing.assert_allclose(g, r, rtol=0,
+                                   atol=tol * float(jnp.max(jnp.abs(r))))
     for g, r in zip(jax.tree_util.tree_leaves(grads),
                     jax.tree_util.tree_leaves(want_grads)):
         assert float(jnp.max(jnp.abs(r.astype(jnp.float32)))) > 0
         np.testing.assert_array_equal(np.asarray(g, np.float32),
                                       np.asarray(r, np.float32))
+
+
+def test_the_mixer_in_front_of_the_scans_kernels_takes_the_taps_kernels():
+    """``blocks.mamba_block`` at the smallest shape the scan's kernels and
+    the taps' both take (two heads of 64 on one B/C group over a state of 128
+    are 384 channels through the taps, and 1,536 positions of them fill one
+    block of 512 Ki elements; interpret mode here): the taps are their Mosaic
+    calls in front of the scan's, and the output and every gradient agree with
+    the block as it stood before PR 72, the XLA taps and the skip as 4-D
+    math in front of the same scan. ``tests/test_gated_conv.py``'s
+    tolerances for the taps' two forms in bf16: one rounding of the largest
+    entry (2 ** -7) for the output and every gradient that passes through
+    it, 1e-5 for the sums over the tokens that the kernels take block by
+    block (the filter's, the bias's) and for ``D``'s, another order of one
+    sum (seen: 6e-8, 1e-7 and 8e-7, everything else to the bit)."""
+    from ps_tpu.models import nemotron_h
+    from ps_tpu.ops import gated_conv, ssd_mosaic
+
+    cfg = nemotron_h.NemotronHConfig(
+        hidden_size=64, mamba_num_heads=2, mamba_head_dim=64, n_groups=1,
+        ssm_state_size=128, chunk_size=128)
+    lp, x = _mixer_case(cfg, 1, 1536, seed=72)
+    assert ssd_mosaic.takes(jax.ShapeDtypeStruct((1, 1536, 2, 64), x.dtype),
+                            jax.ShapeDtypeStruct((1, 1536, 1, 128), x.dtype),
+                            cfg.chunk_size)
+    assert gated_conv.path(jax.ShapeDtypeStruct((1, 1536, 384), x.dtype),
+                           lp["conv"]["kernel"]) == "kernel"
+
+    # the forward alone: the taps' call and the scan's, where one stood
+    for block, calls in ((nemotron_h.mamba_block, 2),
+                         (_mamba_as_nemotron_had_it, 1)):
+        traced = jax.make_jaxpr(lambda lp, x: block(lp, x, cfg))(lp, x)
+        assert str(traced).count("pallas_call") == calls
+    (_, out), grads = _mixer_value_and_grads(nemotron_h.mamba_block, cfg,
+                                             lp, x)
+    (_, want), want_grads = _mixer_value_and_grads(
+        _mamba_as_nemotron_had_it, cfg, lp, x)
+
+    def rel(got, ref):
+        got, ref = (np.asarray(t, np.float32) for t in (got, ref))
+        return np.abs(got - ref).max() / np.abs(ref).max()
+
+    assert rel(out, want) <= 2.0 ** -7
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (leaf, g), r in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        name = jax.tree_util.keystr(leaf)
+        a_sum = name.endswith(("['D']", "['conv']['kernel']",
+                               "['conv']['bias']"))
+        assert g.dtype == r.dtype and rel(g, r) <= (
+            1e-5 if a_sum else 2.0 ** -7), (name, rel(g, r))
 
 
 def test_the_interleaved_rotation_leaves_the_other_callers_trace_alone():

@@ -290,11 +290,12 @@ def test_the_taps_shift_in_vmem_at_the_cells_shapes(cell, one_chip,
 @pytest.mark.parametrize("cell", sorted(c for c in TAPS if "kimi" not in c))
 def test_the_taps_xla_form_writes_no_shifted_copy(cell, one_chip,
                                                   no_compile_cache):
-    """``conv_silu`` as ``mamba_block`` calls it (no word of the kernels: the
-    XLA form) at ``bf16[1, 8192, C]``: the pad in ``x``'s own dtype and the
-    slices cut from it fuse, so the forward keeps nothing beside ``x`` and
-    ``y`` (428 MB of shifted f32 copies before PR 57) and the gradient at
-    most ``dz`` in f32 (571 MB before), and neither holds a Mosaic call."""
+    """``conv_silu`` (no word of the kernels: the XLA form, which
+    ``mamba_block`` called until PR 72) at ``bf16[1, 8192, C]``: the pad in
+    ``x``'s own dtype and the slices cut from it fuse, so the forward keeps
+    nothing beside ``x`` and ``y`` (428 MB of shifted f32 copies before PR 57)
+    and the gradient at most ``dz`` in f32 (571 MB before), and neither holds
+    a Mosaic call."""
     channels, _ = TAPS[cell]
 
     def arg(*shape, dtype=jnp.float32):
@@ -493,6 +494,61 @@ def test_the_scan_compiles_at_granites_shape(one_chip, no_compile_cache):
     assert not re.search(r"f32\[[0-9,]*256,256\]", text)
     assert not re.search(r"\[1,8192,(4096|64,64)\]\S* copy\(", text)
     assert _entering_states(64) <= temp <= _entering_states(64) + 2 ** 27
+
+
+#: (model width, heads of 64 on one B/C group of state 128, chunk): the two
+#: cells whose layers run ``models/blocks.py::mamba_block``
+MIXERS = {"granite-4.0-h-micro.s8192.b1.zipf": (2048, 64, 256),
+          "nemotron-3-super-120b-a12b.s8192.b1.zipf": (4096, 16, 128)}
+
+
+@pytest.mark.parametrize("cell", sorted(MIXERS))
+def test_the_mixer_is_six_mosaic_calls_and_no_copy_at_the_cells_shapes(
+        cell, one_chip, no_compile_cache):
+    """The gradient of two layers of ``mamba_block`` at ``bf16[1, 8192, D]``,
+    each under a ``jax.checkpoint`` with a residual around it as both models
+    run them: the scan takes its kernels at these shapes and the taps
+    theirs (``ssd_mosaic.takes``, ``gated_conv.path``), so a layer holds six
+    Mosaic calls: the taps' forward and the scan's, both again in the
+    recomputation, and the two backward calls. No array as large as the
+    scan's ``x`` is copied into a second layout. With the XLA taps and the
+    skip as 4-D math over ``[.., heads, 64]`` a layer of this program held
+    four such copies (``bf16[1,8192,4096]{1,2,0}`` in front of the scan's
+    backward call, two back to ``{2,1,0}`` behind it,
+    ``f32[1,8192,4096]{1,2,0}`` under the gate's product: 36 in the Granite
+    cell's step, 7.9 ms; one mixer alone compiles without them, two in a row
+    do not)."""
+    from ps_tpu.models import blocks
+
+    width, heads, chunk = MIXERS[cell]
+    inner, state = heads * 64, 128
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lp = {"in_proj": {"kernel": arg(width, 2 * inner + 2 * state + heads)},
+          "conv": {"kernel": arg(inner + 2 * state, 4),
+                   "bias": arg(inner + 2 * state)},
+          "dt_bias": arg(heads), "A_log": arg(heads), "D": arg(heads),
+          "norm": arg(width), "out_norm": {"scale": arg(inner)},
+          "out_proj": {"kernel": arg(inner, width)}}
+
+    @jax.checkpoint
+    def layer(lp, x):
+        return x + blocks.mamba_block(
+            lp, blocks.rms_norm(x, lp["norm"], 1e-5), heads=heads,
+            head_dim=64, groups=1, state=state, chunk=chunk, eps=1e-5)
+
+    def loss(lp, x):
+        return jnp.sum(layer(lp, layer(lp, x)).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        lp, arg(1, 8192, width, dtype=jnp.bfloat16)).compile().as_text()
+    _, calls = _mosaic_calls(text)
+    assert len(calls) == 12
+    assert sum("ps.mamba/conv" in line for line in calls) == 6
+    assert sum("ps.mamba/ssd" in line for line in calls) == 6
+    assert not re.search(rf"\[1,8192,({inner}|{heads},64)\]\S* copy\(", text)
 
 
 def test_a_rehearse_shape_takes_the_xla_form(one_chip, no_compile_cache):

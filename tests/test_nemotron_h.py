@@ -9,9 +9,9 @@ uncut layer; then the family's pieces.
 
 Tolerances. Both sides compute in f32 here and differ only in the order of
 their sums: losses agree to a few f32 roundoffs, gradients to 1e-5 of their
-largest entry (seen: under 9e-6). The weights are scaled up from the cell's
-0.02 so that every mixer and every expert moves the loss by far more than
-that.
+largest entry (seen: under 9e-6), but for one leaf, ``WIDER``. The weights are
+scaled up from the cell's 0.02 so that every mixer and every expert moves the
+loss by far more than that.
 """
 
 import functools
@@ -35,6 +35,18 @@ from ps_tpu.ops.ssd import ssd
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32_TOL = 1e-5
+#: The one leaf of ``_base``'s model that ``F32_TOL`` cannot hold: four sums
+#: over every token of terms that cancel to 6.5e-6 at the largest, a thirtieth
+#: of the other mixers' ``dt_bias``, so it reads the order XLA's CPU backend
+#: sums in and not the model. Compiled beside another mixer's code the whole
+#: program's sums are ordered anew: between PR 72's parent and PR 72 (the
+#: skip over flat channels; the same forward and loss to the bit) each of the
+#: 93 leaves moved in its last bits, the head's included, by 1.4e-7 to 3.6e-6
+#: of its largest entry, and this one by 6.4e-6 (full) and 1.7e-5 (flash):
+#: 4.06e-6 and 6.63e-6 off the reference before, 7.65e-6 and 1.03e-5 since.
+#: Twice the limit for it alone; the other fourteen such sums (``dt_bias``,
+#: ``A_log``, ``D`` of five mixers) read under 6.2e-6 and stay at ``F32_TOL``.
+WIDER = {"['layer6']['mamba']['dt_bias']": 2 * F32_TOL}
 CELL = "nemotron-3-super-120b-a12b.s8192.b1.zipf"
 CONFIG = "benchmark/configs/nemotron-3-super-120b-a12b.json"
 #: the cell's eleven-layer pattern in small: 4 of 32 Mamba heads with 2 of 16
@@ -101,10 +113,11 @@ def _rel(got, want):
                  / (jnp.max(jnp.abs(want)) + 1e-30))
 
 
-def _assert_grads_close(grads, ref_grads, tol=F32_TOL):
+def _assert_grads_close(grads, ref_grads, tol=F32_TOL, wider=None):
     flat = jax.tree_util.tree_leaves_with_path(grads)
     for (path, g), r in zip(flat, jax.tree_util.tree_leaves(ref_grads)):
-        assert _rel(g, r) <= tol, (jax.tree_util.keystr(path), _rel(g, r))
+        name = jax.tree_util.keystr(path)
+        assert _rel(g, r) <= (wider or {}).get(name, tol), (name, _rel(g, r))
 
 
 # -- (b) the model against the reference --------------------------------------
@@ -126,7 +139,7 @@ def test_system_matches_reference(attn):
     # every tensor has a gradient that is not nothing
     assert all(float(jnp.max(jnp.abs(g))) > 0
                for g in jax.tree_util.tree_leaves(ref_grads))
-    _assert_grads_close(grads, ref_grads)
+    _assert_grads_close(grads, ref_grads, wider=WIDER)
     with jax.default_matmul_precision("highest"):
         hidden, *_ = nemotron_h.apply(params, batch["inputs"], cfg, bias,
                                        make_attn_fn(attn))
